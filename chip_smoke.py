@@ -1,0 +1,558 @@
+#!/usr/bin/env python3
+"""The quickest proof that the system still starts on the chip.
+
+`python3 chip_smoke.py` drives the train and serve paths once, through
+the entry points a user calls, at the full width of the widest model the
+repo supports — `gpt_configuration(vocab 256, d_model 1024, 8 heads of
+128, 8 layers)`, bf16 compute, random weights from a seed:
+
+- **train**: `MultiLayerNetwork.fit` over a repeated batch at the
+  `gpt_long` shape (T=4096, B=8, block 512): finite falling loss, flash
+  fwd+bwd kernels engaged.
+- **serve**: `GatewayServer` over TCP on localhost with a `ModelServer`
+  generation tier behind it (`ReplicaEntryPoint.serve_net`), concurrent
+  128-token prompts plus one long prompt that rides chunked prefill,
+  donated KV pools; then the same prompts through a gather-path engine
+  (token agreement) and a short int8-KV engine. Paged kernel engaged for
+  the decode, chunk and int8 shape classes.
+- **lstm**: `lstm_large` (H=1024, T=64, B=2048) with the fused cell.
+- **multichip** (>= 4 devices): the train step through `ParallelWrapper`
+  on a {data 2, model 2} mesh against the one-chip loss, tp=4 decode
+  against tp=1 tokens, and where params, KV and pool replicas land.
+
+It refuses to run unless JAX's first device is a TPU whose `device_kind`
+the repo's tables know; a phase that fails raises, so the exit code is 0
+only if every phase passed. One process holds the chip throughout: each
+phase frees its state before the next (16 GB of HBM does not hold train,
+serve and lstm together). The last line of stdout is one JSON object.
+
+`python3 chip_smoke.py train lstm` runs only the named phases.
+"""
+from __future__ import annotations
+
+import collections
+import gc
+import json
+import os
+import shutil
+import sys
+import threading
+import time
+
+import numpy as np
+
+from deeplearning4j_tpu.ops.kernel_dispatch import engaged
+
+GPT = dict(vocab_size=256, d_model=1024, n_heads=8, n_layers=8)
+TRAIN = dict(T=4096, batch=8, block=512, steps=3)
+SERVE = dict(n_slots=8, max_len=4224, page_size=128, prefill_chunk=256,
+             n_short=6, short_len=128, long_len=2304, n_tokens=64,
+             int8_tokens=16)
+LSTM = dict(vocab=256, hidden=1024, T=64, batch=2048, steps=2)
+
+# A greedy token may differ between two correct attention paths only
+# where the model itself is undecided: the log-probability gap between
+# the two candidates, read off the net's own forward pass, must be inside
+# bf16 noise (8 bits of mantissa on d_model-long sums).
+TIE_MARGIN_NATS = 0.1
+# bf16 tolerance for one loss computed two ways (1 chip vs the mesh)
+LOSS_RTOL = 2e-2
+
+
+class SmokeFailure(Exception):
+    """A phase ran and what came out was wrong."""
+
+
+def _check(cond, what: str) -> None:
+    if not cond:
+        raise SmokeFailure(what)
+
+
+def _hbm_in_use(n_devices: int = 1) -> list:
+    """Bytes in use on each of the first `n_devices` (0 where the
+    backend keeps no count, as on the CPU)."""
+    import jax
+
+    return [int((d.memory_stats() or {}).get("bytes_in_use", 0))
+            for d in jax.devices()[:n_devices]]
+
+
+class CompileMeter:
+    """Backend-compile seconds and persistent-cache hits/misses of this
+    process, from JAX's own monitoring events."""
+
+    def __init__(self):
+        import jax.monitoring
+
+        self._lock = threading.Lock()
+        self._n = collections.Counter()  # guarded by: _lock
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event.startswith("/jax/compilation_cache/cache_"):
+            with self._lock:
+                self._n[event.rsplit("/", 1)[1]] += 1
+
+    def _on_duration(self, event: str, secs: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            with self._lock:
+                self._n["compile_seconds"] += secs
+
+    def read(self) -> dict:
+        with self._lock:
+            return {"compile_seconds": round(self._n["compile_seconds"], 2),
+                    "cache_hits": self._n["cache_hits"],
+                    "cache_misses": self._n["cache_misses"]}
+
+
+def _gpt_net(gpt: dict, max_length: int, block: int = 1024):
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models.transformer import gpt_configuration
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+    net = MultiLayerNetwork(
+        gpt_configuration(max_length=max_length,
+                          attention_block_size=block, **gpt),
+        compute_dtype=jnp.bfloat16)
+    net.init()
+    return net
+
+
+def _lm_batch(vocab: int, batch: int, T: int):
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+
+    ids = np.random.default_rng(0).integers(0, vocab, (batch, T + 1))
+    return DataSet(ids[:, :-1].astype(np.int32), ids[:, 1:].astype(np.int32))
+
+
+def _fit_steps(fit, net, ds, steps: int, vocab: int,
+               must_fall: bool = True) -> dict:
+    """`steps` calls of `fit(ds)` over the SAME batch: per-step loss and
+    wall seconds (the first step's include tracing and compiling). The
+    loss must be finite, start near ln(vocab) — random weights predict
+    uniformly — and, over an Adam-sized step, fall."""
+    losses, secs = [], []
+    for _ in range(steps):
+        t0 = time.perf_counter()
+        fit(ds)
+        losses.append(float(net.score_value))  # host read = the barrier
+        secs.append(round(time.perf_counter() - t0, 3))
+    _check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    _check(0.8 < losses[0] / np.log(vocab) < 1.25,
+           f"first loss {losses[0]} is not near ln({vocab})")
+    _check(losses[-1] < losses[0] or not must_fall,
+           f"loss did not fall over a repeated batch: {losses}")
+    return {"losses": [round(l, 4) for l in losses], "step_seconds": secs}
+
+
+# ------------------------------------------------------------------ train
+def phase_train(gpt: dict, shape: dict, *, kernels: bool) -> dict:
+    net = _gpt_net(gpt, shape["T"], shape["block"])
+    ds = _lm_batch(gpt["vocab_size"], shape["batch"], shape["T"])
+    out = _fit_steps(net.fit, net, ds, shape["steps"], gpt["vocab_size"])
+    if kernels:
+        hd = gpt["d_model"] // gpt["n_heads"]
+        tiles = engaged("flash_attention", lambda k: k[0] == "bfloat16"
+                         and k[2] == hd and shape["T"] % k[1] == 0)
+        _check(tiles, "the step ran but no flash fwd+bwd tile engaged")
+        out["flash_tile"] = max(k[1] for k in tiles)
+    return out
+
+
+# ------------------------------------------------------------------- lstm
+def phase_lstm(shape: dict, *, kernels: bool) -> dict:
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+    from deeplearning4j_tpu.datasets.normalizers import OneHotEncoder
+    from deeplearning4j_tpu.nn.conf import (
+        GravesLSTM,
+        InputType,
+        NeuralNetConfiguration,
+        RnnOutputLayer,
+    )
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+    from deeplearning4j_tpu.nn.updater import Updater
+    from deeplearning4j_tpu.ops.activations import Activation
+    from deeplearning4j_tpu.ops.losses import LossFunction
+
+    vocab, hidden = shape["vocab"], shape["hidden"]
+    conf = (NeuralNetConfiguration.Builder()
+            .seed(1).learning_rate(0.1).updater(Updater.RMSPROP)
+            .list()
+            .layer(GravesLSTM(n_in=vocab, n_out=hidden,
+                              activation=Activation.TANH))
+            .layer(GravesLSTM(n_out=hidden, activation=Activation.TANH))
+            .layer(RnnOutputLayer(n_out=vocab, loss=LossFunction.MCXENT,
+                                  activation=Activation.SOFTMAX))
+            .set_input_type(InputType.recurrent(vocab))
+            .build())
+    net = MultiLayerNetwork(conf, compute_dtype=jnp.bfloat16)
+    net.init()
+    net.set_normalizer(OneHotEncoder(vocab))
+    ids = np.random.default_rng(0).integers(
+        0, vocab, (shape["batch"], shape["T"] + 1))
+    ds = DataSet(ids[:, :-1].astype(np.uint8), ids[:, 1:].astype(np.int32))
+    # lstm_large's own updater (RMSprop at 0.1) overshoots on its second
+    # step at any size: finite and well-scaled is what this phase asks
+    out = _fit_steps(net.fit, net, ds, shape["steps"], vocab,
+                     must_fall=False)
+    if kernels:
+        blocks = engaged("fused_lstm", lambda k: k[0] == "bfloat16"
+                          and k[2] == hidden and not k[3]
+                          and shape["batch"] % k[1] == 0)
+        _check(blocks, "the step ran but the fused LSTM cell did not engage")
+        out["batch_block"] = max(k[1] for k in blocks)
+    return out
+
+
+# ------------------------------------------------------------------ serve
+def _serve_prompts(vocab: int, shape: dict) -> list:
+    rng = np.random.default_rng(1)
+    lens = [shape["short_len"]] * shape["n_short"] + [shape["long_len"]]
+    return [rng.integers(0, vocab, n).astype(np.int32) for n in lens]
+
+
+def _engine_kwargs(shape: dict) -> dict:
+    return dict(n_slots=shape["n_slots"], max_len=shape["max_len"],
+                page_size=shape["page_size"],
+                prefill_chunk=shape["prefill_chunk"],
+                prompt_buckets=(shape["short_len"],))
+
+
+def _through_gateway(net, prompts, n_tokens: int, gen: dict):
+    """The normal serving entry points in one process: a gateway on real
+    TCP, the net installed behind a `ModelServer` generation tier, one
+    client connection per concurrent request."""
+    from deeplearning4j_tpu.gateway import GatewayClient, GatewayServer
+    from deeplearning4j_tpu.serving.remote_replica import ReplicaEntryPoint
+
+    entry = ReplicaEntryPoint(serving={"generation": gen})
+    entry.serve_net(net, "gpt")
+    gw = GatewayServer(entry_point=entry).start()
+    results: list = [None] * len(prompts)
+
+    def one(i: int) -> None:
+        # the first request waits out the compiles: a client timeout
+        # would RETRY the idempotent generate and double the work
+        client = GatewayClient(port=gw.port, timeout=1100.0, max_retries=0)
+        try:
+            results[i] = client.call("generate", name="gpt",
+                                     prompt_ids=prompts[i],
+                                     n_tokens=n_tokens)
+        except BaseException as e:  # re-raised by the caller below
+            results[i] = e
+        finally:
+            client.close()
+
+    try:
+        threads = [threading.Thread(target=one, args=(i,), daemon=True)
+                   for i in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(1150.0)
+        _check(not any(t.is_alive() for t in threads),
+               "generate requests still in flight after 1150 s")
+        for r in results:
+            if isinstance(r, BaseException):
+                raise r
+        client = GatewayClient(port=gw.port)
+        try:
+            stats = client.call("server_stats", name="gpt")["generation"]
+        finally:
+            client.close()
+    finally:
+        gw.stop()
+    return [np.asarray(r) for r in results], stats
+
+
+def _through_engine(net, prompts, n_tokens: int, **kw):
+    """The same prompts straight through a `DecodeEngine`, all submitted
+    at once; returns (tokens, stats)."""
+    from deeplearning4j_tpu.serving.decode_engine import DecodeEngine
+
+    engine = DecodeEngine(net, **kw)
+    try:
+        reqs = [engine.submit(p, n_tokens, timeout=1100.0) for p in prompts]
+        toks = [np.asarray(r.result(timeout=1150.0)) for r in reqs]
+        return toks, engine.stats()
+    finally:
+        engine.shutdown(drain_timeout=30.0)
+
+
+def _check_tokens(toks, n_tokens: int, vocab: int, stats: dict,
+                  n_requests: int, who: str) -> None:
+    for t in toks:
+        _check(t.shape == (n_tokens,) and t.dtype.kind == "i"
+               and 0 <= t.min() and t.max() < vocab,
+               f"{who}: bad tokens shape={t.shape} dtype={t.dtype}")
+    _check(stats["failures"] == 0, f"{who}: {stats['failures']} failures")
+    _check(stats["served"] == n_requests,
+           f"{who}: served {stats['served']} of {n_requests}")
+    _check(stats["pages_in_use"] == 0,
+           f"{who}: {stats['pages_in_use']} pages still held after drain")
+
+
+def _tie_margin(net, prompt, a, b) -> float:
+    """Log-probability gap, by the net's own full-context forward pass,
+    between the two tokens on which paths `a` and `b` first disagree.
+    The context is right-padded to one fixed width (causal attention:
+    padding after a position cannot change it), so every call shares
+    one compile."""
+    i = int(np.argmax(a != b))
+    ctx = np.concatenate([prompt, a[:i]])
+    width = -(-(len(prompt) + len(a)) // 128) * 128
+    ids = np.zeros((1, width), np.int32)
+    ids[0, :len(ctx)] = ctx
+    probs = np.asarray(net.output(ids), np.float64)[0, len(ctx) - 1]
+    return float(abs(np.log(probs[a[i]]) - np.log(probs[b[i]])))
+
+
+def _agreement(net, prompts, got, ref, who: str) -> dict:
+    """How far two greedy token streams agree, request by request, and —
+    where they part — whether the model itself was undecided there."""
+    prefix = [int(np.argmax(a != b)) if np.any(a != b) else len(a)
+              for a, b in zip(got, ref)]
+    margins = [round(_tie_margin(net, p, a, b), 4)
+               for p, a, b, n in zip(prompts, got, ref, prefix) if n < len(a)]
+    _check(all(m < TIE_MARGIN_NATS for m in margins),
+           f"{who} disagree where the model is not undecided: "
+           f"margins {margins} nats (prefixes {prefix})")
+    return {"common_prefix_tokens": prefix, "of": len(got[0]),
+            "tie_margins_nats": margins}
+
+
+def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
+    vocab, n_tokens = gpt["vocab_size"], shape["n_tokens"]
+    net = _gpt_net(gpt, shape["max_len"])
+    prompts = _serve_prompts(vocab, shape)
+    n_chunks = -(-shape["long_len"] // shape["prefill_chunk"])
+    dispatches = collections.Counter()
+
+    def count(phase: str, info: dict) -> None:
+        if phase == "pre_decode":
+            dispatches["decode_chunk" if info["chunk"] > 1
+                       else "decode_step"] += 1
+        elif phase == "pre_prefill":
+            dispatches["prefill"] += 1
+
+    gen = _engine_kwargs(shape)
+    toks, stats = _through_gateway(net, prompts, n_tokens,
+                                   dict(gen, step_hooks=[count]))
+    _check_tokens(toks, n_tokens, vocab, stats, len(prompts), "gateway")
+    _check(stats["prefill_chunks"] >= n_chunks,
+           f"long prompt did not ride chunked prefill: "
+           f"{stats['prefill_chunks']} chunks < {n_chunks}")
+    _check(dispatches["decode_chunk"] > 0 and dispatches["prefill"] > 0,
+           f"fused decode_chunk scan never dispatched: {dict(dispatches)}")
+    out = {"requests": len(prompts), "tokens": int(sum(map(len, toks))),
+           "prefill_chunks": stats["prefill_chunks"],
+           "decode_steps": stats["decode_steps"],
+           "dispatches": dict(dispatches)}
+    gc.collect()  # free the device state just dropped
+
+    # the same prompts down the gather path — the XLA reference the
+    # kernel's probe is checked against — in a fresh engine (the dispatch
+    # is traced into each engine's jit closures, so the switch is read
+    # at build time)
+    os.environ["DL4J_TPU_NO_PALLAS_PAGED_ATTENTION"] = "1"
+    try:
+        ref, ref_stats = _through_engine(net, prompts, n_tokens, **gen)
+    finally:
+        del os.environ["DL4J_TPU_NO_PALLAS_PAGED_ATTENTION"]
+    _check_tokens(ref, n_tokens, vocab, ref_stats, len(prompts), "gather")
+    out["agreement"] = _agreement(net, prompts, toks, ref,
+                                  "kernel and gather paths")
+    gc.collect()  # free the device state just dropped
+
+    # int8 KV: one short and the long prompt, so the quantized decode
+    # AND chunk classes dispatch
+    q_prompts = [prompts[0], prompts[-1]]
+    q_toks, q_stats = _through_engine(net, q_prompts, shape["int8_tokens"],
+                                      quantize={"kv": "int8"}, **gen)
+    _check_tokens(q_toks, shape["int8_tokens"], vocab, q_stats,
+                  len(q_prompts), "int8")
+    _check(q_stats["kv_quant_bits"] == 8, "int8 engine built bf16 pools")
+    out["int8"] = {"requests": len(q_prompts),
+                   "first_token_matches_bf16": [
+                       bool(q[0] == t[0]) for q, t in
+                       zip(q_toks, (toks[0], toks[-1]))]}
+
+    if kernels:
+        H = gpt["n_heads"]
+        hd = gpt["d_model"] // H
+        for C, kind in ((1, "dense"), (shape["prefill_chunk"], "dense"),
+                        (1, "int8"), (shape["prefill_chunk"], "int8")):
+            key = ("bfloat16", C, H, H, hd, shape["page_size"], kind)
+            _check(engaged("paged_attention", lambda k: k == key),
+                   f"paged kernel did not engage for shape class {key}")
+        out["paged_classes"] = len(engaged("paged_attention"))
+    return out
+
+
+# -------------------------------------------------------------- multichip
+def phase_multichip(gpt: dict, train: dict, serve: dict,
+                    one_chip_loss: float) -> dict:
+    """Four chips, one process. `one_chip_loss`: the train phase's
+    first-step loss (same seed, same batch) the mesh run must match."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from deeplearning4j_tpu.parallel.mesh import make_mesh
+    from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
+    from deeplearning4j_tpu.serving.decode_engine import DecodeEngine
+    from deeplearning4j_tpu.serving.replica_pool import ReplicaPool
+
+    out = {}
+    # (a) the train step over {data 2, model 2}, Megatron specs
+    net = _gpt_net(gpt, train["T"], train["block"])
+    megatron = {"Wqkv": P(None, "model"), "bqkv": P("model"),
+                "Wo": P("model", None), "W1": P(None, "model"),
+                "b1": P("model"), "W2": P("model", None)}
+    specs = {i: megatron for i, layer in enumerate(net.layers)
+             if type(layer).__name__ == "TransformerBlock"}
+    mesh = make_mesh({"data": 2, "model": 2}, devices=jax.devices()[:4])
+    pw = ParallelWrapper(net, mesh=mesh, param_specs=specs)
+    ds = _lm_batch(gpt["vocab_size"], train["batch"], train["T"])
+    # judged against the one-chip loss, not against its own trajectory
+    fitted = _fit_steps(pw.fit, net, ds, train["steps"], gpt["vocab_size"],
+                        must_fall=False)
+    mesh_loss = fitted["losses"][0]
+    _check(abs(mesh_loss - one_chip_loss) <= LOSS_RTOL * abs(one_chip_loss),
+           f"mesh loss {mesh_loss} vs one-chip {one_chip_loss}")
+    out["train"] = dict(fitted, mesh=dict(mesh.shape),
+                        one_chip_loss=one_chip_loss,
+                        hbm_in_use=_hbm_in_use(4))
+    del pw, net
+    gc.collect()  # free the device state just dropped
+
+    # (b) tp=4 decode against tp=1 tokens, (c) where params and KV sit
+    net = _gpt_net(gpt, serve["max_len"])
+    prompts = _serve_prompts(gpt["vocab_size"], serve)[:3]
+    gen = _engine_kwargs(serve)
+    n_tokens = serve["int8_tokens"]
+    tp1, _ = _through_engine(net, prompts, n_tokens, **gen)
+    gc.collect()  # free the device state just dropped
+    before = _hbm_in_use(4)
+    engine = DecodeEngine(net, parallel={"tp": 4}, **gen)
+    try:
+        reqs = [engine.submit(p, n_tokens, timeout=1100.0) for p in prompts]
+        tp4 = [np.asarray(r.result(timeout=1150.0)) for r in reqs]
+        held = [a - b for a, b in zip(_hbm_in_use(4), before)]
+        tp_stats = engine.stats()
+        # every KV pool and every sharded weight: a quarter on each chip
+        spread = [sorted(sh.device.id for sh in leaf.addressable_shards
+                         if sh.data.nbytes * 4 == leaf.nbytes)
+                  for leaf in jax.tree_util.tree_leaves(engine._caches)
+                  + [engine._dparams[engine._plan.block_is[0]]["Wqkv"]]]
+    finally:
+        engine.shutdown(drain_timeout=30.0)
+    _check(tp_stats["failures"] == 0, f"tp=4: {tp_stats['failures']} failed")
+    _check(all(len(set(ids)) == 4 for ids in spread),
+           f"tp=4 pools/weights not quartered over 4 devices: {spread[:2]}")
+    out["tp4"] = dict(_agreement(net, prompts, tp4, tp1, "tp=4 and tp=1"),
+                      engine_bytes_per_device=held)
+    del engine
+    gc.collect()  # free the device state just dropped
+
+    # (d) where an in-process pool's four replicas land: recorded, not
+    # judged — placement is ROADMAP R5's
+    pool = ReplicaPool.from_net(net, 4)
+    try:
+        out["replica_devices"] = [
+            sorted({d.id for leaf in jax.tree_util.tree_leaves(
+                rep.server.net._params) for d in leaf.devices()})
+            for rep in pool._replicas]
+    finally:
+        pool.shutdown(drain_timeout=10.0)
+    return out
+
+
+# ------------------------------------------------------------------- main
+def _versions() -> dict:
+    import importlib.metadata as md
+
+    import jax
+
+    return {"jax": jax.__version__, "jaxlib": md.version("jaxlib"),
+            "libtpu": md.version("libtpu")}
+
+
+def main(argv=None) -> int:
+    import jax
+
+    from deeplearning4j_tpu.native.loader import native_available
+    from deeplearning4j_tpu.ops.kernel_dispatch import (
+        verdicts_as_json,
+        vmem_limit_for_kind,
+    )
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+
+    names = list(sys.argv[1:] if argv is None else argv) \
+        or ["train", "serve", "lstm", "multichip"]
+    unknown = set(names) - {"train", "serve", "lstm", "multichip"}
+    if unknown or ("multichip" in names and "train" not in names):
+        print(f"chip_smoke: phases are train serve lstm multichip "
+              f"(multichip compares against train's loss, so name both); "
+              f"got {names}", file=sys.stderr)
+        return 2
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"chip_smoke: no accelerator — JAX's first device is "
+              f"{dev.platform}:{dev.device_kind}; this script only runs "
+              "on a TPU", file=sys.stderr)
+        return 2
+    vmem_limit_for_kind(dev.device_kind)  # a chip the tables do not know
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    cache_dir = enable_compile_cache()
+    meter = CompileMeter()
+    print(f"chip_smoke: {device}, {_versions()}, compile cache {cache_dir}",
+          flush=True)
+
+    phases: dict = {}
+
+    def run(name: str, fn, *args, **kw) -> None:
+        before, t0 = meter.read(), time.perf_counter()
+        result = fn(*args, **kw)
+        gc.collect()  # free the device state just dropped
+        after = meter.read()
+        result.update(
+            ok=True, seconds=round(time.perf_counter() - t0, 1),
+            hbm_in_use_after=_hbm_in_use()[0],
+            **{k: round(after[k] - before[k], 2) for k in after})
+        phases[name] = result
+        print(f"{name}: {json.dumps(result)}", flush=True)
+
+    if "train" in names:
+        run("train", phase_train, GPT, TRAIN, kernels=True)
+    if "serve" in names:
+        run("serve", phase_serve, GPT, SERVE, kernels=True)
+    if "lstm" in names:
+        run("lstm", phase_lstm, LSTM, kernels=True)
+    if "multichip" in names:
+        if device["count"] >= 4:
+            run("multichip", phase_multichip, GPT, TRAIN, SERVE,
+                phases["train"]["losses"][0])
+        else:
+            print(f"multichip: not run ({device['count']} device)",
+                  flush=True)
+            phases["multichip"] = {
+                "ok": None, "not_run": f"{device['count']} device"}
+
+    print(json.dumps({
+        "ok": True, "device": device, "versions": _versions(),
+        "phases": phases, "kernels": verdicts_as_json(),
+        "compile": dict(meter.read(), cache_dir=cache_dir),
+        "native": {"gxx": shutil.which("g++") is not None,
+                   "loaded": native_available()},
+        "claim": None}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

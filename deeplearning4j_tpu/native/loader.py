@@ -9,7 +9,9 @@ pure-Python fallback.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
+import os
 import subprocess
 import threading
 from pathlib import Path
@@ -20,16 +22,26 @@ import numpy as np
 logger = logging.getLogger("deeplearning4j_tpu")
 
 _SRC = Path(__file__).parent / "src" / "dl4jtpu_native.cpp"
-_SO = Path(__file__).parent / "_dl4jtpu_native.so"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def _so_path() -> Path:
+    """The shared library's path, NAMED BY ITS SOURCE'S CONTENT: the
+    binary is git-ignored and tools copy working trees between machines,
+    so a file's presence (or its mtime) proves nothing about what it was
+    built from — a digest in the name does, and a stale or foreign
+    binary is simply never found."""
+    digest = hashlib.sha1(_SRC.read_bytes()).hexdigest()[:12]
+    return Path(__file__).parent / f"_dl4jtpu_native.{digest}.so"
+
+
+def _build(so: Path) -> bool:
+    tmp = so.with_suffix(f".{os.getpid()}.tmp")  # concurrent builders
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-           "-o", str(_SO), str(_SRC)]
+           "-o", str(tmp), str(_SRC)]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired) as e:
@@ -39,6 +51,7 @@ def _build() -> bool:
         logger.warning("native build failed; using Python fallbacks:\n%s",
                        proc.stderr[-2000:])
         return False
+    os.replace(tmp, so)
     return True
 
 
@@ -49,11 +62,11 @@ def native_lib() -> Optional[ctypes.CDLL]:
         if _tried:
             return _lib
         _tried = True
-        if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-            if not _build():
-                return None
+        so = _so_path()
+        if not so.exists() and not _build(so):
+            return None
         try:
-            lib = ctypes.CDLL(str(_SO))
+            lib = ctypes.CDLL(str(so))
         except OSError as e:
             logger.warning("native library load failed (%s)", e)
             return None

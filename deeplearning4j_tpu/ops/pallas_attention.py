@@ -2,7 +2,8 @@
 
 The role of `deeplearning4j-cuda`'s helpers in the reference (SURVEY §2.3):
 a hand-written accelerator kernel behind the same contract as the built-in
-path, picked when available, falling through silently otherwise
+path, picked when available, falling through (with the reason recorded
+in `kernel_dispatch.kernel_verdicts()`) otherwise
 (`ConvolutionLayer.initializeHelper`, `ConvolutionLayer.java:69-79`). Here
 the built-in paths are `ops/attention.py` full/blockwise attention (XLA);
 this module is the Mosaic/Pallas fast path for the no-mask case — and since
@@ -40,13 +41,11 @@ the whole pipeline f64 so eps-scale central differences stay meaningful.
 from __future__ import annotations
 
 import functools
-import logging
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-logger = logging.getLogger("deeplearning4j_tpu")
 
 NEG_INF = -1e30
 
@@ -57,11 +56,14 @@ from deeplearning4j_tpu.ops.kernel_dispatch import (  # noqa: E402
     vmem_limit_bytes as _vmem_limit,
     dot as _dot,
     mxu_dtype as _mxu_dtype,
+    platform_supported as _kernels_dispatch,
     probe_verdict as _probe_verdict,
-    run_probe_out_of_trace as _run_probe_out_of_trace,
+    record_decline as _record_decline,
     stat_dtype as _stat_dtype,
-    tpu_compiler_params as _compiler_params,
+    traced_mesh as _traced_mesh,
 )
+
+FAMILY = "flash_attention"  # this module's row in kernel_verdicts()
 
 
 def _masked_scores(q_ref, k_ref, qi, ki, *, sm_scale, causal, block_q,
@@ -268,7 +270,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 128), sdt),  # running denom l
             pltpu.VMEM((block_q, D), sdt),    # unnormalised output
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
@@ -326,7 +328,7 @@ def _flash_mha_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), sdt)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
@@ -358,7 +360,7 @@ def _flash_mha_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
             pltpu.VMEM((block_k, D), sdt),
             pltpu.VMEM((block_k, D), sdt),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
@@ -389,18 +391,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return _flash_mha(q, k, v, causal, scale, block_q, block_k, interpret)
 
 
-_probe_cache: dict = {}  # (dtype name, block, head_dim) -> probe verdict
-
-
 def _platform_supported() -> bool:
-    import os
-
-    if os.environ.get("DL4J_TPU_NO_PALLAS_ATTENTION"):
-        return False  # forced XLA-blockwise fallback (A/B benches, tests)
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
+    # the switch forces the XLA-blockwise fallback (A/B benches, tests)
+    return _kernels_dispatch("DL4J_TPU_NO_PALLAS_ATTENTION")
 
 
 def _eager_probe(dtype, block: int, head_dim: int) -> bool:
@@ -409,7 +402,7 @@ def _eager_probe(dtype, block: int, head_dim: int) -> bool:
     inside a jit trace, where a Mosaic compile failure would surface at
     the OUTER jit's compile — far from any try/except here. Probing
     eagerly up front turns a platform that can't compile the kernels into
-    a silent XLA fallback instead of a training crash. Probed per
+    a recorded XLA fallback instead of a training crash. Probed per
     (dtype, block) at T=block so the exact tile configuration that will
     run is the one proven to compile."""
     B, T, H = 1, block, 1
@@ -439,10 +432,35 @@ def _probed_block(dtype, Tq: int, Tk: int, D: int) -> Optional[int]:
         if Tq % block or Tk % block:
             continue
         key = (jnp.dtype(dtype).name, block, D)
-        if _probe_verdict(_probe_cache, key, _eager_probe,
-                          (dtype, block, D), "pallas flash-attention"):
+        if _probe_verdict(FAMILY, key, _eager_probe, (dtype, block, D)):
             return block
     return None
+
+
+def flash_attention_over_mesh(q, k, v, mesh, batch_axis, *, causal: bool,
+                              block: int, interpret: bool = False):
+    """`flash_attention` for a step jitted over `mesh`: the kernel is
+    independent per (batch, head), so it runs as the per-device body of a
+    `shard_map` with EVERY mesh axis manual (Mosaic's condition) — batch
+    over `batch_axis`, heads over the remaining axes where the head count
+    divides (the Megatron layout a column-sharded `Wqkv` already
+    produces), replicated over them otherwise. A spec that does not match
+    how the operands arrive costs a reshard, never correctness. Returns
+    None when the batch does not divide its axis."""
+    from jax.sharding import PartitionSpec as P
+
+    B, _, H, _ = q.shape
+    if batch_axis is not None and B % mesh.shape[batch_axis]:
+        return None
+    head_axes = tuple(a for a in mesh.axis_names if a != batch_axis)
+    n_head = 1
+    for a in head_axes:
+        n_head *= mesh.shape[a]
+    spec = P(batch_axis, None, head_axes if H % n_head == 0 else None, None)
+    body = functools.partial(flash_attention, causal=causal, block_q=block,
+                             block_k=block, interpret=interpret)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def flash_attention_or_none(q, k, v, *,
@@ -463,9 +481,19 @@ def flash_attention_or_none(q, k, v, *,
     if block is None:
         return None
     try:
-        return flash_attention(q, k, v, causal=causal, block_q=block,
-                               block_k=block)
+        scope = _traced_mesh()
+        if scope is None:
+            return flash_attention(q, k, v, causal=causal, block_q=block,
+                                   block_k=block)
+        out = flash_attention_over_mesh(q, k, v, *scope, causal=causal,
+                                        block=block)
+        if out is None:
+            _record_decline(
+                FAMILY, (jnp.dtype(q.dtype).name, block, D, "mesh"),
+                f"batch {B} does not divide mesh axis {scope[1]!r} of "
+                f"{dict(scope[0].shape)}")
+        return out
     except Exception as e:  # per-shape staging failure: fall back
-        logger.warning("pallas flash-attention declined for shape %s (%s)",
-                       q.shape, e)
+        _record_decline(FAMILY, (jnp.dtype(q.dtype).name, block, D),
+                        f"staging at {q.shape}: {type(e).__name__}: {e}")
         return None

@@ -34,7 +34,7 @@ Gate math (order [i, f, o, g], matching GravesLSTMParamInitializer):
 
 Dispatch follows the cuDNN-helper pattern (`ConvolutionLayer.java:69-79`,
 as in `ops/pallas_attention.py`): an eager compile probe per shape class,
-silent fall-through to the lax.scan path when the kernel can't serve
+recorded fall-through to the lax.scan path when the kernel can't serve
 (non-sigmoid/tanh activations, non-MXU-friendly sizes, or a platform
 where Mosaic won't compile). Masked (variable-length) sequences run a
 dedicated kernel pair: a masked step passes (h, c) through and emits
@@ -45,8 +45,6 @@ masking they differ.
 from __future__ import annotations
 
 import functools
-import logging
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -56,12 +54,15 @@ from deeplearning4j_tpu.ops.kernel_dispatch import (
     vmem_limit_bytes as _vmem_limit,
     dot as _dot,
     mxu_dtype as _mxu_dtype,
+    platform_supported as _kernels_dispatch,
     probe_verdict as _probe_verdict,
+    record_decline as _record_decline,
     stat_dtype as _stat_dtype,
-    tpu_compiler_params as _compiler_params,
+    traced_mesh as _traced_mesh,
 )
 
-logger = logging.getLogger("deeplearning4j_tpu")
+FAMILY = "fused_lstm"  # this module's row in kernel_verdicts()
+
 
 
 def _lstm_fwd_kernel(xw_ref, rw_ref, peep_ref, h0_ref, c0_ref,
@@ -339,7 +340,7 @@ def _fwd_call(xw, rw, peep, h0, c0, *, bb: int, with_stash: bool,
                                  xw.dtype)],
         scratch_shapes=[pltpu.VMEM((bb, H), sdt),
                         pltpu.VMEM((bb, H), sdt)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
@@ -379,7 +380,7 @@ def _bwd_call(gates, c_stash, dh_out, dcT, rw, peep, c0, *, bb: int,
                    jax.ShapeDtypeStruct((2, B, H), sdt)],
         scratch_shapes=[pltpu.VMEM((bb, H), sdt),
                         pltpu.VMEM((bb, H), sdt)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
@@ -470,7 +471,7 @@ def _fwd_call_masked(xw, rw, peep, h0, c0, mask, *, bb: int,
                                  xw.dtype)],                   # gates
         scratch_shapes=[pltpu.VMEM((bb, H), sdt),
                         pltpu.VMEM((bb, H), sdt)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
@@ -511,7 +512,7 @@ def _bwd_call_masked(gates, c_sel, dh_out, dhT, dcT, mask, rw, peep, c0,
                    jax.ShapeDtypeStruct((2, B, H), sdt)],
         scratch_shapes=[pltpu.VMEM((bb, H), sdt),
                         pltpu.VMEM((bb, H), sdt)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
@@ -568,22 +569,14 @@ def _lstm_core_masked_bwd(interpret, bb, res, cots):
 _lstm_core_masked.defvjp(_lstm_core_masked_fwd, _lstm_core_masked_bwd)
 
 
-_probe_cache: dict = {}  # (dtype name, batch block, H, masked) -> verdict
-
-
 def _platform_ok() -> bool:
-    if os.environ.get("DL4J_TPU_NO_PALLAS_LSTM"):
-        return False
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
+    return _kernels_dispatch("DL4J_TPU_NO_PALLAS_LSTM")
 
 
 def _eager_probe(dtype, bb, H, masked: bool = False) -> bool:
     """Compile + run fwd AND bwd once at the TILE configuration the real
     call will use — (T=2, B=batch block, H) — outside any trace, so a
-    Mosaic failure becomes a silent scan fallback instead of an outer-jit
+    Mosaic failure becomes a recorded scan fallback instead of an outer-jit
     compile crash (same rationale as the flash-attention probe). The block
     shapes are what Mosaic compiles; T and the number of batch blocks only
     set the grid length, so a tiny-T probe proves the real kernel without
@@ -622,8 +615,8 @@ def _probed_batch_block(dtype, B: int, H: int, masked: bool) -> Optional[int]:
         if B % bb:
             continue
         key = (jnp.dtype(dtype).name, bb, H, masked)
-        if _probe_verdict(_probe_cache, key, _eager_probe,
-                          (dtype, bb, H, masked), "pallas fused LSTM"):
+        if _probe_verdict(FAMILY, key, _eager_probe,
+                          (dtype, bb, H, masked)):
             return bb
     return None
 
@@ -649,6 +642,13 @@ def lstm_fused_or_none(x, W, RW, b, peephole, h0, c0, *,
     if not interpret and not _platform_ok():
         return None
     masked = mask is not None
+    if not interpret and _traced_mesh() is not None:
+        # a Mosaic kernel traced into a mesh-wide jit fails at lowering;
+        # the cell is not shard_map-wrapped (as flash attention is) yet
+        _record_decline(FAMILY, (jnp.dtype(x.dtype).name, "mesh", H, masked),
+                        "step is jitted over a device mesh and the fused "
+                        "cell has no shard_map wrap; the scan path runs")
+        return None
     if interpret:
         bb = _batch_block(B)  # no probe: the interpreter always works
     else:
@@ -682,8 +682,8 @@ def lstm_fused_or_none(x, W, RW, b, peephole, h0, c0, *,
             h_tbh, cT = _lstm_core(xw, RW, peep, h0, c0, interpret, bb)
             hT = None
     except Exception as e:  # per-shape staging failure: fall back
-        logger.warning("pallas fused LSTM declined for shape %s (%s)",
-                       x.shape, e)
+        _record_decline(FAMILY, (jnp.dtype(x.dtype).name, bb, H, masked),
+                        f"staging at {x.shape}: {type(e).__name__}: {e}")
         return None
     if reverse:
         h_tbh = h_tbh[::-1]

@@ -54,7 +54,6 @@ dispatch, so tier-1 runs the XLA numerics bit-for-bit unchanged.
 from __future__ import annotations
 
 import functools
-import logging
 from typing import Optional
 
 import jax
@@ -63,13 +62,15 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.ops.kernel_dispatch import (
     dot as _dot,
     mxu_dtype as _mxu_dtype,
+    platform_supported as _kernels_dispatch,
     probe_verdict as _probe_verdict,
+    record_decline as _record_decline,
     stat_dtype as _stat_dtype,
-    tpu_compiler_params as _compiler_params,
     vmem_limit_bytes as _vmem_limit,
 )
 
-logger = logging.getLogger("deeplearning4j_tpu")
+FAMILY = "paged_attention"  # this module's row in kernel_verdicts()
+
 
 NEG_INF = -1e30  # matches ops/attention.py: exp()/where() stay NaN-free
 
@@ -250,7 +251,7 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, C, H, hd), q.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
@@ -276,18 +277,9 @@ def vmem_bytes_estimate(C: int, H: int, Hkv: int, hd: int, page: int,
     return tiles + scratch
 
 
-_probe_cache: dict = {}  # (dtype, C, H, Hkv, hd, page) -> verdict
-
-
 def _platform_supported() -> bool:
-    import os
-
-    if os.environ.get("DL4J_TPU_NO_PALLAS_PAGED_ATTENTION"):
-        return False  # forced gather fallback (A/B benches, tests)
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
+    # the switch forces the gather fallback (A/B benches, tests)
+    return _kernels_dispatch("DL4J_TPU_NO_PALLAS_PAGED_ATTENTION")
 
 
 def _int8_kv_allowed() -> bool:
@@ -351,12 +343,17 @@ def _eager_probe(dtype, C: int, H: int, Hkv: int, hd: int, page: int,
         out = np.asarray(paged_attention(q, k_pool, v_pool, pt, p0))
         kd, vd = paged_gather(k_pool, v_pool, pt)
     ref = np.asarray(jax.vmap(cached_attention_chunk)(q, kd, vd, qpos))
-    ref = ref.reshape(S, C, H, hd)
-    if not np.all(np.isfinite(out.astype(np.float32))):
+    ref = ref.reshape(S, C, H, hd).astype(np.float32)
+    out = out.astype(np.float32)
+    if not np.all(np.isfinite(out)):
         return False
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
-    return bool(np.allclose(out.astype(np.float32),
-                            ref.astype(np.float32), atol=tol, rtol=tol))
+    if not np.allclose(out, ref, atol=tol, rtol=tol):
+        raise ValueError(
+            "kernel compiled but disagrees with the gather reference: "
+            f"max abs err {np.max(np.abs(out - ref)):.3g} at atol=rtol="
+            f"{tol:g}")
+    return True
 
 
 def paged_attention_or_none(q, k_pool, v_pool, page_table, positions,
@@ -379,27 +376,24 @@ def paged_attention_or_none(q, k_pool, v_pool, page_table, positions,
         return None
     if quantized and not _int8_kv_allowed():
         return None
+    key = (jnp.dtype(q.dtype).name, C, H, Hkv, hd, page,
+           "int8" if quantized else "dense")
     kv_itemsize = 1 if quantized else q.dtype.itemsize
     est = vmem_bytes_estimate(C, H, Hkv, hd, page, q.dtype.itemsize,
                               kv_itemsize=kv_itemsize)
     if est > _vmem_limit():
-        logger.warning(
-            "pallas paged-attention declined: shape (C=%d, H=%d, Hkv=%d, "
-            "hd=%d, page=%d) needs ~%d MiB VMEM > %d MiB ceiling; using "
-            "the gather path", C, H, Hkv, hd, page, est >> 20,
-            _vmem_limit() >> 20)
+        _record_decline(FAMILY, key,
+                        f"needs ~{est >> 20} MiB VMEM > "
+                        f"{_vmem_limit() >> 20} MiB ceiling")
         return None
-    key = (jnp.dtype(q.dtype).name, C, H, Hkv, hd, page,
-           "int8" if quantized else "dense")
-    if not _probe_verdict(_probe_cache, key, _eager_probe,
-                          (q.dtype, C, H, Hkv, hd, page, quantized),
-                          "pallas paged-attention"):
+    if not _probe_verdict(FAMILY, key, _eager_probe,
+                          (q.dtype, C, H, Hkv, hd, page, quantized)):
         return None
     try:
         return paged_attention(q, k_pool, v_pool, page_table, positions,
                                k_scale=k_scale, v_scale=v_scale,
                                active=active)
     except Exception as e:  # per-shape staging failure: fall back
-        logger.warning("pallas paged-attention declined for shape %s "
-                       "(%s)", q.shape, e)
+        _record_decline(FAMILY, key,
+                        f"staging at {q.shape}: {type(e).__name__}: {e}")
         return None

@@ -18,6 +18,7 @@ parity test — the analogue of the reference's
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 from typing import Callable, Dict, Optional, Union
 
@@ -146,7 +147,7 @@ class ParallelWrapper:
 
         self._jit_step_tbptt = None
         self._tbptt_lstate_sh = None
-        step = self._with_expert_scope(self._wrap_step(net.train_step_fn()))
+        step = self._with_mesh_scopes(self._wrap_step(net.train_step_fn()))
         self._jit_step = jax.jit(
             step,
             in_shardings=(self._param_sh, self._upd_sh, self._lstate_sh,
@@ -187,19 +188,25 @@ class ParallelWrapper:
     def _wrap_step(self, step):
         return step
 
-    def _with_expert_scope(self, step):
-        """Trace the step inside expert_mesh_scope when the net has
-        expert-parallel MoE layers (the scope is consulted at trace time;
-        compiled steps carry no runtime cost)."""
-        if not self._expert_layers:
-            return step
+    def _with_mesh_scopes(self, step):
+        """Trace the step inside the scopes that tell mesh-aware code
+        where it is running (consulted at trace time; compiled steps
+        carry no runtime cost): `kernel_dispatch.mesh_scope` always — a
+        Pallas kernel cannot be partitioned automatically and must wrap
+        itself over this mesh or decline — and `expert_mesh_scope` when
+        the net has expert-parallel MoE layers."""
+        from deeplearning4j_tpu.ops.kernel_dispatch import mesh_scope
         from deeplearning4j_tpu.parallel.experts import expert_mesh_scope
 
         data_axis = (self.data_axis if self.data_axis in self.mesh.shape
                      else None)
 
         def scoped(*args):
-            with expert_mesh_scope(self.mesh, data_axis):
+            with contextlib.ExitStack() as scopes:
+                scopes.enter_context(mesh_scope(self.mesh, data_axis))
+                if self._expert_layers:
+                    scopes.enter_context(
+                        expert_mesh_scope(self.mesh, data_axis))
                 return step(*args)
         return scoped
 
@@ -322,7 +329,7 @@ class ParallelWrapper:
             for key in saved:
                 lstate_sh[key] = {"h": self._batch_sh, "c": self._batch_sh}
             self._tbptt_lstate_sh = lstate_sh
-            step = self._with_expert_scope(
+            step = self._with_mesh_scopes(
                 self._wrap_step(net.train_step_fn()))
             self._jit_step_tbptt = jax.jit(
                 step,

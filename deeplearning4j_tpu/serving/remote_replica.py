@@ -761,6 +761,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         help="chaos drill: SIGKILL self on reload_model")
     args = parser.parse_args(argv)
 
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     serving = json.loads(args.serving) if args.serving else {}
     chaos = {"die_on_reload": True} if args.chaos_die_on_reload else None
     entry = ReplicaEntryPoint(serving=serving, scratch_dir=args.scratch,
@@ -803,7 +806,11 @@ class ReplicaSupervisor:
 
     `kill(i)` is the chaos drill seam (`kill -9` by default);
     `chaos_die_on_reload` arms specific slots to SIGKILL themselves
-    mid-`reload_model`."""
+    mid-`reload_model`.
+
+    Children run on the CPU (`child_platform`) unless `env` names
+    another ``JAX_PLATFORMS``: an accelerator belongs to one process,
+    and the process building a supervisor already holds it."""
 
     def __init__(self, model_path, n_replicas: int, *,
                  scratch_dir, serving: Optional[dict] = None,
@@ -832,8 +839,16 @@ class ReplicaSupervisor:
         self.restart_window = restart_window
         self.poll_interval = poll_interval
         self.spawn_timeout = spawn_timeout
+        # every path that builds a supervisor does so from a process
+        # holding a live net (so the chip, where there is one); a child
+        # that inherited the parent's platform would try to open the
+        # same chip and fail or hang — so each child's platform is
+        # STATED, never inherited (a launcher that stays off JAX can
+        # still hand a chip to exactly one child through `env`)
         self._env = dict(os.environ)
+        self._env["JAX_PLATFORMS"] = "cpu"
         self._env.update(env or {})
+        self.child_platform = self._env["JAX_PLATFORMS"]
         self._chaos = frozenset(chaos_die_on_reload)
         from deeplearning4j_tpu.parallel.multiprocess import free_port
         self.ports = [free_port() for _ in range(n_replicas)]

@@ -68,24 +68,6 @@ _ROW_KEYS = ("Wo", "W2")
 _MESH_CACHE: dict = {}
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """Version-portable `shard_map`: the top-level `jax.shard_map`
-    spelling with `check_vma` (the repo's training-side idiom —
-    parallel/sequence.py) where available, else the older
-    `jax.experimental.shard_map` with `check_rep`. Replication checking
-    is off either way: every non-pool output is produced by identical
-    deterministic per-device math after each psum."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=False)
-
-
 def tp_mesh(degree: int):
     """The serving `tp` mesh over the first `degree` local devices,
     cached per process. Raises ValueError (typed, at construction —
@@ -247,10 +229,13 @@ class TPPlan:
         deterministic math on replicated inputs after each psum, so
         replication checking off (the repo's established shard_map
         idiom — parallel/sequence.py) is sound here."""
-        return _shard_map(
+        import jax
+
+        return jax.shard_map(
             fn, mesh=self.mesh,
             in_specs=self.in_specs(n_in, params_at, caches_at),
-            out_specs=self.out_specs(n_out, caches_out_at))
+            out_specs=self.out_specs(n_out, caches_out_at),
+            check_vma=False)
 
     # -- byte accounting ---------------------------------------------------
     def weight_bytes_per_chip(self, params) -> int:

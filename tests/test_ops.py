@@ -186,22 +186,58 @@ def test_vmem_limit_per_generation_table():
     assert vmem_limit_for_kind("TPU v5 lite") == 112 * 1024 * 1024
 
 
-def test_vmem_limit_prefix_matching_and_default():
-    from deeplearning4j_tpu.ops.kernel_dispatch import (
-        _DEFAULT_VMEM_PER_CORE,
-        VMEM_LIMIT_BYTES,
-        vmem_limit_for_kind,
-    )
+def test_vmem_limit_prefix_matching_and_unknown_kind():
+    import pytest
+
+    from deeplearning4j_tpu.ops.kernel_dispatch import vmem_limit_for_kind
 
     # longest prefix wins: "TPU v5 lite" must not resolve through
     # "TPU v5"'s row
     assert vmem_limit_for_kind("TPU v5 lite chip") == \
         vmem_limit_for_kind("TPU v5 lite")
-    # unknown kinds (future generations, CPU interpret mode) keep the
-    # v4/v5-class default so big-slab kernels stay enabled
-    assert vmem_limit_for_kind("TPU v9 hypothetical") == \
-        _DEFAULT_VMEM_PER_CORE * 7 // 8
-    assert vmem_limit_for_kind("") == VMEM_LIMIT_BYTES
+    # the CPU backend (interpret mode) keeps the v4/v5-class ceiling ...
+    assert vmem_limit_for_kind("cpu") == 112 * 1024 * 1024
+    # ... but an accelerator the table does not know is an error, not a
+    # guessed 128 MiB
+    for kind in ("TPU v9 hypothetical", "", "NVIDIA H100"):
+        with pytest.raises(ValueError, match="unknown device_kind"):
+            vmem_limit_for_kind(kind)
+
+
+def test_kernel_verdicts_report_a_raising_probe_with_its_message(monkeypatch):
+    """The dispatch verdict is a public fact: a probe that raises is
+    reported False WITH the compiler's message, a passing one True, each
+    probed once, and a staging failure overrides an earlier pass."""
+    from deeplearning4j_tpu.ops import kernel_dispatch as kd
+
+    monkeypatch.setattr(kd, "_verdicts", {})
+    assert kd.kernel_verdicts() == {}
+    calls = []
+
+    def mosaic_says_no(shape):
+        calls.append(shape)
+        raise RuntimeError("Mosaic failed to compile TPU kernel: "
+                           "unsupported shape cast")
+
+    assert kd.probe_verdict("fam", ("bf16", 1), mosaic_says_no,
+                            ((1, 128),)) is False
+    assert kd.probe_verdict("fam", ("bf16", 1), mosaic_says_no,
+                            ((1, 128),)) is False
+    assert calls == [(1, 128)]  # cached: the probe ran once
+    assert kd.probe_verdict("fam", ("bf16", 8), lambda: True, ()) is True
+    table = kd.kernel_verdicts()
+    assert table["fam"][("bf16", 8)] == kd.KernelVerdict(True, "")
+    bad = table["fam"][("bf16", 1)]
+    assert bad.ok is False
+    assert "RuntimeError" in bad.message
+    assert "unsupported shape cast" in bad.message
+    # the accessor hands out a copy
+    table["fam"].clear()
+    assert len(kd.kernel_verdicts()["fam"]) == 2
+    kd.record_decline("fam", ("bf16", 8), "staging at (2, 8): boom")
+    assert kd.kernel_verdicts()["fam"][("bf16", 8)] == \
+        kd.KernelVerdict(False, "staging at (2, 8): boom")
+    assert kd.probe_verdict("fam", ("bf16", 8), lambda: True, ()) is False
 
 
 def test_vmem_limit_bytes_cached_and_positive():
@@ -212,3 +248,95 @@ def test_vmem_limit_bytes_cached_and_positive():
     assert v1 > 0
     assert kd.vmem_limit_bytes() is v1 or kd.vmem_limit_bytes() == v1
     assert kd._vmem_limit_cache  # verdict cached after first detection
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels under a device mesh: Mosaic cannot auto-partition, so a
+# mesh-jitted step announces itself and the kernel wraps in shard_map
+
+
+def test_flash_over_mesh_matches_unwrapped_kernel():
+    """The all-axes-manual shard_map wrap is a pure re-layout: same
+    values and same gradients as the unwrapped kernel (interpret mode),
+    with heads over the non-batch axis when they divide and replicated
+    over it when they do not."""
+    import functools
+
+    import jax
+
+    from deeplearning4j_tpu.ops.pallas_attention import (
+        flash_attention,
+        flash_attention_over_mesh,
+    )
+    from deeplearning4j_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh({"data": 2, "model": 2}, devices=jax.devices()[:4])
+    rng = np.random.default_rng(3)
+    for H in (2, 3):  # 3 heads do not divide the model axis
+        q, k, v = (jnp.asarray(rng.standard_normal((2, 128, H, 128)),
+                               jnp.float32) for _ in range(3))
+
+        def loss(fn, q, k, v):
+            return jnp.sum(fn(q, k, v) ** 2)
+
+        plain = functools.partial(flash_attention, causal=True,
+                                  block_q=128, block_k=128, interpret=True)
+        meshed = functools.partial(flash_attention_over_mesh, mesh=mesh,
+                                   batch_axis="data", causal=True,
+                                   block=128, interpret=True)
+        np.testing.assert_allclose(np.asarray(meshed(q, k, v)),
+                                   np.asarray(plain(q, k, v)),
+                                   rtol=1e-5, atol=1e-5)
+        g_plain = jax.grad(functools.partial(loss, plain),
+                           argnums=(0, 1, 2))(q, k, v)
+        g_mesh = jax.grad(functools.partial(loss, meshed),
+                          argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g_mesh, g_plain):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-4)
+    # a batch that does not divide its axis is a decline, not a crash
+    q3 = jnp.zeros((3, 128, 2, 128), jnp.float32)
+    assert flash_attention_over_mesh(q3, q3, q3, mesh, "data", causal=True,
+                                     block=128, interpret=True) is None
+
+
+def test_parallel_wrapper_traces_its_step_inside_mesh_scope():
+    import jax
+
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+    from deeplearning4j_tpu.nn.conf import (
+        DenseLayer,
+        InputType,
+        NeuralNetConfiguration,
+        OutputLayer,
+    )
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+    from deeplearning4j_tpu.ops import kernel_dispatch as kd
+    from deeplearning4j_tpu.parallel.mesh import make_mesh
+    from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
+
+    net = MultiLayerNetwork(
+        NeuralNetConfiguration.Builder().seed(0).list()
+        .layer(DenseLayer(n_out=8))
+        .layer(OutputLayer(n_out=2))
+        .set_input_type(InputType.feed_forward(4)).build())
+    net.init()
+    seen = []
+    make_step = net.train_step_fn
+
+    def spying_step_fn():
+        step = make_step()
+
+        def spy(*args):
+            seen.append(kd.traced_mesh())
+            return step(*args)
+        return spy
+
+    net.train_step_fn = spying_step_fn
+    mesh = make_mesh({"data": 4}, devices=jax.devices()[:4])
+    assert kd.traced_mesh() is None
+    ParallelWrapper(net, mesh=mesh).fit(
+        DataSet(np.ones((8, 4), np.float32),
+                np.eye(2, dtype=np.float32)[[0, 1] * 4]))
+    assert seen and all(s == (mesh, "data") for s in seen)
+    assert kd.traced_mesh() is None  # the scope closed with the trace

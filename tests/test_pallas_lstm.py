@@ -224,8 +224,10 @@ def test_probe_falls_back_to_smaller_batch_block(monkeypatch):
         calls.append(bb)
         return bb <= 64  # big tiles "overflow VMEM"
 
+    from deeplearning4j_tpu.ops import kernel_dispatch as kd
+
     monkeypatch.setattr(mod, "_eager_probe", fake_probe)
-    monkeypatch.setattr(mod, "_probe_cache", {})
+    monkeypatch.setattr(kd, "_verdicts", {})
     bb = mod._probed_batch_block(jnp.float32, 512, 128, False)
     assert bb == 64
     assert calls == [512, 256, 128, 64]
@@ -238,5 +240,10 @@ def test_probe_falls_back_to_smaller_batch_block(monkeypatch):
     # every dividing candidate failing -> decline
     monkeypatch.setattr(mod, "_eager_probe",
                         lambda dtype, bb, H, masked=False: False)
-    monkeypatch.setattr(mod, "_probe_cache", {})
+    monkeypatch.setattr(kd, "_verdicts", {})
     assert mod._probed_batch_block(jnp.float32, 512, 128, False) is None
+    # ... and every declined candidate is on the public record
+    declined = kd.kernel_verdicts()[mod.FAMILY]
+    assert set(declined) == {("float32", bb, 128, False)
+                             for bb in (512, 256, 128, 64, 32, 16, 8)}
+    assert not any(v.ok for v in declined.values())
