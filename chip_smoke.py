@@ -24,7 +24,12 @@ It refuses to run unless JAX's first device is a TPU whose `device_kind`
 the repo's tables know; a phase that fails raises, so the exit code is 0
 only if every phase passed. One process holds the chip throughout: each
 phase frees its state before the next (16 GB of HBM does not hold train,
-serve and lstm together). The last line of stdout is one JSON object.
+serve and lstm together). The second-to-last line of stdout is the JSON
+summary (versions, per-phase facts, kernel verdicts, compile seconds,
+ending `"claim": null`); the last line is the verdict the driver reads,
+`{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`
+with exactly those keys. A failed phase prints the same line with
+`"ok": false` and re-raises.
 
 `python3 chip_smoke.py train lstm` runs only the named phases.
 """
@@ -528,21 +533,25 @@ def main(argv=None) -> int:
         phases[name] = result
         print(f"{name}: {json.dumps(result)}", flush=True)
 
-    if "train" in names:
-        run("train", phase_train, GPT, TRAIN, kernels=True)
-    if "serve" in names:
-        run("serve", phase_serve, GPT, SERVE, kernels=True)
-    if "lstm" in names:
-        run("lstm", phase_lstm, LSTM, kernels=True)
-    if "multichip" in names:
-        if device["count"] >= 4:
-            run("multichip", phase_multichip, GPT, TRAIN, SERVE,
-                phases["train"]["losses"][0])
-        else:
-            print(f"multichip: not run ({device['count']} device)",
-                  flush=True)
-            phases["multichip"] = {
-                "ok": None, "not_run": f"{device['count']} device"}
+    try:
+        if "train" in names:
+            run("train", phase_train, GPT, TRAIN, kernels=True)
+        if "serve" in names:
+            run("serve", phase_serve, GPT, SERVE, kernels=True)
+        if "lstm" in names:
+            run("lstm", phase_lstm, LSTM, kernels=True)
+        if "multichip" in names:
+            if device["count"] >= 4:
+                run("multichip", phase_multichip, GPT, TRAIN, SERVE,
+                    phases["train"]["losses"][0])
+            else:
+                print(f"multichip: not run ({device['count']} device)",
+                      flush=True)
+                phases["multichip"] = {
+                    "ok": None, "not_run": f"{device['count']} device"}
+    except BaseException:
+        print(json.dumps({"ok": False, "device": device}), flush=True)
+        raise
 
     print(json.dumps({
         "ok": True, "device": device, "versions": _versions(),
@@ -551,6 +560,8 @@ def main(argv=None) -> int:
         "native": {"gxx": shutil.which("g++") is not None,
                    "loaded": native_available()},
         "claim": None}), flush=True)
+    # the driver's contract: exactly these keys, on the last line
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

@@ -88,6 +88,44 @@ def test_main_refuses_a_cpu_platform(capsys):
     assert "no accelerator" in captured.err
 
 
+class _FakeTpu:
+    platform, device_kind, id = "tpu", "TPU v5 lite", 0
+
+    def memory_stats(self):
+        return {"bytes_in_use": 0}
+
+
+def test_last_line_is_the_drivers_verdict(monkeypatch, capsys):
+    """The driver reads the LAST stdout line and wants exactly
+    {"ok", "device": {"platform", "kind", "count"}}; the rich summary
+    (ending "claim": null) is the line before it."""
+    import jax
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeTpu()])
+    monkeypatch.setattr(chip_smoke, "phase_lstm",
+                        lambda shape, kernels: {"losses": [1.0]})
+    saved = jax.config.jax_compilation_cache_dir
+    try:
+        assert chip_smoke.main(["lstm"]) == 0
+        summary, verdict = capsys.readouterr().out.splitlines()[-2:]
+        assert json.loads(verdict) == {"ok": True, "device": {
+            "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+        assert summary.endswith('"claim": null}')
+        assert json.loads(summary)["phases"]["lstm"]["ok"] is True
+
+        def fails(shape, kernels):
+            raise chip_smoke.SmokeFailure("wrong")
+
+        monkeypatch.setattr(chip_smoke, "phase_lstm", fails)
+        with pytest.raises(chip_smoke.SmokeFailure):
+            chip_smoke.main(["lstm"])
+        verdict = capsys.readouterr().out.splitlines()[-1]
+        assert json.loads(verdict)["ok"] is False
+        assert set(json.loads(verdict)) == {"ok", "device"}
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved)
+
+
 def test_script_fails_without_a_chip_and_prints_no_result():
     proc = subprocess.run(
         [sys.executable, str(REPO / "chip_smoke.py")], cwd=str(REPO),
