@@ -1396,11 +1396,10 @@ def bench_serve_generate():
     paged p50/p99 arrival→completion latency, `slot_occupancy_pct`,
     `pages_in_use_peak` + `prefill_chunks` (the new paging/chunking
     accounting), the r5 configuration's goodput + latency on the same
-    traffic, their ratio `paged_vs_r5_goodput`, a GQA variant line
-    (`gpt_configuration(n_kv_heads=...)`) kept OFF the headline, and
-    `tracing_overhead_pct` — the goodput cost of serving observability
-    (on by default in the headline) vs the same traffic under the
-    `DL4J_TPU_NO_TRACING` kill switch (target < 2%)."""
+    traffic, their ratio `paged_vs_r5_goodput`, and a GQA variant line
+    (`gpt_configuration(n_kv_heads=...)`) kept OFF the headline. What
+    serving observability costs is measured at real widths on the chip
+    (`PERF.md`, PR 25), no longer here."""
     import jax.numpy as jnp
 
     from deeplearning4j_tpu.models.transformer import gpt_configuration
@@ -1559,30 +1558,6 @@ def bench_serve_generate():
     bench_serve_generate.paged_gather_device_ms_per_token = gather_dms
     bench_serve_generate.paged_kernel_vs_gather = round(
         gather_dms / bench_serve_generate.device_ms_per_token, 3)
-
-    # tracing overhead A/B (ISSUE 12): the headline above ran with
-    # serving observability ON (its default) — every request carried a
-    # span timeline and the flight recorder logged scheduler events.
-    # Re-run the IDENTICAL paged config and traffic under the
-    # DL4J_TPU_NO_TRACING kill switch (fresh engine: traces become
-    # NULL_TRACE, recorder writes drop) and price the delta:
-    # `tracing_overhead_pct` = how much goodput tracing costs (positive
-    # = tracing is that much slower; acceptance target < 2%).
-    prior = os.environ.get("DL4J_TPU_NO_TRACING")
-    os.environ["DL4J_TPU_NO_TRACING"] = "1"
-    try:
-        untraced_goodput = engine_goodput(
-            net, shp["r5_n_slots"] * shp["slots_multiplier"],
-            pool_pages=kv_budget_pages, prompt_buckets=(short_t0,))[0]
-    finally:
-        if prior is None:
-            os.environ.pop("DL4J_TPU_NO_TRACING", None)
-        else:
-            os.environ["DL4J_TPU_NO_TRACING"] = prior
-    bench_serve_generate.untraced_goodput_tokens_per_sec = round(
-        untraced_goodput, 1)
-    bench_serve_generate.tracing_overhead_pct = round(
-        (untraced_goodput / goodput - 1.0) * 100.0, 2)
 
     # GQA variant line (not the headline: baseline comparability)
     gqa_net = build_net(n_kv_heads=shp["gqa_kv_heads"])
@@ -2898,9 +2873,6 @@ def main() -> None:
                 ("dropout_rng_overhead_pct", "dropout_rng_overhead_pct"),
                 ("attention_block512_overhead_pct",
                  "attention_block512_overhead_pct"),
-                ("tracing_overhead_pct", "tracing_overhead_pct"),
-                ("untraced_goodput_tokens_per_sec",
-                 "untraced_goodput_tokens_per_sec"),
                 ("paged_kernel_device_ms_per_token",
                  "paged_kernel_device_ms_per_token"),
                 ("paged_gather_device_ms_per_token",

@@ -140,11 +140,14 @@ class GPTPlan:
         stay in the param dtype."""
         if self.cdt == self.dtype:
             return params
+        import jax
+
         from deeplearning4j_tpu.nn.precision import tree_cast
 
-        return [tree_cast(p, self.cdt)
-                if i in (self.emb_i, *self.block_is) else p
-                for i, p in enumerate(params)]
+        with jax.named_scope("cast_params"):
+            return [tree_cast(p, self.cdt)
+                    if i in (self.emb_i, *self.block_is) else p
+                    for i, p in enumerate(params)]
 
     def final_logits(self, bp, params, x):
         """Trailing LN(s) in the compute dtype (`bp`), then the output
@@ -152,14 +155,17 @@ class GPTPlan:
         training step draws (`MultiLayerNetwork._loss_pure` casts hidden
         layers, including trailing LNs, and restores the param dtype only
         for the loss head)."""
+        import jax
+
         from deeplearning4j_tpu.nn.conf.layers import layer_norm
 
-        for i in self.ln_is:
-            if i > max(self.block_is, default=-1):
-                x = layer_norm(x, bp[i]["gamma"], bp[i]["beta"],
-                               self.layers[i].eps)
-        x = x.astype(self.dtype)
-        return x @ params[self.out_i]["W"] + params[self.out_i]["b"]
+        with jax.named_scope("head"):
+            for i in self.ln_is:
+                if i > max(self.block_is, default=-1):
+                    x = layer_norm(x, bp[i]["gamma"], bp[i]["beta"],
+                                   self.layers[i].eps)
+            x = x.astype(self.dtype)
+            return x @ params[self.out_i]["W"] + params[self.out_i]["b"]
 
 
 def _block_heads(layer, p, x, positions=None, shard=None):
@@ -177,6 +183,8 @@ def _block_heads(layer, p, x, positions=None, shard=None):
     rotates per head, so local slices rotate identically to their
     global positions. `shard=None` is byte-identical to the
     single-device path (qw == d)."""
+    import jax
+
     from deeplearning4j_tpu.nn.conf.layers import layer_norm
 
     d = x.shape[-1]
@@ -185,17 +193,19 @@ def _block_heads(layer, p, x, positions=None, shard=None):
     Hkv = layer._kv_heads // shard if shard else layer._kv_heads
     qw = H * hd
     kvw = Hkv * hd
-    h1 = layer_norm(x, p["ln1_g"], p["ln1_b"], layer.eps)
-    qkv = h1 @ p["Wqkv"] + p["bqkv"]
-    q = qkv[..., :qw].reshape(*x.shape[:-1], H, hd)
-    k = qkv[..., qw:qw + kvw].reshape(*x.shape[:-1], Hkv, hd)
-    v = qkv[..., qw + kvw:].reshape(*x.shape[:-1], Hkv, hd)
-    if layer.rope:
-        from deeplearning4j_tpu.ops.rope import rope_angles, rope_rotate
+    with jax.named_scope("ln1"):
+        h1 = layer_norm(x, p["ln1_g"], p["ln1_b"], layer.eps)
+    with jax.named_scope("attn.qkv"):
+        qkv = h1 @ p["Wqkv"] + p["bqkv"]
+        q = qkv[..., :qw].reshape(*x.shape[:-1], H, hd)
+        k = qkv[..., qw:qw + kvw].reshape(*x.shape[:-1], Hkv, hd)
+        v = qkv[..., qw + kvw:].reshape(*x.shape[:-1], Hkv, hd)
+        if layer.rope:
+            from deeplearning4j_tpu.ops.rope import rope_angles, rope_rotate
 
-        cos, sin = rope_angles(positions, hd, layer.rope_base)
-        q = rope_rotate(q, cos, sin)
-        k = rope_rotate(k, cos, sin)
+            cos, sin = rope_angles(positions, hd, layer.rope_base)
+            q = rope_rotate(q, cos, sin)
+            k = rope_rotate(k, cos, sin)
     return q, k, v
 
 
@@ -217,7 +227,10 @@ def _block_out_proj(p, att, axis_name=None):
     (..., H·hd). Under tensor parallelism `att` carries the local
     H/tp head slice and `Wo` the matching row slice; the replicated
     bias is added AFTER the all-reduce so it lands exactly once."""
-    return _psum_partial(att @ p["Wo"], axis_name) + p["bo"]
+    import jax
+
+    with jax.named_scope("attn.out"):
+        return _psum_partial(att @ p["Wo"], axis_name) + p["bo"]
 
 
 def _block_ffn(layer, p, x, axis_name=None):
@@ -232,7 +245,8 @@ def _block_ffn(layer, p, x, axis_name=None):
 
     from deeplearning4j_tpu.nn.conf.layers import layer_norm
 
-    h2 = layer_norm(x, p["ln2_g"], p["ln2_b"], layer.eps)
+    with jax.named_scope("ln2"):
+        h2 = layer_norm(x, p["ln2_g"], p["ln2_b"], layer.eps)
     if layer.moe_experts > 0:
         from deeplearning4j_tpu.parallel.experts import switch_ffn
 
@@ -244,12 +258,19 @@ def _block_ffn(layer, p, x, axis_name=None):
                          train=False,
                          passthrough="zero").reshape(*lead, -1)
     elif layer.ffn_activation == "swiglu":
-        ffn = _psum_partial((jax.nn.silu(h2 @ p["W1"])
-                             * (h2 @ p["W3"])) @ p["W2"],
-                            axis_name) + p["b2"]
+        with jax.named_scope("mlp.up"):
+            gate, up = h2 @ p["W1"], h2 @ p["W3"]
+        with jax.named_scope("mlp.act"):
+            act = jax.nn.silu(gate) * up
+        with jax.named_scope("mlp.down"):
+            ffn = _psum_partial(act @ p["W2"], axis_name) + p["b2"]
     else:
-        ffn = _psum_partial(jax.nn.gelu(h2 @ p["W1"] + p["b1"])
-                            @ p["W2"], axis_name) + p["b2"]
+        with jax.named_scope("mlp.up"):
+            up = h2 @ p["W1"] + p["b1"]
+        with jax.named_scope("mlp.act"):
+            act = jax.nn.gelu(up)
+        with jax.named_scope("mlp.down"):
+            ffn = _psum_partial(act @ p["W2"], axis_name) + p["b2"]
     return x + ffn
 
 
@@ -284,16 +305,18 @@ def _prefill_block_attention(layer, q, k, v):
     """Causal prefill attention for one block: GQA keys/values widened to
     the full head count (training-path semantics; the grouped-decode win
     only applies to the cached step)."""
+    import jax
     import jax.numpy as jnp
 
     from deeplearning4j_tpu.ops.attention import full_attention
 
-    kf, vf = k, v
-    if layer._kv_heads != layer.n_heads:
-        g = layer.n_heads // layer._kv_heads
-        kf = jnp.repeat(k, g, axis=2)
-        vf = jnp.repeat(v, g, axis=2)
-    return full_attention(q, kf, vf, causal=True)
+    with jax.named_scope("attn.core"):
+        kf, vf = k, v
+        if layer._kv_heads != layer.n_heads:
+            g = layer.n_heads // layer._kv_heads
+            kf = jnp.repeat(k, g, axis=2)
+            vf = jnp.repeat(v, g, axis=2)
+        return full_attention(q, kf, vf, causal=True)
 
 
 def _prefill_chunk_block_attention(layer, q, k_cache, v_cache, q_pos):

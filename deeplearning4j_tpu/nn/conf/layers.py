@@ -1395,31 +1395,41 @@ class TransformerBlock(FeedForwardLayer):
         H = self.n_heads
         Hkv = self._kv_heads
         hd = d // H
-        h1 = layer_norm(x, params["ln1_g"], params["ln1_b"], self.eps)
-        qkv = h1 @ params["Wqkv"] + params["bqkv"]
-        kvw = Hkv * hd
-        q = qkv[..., :d].reshape(B, T, H, hd)
-        k = qkv[..., d:d + kvw].reshape(B, T, Hkv, hd)
-        v = qkv[..., d + kvw:].reshape(B, T, Hkv, hd)
-        if self.rope:
-            from deeplearning4j_tpu.ops.rope import rope_angles, rope_rotate
+        # the scopes name the block's parts in a profiler capture, under
+        # the same names as the serving path (models/transformer.py)
+        with jax.named_scope("ln1"):
+            h1 = layer_norm(x, params["ln1_g"], params["ln1_b"], self.eps)
+        with jax.named_scope("attn.qkv"):
+            qkv = h1 @ params["Wqkv"] + params["bqkv"]
+            kvw = Hkv * hd
+            q = qkv[..., :d].reshape(B, T, H, hd)
+            k = qkv[..., d:d + kvw].reshape(B, T, Hkv, hd)
+            v = qkv[..., d + kvw:].reshape(B, T, Hkv, hd)
+            if self.rope:
+                from deeplearning4j_tpu.ops.rope import (
+                    rope_angles,
+                    rope_rotate,
+                )
 
-            cos, sin = rope_angles(jnp.arange(T), hd, self.rope_base)
-            q = rope_rotate(q, cos, sin)
-            k = rope_rotate(k, cos, sin)
+                cos, sin = rope_angles(jnp.arange(T), hd, self.rope_base)
+                q = rope_rotate(q, cos, sin)
+                k = rope_rotate(k, cos, sin)
         # GQA: query head j attends through KV head j // (H // Hkv).
         # K/V go to the dispatch UN-repeated (Hkv heads): the
         # full-attention path groups them as a broadcast einsum —
         # bit-identical per-head dots without copying each KV element
         # H/Hkv× through HBM — and the kernel paths (flash/blockwise/
         # ring) widen inside multi_head_attention
-        att = multi_head_attention(q, k, v, causal=self.causal,
-                                   key_mask=mask,
-                                   block_size=self.block_size)
-        att = att.reshape(B, T, d) @ params["Wo"] + params["bo"]
-        att = self._maybe_dropout(att, train, rng)
-        x = x + att
-        h2 = layer_norm(x, params["ln2_g"], params["ln2_b"], self.eps)
+        with jax.named_scope("attn.core"):
+            att = multi_head_attention(q, k, v, causal=self.causal,
+                                       key_mask=mask,
+                                       block_size=self.block_size)
+        with jax.named_scope("attn.out"):
+            att = att.reshape(B, T, d) @ params["Wo"] + params["bo"]
+            att = self._maybe_dropout(att, train, rng)
+            x = x + att
+        with jax.named_scope("ln2"):
+            h2 = layer_norm(x, params["ln2_g"], params["ln2_b"], self.eps)
         if self.moe_experts > 0:
             from deeplearning4j_tpu.parallel.experts import switch_ffn
 
@@ -1435,11 +1445,19 @@ class TransformerBlock(FeedForwardLayer):
                              train=train,
                              passthrough="zero").reshape(B, T, d)
         elif self.ffn_activation == "swiglu":
-            ffn = (jax.nn.silu(h2 @ params["W1"])
-                   * (h2 @ params["W3"])) @ params["W2"] + params["b2"]
+            with jax.named_scope("mlp.up"):
+                gate, up = h2 @ params["W1"], h2 @ params["W3"]
+            with jax.named_scope("mlp.act"):
+                act = jax.nn.silu(gate) * up
+            with jax.named_scope("mlp.down"):
+                ffn = act @ params["W2"] + params["b2"]
         else:
-            ffn = jax.nn.gelu(h2 @ params["W1"] + params["b1"]) @ params["W2"] \
-                + params["b2"]
+            with jax.named_scope("mlp.up"):
+                up = h2 @ params["W1"] + params["b1"]
+            with jax.named_scope("mlp.act"):
+                act = jax.nn.gelu(up)
+            with jax.named_scope("mlp.down"):
+                ffn = act @ params["W2"] + params["b2"]
         ffn = self._maybe_dropout(
             ffn, train, None if rng is None else jax.random.fold_in(rng, 1))
         return x + ffn

@@ -238,8 +238,11 @@ class MultiLayerNetwork:
                 x = self.conf.preprocessors[i].preprocess(x, rng=lrng,
                                                           train=train)
             mask = fmask if x.ndim == 3 else None
-            x, new_state[i] = layer.forward(params[i], lstate[i], x,
-                                            train=train, rng=lrng, mask=mask)
+            # names the layer's operations in a profiler capture
+            with jax.named_scope(f"L{i}.{type(layer).__name__}"):
+                x, new_state[i] = layer.forward(params[i], lstate[i], x,
+                                                train=train, rng=lrng,
+                                                mask=mask)
         return x, new_state
 
     def _loss_pure(self, params: Params, lstate: LState, features, labels,
@@ -253,7 +256,8 @@ class MultiLayerNetwork:
             # loss head, L1/L2, and carried state stay in the param dtype
             from deeplearning4j_tpu.nn.precision import tree_cast
 
-            params = tree_cast(params, self.compute_dtype)
+            with jax.named_scope("cast_params"):
+                params = tree_cast(params, self.compute_dtype)
             if not getattr(self.layers[0], "integer_input", False):
                 # token-id inputs must NOT be cast (bf16 corrupts ids > 256);
                 # in a sequential net raw features only ever feed layer 0,
@@ -278,8 +282,9 @@ class MultiLayerNetwork:
             x = self.conf.preprocessors[len(self.layers) - 1].preprocess(
                 x, rng=out_rng, train=train)
         mask = lmask if lmask is not None else (fmask if x.ndim == 3 else None)
-        loss = out_layer.loss_score(params_in[-1], x, labels, train=train,
-                                    rng=out_rng, mask=mask)
+        with jax.named_scope("loss"):
+            loss = out_layer.loss_score(params_in[-1], x, labels,
+                                        train=train, rng=out_rng, mask=mask)
         loss = loss + self._reg_score(params_in)
         for term in aux_terms:  # mid-network losses (MoE load balancing)
             loss = loss + term
@@ -331,8 +336,9 @@ class MultiLayerNetwork:
             new_params = []
             new_upd = []
             for i, layer in enumerate(self.layers):
-                p_new, u_new = apply_layer_update(layer, upd[i], params[i],
-                                                  grads[i], iteration)
+                with jax.named_scope(f"update.L{i}"):
+                    p_new, u_new = apply_layer_update(
+                        layer, upd[i], params[i], grads[i], iteration)
                 new_params.append(p_new)
                 new_upd.append(u_new)
             return new_params, new_upd, new_lstate, loss, grads

@@ -143,7 +143,7 @@ class _GenRequest:
                  "prefill_pos", "hit_len", "n_shared", "nodes", "digests",
                  "trace", "tenant", "priority", "resumed_at",
                  "preempted", "handoff", "import_state", "prefix_import",
-                 "sink", "logprobs", "logprob_values")
+                 "sink", "logprobs", "logprob_values", "decode_span")
 
     def __init__(self, prompt: np.ndarray, n_tokens: int,
                  temperature: float, seed: int,
@@ -205,6 +205,10 @@ class _GenRequest:
         # the request timeline, carried across the caller-thread →
         # scheduler-thread hop (thread-locals do not cross it)
         self.trace = observability.NULL_TRACE
+        # the request's ONE `decode` (or `spec-verify`) span, opened at
+        # its first decode dispatch and extended in place by each later
+        # one; the per-dispatch record is the scheduler's timeline
+        self.decode_span = None
 
     def expired(self, now: Optional[float] = None) -> bool:
         return self.deadline is not None and \
@@ -323,7 +327,7 @@ def _write_pages(kp_, vp_, kcol, vrow, wpids, woff, page):
     return kp_, vp_
 
 
-def _dispatched(thunk, span=None):
+def _dispatched(thunk):
     """Run one compiled dispatch INCLUDING its host materialization,
     tagging any exception raised so the caller can tell a FAILED
     DISPATCH (which, under buffer donation, may have invalidated the
@@ -331,15 +335,8 @@ def _dispatched(thunk, span=None):
     landed (non-finite screens, hooks) — only the former justifies
     failing other slots. The device_get must live inside the thunk: on
     asynchronous backends a device-side error surfaces at
-    materialization, not at the dispatch call.
-
-    `span` (tensor-parallel engines pass "tp-dispatch") wraps the
-    dispatch in a trace annotation so `--trace` captures show which
-    wall-time went to sharded dispatches + their collectives."""
+    materialization, not at the dispatch call."""
     try:
-        if span is not None:
-            with observability.annotation(span):
-                return thunk()
         return thunk()
     except BaseException as e:
         e._dispatch_failure = True
@@ -428,9 +425,11 @@ class DecodeEngine:
         ``flight_record`` / ``metrics`` surface covers both layers;
         a standalone engine builds private instances. Request
         timelines (queue-wait, admission, prefix-bind, prefill chunks,
-        decode/spec-verify dispatches) ride `_GenRequest.trace`; all
-        recording is host-side and kill-switched by
-        ``DL4J_TPU_NO_TRACING=1``.
+        one decode/spec-verify span) ride `_GenRequest.trace`; the
+        scheduler thread's own timeline (leaf phases, one set per
+        dispatch) goes to `observability.TIMELINE` and, as counters,
+        to ``stats()["loop"]``. All recording is host-side and
+        kill-switched by ``DL4J_TPU_NO_TRACING=1``; counters stay on.
     quantize : None or ``{"kv": "int8"}`` — the quantized KV tier
         (`serving/quantize.py`): pools allocate int8 elements plus
         per-(head, position) f32 scale pools riding the same page
@@ -628,6 +627,14 @@ class DecodeEngine:
         self._wfq_pass: dict = {}  # guarded by: _cond
         self._wfq_floor = 0.0  # guarded by: _cond
         self._queue_wait_ewma = 0.0  # guarded by: _cond
+        # admission wait, summed where admission happens: seconds
+        # queued over requests that left the queue, into a slot or,
+        # expired, to a typed shed
+        self.queue_wait_s = 0.0  # guarded by: _cond
+        self.admitted = 0  # guarded by: _cond
+        # the scheduler thread's account of its own time; written by
+        # that thread alone, read by stats()
+        self._phases = observability.ThreadPhases()
         self._chunk_ewma = 0.0  # guarded by: _cond
         # KV handoff plane (kv_transfer): disagg role, the sender-side
         # lease ledger, and the scheduler's migrate-everything switch
@@ -932,44 +939,48 @@ class DecodeEngine:
                 q, k, v = _block_heads(layer, p, x[:, None, :],
                                        pos[:, None], shard=tp_shard)
                 q, k, v = q[:, 0], k[:, 0], v[:, 0]
-                if kv_quant:
-                    # quantize the single-position (S, Hkv, hd) write
-                    # per head; the scale lands at the SAME
-                    # (page, head, offset) the payload does, so trash-
-                    # page redirection masks both together
-                    kp_, vp_, ks_, vs_ = caches[bi]
-                    kq, ksc = quantize_heads(k)
-                    vq, vsc = quantize_heads(v)
-                    kp_ = kp_.at[pids, :, :, loff].set(kq)
-                    vp_ = vp_.at[pids, :, loff, :].set(vq)
-                    ks_ = ks_.at[pids, :, loff].set(ksc)
-                    vs_ = vs_.at[pids, :, loff].set(vsc)
-                else:
-                    kp_, vp_ = caches[bi]
-                    ks_ = vs_ = None
-                    kp_ = kp_.at[pids, :, :, loff].set(k)
-                    vp_ = vp_.at[pids, :, loff, :].set(v)
+                with jax.named_scope("kv.write"):
+                    if kv_quant:
+                        # quantize the single-position (S, Hkv, hd)
+                        # write per head; the scale lands at the SAME
+                        # (page, head, offset) the payload does, so
+                        # trash-page redirection masks both together
+                        kp_, vp_, ks_, vs_ = caches[bi]
+                        kq, ksc = quantize_heads(k)
+                        vq, vsc = quantize_heads(v)
+                        kp_ = kp_.at[pids, :, :, loff].set(kq)
+                        vp_ = vp_.at[pids, :, loff, :].set(vq)
+                        ks_ = ks_.at[pids, :, loff].set(ksc)
+                        vs_ = vs_.at[pids, :, loff].set(vsc)
+                    else:
+                        kp_, vp_ = caches[bi]
+                        ks_ = vs_ = None
+                        kp_ = kp_.at[pids, :, :, loff].set(k)
+                        vp_ = vp_.at[pids, :, loff, :].set(v)
                 # kernel-dispatched paged attention: on TPU the Pallas
                 # kernel streams pages straight from the pool (no dense
                 # gather transient — the decode path's dominant cache-
                 # byte cost halves); on CPU/fallback the gather + dense
                 # step reference numerics run unchanged
-                att = paged_attention_step_auto(q, kp_, vp_, page_table,
-                                                pos, active,
-                                                k_scale=ks_, v_scale=vs_)
+                with jax.named_scope("kv.attend"):
+                    att = paged_attention_step_auto(
+                        q, kp_, vp_, page_table, pos, active,
+                        k_scale=ks_, v_scale=vs_)
                 att = _block_out_proj(p, att, tp_axis)
                 x = _block_ffn(layer, p, x + att, axis_name=tp_axis)
                 new_caches.append((kp_, vp_, ks_, vs_) if kv_quant
                                   else (kp_, vp_))
             logits = plan.final_logits(bp, params, x)
-            nxt, new_keys = sample_slots(logits, keys, temps)
-            nxt = jnp.where(active, nxt, tok)
+            with jax.named_scope("sample"):
+                nxt, new_keys = sample_slots(logits, keys, temps)
+                nxt = jnp.where(active, nxt, tok)
             new_pos = jnp.where(active, pos + 1, pos)
+            with jax.named_scope("finite-check"):
+                step_ok = logits_ok(logits, active)
             if K:
-                return new_caches, nxt, new_pos, new_keys, \
-                    logits_ok(logits, active), lp_math(logits, nxt)
-            return new_caches, nxt, new_pos, new_keys, \
-                logits_ok(logits, active)
+                return new_caches, nxt, new_pos, new_keys, step_ok, \
+                    lp_math(logits, nxt)
+            return new_caches, nxt, new_pos, new_keys, step_ok
 
         def decode_step(params, caches, page_table, tok, pos, keys, temps,
                         active):
@@ -1037,35 +1048,43 @@ class DecodeEngine:
                 kcol = jnp.transpose(k, (0, 2, 3, 1))   # (1, Hkv, hd, P)
                 vrow = jnp.transpose(v, (0, 2, 1, 3))   # (1, Hkv, P, hd)
                 z0 = jnp.zeros((), jnp.int32)
-                if kv_quant:
-                    # the prompt span quantizes per (head, position):
-                    # abs-max over the hd axis of each lane-last layout
-                    kp_, vp_, ks_, vs_ = caches[bi]
-                    kcol, kscol = quantize_heads(kcol, axis=2)
-                    vrow, vscol = quantize_heads(vrow, axis=3)
-                    ks_ = write_scale_pages(ks_, kscol, wpids, z0, page)
-                    vs_ = write_scale_pages(vs_, vscol, wpids, z0, page)
-                    kp_, vp_ = write_pages(kp_, vp_, kcol, vrow, wpids, z0)
-                    new_caches.append((kp_, vp_, ks_, vs_))
-                else:
-                    kp_, vp_ = caches[bi]
-                    kp_, vp_ = write_pages(kp_, vp_, kcol, vrow, wpids, z0)
-                    new_caches.append((kp_, vp_))
+                with jax.named_scope("kv.write"):
+                    if kv_quant:
+                        # the prompt span quantizes per (head,
+                        # position): abs-max over the hd axis of each
+                        # lane-last layout
+                        kp_, vp_, ks_, vs_ = caches[bi]
+                        kcol, kscol = quantize_heads(kcol, axis=2)
+                        vrow, vscol = quantize_heads(vrow, axis=3)
+                        ks_ = write_scale_pages(ks_, kscol, wpids, z0,
+                                                page)
+                        vs_ = write_scale_pages(vs_, vscol, wpids, z0,
+                                                page)
+                        kp_, vp_ = write_pages(kp_, vp_, kcol, vrow,
+                                               wpids, z0)
+                        new_caches.append((kp_, vp_, ks_, vs_))
+                    else:
+                        kp_, vp_ = caches[bi]
+                        kp_, vp_ = write_pages(kp_, vp_, kcol, vrow,
+                                               wpids, z0)
+                        new_caches.append((kp_, vp_))
             logits = plan.final_logits(bp, params, x[0, t0 - 1][None])
             # kp samples the prefill token, kdec seeds the slot's decode
             # key — the same split generate() draws from PRNGKey(seed).
             # Temperature is dynamic per request, so the greedy/sampled
             # select mirrors sample_slots (same scale_and_filter core)
-            greedy = _sample_logits(logits, kp, 0.0, 0)
-            drawn = jax.random.categorical(
-                kp, scale_and_filter(logits, temp[None]),
-                axis=-1).astype(jnp.int32)
-            tok0 = jnp.where(temp > 0, drawn, greedy)
+            with jax.named_scope("sample"):
+                greedy = _sample_logits(logits, kp, 0.0, 0)
+                drawn = jax.random.categorical(
+                    kp, scale_and_filter(logits, temp[None]),
+                    axis=-1).astype(jnp.int32)
+                tok0 = jnp.where(temp > 0, drawn, greedy)
             tok = tok.at[slot].set(tok0[0])
             pos = pos.at[slot].set(t0)
             keys = keys.at[slot].set(kdec)
             temps = temps.at[slot].set(temp)
-            ok0 = jnp.all(jnp.isfinite(logits.astype(jnp.float32)))
+            with jax.named_scope("finite-check"):
+                ok0 = jnp.all(jnp.isfinite(logits.astype(jnp.float32)))
             if K:
                 return new_caches, tok, pos, keys, temps, tok0, ok0, \
                     lp_math(logits, tok0)
@@ -1102,36 +1121,41 @@ class DecodeEngine:
                 q, k, v = _block_heads(layer, p, x, qpos, shard=tp_shard)
                 kcol = jnp.transpose(k, (0, 2, 3, 1))   # (1, Hkv, hd, C)
                 vrow = jnp.transpose(v, (0, 2, 1, 3))   # (1, Hkv, C, hd)
-                if kv_quant:
-                    kp_, vp_, ks_, vs_ = caches[bi]
-                    kcol, kscol = quantize_heads(kcol, axis=2)
-                    vrow, vscol = quantize_heads(vrow, axis=3)
-                    ks_ = write_scale_pages(ks_, kscol, wpids, woff, page)
-                    vs_ = write_scale_pages(vs_, vscol, wpids, woff, page)
-                else:
-                    kp_, vp_ = caches[bi]
-                    ks_ = vs_ = None
-                kp_, vp_ = write_pages(kp_, vp_, kcol, vrow, wpids, woff)
+                with jax.named_scope("kv.write"):
+                    if kv_quant:
+                        kp_, vp_, ks_, vs_ = caches[bi]
+                        kcol, kscol = quantize_heads(kcol, axis=2)
+                        vrow, vscol = quantize_heads(vrow, axis=3)
+                        ks_ = write_scale_pages(ks_, kscol, wpids, woff,
+                                                page)
+                        vs_ = write_scale_pages(vs_, vscol, wpids, woff,
+                                                page)
+                    else:
+                        kp_, vp_ = caches[bi]
+                        ks_ = vs_ = None
+                    kp_, vp_ = write_pages(kp_, vp_, kcol, vrow, wpids,
+                                           woff)
                 # attend AFTER the write: the chunk attends to itself
                 # through the cache, which is exactly causal with the
                 # <= qpos mask; the auto path walks the slot's page row
                 # in place on TPU and falls back to gather + chunk
                 # (`_prefill_chunk_block_attention` numerics) elsewhere
-                att = paged_attention_chunk_auto(q, kp_, vp_,
-                                                 page_row[None],
-                                                 off[None],
-                                                 k_scale=ks_, v_scale=vs_)
+                with jax.named_scope("kv.attend"):
+                    att = paged_attention_chunk_auto(
+                        q, kp_, vp_, page_row[None], off[None],
+                        k_scale=ks_, v_scale=vs_)
                 att = _block_out_proj(p, att.reshape(1, Cw, -1), tp_axis)
                 x = _block_ffn(layer, p, x + att, axis_name=tp_axis)
                 new_caches.append((kp_, vp_, ks_, vs_) if kv_quant
                                   else (kp_, vp_))
             r = jnp.clip(t0 - 1 - off, 0, Cw - 1)
             logits = plan.final_logits(bp, params, x[0, r][None])
-            greedy = _sample_logits(logits, kp, 0.0, 0)
-            drawn = jax.random.categorical(
-                kp, scale_and_filter(logits, temp[None]),
-                axis=-1).astype(jnp.int32)
-            tok0 = jnp.where(temp > 0, drawn, greedy)
+            with jax.named_scope("sample"):
+                greedy = _sample_logits(logits, kp, 0.0, 0)
+                drawn = jax.random.categorical(
+                    kp, scale_and_filter(logits, temp[None]),
+                    axis=-1).astype(jnp.int32)
+                tok0 = jnp.where(temp > 0, drawn, greedy)
             tok = tok.at[slot].set(tok0[0])
             pos = pos.at[slot].set(t0)
             keys = keys.at[slot].set(kdec)
@@ -1139,8 +1163,9 @@ class DecodeEngine:
             # screen the whole chunk's hidden states, not only the
             # logits row: a non-finite mid-prompt chunk poisons the
             # cache it just wrote, and must fail HERE, typed
-            ok = jnp.all(jnp.isfinite(logits.astype(jnp.float32))) \
-                & jnp.all(jnp.isfinite(x.astype(jnp.float32)))
+            with jax.named_scope("finite-check"):
+                ok = jnp.all(jnp.isfinite(logits.astype(jnp.float32))) \
+                    & jnp.all(jnp.isfinite(x.astype(jnp.float32)))
             if K:
                 return new_caches, tok, pos, keys, temps, tok0, ok, \
                     lp_math(logits, tok0)
@@ -1164,7 +1189,6 @@ class DecodeEngine:
         # otherwise
         self._dparams = tp.shard_params(net._params) if tp is not None \
             else net._params
-        self._tp_span = "tp-dispatch" if tp is not None else None
         self._plan = plan
         self._net = net
         self.max_len = L
@@ -1387,6 +1411,17 @@ class DecodeEngine:
         req.finish(err)
 
     # graftlint: hot-loop
+    def _queue_waited(self, req: _GenRequest, now: float,
+                      decision: Optional[str] = None) -> None:
+        """A request leaves the queue: its own `queue-wait` span, and
+        the serving front's two sums."""
+        req.trace.add_timed("queue-wait", req.enqueued_at, now,
+                            decision=decision)
+        with self._cond:
+            self.queue_wait_s += now - req.enqueued_at
+            self.admitted += 1
+
+    # graftlint: hot-loop
     def _shed_obs(self, trace, err: BaseException, **attrs) -> None:
         """Door-shed path (no request handle yet): finish the timeline
         with the typed decision and pin it in the failures ring."""
@@ -1425,6 +1460,9 @@ class DecodeEngine:
         if sink is not None:
             entry = req.logprob_values[-1] \
                 if req.logprobs and req.logprob_values else None
+            # the consumer's time inside the scheduler thread: a
+            # counter, not a span a token
+            t_sink = time.perf_counter()
             try:
                 sink(len(req.tokens), req.tokens[-1], entry)
             # graftlint: disable=typed-error  scheduler protection: a
@@ -1434,6 +1472,9 @@ class DecodeEngine:
                 logger.exception(
                     "decode engine: stream sink failed; detaching it")
                 req.sink = None
+            ph = self._phases
+            ph.sink_s += time.perf_counter() - t_sink
+            ph.sink_n += 1
 
     def flight_record(self) -> dict:
         """Dump the flight recorder (request timelines + scheduler
@@ -1758,8 +1799,7 @@ class DecodeEngine:
                 self._pages_demand_queued -= req.n_pages
                 self._free_request_pages_locked(req)  # delta-pin release
                 self.shed_deadline += 1
-                req.trace.add_timed("queue-wait", req.enqueued_at, now,
-                                    decision="expired")
+                self._queue_waited(req, now, "expired")
                 self._finish_obs(req, DeadlineExceededError(
                     "deadline expired while queued; request shed before "
                     "prefill"))
@@ -2432,7 +2472,13 @@ class DecodeEngine:
                    100.0 * self.cluster_prefix_hit_tokens
                    / self.prompt_tokens, 1) if self.prompt_tokens
                    else 0.0,
-               "prompt_buckets": list(self.prompt_buckets)}
+               "prompt_buckets": list(self.prompt_buckets),
+               # the scheduler thread's time by leaf phase, and the
+               # admission wait: cumulative, so two readings bracket a
+               # window
+               "loop": self._phases.counters(),
+               "queue_wait_s": self.queue_wait_s,
+               "admitted": self.admitted}
         if self._prefix_cache is not None:
             hit_pct = (100.0 * self.prefix_hit_tokens / self.prompt_tokens
                        if self.prompt_tokens else 0.0)
@@ -2546,10 +2592,23 @@ class DecodeEngine:
             hook(phase, info)
 
     def _loop(self) -> None:
+        """The scheduler thread. Every moment of it is in one leaf
+        phase of `observability.LEAF_PHASES`: the methods below move
+        `self._phases` on as they go, and one pass of the try-block is
+        one iteration, the `cause` its spans share."""
+        try:
+            self._schedule()
+        finally:
+            self._phases.close()
+
+    def _schedule(self) -> None:
+        ph = self._phases
         while True:
             with self._cond:
                 while not self._closed and not self._kill \
                         and not self._work_pending():
+                    # one span per stay, not one per 50 ms wake
+                    ph.enter("wait-work")
                     self._cond.wait(0.05)
                 if self._kill:
                     self._fail_all_locked(ServerClosedError(
@@ -2570,15 +2629,20 @@ class DecodeEngine:
                         self._abort_pending_swap_locked()
                         self._cond.notify_all()
                         return
+            ph.begin_iteration()
             try:
                 if not self._draining and not self._closed:
                     self._admit()
+                ph.enter("housekeeping")
                 self._expire_in_flight()
                 self._step_migrations()
                 self._serve_prefix_exports()
                 self._sweep_leases()
                 self._step_prefills()
                 self._step_active()
+                # until the next iteration's first phase: the swap
+                # check and the top of this loop
+                ph.enter("housekeeping")
                 self._maybe_swap()
             # graftlint: disable=typed-error  scheduler firewall: the
             # iteration's failure is converted to InferenceFailedError and
@@ -2769,6 +2833,9 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         while True:
+            # again after each one-shot prefill, which has phases of its
+            # own
+            self._phases.enter("admit")
             preempt = None
             with self._cond:
                 if not self._queue:
@@ -2879,13 +2946,12 @@ class DecodeEngine:
             if req.expired(now):
                 with self._cond:
                     self.shed_deadline += 1
-                req.trace.add_timed("queue-wait", req.enqueued_at, now,
-                                    decision="expired")
+                self._queue_waited(req, now, "expired")
                 self._finish_obs(req, DeadlineExceededError(
                     "deadline expired while queued; request shed before "
                     "prefill"))
                 continue
-            req.trace.add_timed("queue-wait", req.enqueued_at, now)
+            self._queue_waited(req, now)
             with self._cond:
                 # ground the SLO estimator's queue-wait term on every
                 # admission (preempted re-admissions fold in too: their
@@ -2981,9 +3047,13 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
+        ph = self._phases
         page = self.page_size
         t0 = req.prompt.shape[0]
         bucket = self._bucket_for(t0)
+        ph.enter("prefill.dispatch", program="prefill", chunk=bucket,
+                 active=int(self._active.sum()), tp=self._tp_degree,
+                 trace_id=req.trace.trace_id)
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :t0] = req.prompt
         n_w = -(-bucket // page)
@@ -3006,10 +3076,12 @@ class DecodeEngine:
                 (self._caches, self._tok, self._pos, self._keys,
                  self._temps, tok0, ok) = self._prefill(*args)
                 lp0 = None
+            ph.enter("prefill.wait")
             return jax.device_get((tok0, ok, lp0))
 
         tp0 = time.monotonic()
-        first, ok, lp0 = _dispatched(run, span=self._tp_span)
+        first, ok, lp0 = _dispatched(run)
+        ph.enter("prefill.deliver")
         tp1 = time.monotonic()
         # host clock around the dispatch+materialization — already
         # synced, so the span costs no extra device round-trip
@@ -3023,7 +3095,11 @@ class DecodeEngine:
         if self._spec is not None:
             # mirror the prompt into the draft's pools (same pages, same
             # padded ids) so proposing can start from a complete context
+            ph.enter("prefill.dispatch", program="draft_prefill",
+                     chunk=bucket, tp=self._tp_degree,
+                     trace_id=req.trace.trace_id)
             _dispatched(lambda: self._spec.prefill_one_shot(ids, wpids))
+            ph.enter("prefill.deliver")
         self._hook("post_prefill", info)
         with self._cond:
             self.prefills += 1
@@ -3073,6 +3149,11 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
+        ph = self._phases
+        ph.enter("prefill.dispatch", program="prefill_chunk_fn",
+                 chunk=self.prefill_chunk,
+                 active=int(self._active.sum()), tp=self._tp_degree,
+                 trace_id=req.trace.trace_id)
         C, page = self.prefill_chunk, self.page_size
         off = req.prefill_pos
         t0 = req.prompt.shape[0]
@@ -3119,11 +3200,13 @@ class DecodeEngine:
                 (self._caches, self._tok, self._pos, self._keys,
                  self._temps, tok0, ok) = self._prefill_chunk_fn(*args)
                 lp0 = None
+            ph.enter("prefill.wait")
             return jax.device_get((tok0, ok, lp0))
 
         tp0 = time.monotonic()
         try:
-            first, ok, lp0 = _dispatched(run, span=self._tp_span)
+            first, ok, lp0 = _dispatched(run)
+            ph.enter("prefill.deliver")
             tp1 = time.monotonic()
             req.trace.add_timed("prefill-chunk", tp0, tp1,
                                 chunk_off=off, width=W, final=final)
@@ -3133,8 +3216,12 @@ class DecodeEngine:
                     "prefill (poisoned parameters or a numerically broken "
                     "graph)")
             if self._spec is not None:
+                ph.enter("prefill.dispatch", program="draft_prefill_chunk",
+                         chunk=W, tp=self._tp_degree,
+                         trace_id=req.trace.trace_id)
                 _dispatched(lambda: self._spec.prefill_chunk(
                     self._page_table[slot], ids, off, woff, pids))
+                ph.enter("prefill.deliver")
         # graftlint: disable=typed-error  converts to a typed failure:
         # _prefill_failure wraps non-ServingError causes in
         # InferenceFailedError and fails only the one request
@@ -3687,8 +3774,7 @@ class DecodeEngine:
             self._queue = keep
             self.shed_deadline += len(expired_queued)
         for req in expired_queued:
-            req.trace.add_timed("queue-wait", req.enqueued_at, now,
-                                decision="expired")
+            self._queue_waited(req, now, "expired")
             self._finish_obs(req, DeadlineExceededError(
                 "deadline expired while queued; request shed before "
                 "prefill"))
@@ -3767,6 +3853,20 @@ class DecodeEngine:
             self._reset_device_state()
 
     # graftlint: hot-loop
+    def _extend_decode_span(self, req: _GenRequest, name: str, t0: float,
+                            t1: float, steps: int) -> None:
+        """The request's one `decode` / `spec-verify` span: opened by
+        its first decode dispatch, stretched by each later one."""
+        sp = req.decode_span
+        if sp is None:
+            req.decode_span = req.trace.add_timed(
+                name, t0, t1, steps=steps, dispatches=1)
+        else:
+            sp.t1 = t1
+            sp.attrs["steps"] += steps
+            sp.attrs["dispatches"] += 1
+
+    # graftlint: hot-loop
     def _retire_or_poison(self, s: int, req: _GenRequest, toks, oks,
                           n_steps: int, lps=None) -> None:
         """Consume one slot's emitted tokens from a decode/verify
@@ -3833,6 +3933,9 @@ class DecodeEngine:
             # span: a preempted request's prompt absorbed its emitted
             # tokens, which its n_tokens budget already spans
             wl[s] = r.prompt.shape[0] - r.resumed_at + r.n_tokens - 2
+        ph = self._phases
+        ph.enter("decode.dispatch", program="spec_verify", chunk=k + 1,
+                 active=len(live), tp=self._tp_degree)
         info = {"active": len(live), "step": self.decode_steps,
                 "spec": True, "k": k}
         t0c = time.monotonic()
@@ -3851,9 +3954,11 @@ class DecodeEngine:
                     self._dparams, self._caches, self._page_table,
                     self._tok, self._pos, self._keys, self._temps,
                     active, wlimit, props, qd)
+                ph.enter("decode.wait")
                 return jax.device_get((out, n_emit, oks))
 
-            out, n_emit, oks = _dispatched(run, span=self._tp_span)
+            out, n_emit, oks = _dispatched(run)
+            ph.enter("decode.deliver")
             self._hook("post_decode", info)
             t1c = time.monotonic()
         # graftlint: disable=typed-error  converts to a typed failure:
@@ -3881,8 +3986,7 @@ class DecodeEngine:
         delivered = 0
         for s, req in live:
             n = max(1, int(n_emit[s]))
-            req.trace.add_timed("spec-verify", t0c, t1c, k=k,
-                                emitted=n, active=len(live))
+            self._extend_decode_span(req, "spec-verify", t0c, t1c, n)
             before = len(req.tokens)
             self._retire_or_poison(s, req, out[s, :n],
                                    np.repeat(oks[s], n), n)
@@ -3906,6 +4010,11 @@ class DecodeEngine:
             return
         now = time.monotonic()
         chunked = self._spec is None and self._chunk_eligible(live, now)
+        ph = self._phases
+        ph.enter("decode.dispatch",
+                 program="decode_chunked" if chunked else "decode_step",
+                 chunk=self.decode_chunk if chunked else 1,
+                 active=len(live), tp=self._tp_degree)
         info = {"active": len(live), "step": self.decode_steps,
                 "chunk": self.decode_chunk if chunked else 1}
         t0 = time.monotonic()
@@ -3932,6 +4041,7 @@ class DecodeEngine:
                             jnp.asarray(self._active))
                         lps_d = None
                     # (chunk, S) tokens + per-step flags, ONE host sync
+                    ph.enter("decode.wait")
                     return jax.device_get((toks_d, oks_d, lps_d))
                 if self._logprobs_k:
                     (self._caches, self._tok, self._pos, self._keys,
@@ -3948,11 +4058,13 @@ class DecodeEngine:
                     lp_d = None
                 # THE per-iteration host sync — the price of
                 # iteration-level scheduling; chunking amortizes it
+                ph.enter("decode.wait")
                 t, o, lp = jax.device_get((self._tok, ok_d, lp_d))
                 return t[None], o[None], (None if lp is None else
                                           tuple(a[None] for a in lp))
 
-            toks, oks, lps = _dispatched(run, span=self._tp_span)
+            toks, oks, lps = _dispatched(run)
+            ph.enter("decode.deliver")
             self._hook("post_decode", info)
         # graftlint: disable=typed-error  converts to a typed failure:
         # _decode_failure wraps the cause in InferenceFailedError for the
@@ -3968,8 +4080,7 @@ class DecodeEngine:
             self.decode_steps += n_steps
             self.active_slot_steps += len(live) * n_steps
         for s, req in live:
-            req.trace.add_timed("decode", t0, t1, steps=n_steps,
-                                active=len(live))
+            self._extend_decode_span(req, "decode", t0, t1, n_steps)
             # per-step, per-slot non-finite screen (predict's breaker
             # discipline): a poisoned step fails THIS request typed —
             # unless it already completed via EOS at an earlier step of

@@ -1,11 +1,11 @@
-"""Serving-tier observability: request tracing, metrics registry, and
-flight recorder for the gateway → ReplicaPool → ModelServer →
+"""Serving-tier observability: request tracing, metrics registry,
+flight recorder and thread timeline for the gateway → ReplicaPool → ModelServer →
 DecodeEngine stack.
 
 The serving layers (PRs 4-9) expose only point-in-time ``stats()``
 counters — when a request sheds, fails over, hedges, or takes a p99
 excursion there is no record of *where the time went* or *which layer
-decided what*. This module closes that gap with three pieces, in the
+decided what*. This module closes that gap with four pieces, in the
 spirit of Dapper-style always-on tracing:
 
 - **Request tracing** (`Trace`/`Span`): a ``trace_id`` minted at the
@@ -36,6 +36,16 @@ spirit of Dapper-style always-on tracing:
   push a postmortem out), dumpable via the gateway ``flight_record``
   RPC.
 
+- **Thread timeline** (`Timeline`/`ThreadPhases`): the other axis — not
+  one request's life but one THREAD's. `TIMELINE` is a bounded
+  process-wide ring of phase spans on `time.perf_counter()`; a
+  `ThreadPhases` (the decode scheduler owns one) switches its thread
+  from leaf phase to leaf phase, so the spans partition the thread's
+  time, and keeps the same boundaries as cumulative counters that stay
+  on under the kill switch. Every phase also opens a
+  `jax.profiler.TraceAnnotation("dl4j:<phase>")`, so any profiler
+  capture shows the phases beside the XLA op timeline with no knob.
+
 Hot-path discipline: every recording call is pure host-side arithmetic
 (monotonic reads, int/str attrs, deque appends). Nothing here may
 receive a device array — formatting one would block the scheduler
@@ -43,15 +53,7 @@ thread on the device stream, which is exactly the hazard the graftlint
 ``host-sync`` rule now also flags for recorder calls inside
 ``# graftlint: hot-loop`` scopes. The whole subsystem is kill-switched
 by ``DL4J_TPU_NO_TRACING=1`` (spans become no-ops on the shared
-`NULL_TRACE`, the recorder drops writes); `bench.py serve_generate`
-prices the on-vs-off goodput delta as ``tracing_overhead_pct``.
-
-Spans also name host phases in XLA/Perfetto traces: when
-``DL4J_TPU_XLA_SPAN_ANNOTATIONS=1``, `Trace.span` wraps
-`profiler.trace_annotation`, so a `jax.profiler` capture (e.g.
-``bench.py --trace``) shows ``serve:prefill-chunk`` etc. interleaved
-with the XLA op timeline. Off by default: annotations cost a context
-manager per span even with no profiler attached.
+`NULL_TRACE`, the recorder and the timeline drop writes; counters stay).
 """
 from __future__ import annotations
 
@@ -64,38 +66,21 @@ from contextlib import contextmanager
 from typing import Callable, Dict, List, Optional, Sequence
 
 __all__ = [
-    "Counter", "FlightRecorder", "Gauge", "Histogram", "MetricsRegistry",
-    "NULL_TRACE", "Span", "Trace", "attach_trace", "current_trace",
+    "Counter", "FlightRecorder", "Gauge", "Histogram", "LEAF_PHASES",
+    "MetricsRegistry", "NULL_TRACE", "Span", "TIMELINE", "ThreadPhases",
+    "Timeline", "Trace", "attach_trace", "current_trace",
     "graft_remote_trace", "maybe_trace", "new_trace_id", "tracing_enabled",
     "use_trace", "wire_trace_context",
 ]
 
 _KILL_ENV = "DL4J_TPU_NO_TRACING"
-_XLA_ANNOTATE_ENV = "DL4J_TPU_XLA_SPAN_ANNOTATIONS"
 
 
 def tracing_enabled() -> bool:
     """The kill switch: ``DL4J_TPU_NO_TRACING=1`` turns every trace
-    into `NULL_TRACE` and every recorder write into a no-op — the
-    baseline side of the in-bench ``tracing_overhead_pct`` A/B."""
+    into `NULL_TRACE` and every recorder or timeline write into a
+    no-op."""
     return os.environ.get(_KILL_ENV, "") not in ("1", "true", "yes")
-
-
-def _xla_annotations_enabled() -> bool:
-    return os.environ.get(_XLA_ANNOTATE_ENV, "") in ("1", "true", "yes")
-
-
-def annotation(name: str):
-    """A ``serve:<name>`` `profiler.trace_annotation` context when
-    ``DL4J_TPU_XLA_SPAN_ANNOTATIONS=1``, else a free no-op — lets
-    serving internals (draft mirrors, verify drivers) name themselves
-    in a `jax.profiler` capture without paying for the context manager
-    when no one is looking."""
-    if _xla_annotations_enabled():
-        from deeplearning4j_tpu.profiler import trace_annotation
-
-        return trace_annotation(f"serve:{name}")
-    return _NullContext()
 
 
 def new_trace_id() -> str:
@@ -171,20 +156,11 @@ class Trace:
         """Record ``name`` over the with-block. An escaping exception
         stamps the span's decision with the exception class name and
         re-raises; otherwise the decision is ``"ok"`` (callers may
-        overwrite via the yielded span). With
-        ``DL4J_TPU_XLA_SPAN_ANNOTATIONS=1`` the block is also wrapped
-        in `profiler.trace_annotation`, naming the phase in any active
-        `jax.profiler` capture."""
+        overwrite via the yielded span)."""
         sp = Span(name, time.monotonic(), attrs=attrs or None)
         self._append(sp)
         try:
-            if _xla_annotations_enabled():
-                from deeplearning4j_tpu.profiler import trace_annotation
-
-                with trace_annotation(f"serve:{name}"):
-                    yield sp
-            else:
-                yield sp
+            yield sp
         except BaseException as e:
             sp.t1 = time.monotonic()
             sp.decision = type(e).__name__
@@ -198,10 +174,15 @@ class Trace:
         self._append(Span(name, time.monotonic(), attrs=attrs or None))
 
     def add_timed(self, name: str, t0: float, t1: float,
-                  decision: Optional[str] = None, **attrs) -> None:
+                  decision: Optional[str] = None, **attrs) -> Span:
         """Record an interval measured by the caller (e.g. queue-wait
-        from a request's ``enqueued_at`` to its admission)."""
-        self._append(Span(name, t0, t1, decision, attrs or None))
+        from a request's ``enqueued_at`` to its admission). Returns the
+        span, so a caller that keeps measuring the same thing (the
+        engine's one ``decode`` span per request) extends it in place
+        instead of appending one per observation."""
+        sp = Span(name, t0, t1, decision, attrs or None)
+        self._append(sp)
+        return sp
 
     def finish(self, decision: str) -> None:
         self.decision = decision
@@ -774,6 +755,152 @@ class FlightRecorder:
         }
 
 
+# -- thread timeline -------------------------------------------------------
+
+class Timeline:
+    """Bounded ring of thread-phase spans, shared by the whole process:
+    a reader that comes after the engine and the server are gone (the
+    benchmark's metric readers do) can still import it.
+
+    A span is the plain tuple ``(name, t0, t1, cause, tid, attrs)``:
+    `t0`/`t1` are `time.perf_counter()` seconds, `cause` is what set the
+    work off (for the decode scheduler, its iteration's number, shared
+    by every span of that iteration), `tid` the recording thread's
+    ident — several schedulers in one process (in-process replicas)
+    read as one timeline each — and `attrs` a dict or None. The oldest
+    span is dropped for the newest once `capacity` is reached, and
+    `dropped` counts them."""
+
+    def __init__(self, capacity: int = 32768):
+        self._lock = threading.Lock()
+        self._spans = deque(maxlen=capacity)
+        self._appended = 0
+
+    def record(self, name: str, t0: float, t1: float, cause=None,
+               tid: Optional[int] = None,
+               attrs: Optional[dict] = None) -> None:
+        """Append one span measured by the caller. Not switched: the
+        writer tests `tracing_enabled` once for many spans."""
+        with self._lock:
+            self._spans.append((name, t0, t1, cause, tid, attrs))
+            self._appended += 1
+
+    @property
+    def dropped(self) -> int:
+        with self._lock:
+            return self._appended - len(self._spans)
+
+    def snapshot(self, t0: Optional[float] = None,
+                 t1: Optional[float] = None) -> List[tuple]:
+        """The spans that overlap [t0, t1] (either side open), oldest
+        first."""
+        with self._lock:
+            spans = list(self._spans)
+        return [s for s in spans
+                if (t1 is None or s[1] <= t1) and (t0 is None or s[2] >= t0)]
+
+
+#: the one timeline of the process
+TIMELINE = Timeline()
+
+#: what a decode scheduler's thread can be doing; every moment of it is
+#: in exactly one of these (docs/observability.md names what each holds)
+LEAF_PHASES = (
+    "wait-work", "admit", "housekeeping",
+    "prefill.dispatch", "prefill.wait", "prefill.deliver",
+    "decode.dispatch", "decode.wait", "decode.deliver")
+
+_TraceAnnotation = None
+
+
+class ThreadPhases:
+    """One thread's time, cut into leaf phases. `enter(name)` ends the
+    phase the thread was in and starts the next at the same instant, so
+    phases cannot overlap and leave no gap between the first `enter` and
+    `close`. Each ended phase becomes a span on `timeline`, a
+    `jax.profiler.TraceAnnotation("dl4j:<name>")` while it lasts (a
+    flag test when no profiler session is open) and two cumulative
+    counters, `<name>_s` and `<name>_n`; the kill switch, read once per
+    `begin_iteration`, stops the spans and annotations and leaves the
+    counters.
+
+    Only the owning thread calls `begin_iteration`/`enter`/`close`;
+    `counters()` may be read from any thread."""
+
+    def __init__(self, timeline: Timeline = TIMELINE):
+        self._timeline = timeline
+        self._acc = {p: [0.0, 0] for p in LEAF_PHASES}
+        self._labels = {p: "dl4j:" + p for p in LEAF_PHASES}
+        self.iterations = 0
+        self.sink_s = 0.0
+        self.sink_n = 0
+        self._on = tracing_enabled()
+        self._tid = None
+        # (name, t0, cause, attrs) of the phase the thread is in
+        self._open = None
+        self._annotation = None
+
+    def begin_iteration(self) -> None:
+        """A new cause for the spans that follow."""
+        self.iterations += 1
+        self._on = tracing_enabled()
+
+    def enter(self, name: str, **attrs) -> None:
+        """Move the thread into phase `name`. Entering the phase it is
+        already in changes nothing: the phase goes on."""
+        if self._open is not None and self._open[0] == name:
+            return
+        if name not in self._acc:
+            raise KeyError(f"{name!r} is no leaf phase of LEAF_PHASES")
+        now = time.perf_counter()
+        self._end(now)
+        self._open = (name, now, self.iterations, attrs or None)
+        if self._tid is None:
+            self._tid = threading.get_ident()
+        if self._on:
+            global _TraceAnnotation
+            if _TraceAnnotation is None:
+                from jax.profiler import TraceAnnotation as _TraceAnnotation
+            self._annotation = _TraceAnnotation(self._labels[name])
+            self._annotation.__enter__()
+
+    def close(self) -> None:
+        """End the open phase; the thread is leaving."""
+        self._end(time.perf_counter())
+
+    def _end(self, now: float) -> None:
+        if self._open is None:
+            return
+        name, t0, cause, attrs = self._open
+        self._open = None
+        acc = self._acc[name]
+        acc[0] += now - t0
+        acc[1] += 1
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        if self._on:
+            self._timeline.record(name, t0, now, cause, self._tid, attrs)
+
+    def counters(self) -> dict:
+        """``{"iterations", "<phase>_s", "<phase>_n", "sink_s",
+        "sink_n", "spans_dropped"}``: cumulative, so the difference of
+        two readings is a window's account. The phase still open counts
+        with the seconds it has lasted so far, so the `_s` of all
+        phases add up to the time since the first `enter`."""
+        out = {"iterations": self.iterations}
+        for name, (seconds, n) in self._acc.items():
+            out[name + "_s"] = seconds
+            out[name + "_n"] = n
+        open_ = self._open
+        if open_ is not None:
+            out[open_[0] + "_s"] += time.perf_counter() - open_[1]
+        out["sink_s"] = self.sink_s
+        out["sink_n"] = self.sink_n
+        out["spans_dropped"] = self._timeline.dropped
+        return out
+
+
 # -- stats-schema contracts ------------------------------------------------
 # The single source of truth for the key sets the serving layers'
 # ``stats()`` dicts promise (tests and external scrapers rely on them;
@@ -829,6 +956,11 @@ DECODE_ENGINE_STATS_KEYS = frozenset({
     "prefix_fetches", "prefix_fetch_fallbacks", "prefix_fetch_bytes",
     "prefix_fetch_ms", "prefix_exports", "cluster_prefix_hit_tokens",
     "cluster_prefix_hit_tokens_pct",
+    # the scheduler thread's own account (`ThreadPhases.counters`:
+    # seconds and counts per leaf phase, cumulative), and admission
+    # wait summed where admission happens: seconds queued over requests
+    # that left the queue for a slot
+    "loop", "queue_wait_s", "admitted",
 })
 
 # Per-tenant counters nested under DecodeEngine ``stats()["tenants"]``

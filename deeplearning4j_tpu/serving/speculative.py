@@ -47,8 +47,6 @@ from typing import List, Optional
 
 import numpy as np
 
-from deeplearning4j_tpu.serving import observability
-
 
 def resolve_draft_net(draft, target_net):
     """Materialize the `speculative={"draft": ...}` config value:
@@ -530,11 +528,9 @@ class SpeculativeDecoder:
         import jax
         import jax.numpy as jnp
 
-        with observability.annotation("draft-prefill"):
-            self._caches = self._draft_prefill(
-                self._draft_params(), self._caches, jnp.asarray(ids),
-                wpids)
-            jax.device_get(self._caches[0][0][0, 0, 0, 0])
+        self._caches = self._draft_prefill(
+            self._draft_params(), self._caches, jnp.asarray(ids), wpids)
+        jax.device_get(self._caches[0][0][0, 0, 0, 0])
         self.draft_prefills += 1
 
     def prefill_chunk(self, page_row, ids, off, woff, pids) -> None:
@@ -542,13 +538,12 @@ class SpeculativeDecoder:
         import jax
         import jax.numpy as jnp
 
-        with observability.annotation("draft-prefill-chunk"):
-            self._caches = self._draft_prefill_chunk(
-                self._draft_params(), self._caches, page_row,
-                jnp.asarray(ids), jnp.asarray(off, jnp.int32),
-                jnp.asarray(woff, jnp.int32),
-                jnp.asarray(np.asarray(pids, np.int32)))
-            jax.device_get(self._caches[0][0][0, 0, 0, 0])
+        self._caches = self._draft_prefill_chunk(
+            self._draft_params(), self._caches, page_row,
+            jnp.asarray(ids), jnp.asarray(off, jnp.int32),
+            jnp.asarray(woff, jnp.int32),
+            jnp.asarray(np.asarray(pids, np.int32)))
+        jax.device_get(self._caches[0][0][0, 0, 0, 0])
         self.draft_chunk_prefills += 1
 
     def stats(self) -> dict:

@@ -702,3 +702,181 @@ def test_gateway_generate_failure_postmortem_over_the_wire(net):
         if cl is not None:
             cl.close()
         gw.stop()
+
+
+# ------------------------------------------- the scheduler's own timeline
+
+
+def test_timeline_is_bounded_and_counts_what_it_drops():
+    tl = obs.Timeline(capacity=4)
+    for i in range(10):
+        tl.record("decode.wait", float(i), i + 0.5, cause=i)
+    spans = tl.snapshot()
+    assert [s[3] for s in spans] == [6, 7, 8, 9]  # the oldest went
+    assert tl.dropped == 6
+    assert all(isinstance(s, tuple) and len(s) == 6 for s in spans)
+    # a window keeps every span that overlaps it, cut spans included
+    assert [s[3] for s in tl.snapshot(t0=7.2, t1=8.1)] == [7, 8]
+    assert obs.TIMELINE.snapshot() is not obs.TIMELINE.snapshot()
+
+
+def test_thread_phases_partition_a_threads_time():
+    tl = obs.Timeline()
+    ph = obs.ThreadPhases(tl)
+    ph.enter("wait-work")
+    ph.begin_iteration()
+    ph.enter("admit")
+    ph.enter("admit")  # the phase goes on: no second span
+    ph.enter("decode.dispatch", program="decode_step", chunk=1, active=2)
+    ph.enter("decode.wait")
+    still_open = ph.counters()
+    ph.close()
+    spans = tl.snapshot()
+    assert [s[0] for s in spans] == ["wait-work", "admit",
+                                     "decode.dispatch", "decode.wait"]
+    for a, b in zip(spans, spans[1:]):
+        assert a[2] == b[1]  # one instant ends a phase and starts the next
+    assert [s[3] for s in spans] == [0, 1, 1, 1]
+    assert {s[4] for s in spans} == {threading.get_ident()}
+    assert spans[2][5] == {"program": "decode_step", "chunk": 1,
+                           "active": 2}
+    c = ph.counters()
+    assert c["iterations"] == 1 and c["admit_n"] == 1
+    total = sum(v for k, v in c.items()
+                if k.endswith("_s") and k != "sink_s")
+    assert total == pytest.approx(spans[-1][2] - spans[0][1], abs=1e-9)
+    # the open phase already counted with what it had lasted
+    assert 0.0 < still_open["decode.wait_s"] <= c["decode.wait_s"]
+    assert still_open["decode.wait_n"] == 0
+    with pytest.raises(KeyError):
+        ph.enter("no-such-phase")
+
+
+def _scheduler_spans(eng, since):
+    return sorted((s for s in obs.TIMELINE.snapshot(t0=since)
+                   if s[4] == eng._thread.ident), key=lambda s: s[1])
+
+
+def test_scheduler_leaf_spans_partition_its_thread(net):
+    """The property the per-layer metrics rest on: over a stretch in
+    which the engine runs, its thread is in exactly one leaf phase at
+    any moment, and the counters say what the spans say."""
+    since = time.perf_counter()
+    seen = []
+    eng = DecodeEngine(net, n_slots=2, max_len=32, prompt_buckets=(8,),
+                       decode_chunk=4)
+    try:
+        reqs = [eng.submit(p, 9, on_token=lambda c, t, e: seen.append(c))
+                for p in _prompts(5, 5, seed=11)]
+        for r in reqs:
+            assert r.result(timeout=120.0).shape == (9,)
+    finally:
+        eng.shutdown()
+    spans = _scheduler_spans(eng, since)
+    assert {s[0] for s in spans} <= set(obs.LEAF_PHASES)
+    assert {"admit", "housekeeping", "prefill.dispatch", "prefill.wait",
+            "prefill.deliver", "decode.dispatch", "decode.wait",
+            "decode.deliver"} <= {s[0] for s in spans}
+    gaps = 0.0
+    for a, b in zip(spans, spans[1:]):
+        assert b[1] >= a[2], f"{a} overlaps {b}"
+        gaps += b[1] - a[2]
+        assert b[3] >= a[3]  # an iteration's spans lie together
+    assert gaps <= 0.01 * (spans[-1][2] - spans[0][1])
+    loop = eng.stats()["loop"]
+    for phase in obs.LEAF_PHASES:
+        mine = [s for s in spans if s[0] == phase]
+        assert loop[phase + "_n"] == len(mine)
+        assert loop[phase + "_s"] == pytest.approx(
+            sum(s[2] - s[1] for s in mine), abs=1e-3)
+    assert loop["iterations"] == max(s[3] for s in spans)
+    assert loop["spans_dropped"] == obs.TIMELINE.dropped
+    assert loop["sink_n"] == len(seen) == 5 * 9 and loop["sink_s"] > 0.0
+    # a dispatch names its program, and a prefill the request it serves
+    ids = {r.trace.trace_id for r in reqs}
+    prefills = [s for s in spans if s[0] == "prefill.dispatch"]
+    assert {s[5]["trace_id"] for s in prefills} == ids
+    assert {s[5]["program"] for s in prefills} == {"prefill"}
+    decodes = [s for s in spans if s[0] == "decode.dispatch"]
+    assert {s[5]["program"] for s in decodes} <= {"decode_chunked",
+                                                  "decode_step"}
+    assert all(1 <= s[5]["active"] <= 2 and s[5]["chunk"] in (1, 4)
+               for s in decodes)
+    # within an iteration: dispatch, then wait, then deliver
+    by_cause = {}
+    for s in spans:
+        by_cause.setdefault(s[3], []).append(s[0])
+    for names in by_cause.values():
+        d = [n for n in names if n.startswith("decode.")]
+        assert d in ([], ["decode.dispatch", "decode.wait",
+                          "decode.deliver"])
+
+
+def test_request_carries_one_decode_span_however_many_dispatches(net):
+    eng = DecodeEngine(net, n_slots=2, max_len=32, prompt_buckets=(8,),
+                       decode_chunk=1)
+    try:
+        req = eng.submit(_prompts(1, 5, seed=5)[0], 12)
+        req.result(timeout=120.0)
+    finally:
+        eng.shutdown()
+    decode = [s for s in req.trace.to_dict()["spans"]
+              if s["name"] == "decode"]
+    assert len(decode) == 1
+    assert decode[0]["attrs"] == {"steps": 11, "dispatches": 11}
+    assert decode[0]["t1"] > decode[0]["t0"]
+
+
+def test_kill_switch_stops_the_timeline_and_not_the_loop_counters(
+        net, monkeypatch):
+    monkeypatch.setenv("DL4J_TPU_NO_TRACING", "1")
+    since = time.perf_counter()
+    eng = DecodeEngine(net, n_slots=2, max_len=32, prompt_buckets=(8,))
+    try:
+        assert eng.submit(_prompts(1, 5, seed=6)[0], 6) \
+            .result(timeout=120.0).shape == (6,)
+    finally:
+        eng.shutdown()
+    assert _scheduler_spans(eng, since) == []
+    st = eng.stats()
+    assert st["loop"]["iterations"] >= 1
+    assert st["loop"]["decode.dispatch_n"] >= 1
+    assert st["loop"]["decode.wait_s"] > 0.0
+    assert st["admitted"] == 1 and st["queue_wait_s"] > 0.0
+
+
+def test_queue_wait_sums_are_the_requests_own_spans(net):
+    eng = DecodeEngine(net, n_slots=1, max_len=32, prompt_buckets=(8,))
+    try:
+        reqs = [eng.submit(p, 4) for p in _prompts(4, 5, seed=8)]
+        for r in reqs:
+            r.result(timeout=120.0)
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    waits = [s["t1"] - s["t0"] for r in reqs
+             for s in r.trace.to_dict()["spans"] if s["name"] == "queue-wait"]
+    assert st["admitted"] == len(waits) == 4
+    assert st["queue_wait_s"] == pytest.approx(sum(waits), abs=1e-6)
+    # one slot: the later requests really waited for it
+    assert max(waits) > 10 * min(waits)
+
+
+def test_loop_and_front_keys_in_contract_and_exposition(net):
+    assert {"loop", "queue_wait_s", "admitted"} \
+        <= obs.DECODE_ENGINE_STATS_KEYS
+    eng = DecodeEngine(net, n_slots=2, max_len=32, prompt_buckets=(8,))
+    try:
+        eng.submit(_prompts(1, 5, seed=9)[0], 3).result(timeout=120.0)
+        loop = eng.metrics_snapshot()["components"]["decode_engine"]["loop"]
+        assert set(loop) == {"iterations", "sink_s", "sink_n",
+                             "spans_dropped"} \
+            | {p + sfx for p in obs.LEAF_PHASES for sfx in ("_s", "_n")}
+        text = eng.metrics_text()
+    finally:
+        eng.shutdown()
+    assert "dl4j_stats_decode_engine_admitted 1" in text
+    assert "dl4j_stats_decode_engine_queue_wait_s " in text
+    assert "dl4j_stats_decode_engine_loop_iterations " in text
+    assert "dl4j_stats_decode_engine_loop_decode_deliver_s " in text
+    assert "dl4j_stats_decode_engine_loop_wait_work_n " in text
