@@ -14,7 +14,9 @@ repo supports — `gpt_configuration(vocab 256, d_model 1024, 8 heads of
   128-token prompts plus one long prompt that rides chunked prefill,
   donated KV pools; then the same prompts through a gather-path engine
   (token agreement) and a short int8-KV engine. Paged kernel engaged for
-  the decode, chunk and int8 shape classes.
+  the decode, chunk and int8 shape classes, the in-place KV write for
+  the bf16 and int8 pools, and the optimised `decode_step` /
+  `decode_chunked` programs hold no copy or layout change of a pool.
 - **lstm**: `lstm_large` (H=1024, T=64, B=2048) with the fused cell.
 - **multichip** (>= 4 devices): the train step through `ParallelWrapper`
   on a {data 2, model 2} mesh against the one-chip loss, tp=4 decode
@@ -39,6 +41,7 @@ import collections
 import gc
 import json
 import os
+import re
 import shutil
 import sys
 import threading
@@ -331,6 +334,44 @@ def _agreement(net, prompts, got, ref, who: str) -> dict:
             "tie_margins_nats": margins}
 
 
+_HLO_DTYPES = {"bfloat16": "bf16", "float32": "f32", "int8": "s8"}
+_LAYOUT_OPCODES = ("copy", "copy-start", "transpose")
+
+
+def pool_layout_copies(hlo_text: str, pool_shapes) -> int:
+    """Instructions of an optimised HLO module whose result has a KV
+    pool's shape (`pool_shapes`: HLO strings such as
+    "bf16[137,16,128,128]") and whose opcode copies it or changes its
+    layout. A decode program that writes its donated pools in place has
+    none; each one is a whole pool read and written per step."""
+    line = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.+?) ([\w\-]+)\(")
+    n = 0
+    for text in hlo_text.splitlines():
+        m = line.match(text)
+        if m and m.group(2) in _LAYOUT_OPCODES \
+                and any(shape in m.group(1) for shape in pool_shapes):
+            n += 1
+    return n
+
+
+def _decode_program_copies(engine) -> dict:
+    """Compile the engine's two decode programs as its scheduler calls
+    them and count the pool copies in each (`pool_layout_copies`)."""
+    import jax
+    import jax.numpy as jnp
+
+    pools = jax.tree_util.tree_leaves(engine._caches)
+    shapes = {f"{_HLO_DTYPES[p.dtype.name]}"
+              f"[{','.join(map(str, p.shape))}]" for p in pools}
+    args = (engine._dparams, engine._caches, engine._page_table,
+            engine._tok, engine._pos, engine._keys, engine._temps,
+            jnp.asarray(engine._active))
+    return {name: pool_layout_copies(
+                fn.lower(*args).compile().as_text(), shapes)
+            for name, fn in (("decode_step", engine._decode_step),
+                             ("decode_chunked", engine._decode_chunked))}
+
+
 def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
     vocab, n_tokens = gpt["vocab_size"], shape["n_tokens"]
     net = _gpt_net(gpt, shape["max_len"])
@@ -387,6 +428,18 @@ def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
                        bool(q[0] == t[0]) for q, t in
                        zip(q_toks, (toks[0], toks[-1]))]}
 
+    # the decode programs themselves: a pool copied or re-laid-out per
+    # step is half the step's device time (PERF.md, PR 26)
+    from deeplearning4j_tpu.serving.decode_engine import DecodeEngine
+
+    engine = DecodeEngine(net, **gen)
+    try:
+        out["pool_layout_copies"] = _decode_program_copies(engine)
+    finally:
+        engine.shutdown(drain_timeout=30.0)
+    print(f"serve: pool copies in the decode programs "
+          f"{out['pool_layout_copies']}", flush=True)
+
     if kernels:
         H = gpt["n_heads"]
         hd = gpt["d_model"] // H
@@ -396,6 +449,13 @@ def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
             _check(engaged("paged_attention", lambda k: k == key),
                    f"paged kernel did not engage for shape class {key}")
         out["paged_classes"] = len(engaged("paged_attention"))
+        for key in (("bfloat16", H, hd, shape["page_size"], "dense"),
+                    ("int8", H, hd, shape["page_size"], "int8")):
+            _check(engaged("paged_kv_write", lambda k: k == key),
+                   f"in-place KV write did not engage for {key}")
+        _check(not any(out["pool_layout_copies"].values()),
+               f"the decode programs copy their KV pools: "
+               f"{out['pool_layout_copies']}")
     return out
 
 

@@ -10,7 +10,8 @@ precision-pinned dot_general, out-of-trace probe execution, and the
 process-wide verdict table (`kernel_verdicts`).
 
 Dispatch contract (every kernel family — `pallas_attention`,
-`pallas_lstm`, `pallas_paged_attention` — holds all five):
+`pallas_lstm`, `pallas_paged_attention`, `pallas_paged_kv_write` — holds
+all five):
 
 1. **Same signature, same semantics** as the XLA path it replaces; the
    XLA path stays in-tree as the portable reference numerics.

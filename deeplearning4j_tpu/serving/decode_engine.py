@@ -19,7 +19,15 @@ Agrawal et al., 2024):
   V `(P, Hkv, page, hd)` — the r4 decode layouts with the length axis
   cut into fixed-size pow-2 pages. Page 0 is a reserved trash page that
   absorbs masked writes from inactive slots; every other page is
-  allocated to exactly one request at a time. A per-slot **page table**
+  allocated to exactly one request at a time. The decode step's one
+  new position per slot reaches the pools through `_write_token`: on
+  TPU the `paged_kv_write` kernel (`ops/pallas_paged_kv_write.py`)
+  rewrites only the tile that holds the position, pools aliased
+  input→output, so neither `decode_step` nor the `decode_chunked` scan
+  copies a pool; on CPU, under `DL4J_TPU_NO_PALLAS_PAGED_KV_WRITE`, or
+  where the family's probe declined, the XLA scatter runs (same pools
+  bit for bit outside the trash page; on TPU it costs two whole-pool
+  layout copies per pool per step). A per-slot **page table**
   `(S, n_pages_max)` lives on device; attention dispatches through
   `ops.attention.paged_attention_step_auto` — on TPU the Pallas
   paged-attention kernel (`ops/pallas_paged_attention.py`) walks the
@@ -325,6 +333,32 @@ def _write_pages(kp_, vp_, kcol, vrow, wpids, woff, page):
         vp_ = jax.lax.dynamic_update_slice(
             vp_, vrow[:, :, nfull * page:, :], (wpids[nfull], z, woff, z))
     return kp_, vp_
+
+
+def _write_token(cache, k, v, pids, loff, scales=None):
+    """Write ONE decode position per slot into a block's pools: `k`/`v`
+    (S, Hkv, hd) land at in-page offset `loff[s]` of pool page
+    `pids[s]` (inactive lanes arrive redirected to trash page 0).
+    `cache` is the block's (K, V) pools, or (K, V, K-scale, V-scale)
+    for int8 KV with `scales` = the (S, Hkv) per-head scale pair;
+    returns the same tuple, written. On TPU the `paged_kv_write`
+    kernel family updates the donated pools in place
+    (`ops/pallas_paged_kv_write.py`); on CPU, under
+    `DL4J_TPU_NO_PALLAS_PAGED_KV_WRITE`, or where the family's probe
+    declined, the XLA scatter runs — bit-identical on every page but
+    the trash page, at two whole-pool layout copies per pool per step
+    on the TPU. Module level so the speculative draft and verifier
+    write their pools the same way."""
+    from deeplearning4j_tpu.ops.pallas_paged_kv_write import (
+        paged_kv_write_or_none,
+        scatter_kv_write,
+    )
+
+    args = (*cache[:2], k, v, pids, loff, *cache[2:], *(scales or ()))
+    out = paged_kv_write_or_none(*args)
+    if out is None:
+        out = scatter_kv_write(*args)
+    return out[:len(cache)]
 
 
 def _dispatched(thunk):
@@ -828,10 +862,12 @@ class DecodeEngine:
         max_queued_pages = self._requested_max_queued_pages
         if max_queued_pages is None:
             max_queued_pages = 4 * pool_pages
-        # buffer donation keeps the page pools in place in HBM instead
-        # of copying ~pool_pages*page*layers of KV every step; CPU (the
-        # test backend) does not support donation and would warn once
-        # per dispatch
+        # buffer donation lets the steps write the page pools where they
+        # lie in HBM; it is necessary, not sufficient: the decode write
+        # must also keep the pools' layout (`_write_token`), or XLA
+        # copies ~pool_pages*page*layers of KV twice a step around it.
+        # CPU (the test backend) does not support donation and would
+        # warn once per dispatch
         donate = jax.default_backend() != "cpu"
         self._donate = donate
 
@@ -945,18 +981,14 @@ class DecodeEngine:
                         # write per head; the scale lands at the SAME
                         # (page, head, offset) the payload does, so
                         # trash-page redirection masks both together
-                        kp_, vp_, ks_, vs_ = caches[bi]
                         kq, ksc = quantize_heads(k)
                         vq, vsc = quantize_heads(v)
-                        kp_ = kp_.at[pids, :, :, loff].set(kq)
-                        vp_ = vp_.at[pids, :, loff, :].set(vq)
-                        ks_ = ks_.at[pids, :, loff].set(ksc)
-                        vs_ = vs_.at[pids, :, loff].set(vsc)
+                        kp_, vp_, ks_, vs_ = _write_token(
+                            caches[bi], kq, vq, pids, loff, (ksc, vsc))
                     else:
-                        kp_, vp_ = caches[bi]
                         ks_ = vs_ = None
-                        kp_ = kp_.at[pids, :, :, loff].set(k)
-                        vp_ = vp_.at[pids, :, loff, :].set(v)
+                        kp_, vp_ = _write_token(caches[bi], k, v, pids,
+                                                loff)
                 # kernel-dispatched paged attention: on TPU the Pallas
                 # kernel streams pages straight from the pool (no dense
                 # gather transient — the decode path's dominant cache-

@@ -106,7 +106,10 @@ class SpeculativeDecoder:
             paged_attention_chunk_auto,
             paged_attention_step_auto,
         )
-        from deeplearning4j_tpu.serving.decode_engine import _write_pages
+        from deeplearning4j_tpu.serving.decode_engine import (
+            _write_pages,
+            _write_token,
+        )
         from deeplearning4j_tpu.serving.quantize import (
             _write_scale_pages,
             quantize_heads,
@@ -290,18 +293,14 @@ class SpeculativeDecoder:
                                              p_j[:, None], shard=tp_shard)
                     q, kh, vh = q[:, 0], kh[:, 0], vh[:, 0]
                     if kv_quant:
-                        kp_, vp_, ks_, vs_ = caches[bi]
                         kq, ksc = quantize_heads(kh)
                         vq, vsc = quantize_heads(vh)
-                        kp_ = kp_.at[pids, :, :, loff].set(kq)
-                        vp_ = vp_.at[pids, :, loff, :].set(vq)
-                        ks_ = ks_.at[pids, :, loff].set(ksc)
-                        vs_ = vs_.at[pids, :, loff].set(vsc)
+                        kp_, vp_, ks_, vs_ = _write_token(
+                            caches[bi], kq, vq, pids, loff, (ksc, vsc))
                     else:
-                        kp_, vp_ = caches[bi]
                         ks_ = vs_ = None
-                        kp_ = kp_.at[pids, :, :, loff].set(kh)
-                        vp_ = vp_.at[pids, :, loff, :].set(vh)
+                        kp_, vp_ = _write_token(caches[bi], kh, vh, pids,
+                                                loff)
                     att = paged_attention_step_auto(q, kp_, vp_,
                                                     page_table, p_j,
                                                     active,
@@ -347,11 +346,7 @@ class SpeculativeDecoder:
                 p = bp[i]
                 layer = tplan.layers[i]
                 q, kh, vh = _block_heads(layer, p, x, qpos, shard=tp_shard)
-                if kv_quant:
-                    kp_, vp_, ks_, vs_ = caches[bi]
-                else:
-                    kp_, vp_ = caches[bi]
-                    ks_ = vs_ = None
+                cache = caches[bi]
                 for j in range(C):
                     p_j = pos + j
                     wpos = jnp.minimum(p_j, L_logical - 1)
@@ -362,13 +357,13 @@ class SpeculativeDecoder:
                     if kv_quant:
                         kq, ksc = quantize_heads(kh[:, j])
                         vq, vsc = quantize_heads(vh[:, j])
-                        kp_ = kp_.at[pids, :, :, loff].set(kq)
-                        vp_ = vp_.at[pids, :, loff, :].set(vq)
-                        ks_ = ks_.at[pids, :, loff].set(ksc)
-                        vs_ = vs_.at[pids, :, loff].set(vsc)
+                        cache = _write_token(cache, kq, vq, pids, loff,
+                                             (ksc, vsc))
                     else:
-                        kp_ = kp_.at[pids, :, :, loff].set(kh[:, j])
-                        vp_ = vp_.at[pids, :, loff, :].set(vh[:, j])
+                        cache = _write_token(cache, kh[:, j], vh[:, j],
+                                             pids, loff)
+                kp_, vp_ = cache[:2]
+                ks_, vs_ = cache[2:] if kv_quant else (None, None)
                 # one (k+1)-wide paged chunk per slot: the kernel walks
                 # the page table in place; the fallback is exactly
                 # `_verify_block_attention` (gather + vmapped chunk)

@@ -49,7 +49,50 @@ def test_serve_phase_toy():
     # on the CPU both engines ARE the gather path: tokens identical
     assert out["agreement"]["common_prefix_tokens"] == [8] * 4
     assert out["agreement"]["tie_margins_nats"] == []
+    # counted on every backend, judged only where kernels dispatch
+    assert set(out["pool_layout_copies"]) == {"decode_step",
+                                              "decode_chunked"}
     json.dumps(out)  # the summary line must serialize
+
+
+# lines as XLA:TPU prints them (PR 26's parent, layouts and configs
+# kept, operand lists cut): what the count must and must not see
+_CANNED_HLO = """\
+HloModule jit_decode_chunked, is_scheduled=true, input_output_alias={ {0}: (30, {}, may-alias) }
+%fused_computation.3 (param_0.9: bf16[137,16,128,128], param_1.14: s32[32]) -> bf16[137,16,128,128] {
+  %param_0.9 = bf16[137,16,128,128]{2,1,3,0:T(8,128)(2,1)S(1)} parameter(0)
+  ROOT %scatter.6 = bf16[137,16,128,128]{2,1,3,0:T(8,128)(2,1)S(1)} scatter(%param_0.9, %custom-call.3, %transpose.99), update_window_dims={1,2}
+}
+%fused_computation.9 (param_0.755: bf16[4,8,16,128]) -> bf16[4,8,16,128] {
+  %copy.40 = bf16[4,8,16,128]{3,1,2,0:T(8,128)(2,1)} copy(%param_0.755)
+  ROOT %transpose.7 = bf16[32,16,128]{1,2,0:T(8,128)(2,1)} transpose(%copy.40), dimensions={0,2,1}
+}
+ENTRY %main.33 (caches_0__0_.1: bf16[137,16,128,128], pos.1: s32[32]) -> (bf16[137,16,128,128], s32[32]) {
+  %caches_0__0_.1 = bf16[137,16,128,128]{3,2,1,0:T(8,128)(2,1)} parameter(30), sharding={replicated}, metadata={op_name="caches[0][0]"}
+  %copy.42 = bf16[137,16,128,128]{2,1,3,0:T(8,128)(2,1)S(1)} copy(%caches_0__0_.1), sharding={replicated}, metadata={op_name="caches[0][0]"}
+  %fusion.3 = bf16[137,16,128,128]{2,1,3,0:T(8,128)(2,1)S(1)} fusion(%copy.42, %fusion.217), kind=kCustom, calls=%fused_computation.3, metadata={op_name="jit(decode_step)/kv.write/scatter"}
+  %copy-start.2 = (bf16[137,16,128,128]{3,1,2,0:T(8,128)(2,1)S(1)}, bf16[137,16,128,128]{3,1,2,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%fusion.3)
+  %copy-done.2 = bf16[137,16,128,128]{3,1,2,0:T(8,128)(2,1)S(1)} copy-done(%copy-start.2)
+  %transpose.1 = bf16[137,16,128,128]{3,2,1,0:T(8,128)(2,1)} transpose(%copy-done.2), dimensions={0,1,3,2}
+  %copy-start.4 = (f32[2048,2048]{1,0:T(8,128)S(1)}, f32[2048,2048]{1,0:T(8,128)}, u32[]{:S(2)}) copy-start(%params_1___Wo__.1)
+  %kv.attend.2 = bf16[32,1,16,128]{3,2,1,0:T(8,128)(2,1)} custom-call(%copy.44, %pos.1, %transpose.1), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[32]{0}, bf16[137,16,128,128]{3,2,1,0}}
+  %kv.write.1 = (bf16[137,16,128,128]{3,2,1,0:T(8,128)(2,1)}, bf16[137,16,128,128]{3,2,1,0:T(8,128)(2,1)}) custom-call(%pos.1, %caches_0__0_.1), custom_call_target="tpu_custom_call", output_to_operand_aliasing={{0}: (1, {})}
+  ROOT %copy.52 = bf16[137,16,128,128]{3,2,1,0:T(8,128)(2,1)} copy(%transpose.1)
+}
+"""
+
+
+def test_pool_layout_copies_counts_pool_shaped_copies_only():
+    """`copy`, `copy-start` and `transpose` of a pool's shape count,
+    `ROOT` or not, tuple-typed or not; the scatter, the kernels, the
+    fusion that wraps them, `copy-done` (its `copy-start` counted) and
+    copies of anything smaller or of a weight do not."""
+    count = chip_smoke.pool_layout_copies
+    assert count(_CANNED_HLO, {"bf16[137,16,128,128]"}) == 4
+    assert count(_CANNED_HLO, {"bf16[4,8,16,128]"}) == 1
+    assert count(_CANNED_HLO, {"s8[137,16,128,128]", "f32[137,16,128]"}) == 0
+    assert count(_CANNED_HLO, {"f32[2048,2048]"}) == 1  # a weight's prefetch
+    assert count("", {"bf16[137,16,128,128]"}) == 0
 
 
 def test_multichip_phase_toy_on_the_virtual_mesh():
