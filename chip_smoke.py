@@ -17,6 +17,14 @@ repo supports — `gpt_configuration(vocab 256, d_model 1024, 8 heads of
   the decode, chunk and int8 shape classes, the in-place KV write for
   the bf16 and int8 pools, and the optimised `decode_step` /
   `decode_chunked` programs hold no copy or layout change of a pool.
+- **hybrid**: two composed blocks at granite-4.0-h-small's published
+  widths (one Mamba-2, one position-free grouped-query attention, each
+  with 8 of 72 top-10 dropless experts held plus the shared expert)
+  through `DecodeEngine`: bucketed and chunked prefill, the fused decode
+  scan, recurrent state beside paged K/V; tokens against an engine on
+  the XLA expert products; the grouped-expert, paged-attention and
+  KV-write kernels engaged at its shape classes; no state- or
+  pool-shaped copy in the decode programs.
 - **lstm**: `lstm_large` (H=1024, T=64, B=2048) with the fused cell.
 - **multichip** (>= 4 devices): the train step through `ParallelWrapper`
   on a {data 2, model 2} mesh against the one-chip loss, tp=4 decode
@@ -33,7 +41,8 @@ ending `"claim": null`); the last line is the verdict the driver reads,
 with exactly those keys. A failed phase prints the same line with
 `"ok": false` and re-raises.
 
-`python3 chip_smoke.py train lstm` runs only the named phases.
+`python3 chip_smoke.py train lstm` runs only the named phases
+(`train serve hybrid lstm multichip`).
 """
 from __future__ import annotations
 
@@ -56,6 +65,19 @@ TRAIN = dict(T=4096, batch=8, block=512, steps=3)
 SERVE = dict(n_slots=8, max_len=4224, page_size=128, prefill_chunk=256,
              n_short=6, short_len=128, long_len=2304, n_tokens=64,
              int8_tokens=16)
+HYBRID = dict(vocab_size=256, d_model=4096, layer_types=("mamba", "attention"),
+              n_heads=32, n_kv_heads=8, attention_multiplier=0.0078125,
+              mamba_heads=128, mamba_head_dim=64, mamba_state=128,
+              n_experts=72, top_k=10, expert_width=768, shared_width=1536,
+              experts_held=(0, 8), embedding_multiplier=12.0,
+              residual_multiplier=0.22, logits_scaling=16.0)
+# 64 slots, the benchmark cell's: at 8 the whole recurrent state (34 MB)
+# fits the chip's fast memory, XLA parks it there (one same-layout
+# `copy-start` a decode program) and the copy count would say nothing
+# about a deployment's 268 MB a layer
+HYBRID_SERVE = dict(n_slots=64, max_len=1024, page_size=128,
+                    prefill_chunk=256, n_short=5, short_len=100,
+                    long_len=600, n_tokens=24)
 LSTM = dict(vocab=256, hidden=1024, T=64, batch=2048, steps=2)
 
 # A greedy token may differ between two correct attention paths only
@@ -356,11 +378,17 @@ def pool_layout_copies(hlo_text: str, pool_shapes) -> int:
 
 def _decode_program_copies(engine) -> dict:
     """Compile the engine's two decode programs as its scheduler calls
-    them and count the pool copies in each (`pool_layout_copies`)."""
+    them and count in each the copies of what the blocks keep between
+    tokens (`pool_layout_copies`): K/V pools, their scale pools, and a
+    recurrent block's state. Its convolution tail is left out: 3 taps
+    a channel, 0.1% of the state's bytes, and XLA moves it to fast
+    memory and back each step by choice (same layout, `S(1)`)."""
     import jax
     import jax.numpy as jnp
 
-    pools = jax.tree_util.tree_leaves(engine._caches)
+    pools = jax.tree_util.tree_leaves(
+        [c[:1] if st.kind == "recurrent" else c
+         for st, c in zip(engine._states, engine._caches)])
     shapes = {f"{_HLO_DTYPES[p.dtype.name]}"
               f"[{','.join(map(str, p.shape))}]" for p in pools}
     args = (engine._dparams, engine._caches, engine._page_table,
@@ -455,6 +483,92 @@ def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
                    f"in-place KV write did not engage for {key}")
         _check(not any(out["pool_layout_copies"].values()),
                f"the decode programs copy their KV pools: "
+               f"{out['pool_layout_copies']}")
+    return out
+
+
+def _hybrid_net(hyb: dict, dtype):
+    """Composed blocks from `hybrid_moe_configuration`, `init()`'s own
+    weights, the embedding scaled down so that a token's own logit does
+    not decide every step (tied head, multiplier 12)."""
+    from deeplearning4j_tpu.models.transformer import (
+        hybrid_moe_configuration,
+    )
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+    hyb = dict(hyb)
+    net = MultiLayerNetwork(hybrid_moe_configuration(
+        hyb.pop("vocab_size"), hyb.pop("d_model"), hyb.pop("layer_types"),
+        **hyb), dtype=dtype)
+    net.init()
+    net._params[0]["W"] = net._params[0]["W"] * 0.1
+    return net
+
+
+def phase_hybrid(hyb: dict, shape: dict, *, kernels: bool,
+                 dtype=None) -> dict:
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.serving.decode_engine import DecodeEngine
+
+    vocab, n_tokens = hyb["vocab_size"], shape["n_tokens"]
+    net = _hybrid_net(hyb, dtype or jnp.bfloat16)
+    prompts = _serve_prompts(vocab, shape)
+    gen = _engine_kwargs(shape)
+    toks, stats = _through_engine(net, prompts, n_tokens, **gen)
+    _check_tokens(toks, n_tokens, vocab, stats, len(prompts), "hybrid")
+    n_chunks = -(-shape["long_len"] // shape["prefill_chunk"])
+    _check(stats["prefill_chunks"] >= n_chunks,
+           f"long prompt did not ride chunked prefill: "
+           f"{stats['prefill_chunks']} chunks < {n_chunks}")
+    _check(stats["state_resets"] == len(prompts),
+           f"{stats['state_resets']} slot states reset for "
+           f"{len(prompts)} admissions")
+    share = stats["moe_held_choices"] / max(1, stats["moe_routed"])
+    held = hyb["experts_held"][1] / hyb["n_experts"]
+    _check(0.5 * held < share < 2.0 * held,
+           f"{share:.3f} of the router's choices fell on the "
+           f"{held:.3f} of the experts held")
+    out = {"requests": len(prompts), "tokens": int(sum(map(len, toks))),
+           "prefill_chunks": stats["prefill_chunks"],
+           "decode_steps": stats["decode_steps"],
+           "state_bytes_per_slot": stats["state_bytes_per_slot"],
+           "held_share_of_choices": round(share, 4)}
+    gc.collect()
+
+    # the same prompts with the experts as XLA batched products
+    os.environ["DL4J_TPU_NO_PALLAS_MOE_EXPERTS"] = "1"
+    try:
+        ref, ref_stats = _through_engine(net, prompts, n_tokens, **gen)
+    finally:
+        del os.environ["DL4J_TPU_NO_PALLAS_MOE_EXPERTS"]
+    _check_tokens(ref, n_tokens, vocab, ref_stats, len(prompts), "xla-moe")
+    out["agreement"] = _agreement(net, prompts, toks, ref,
+                                  "kernel and XLA expert products")
+    gc.collect()
+
+    engine = DecodeEngine(net, **gen)
+    try:
+        out["pool_layout_copies"] = _decode_program_copies(engine)
+    finally:
+        engine.shutdown(drain_timeout=30.0)
+    print(f"hybrid: state and pool copies in the decode programs "
+          f"{out['pool_layout_copies']}", flush=True)
+    if kernels:
+        d, f = hyb["d_model"], hyb["expert_width"]
+        for rows in (shape["n_slots"], shape["prefill_chunk"]):
+            key = ("bfloat16", rows, d, f)
+            _check(engaged("moe_experts", lambda k: k == key),
+                   f"grouped expert kernel did not engage for {key}")
+        H, Hkv = hyb["n_heads"], hyb["n_kv_heads"]
+        key = ("bfloat16", 1, H, Hkv, d // H, shape["page_size"], "dense")
+        _check(engaged("paged_attention", lambda k: k == key),
+               f"paged kernel did not engage for shape class {key}")
+        key = ("bfloat16", Hkv, d // H, shape["page_size"], "dense")
+        _check(engaged("paged_kv_write", lambda k: k == key),
+               f"in-place KV write did not engage for {key}")
+        _check(not any(out["pool_layout_copies"].values()),
+               f"the decode programs copy their state or pools: "
                f"{out['pool_layout_copies']}")
     return out
 
@@ -558,10 +672,10 @@ def main(argv=None) -> int:
     from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
 
     names = list(sys.argv[1:] if argv is None else argv) \
-        or ["train", "serve", "lstm", "multichip"]
-    unknown = set(names) - {"train", "serve", "lstm", "multichip"}
+        or ["train", "serve", "hybrid", "lstm", "multichip"]
+    unknown = set(names) - {"train", "serve", "hybrid", "lstm", "multichip"}
     if unknown or ("multichip" in names and "train" not in names):
-        print(f"chip_smoke: phases are train serve lstm multichip "
+        print(f"chip_smoke: phases are train serve hybrid lstm multichip "
               f"(multichip compares against train's loss, so name both); "
               f"got {names}", file=sys.stderr)
         return 2
@@ -598,6 +712,8 @@ def main(argv=None) -> int:
             run("train", phase_train, GPT, TRAIN, kernels=True)
         if "serve" in names:
             run("serve", phase_serve, GPT, SERVE, kernels=True)
+        if "hybrid" in names:
+            run("hybrid", phase_hybrid, HYBRID, HYBRID_SERVE, kernels=True)
         if "lstm" in names:
             run("lstm", phase_lstm, LSTM, kernels=True)
         if "multichip" in names:

@@ -26,9 +26,18 @@ from deeplearning4j_tpu.nn.conf import (
     MultiLayerConfiguration,
     NeuralNetConfiguration,
 )
+from deeplearning4j_tpu.nn.conf.decoder_block import (
+    AttentionMixer,
+    DecoderBlock,
+    Mamba2Mixer,
+    MoEFeedForward,
+    RMSNorm,
+)
 from deeplearning4j_tpu.nn.conf.layers import (
     LayerNormalization,
+    RMSNormalization,
     RnnOutputLayer,
+    TiedRnnOutputLayer,
     TokenEmbedding,
     TransformerBlock,
 )
@@ -90,6 +99,69 @@ def gpt_configuration(vocab_size: int,
             .build())
 
 
+def hybrid_moe_configuration(vocab_size: int, d_model: int,
+                             layer_types, *,
+                             n_heads: int, n_kv_heads: int,
+                             attention_multiplier: float = None,
+                             mamba_heads: int, mamba_head_dim: int,
+                             mamba_state: int, mamba_conv: int = 4,
+                             mamba_chunk: int = 256,
+                             n_experts: int, top_k: int,
+                             expert_width: int, shared_width: int = 0,
+                             experts_held=None,
+                             embedding_multiplier: float = 1.0,
+                             residual_multiplier: float = 1.0,
+                             logits_scaling: float = 1.0,
+                             eps: float = 1e-5, seed: int = 12345,
+                             learning_rate: float = 3e-4,
+                             updater: Updater = Updater.ADAM,
+                             ) -> MultiLayerConfiguration:
+    """Causal LM of composed `DecoderBlock`s, one per entry of
+    `layer_types` ("mamba": a Mamba-2 mixer, "attention": grouped-query
+    attention without positions), each followed by top-k dropless routed
+    experts plus a shared expert, under RMSNorm; the token embedding is
+    scaled by `embedding_multiplier`, both residual branches by
+    `residual_multiplier`, and the output head is the embedding,
+    transposed, over `logits_scaling` (the Hugging Face
+    `granitemoehybrid` family's layout). `experts_held = (first,
+    count)`: the share of each layer's experts this network holds."""
+    b = (NeuralNetConfiguration.Builder()
+         .seed(seed)
+         .learning_rate(learning_rate)
+         .updater(updater)
+         .drop_out(0.0)
+         .list()
+         .layer(TokenEmbedding(n_in=vocab_size, n_out=d_model,
+                               positional=False,
+                               multiplier=embedding_multiplier)))
+    ffn = MoEFeedForward(n_experts=n_experts, top_k=top_k,
+                         expert_width=expert_width,
+                         shared_width=shared_width,
+                         experts_held=experts_held)
+    mixers = {
+        "mamba": Mamba2Mixer(n_heads=mamba_heads, head_dim=mamba_head_dim,
+                             d_state=mamba_state, d_conv=mamba_conv,
+                             chunk=mamba_chunk, eps=eps),
+        "attention": AttentionMixer(n_heads=n_heads, n_kv_heads=n_kv_heads,
+                                    scale=attention_multiplier)}
+    for kind in layer_types:
+        b = b.layer(DecoderBlock(n_in=d_model, n_out=d_model,
+                                 mixer=mixers[kind], ffn=ffn,
+                                 norm=RMSNorm(eps=eps),
+                                 residual_multiplier=residual_multiplier))
+    return (b
+            .layer(RMSNormalization(n_in=d_model, n_out=d_model, eps=eps,
+                                    dropout=0.0))
+            .layer(TiedRnnOutputLayer(n_in=d_model, n_out=vocab_size,
+                                      tied_to=0,
+                                      logits_scaling=logits_scaling,
+                                      activation=Activation.SOFTMAX,
+                                      loss=LossFunction.MCXENT,
+                                      dropout=0.0))
+            .set_input_type(InputType.recurrent(vocab_size))
+            .build())
+
+
 # ---------------------------------------------------------------------------
 # shared decode plan + per-block compute (generate() AND the serving
 # decode engine trace through these — one implementation of the numerics)
@@ -114,13 +186,28 @@ class GPTPlan:
         self.emb_i = 0
         self.emb = layers[0]
         self.block_is = [i for i, l in enumerate(layers)
-                        if isinstance(l, TransformerBlock)]
+                        if isinstance(l, (TransformerBlock, DecoderBlock))]
         self.ln_is = [i for i, l in enumerate(layers)
-                      if isinstance(l, LayerNormalization)]
+                      if isinstance(l, (LayerNormalization,
+                                        RMSNormalization))]
         self.out_i = next(i for i, l in enumerate(layers)
                           if isinstance(l, RnnOutputLayer))
         self.dtype = net.dtype
         self.cdt = net.compute_dtype or net.dtype
+
+    def state_kinds(self):
+        """Per block, the cache state a decode engine keeps for it:
+        "kv" (paged key/value pools) or "recurrent" (per-slot arrays).
+        A `TransformerBlock` keeps K/V; a composed `DecoderBlock` keeps
+        what its mixer kind declares."""
+        return ["kv" if isinstance(self.layers[i], TransformerBlock)
+                else self.layers[i].mixer.state for i in self.block_is]
+
+    @property
+    def composed(self) -> bool:
+        """Whether any block is a composed `DecoderBlock`."""
+        return any(isinstance(self.layers[i], DecoderBlock)
+                   for i in self.block_is)
 
     def kv_geometry(self):
         """Per-block (Hkv, head_dim) pairs — the KV-cache geometry the
@@ -132,7 +219,10 @@ class GPTPlan:
         out = []
         for i in self.block_is:
             layer = self.layers[i]
-            out.append((layer._kv_heads, layer.n_out // layer.n_heads))
+            if isinstance(layer, TransformerBlock):
+                out.append((layer._kv_heads, layer.n_out // layer.n_heads))
+            elif layer.mixer.state == "kv":
+                out.append(layer.mixer.kv_geometry(layer._d))
         return out
 
     def cast_blocks(self, params):
@@ -157,14 +247,14 @@ class GPTPlan:
         for the loss head)."""
         import jax
 
-        from deeplearning4j_tpu.nn.conf.layers import layer_norm
-
         with jax.named_scope("head"):
             for i in self.ln_is:
                 if i > max(self.block_is, default=-1):
-                    x = layer_norm(x, bp[i]["gamma"], bp[i]["beta"],
-                                   self.layers[i].eps)
+                    x = self.layers[i].forward(bp[i], None, x)[0]
             x = x.astype(self.dtype)
+            head = self.layers[self.out_i]
+            if isinstance(head, TiedRnnOutputLayer):
+                return head.pre_output(params[head.tied_to], x)
             return x @ params[self.out_i]["W"] + params[self.out_i]["b"]
 
 
@@ -383,6 +473,11 @@ def generate(net, prompt_ids, n_tokens: int, temperature: float = 1.0,
     import numpy as np
 
     plan = GPTPlan(net)
+    if plan.composed:
+        raise ValueError(
+            "generate() runs TransformerBlock networks; a network of "
+            "composed DecoderBlocks generates through "
+            "serving.decode_engine.DecodeEngine / ModelServer.generate")
     layers = plan.layers
     emb_i, block_is = plan.emb_i, plan.block_is
     emb = plan.emb

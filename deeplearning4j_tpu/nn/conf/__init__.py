@@ -17,9 +17,18 @@ from deeplearning4j_tpu.nn.conf.layers import (  # noqa: F401
     LossLayer,
     OutputLayer,
     RBM,
+    RMSNormalization,
     RnnOutputLayer,
     SelfAttention,
     SubsamplingLayer,
+    TiedRnnOutputLayer,
+)
+from deeplearning4j_tpu.nn.conf.decoder_block import (  # noqa: F401
+    AttentionMixer,
+    DecoderBlock,
+    Mamba2Mixer,
+    MoEFeedForward,
+    RMSNorm,
 )
 from deeplearning4j_tpu.nn.conf.variational import (  # noqa: F401
     BernoulliReconstructionDistribution,

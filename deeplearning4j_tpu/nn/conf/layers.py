@@ -345,6 +345,33 @@ class RnnOutputLayer(OutputLayer):
 
 @register_layer
 @dataclass
+class TiedRnnOutputLayer(RnnOutputLayer):
+    """Per-timestep output head tied to an embedding: logits are
+    `x E^T / logits_scaling` with `E` the `W` of layer `tied_to` (a
+    `TokenEmbedding`), so the head holds no parameters of its own and
+    the table's gradient is the sum over both of its uses. The network
+    hands this layer the tied layer's parameters
+    (`MultiLayerNetwork._params_of`). The product accumulates and comes
+    out in float32 whatever the table's dtype: a vocabulary of 1e5
+    logits in bfloat16 would tie at the top."""
+
+    TYPE = "tied_rnn_output"
+    tied_to: int = 0
+    logits_scaling: float = 1.0
+
+    @property
+    def has_params(self) -> bool:
+        return False
+
+    def pre_output(self, params, x, *, train=False, rng=None):
+        x = self._maybe_dropout(x, train, rng)
+        z = jnp.einsum("...d,vd->...v", x, params["W"],
+                       preferred_element_type=jnp.float32)
+        return z / self.logits_scaling
+
+
+@register_layer
+@dataclass
 class LossLayer(Layer):
     """Parameter-free loss head (reference `nn/conf/layers/LossLayer.java`)."""
 
@@ -1195,6 +1222,37 @@ def layer_norm(x, gamma, beta, eps=1e-5):
     return out.astype(x.dtype)
 
 
+def rms_norm(x, w, eps: float = 1e-5):
+    """x / sqrt(mean(x^2) + eps) * w, statistics in float32."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+@register_layer
+@dataclass
+class RMSNormalization(FeedForwardLayer):
+    """Root-mean-square normalization over the feature axis, one gain
+    and no bias (`rms_norm`, which the composed block's norm kind
+    shares)."""
+
+    TYPE = "rms_norm"
+    input_kind = "rnn"
+    n_in: int = 0
+    n_out: int = 0
+    eps: float = 1e-5
+
+    def output_type(self, it: InputType) -> InputType:
+        return it
+
+    def init_params(self, key, it, dtype=jnp.float32) -> Params:
+        return {"gamma": jnp.ones((self.n_out or self.n_in or it.size,),
+                                  dtype)}
+
+    def forward(self, params, state, x, *, train=False, rng=None, mask=None):
+        return rms_norm(x, params["gamma"], self.eps), state
+
+
 @register_layer
 @dataclass
 class TokenEmbedding(FeedForwardLayer):
@@ -1212,6 +1270,10 @@ class TokenEmbedding(FeedForwardLayer):
     # False: tokens only — for RoPE models, where position lives in the
     # attention rotation and a learned absolute table would fight it
     positional: bool = True
+    # the embedding is multiplied by this on the way out (Granite's
+    # `embedding_multiplier`); the table itself stays unscaled, so a
+    # tied output head reads the same matrix
+    multiplier: float = 1.0
 
     def output_type(self, it: InputType) -> InputType:
         t = it.timeseries_length if isinstance(it, InputTypeRecurrent) else -1
@@ -1240,8 +1302,15 @@ class TokenEmbedding(FeedForwardLayer):
         y = params["W"][idx]
         if self.positional:
             y = y + params["P"][:T]
-        y = self._maybe_dropout(y, train, rng)
+        y = self._maybe_dropout(self.scaled(y), train, rng)
         return y, state
+
+    def scaled(self, y):
+        """`y * multiplier`: for every path that looks the table up
+        itself (this forward, the decode engine's programs)."""
+        if self.multiplier == 1.0:
+            return y
+        return y * jnp.asarray(self.multiplier, y.dtype)
 
     def param_flags(self, name):
         # positional table: neither a bias nor weight-decayed

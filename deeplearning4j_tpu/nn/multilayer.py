@@ -223,6 +223,12 @@ class MultiLayerNetwork:
             self.init()
 
     # ------------------------------------------------------------- forward
+    def _params_of(self, params: Params, i: int):
+        """Layer i's parameters: its own, or those of the layer it is
+        tied to (`TiedRnnOutputLayer.tied_to`)."""
+        tied = getattr(self.layers[i], "tied_to", None)
+        return params[i if tied is None else tied]
+
     def _forward_pure(self, params: Params, lstate: LState, x: jnp.ndarray, *,
                       train: bool, rng: Optional[jax.Array],
                       fmask: Optional[jnp.ndarray],
@@ -240,7 +246,8 @@ class MultiLayerNetwork:
             mask = fmask if x.ndim == 3 else None
             # names the layer's operations in a profiler capture
             with jax.named_scope(f"L{i}.{type(layer).__name__}"):
-                x, new_state[i] = layer.forward(params[i], lstate[i], x,
+                x, new_state[i] = layer.forward(self._params_of(params, i),
+                                                lstate[i], x,
                                                 train=train, rng=lrng,
                                                 mask=mask)
         return x, new_state
@@ -283,7 +290,8 @@ class MultiLayerNetwork:
                 x, rng=out_rng, train=train)
         mask = lmask if lmask is not None else (fmask if x.ndim == 3 else None)
         with jax.named_scope("loss"):
-            loss = out_layer.loss_score(params_in[-1], x, labels,
+            loss = out_layer.loss_score(
+                self._params_of(params_in, len(self.layers) - 1), x, labels,
                                         train=train, rng=out_rng, mask=mask)
         loss = loss + self._reg_score(params_in)
         for term in aux_terms:  # mid-network losses (MoE load balancing)
@@ -864,7 +872,8 @@ class MultiLayerNetwork:
         if out_i in self.conf.preprocessors:
             x = self.conf.preprocessors[out_i].preprocess(x)
         mask = lm if lm is not None else (fm if x.ndim == 3 else None)
-        scores = self.layers[-1].score_array(self._params[-1], x, l,
+        scores = self.layers[-1].score_array(
+            self._params_of(self._params, len(self.layers) - 1), x, l,
                                              mask=mask)
         if add_regularization:
             scores = scores + self._reg_score(self._params)

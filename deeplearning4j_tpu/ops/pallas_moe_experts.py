@@ -1,0 +1,174 @@
+"""Pallas TPU grouped expert product: the gated FFNs of the experts a
+chip holds, summed under the router's gates.
+
+    y = sum_e gates[:, e] * (silu(x Wg[e]) * (x Wu[e])) Wd[e]
+
+`x` (N, d) tokens, `gates` (N, E) float32 (zero where the router did
+not choose expert e), `Wg`/`Wu` (E, d, f), `Wd` (E, f, d). The grid runs
+over (token tiles, experts): one grid step brings one whole expert
+(three (d, f)-sized matrices) into VMEM, while the token tile and a
+float32 accumulator stay resident, so each held expert's weights cross
+HBM once per token tile: the least a decode step can move when every
+held expert is chosen by some slot, which at 64 slots x top-10 of 72 is
+every step. Every token meets every held expert (the gate weighs the
+result), so the kernel does `E / hit` times the multiply-adds a sorted
+grouped product would: at decode sizes the weights' bytes bound it, not
+the MXU. As XLA batched einsums the same product materialises the
+(E, N, f) intermediates in HBM.
+
+Dispatch rides `ops/kernel_dispatch.py` under the family name
+`moe_experts`: the probe compiles and runs the kernel at the exact shape
+class and checks it against `parallel.experts.grouped_expert_ffn_xla`;
+`DL4J_TPU_NO_PALLAS_MOE_EXPERTS` forces the XLA products; CPU backends
+never dispatch.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.ops.kernel_dispatch import (
+    platform_supported as _kernels_dispatch,
+    probe_verdict as _probe_verdict,
+    record_decline as _record_decline,
+    vmem_limit_bytes as _vmem_limit,
+)
+
+FAMILY = "moe_experts"  # this module's row in kernel_verdicts()
+_MAX_ROWS = 512         # token rows per tile
+
+
+def _experts_kernel(x_ref, g_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref):
+    from jax.experimental import pallas as pl
+
+    e = pl.program_id(1)
+
+    @pl.when(e == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    x = x_ref[...]
+    g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+    h = g * jax.nn.sigmoid(g) * u * g_ref[0]
+    acc_ref[...] += jnp.dot(h.astype(x.dtype), wd_ref[0],
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(e == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _row_tile(n: int) -> int:
+    """Rows per token tile: all of them up to `_MAX_ROWS`, else the
+    largest multiple of 8 that divides `n` and fits; 0 if none does."""
+    if n <= _MAX_ROWS:
+        return n
+    for t in range(_MAX_ROWS, 7, -8):
+        if n % t == 0:
+            return t
+    return 0
+
+
+# jitted so that a step over many layers traces and lowers the kernel
+# once and calls it once a layer (`pallas_paged_kv_write`'s lesson)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def moe_experts(x, gates, Wg, Wu, Wd, *, interpret: bool = False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    N, d = x.shape
+    E, _, f = Wg.shape
+    tn = _row_tile(N)
+    # (E, N, 1): one expert's gate column arrives as a (tn, 1) block
+    g3 = jnp.swapaxes(gates.astype(jnp.float32), 0, 1)[..., None]
+    return pl.pallas_call(
+        _experts_kernel,
+        grid=(N // tn, E),
+        in_specs=[pl.BlockSpec((tn, d), lambda n, e: (n, 0)),
+                  pl.BlockSpec((1, tn, 1), lambda n, e: (e, n, 0)),
+                  pl.BlockSpec((1, d, f), lambda n, e: (e, 0, 0)),
+                  pl.BlockSpec((1, d, f), lambda n, e: (e, 0, 0)),
+                  pl.BlockSpec((1, f, d), lambda n, e: (e, 0, 0))],
+        out_specs=pl.BlockSpec((tn, d), lambda n, e: (n, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, d), x.dtype),
+        scratch_shapes=[pltpu.VMEM((tn, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit()),
+        interpret=interpret,
+    )(x, g3, Wg, Wu, Wd)
+
+
+def vmem_bytes_estimate(tn: int, d: int, f: int, dtype) -> int:
+    """Resident VMEM of one grid step: three expert matrices, double-
+    buffered; the token tile and the output tile, double-buffered; the
+    float32 accumulator and the two (tn, f) float32 intermediates."""
+    item = jnp.dtype(dtype).itemsize
+    return 2 * 3 * d * f * item + 4 * tn * d * item + 4 * tn * d \
+        + 3 * 4 * tn * f
+
+
+def _platform_supported() -> bool:
+    return _kernels_dispatch("DL4J_TPU_NO_PALLAS_MOE_EXPERTS")
+
+
+def _eager_probe(dtype, tn: int, d: int, f: int) -> bool:
+    """Compile and run the kernel at this shape class (two experts, one
+    of them chosen by no token) and hold it to the XLA products."""
+    import numpy as np
+
+    from deeplearning4j_tpu.parallel.experts import grouped_expert_ffn_xla
+
+    rng = np.random.default_rng(0)
+    E = 2
+    x = jnp.asarray(rng.standard_normal((tn, d)), dtype)
+    Wg, Wu = (jnp.asarray(rng.standard_normal((E, d, f)) / d ** 0.5, dtype)
+              for _ in range(2))
+    Wd = jnp.asarray(rng.standard_normal((E, f, d)) / f ** 0.5, dtype)
+    gates = jnp.asarray(np.stack([rng.random(tn), np.zeros(tn)], 1),
+                        jnp.float32)
+    got = np.asarray(moe_experts(x, gates, Wg, Wu, Wd), np.float32)
+    want = np.asarray(grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd),
+                      np.float32)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
+    err = float(np.max(np.abs(got - want))) / float(np.max(np.abs(want)))
+    if not np.isfinite(err) or err > tol:
+        raise ValueError("kernel compiled but lies %.3g of the largest "
+                         "output from the XLA products" % err)
+    return True
+
+
+def moe_experts_or_none(x, gates, Wg, Wu, Wd):
+    """Dispatch probe: the grouped product, or None when the kernel
+    cannot serve this call (CPU backend, kill switch, a dtype Mosaic
+    does not tile, widths off the 128-lane grid, VMEM overflow) or its
+    shape class failed the compile+parity probe."""
+    N, d = x.shape
+    E, _, f = Wg.shape
+    dtype = x.dtype
+    if not _platform_supported() or Wg.dtype != dtype \
+            or dtype not in (jnp.float32, jnp.bfloat16):
+        return None
+    tn = _row_tile(N)
+    key = (jnp.dtype(dtype).name, tn, d, f)
+    if not tn or tn % 8 or d % 128 or f % 128:
+        _record_decline(FAMILY, key, f"{N} rows, widths {d} x {f}: off "
+                                     "the (8, 128) tile grid")
+        return None
+    est = vmem_bytes_estimate(tn, d, f, dtype)
+    if est > _vmem_limit():
+        _record_decline(FAMILY, key,
+                        f"needs ~{est >> 20} MiB VMEM > "
+                        f"{_vmem_limit() >> 20} MiB ceiling")
+        return None
+    if not _probe_verdict(FAMILY, key, _eager_probe, (dtype, tn, d, f)):
+        return None
+    try:
+        return moe_experts(x, gates, Wg, Wu, Wd)
+    except Exception as e:  # per-shape staging failure: fall back
+        _record_decline(FAMILY, key, f"staging at {x.shape}: "
+                                     f"{type(e).__name__}: {e}")
+        return None

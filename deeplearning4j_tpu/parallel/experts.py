@@ -261,3 +261,84 @@ def switch_ffn(params, tokens: jnp.ndarray, *, act: Callable,
     if train:
         add_aux_loss(aux_weight * aux)
     return y
+
+
+# -- top-k dropless routing, with the experts held here --------------------
+# The layer is told which experts it holds, `experts_held = (first,
+# count)`: it routes over ALL `n_experts`, and computes the gated outputs
+# of its own `count` experts only. On one chip of a deployment that
+# splits a layer's experts over several, that partial sum is the chip's
+# part of the layer; what the other chips' experts would add is left out
+# (their exchange is the all-to-all above, not run here). Dropless: there
+# is no capacity, so no routing can lose a token.
+
+
+def topk_gates(logits: jnp.ndarray, top_k: int) -> jnp.ndarray:
+    """(N, E) router logits -> (N, E) float32 gates: softmax over each
+    row's `top_k` largest logits, zero elsewhere."""
+    top_v, top_i = lax.top_k(logits.astype(jnp.float32), top_k)
+    w = jax.nn.softmax(top_v, axis=-1)
+    rows = jnp.arange(logits.shape[0])[:, None]
+    return jnp.zeros(logits.shape, jnp.float32).at[rows, top_i].set(w)
+
+
+def held_gates(logits: jnp.ndarray, top_k: int, experts_held) -> jnp.ndarray:
+    """The gates of the experts held here, (N, count): a column of
+    zeros for a held expert that no token chose."""
+    first, count = experts_held
+    return topk_gates(logits, top_k)[:, first:first + count]
+
+
+def grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd):
+    """sum_e gates[:, e] * (silu(x Wg[e]) * (x Wu[e])) Wd[e] over the
+    experts held, as batched products: every token meets every held
+    expert and the gate (zero where the router did not choose it)
+    weighs the result. `x` (N, d); `gates` (N, E) float32; `Wg`, `Wu`
+    (E, d, f); `Wd` (E, f, d). Products accumulate in float32."""
+    g = jnp.einsum("nd,edf->enf", x, Wg,
+                   preferred_element_type=jnp.float32)
+    u = jnp.einsum("nd,edf->enf", x, Wu,
+                   preferred_element_type=jnp.float32)
+    h = jax.nn.silu(g) * u * jnp.swapaxes(gates, 0, 1)[..., None]
+    y = jnp.einsum("enf,efd->nd", h.astype(x.dtype), Wd,
+                   preferred_element_type=jnp.float32)
+    return y.astype(x.dtype)
+
+
+def grouped_expert_ffn(x, gates, Wg, Wu, Wd):
+    """The grouped product behind the kernel-dispatch contract: the
+    Pallas kernel of `ops/pallas_moe_experts.py` on a TPU (each held
+    expert's weights streamed through VMEM once), the batched XLA
+    products elsewhere."""
+    from deeplearning4j_tpu.ops.pallas_moe_experts import (
+        moe_experts_or_none,
+    )
+
+    out = moe_experts_or_none(x, gates, Wg, Wu, Wd)
+    return grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd) \
+        if out is None else out
+
+
+def gated_mlp(x, Wg, Wu, Wd):
+    """(silu(x Wg) * (x Wu)) Wd: the shared expert, and any gated MLP."""
+    g = jnp.dot(x, Wg, preferred_element_type=jnp.float32)
+    u = jnp.dot(x, Wu, preferred_element_type=jnp.float32)
+    return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), Wd,
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def dropless_moe(x, router, Wg, Wu, Wd, *, top_k: int, experts_held,
+                 count_mask=None):
+    """Top-k dropless routing over `router.shape[1]` experts, computed
+    for the experts held. `x` (N, d). Returns (y (N, d), counts): with
+    a `count_mask` (N,) bool, `counts` is an int32 (count,) vector, how
+    many of the masked-in tokens chose each held expert; else None."""
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
+        gates = held_gates(logits, top_k, experts_held)
+    with jax.named_scope("moe.experts"):
+        y = grouped_expert_ffn(x, gates, Wg, Wu, Wd)
+    if count_mask is None:
+        return y, None
+    chose = (gates > 0) & count_mask[:, None]
+    return y, jnp.sum(chose, axis=0).astype(jnp.int32)

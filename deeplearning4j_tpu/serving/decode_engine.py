@@ -115,6 +115,7 @@ import hashlib
 import logging
 import threading
 import time
+from types import SimpleNamespace
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
@@ -742,6 +743,16 @@ class DecodeEngine:
         self.prefix_fetch_seconds = 0.0  # guarded by: _cond
         self.prefix_exports_served = 0  # guarded by: _cond
         self.cluster_prefix_hit_tokens = 0  # guarded by: _cond
+        # routed experts and recurrent state (composed blocks): top-k
+        # choices made by active slots in decode steps, those that fell
+        # on experts held here, held experts hit (summed over blocks
+        # and steps), the steps counted, and slot states overwritten
+        # from zeros at admission
+        self.moe_routed = 0  # guarded by: _cond
+        self.moe_held_choices = 0  # guarded by: _cond
+        self.moe_experts_hit = 0  # guarded by: _cond
+        self.moe_steps = 0  # guarded by: _cond
+        self.state_resets = 0  # guarded by: _cond
         self.spec_steps = 0  # guarded by: _cond
         self.spec_proposed = 0  # guarded by: _cond
         self.spec_accepted = 0  # guarded by: _cond
@@ -807,18 +818,12 @@ class DecodeEngine:
 
         from deeplearning4j_tpu.models.transformer import (
             GPTPlan,
-            _block_ffn,
-            _block_heads,
-            _block_out_proj,
-            _prefill_block_attention,
             _sample_logits,
         )
-        from deeplearning4j_tpu.ops.attention import (
-            paged_attention_chunk_auto,
-            paged_attention_step_auto,
-        )
+        from deeplearning4j_tpu.serving import block_state
 
         plan = GPTPlan(net)
+        self._refuse_unsupported(plan)
         # tensor-parallel plan: geometry validated HERE (construction /
         # weight swap), so a bad tp config is a typed ValueError before
         # any device work; None means the single-device engine
@@ -920,6 +925,17 @@ class DecodeEngine:
         def write_pages(kp_, vp_, kcol, vrow, wpids, woff):
             return _write_pages(kp_, vp_, kcol, vrow, wpids, woff, page)
 
+        # what each block keeps between tokens, by the kind the plan
+        # declares for it (serving/block_state.py): paged K/V pools or
+        # per-slot recurrent arrays
+        states = block_state.block_states(plan, SimpleNamespace(
+            n_slots=S, page=page, pool_pages=pool_pages, cdt=cdt,
+            kv_quant=kv_quant, tp_shard=tp_shard, tp_axis=tp_axis,
+            quantize_heads=quantize_heads, write_token=_write_token,
+            write_pages=write_pages,
+            write_scale_pages=write_scale_pages))
+        n_held = block_state.moe_held(plan)
+
         # logprob returns (ROADMAP 5(c)): K > 0 makes every sampler
         # site also emit (chosen logprob, top-K logprobs, top-K ids)
         # from the UNSCALED model distribution — the values are a
@@ -957,51 +973,22 @@ class DecodeEngine:
             x = bp[emb_i]["W"][tok]
             if emb.positional:
                 x = x + bp[emb_i]["P"][jnp.minimum(pos, emb.max_length - 1)]
-            x = x.astype(cdt)
+            x = emb.scaled(x).astype(cdt)
             wpos = jnp.minimum(pos, L_logical - 1)
             lpage = wpos // page
-            loff = wpos % page
             rows = jnp.arange(S)
-            # inactive lanes write to the reserved trash page 0
-            pids = jnp.where(active, page_table[rows, lpage], 0)
+            d = SimpleNamespace(
+                page_table=page_table, pos=pos, active=active,
+                loff=wpos % page,
+                # inactive lanes write to the reserved trash page 0
+                pids=jnp.where(active, page_table[rows, lpage], 0),
+                # per-expert counts of the active slots' choices, where
+                # the net routes
+                count_mask=active if n_held else None, counts=[])
             new_caches = []
             for bi, i in enumerate(block_is):
-                p = bp[i]
-                layer = layers[i]
-                # same operand ranks as generate's decode ((S,1,d) heads,
-                # squeezed) so XLA picks the same accumulation order —
-                # argmax parity is a numerics property, not just a logic
-                # one. positions: a per-slot column vector
-                q, k, v = _block_heads(layer, p, x[:, None, :],
-                                       pos[:, None], shard=tp_shard)
-                q, k, v = q[:, 0], k[:, 0], v[:, 0]
-                with jax.named_scope("kv.write"):
-                    if kv_quant:
-                        # quantize the single-position (S, Hkv, hd)
-                        # write per head; the scale lands at the SAME
-                        # (page, head, offset) the payload does, so
-                        # trash-page redirection masks both together
-                        kq, ksc = quantize_heads(k)
-                        vq, vsc = quantize_heads(v)
-                        kp_, vp_, ks_, vs_ = _write_token(
-                            caches[bi], kq, vq, pids, loff, (ksc, vsc))
-                    else:
-                        ks_ = vs_ = None
-                        kp_, vp_ = _write_token(caches[bi], k, v, pids,
-                                                loff)
-                # kernel-dispatched paged attention: on TPU the Pallas
-                # kernel streams pages straight from the pool (no dense
-                # gather transient — the decode path's dominant cache-
-                # byte cost halves); on CPU/fallback the gather + dense
-                # step reference numerics run unchanged
-                with jax.named_scope("kv.attend"):
-                    att = paged_attention_step_auto(
-                        q, kp_, vp_, page_table, pos, active,
-                        k_scale=ks_, v_scale=vs_)
-                att = _block_out_proj(p, att, tp_axis)
-                x = _block_ffn(layer, p, x + att, axis_name=tp_axis)
-                new_caches.append((kp_, vp_, ks_, vs_) if kv_quant
-                                  else (kp_, vp_))
+                x, cache = states[bi].decode(bp[i], x, caches[bi], d)
+                new_caches.append(cache)
             logits = plan.final_logits(bp, params, x)
             with jax.named_scope("sample"):
                 nxt, new_keys = sample_slots(logits, keys, temps)
@@ -1009,10 +996,16 @@ class DecodeEngine:
             new_pos = jnp.where(active, pos + 1, pos)
             with jax.named_scope("finite-check"):
                 step_ok = logits_ok(logits, active)
+            out = (new_caches, nxt, new_pos, new_keys, step_ok)
             if K:
-                return new_caches, nxt, new_pos, new_keys, step_ok, \
-                    lp_math(logits, nxt)
-            return new_caches, nxt, new_pos, new_keys, step_ok
+                out += (lp_math(logits, nxt),)
+            if n_held:
+                # (2, held): choices that fell on each held expert,
+                # summed over blocks, and in how many blocks it was hit
+                c = jnp.stack(d.counts)
+                out += (jnp.stack([c.sum(0), (c > 0).sum(0)])
+                        .astype(jnp.int32),)
+            return out
 
         def decode_step(params, caches, page_table, tok, pos, keys, temps,
                         active):
@@ -1030,27 +1023,19 @@ class DecodeEngine:
             bp = plan.cast_blocks(params)
 
             def body(carry, _):
-                caches, tok, pos, keys = carry
-                out = step_math(bp, params, caches, page_table, tok,
-                                pos, keys, temps, active)
-                if K:
-                    caches, tok, pos, keys, step_ok, lp = out
-                    return (caches, tok, pos, keys), (tok, step_ok, lp)
-                caches, tok, pos, keys, step_ok = out
-                return (caches, tok, pos, keys), (tok, step_ok)
+                out = step_math(bp, params, *carry[:1], page_table,
+                                *carry[1:], temps, active)
+                # per-STEP outputs (chunk, S): the host attributes a
+                # poisoned step to the right iteration, so a request
+                # that completed via EOS before the bad step still
+                # succeeds
+                return out[:4], (out[1],) + out[4:]
 
-            if K:
-                (caches, tok, pos, keys), (toks, oks, lps) = jax.lax.scan(
-                    body, (caches, tok, pos, keys), None,
-                    length=self.decode_chunk)
-                return caches, tok, pos, keys, toks, oks, lps
-            (caches, tok, pos, keys), (toks, oks) = jax.lax.scan(
+            carry, per_step = jax.lax.scan(
                 body, (caches, tok, pos, keys), None,
                 length=self.decode_chunk)
-            # per-STEP flags (chunk, S): the host attributes a poisoned
-            # step to the right iteration, so a request that completed
-            # via EOS before the bad step still succeeds
-            return caches, tok, pos, keys, toks, oks
+            # caches, tok, pos, keys, then toks, oks[, lps][, counts]
+            return carry + per_step
 
         def prefill(params, caches, ids, t0, slot, wpids, tok, pos, keys,
                     temps, kp, kdec, temp):
@@ -1067,39 +1052,12 @@ class DecodeEngine:
             x = bp[emb_i]["W"][ids]
             if emb.positional:
                 x = x + bp[emb_i]["P"][:P]
-            x = x.astype(cdt)
+            x = emb.scaled(x).astype(cdt)
+            d = SimpleNamespace(wpids=wpids, t0=t0, slot=slot)
             new_caches = []
             for bi, i in enumerate(block_is):
-                p = bp[i]
-                layer = layers[i]
-                q, k, v = _block_heads(layer, p, x, jnp.arange(P),
-                                       shard=tp_shard)
-                att = _prefill_block_attention(layer, q, k, v)
-                att = _block_out_proj(p, att.reshape(1, P, -1), tp_axis)
-                x = _block_ffn(layer, p, x + att, axis_name=tp_axis)
-                kcol = jnp.transpose(k, (0, 2, 3, 1))   # (1, Hkv, hd, P)
-                vrow = jnp.transpose(v, (0, 2, 1, 3))   # (1, Hkv, P, hd)
-                z0 = jnp.zeros((), jnp.int32)
-                with jax.named_scope("kv.write"):
-                    if kv_quant:
-                        # the prompt span quantizes per (head,
-                        # position): abs-max over the hd axis of each
-                        # lane-last layout
-                        kp_, vp_, ks_, vs_ = caches[bi]
-                        kcol, kscol = quantize_heads(kcol, axis=2)
-                        vrow, vscol = quantize_heads(vrow, axis=3)
-                        ks_ = write_scale_pages(ks_, kscol, wpids, z0,
-                                                page)
-                        vs_ = write_scale_pages(vs_, vscol, wpids, z0,
-                                                page)
-                        kp_, vp_ = write_pages(kp_, vp_, kcol, vrow,
-                                               wpids, z0)
-                        new_caches.append((kp_, vp_, ks_, vs_))
-                    else:
-                        kp_, vp_ = caches[bi]
-                        kp_, vp_ = write_pages(kp_, vp_, kcol, vrow,
-                                               wpids, z0)
-                        new_caches.append((kp_, vp_))
+                x, cache = states[bi].prefill(bp[i], x, caches[bi], d)
+                new_caches.append(cache)
             logits = plan.final_logits(bp, params, x[0, t0 - 1][None])
             # kp samples the prefill token, kdec seeds the slot's decode
             # key — the same split generate() draws from PRNGKey(seed).
@@ -1145,41 +1103,15 @@ class DecodeEngine:
                 # tail
                 x = x + bp[emb_i]["P"][jnp.minimum(qpos,
                                                    emb.max_length - 1)]
-            x = x.astype(cdt)
+            x = emb.scaled(x).astype(cdt)
+            d = SimpleNamespace(wpids=wpids, woff=woff, off=off,
+                                     qpos=qpos, page_row=page_row,
+                                     t0=t0, slot=slot)
             new_caches = []
             for bi, i in enumerate(block_is):
-                p = bp[i]
-                layer = layers[i]
-                q, k, v = _block_heads(layer, p, x, qpos, shard=tp_shard)
-                kcol = jnp.transpose(k, (0, 2, 3, 1))   # (1, Hkv, hd, C)
-                vrow = jnp.transpose(v, (0, 2, 1, 3))   # (1, Hkv, C, hd)
-                with jax.named_scope("kv.write"):
-                    if kv_quant:
-                        kp_, vp_, ks_, vs_ = caches[bi]
-                        kcol, kscol = quantize_heads(kcol, axis=2)
-                        vrow, vscol = quantize_heads(vrow, axis=3)
-                        ks_ = write_scale_pages(ks_, kscol, wpids, woff,
-                                                page)
-                        vs_ = write_scale_pages(vs_, vscol, wpids, woff,
-                                                page)
-                    else:
-                        kp_, vp_ = caches[bi]
-                        ks_ = vs_ = None
-                    kp_, vp_ = write_pages(kp_, vp_, kcol, vrow, wpids,
-                                           woff)
-                # attend AFTER the write: the chunk attends to itself
-                # through the cache, which is exactly causal with the
-                # <= qpos mask; the auto path walks the slot's page row
-                # in place on TPU and falls back to gather + chunk
-                # (`_prefill_chunk_block_attention` numerics) elsewhere
-                with jax.named_scope("kv.attend"):
-                    att = paged_attention_chunk_auto(
-                        q, kp_, vp_, page_row[None], off[None],
-                        k_scale=ks_, v_scale=vs_)
-                att = _block_out_proj(p, att.reshape(1, Cw, -1), tp_axis)
-                x = _block_ffn(layer, p, x + att, axis_name=tp_axis)
-                new_caches.append((kp_, vp_, ks_, vs_) if kv_quant
-                                  else (kp_, vp_))
+                x, cache = states[bi].prefill_chunk(bp[i], x, caches[bi],
+                                                    d)
+                new_caches.append(cache)
             r = jnp.clip(t0 - 1 - off, 0, Cw - 1)
             logits = plan.final_logits(bp, params, x[0, r][None])
             with jax.named_scope("sample"):
@@ -1222,6 +1154,14 @@ class DecodeEngine:
         self._dparams = tp.shard_params(net._params) if tp is not None \
             else net._params
         self._plan = plan
+        self._states = states
+        self._n_held = n_held
+        self._recurrent = any(st.kind == "recurrent" for st in states)
+        self._state_bytes_per_slot = sum(st.bytes_per_slot()
+                                         for st in states)
+        routed = block_state.routed_ffns(plan)
+        self._moe_blocks = len(routed)
+        self._moe_top_k = max([ffn.top_k for ffn in routed], default=0)
         self._net = net
         self.max_len = L
         self.page_size = page
@@ -1297,6 +1237,51 @@ class DecodeEngine:
                 tp=tp, tp_params=self._dparams if tp is not None else None)
         self._reset_device_state()
 
+    def _refuse_unsupported(self, plan) -> None:
+        """Engine features that cannot hold a composed block, or a
+        block with per-slot recurrent state, yet: refused typed when
+        the engine is built (construction, weight swap), so nothing is
+        silently wrong later. Replay (preemption folding emitted tokens
+        back into the prompt) needs no state of the old slot and works."""
+        from deeplearning4j_tpu.serving.block_state import (
+            RecurrentStateUnsupported,
+        )
+
+        if not plan.composed:
+            return
+        asked = []
+        if self._speculative_cfg is not None:
+            asked.append("speculative decoding (draft and verifier assume "
+                         "TransformerBlock K/V)")
+        if self._tp_degree > 1:
+            asked.append("parallel={'tp': N} (no sharding rule for "
+                         "composed blocks)")
+        if "recurrent" in plan.state_kinds():
+            if self._prefix_cache_cfg not in (None, False):
+                asked.append("prefix_cache (a hit needs the recurrent "
+                             "state at the shared boundary; only K/V "
+                             "pages are kept)")
+            if self._quantize_cfg and self._quantize_cfg.get("kv"):
+                asked.append("quantize={'kv': 'int8'} (no quantized form "
+                             "of the recurrent state)")
+            if self._role != "both":
+                asked.append(f"role={self._role!r} (KV handoff does not "
+                             "carry recurrent state)")
+        if asked:
+            raise RecurrentStateUnsupported(
+                "not supported for this network's blocks yet: "
+                + "; ".join(asked))
+
+    def _require_kv_only(self, what: str) -> None:
+        if self._recurrent:
+            from deeplearning4j_tpu.serving.block_state import (
+                RecurrentStateUnsupported,
+            )
+
+            raise RecurrentStateUnsupported(
+                f"{what} moves K/V pages only; this engine's blocks also "
+                "keep per-slot recurrent state")
+
     def _reset_device_state(self) -> None:
         """Fresh page pools + page table + per-slot state (construction,
         weight swap, or recovery after a failed device step — a raised
@@ -1309,27 +1294,7 @@ class DecodeEngine:
 
         plan, S = self._plan, self.n_slots
         page, P = self.page_size, self.pool_pages
-        caches = []
-        for i in plan.block_is:
-            layer = plan.layers[i]
-            hd = layer.n_out // layer.n_heads
-            Hkv = layer._kv_heads
-            # +1: page 0 is the reserved trash page for masked writes
-            if self._kv_quant:
-                # int8 payload pools + f32 per-(head, position) scale
-                # pools riding the same page table; zero scales never
-                # dequantize stale garbage (0 * s == 0 either way), but
-                # 1.0 keeps the trash page's dequant exactly 0.0 in one
-                # multiply like a real all-zero write would
-                caches.append(
-                    (jnp.zeros((P + 1, Hkv, hd, page), jnp.int8),
-                     jnp.zeros((P + 1, Hkv, page, hd), jnp.int8),
-                     jnp.ones((P + 1, Hkv, page), jnp.float32),
-                     jnp.ones((P + 1, Hkv, page), jnp.float32)))
-            else:
-                caches.append(
-                    (jnp.zeros((P + 1, Hkv, hd, page), plan.cdt),
-                     jnp.zeros((P + 1, Hkv, page, hd), plan.cdt)))
+        caches = [st.alloc() for st in self._states]
         if self._tp is not None:
             # head axis (axis 1 in every pool + scale-sidecar layout)
             # over `tp`: each device owns Hkv/N heads of EVERY page, so
@@ -1884,6 +1849,7 @@ class DecodeEngine:
         returning None skips the fetch. Every wire failure degrades to
         cold prefill — the fetch path is never load-bearing.
         Chainable."""
+        self._require_kv_only("the cluster prefix cache")
         with self._cond:
             self._prefix_directory = directory
             self._holder_id = str(holder_id)
@@ -1936,6 +1902,7 @@ class DecodeEngine:
         caller blocks up to `timeout`. Typed `KVTransferError` when
         the chain is no longer resident deeper than `have_pages` (the
         directory entry was stale)."""
+        self._require_kv_only("a prefix export")
         from deeplearning4j_tpu.serving.kv_transfer import KVTransferError
 
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
@@ -2157,6 +2124,7 @@ class DecodeEngine:
         Blocks up to `wait` seconds for the scheduler's migration pass
         to drain the engine (pass `wait=None`/0 for fire-and-forget).
         Idempotent — an empty engine migrates nothing."""
+        self._require_kv_only("slot migration")
         with self._cond:
             if self._closed:
                 raise ServerClosedError("decode engine is shut down")
@@ -2229,6 +2197,7 @@ class DecodeEngine:
         SMALLER of the sender's remaining budget and `timeout`.
         `on_token` re-attaches a stream sink so a mid-stream migration
         keeps publishing under the sender's cursor."""
+        self._require_kv_only("resuming a migrated slot")
         from deeplearning4j_tpu.serving.kv_transfer import (
             KVTransferError,
             verify_payload,
@@ -2465,6 +2434,18 @@ class DecodeEngine:
                # bits reflect the BUILT pools (kill switch included)
                "kv_quant_bits": self._kv_quant_bits,
                "kv_bytes_per_token": self._kv_bytes_per_token,
+               # what a slot holds whatever its length: the recurrent
+               # blocks' state and convolution tails (0: none)
+               "state_bytes_per_slot": self._state_bytes_per_slot,
+               "state_resets": self.state_resets,
+               # routed experts, decode steps only: top-k choices of
+               # active slots, those on experts held here, held experts
+               # hit (summed over blocks and steps) and the steps
+               "moe_routed": self.moe_routed,
+               "moe_held_choices": self.moe_held_choices,
+               "moe_experts_hit": self.moe_experts_hit,
+               "moe_steps": self.moe_steps,
+               "moe_experts_held": self._n_held * self._moe_blocks,
                # tensor-parallel tier: degree 1 when off, so dashboards
                # can chart capacity without branching on key presence;
                # per-shard KV bytes is the per-chip residency claim
@@ -3136,6 +3117,7 @@ class DecodeEngine:
         with self._cond:
             self.prefills += 1
             self.tokens_generated += 1
+            self.state_resets += int(self._recurrent)
             # a one-shot prefill grounds the SLO estimator as a single
             # chunk observation (same dispatch scale as a chunk)
             self._chunk_ewma = 0.8 * self._chunk_ewma + 0.2 * (tp1 - tp0)
@@ -3263,6 +3245,7 @@ class DecodeEngine:
         self._hook("post_prefill", info)
         with self._cond:
             self.prefill_chunks += 1
+            self.state_resets += int(self._recurrent and off == 0)
             self._chunk_ewma = 0.8 * self._chunk_ewma + 0.2 * (tp1 - tp0)
         if not final:
             req.prefill_pos = off + C
@@ -4031,6 +4014,18 @@ class DecodeEngine:
         return True
 
     # graftlint: hot-loop
+    def _count_experts(self, counts, n_live: int) -> None:
+        """One dispatch's routing counts (`step_math`): (..., 2, held),
+        choices that fell on each held expert and in how many blocks
+        each was hit, for one step or a chunk of them."""
+        counts = np.asarray(counts).reshape(-1, 2, self._n_held)
+        with self._cond:
+            self.moe_routed += counts.shape[0] * n_live \
+                * self._moe_top_k * self._moe_blocks
+            self.moe_held_choices += int(counts[:, 0].sum())
+            self.moe_experts_hit += int(counts[:, 1].sum())
+            self.moe_steps += counts.shape[0]
+
     def _step_active(self) -> None:
         import jax.numpy as jnp
 
@@ -4056,42 +4051,28 @@ class DecodeEngine:
             self._hook("pre_decode", info)
 
             def run():
-                if chunked:
-                    if self._logprobs_k:
-                        (self._caches, self._tok, self._pos, self._keys,
-                         toks_d, oks_d, lps_d) = self._decode_chunked(
-                            self._dparams, self._caches,
-                            self._page_table, self._tok, self._pos,
-                            self._keys, self._temps,
-                            jnp.asarray(self._active))
-                    else:
-                        (self._caches, self._tok, self._pos, self._keys,
-                         toks_d, oks_d) = self._decode_chunked(
-                            self._dparams, self._caches,
-                            self._page_table, self._tok, self._pos,
-                            self._keys, self._temps,
-                            jnp.asarray(self._active))
-                        lps_d = None
-                    # (chunk, S) tokens + per-step flags, ONE host sync
-                    ph.enter("decode.wait")
-                    return jax.device_get((toks_d, oks_d, lps_d))
-                if self._logprobs_k:
-                    (self._caches, self._tok, self._pos, self._keys,
-                     ok_d, lp_d) = self._decode_step(
-                        self._dparams, self._caches, self._page_table,
-                        self._tok, self._pos, self._keys, self._temps,
-                        jnp.asarray(self._active))
-                else:
-                    (self._caches, self._tok, self._pos, self._keys,
-                     ok_d) = self._decode_step(
-                        self._dparams, self._caches, self._page_table,
-                        self._tok, self._pos, self._keys, self._temps,
-                        jnp.asarray(self._active))
-                    lp_d = None
+                fn = self._decode_chunked if chunked else self._decode_step
+                out = fn(self._dparams, self._caches, self._page_table,
+                         self._tok, self._pos, self._keys, self._temps,
+                         jnp.asarray(self._active))
+                self._caches, self._tok, self._pos, self._keys = out[:4]
+                # after the state: (toks,) oks[, logprobs][, counts]
+                rest = list(out[4:])
+                toks_d = rest.pop(0) if chunked else self._tok
+                oks_d = rest.pop(0)
+                lps_d = rest.pop(0) if self._logprobs_k else None
+                counts_d = rest.pop(0) if self._n_held else None
                 # THE per-iteration host sync — the price of
-                # iteration-level scheduling; chunking amortizes it
+                # iteration-level scheduling; chunking amortizes it to
+                # (chunk, S) tokens + per-step flags in ONE sync, and
+                # the experts' counts ride the same one
                 ph.enter("decode.wait")
-                t, o, lp = jax.device_get((self._tok, ok_d, lp_d))
+                t, o, lp, counts = jax.device_get(
+                    (toks_d, oks_d, lps_d, counts_d))
+                if counts is not None:
+                    self._count_experts(counts, len(live))
+                if chunked:
+                    return t, o, lp
                 return t[None], o[None], (None if lp is None else
                                           tuple(a[None] for a in lp))
 

@@ -933,6 +933,12 @@ DECODE_ENGINE_STATS_KEYS = frozenset({
     # (8 = int8 pools, else the compute dtype's width) and the
     # per-generated-token KV byte cost including the scale sidecar
     "kv_quant_bits", "kv_bytes_per_token",
+    # composed blocks: bytes a slot holds whatever its length (recurrent
+    # state + convolution tails), slot states overwritten at admission,
+    # and the routed experts' decode-step counts (choices made, choices
+    # on experts held here, held experts hit, steps, experts held)
+    "state_bytes_per_slot", "state_resets", "moe_routed",
+    "moe_held_choices", "moe_experts_hit", "moe_steps", "moe_experts_held",
     # tensor-parallel tier: mesh degree (1 = single-device engine, so
     # capacity dashboards never branch on key presence) and the
     # per-shard slice of kv_bytes_per_token — each device's actual

@@ -55,6 +55,31 @@ def test_serve_phase_toy():
     json.dumps(out)  # the summary line must serialize
 
 
+def test_hybrid_phase_toy():
+    import jax.numpy as jnp
+
+    hyb = dict(vocab_size=64, d_model=64, layer_types=("mamba", "attention"),
+               n_heads=4, n_kv_heads=2, attention_multiplier=0.1,
+               mamba_heads=8, mamba_head_dim=16, mamba_state=16,
+               mamba_chunk=8, n_experts=8, top_k=2, expert_width=32,
+               shared_width=48, experts_held=(0, 4),
+               embedding_multiplier=12.0, residual_multiplier=0.22,
+               logits_scaling=16.0)
+    out = chip_smoke.phase_hybrid(
+        hyb, dict(n_slots=4, max_len=96, page_size=8, prefill_chunk=16,
+                  n_short=3, short_len=8, long_len=40, n_tokens=8),
+        kernels=False, dtype=jnp.float32)
+    assert out["requests"] == 4 and out["tokens"] == 4 * 8
+    assert out["prefill_chunks"] >= 3
+    # on the CPU both engines ARE the XLA products: tokens identical
+    assert out["agreement"]["common_prefix_tokens"] == [8] * 4
+    # state (f32) and pools are counted together, by shape
+    assert set(out["pool_layout_copies"]) == {"decode_step",
+                                              "decode_chunked"}
+    assert out["state_bytes_per_slot"] == 8 * 16 * 16 * 4 + 160 * 3 * 4
+    json.dumps(out)
+
+
 # lines as XLA:TPU prints them (PR 26's parent, layouts and configs
 # kept, operand lists cut): what the count must and must not see
 _CANNED_HLO = """\

@@ -117,7 +117,10 @@ def test_multi_head_attention_accepts_grouped_kv():
     vr = jnp.repeat(v, H // Hkv, axis=2)
     ref = np.asarray(full_attention(q, kr, vr, causal=True))
     got = np.asarray(multi_head_attention(q, k, v, causal=True))
-    np.testing.assert_array_equal(got, ref)
+    # the grouped einsum contracts the same terms in another order:
+    # float32 sums of 8 products reassociated differ by a few ulp
+    # (1.2e-6 relative, met on this backend), never by an equation
+    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)
     # long-seq path (blockwise widens internally): same numerics as the
     # widened blockwise call
     from deeplearning4j_tpu.ops.attention import blockwise_attention
@@ -130,9 +133,11 @@ def test_multi_head_attention_accepts_grouped_kv():
 
 
 def test_gqa_transformer_block_forward_bit_parity():
-    """gpt-config bit-parity pin for the satellite: a GQA block's
-    forward through the grouped-einsum dispatch equals the historical
-    materialized-repeat computation exactly."""
+    """gpt-config parity pin for the satellite: a GQA block's forward
+    through the grouped-einsum dispatch equals the historical
+    materialized-repeat computation to float32 reassociation (the
+    grouped path sums the same products in another order; the name
+    dates from when the two happened to agree bit for bit)."""
     from deeplearning4j_tpu.models.transformer import gpt_configuration
     from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
     from deeplearning4j_tpu.ops import attention as att_mod
@@ -165,7 +170,7 @@ def test_gqa_transformer_block_forward_bit_parity():
         ref = np.asarray(net2.output(ids))
     finally:
         att_mod.multi_head_attention = orig
-    np.testing.assert_array_equal(got, ref)
+    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-7)
 
 
 # ---------------------------------------------------------------------------
