@@ -360,44 +360,72 @@ _HLO_DTYPES = {"bfloat16": "bf16", "float32": "f32", "int8": "s8"}
 _LAYOUT_OPCODES = ("copy", "copy-start", "transpose")
 
 
-def pool_layout_copies(hlo_text: str, pool_shapes) -> int:
-    """Instructions of an optimised HLO module whose result has a KV
-    pool's shape (`pool_shapes`: HLO strings such as
-    "bf16[137,16,128,128]") and whose opcode copies it or changes its
-    layout. A decode program that writes its donated pools in place has
-    none; each one is a whole pool read and written per step."""
+def _count_instructions(hlo_text: str, opcodes, shapes) -> int:
+    """Instructions of an HLO module, fused computations included, with
+    one of `opcodes` and a result of one of `shapes` (HLO strings such
+    as "bf16[137,16,128,128]")."""
     line = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.+?) ([\w\-]+)\(")
     n = 0
     for text in hlo_text.splitlines():
         m = line.match(text)
-        if m and m.group(2) in _LAYOUT_OPCODES \
-                and any(shape in m.group(1) for shape in pool_shapes):
+        if m and m.group(2) in opcodes \
+                and any(shape in m.group(1) for shape in shapes):
             n += 1
     return n
 
 
-def _decode_program_copies(engine) -> dict:
+def pool_layout_copies(hlo_text: str, pool_shapes) -> int:
+    """Instructions of an optimised HLO module whose result has a KV
+    pool's shape and whose opcode copies it or changes its layout. A
+    decode program that writes its donated pools in place has none;
+    each one is a whole pool read and written per step."""
+    return _count_instructions(hlo_text, _LAYOUT_OPCODES, pool_shapes)
+
+
+def weight_converts(hlo_text: str, weight_shapes) -> int:
+    """`convert`s of an optimised HLO module, alone or fused into the
+    matmul that reads them, whose result is a weight matrix in the
+    compute dtype (`weight_shapes`: the served matrices as the programs
+    are handed them). A serving program has none: the engine casts its
+    weights once, when it is built; each one is a matrix read at the
+    master's width, every dispatch."""
+    return _count_instructions(hlo_text, ("convert",), weight_shapes)
+
+
+def _hlo_shapes(arrays) -> set:
+    return {f"{_HLO_DTYPES[a.dtype.name]}[{','.join(map(str, a.shape))}]"
+            for a in arrays}
+
+
+def _decode_program_counts(engine) -> dict:
     """Compile the engine's two decode programs as its scheduler calls
     them and count in each the copies of what the blocks keep between
     tokens (`pool_layout_copies`): K/V pools, their scale pools, and a
     recurrent block's state. Its convolution tail is left out: 3 taps
     a channel, 0.1% of the state's bytes, and XLA moves it to fast
-    memory and back each step by choice (same layout, `S(1)`)."""
+    memory and back each step by choice (same layout, `S(1)`). And the
+    casts of a weight matrix (`weight_converts`)."""
     import jax
     import jax.numpy as jnp
 
-    pools = jax.tree_util.tree_leaves(
+    pools = _hlo_shapes(jax.tree_util.tree_leaves(
         [c[:1] if st.kind == "recurrent" else c
-         for st, c in zip(engine._states, engine._caches)])
-    shapes = {f"{_HLO_DTYPES[p.dtype.name]}"
-              f"[{','.join(map(str, p.shape))}]" for p in pools}
-    args = (engine._dparams, engine._caches, engine._page_table,
+         for st, c in zip(engine._states, engine._caches)]))
+    plan = engine._plan
+    weights = _hlo_shapes(
+        w for i in (plan.emb_i, *plan.block_is)
+        for w in jax.tree_util.tree_leaves(engine._weights[i])
+        if w.ndim >= 2 and w.dtype == plan.cdt)
+    args = (engine._weights, engine._caches, engine._page_table,
             engine._tok, engine._pos, engine._keys, engine._temps,
             jnp.asarray(engine._active))
-    return {name: pool_layout_copies(
-                fn.lower(*args).compile().as_text(), shapes)
-            for name, fn in (("decode_step", engine._decode_step),
-                             ("decode_chunked", engine._decode_chunked))}
+    texts = {name: fn.lower(*args).compile().as_text()
+             for name, fn in (("decode_step", engine._decode_step),
+                              ("decode_chunked", engine._decode_chunked))}
+    return {"pool_layout_copies": {n: pool_layout_copies(t, pools)
+                                   for n, t in texts.items()},
+            "weight_converts": {n: weight_converts(t, weights)
+                                for n, t in texts.items()}}
 
 
 def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
@@ -462,11 +490,24 @@ def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
 
     engine = DecodeEngine(net, **gen)
     try:
-        out["pool_layout_copies"] = _decode_program_copies(engine)
+        out.update(_decode_program_counts(engine))
+        built = engine.stats()
     finally:
         engine.shutdown(drain_timeout=30.0)
+    # f32 masters, bf16 compute: cast once when the engine is built,
+    # and by no decode program (PERF.md, PR 29)
+    out["weight_casts"] = built["weight_casts"]
+    out["weights_resident_bytes"] = built["weights_resident_bytes"]
     print(f"serve: pool copies in the decode programs "
-          f"{out['pool_layout_copies']}", flush=True)
+          f"{out['pool_layout_copies']}, weight casts "
+          f"{out['weight_converts']}, cast when built "
+          f"{out['weight_casts']} ({out['weights_resident_bytes']} bytes)",
+          flush=True)
+    _check(out["weight_casts"] == 1 and out["weights_resident_bytes"] > 0
+           and not any(out["weight_converts"].values()),
+           f"the decode programs cast their weights: "
+           f"{out['weight_converts']} converts, {out['weight_casts']} "
+           f"cast(s) when built")
 
     if kernels:
         H = gpt["n_heads"]
@@ -549,7 +590,7 @@ def phase_hybrid(hyb: dict, shape: dict, *, kernels: bool,
 
     engine = DecodeEngine(net, **gen)
     try:
-        out["pool_layout_copies"] = _decode_program_copies(engine)
+        out.update(_decode_program_counts(engine))
     finally:
         engine.shutdown(drain_timeout=30.0)
     print(f"hybrid: state and pool copies in the decode programs "
@@ -627,7 +668,7 @@ def phase_multichip(gpt: dict, train: dict, serve: dict,
         spread = [sorted(sh.device.id for sh in leaf.addressable_shards
                          if sh.data.nbytes * 4 == leaf.nbytes)
                   for leaf in jax.tree_util.tree_leaves(engine._caches)
-                  + [engine._dparams[engine._plan.block_is[0]]["Wqkv"]]]
+                  + [engine._weights[engine._plan.block_is[0]]["Wqkv"]]]
     finally:
         engine.shutdown(drain_timeout=30.0)
     _check(tp_stats["failures"] == 0, f"tp=4: {tp_stats['failures']} failed")

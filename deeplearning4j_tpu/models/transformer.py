@@ -225,26 +225,54 @@ class GPTPlan:
                 out.append(layer.mixer.kv_geometry(layer._d))
         return out
 
-    def cast_blocks(self, params):
-        """Embedding + block params in the compute dtype; head params
-        stay in the param dtype."""
+    def _cast(self, params, wrap):
+        """`params` with the layers that are read in the compute dtype
+        cast to it by `wrap(cast_weights)`: every block, and the
+        embedding unless the head is tied to it (a tied head reads the
+        table in the param dtype, so one table is held, not two, and its
+        gathered rows are cast with the activations). The other leaves
+        are `params` own arrays; where the two dtypes are equal the
+        result IS `params`."""
         if self.cdt == self.dtype:
             return params
-        import jax
-
         from deeplearning4j_tpu.nn.precision import tree_cast
 
+        def cast_weights(tree):
+            return tree_cast(tree, self.cdt)
+
+        head = self.layers[self.out_i]
+        tied = head.tied_to if isinstance(head, TiedRnnOutputLayer) else None
+        done = wrap(cast_weights)(
+            {i: params[i] for i in (self.emb_i, *self.block_is)
+             if i != tied})
+        return [done.get(i, p) for i, p in enumerate(params)]
+
+    def cast_blocks(self, params):
+        """Embedding + block params in the compute dtype; head params
+        stay in the param dtype. Traced into the caller's program: for
+        one that runs once per call (`generate`)."""
+        import jax
+
         with jax.named_scope("cast_params"):
-            return [tree_cast(p, self.cdt)
-                    if i in (self.emb_i, *self.block_is) else p
-                    for i, p in enumerate(params)]
+            return self._cast(params, lambda cast: cast)
+
+    def resident_weights(self, params):
+        """`cast_blocks` run ONCE, as a program of its own
+        (`jit_cast_weights` in a trace), for the decode engine, which
+        keeps the result on the device and hands it to every dispatch:
+        no serving program converts a weight. Sharded leaves keep their
+        sharding."""
+        import jax
+
+        return self._cast(params, jax.jit)
 
     def final_logits(self, bp, params, x):
-        """Trailing LN(s) in the compute dtype (`bp`), then the output
-        head in the param dtype — the same precision boundary the
-        training step draws (`MultiLayerNetwork._loss_pure` casts hidden
-        layers, including trailing LNs, and restores the param dtype only
-        for the loss head)."""
+        """Trailing LN(s) from `bp`, then the output head in the param
+        dtype from `params` — the same precision boundary the training
+        step draws (`MultiLayerNetwork._loss_pure` restores the param
+        dtype for the loss head). `cast_blocks` and `resident_weights`
+        leave the head's leaves in the param dtype, so a caller that
+        holds only their result passes it twice."""
         import jax
 
         with jax.named_scope("head"):

@@ -692,6 +692,7 @@ class DecodeEngine:
         self.tokens_generated = 0  # guarded by: _cond
         self.pages_in_use_peak = 0  # guarded by: _cond
         self.swaps = 0  # guarded by: _cond
+        self.weight_casts = 0  # guarded by: _cond
         # QoS counters: batch-lane slots yielded to interactive
         # pressure, SLO-estimator door sheds, per-tenant quota sheds
         self.preemptions = 0  # guarded by: _cond
@@ -963,8 +964,12 @@ class DecodeEngine:
                 return fn
             return tp.shard(fn, n_in=n_in, n_out=n_out)
 
-        def step_math(bp, params, caches, page_table, tok, pos, keys,
-                      temps, active):
+        # every program's first argument `bp` is the engine's resident
+        # weights (`GPTPlan.resident_weights`, made once per build):
+        # embedding and blocks in the compute dtype, trailing norms and
+        # head in the param dtype. No program converts a weight
+        def decode_step(bp, caches, page_table, tok, pos, keys, temps,
+                        active):
             """Advance ALL slots one token: inactive slots are masked
             (token/position carried through unchanged, cache writes
             redirected to the trash page so a reallocated page is never
@@ -989,7 +994,7 @@ class DecodeEngine:
             for bi, i in enumerate(block_is):
                 x, cache = states[bi].decode(bp[i], x, caches[bi], d)
                 new_caches.append(cache)
-            logits = plan.final_logits(bp, params, x)
+            logits = plan.final_logits(bp, bp, x)
             with jax.named_scope("sample"):
                 nxt, new_keys = sample_slots(logits, keys, temps)
                 nxt = jnp.where(active, nxt, tok)
@@ -1007,23 +1012,19 @@ class DecodeEngine:
                         .astype(jnp.int32),)
             return out
 
-        def decode_step(params, caches, page_table, tok, pos, keys, temps,
-                        active):
-            bp = plan.cast_blocks(params)
-            return step_math(bp, params, caches, page_table, tok, pos,
-                             keys, temps, active)
+        # the chunk scans the step's body, not the jitted program the
+        # name is rebound to below
+        step_math = decode_step
 
-        def decode_chunked(params, caches, page_table, tok, pos, keys,
+        def decode_chunked(bp, caches, page_table, tok, pos, keys,
                            temps, active):
             """`decode_chunk` iterations of the SAME step body fused into
             one dispatch via lax.scan — used only when the scheduler
             proves no admission/retirement/deadline/prefill event can
             land inside the chunk (page tables are therefore invariant
             across it). Returns every intermediate token (chunk, S)."""
-            bp = plan.cast_blocks(params)
-
             def body(carry, _):
-                out = step_math(bp, params, *carry[:1], page_table,
+                out = step_math(bp, *carry[:1], page_table,
                                 *carry[1:], temps, active)
                 # per-STEP outputs (chunk, S): the host attributes a
                 # poisoned step to the right iteration, so a request
@@ -1037,7 +1038,7 @@ class DecodeEngine:
             # caches, tok, pos, keys, then toks, oks[, lps][, counts]
             return carry + per_step
 
-        def prefill(params, caches, ids, t0, slot, wpids, tok, pos, keys,
+        def prefill(bp, caches, ids, t0, slot, wpids, tok, pos, keys,
                     temps, kp, kdec, temp):
             """One-shot prefill: write one prompt's KV into the slot's
             pages and emit its first token. `ids` is (1, bucket) — pow-2
@@ -1047,7 +1048,6 @@ class DecodeEngine:
             numerics. The block math is IDENTICAL to `generate`'s
             prefill (`_prefill_block_attention`) — only the cache
             write targets pages instead of a slot row."""
-            bp = plan.cast_blocks(params)
             P = ids.shape[1]
             x = bp[emb_i]["W"][ids]
             if emb.positional:
@@ -1058,7 +1058,7 @@ class DecodeEngine:
             for bi, i in enumerate(block_is):
                 x, cache = states[bi].prefill(bp[i], x, caches[bi], d)
                 new_caches.append(cache)
-            logits = plan.final_logits(bp, params, x[0, t0 - 1][None])
+            logits = plan.final_logits(bp, bp, x[0, t0 - 1][None])
             # kp samples the prefill token, kdec seeds the slot's decode
             # key — the same split generate() draws from PRNGKey(seed).
             # Temperature is dynamic per request, so the greedy/sampled
@@ -1080,7 +1080,7 @@ class DecodeEngine:
                     lp_math(logits, tok0)
             return new_caches, tok, pos, keys, temps, tok0, ok0
 
-        def prefill_chunk_fn(params, caches, page_row, ids, off, woff,
+        def prefill_chunk_fn(bp, caches, page_row, ids, off, woff,
                              t0, slot, wpids, tok, pos, keys, temps, kp,
                              kdec, temp):
             """One prefill CHUNK: embed `ids` (1, prefill_chunk) at
@@ -1091,7 +1091,6 @@ class DecodeEngine:
             by the host — on the FINAL chunk). Slot token/position/key
             state is set every chunk; the final chunk's values are the
             ones that stick before decode starts."""
-            bp = plan.cast_blocks(params)
             Cw = ids.shape[1]
             qpos = off + jnp.arange(Cw)
             x = bp[emb_i]["W"][ids]
@@ -1113,7 +1112,7 @@ class DecodeEngine:
                                                     d)
                 new_caches.append(cache)
             r = jnp.clip(t0 - 1 - off, 0, Cw - 1)
-            logits = plan.final_logits(bp, params, x[0, r][None])
+            logits = plan.final_logits(bp, bp, x[0, r][None])
             with jax.named_scope("sample"):
                 greedy = _sample_logits(logits, kp, 0.0, 0)
                 drawn = jax.random.categorical(
@@ -1147,12 +1146,23 @@ class DecodeEngine:
                           donate_argnums=(1,) if donate else ())
         prefill_chunk_fn = jax.jit(_shard(prefill_chunk_fn, 16, 7),
                                    donate_argnums=(1,) if donate else ())
-        # params placed once per (re)build: permuted + head/width-
+        # weights placed once per (re)build: permuted + head/width-
         # sharded over the mesh under TP (a weight swap reshards from
-        # the swapped net's clean host copy), the net's own tree
-        # otherwise
-        self._dparams = tp.shard_params(net._params) if tp is not None \
+        # the swapped net's clean host copy), then cast to the compute
+        # dtype by a program of their own; where the net's two dtypes
+        # are equal, the net's own tree. A rebuild drops the old
+        # resident trees (the draft's with its decoder) before it makes
+        # the new: two of them beside two nets' masters may not fit
+        self._weights = self._spec = None
+        placed = tp.shard_params(net._params) if tp is not None \
             else net._params
+        self._weights = plan.resident_weights(placed)
+        uncast = {id(x) for x in jax.tree_util.tree_leaves(placed)}
+        self._weights_resident_bytes = sum(
+            x.nbytes for x in jax.tree_util.tree_leaves(self._weights)
+            if id(x) not in uncast)
+        with self._cond:
+            self.weight_casts += int(self._weights is not placed)
         self._plan = plan
         self._states = states
         self._n_held = n_held
@@ -1234,7 +1244,7 @@ class DecodeEngine:
                 draft_net=self._draft_net, k=k, n_slots=S, page=page,
                 L_logical=L_logical, pool_pages=pool_pages,
                 top_k=self.top_k, donate=donate, kv_quant=kv_quant,
-                tp=tp, tp_params=self._dparams if tp is not None else None)
+                tp=tp, target_weights=self._weights)
         self._reset_device_state()
 
     def _refuse_unsupported(self, plan) -> None:
@@ -2420,6 +2430,11 @@ class DecodeEngine:
                "slot_occupancy_pct": round(occupancy, 1),
                "n_slots": self.n_slots, "active_slots": active,
                "queued": queued, "swaps": self.swaps,
+               # times the weights were cast to the compute dtype (once
+               # a build; 0 where the net's two dtypes are equal) and
+               # the bytes of that tree held beyond the net's own
+               "weight_casts": self.weight_casts,
+               "weights_resident_bytes": self._weights_resident_bytes,
                "max_len": self.max_len,
                "page_size": self.page_size,
                "pool_pages": self.pool_pages,
@@ -2594,6 +2609,15 @@ class DecodeEngine:
                     break
                 self._cond.wait(min(remaining, 0.05))
         self._thread.join(max(0.0, deadline - time.monotonic()) + 5.0)
+        if not self._thread.is_alive():
+            # a stopped scheduler dispatches nothing more: the resident
+            # weights go now, not when the last reference to the engine
+            # does (a failed request's traceback keeps one), and what
+            # stays on the device is the net's own tree and the pools
+            self._weights = None
+            self._weights_resident_bytes = 0
+            if self._spec is not None:
+                self._spec._weights = None
         if not drained:
             logger.warning("decode engine: shutdown drain timed out with "
                            "generations still in flight")
@@ -3077,7 +3101,7 @@ class DecodeEngine:
         self._hook("pre_prefill", info)
 
         def run():
-            args = (self._dparams, self._caches, jnp.asarray(ids),
+            args = (self._weights, self._caches, jnp.asarray(ids),
                     jnp.asarray(t0, jnp.int32),
                     jnp.asarray(slot, jnp.int32),
                     wpids, self._tok, self._pos, self._keys, self._temps,
@@ -3198,7 +3222,7 @@ class DecodeEngine:
         self._hook("pre_prefill", info)
 
         def run():
-            args = (self._dparams, self._caches, self._page_table[slot],
+            args = (self._weights, self._caches, self._page_table[slot],
                     jnp.asarray(ids), jnp.asarray(off, jnp.int32),
                     jnp.asarray(woff, jnp.int32),
                     jnp.asarray(t0, jnp.int32),
@@ -3961,12 +3985,12 @@ class DecodeEngine:
                 wlimit = jnp.asarray(wl)
                 active = jnp.asarray(self._active)
                 (spec._caches, spec._keys, props, qd) = spec._propose(
-                    spec._draft_params(), spec._caches, self._page_table,
+                    spec._weights, spec._caches, self._page_table,
                     self._tok, self._pos, spec._keys, self._temps,
                     active, wlimit)
                 (self._caches, self._tok, self._pos, self._keys, out,
                  n_emit, oks) = spec._verify(
-                    self._dparams, self._caches, self._page_table,
+                    self._weights, self._caches, self._page_table,
                     self._tok, self._pos, self._keys, self._temps,
                     active, wlimit, props, qd)
                 ph.enter("decode.wait")
@@ -4052,7 +4076,7 @@ class DecodeEngine:
 
             def run():
                 fn = self._decode_chunked if chunked else self._decode_step
-                out = fn(self._dparams, self._caches, self._page_table,
+                out = fn(self._weights, self._caches, self._page_table,
                          self._tok, self._pos, self._keys, self._temps,
                          jnp.asarray(self._active))
                 self._caches, self._tok, self._pos, self._keys = out[:4]

@@ -1195,6 +1195,12 @@ class ModelServer:
         with self._reload_lock:
             try:
                 candidate = self._load_candidate(source, step)
+                # a checkpoint records parameters, not the precision
+                # they are served in: the candidate computes as the
+                # live net does
+                if getattr(candidate, "compute_dtype", None) is None:
+                    candidate.compute_dtype = getattr(
+                        self._raw_net, "compute_dtype", None)
                 raw_candidate = candidate
                 wq = self._quantize_cfg.get("weights") \
                     if self._quantize_cfg else None

@@ -88,7 +88,7 @@ class SpeculativeDecoder:
                  n_slots: int, page: int, L_logical: int,
                  pool_pages: int, top_k: int, donate: bool,
                  kv_quant: Optional[str] = None,
-                 tp=None, tp_params=None):
+                 tp=None, target_weights=None):
         if k < 1:
             raise ValueError("speculative k must be >= 1")
         import jax
@@ -152,24 +152,24 @@ class SpeculativeDecoder:
                 "could not embed positions the target serves")
         # tensor parallelism: the engine's TPPlan (target geometry) is
         # shared for the verify step; the draft gets its OWN plan unless
-        # self-drafting (same net → reuse the engine's already-placed
-        # sharded params instead of device_put-ing them twice). A draft
-        # whose heads/FFN don't divide the degree fails HERE with the
-        # same typed ValueError the engine raises for the target.
+        # self-drafting. A draft whose heads/FFN don't divide the degree
+        # fails HERE with the same typed ValueError the engine raises
+        # for the target.
         self._tp = tp
-        if tp is not None:
-            if self.self_draft:
-                dtp = tp
-                self._dparams_sharded = tp_params
-            else:
-                from deeplearning4j_tpu.serving.tp_engine import TPPlan
+        dtp = tp
+        if tp is not None and not self.self_draft:
+            from deeplearning4j_tpu.serving.tp_engine import TPPlan
 
-                dtp = TPPlan(draft_net, dplan, tp.degree)
-                self._dparams_sharded = dtp.shard_params(draft_net._params)
-        else:
-            dtp = None
-            self._dparams_sharded = None
+            dtp = TPPlan(draft_net, dplan, tp.degree)
         self._dtp = dtp
+        # the weights the draft's programs are handed, resident in the
+        # draft's compute dtype like the engine's own
+        # (`target_weights`, which the verifier takes and a self-draft
+        # shares): placed and cast once here, converted by no program
+        self._weights = target_weights if self.self_draft \
+            else dplan.resident_weights(
+                dtp.shard_params(draft_net._params) if dtp is not None
+                else draft_net._params)
         tp_axis = tp.axis if tp is not None else None
         tp_shard = tp.degree if tp is not None else None
 
@@ -188,8 +188,7 @@ class SpeculativeDecoder:
             return _top_k_filter(logits / safe_t, top_k)
 
         # -- draft prefill (one-shot + chunk): KV writes only, no head --
-        def draft_prefill(dparams, dcaches, ids, wpids):
-            bp = dplan.cast_blocks(dparams)
+        def draft_prefill(bp, dcaches, ids, wpids):
             P = ids.shape[1]
             x = bp[dplan.emb_i]["W"][ids]
             if dplan.emb.positional:
@@ -224,9 +223,8 @@ class SpeculativeDecoder:
                     new_caches.append((kp_, vp_))
             return new_caches
 
-        def draft_prefill_chunk(dparams, dcaches, page_row, ids, off, woff,
+        def draft_prefill_chunk(bp, dcaches, page_row, ids, off, woff,
                                 wpids):
-            bp = dplan.cast_blocks(dparams)
             Cw = ids.shape[1]
             qpos = off + jnp.arange(Cw)
             x = bp[dplan.emb_i]["W"][ids]
@@ -265,9 +263,8 @@ class SpeculativeDecoder:
         # k proposals plus one cache-completion step, so the draft's KV
         # covers every position the NEXT round may start from (an
         # all-accepted verify advances the slot past the k-th write)
-        def draft_propose(dparams, dcaches, page_table, tok, pos, dkeys,
+        def draft_propose(bp, dcaches, page_table, tok, pos, dkeys,
                           temps, active, wlimit):
-            bp = dplan.cast_blocks(dparams)
             rows = jnp.arange(S)
 
             def body(carry, j):
@@ -310,7 +307,7 @@ class SpeculativeDecoder:
                     x = _block_ffn(layer, p, x + att, axis_name=tp_axis)
                     new_caches.append((kp_, vp_, ks_, vs_) if kv_quant
                                       else (kp_, vp_))
-                logits = dplan.final_logits(bp, dparams, x)
+                logits = dplan.final_logits(bp, bp, x)
                 scaled = scale_and_filter(logits, temps)
                 qdist = jax.nn.softmax(scaled.astype(jnp.float32), axis=-1)
                 ks = jax.vmap(jax.random.split)(keys)
@@ -330,9 +327,8 @@ class SpeculativeDecoder:
             return caches, keys, props, qd
 
         # -- target verify: one (k+1)-wide chunk per slot -------------------
-        def verify(params, caches, page_table, tok, pos, keys, temps,
+        def verify(bp, caches, page_table, tok, pos, keys, temps,
                    active, wlimit, props, qdists):
-            bp = tplan.cast_blocks(params)
             rows = jnp.arange(S)
             block = jnp.concatenate([tok[:, None], props], axis=1)  # (S,C)
             qpos = pos[:, None] + jnp.arange(C)[None, :]            # (S,C)
@@ -374,7 +370,7 @@ class SpeculativeDecoder:
                 x = _block_ffn(layer, p, x + att, axis_name=tp_axis)
                 new_caches.append((kp_, vp_, ks_, vs_) if kv_quant
                                   else (kp_, vp_))
-            logits = tplan.final_logits(bp, params, x)       # (S, C, V)
+            logits = tplan.final_logits(bp, bp, x)       # (S, C, V)
 
             # --- acceptance (Leviathan rejection sampling; greedy =
             # argmax equality). Query j consumed [tok, props][j] and its
@@ -499,13 +495,6 @@ class SpeculativeDecoder:
         self._keys = jnp.stack(
             [jax.random.PRNGKey(1000 + i) for i in range(S)])
 
-    def _draft_params(self):
-        """The params list the compiled draft closures consume: the
-        permuted+placed shards under TP, the net's own list otherwise."""
-        if self._dparams_sharded is not None:
-            return self._dparams_sharded
-        return self.draft_net._params
-
     def seed_slot(self, slot: int, seed: int) -> None:
         """Per-request draft PRNG stream (deterministic per seed, on a
         different fold than the target's kp/kd split)."""
@@ -524,7 +513,7 @@ class SpeculativeDecoder:
         import jax.numpy as jnp
 
         self._caches = self._draft_prefill(
-            self._draft_params(), self._caches, jnp.asarray(ids), wpids)
+            self._weights, self._caches, jnp.asarray(ids), wpids)
         jax.device_get(self._caches[0][0][0, 0, 0, 0])
         self.draft_prefills += 1
 
@@ -534,7 +523,7 @@ class SpeculativeDecoder:
         import jax.numpy as jnp
 
         self._caches = self._draft_prefill_chunk(
-            self._draft_params(), self._caches, page_row,
+            self._weights, self._caches, page_row,
             jnp.asarray(ids), jnp.asarray(off, jnp.int32),
             jnp.asarray(woff, jnp.int32),
             jnp.asarray(np.asarray(pids, np.int32)))
