@@ -9,9 +9,9 @@ the built-in paths are `ops/attention.py` full/blockwise attention (XLA);
 this module is the Mosaic/Pallas fast path for the no-mask case — and since
 it carries a custom VJP (two backward kernels, the standard dQ / dKV
 split), it serves TRAINING too, the analogue of the cuDNN backward helpers
-gradient-checked in `CuDNNGradientChecks.java`. Measured IN-BENCH on v5e
-(`bench.py gpt_long` reports `flash_speedup_vs_xla_blockwise` at the
-exact bench shape every run): 2.6-3.0x the XLA blockwise path for causal
+gradient-checked in `CuDNNGradientChecks.java`. Measured on a v5e before
+this round's records began (not in `PERF_LEDGER.jsonl`; `PERF.md` holds
+what the chip reads now): 2.6-3.0x the XLA blockwise path for causal
 fwd+bwd at T=4096, block 1024 (block-512 tiles measured 1.9x). Block
 sizes beyond 1024 are exhausted as a lever: with the scoped-VMEM ceiling
 raised to admit them, (bq, bk) in {2048x1024, 1024x2048, 2048x2048,
@@ -392,7 +392,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
 
 def _platform_supported() -> bool:
-    # the switch forces the XLA-blockwise fallback (A/B benches, tests)
+    # the switch forces the XLA-blockwise fallback (A/B runs, tests)
     return _kernels_dispatch("DL4J_TPU_NO_PALLAS_ATTENTION")
 
 

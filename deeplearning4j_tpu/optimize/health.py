@@ -20,8 +20,8 @@ Pieces:
   finiteness flag, and commits the candidate parameters/updater/layer
   state ONLY when loss and gradient norm are both finite — a non-finite
   candidate can never overwrite good parameters. The host reads one
-  small `(loss, grad_norm, ok)` vector per step (one device→host sync;
-  `bench.py sentinel` prices it) and runs EWMA spike detection plus the
+  small `(loss, grad_norm, ok)` vector per step (one device→host sync)
+  and runs EWMA spike detection plus the
   escalation ladder on it.
 - The bounded **escalation ladder** — each rung fires after
   `skip_budget` consecutive unhealthy steps (non-finite = skipped

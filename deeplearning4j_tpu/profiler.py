@@ -125,10 +125,9 @@ def trace_annotation(name: str):
 @contextmanager
 def trace_capture(log_dir: str):
     """Capture a `jax.profiler` trace over the with-block (the
-    block-scoped sibling of `XlaTraceListener`'s iteration window —
-    `bench.py --trace` wraps one timed benchmark rep in this). The
+    block-scoped sibling of `XlaTraceListener`'s iteration window). The
     trace always stops, even when the block raises, so an aborted
-    bench never leaves the profiler armed for the next one."""
+    run never leaves the profiler armed for the next one."""
     import jax
 
     jax.profiler.start_trace(log_dir)

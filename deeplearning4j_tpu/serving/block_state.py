@@ -23,10 +23,11 @@ kind each block keeps (`GPTPlan.state_kinds`):
 Each has `alloc()`, `decode(p, x, cache, d)`, `prefill(p, x, cache, d)`
 and `prefill_chunk(p, x, cache, d)`, the last three returning
 `(x, cache)`. `d` (a `SimpleNamespace`) carries what the program computed once for all blocks
-(page ids, offsets, positions, the active mask, the slot); `env` what
-the engine fixed at build time. The dense path's operations are the
-ones the engine's closures held before this module existed, in the same
-order.
+(page ids, offsets, positions, the active mask, the slot); `env` the
+numbers the engine fixed at build time and the tensor-parallel axis
+(no function: the pool writers `_write_pages` / `_write_token` live
+here, beside `KVPages`, and the speculative draft and verifier import
+them from here too).
 """
 from __future__ import annotations
 
@@ -37,6 +38,10 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.decoder_block import sub
 from deeplearning4j_tpu.nn.conf.layers import TransformerBlock
+from deeplearning4j_tpu.serving.quantize import (
+    _write_scale_pages,
+    quantize_heads,
+)
 
 
 class RecurrentStateUnsupported(ValueError):
@@ -99,6 +104,58 @@ def _finish_composed(layer, p, x, mixed, d):
     return out
 
 
+def _write_pages(kp_, vp_, kcol, vrow, wpids, woff, page):
+    """Scatter one contiguous prefill span (1, Hkv, hd, W) /
+    (1, Hkv, W, hd) into the pool pages `wpids`: floor(W/page) aligned
+    full-page writes, then a partial tail (a non-pow-2 fallback bucket,
+    or a sub-page chunk) at in-page offset `woff` — which is nonzero
+    only in the W < page chunked case, where chunk-aligned pow-2
+    offsets guarantee the span never straddles a page boundary. The
+    speculative draft's prefill mirrors the exact same write discipline
+    into its own pools."""
+    W = kcol.shape[3]
+    z = jnp.zeros((), jnp.int32)
+    nfull = W // page
+    for j in range(nfull):
+        kp_ = jax.lax.dynamic_update_slice(
+            kp_, kcol[..., j * page:(j + 1) * page], (wpids[j], z, z, z))
+        vp_ = jax.lax.dynamic_update_slice(
+            vp_, vrow[:, :, j * page:(j + 1) * page, :], (wpids[j], z, z, z))
+    if W % page:
+        kp_ = jax.lax.dynamic_update_slice(
+            kp_, kcol[..., nfull * page:], (wpids[nfull], z, z, woff))
+        vp_ = jax.lax.dynamic_update_slice(
+            vp_, vrow[:, :, nfull * page:, :], (wpids[nfull], z, woff, z))
+    return kp_, vp_
+
+
+def _write_token(cache, k, v, pids, loff, scales=None):
+    """Write ONE decode position per slot into a block's pools: `k`/`v`
+    (S, Hkv, hd) land at in-page offset `loff[s]` of pool page
+    `pids[s]` (inactive lanes arrive redirected to trash page 0).
+    `cache` is the block's (K, V) pools, or (K, V, K-scale, V-scale)
+    for int8 KV with `scales` = the (S, Hkv) per-head scale pair;
+    returns the same tuple, written. On TPU the `paged_kv_write`
+    kernel family updates the donated pools in place
+    (`ops/pallas_paged_kv_write.py`), so neither `decode_step` nor the
+    `decode_chunked` scan copies a pool; on CPU, under
+    `DL4J_TPU_NO_PALLAS_PAGED_KV_WRITE`, or where the family's probe
+    declined, the XLA scatter runs — bit-identical on every page but
+    the trash page, at two whole-pool layout copies per pool per step
+    on the TPU. The speculative draft and verifier write their pools
+    the same way."""
+    from deeplearning4j_tpu.ops.pallas_paged_kv_write import (
+        paged_kv_write_or_none,
+        scatter_kv_write,
+    )
+
+    args = (*cache[:2], k, v, pids, loff, *cache[2:], *(scales or ()))
+    out = paged_kv_write_or_none(*args)
+    if out is None:
+        out = scatter_kv_write(*args)
+    return out[:len(cache)]
+
+
 class KVPages:
     kind = "kv"
 
@@ -146,13 +203,13 @@ class KVPages:
                 # write per head; the scale lands at the SAME
                 # (page, head, offset) the payload does, so
                 # trash-page redirection masks both together
-                kq, ksc = env.quantize_heads(k)
-                vq, vsc = env.quantize_heads(v)
-                kp_, vp_, ks_, vs_ = env.write_token(
+                kq, ksc = quantize_heads(k)
+                vq, vsc = quantize_heads(v)
+                kp_, vp_, ks_, vs_ = _write_token(
                     cache, kq, vq, d.pids, d.loff, (ksc, vsc))
             else:
                 ks_ = vs_ = None
-                kp_, vp_ = env.write_token(cache, k, v, d.pids, d.loff)
+                kp_, vp_ = _write_token(cache, k, v, d.pids, d.loff)
         # kernel-dispatched paged attention: on TPU the Pallas
         # kernel streams pages straight from the pool (no dense
         # gather transient — the decode path's dominant cache-
@@ -180,17 +237,16 @@ class KVPages:
                 # position): abs-max over the hd axis of each
                 # lane-last layout
                 kp_, vp_, ks_, vs_ = cache
-                kcol, kscol = env.quantize_heads(kcol, axis=2)
-                vrow, vscol = env.quantize_heads(vrow, axis=3)
-                ks_ = env.write_scale_pages(ks_, kscol, d.wpids, z0,
-                                            env.page)
-                vs_ = env.write_scale_pages(vs_, vscol, d.wpids, z0,
-                                            env.page)
-                kp_, vp_ = env.write_pages(kp_, vp_, kcol, vrow,
-                                           d.wpids, z0)
+                kcol, kscol = quantize_heads(kcol, axis=2)
+                vrow, vscol = quantize_heads(vrow, axis=3)
+                ks_ = _write_scale_pages(ks_, kscol, d.wpids, z0, env.page)
+                vs_ = _write_scale_pages(vs_, vscol, d.wpids, z0, env.page)
+                kp_, vp_ = _write_pages(kp_, vp_, kcol, vrow, d.wpids, z0,
+                                        env.page)
                 return x, (kp_, vp_, ks_, vs_)
             kp_, vp_ = cache
-            kp_, vp_ = env.write_pages(kp_, vp_, kcol, vrow, d.wpids, z0)
+            kp_, vp_ = _write_pages(kp_, vp_, kcol, vrow, d.wpids, z0,
+                                    env.page)
             return x, (kp_, vp_)
 
     def prefill_chunk(self, p, x, cache, d):
@@ -206,17 +262,17 @@ class KVPages:
         with jax.named_scope("kv.write"):
             if env.kv_quant:
                 kp_, vp_, ks_, vs_ = cache
-                kcol, kscol = env.quantize_heads(kcol, axis=2)
-                vrow, vscol = env.quantize_heads(vrow, axis=3)
-                ks_ = env.write_scale_pages(ks_, kscol, d.wpids, d.woff,
-                                            env.page)
-                vs_ = env.write_scale_pages(vs_, vscol, d.wpids, d.woff,
-                                            env.page)
+                kcol, kscol = quantize_heads(kcol, axis=2)
+                vrow, vscol = quantize_heads(vrow, axis=3)
+                ks_ = _write_scale_pages(ks_, kscol, d.wpids, d.woff,
+                                         env.page)
+                vs_ = _write_scale_pages(vs_, vscol, d.wpids, d.woff,
+                                         env.page)
             else:
                 kp_, vp_ = cache
                 ks_ = vs_ = None
-            kp_, vp_ = env.write_pages(kp_, vp_, kcol, vrow, d.wpids,
-                                       d.woff)
+            kp_, vp_ = _write_pages(kp_, vp_, kcol, vrow, d.wpids, d.woff,
+                                    env.page)
         # attend AFTER the write: the chunk attends to itself
         # through the cache, which is exactly causal with the
         # <= qpos mask; the auto path walks the slot's page row

@@ -1,112 +1,59 @@
-"""Continuous-batching decode engine: PAGED KV cache + chunked prefill
-over slotted iteration-level scheduling.
+"""Continuous-batching decode engine: the scheduler.
 
 `models/transformer.generate` is a whole-batch synchronous sampler:
 every request in a batch decodes the same number of tokens in lockstep,
 so at mixed output lengths every request waits for the slowest sequence
-and the chip idles between calls. The r4 decode profile concluded that
-at serving shapes decode is dispatch+cache-bandwidth bound and
-"throughput scales with batch, not with further kernel work" — the
-batch dimension is therefore the scheduling resource. This engine turns
-it into a pool of `n_slots` decode **slots** (Orca's iteration-level
-scheduling, OSDI '22), with the KV memory behind the slots managed as
-**pages** (PagedAttention, Kwon et al., SOSP '23) and long prompts
-prefilled in **chunks interleaved with decode** (Sarathi-Serve,
-Agrawal et al., 2024):
+and the chip idles between calls. At serving shapes decode is
+dispatch+cache-bandwidth bound, so the batch dimension is the
+scheduling resource. `DecodeEngine` turns it into a pool of `n_slots`
+decode **slots** (Orca's iteration-level scheduling, OSDI '22) over KV
+memory managed as **pages** (PagedAttention, SOSP '23), with long
+prompts prefilled in **chunks interleaved with decode** (Sarathi-Serve,
+2024). It is four parts, and the imports point one way:
 
-- **one paged KV pool** per block, allocated once and advanced in
-  place (donated through the jitted step): K `(P, Hkv, hd, page)`,
-  V `(P, Hkv, page, hd)` — the r4 decode layouts with the length axis
-  cut into fixed-size pow-2 pages. Page 0 is a reserved trash page that
-  absorbs masked writes from inactive slots; every other page is
-  allocated to exactly one request at a time. The decode step's one
-  new position per slot reaches the pools through `_write_token`: on
-  TPU the `paged_kv_write` kernel (`ops/pallas_paged_kv_write.py`)
-  rewrites only the tile that holds the position, pools aliased
-  input→output, so neither `decode_step` nor the `decode_chunked` scan
-  copies a pool; on CPU, under `DL4J_TPU_NO_PALLAS_PAGED_KV_WRITE`, or
-  where the family's probe declined, the XLA scatter runs (same pools
-  bit for bit outside the trash page; on TPU it costs two whole-pool
-  layout copies per pool per step). A per-slot **page table**
-  `(S, n_pages_max)` lives on device; attention dispatches through
-  `ops.attention.paged_attention_step_auto` — on TPU the Pallas
-  paged-attention kernel (`ops/pallas_paged_attention.py`) walks the
-  page table IN PLACE, streaming pages from the pool with no dense
-  transient; on CPU (and under the probe/kill-switch fallback)
-  `ops.attention.paged_gather` reassembles each slot's logical cache
-  in position order and the attention numerics are EXACTLY the dense
-  slotted step's (`cached_attention_step` on the gathered view).
-- **memory-side admission control**: a request needs
-  `ceil(span/page)` pages (span = padded prefill width or
-  prompt+output, whichever is larger). Pages are allocated at
-  ADMISSION — queued requests hold no memory — and returned to the
-  free list on retirement/expiry/failure, so slots-per-chip is bound
-  by ACTUAL request lengths, not worst-case `max_len` per slot. When
-  the pool is exhausted the queue head WAITS (FIFO) for a retirement
-  to free pages, and the bounded queue gains a memory axis: beyond
-  `max_queued_pages` of aggregate queued page demand, `submit` sheds
-  with the typed `OutOfPagesError` (a `ServerOverloadedError`
-  subclass, `retry_after` included) — the same at-the-door discipline
-  as the count-bounded queue.
-- **a jitted decode step advances ALL active slots every iteration** —
-  per-slot position + active mask make ONE compiled decode shape
-  correct for any mix of sequence lengths; inactive slots' cache
-  writes are redirected to the trash page so a freed (and reallocated)
-  page can never be corrupted by a stale lane.
-- **prefill**: prompts up to the largest `prompt_buckets` entry
-  prefill in ONE dispatch exactly as before (same
-  `_prefill_block_attention` numerics as `generate`), now writing
-  into the slot's pages. Prompts longer than every bucket AND longer
-  than `prefill_chunk` prefill in fixed-size chunks of
-  `prefill_chunk` tokens, at most `prefill_chunk_budget` chunk
-  dispatches per scheduler iteration, INTERLEAVED with decode steps —
-  admitting a 4096-token prompt no longer head-of-line-blocks every
-  in-flight decode. Each chunk attends causally over
-  [prior chunks ‖ itself] through the paged cache
-  (`models.transformer._prefill_chunk_block_attention`); the final
-  chunk samples the first token with the same kp/kd key discipline as
-  `generate`.
-- **a host scheduler loop** admits queued requests into free slots,
-  drives pending prefill chunks, retires slots on EOS / max-tokens /
-  expired deadlines, and delivers tokens per-request as they complete.
+    decode_engine.py     this file: construction, `submit` and the QoS
+      |                  door, the scheduler thread (`_schedule`:
+      |                  `_admit`, prefill and decode dispatches,
+      |                  `_retire_or_poison`, `_emit_token`), weight
+      |                  swap, `stats`, shutdown
+      +-> decode_programs.py   the four jitted programs (`decode_step`,
+      |                        `decode_chunked`, `prefill`,
+      |                        `prefill_chunk_fn`): plan + geometry in
+      +-> page_pool.py         what a page is and who owns it: free
+      |                        list, page table, prefix-cache refcounts,
+      |                        lease ownership
+      +-> kv_handoff.py        everything that moves pages BETWEEN
+                               engines: leases, migration, disaggregated
+                               roles, the cluster prefix cache
+          (all three stand on block_state, kv_transfer, prefix_cache,
+           models/transformer and ops/, and never import this file)
 
-Robustness rides the PR-4 serving tier: a bounded queue sheds with the
-typed `ServerOverloadedError` (+`retry_after`), the page ledger sheds
-with `OutOfPagesError`, a deadline expiring in the queue sheds BEFORE
-prefill, a deadline expiring in flight (mid-prefill or mid-decode)
-frees its slot AND its pages, an optional `CircuitBreaker` gates
-admission and counts device failures, and `drain_and_swap(net)` lets a
-hot reload finish in-flight requests on the old weights, swap, and
-keep serving.
+**The path of a token.** `submit` judges a request at the door (typed
+sheds: `ServerOverloadedError`, `OutOfPagesError`,
+`TenantQuotaExceededError`, `DeadlineExceededError`, breaker) and
+queues it holding a page RESERVATION, no pages. Each iteration of the
+scheduler thread `_admit`s queued requests into free slots (pages taken
+from the pool, the longest cached prefix bound), one-shot prefills short
+prompts at a pow-2 bucket and parks long or prefix-hit ones for
+`_step_prefills` (one chunk an iteration), then `_step_active` advances
+ALL active slots one token — or `decode_chunk` tokens in one dispatch
+when no scheduling event can land inside — syncs once, and
+`_retire_or_poison` delivers each slot's tokens (`_emit_token`), retires
+on EOS / max-tokens and fails a slot whose logits went non-finite, typed,
+while its neighbours keep decoding. The thread is always in one leaf
+phase of `observability.LEAF_PHASES`; the hand-off plane's share of an
+iteration is one `plane.step()` under `housekeeping`.
 
-**Parity guarantee**: the engine traces the SAME per-block helpers as
-`generate` (`models.transformer.GPTPlan`/`_block_heads`/`_block_ffn`/
-`_prefill_block_attention`/`cached_attention_step`-semantics via the
-paged dispatch), and the paged storage is reassembled (fallback) or
-walked (kernel) in logical-position order, so slotted greedy decode
-reproduces whole-batch `generate` argmax-exactly at f32 for the same
-prompts, regardless of admission order, page/slot reuse, or prefill
-chunking (asserted in `tests/test_serving_generate.py`; the kernel-vs-
-gather parity is pinned in `tests/test_pallas_paged_attention.py` and
-by the dispatch probe itself, which checks numerics before trusting
-the kernel).
-
-**Latency tier (PR 8)** — two opt-in mechanisms compose on top:
-
-- `prefix_cache={...}` (`serving.prefix_cache.PrefixCache`): prompts
-  sharing a page-aligned prefix bind the SAME resident pool pages
-  (refcounted, read-only; the first divergent page starts fresh — page-
-  granular copy-on-write), skipping the shared prefill entirely. Under
-  pool pressure, unreferenced cached pages are reclaimed LRU-first, so
-  caching can never shrink effective capacity; every pool rebuild
-  (weight swap, failure recovery) invalidates the cache wholesale.
-- `speculative={"draft": ..., "k": ...}`
-  (`serving.speculative.SpeculativeDecoder`): a draft model proposes k
-  tokens per slot per iteration, verified in ONE batched target chunk
-  through the paged cache; greedy emission stays argmax-exact and
-  sampled emission distribution-exact for any draft (see that module's
-  docstring). The draft keeps its own paged pools behind the same page
-  table, so prefix hits skip the draft prefill too.
+Robustness rides the PR-4 serving tier: a deadline expiring in the
+queue sheds BEFORE prefill, one expiring in flight frees its slot AND
+its pages, an optional `CircuitBreaker` gates admission and counts
+device failures, a failed donated dispatch fails what the lost pools
+backed and rebuilds them, and `drain_and_swap(net)` lets a hot reload
+finish in-flight requests on the old weights, swap, and keep serving.
+Opt-in on top: `prefix_cache=` (`serving/prefix_cache.py`: page-aligned
+shared prefixes bind the same resident pages, reclaimed LRU-first under
+pressure) and `speculative=` (`serving/speculative.py`: a draft proposes
+k tokens a slot, verified in one batched chunk).
 """
 from __future__ import annotations
 
@@ -308,60 +255,6 @@ class _TenantState:
                 "weight": self.weight}
 
 
-def _write_pages(kp_, vp_, kcol, vrow, wpids, woff, page):
-    """Scatter one contiguous prefill span (1, Hkv, hd, W) /
-    (1, Hkv, W, hd) into the pool pages `wpids`: floor(W/page) aligned
-    full-page writes, then a partial tail (a non-pow-2 fallback bucket,
-    or a sub-page chunk) at in-page offset `woff` — which is nonzero
-    only in the W < page chunked case, where chunk-aligned pow-2
-    offsets guarantee the span never straddles a page boundary. Module
-    level (not an engine closure) so the speculative draft's prefill
-    mirrors the exact same write discipline into its own pools."""
-    import jax
-    import jax.numpy as jnp
-
-    W = kcol.shape[3]
-    z = jnp.zeros((), jnp.int32)
-    nfull = W // page
-    for j in range(nfull):
-        kp_ = jax.lax.dynamic_update_slice(
-            kp_, kcol[..., j * page:(j + 1) * page], (wpids[j], z, z, z))
-        vp_ = jax.lax.dynamic_update_slice(
-            vp_, vrow[:, :, j * page:(j + 1) * page, :], (wpids[j], z, z, z))
-    if W % page:
-        kp_ = jax.lax.dynamic_update_slice(
-            kp_, kcol[..., nfull * page:], (wpids[nfull], z, z, woff))
-        vp_ = jax.lax.dynamic_update_slice(
-            vp_, vrow[:, :, nfull * page:, :], (wpids[nfull], z, woff, z))
-    return kp_, vp_
-
-
-def _write_token(cache, k, v, pids, loff, scales=None):
-    """Write ONE decode position per slot into a block's pools: `k`/`v`
-    (S, Hkv, hd) land at in-page offset `loff[s]` of pool page
-    `pids[s]` (inactive lanes arrive redirected to trash page 0).
-    `cache` is the block's (K, V) pools, or (K, V, K-scale, V-scale)
-    for int8 KV with `scales` = the (S, Hkv) per-head scale pair;
-    returns the same tuple, written. On TPU the `paged_kv_write`
-    kernel family updates the donated pools in place
-    (`ops/pallas_paged_kv_write.py`); on CPU, under
-    `DL4J_TPU_NO_PALLAS_PAGED_KV_WRITE`, or where the family's probe
-    declined, the XLA scatter runs — bit-identical on every page but
-    the trash page, at two whole-pool layout copies per pool per step
-    on the TPU. Module level so the speculative draft and verifier
-    write their pools the same way."""
-    from deeplearning4j_tpu.ops.pallas_paged_kv_write import (
-        paged_kv_write_or_none,
-        scatter_kv_write,
-    )
-
-    args = (*cache[:2], k, v, pids, loff, *cache[2:], *(scales or ()))
-    out = paged_kv_write_or_none(*args)
-    if out is None:
-        out = scatter_kv_write(*args)
-    return out[:len(cache)]
-
-
 def _dispatched(thunk):
     """Run one compiled dispatch INCLUDING its host materialization,
     tagging any exception raised so the caller can tell a FAILED
@@ -420,10 +313,6 @@ class DecodeEngine:
     prefill_chunk : pow-2 chunk width for chunked prefill of long
         prompts. Chunking activates for prompts longer than both the
         largest bucket and this value (and only when it is < max_len).
-    prefill_chunk_budget : max prefill-chunk dispatches per scheduler
-        iteration — the knob trading admission latency of long prompts
-        against decode latency of in-flight requests. 1 interleaves
-        one chunk between consecutive decode steps.
     max_queue : bounded admission queue; beyond it `submit` sheds with
         the typed `ServerOverloadedError`.
     eos_token : optional token id that retires a slot early.
@@ -501,7 +390,6 @@ class DecodeEngine:
                  max_queued_pages: Optional[int] = None,
                  prompt_buckets: Sequence[int] = (32, 64, 128),
                  prefill_chunk: int = 256,
-                 prefill_chunk_budget: int = 1,
                  max_queue: int = 64,
                  default_timeout: Optional[float] = None,
                  eos_token: Optional[int] = None,
@@ -536,8 +424,6 @@ class DecodeEngine:
             raise ValueError("page_size must be a power of two")
         if prefill_chunk < 1 or prefill_chunk & (prefill_chunk - 1):
             raise ValueError("prefill_chunk must be a power of two")
-        if prefill_chunk_budget < 1:
-            raise ValueError("prefill_chunk_budget must be >= 1")
         if pool_pages is not None and pool_pages < 1:
             raise ValueError("pool_pages must be >= 1")
         if max_queued_pages is not None and max_queued_pages < 0:
@@ -614,7 +500,6 @@ class DecodeEngine:
         self.eos_token = eos_token
         self.top_k = top_k
         self.decode_chunk = decode_chunk
-        self.prefill_chunk_budget = prefill_chunk_budget
         self.breaker = breaker
         self.step_hooks: List[Callable] = list(step_hooks)
         self._requested_max_len = max_len
@@ -671,12 +556,7 @@ class DecodeEngine:
         # that thread alone, read by stats()
         self._phases = observability.ThreadPhases()
         self._chunk_ewma = 0.0  # guarded by: _cond
-        # KV handoff plane (kv_transfer): disagg role, the sender-side
-        # lease ledger, and the scheduler's migrate-everything switch
-        from deeplearning4j_tpu.serving.kv_transfer import LeaseTable
         self._role = role
-        self._leases = LeaseTable(ttl=handoff_ttl)  # guarded by: _cond
-        self._migrate_all = False  # guarded by: _cond
         # counters (observable state for tests/telemetry)
         self.submitted = 0  # guarded by: _cond
         self.served = 0  # guarded by: _cond
@@ -690,7 +570,6 @@ class DecodeEngine:
         self.decode_steps = 0  # guarded by: _cond
         self.active_slot_steps = 0  # guarded by: _cond
         self.tokens_generated = 0  # guarded by: _cond
-        self.pages_in_use_peak = 0  # guarded by: _cond
         self.swaps = 0  # guarded by: _cond
         self.weight_casts = 0  # guarded by: _cond
         # QoS counters: batch-lane slots yielded to interactive
@@ -699,51 +578,11 @@ class DecodeEngine:
         self.slo_sheds = 0  # guarded by: _cond
         self.shed_quota = 0  # guarded by: _cond
         self.shed_page_quota = 0  # guarded by: _cond
-        # KV migration counters: slots exported under lease / imported
-        # and resumed, lease resolutions, and outbound KV wire bytes
-        self.migrations_out = 0  # guarded by: _cond
-        self.migrations_in = 0  # guarded by: _cond
-        self.handoffs_committed = 0  # guarded by: _cond
-        self.handoffs_aborted = 0  # guarded by: _cond
-        self.handoffs_expired = 0  # guarded by: _cond
-        self.kv_transfer_bytes = 0  # guarded by: _cond
         # latency-tier counters (prefix cache + speculative decoding)
         self.prompt_tokens = 0  # guarded by: _cond
         self.prefix_hits = 0  # guarded by: _cond
         self.prefix_misses = 0  # guarded by: _cond
         self.prefix_hit_tokens = 0  # guarded by: _cond
-        # cluster prefix tier (`bind_prefix_directory`): the directory,
-        # this engine's holder id, and the peers resolver are all None
-        # until bound — every cluster path is a no-op without them
-        self._prefix_directory = None
-        self._holder_id: Optional[str] = None
-        self._prefix_peers = None  # holder_id -> peer handle, or None
-        self._prefix_fetch_frame_pages = 8
-        self._prefix_fetch_timeout = 5.0
-        self._prefix_min_fetch_pages = 1
-        # scheduler-serviced prefix export queue: RPC threads park an
-        # export request here and wait; the scheduler thread — the only
-        # thread allowed to touch device pools under donation — fills
-        # it between dispatches
-        # guarded by: _cond
-        self._prefix_exports: collections.deque = collections.deque()
-        # single-flight: chains with a cluster fetch in progress, so a
-        # burst of same-prefix admits pulls the pages over the wire
-        # ONCE — the rest wait and re-check the local cache
-        # guarded by: _cond
-        self._prefix_fetching: set = set()
-        # fetched bundles still riding the queue toward the cache
-        # (bound at ADMISSION, not at submit): waiters share the
-        # winner's bundle instead of re-fetching; TTL'd by the fetch
-        # timeout, duplicate binds dropped by admission's stale-check
-        # guarded by: _cond
-        self._prefix_fetch_ready: dict = {}
-        self.prefix_fetches = 0  # guarded by: _cond
-        self.prefix_fetch_fallbacks = 0  # guarded by: _cond
-        self.prefix_fetch_bytes = 0  # guarded by: _cond
-        self.prefix_fetch_seconds = 0.0  # guarded by: _cond
-        self.prefix_exports_served = 0  # guarded by: _cond
-        self.cluster_prefix_hit_tokens = 0  # guarded by: _cond
         # routed experts and recurrent state (composed blocks): top-k
         # choices made by active slots in decode steps, those that fell
         # on experts held here, held experts hit (summed over blocks
@@ -783,9 +622,8 @@ class DecodeEngine:
                     bound_ms=round(bound, 3)))
         self.metrics.gauge("decode_engine_queued",
                            lambda: len(self._queue))
-        self.metrics.gauge(
-            "decode_engine_pages_in_use",
-            lambda: self.pool_pages - len(self._free_pages))
+        self.metrics.gauge("decode_engine_pages_in_use",
+                           lambda: self._pool.in_use())
         if self._tp_degree > 1:
             # per-shard gauges carry a {tp_rank} label (parsed out of
             # the series name by MetricsRegistry.exposition — one
@@ -803,6 +641,17 @@ class DecodeEngine:
             # server-owned breaker already feeds the shared recorder
             self.breaker.on_event = lambda state: self.recorder.event(
                 "breaker", state=state)
+        # everything that moves KV pages between engines: leases,
+        # migration, disaggregated roles, the cluster prefix cache. It
+        # gets the lock, the pool of each build and four callables,
+        # never the engine
+        from deeplearning4j_tpu.serving.kv_handoff import HandoffPlane
+        self._pool = None  # the PagePool of the current build
+        self._plane = HandoffPlane(
+            self._cond, role=role, handoff_ttl=handoff_ttl,
+            recorder=self.recorder, breaker=self.breaker,
+            read_slot=self._read_slot, write_slot=self._write_slot,
+            enqueue_resumed=self._enqueue_resumed, in_flight=self.pending)
         self._build(net)
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="decode-engine-scheduler")
@@ -817,11 +666,9 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from deeplearning4j_tpu.models.transformer import (
-            GPTPlan,
-            _sample_logits,
-        )
-        from deeplearning4j_tpu.serving import block_state
+        from deeplearning4j_tpu.models.transformer import GPTPlan
+        from deeplearning4j_tpu.serving import block_state, decode_programs
+        from deeplearning4j_tpu.serving.page_pool import PagePool
 
         plan = GPTPlan(net)
         self._refuse_unsupported(plan)
@@ -841,10 +688,7 @@ class DecodeEngine:
             L = min(L, plan.emb.max_length)
         if L < 2:
             raise ValueError(f"max_len {L} leaves no room to decode")
-        S = self.n_slots
-        emb_i, block_is = plan.emb_i, plan.block_is
-        layers, emb, cdt = plan.layers, plan.emb, plan.cdt
-        top_k = self.top_k
+        S, cdt = self.n_slots, plan.cdt
         buckets = tuple(b for b in self._prompt_buckets if b <= L) or \
             (min(32, L),)
         from deeplearning4j_tpu.serving.model_server import _bucket
@@ -886,266 +730,18 @@ class DecodeEngine:
         kv_quant = "int8" if (self._quantize_cfg is not None
                               and self._quantize_cfg.get("kv") == "int8"
                               and _qz.int8_kv_enabled()) else None
-        quantize_heads = _qz.quantize_heads
-        write_scale_pages = _qz._write_scale_pages
-
-        from deeplearning4j_tpu.models.transformer import _top_k_filter
-
-        def scale_and_filter(logits, temps):
-            """Dynamic-temperature scale + shared top-k truncation.
-            `temps` broadcasts over the row dim; <= 0 rows are scaled by
-            1 (their categorical draw is discarded for greedy argmax)."""
-            safe_t = jnp.where(temps > 0, temps, 1.0).astype(logits.dtype)
-            return _top_k_filter(logits / safe_t[..., None], top_k)
-
-        def sample_slots(logits, keys, temps):
-            """Per-slot sampling: greedy argmax where temps <= 0 (the
-            parity-pinned path — identical to `_sample_logits` at
-            temperature 0), per-slot-key categorical otherwise."""
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            ks = jax.vmap(jax.random.split)(keys)      # (S, 2, 2)
-            new_keys, subs = ks[:, 0], ks[:, 1]
-            scaled = scale_and_filter(logits, temps)
-            sampled = jax.vmap(
-                lambda k, lg: jax.random.categorical(k, lg))(subs, scaled)
-            return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy), \
-                new_keys
-
-        def logits_ok(logits, active):
-            """Per-slot non-finite screen, the predict path's breaker
-            discipline applied to generation: a slot whose logits go
-            NaN/Inf must FAIL typed (and count toward the breaker), not
-            'succeed' with garbage argmax tokens. Returns (S,) bool;
-            inactive rows pass — freed slots hold stale state by
-            design. Per-slot attribution means one poisoned sequence
-            does not take healthy neighbors down with it."""
-            row_ok = jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
-                             axis=-1)
-            return jnp.where(active, row_ok, True)
-
-        def write_pages(kp_, vp_, kcol, vrow, wpids, woff):
-            return _write_pages(kp_, vp_, kcol, vrow, wpids, woff, page)
 
         # what each block keeps between tokens, by the kind the plan
         # declares for it (serving/block_state.py): paged K/V pools or
         # per-slot recurrent arrays
         states = block_state.block_states(plan, SimpleNamespace(
             n_slots=S, page=page, pool_pages=pool_pages, cdt=cdt,
-            kv_quant=kv_quant, tp_shard=tp_shard, tp_axis=tp_axis,
-            quantize_heads=quantize_heads, write_token=_write_token,
-            write_pages=write_pages,
-            write_scale_pages=write_scale_pages))
+            kv_quant=kv_quant, tp_shard=tp_shard, tp_axis=tp_axis))
         n_held = block_state.moe_held(plan)
-
-        # logprob returns (ROADMAP 5(c)): K > 0 makes every sampler
-        # site also emit (chosen logprob, top-K logprobs, top-K ids)
-        # from the UNSCALED model distribution — the values are a
-        # report on the model, not on the temperature/top-k sampling
-        # transform, so greedy and sampled requests read the same
-        # per-token numbers. Incompatible with speculative decoding
-        # and TP (validated at construction), so when K > 0 the extra
-        # tuple never has to cross a shard_map boundary.
-        K = self._logprobs_k
-
-        def lp_math(logits, chosen_tok):
-            lsm = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            chosen = jnp.take_along_axis(
-                lsm, chosen_tok[..., None].astype(jnp.int32),
-                axis=-1)[..., 0]
-            top_v, top_i = jax.lax.top_k(lsm, K)
-            return chosen, top_v, top_i.astype(jnp.int32)
-
-        def _shard(fn, n_in, n_out):
-            """Identity on one device; under TP the body becomes the
-            per-shard program of a `shard_map` over the tp mesh
-            (serving/tp_engine.py) — params head/width-sharded, pools
-            head-sharded, page table and slot state replicated."""
-            if tp is None:
-                return fn
-            return tp.shard(fn, n_in=n_in, n_out=n_out)
-
-        # every program's first argument `bp` is the engine's resident
-        # weights (`GPTPlan.resident_weights`, made once per build):
-        # embedding and blocks in the compute dtype, trailing norms and
-        # head in the param dtype. No program converts a weight
-        def decode_step(bp, caches, page_table, tok, pos, keys, temps,
-                        active):
-            """Advance ALL slots one token: inactive slots are masked
-            (token/position carried through unchanged, cache writes
-            redirected to the trash page so a reallocated page is never
-            corrupted), so every iteration compiles to this single
-            shape."""
-            x = bp[emb_i]["W"][tok]
-            if emb.positional:
-                x = x + bp[emb_i]["P"][jnp.minimum(pos, emb.max_length - 1)]
-            x = emb.scaled(x).astype(cdt)
-            wpos = jnp.minimum(pos, L_logical - 1)
-            lpage = wpos // page
-            rows = jnp.arange(S)
-            d = SimpleNamespace(
-                page_table=page_table, pos=pos, active=active,
-                loff=wpos % page,
-                # inactive lanes write to the reserved trash page 0
-                pids=jnp.where(active, page_table[rows, lpage], 0),
-                # per-expert counts of the active slots' choices, where
-                # the net routes
-                count_mask=active if n_held else None, counts=[])
-            new_caches = []
-            for bi, i in enumerate(block_is):
-                x, cache = states[bi].decode(bp[i], x, caches[bi], d)
-                new_caches.append(cache)
-            logits = plan.final_logits(bp, bp, x)
-            with jax.named_scope("sample"):
-                nxt, new_keys = sample_slots(logits, keys, temps)
-                nxt = jnp.where(active, nxt, tok)
-            new_pos = jnp.where(active, pos + 1, pos)
-            with jax.named_scope("finite-check"):
-                step_ok = logits_ok(logits, active)
-            out = (new_caches, nxt, new_pos, new_keys, step_ok)
-            if K:
-                out += (lp_math(logits, nxt),)
-            if n_held:
-                # (2, held): choices that fell on each held expert,
-                # summed over blocks, and in how many blocks it was hit
-                c = jnp.stack(d.counts)
-                out += (jnp.stack([c.sum(0), (c > 0).sum(0)])
-                        .astype(jnp.int32),)
-            return out
-
-        # the chunk scans the step's body, not the jitted program the
-        # name is rebound to below
-        step_math = decode_step
-
-        def decode_chunked(bp, caches, page_table, tok, pos, keys,
-                           temps, active):
-            """`decode_chunk` iterations of the SAME step body fused into
-            one dispatch via lax.scan — used only when the scheduler
-            proves no admission/retirement/deadline/prefill event can
-            land inside the chunk (page tables are therefore invariant
-            across it). Returns every intermediate token (chunk, S)."""
-            def body(carry, _):
-                out = step_math(bp, *carry[:1], page_table,
-                                *carry[1:], temps, active)
-                # per-STEP outputs (chunk, S): the host attributes a
-                # poisoned step to the right iteration, so a request
-                # that completed via EOS before the bad step still
-                # succeeds
-                return out[:4], (out[1],) + out[4:]
-
-            carry, per_step = jax.lax.scan(
-                body, (caches, tok, pos, keys), None,
-                length=self.decode_chunk)
-            # caches, tok, pos, keys, then toks, oks[, lps][, counts]
-            return carry + per_step
-
-        def prefill(bp, caches, ids, t0, slot, wpids, tok, pos, keys,
-                    temps, kp, kdec, temp):
-            """One-shot prefill: write one prompt's KV into the slot's
-            pages and emit its first token. `ids` is (1, bucket) — pow-2
-            padded; the pad region's KV entries land in the request's
-            own pages and are masked off by position until decode
-            overwrites them, so padding never changes a real token's
-            numerics. The block math is IDENTICAL to `generate`'s
-            prefill (`_prefill_block_attention`) — only the cache
-            write targets pages instead of a slot row."""
-            P = ids.shape[1]
-            x = bp[emb_i]["W"][ids]
-            if emb.positional:
-                x = x + bp[emb_i]["P"][:P]
-            x = emb.scaled(x).astype(cdt)
-            d = SimpleNamespace(wpids=wpids, t0=t0, slot=slot)
-            new_caches = []
-            for bi, i in enumerate(block_is):
-                x, cache = states[bi].prefill(bp[i], x, caches[bi], d)
-                new_caches.append(cache)
-            logits = plan.final_logits(bp, bp, x[0, t0 - 1][None])
-            # kp samples the prefill token, kdec seeds the slot's decode
-            # key — the same split generate() draws from PRNGKey(seed).
-            # Temperature is dynamic per request, so the greedy/sampled
-            # select mirrors sample_slots (same scale_and_filter core)
-            with jax.named_scope("sample"):
-                greedy = _sample_logits(logits, kp, 0.0, 0)
-                drawn = jax.random.categorical(
-                    kp, scale_and_filter(logits, temp[None]),
-                    axis=-1).astype(jnp.int32)
-                tok0 = jnp.where(temp > 0, drawn, greedy)
-            tok = tok.at[slot].set(tok0[0])
-            pos = pos.at[slot].set(t0)
-            keys = keys.at[slot].set(kdec)
-            temps = temps.at[slot].set(temp)
-            with jax.named_scope("finite-check"):
-                ok0 = jnp.all(jnp.isfinite(logits.astype(jnp.float32)))
-            if K:
-                return new_caches, tok, pos, keys, temps, tok0, ok0, \
-                    lp_math(logits, tok0)
-            return new_caches, tok, pos, keys, temps, tok0, ok0
-
-        def prefill_chunk_fn(bp, caches, page_row, ids, off, woff,
-                             t0, slot, wpids, tok, pos, keys, temps, kp,
-                             kdec, temp):
-            """One prefill CHUNK: embed `ids` (1, prefill_chunk) at
-            absolute positions off..off+C-1, write its KV into pages
-            `wpids`, attend causally over [prior chunks ‖ this chunk]
-            through the slot's gathered page row, and emit logits at
-            prompt position t0-1 (only meaningful — and only consumed
-            by the host — on the FINAL chunk). Slot token/position/key
-            state is set every chunk; the final chunk's values are the
-            ones that stick before decode starts."""
-            Cw = ids.shape[1]
-            qpos = off + jnp.arange(Cw)
-            x = bp[emb_i]["W"][ids]
-            if emb.positional:
-                # gather (not dynamic_slice): a padded final chunk may
-                # run past the positional table, and dynamic_slice's
-                # start-clamping would silently shift REAL positions —
-                # the per-position clamp only garbles the masked pad
-                # tail
-                x = x + bp[emb_i]["P"][jnp.minimum(qpos,
-                                                   emb.max_length - 1)]
-            x = emb.scaled(x).astype(cdt)
-            d = SimpleNamespace(wpids=wpids, woff=woff, off=off,
-                                     qpos=qpos, page_row=page_row,
-                                     t0=t0, slot=slot)
-            new_caches = []
-            for bi, i in enumerate(block_is):
-                x, cache = states[bi].prefill_chunk(bp[i], x, caches[bi],
-                                                    d)
-                new_caches.append(cache)
-            r = jnp.clip(t0 - 1 - off, 0, Cw - 1)
-            logits = plan.final_logits(bp, bp, x[0, r][None])
-            with jax.named_scope("sample"):
-                greedy = _sample_logits(logits, kp, 0.0, 0)
-                drawn = jax.random.categorical(
-                    kp, scale_and_filter(logits, temp[None]),
-                    axis=-1).astype(jnp.int32)
-                tok0 = jnp.where(temp > 0, drawn, greedy)
-            tok = tok.at[slot].set(tok0[0])
-            pos = pos.at[slot].set(t0)
-            keys = keys.at[slot].set(kdec)
-            temps = temps.at[slot].set(temp)
-            # screen the whole chunk's hidden states, not only the
-            # logits row: a non-finite mid-prompt chunk poisons the
-            # cache it just wrote, and must fail HERE, typed
-            with jax.named_scope("finite-check"):
-                ok = jnp.all(jnp.isfinite(logits.astype(jnp.float32))) \
-                    & jnp.all(jnp.isfinite(x.astype(jnp.float32)))
-            if K:
-                return new_caches, tok, pos, keys, temps, tok0, ok, \
-                    lp_math(logits, tok0)
-            return new_caches, tok, pos, keys, temps, tok0, ok
-
-        # jit OUTSIDE the shard_map (donation must alias the sharded
-        # pool buffers, and an inner jit would be inlined by the
-        # per-shard trace) — the literal jax.jit assign keeps
-        # graftlint's donation rule pointed at these call sites
-        decode_step = jax.jit(_shard(decode_step, 8, 5),
-                              donate_argnums=(1,) if donate else ())
-        decode_chunked = jax.jit(_shard(decode_chunked, 8, 6),
-                                 donate_argnums=(1,) if donate else ())
-        prefill = jax.jit(_shard(prefill, 13, 7),
-                          donate_argnums=(1,) if donate else ())
-        prefill_chunk_fn = jax.jit(_shard(prefill_chunk_fn, 16, 7),
-                                   donate_argnums=(1,) if donate else ())
+        programs = decode_programs.build_programs(
+            plan, states, n_slots=S, page=page, L_logical=L_logical,
+            decode_chunk=self.decode_chunk, top_k=self.top_k,
+            logprobs=self._logprobs_k, tp=tp, donate=donate)
         # weights placed once per (re)build: permuted + head/width-
         # sharded over the mesh under TP (a weight swap reshards from
         # the swapped net's clean host copy), then cast to the compute
@@ -1182,10 +778,10 @@ class DecodeEngine:
         self._n_pages_max = n_pages_max
         self._L_logical = L_logical
         self.prompt_buckets = buckets
-        self._decode_step = decode_step
-        self._decode_chunked = decode_chunked
-        self._prefill = prefill
-        self._prefill_chunk_fn = prefill_chunk_fn
+        self._decode_step = programs.decode_step
+        self._decode_chunked = programs.decode_chunked
+        self._prefill = programs.prefill
+        self._prefill_chunk_fn = programs.prefill_chunk_fn
         self._kv_quant = kv_quant
         self._kv_quant_bits = 8 if kv_quant \
             else 8 * jnp.dtype(cdt).itemsize
@@ -1215,12 +811,6 @@ class DecodeEngine:
             self._prefix_cache = PrefixCache(page, **pc_kw) \
                 .bind_guard(self._cond).bind_recorder(self.recorder) \
                 .bind_version(self._weight_version)
-            if self._prefix_directory is not None:
-                # a rebuild keeps the engine's cluster membership: the
-                # fresh cache re-publishes under the NEW weight version
-                # as it warms (old entries age out / were dropped)
-                self._prefix_cache.bind_directory(
-                    self._prefix_directory, self._holder_id)
         self._spec = None
         if self._speculative_cfg is not None:
             from deeplearning4j_tpu.serving.speculative import (
@@ -1245,6 +835,19 @@ class DecodeEngine:
                 L_logical=L_logical, pool_pages=pool_pages,
                 top_k=self.top_k, donate=donate, kv_quant=kv_quant,
                 tp=tp, target_weights=self._weights)
+        old = self._pool
+        self._pool = PagePool(
+            self._cond, n_slots=S, page_size=page, pool_pages=pool_pages,
+            n_pages_max=n_pages_max, prefill_width=self._prefill_width,
+            prefix_cache=self._prefix_cache, leases=self._plane.leases,
+            recorder=self.recorder)
+        if old is not None:
+            with self._cond:  # the peak is the engine's, across swaps
+                self._pool.in_use_peak = old.in_use_peak
+        self._plane.on_rebuild(
+            pool=self._pool, weight_version=self._weight_version,
+            kv_quant=kv_quant, max_len=L, n_blocks=len(states),
+            recurrent=self._recurrent)
         self._reset_device_state()
 
     def _refuse_unsupported(self, plan) -> None:
@@ -1282,16 +885,6 @@ class DecodeEngine:
                 "not supported for this network's blocks yet: "
                 + "; ".join(asked))
 
-    def _require_kv_only(self, what: str) -> None:
-        if self._recurrent:
-            from deeplearning4j_tpu.serving.block_state import (
-                RecurrentStateUnsupported,
-            )
-
-            raise RecurrentStateUnsupported(
-                f"{what} moves K/V pages only; this engine's blocks also "
-                "keep per-slot recurrent state")
-
     def _reset_device_state(self) -> None:
         """Fresh page pools + page table + per-slot state (construction,
         weight swap, or recovery after a failed device step — a raised
@@ -1302,8 +895,7 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        plan, S = self._plan, self.n_slots
-        page, P = self.page_size, self.pool_pages
+        S = self.n_slots
         caches = [st.alloc() for st in self._states]
         if self._tp is not None:
             # head axis (axis 1 in every pool + scale-sidecar layout)
@@ -1313,29 +905,35 @@ class DecodeEngine:
             caches = [tuple(self._tp.shard_pool(x) for x in c)
                       for c in caches]
         self._caches = caches
-        self._page_table = jnp.zeros((S, self._n_pages_max), jnp.int32)
         self._tok = jnp.zeros((S,), jnp.int32)
         self._pos = jnp.zeros((S,), jnp.int32)
         self._keys = jnp.stack([jax.random.PRNGKey(i) for i in range(S)])
         self._temps = jnp.zeros((S,), jnp.float32)
-        # the free list and the active mask are read by submit()/stats()
-        # on caller threads — publish the rebuilt state under the lock
-        # (the device arrays above are scheduler-thread-owned)
+        # whole free list, zeroed page table, cleared prefix cache,
+        # leases' page ownership voided
+        self._pool.reset()
+        # the active mask is read by stats() on caller threads — publish
+        # it under the lock (the device arrays above are
+        # scheduler-thread-owned)
         with self._cond:
-            self._free_pages = list(range(P, 0, -1))  # guarded by: _cond
             self._active = np.zeros((S,), bool)  # guarded by: _cond
-            if self._prefix_cache is not None:
-                # the pools just rebuilt: every cached page id is stale
-                self._prefix_cache.clear()
-            # leased page ids index into the pools that just vanished:
-            # void the ownership (the free list above is already whole)
-            # but keep payloads fetchable — a receiver mid-resume holds
-            # host copies and must still be able to finish
-            self._leases.invalidate_pages()
         if self._spec is not None:
             self._spec.reset_state()
 
-    # -- paging arithmetic -------------------------------------------------
+    # the pool's state under the names tests and `chip_smoke.py` read
+    @property
+    def _page_table(self):
+        return self._pool.page_table
+
+    @property
+    def _free_pages(self):
+        return self._pool._free_pages
+
+    @property
+    def pages_in_use_peak(self) -> int:
+        return self._pool.in_use_peak
+
+    # -- prefill geometry --------------------------------------------------
     def _bucket_for(self, t0: int) -> int:
         from deeplearning4j_tpu.serving.model_server import _bucket
 
@@ -1352,52 +950,6 @@ class DecodeEngine:
         C = self.prefill_chunk
         return -(-t0 // C) * C if self._is_chunked(t0) \
             else self._bucket_for(t0)
-
-    def _pages_for(self, t0: int, n_tokens: int) -> int:
-        """Pages a request must hold: its padded prefill width (pad-
-        tail KV lands in owned pages) or prompt+output KV span,
-        whichever is larger. The last generated token is never written
-        back, hence n_tokens - 1. This is the COLD cost — reservations
-        and queue demand always use it, so a cache hit can only shrink
-        the allocation at admission, never under-reserve."""
-        span = max(self._prefill_width(t0), t0 + n_tokens - 1)
-        return -(-span // self.page_size)
-
-    def _pages_for_hit(self, t0: int, n_tokens: int) -> int:
-        """Total LOGICAL pages of a prefix-hit request (shared + owned):
-        the hit path suffix-prefills in chunks whose padded tail never
-        runs past page·ceil(t0/page), so the span is just the KV the
-        request actually writes — always <= the cold `_pages_for`."""
-        return -(-(t0 + n_tokens - 1) // self.page_size)
-
-    def _free_request_pages_locked(self, req: _GenRequest) -> None:
-        """Drop the request's page references: owned pages return to the
-        free list; shared (cached) pages only lose this request's
-        refcount — the cache keeps them resident until LRU reclaim, and
-        a prefix another slot still shares is never freed here."""
-        assert_owned(self._cond, "DecodeEngine._free_request_pages_locked")
-        if req.nodes:
-            self._prefix_cache.release(req.nodes)
-            req.nodes = None
-        if req.pages:
-            self._free_pages.extend(req.pages[req.n_shared:])
-        req.pages = None
-
-    def _promote_prefix_locked(self, req: _GenRequest) -> None:
-        """After a successful prefill, publish the prompt's fully-
-        covered pages into the prefix cache so the NEXT same-prefix
-        request shares them (the request itself keeps decoding on them;
-        page ownership moves to the cache, refcounted)."""
-        assert_owned(self._cond, "DecodeEngine._promote_prefix_locked")
-        if self._prefix_cache is None or req.pages is None:
-            return
-        req.nodes, freed = self._prefix_cache.insert(req.prompt, req.pages,
-                                                     req.nodes or [],
-                                                     tenant=req.tenant)
-        req.n_shared = len(req.nodes)
-        # pages evicted to respect the cache's max_pages cap go straight
-        # back to the pool — a cap-driven eviction must never leak
-        self._free_pages.extend(freed)
 
     # -- observability -----------------------------------------------------
     # graftlint: hot-loop
@@ -1547,8 +1099,8 @@ class DecodeEngine:
                 f"prompt ({T0}) + n_tokens ({n_tokens}) exceeds the "
                 f"engine's max_len {self.max_len} — raise max_len or "
                 "shorten the request")
-        need = self._pages_for(T0, n_tokens)
-        if need > self.pool_pages:
+        need = self._pool.pages_for(T0, n_tokens)
+        if not self._pool.can_hold(need):
             raise ValueError(
                 f"request needs {need} KV pages of {self.page_size} "
                 f"tokens but the pool holds only {self.pool_pages} — "
@@ -1589,14 +1141,12 @@ class DecodeEngine:
         # a prefill-role engine never decodes: the finished prefill is
         # exported under a lease and the caller redirected
         req.handoff = self._role == "prefill"
-        if self._prefix_directory is not None \
-                and self._prefix_peers is not None:
-            # cluster prefix fetch rides the SUBMIT thread — wire I/O
-            # must never stall the scheduler. `_admit` binds the
-            # verified payload under the lock, or drops it and
-            # prefills cold (a fetch wasted on a door refusal below is
-            # accepted; it touched no engine state)
-            req.prefix_import = self._fetch_prefix_for(req.prompt, tenant)
+        # cluster prefix fetch (None unless a directory and peers are
+        # bound) rides the SUBMIT thread — wire I/O must never stall the
+        # scheduler. `_admit` binds the verified payload, or drops it
+        # and prefills cold (a fetch wasted on a door refusal below is
+        # accepted; it touched no engine state)
+        req.prefix_import = self._plane.fetch_prefix_for(req.prompt, tenant)
         with self._cond:
             if self._closed:
                 err = ServerClosedError("decode engine is shut down")
@@ -1727,7 +1277,7 @@ class DecodeEngine:
                 # its retry_after would otherwise promise a retry that
                 # could never succeed
                 self.shed_out_of_pages += 1
-                held = self.pool_pages - len(self._free_pages)
+                held = self._pool.in_use()
                 n_live = sum(1 for r in self._slots if r is not None)
                 retry = max(0.001, self._step_ewma
                             * (len(self._queue) + n_live + 1))
@@ -1804,7 +1354,7 @@ class DecodeEngine:
         for req in self._queue:
             if req.expired(now):
                 self._pages_demand_queued -= req.n_pages
-                self._free_request_pages_locked(req)  # delta-pin release
+                self._pool.release_locked(req)  # delta-pin release
                 self.shed_deadline += 1
                 self._queue_waited(req, now, "expired")
                 self._finish_obs(req, DeadlineExceededError(
@@ -1841,386 +1391,92 @@ class DecodeEngine:
                             burst=burst, max_pages=max_pages,
                             weight=weight)
 
-    # -- cluster-global prefix cache (prefix_directory) --------------------
+    # -- the KV hand-off plane's public surface (serving/kv_handoff.py) ----
     def bind_prefix_directory(self, directory, holder_id: str,
                               peers: Optional[Callable] = None, *,
                               fetch_timeout: float = 5.0,
                               frame_pages: int = 8,
                               min_fetch_pages: int = 1) -> "DecodeEngine":
-        """Join a cluster-wide `PrefixDirectory`: this engine's prefix
-        cache publishes its promoted chains under `holder_id` (and
-        retracts on evict/clear), and — when `peers` is given — a
-        local prefix miss with a directory hit FETCHES the chain's
-        pages from the holder instead of re-prefilling them.
-        `peers(holder_id)` resolves a holder name to an engine-shaped
-        handle exposing `export_prefix` / `fetch_handoff_frame` /
-        `commit_handoff` / `abort_handoff` (an in-process engine, a
-        `ModelServer`, or a `RemoteReplica` — the deployment seam);
-        returning None skips the fetch. Every wire failure degrades to
-        cold prefill — the fetch path is never load-bearing.
-        Chainable."""
-        self._require_kv_only("the cluster prefix cache")
-        with self._cond:
-            self._prefix_directory = directory
-            self._holder_id = str(holder_id)
-            self._prefix_peers = peers
-            self._prefix_fetch_timeout = float(fetch_timeout)
-            self._prefix_fetch_frame_pages = max(1, int(frame_pages))
-            self._prefix_min_fetch_pages = max(1, int(min_fetch_pages))
-            if self._prefix_cache is not None:
-                self._prefix_cache.bind_directory(directory,
-                                                  self._holder_id)
-                chains = self._prefix_cache.chains()
-                if chains:  # late bind: announce what is already warm
-                    directory.publish(self._weight_version,
-                                      self.page_size, chains,
-                                      self._holder_id)
+        """Join a cluster-wide `PrefixDirectory`
+        (`HandoffPlane.bind_prefix_directory`). Chainable."""
+        self._plane.bind_prefix_directory(
+            directory, holder_id, peers, fetch_timeout=fetch_timeout,
+            frame_pages=frame_pages, min_fetch_pages=min_fetch_pages)
         return self
 
-    def prefix_depth(self, prompt_ids,
-                     tenant: Optional[str] = None) -> int:
-        """Fully-covered resident prefix pages this engine holds for
-        `prompt_ids` at its CURRENT weight version — the receiver-side
-        answer a delta sender asks before choosing `skip_pages`."""
-        prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
-        with self._cond:
-            if self._prefix_cache is None:
-                return 0
-            return len(self._prefix_cache.match(prompt, tenant=tenant))
+    def prefix_depth(self, prompt_ids, tenant: Optional[str] = None) -> int:
+        """Resident prefix pages held for `prompt_ids`."""
+        return self._plane.prefix_depth(prompt_ids, tenant)
 
     def prefix_chains(self) -> dict:
-        """Snapshot of every resident chain key at the current weight
-        version — the pull-mode directory refresh for remote replicas
-        whose promotions cannot ride a shared in-process directory."""
-        with self._cond:
-            chains = [] if self._prefix_cache is None \
-                else self._prefix_cache.chains()
-            return {"weight_version": self._weight_version,
-                    "page_size": self.page_size, "chains": chains}
+        """Every resident chain key at the current weight version."""
+        return self._plane.prefix_chains()
 
     def export_prefix(self, prompt_ids, have_pages: int = 0,
                       tenant: Optional[str] = None,
                       frame_pages: Optional[int] = None,
                       timeout: Optional[float] = None) -> dict:
-        """Holder-side cluster-prefix export: serialize this engine's
-        resident chain pages for `prompt_ids` (beyond the receiver's
-        `have_pages`) into a leased `kind="prefix"` handoff and return
-        its framed HEADER — the receiver then drains
-        `fetch_handoff_frame` and commits. The device read runs on the
-        scheduler thread via a parked work item (only that thread may
-        touch the pools between dispatches under donation); this
-        caller blocks up to `timeout`. Typed `KVTransferError` when
-        the chain is no longer resident deeper than `have_pages` (the
-        directory entry was stale)."""
-        self._require_kv_only("a prefix export")
-        from deeplearning4j_tpu.serving.kv_transfer import KVTransferError
-
-        prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
-        item = {"prompt": prompt, "have": max(0, int(have_pages)),
-                "tenant": tenant, "frame_pages": frame_pages,
-                "done": threading.Event(), "result": None, "error": None}
-        with self._cond:
-            if self._closed:
-                raise ServerClosedError("decode engine is shut down")
-            self._prefix_exports.append(item)
-            self._cond.notify_all()
-        wait = self._prefix_fetch_timeout if timeout is None \
-            else float(timeout)
-        if not item["done"].wait(wait):
-            raise KVTransferError(
-                f"prefix export timed out after {wait:.1f}s (scheduler "
-                "busy); fall back to cold prefill")
-        if item["error"] is not None:
-            raise item["error"]
-        return item["result"]
+        """Holder-side cluster-prefix export: the framed header of a
+        leased `kind="prefix"` handoff."""
+        return self._plane.export_prefix(prompt_ids, have_pages, tenant,
+                                         frame_pages, timeout)
 
     def fetch_handoff_header(self, handoff_id: str, skip_pages: int = 0,
                              frame_pages: Optional[int] = None) -> dict:
-        """Framed-transfer entry for ANY leased handoff (migration or
-        prefix export): the blockless header, advanced by `skip_pages`
-        pages the receiver proved it holds (delta transfer). Extends
-        the lease TTL. Typed `KVTransferError` on an unknown lease."""
-        from deeplearning4j_tpu.serving import kv_transfer
-
-        with self._cond:
-            lease = self._leases.touch(handoff_id)
-            if lease is None:
-                raise kv_transfer.KVTransferError(
-                    f"unknown or expired handoff lease {handoff_id!r}; "
-                    "fall back to re-prefill from the prompt")
-            return kv_transfer.payload_header(
-                lease.payload, skip_pages=skip_pages,
-                frame_pages=frame_pages)
+        """The blockless header of a leased handoff."""
+        return self._plane.fetch_handoff_header(handoff_id, skip_pages,
+                                                frame_pages)
 
     def fetch_handoff_frame(self, handoff_id: str, frame: int,
                             skip_pages: int = 0,
                             frame_pages: Optional[int] = None) -> dict:
-        """One bounded frame of a leased handoff (host-side numpy
-        slicing — safe on any RPC thread). Extends the lease TTL, so a
-        receiver mid-drain cannot lose the race against the orphan
-        sweep."""
-        from deeplearning4j_tpu.serving import kv_transfer
-
-        with self._cond:
-            lease = self._leases.touch(handoff_id)
-            if lease is None:
-                raise kv_transfer.KVTransferError(
-                    f"unknown or expired handoff lease {handoff_id!r}; "
-                    "fall back to re-prefill from the prompt")
-            return kv_transfer.slice_frame(
-                lease.payload, frame, skip_pages=skip_pages,
-                frame_pages=frame_pages)
-
-    def _fetch_prefix_for(self, prompt: np.ndarray,
-                          tenant: Optional[str]) -> Optional[dict]:
-        """Submit-thread cluster-prefix fetch: on a local miss with a
-        directory hit, pull the chain's missing pages from a holder
-        and return a verified ``{"payload", "have", "depth",
-        "source"}`` bundle for `_admit` to bind. Returns None — never
-        raises — on any miss, skew, or wire failure: the request then
-        cold-prefills exactly as it would today (the never-slower
-        contract)."""
-        from deeplearning4j_tpu.serving import kv_transfer
-
-        t0 = int(prompt.shape[0])
-        page = self.page_size
-        cap = max(0, (t0 - 1) // page)
-        if cap < self._prefix_min_fetch_pages:
-            return None
-        with self._cond:
-            if self._prefix_cache is None:
-                return None
-            local = len(self._prefix_cache.match(prompt, tenant=tenant))
-        if cap - local < self._prefix_min_fetch_pages:
-            return None
-        hit = self._prefix_directory.best_holder(
-            prompt, tenant, exclude=(self._holder_id,))
-        if hit is None or hit["weight_version"] != self._weight_version \
-                or int(hit["page_size"]) != page:
-            return None
-        depth = min(int(hit["depth"]), cap)
-        if depth - local < self._prefix_min_fetch_pages:
-            return None
-        holder = hit["holders"][0]
-        # single-flight per chain: a same-prefix burst on a cold engine
-        # must not become a thundering herd of identical wire fetches —
-        # one admit pulls the pages, the rest wait (bounded by the
-        # fetch timeout) and re-check the cache the winner filled
-        sf_key = (hit["weight_version"], tenant,
-                  prompt[:depth * page].tobytes())
-        sf_deadline = time.monotonic() + self._prefix_fetch_timeout
-        with self._cond:
-            while sf_key in self._prefix_fetching:
-                remaining = sf_deadline - time.monotonic()
-                if remaining <= 0:
-                    return None  # waited out: cold prefill, never slower
-                self._cond.wait(remaining)
-            if self._prefix_cache is None:
-                return None
-            local = len(self._prefix_cache.match(prompt, tenant=tenant))
-            if depth - local < self._prefix_min_fetch_pages:
-                return None  # the winner's bind covers us: warm admit
-            ready = self._prefix_fetch_ready.get(sf_key)
-            if ready is not None:
-                bundle, expires = ready
-                if time.monotonic() < expires:
-                    # the winner's bundle is still queued toward the
-                    # cache (binding happens at admission, on the
-                    # scheduler thread) — share it instead of pulling
-                    # the same pages over the wire again; every bind
-                    # after the first is dropped by the stale-check
-                    self.recorder.event("prefix-fetch",
-                                        decision="reused", depth=depth)
-                    return dict(bundle)
-                del self._prefix_fetch_ready[sf_key]
-            self._prefix_fetching.add(sf_key)
-        bundle = None
-        try:
-            bundle = self._fetch_prefix_chain(
-                prompt, tenant, hit, depth, local, holder)
-            return bundle
-        finally:
-            with self._cond:
-                if bundle is not None:
-                    now = time.monotonic()
-                    stale = [k for k, (_, exp)
-                             in self._prefix_fetch_ready.items()
-                             if exp <= now]
-                    for k in stale:
-                        del self._prefix_fetch_ready[k]
-                    self._prefix_fetch_ready[sf_key] = (
-                        bundle, now + self._prefix_fetch_timeout)
-                self._prefix_fetching.discard(sf_key)
-                self._cond.notify_all()
-
-    def _fetch_prefix_chain(self, prompt, tenant, hit, depth, local,
-                            holder) -> Optional[dict]:
-        """The wire leg of `_fetch_prefix_for`, run under the chain's
-        single-flight slot: export → frames → verify → commit."""
-        from deeplearning4j_tpu.serving import kv_transfer
-
-        page = self.page_size
-        start = time.monotonic()
-        header = None
-        try:
-            peer = self._prefix_peers(holder)
-            if peer is None:
-                return None
-            header = peer.export_prefix(
-                [int(x) for x in prompt[:depth * page]],
-                have_pages=local, tenant=tenant,
-                frame_pages=self._prefix_fetch_frame_pages,
-                timeout=self._prefix_fetch_timeout)
-            frames = [peer.fetch_handoff_frame(
-                          header["handoff_id"], i, skip_pages=0,
-                          frame_pages=header["frame_pages"])
-                      for i in range(int(header["n_frames"]))]
-            payload = kv_transfer.assemble_payload(header, frames)
-            payload = kv_transfer.verify_payload(
-                payload, weight_version=self._weight_version,
-                kv_quant=self._kv_quant, page_size=page,
-                n_blocks=len(self._caches), max_len=self.max_len,
-                kinds=("prefix",))
-        # graftlint: disable=typed-error  never-slower contract: ANY
-        # fetch-path failure (wire fault, refusal, corruption) degrades
-        # to cold prefill; the typed cause is recorded, not raised
-        except BaseException as e:
-            if header is not None:
-                try:
-                    peer.abort_handoff(header["handoff_id"])
-                # graftlint: disable=typed-error  best-effort abort of
-                # a lease on a peer that may already be dead — its TTL
-                # sweep unpins regardless
-                except BaseException:
-                    pass
-            with self._cond:
-                self.prefix_fetch_fallbacks += 1
-            self.recorder.event(
-                "prefix-fetch", decision="fallback", holder=holder,
-                depth=depth, have=local, error=type(e).__name__)
-            logger.warning(
-                "cluster prefix fetch from %s failed (%s: %s); cold "
-                "prefill", holder, type(e).__name__, e)
-            return None
-        try:
-            peer.commit_handoff(header["handoff_id"])
-        # graftlint: disable=typed-error  commit is an optimization
-        # (early unpin on the holder); its lease TTL unpins regardless
-        except BaseException:
-            logger.warning(
-                "prefix fetch commit_handoff(%s) failed; the holder's "
-                "lease sweep will unpin", header["handoff_id"])
-        dt = time.monotonic() - start
-        nbytes = kv_transfer.payload_nbytes(payload)
-        with self._cond:
-            self.prefix_fetches += 1
-            self.prefix_fetch_bytes += nbytes
-            self.prefix_fetch_seconds += dt
-        omitted = int(payload.get("pages_omitted", 0))
-        self.recorder.event(
-            "prefix-fetch", decision="fetched", holder=holder,
-            depth=depth, have=local,
-            pages=int(payload["pages_shipped"]), skipped=omitted,
-            bytes=nbytes, ms=round(1e3 * dt, 2))
-        return {"payload": payload, "have": omitted, "depth": depth,
-                "source": holder}
-
-    # -- KV handoff public surface (kv_transfer) ---------------------------
-    def migrate_slots(self, wait: Optional[float] = 5.0) -> int:
-        """Export EVERY in-flight request (queued, mid-prefill,
-        decoding) as a leased handoff: each waiter's `result()` raises
-        the `SlotMigratedError` redirect and the pool/coordinator
-        resumes it on a peer. Returns the number of requests marked.
-        Blocks up to `wait` seconds for the scheduler's migration pass
-        to drain the engine (pass `wait=None`/0 for fire-and-forget).
-        Idempotent — an empty engine migrates nothing."""
-        self._require_kv_only("slot migration")
-        with self._cond:
-            if self._closed:
-                raise ServerClosedError("decode engine is shut down")
-            n = len(self._queue) \
-                + sum(1 for r in self._slots if r is not None)
-            if n == 0:
-                return 0
-            self._migrate_all = True
-            self._cond.notify_all()
-            if wait:
-                deadline = time.monotonic() + wait
-                while self._migrate_all or self._queue \
-                        or any(r is not None for r in self._slots):
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        break
-                    self._cond.wait(min(remaining, 0.05))
-        return n
+        """One bounded frame of a leased handoff."""
+        return self._plane.fetch_handoff_frame(handoff_id, frame,
+                                               skip_pages, frame_pages)
 
     def fetch_handoff(self, handoff_id: str) -> dict:
-        """The leased payload for `handoff_id` (extends the lease TTL,
-        so an actively-resuming receiver cannot lose the race against
-        the orphan sweep). Typed `KVTransferError` for an unknown or
-        already-expired lease."""
-        from deeplearning4j_tpu.serving.kv_transfer import KVTransferError
-
-        with self._cond:
-            lease = self._leases.touch(handoff_id)
-            if lease is None:
-                raise KVTransferError(
-                    f"unknown or expired handoff lease {handoff_id!r}; "
-                    "fall back to re-prefill from the prompt")
-            return lease.payload
+        """The leased payload for `handoff_id` (extends its TTL)."""
+        return self._plane.fetch_handoff(handoff_id)
 
     def commit_handoff(self, handoff_id: str) -> bool:
-        """The receiver resumed successfully: release the lease and
-        free the shipped pages on this side. Idempotent (False when the
-        lease is already resolved or expired)."""
-        with self._cond:
-            lease = self._leases.resolve(handoff_id)
-            if lease is None:
-                return False
-            self._release_lease_locked(lease)
-            self.handoffs_committed += 1
-            self._cond.notify_all()
-        self.recorder.event("handoff-commit", handoff_id=handoff_id)
-        return True
+        """The receiver resumed: free the shipped pages. Idempotent."""
+        return self._plane.commit_handoff(handoff_id)
 
     def abort_handoff(self, handoff_id: str) -> bool:
-        """The transfer failed downstream: reclaim the leased pages now
-        instead of waiting out the TTL. Idempotent."""
-        with self._cond:
-            lease = self._leases.resolve(handoff_id)
-            if lease is None:
-                return False
-            self._release_lease_locked(lease)
-            self.handoffs_aborted += 1
-            self._cond.notify_all()
-        self.recorder.event("handoff-abort", handoff_id=handoff_id)
-        return True
+        """The transfer failed downstream: reclaim the pages now."""
+        return self._plane.abort_handoff(handoff_id)
+
+    def migrate_slots(self, wait: Optional[float] = 5.0) -> int:
+        """Export EVERY in-flight request as a leased handoff; each
+        waiter's `result()` raises the `SlotMigratedError` redirect."""
+        return self._plane.migrate_slots(wait)
 
     def resume_submit(self, payload: dict,
                       timeout: Optional[float] = None, *,
                       on_token: Optional[Callable] = None) -> _GenRequest:
-        """Admit a fetched handoff payload: validate it against this
-        engine's weights/geometry (typed `KVTransferError` on ANY
-        mismatch or corruption — nothing is touched), then enqueue a
-        request whose shipped pages re-bind at admission (warm) or that
-        re-prefills from the prompt (cold). The deadline is the
-        SMALLER of the sender's remaining budget and `timeout`.
-        `on_token` re-attaches a stream sink so a mid-stream migration
-        keeps publishing under the sender's cursor."""
-        self._require_kv_only("resuming a migrated slot")
-        from deeplearning4j_tpu.serving.kv_transfer import (
-            KVTransferError,
-            verify_payload,
-        )
+        """Admit a fetched handoff payload (verified, then through
+        `_enqueue_resumed`)."""
+        return self._plane.resume_submit(payload, timeout,
+                                         on_token=on_token)
 
-        if self._role == "prefill":
-            raise KVTransferError(
-                "prefill-role engine does not accept KV handoffs — "
-                "route resumes to a decode-capable replica")
-        payload = verify_payload(
-            payload, weight_version=self._weight_version,
-            kv_quant=self._kv_quant, page_size=self.page_size,
-            n_blocks=len(self._caches), max_len=self.max_len)
+    def resume_generate(self, payload: dict,
+                        timeout: Optional[float] = None, *,
+                        on_token: Optional[Callable] = None):
+        """Blocking `resume_submit`: the TAIL tokens generated here."""
+        return self._plane.resume_generate(payload, timeout,
+                                           on_token=on_token)
+
+    def _enqueue_resumed(self, payload: dict, timeout: Optional[float],
+                         on_token: Optional[Callable]) -> _GenRequest:
+        """The scheduler's door for a handoff payload the plane has
+        verified against this engine's weights and geometry: a request
+        whose shipped pages re-bind at admission (warm) or that
+        re-prefills from the prompt (cold). The deadline is the SMALLER
+        of the sender's remaining budget and `timeout`. No token-rate
+        debit (the sender charged it); the queue bound, the deadline and
+        the tenant's page ceiling apply as in `submit`."""
+        from deeplearning4j_tpu.serving.kv_transfer import KVTransferError
+
         prompt = np.asarray(payload["prompt"], np.int32)
         n_tokens = int(payload["n_tokens"])
         rems = [t for t in (payload.get("deadline_remaining"), timeout)
@@ -2255,7 +1511,7 @@ class DecodeEngine:
                                             np.int32)])
                 req.resumed_at = len(req.tokens)
             t0 = req.prompt.shape[0]
-            req.n_pages = self._pages_for(
+            req.n_pages = self._pool.pages_for(
                 t0, max(1, n_tokens - req.resumed_at))
         else:
             req.import_state = payload
@@ -2320,17 +1576,15 @@ class DecodeEngine:
                 # eviction cannot race the bind; refused typed when the
                 # chain is no longer deep enough (the sender's ladder
                 # re-sends without skip_pages)
-                have = [] if self._prefix_cache is None else \
-                    self._prefix_cache.match(prompt, tenant=req.tenant)
-                if len(have) < omitted:
+                have = self._pool.pin_prefix_locked(prompt, req.tenant,
+                                                    omitted)
+                if have is None:
                     err = KVTransferError(
                         f"delta handoff omits {omitted} prefix pages "
-                        f"but only {len(have)} are resident here; "
-                        "re-send without skip_pages")
+                        "but fewer are resident here; re-send without "
+                        "skip_pages")
                     self._shed_obs(req.trace, err, tenant=req.tenant)
                     raise err
-                have = have[:omitted]
-                self._prefix_cache.acquire(have)
                 req.nodes = have
                 req.n_shared = omitted
             self.submitted += 1
@@ -2342,23 +1596,6 @@ class DecodeEngine:
                             emitted=len(req.tokens))
             self._cond.notify_all()
         return req
-
-    def resume_generate(self, payload: dict,
-                        timeout: Optional[float] = None, *,
-                        on_token: Optional[Callable] = None):
-        """Blocking `resume_submit`: returns only the TAIL tokens this
-        engine generates — the caller splices them after the redirect's
-        already-emitted `tokens`. When the handoff carries logprobs, a
-        dict `{"tokens", "logprobs"}` holding only the tail's share."""
-        req = self.resume_submit(payload, timeout=timeout,
-                                 on_token=on_token)
-        already = len(req.tokens)
-        already_lp = len(req.logprob_values)
-        out = req.result()
-        if req.logprobs:
-            return {"tokens": out[already:],
-                    "logprobs": list(req.logprob_values[already_lp:])}
-        return out[already:]
 
     def generate(self, prompt_ids, n_tokens: int, *,
                  temperature: float = 0.0, seed: int = 0,
@@ -2393,7 +1630,7 @@ class DecodeEngine:
         with self._cond:
             queued = len(self._queue)
             active = sum(1 for r in self._slots if r is not None)
-            held = self.pool_pages - len(self._free_pages)
+            held = self._pool.in_use()
             demand = self._pages_demand_queued
             used_positions = 0
             for r in self._slots:
@@ -2407,8 +1644,7 @@ class DecodeEngine:
                        for name, state in sorted(self._tenants.items())}
             for name, counters in tenants.items():
                 counters["pages_reserved"] = self._tenant_pages_locked(name)
-            leases = len(self._leases)
-            unfetched = self._leases.unfetched()
+            handoff = self._plane.stats()
         occupancy = (100.0 * self.active_slot_steps
                      / (self.decode_steps * self.n_slots)
                      if self.decode_steps else 0.0)
@@ -2439,7 +1675,7 @@ class DecodeEngine:
                "page_size": self.page_size,
                "pool_pages": self.pool_pages,
                "pages_in_use": held,
-               "pages_in_use_peak": self.pages_in_use_peak,
+               "pages_in_use_peak": self._pool.in_use_peak,
                "queued_page_demand": demand,
                "max_queued_pages": self.max_queued_pages,
                "page_fragmentation_pct": round(frag, 1),
@@ -2475,29 +1711,13 @@ class DecodeEngine:
                "shed_quota": self.shed_quota,
                "shed_page_quota": self.shed_page_quota,
                "tenants": tenants,
-               # KV handoff plane: slots exported under lease /
-               # imported, lease resolutions, live leases, wire bytes
-               "migrations_out": self.migrations_out,
-               "migrations_in": self.migrations_in,
-               "handoffs_committed": self.handoffs_committed,
-               "handoffs_aborted": self.handoffs_aborted,
-               "handoffs_expired": self.handoffs_expired,
-               "handoff_leases": leases,
-               "handoffs_unfetched": unfetched,
-               "kv_transfer_bytes": self.kv_transfer_bytes,
-               # cluster prefix plane: unconditional (all zero while no
-               # directory is bound) so the stats-schema contract and
-               # dashboards never branch on key presence
-               "prefix_fetches": self.prefix_fetches,
-               "prefix_fetch_fallbacks": self.prefix_fetch_fallbacks,
-               "prefix_fetch_bytes": self.prefix_fetch_bytes,
-               "prefix_fetch_ms": round(
-                   1e3 * self.prefix_fetch_seconds, 2),
-               "prefix_exports": self.prefix_exports_served,
-               "cluster_prefix_hit_tokens":
-                   self.cluster_prefix_hit_tokens,
+               # the KV hand-off plane's counters: unconditional (all
+               # zero while nothing is bound or migrated) so the
+               # stats-schema contract and dashboards never branch on
+               # key presence
+               **handoff,
                "cluster_prefix_hit_tokens_pct": round(
-                   100.0 * self.cluster_prefix_hit_tokens
+                   100.0 * handoff["cluster_prefix_hit_tokens"]
                    / self.prompt_tokens, 1) if self.prompt_tokens
                    else 0.0,
                "prompt_buckets": list(self.prompt_buckets),
@@ -2597,6 +1817,7 @@ class DecodeEngine:
         deadline = time.monotonic() + drain_timeout
         with self._cond:
             self._closed = True
+            self._plane.close_locked()
             self._cond.notify_all()
         drained = True
         with self._cond:
@@ -2656,11 +1877,11 @@ class DecodeEngine:
                     while self._queue:
                         req = self._queue.popleft()
                         self._pages_demand_queued -= req.n_pages
-                        self._free_request_pages_locked(req)
+                        self._pool.release_locked(req)
                         self._finish_obs(req, ServerClosedError(
                             "engine shut down before this request "
                             "could be served"))
-                    self._drain_prefix_exports_locked(ServerClosedError(
+                    self._plane.fail_all(ServerClosedError(
                         "decode engine is shut down"))
                     if not any(r is not None for r in self._slots):
                         self._abort_pending_swap_locked()
@@ -2672,9 +1893,8 @@ class DecodeEngine:
                     self._admit()
                 ph.enter("housekeeping")
                 self._expire_in_flight()
-                self._step_migrations()
-                self._serve_prefix_exports()
-                self._sweep_leases()
+                if self._plane.step():
+                    self._migrate_in_flight()
                 self._step_prefills()
                 self._step_active()
                 # until the next iteration's first phase: the swap
@@ -2709,25 +1929,23 @@ class DecodeEngine:
             return True
         if self._draining:
             return True  # reach _maybe_swap even with empty slots
-        if self._migrate_all or self._leases.expired_pending():
-            return True  # reach the migration pass / lease sweep
-        if self._prefix_exports:
-            return True  # a peer is waiting on a prefix export
+        if self._plane.pending():
+            return True  # a migration pass, a lease sweep, an export
         return bool(self._queue) and not self._draining
 
     def _fail_all_locked(self, err: BaseException) -> None:
         assert_owned(self._cond, "DecodeEngine._fail_all_locked")
-        self._drain_prefix_exports_locked(err)
+        self._plane.fail_all(err)
         while self._queue:
             req = self._queue.popleft()
             self._pages_demand_queued -= req.n_pages
-            self._free_request_pages_locked(req)
+            self._pool.release_locked(req)
             self._finish_obs(req, err)  # never acquired the breaker
         for s, req in enumerate(self._slots):
             if req is not None:
                 self._slots[s] = None
                 self._active[s] = False
-                self._free_request_pages_locked(req)
+                self._pool.release_locked(req)
                 if self.breaker is not None:
                     # release the request's breaker token — a dropped
                     # half-open probe would wedge the shared breaker in
@@ -2813,8 +2031,8 @@ class DecodeEngine:
         # promote only the CURRENT prompt's fully-covered pages: the
         # latest decoded token's KV is not written yet, so pages
         # touching the decoded tail are not provably complete
-        self._promote_prefix_locked(v)
-        self._free_request_pages_locked(v)
+        self._pool.promote_locked(v, v.prompt, v.tenant)
+        self._pool.release_locked(v)
         self._slots[best] = None
         self._active[best] = False
         emitted = len(v.tokens)
@@ -2830,7 +2048,7 @@ class DecodeEngine:
         v.digests = []
         v.probe = False
         v.preempted += 1
-        v.n_pages = self._pages_for(v.prompt.shape[0],
+        v.n_pages = self._pool.pages_for(v.prompt.shape[0],
                                     max(1, v.n_tokens - emitted))
         self._pages_demand_queued += v.n_pages
         # queue FRONT: the victim was admitted before anything queued,
@@ -2844,7 +2062,7 @@ class DecodeEngine:
         self.recorder.event(
             "preempt", slot=best, reason=reason, tenant=v.tenant,
             victim_emitted=emitted, victim_remaining=v.n_tokens - emitted,
-            head_tenant=head.tenant, free_pages=len(self._free_pages),
+            head_tenant=head.tenant, free_pages=self._pool.n_free(),
             head_need_pages=head.n_pages)
         self._cond.notify_all()
         return (v, old_probe, reason, best)
@@ -2867,8 +2085,6 @@ class DecodeEngine:
         one-shot immediately; a long or prefix-hit one is parked
         mid-prefill and chunk-prefilled by `_step_prefills` interleaved
         with decode."""
-        import jax.numpy as jnp
-
         while True:
             # again after each one-shot prefill, which has phases of its
             # own
@@ -2910,52 +2126,23 @@ class DecodeEngine:
                             head.prompt, head.digests,
                             tenant=head.tenant)
                         pim = head.prefix_import
-                        if pim is not None:
-                            pay = pim["payload"]
-                            if pay["weight_version"] \
-                                    != self._weight_version \
-                                    or int(pay["page_size"]) \
-                                    != self.page_size \
-                                    or not (int(pim["have"])
-                                            <= len(nodes)
-                                            < int(pim["depth"])):
-                                # the fetched bundle went stale between
-                                # submit and admission (weight swap,
-                                # seed-chain eviction, or the local
-                                # cache caught up) — drop it; prefill
-                                # covers the request regardless
-                                head.prefix_import = pim = None
-                                self.recorder.event(
-                                    "prefix-fetch", decision="dropped",
-                                    have=len(nodes))
+                        if pim is not None and \
+                                self._plane.prefix_import_is_stale(
+                                    pim, len(nodes)):
+                            head.prefix_import = pim = None
                         if nodes or pim is not None:
                             # resumed (preempted) requests span only
                             # their REMAINING tokens past the extended
                             # prompt
-                            need = self._pages_for_hit(
+                            need = self._pool.pages_for_hit(
                                 head.prompt.shape[0],
                                 max(1, head.n_tokens - head.resumed_at)) \
                                 - len(nodes)
-                    if need > len(self._free_pages) \
-                            and self._prefix_cache is not None:
-                        # pool pressure: release idle cached pages
-                        # (LRU, leaf-first) — the head's own hit chain
-                        # is pinned so reclaim cannot eat it
-                        self._prefix_cache.acquire(nodes)
-                        try:
-                            reclaimed = self._prefix_cache.reclaim(
-                                need - len(self._free_pages))
-                        finally:
-                            self._prefix_cache.release(nodes)
-                        self._free_pages.extend(reclaimed)
-                        if reclaimed:
-                            self.recorder.event(
-                                "page-reclaim", pages=len(reclaimed),
-                                free_after=len(self._free_pages))
-                    if need > len(self._free_pages):
-                        # page-blocked: a batch slot's pages can cover
-                        # an interactive head (preemption), else wait
-                        # for a retirement to free pages
+                    if not self._pool.make_room_locked(need, nodes):
+                        # page-blocked even after idle cached pages
+                        # were reclaimed: a batch slot's pages can
+                        # cover an interactive head (preemption), else
+                        # wait for a retirement to free pages
                         preempt = self._maybe_preempt_locked(head,
                                                              "pages")
                         if preempt is None:
@@ -3011,7 +2198,7 @@ class DecodeEngine:
                     # acquired at resume_submit — only account here
                     req.n_shared = len(nodes)
                 elif nodes:
-                    self._prefix_cache.acquire(nodes)
+                    self._pool.pin_locked(nodes)
                     req.nodes = nodes
                     req.n_shared = len(nodes)
                     req.hit_len = len(nodes) * self.page_size
@@ -3020,10 +2207,8 @@ class DecodeEngine:
                 elif self._prefix_cache is not None:
                     self.prefix_misses += 1
                 self.prompt_tokens += int(req.prompt.shape[0])
-                req.pages = [n.page_id for n in nodes] + \
-                    [self._free_pages.pop() for _ in range(need)]
-                held = self.pool_pages - len(self._free_pages)
-                self.pages_in_use_peak = max(self.pages_in_use_peak, held)
+                req.pages = self._pool.take_locked(need, nodes)
+                held = self._pool.in_use()
             if nodes:
                 req.trace.event("prefix-bind", shared_pages=req.n_shared,
                                 hit_tokens=req.hit_len)
@@ -3034,16 +2219,23 @@ class DecodeEngine:
                                 hit_tokens=req.hit_len,
                                 pages_in_use=held, tenant=req.tenant,
                                 priority=req.priority)
-            row = np.zeros((self._n_pages_max,), np.int32)
-            row[:len(req.pages)] = req.pages
-            self._page_table = self._page_table.at[slot].set(
-                jnp.asarray(row))
+            self._pool.bind_row(slot, req.pages)
             if req.prefix_import is not None:
                 # fetched cluster-prefix pages scatter into the freshly
                 # allocated tail pages and promote into the local cache
                 # as if prefilled here; ANY failure falls back to
                 # prefilling from the local hit (or cold)
-                self._bind_prefix_import(req)
+                had_hit = req.n_shared > 0
+                gained = self._plane.bind_prefix_import(req)
+                if gained is not None:
+                    with self._cond:
+                        if not had_hit:
+                            # the local lookup missed but the CLUSTER
+                            # hit: fold the request back into the hit
+                            # column
+                            self.prefix_hits += 1
+                            self.prefix_misses -= 1
+                        self.prefix_hit_tokens += gained
             if req.import_state is not None:
                 # shipped KV re-binds directly into the slot: no
                 # prefill — the pages already hold the sender's state
@@ -3145,7 +2337,7 @@ class DecodeEngine:
             # a one-shot prefill grounds the SLO estimator as a single
             # chunk observation (same dispatch scale as a chunk)
             self._chunk_ewma = 0.8 * self._chunk_ewma + 0.2 * (tp1 - tp0)
-            self._promote_prefix_locked(req)
+            self._pool.promote_locked(req, req.prompt, req.tenant)
         if self._spec is not None:
             self._spec.seed_slot(slot, req.seed)
         req.tokens.append(first)
@@ -3159,7 +2351,7 @@ class DecodeEngine:
         if req.handoff:
             # prefill-role (disagg): the freshly computed KV leaves
             # under a lease instead of entering this engine's decode loop
-            self._export_slot(slot, req, attached=False, reason="disagg")
+            self._hand_off(slot, req, attached=False, reason="disagg")
             return
         with self._cond:
             req.slot = slot
@@ -3168,19 +2360,14 @@ class DecodeEngine:
 
     # graftlint: hot-loop
     def _step_prefills(self) -> None:
-        """Drive pending chunked prefills, at most
-        `prefill_chunk_budget` chunk dispatches per scheduler
-        iteration — the interleaving that keeps a long prompt from
-        head-of-line-blocking in-flight decodes."""
-        budget = self.prefill_chunk_budget
+        """Drive pending chunked prefills, one chunk dispatch per
+        scheduler iteration — the interleaving that keeps a long prompt
+        from head-of-line-blocking in-flight decodes."""
         for s in range(self.n_slots):
-            if budget <= 0:
-                return
             req = self._slots[s]
-            if req is None or req.prefill_pos is None:
-                continue
-            self._prefill_chunk_into(s, req)
-            budget -= 1
+            if req is not None and req.prefill_pos is not None:
+                self._prefill_chunk_into(s, req)
+                return
 
     # graftlint: hot-loop
     def _prefill_chunk_into(self, slot: int, req: _GenRequest) -> None:
@@ -3278,7 +2465,7 @@ class DecodeEngine:
         with self._cond:
             self.prefills += 1
             self.tokens_generated += 1
-            self._promote_prefix_locked(req)
+            self._pool.promote_locked(req, req.prompt, req.tenant)
         if self._spec is not None:
             self._spec.seed_slot(slot, req.seed)
         first = int(first[0])
@@ -3290,7 +2477,7 @@ class DecodeEngine:
             self._retire(slot, req)
             return
         if req.handoff:
-            self._export_slot(slot, req, attached=True, reason="disagg")
+            self._hand_off(slot, req, reason="disagg")
             return
         with self._cond:
             self._active[slot] = True
@@ -3308,7 +2495,7 @@ class DecodeEngine:
             if attached:
                 self._slots[slot] = None
                 self._active[slot] = False
-            self._free_request_pages_locked(req)
+            self._pool.release_locked(req)
             self._cond.notify_all()
         err = e if isinstance(e, ServingError) else \
             InferenceFailedError(
@@ -3352,7 +2539,7 @@ class DecodeEngine:
             if attached:
                 self._slots[slot] = None
                 self._active[slot] = False
-            self._free_request_pages_locked(req)
+            self._pool.release_locked(req)
             self.served += 1
             ts = self._tenants.get(req.tenant)
             if ts is not None:
@@ -3368,127 +2555,83 @@ class DecodeEngine:
         self.recorder.event("retire", slot=slot, tokens=len(req.tokens))
         self._finish_obs(req)
 
-    # -- KV handoff / live migration (kv_transfer) -------------------------
-    def _export_slot(self, slot: int, req: _GenRequest, *,
-                     attached: bool = True,
-                     reason: str = "migrate") -> None:
-        """Scheduler-thread export: serialize this slot's decode state
-        (used KV pages of every block + scale sidecars, page span,
-        position/last-token registers, the LIVE per-slot PRNG key, the
-        emitted transcript) into a leased handoff payload, release the
-        slot, and finish the request with the `SlotMigratedError`
-        redirect. Page ownership moves to the lease — freed exactly
-        once by commit, abort, or TTL expiry. Must run on the scheduler
-        thread: the registers it reads are replaced functionally by
-        every dispatch."""
+    # -- what the KV hand-off plane asks of the scheduler --------------------
+    def _read_slot(self, slot: Optional[int], pages: List[int]):
+        """Scheduler-thread device read (every dispatch replaces the
+        buffers functionally): `(registers, blocks, n_pages)` — the
+        pool pages `pages` of every block as host arrays, and, for a
+        slot, its (position, last token, live PRNG key, temperature)
+        with only the pages its position has reached."""
         import jax
         import jax.numpy as jnp
 
-        from deeplearning4j_tpu.serving import kv_transfer
-
-        pos_, tok_, key_, temp_ = jax.device_get(
-            (self._pos[slot], self._tok[slot], self._keys[slot],
-             self._temps[slot]))
-        pos = int(pos_)
-        page = self.page_size
-        used = min(-(-pos // page), len(req.pages))
-        jidx = jnp.asarray(np.asarray(req.pages[:used], np.int32))
+        regs = None
+        if slot is not None:
+            pos_, tok_, key_, temp_ = jax.device_get(
+                (self._pos[slot], self._tok[slot], self._keys[slot],
+                 self._temps[slot]))
+            regs = (int(pos_), int(tok_), np.asarray(key_, np.uint32),
+                    float(temp_))
+            pages = pages[:min(-(-regs[0] // self.page_size), len(pages))]
+        jidx = jnp.asarray(np.asarray(pages, np.int32))
         names = ("k", "v", "ks", "vs") if self._kv_quant else ("k", "v")
-        blocks = []
-        for c in self._caches:
-            blocks.append({name: np.asarray(jax.device_get(arr[jidx]))
-                           for name, arr in zip(names, c)})
-        handoff_id = kv_transfer.LeaseTable.new_id()
-        payload = kv_transfer.build_payload(
-            handoff_id=handoff_id, kind="warm",
-            weight_version=self._weight_version,
-            kv_quant=self._kv_quant, page_size=page,
-            n_blocks=len(self._caches), prompt=req.prompt,
-            n_tokens=req.n_tokens, temperature=req.temperature,
-            seed=req.seed, resumed_at=req.resumed_at,
-            tokens=req.tokens, blocks=blocks, pages_shipped=used,
-            pos=pos, tok=int(tok_), key=np.asarray(key_, np.uint32),
-            temp=float(temp_), tenant=req.tenant, priority=req.priority,
-            preempted=req.preempted, logprobs=req.logprobs,
-            logprob_values=list(req.logprob_values),
-            deadline_remaining=None if req.deadline is None
-            else max(0.0, req.deadline - time.monotonic()))
-        nbytes = kv_transfer.payload_nbytes(payload)
-        with self._cond:
-            self._leases.grant(payload, pages=req.pages,
-                               n_shared=req.n_shared, nodes=req.nodes)
-            req.pages = None  # ownership moved to the lease
-            req.nodes = None
-            if attached:
+        blocks = [{name: np.asarray(jax.device_get(arr[jidx]))
+                   for name, arr in zip(names, c)} for c in self._caches]
+        return regs, blocks, len(pages)
+
+    # graftlint: hot-loop
+    def _write_slot(self, slot: Optional[int], pages: List[int],
+                    blocks: List[dict], regs) -> None:
+        """The reverse of `_read_slot`: scatter `blocks` into the pool
+        pages `pages` (eager `.at[].set`, not a donated dispatch: a
+        failure leaves the pools valid) and, for a slot, restore its
+        registers."""
+        import jax.numpy as jnp
+
+        jidx = jnp.asarray(np.asarray(pages, np.int32))
+        names = ("k", "v", "ks", "vs") if self._kv_quant else ("k", "v")
+        new_caches = []
+        for blk, c in zip(blocks, self._caches):
+            new_c = []
+            for name, arr in zip(names, c):
+                out = arr.at[jidx].set(jnp.asarray(np.asarray(blk[name])))
+                if self._tp is not None:
+                    out = self._tp.shard_pool(out)
+                new_c.append(out)
+            new_caches.append(tuple(new_c))
+        self._caches = new_caches
+        if regs is not None:
+            pos, tok, key, temp = regs
+            self._pos = self._pos.at[slot].set(pos)
+            self._tok = self._tok.at[slot].set(tok)
+            self._keys = self._keys.at[slot].set(jnp.asarray(key))
+            self._temps = self._temps.at[slot].set(temp)
+
+    def _hand_off(self, slot: int, req: _GenRequest, *,
+                  attached: bool = True, reason: str = "migrate") -> None:
+        """A decoding slot leaves under a lease (the prefill role's
+        finished prefill, or the migration pass): the plane takes its
+        state and its pages, then the slot is released and the request
+        finished with the `SlotMigratedError` redirect."""
+        err = self._plane.export_slot(slot, req, reason)
+        if attached:
+            with self._cond:
                 self._slots[slot] = None
                 self._active[slot] = False
-            self.migrations_out += 1
-            self.kv_transfer_bytes += nbytes
-            self._cond.notify_all()
-        if self.breaker is not None:
-            # an export is a routing decision, not sickness: the device
-            # work so far was healthy, and the token must not be dropped
-            self.breaker.record_success(req.probe)
-        req.trace.event("migrate-out", handoff_id=handoff_id, slot=slot,
-                        pos=pos, pages_shipped=used, bytes=nbytes,
-                        reason=reason)
-        self.recorder.event("migrate-out", handoff_id=handoff_id,
-                            slot=slot, pos=pos, pages_shipped=used,
-                            bytes=nbytes, reason=reason)
-        self._finish_obs(req, kv_transfer.SlotMigratedError(
-            f"slot exported under lease {handoff_id} ({reason}); fetch "
-            "the handoff and resume on a peer",
-            handoff_id=handoff_id, tokens=list(req.tokens)))
+                self._cond.notify_all()
+        self._finish_obs(req, err)
 
-    def _export_cold(self, req: _GenRequest, *, reason: str) -> None:
-        """Export a request that holds no (complete) KV — queued, or
-        parked mid-prefill — as a cold handoff: the peer re-prefills
-        from the prompt with the same seed, reproducing the exact
-        output. No pages ride the lease (there is nothing complete to
-        ship), but the payload stays fetchable until resolution."""
-        from deeplearning4j_tpu.serving import kv_transfer
-
-        handoff_id = kv_transfer.LeaseTable.new_id()
-        payload = kv_transfer.build_payload(
-            handoff_id=handoff_id, kind="cold",
-            weight_version=self._weight_version,
-            kv_quant=self._kv_quant, page_size=self.page_size,
-            n_blocks=len(self._caches), prompt=req.prompt,
-            n_tokens=req.n_tokens, temperature=req.temperature,
-            seed=req.seed, resumed_at=req.resumed_at,
-            tokens=req.tokens, blocks=[], pages_shipped=0,
-            tenant=req.tenant, priority=req.priority,
-            preempted=req.preempted, logprobs=req.logprobs,
-            logprob_values=list(req.logprob_values),
-            deadline_remaining=None if req.deadline is None
-            else max(0.0, req.deadline - time.monotonic()))
+    def _migrate_in_flight(self) -> None:
+        """The one-shot migrate-everything pass `migrate_slots()`
+        armed: decoding slots export warm (their KV pages ship), queued
+        and mid-prefill requests export cold (partial KV is never
+        shipped — it is not provably complete)."""
         with self._cond:
-            self._leases.grant(payload)
-            self.migrations_out += 1
-            self._cond.notify_all()
-        req.trace.event("migrate-out", handoff_id=handoff_id,
-                        kind="cold", reason=reason)
-        self.recorder.event("migrate-out", handoff_id=handoff_id,
-                            handoff_kind="cold", reason=reason)
-        self._finish_obs(req, kv_transfer.SlotMigratedError(
-            f"request exported cold under lease {handoff_id} ({reason});"
-            " resume re-prefills from the prompt on a peer",
-            handoff_id=handoff_id, tokens=list(req.tokens)))
-
-    def _step_migrations(self) -> None:
-        """One-shot migrate-everything pass (armed by
-        `migrate_slots()`): decoding slots export warm (their KV pages
-        ship), queued and mid-prefill requests export cold (partial KV
-        is never shipped — it is not provably complete)."""
-        with self._cond:
-            if not self._migrate_all:
-                return
-            self._migrate_all = False
             queued = list(self._queue)
             self._queue.clear()
             for r in queued:
                 self._pages_demand_queued -= r.n_pages
-                self._free_request_pages_locked(r)  # delta-pin release
+                self._pool.release_locked(r)  # delta-pin release
             parked = []
             decoding = []
             for s, r in enumerate(self._slots):
@@ -3501,274 +2644,37 @@ class DecodeEngine:
             for s, r in parked:
                 self._slots[s] = None
                 self._active[s] = False
-                self._free_request_pages_locked(r)
+                self._pool.release_locked(r)
             self._cond.notify_all()
         for r in queued:
-            self._export_cold(r, reason="migrate")
+            self._finish_obs(r, self._plane.export_cold(r, "migrate"))
         for s, r in parked:
             if self.breaker is not None:
                 self.breaker.record_success(r.probe)
-            self._export_cold(r, reason="migrate")
+            self._finish_obs(r, self._plane.export_cold(r, "migrate"))
         for s, r in decoding:
-            self._export_slot(s, r, attached=True, reason="migrate")
-
-    def _drain_prefix_exports_locked(self, err: BaseException) -> None:
-        """Release every parked `export_prefix` waiter with `err` — a
-        scheduler exiting (shutdown/kill) must not leave RPC threads
-        blocked until their timeout."""
-        assert_owned(self._cond,
-                     "DecodeEngine._drain_prefix_exports_locked")
-        while self._prefix_exports:
-            item = self._prefix_exports.popleft()
-            item["error"] = err
-            item["done"].set()
-
-    def _serve_prefix_exports(self) -> None:
-        """Scheduler-thread service for parked `export_prefix` items:
-        only this thread may read the pools between dispatches (a
-        donated dispatch invalidates the old buffers), so the
-        device_get of the chain's pages happens here; the lease grant
-        pins the chain nodes for the drain, and the waiting RPC thread
-        gets the framed header."""
-        import jax
-        import jax.numpy as jnp
-
-        from deeplearning4j_tpu.serving import kv_transfer
-
-        while True:
-            with self._cond:
-                if not self._prefix_exports:
-                    return
-                item = self._prefix_exports.popleft()
-                nodes = [] if self._prefix_cache is None else \
-                    self._prefix_cache.match(item["prompt"],
-                                             tenant=item["tenant"])
-                depth = len(nodes)
-                have = item["have"]
-                if depth <= have:
-                    item["error"] = kv_transfer.KVTransferError(
-                        f"prefix chain no longer resident here beyond "
-                        f"{have} pages (holds {depth}); the directory "
-                        "entry was stale — fall back to cold prefill")
-                    item["done"].set()
-                    continue
-                self._prefix_cache.acquire(nodes)
-                pages = [n.page_id for n in nodes]
-            try:
-                jidx = jnp.asarray(np.asarray(pages[have:], np.int32))
-                names = ("k", "v", "ks", "vs") if self._kv_quant \
-                    else ("k", "v")
-                blocks = []
-                for c in self._caches:
-                    blocks.append(
-                        {name: np.asarray(jax.device_get(arr[jidx]))
-                         for name, arr in zip(names, c)})
-                handoff_id = kv_transfer.LeaseTable.new_id()
-                payload = kv_transfer.build_payload(
-                    handoff_id=handoff_id, kind="prefix",
-                    weight_version=self._weight_version,
-                    kv_quant=self._kv_quant, page_size=self.page_size,
-                    n_blocks=len(self._caches),
-                    prompt=item["prompt"][:depth * self.page_size],
-                    n_tokens=0, temperature=0.0, seed=0, resumed_at=0,
-                    tokens=[], blocks=blocks,
-                    pages_shipped=depth - have, pages_omitted=have,
-                    tenant=item["tenant"], source=self._holder_id)
-                header = kv_transfer.payload_header(
-                    payload,
-                    frame_pages=item["frame_pages"]
-                    or self._prefix_fetch_frame_pages)
-            # graftlint: disable=typed-error  the export dies typed on
-            # the WAITER (a wire edge), never in the scheduler loop;
-            # the pins release like an aborted lease
-            except BaseException as e:
-                with self._cond:
-                    self._prefix_cache.release(nodes)
-                    self._cond.notify_all()
-                item["error"] = e if isinstance(e, ServingError) else \
-                    kv_transfer.KVTransferError(
-                        f"prefix export failed: {type(e).__name__}: {e}")
-                item["done"].set()
-                continue
-            nbytes = kv_transfer.payload_nbytes(payload)
-            with self._cond:
-                # n_shared == len(pages): lease resolution releases the
-                # pins and returns NOTHING to the free list — the cache
-                # owns these pages; the lease only pins them while the
-                # receiver drains frames
-                self._leases.grant(payload, pages=pages,
-                                   n_shared=len(pages), nodes=nodes)
-                self.prefix_exports_served += 1
-                self._cond.notify_all()
-            item["result"] = header
-            item["done"].set()
-            self.recorder.event(
-                "prefix-export", holder=self._holder_id,
-                handoff_id=handoff_id, pages=depth - have,
-                skipped=have, bytes=nbytes)
-
-    def _sweep_leases(self) -> None:
-        """Orphan reclamation: a receiver that died (or never
-        committed) lets its lease expire; the pages come home here, so
-        a dead receiver can never leak sender pages."""
-        now = time.monotonic()
-        with self._cond:
-            if not self._leases.expired_pending(now):
-                return
-            for lease in self._leases.sweep(now):
-                self._release_lease_locked(lease)
-                self.handoffs_expired += 1
-                self.recorder.event("lease-expired",
-                                    handoff_id=lease.handoff_id)
-            self._cond.notify_all()
-
-    def _release_lease_locked(self, lease) -> None:
-        """Return a resolved lease's page ownership to the pool —
-        mirror of `_free_request_pages_locked`, once per lease."""
-        assert_owned(self._cond, "DecodeEngine._release_lease_locked")
-        if lease.nodes:
-            self._prefix_cache.release(lease.nodes)
-            lease.nodes = None
-        if lease.pages:
-            self._free_pages.extend(lease.pages[lease.n_shared:])
-        lease.pages = None
-
-    # graftlint: hot-loop
-    def _bind_prefix_import(self, req: _GenRequest) -> None:
-        """Bind a verified cluster-prefix fetch into this request's
-        pages: scatter the shipped chain pages into the pool (eager
-        `.at[].set`, like `_import_into`), insert the now-resident
-        chain into the local prefix cache (publishing to the directory
-        exactly as a locally promoted prefix would), and extend the
-        request's hit span so suffix prefill starts at the fetched
-        depth. A failed scatter drops the bundle and keeps the local
-        hit — the request still serves, just colder."""
-        import jax.numpy as jnp
-
-        pim, req.prefix_import = req.prefix_import, None
-        payload = pim["payload"]
-        page = self.page_size
-        have = req.n_shared          # local chain pages already bound
-        depth = int(pim["depth"])
-        omitted = int(payload.get("pages_omitted", 0))
-        shipped = int(payload["pages_shipped"])
-        off = have - omitted         # leading shipped pages held here
-        n_new = depth - have
-        if off < 0 or off + n_new > shipped or n_new <= 0:
-            self.recorder.event("prefix-fetch", decision="dropped",
-                                have=have, depth=depth, skipped=omitted)
-            return
-        try:
-            jidx = jnp.asarray(
-                np.asarray(req.pages[have:depth], np.int32))
-            names = ("k", "v", "ks", "vs") if self._kv_quant \
-                else ("k", "v")
-            new_caches = []
-            for blk, c in zip(payload["blocks"], self._caches):
-                new_c = []
-                for name, arr in zip(names, c):
-                    src = np.asarray(blk[name])[off:off + n_new]
-                    out = arr.at[jidx].set(jnp.asarray(src))
-                    if self._tp is not None:
-                        out = self._tp.shard_pool(out)
-                    new_c.append(out)
-                new_caches.append(tuple(new_c))
-            self._caches = new_caches
-        # graftlint: disable=typed-error  never-slower contract: a
-        # failed scatter falls back to prefilling from the local hit;
-        # the pools stay valid (eager updates are not donated
-        # dispatches)
-        except BaseException as e:
-            with self._cond:
-                self.prefix_fetch_fallbacks += 1
-            self.recorder.event("prefix-fetch", decision="bind-failed",
-                                error=type(e).__name__)
-            logger.warning("cluster prefix bind failed (%s: %s); "
-                           "prefilling from the local hit",
-                           type(e).__name__, e)
-            return
-        with self._cond:
-            pnodes, freed = self._prefix_cache.insert(
-                req.prompt[:depth * page], req.pages[:depth],
-                req.nodes or [], tenant=req.tenant)
-            self._free_pages.extend(freed)
-            gained = (len(pnodes) - have) * page
-            req.nodes = pnodes
-            req.n_shared = len(pnodes)
-            req.hit_len = len(pnodes) * page
-            if have == 0:
-                # the local lookup missed but the CLUSTER hit: fold
-                # the request back into the hit column
-                self.prefix_hits += 1
-                self.prefix_misses -= 1
-            self.prefix_hit_tokens += gained
-            self.cluster_prefix_hit_tokens += gained
-            self._cond.notify_all()
-        req.trace.event("prefix-fetch-bind",
-                        pages=len(pnodes) - have,
-                        hit_tokens=req.hit_len, source=pim["source"])
-        self.recorder.event("prefix-fetch", decision="bound",
-                            holder=pim["source"],
-                            pages=len(pnodes) - have,
-                            hit_tokens=req.hit_len)
+            self._hand_off(s, r, reason="migrate")
 
     # graftlint: hot-loop
     def _import_into(self, slot: int, req: _GenRequest) -> None:
-        """Re-bind a validated warm handoff into a free slot: scatter
-        the shipped pages into every block's pools (+ scale sidecars),
-        restore the position/last-token/temperature registers and the
-        live PRNG key, promote the prompt-covered pages into the prefix
-        cache (weight versions already proven equal by validation), and
-        activate — the next `_step_active` continues the sequence
-        argmax-exact."""
-        import jax.numpy as jnp
-
-        payload = req.import_state
-        shipped = int(payload["pages_shipped"])
-        omitted = int(payload.get("pages_omitted", 0))
-        # delta handoff: the first `omitted` pages are the locally
-        # resident prefix chain (pinned at resume_submit, already in
-        # req.pages as shared pages) — shipped pages land after them
-        jidx = jnp.asarray(np.asarray(
-            req.pages[omitted:omitted + shipped], np.int32))
-        names = ("k", "v", "ks", "vs") if self._kv_quant else ("k", "v")
-        new_caches = []
-        for blk, c in zip(payload["blocks"], self._caches):
-            new_c = []
-            for name, arr in zip(names, c):
-                out = arr.at[jidx].set(
-                    jnp.asarray(np.asarray(blk[name])))
-                if self._tp is not None:
-                    out = self._tp.shard_pool(out)
-                new_c.append(out)
-            new_caches.append(tuple(new_c))
-        self._caches = new_caches
-        pos = int(payload["pos"])
-        self._pos = self._pos.at[slot].set(pos)
-        self._tok = self._tok.at[slot].set(int(payload["tok"]))
-        self._keys = self._keys.at[slot].set(
-            jnp.asarray(np.asarray(payload["key"], np.uint32)))
-        self._temps = self._temps.at[slot].set(float(payload["temp"]))
+        """A warm handoff takes a free slot: the plane re-binds its
+        shipped pages and registers, the prompt-covered pages are
+        promoted into the prefix cache (weight versions already proven
+        equal by validation), and the slot activates — the next
+        `_step_active` continues the sequence argmax-exact."""
+        self._plane.import_into(slot, req)
         with self._cond:
             req.slot = slot
             req.import_state = None
             self._slots[slot] = req
             self._active[slot] = True
-            self.migrations_in += 1
-            self._promote_prefix_locked(req)
-            held = self.pool_pages - len(self._free_pages)
-            self.pages_in_use_peak = max(self.pages_in_use_peak, held)
+            self._pool.promote_locked(req, req.prompt, req.tenant)
             self._cond.notify_all()
         if self._spec is not None:
             # cold draft mirror: proposals start from draft-side
             # garbage and greedy verify rejects them — still
             # target-exact, just zero speedup until the draft re-warms
             self._spec.seed_slot(slot, req.seed)
-        req.trace.event("migrate-in", slot=slot, pages_shipped=shipped,
-                        pos=pos)
-        self.recorder.event("migrate-in", slot=slot,
-                            handoff_id=payload["handoff_id"],
-                            pages_shipped=shipped, pos=pos)
 
     def _import_failure(self, slot: int, req: _GenRequest,
                         e: BaseException) -> None:
@@ -3784,7 +2690,7 @@ class DecodeEngine:
             self.failures += 1
             self._slots[slot] = None
             self._active[slot] = False
-            self._free_request_pages_locked(req)
+            self._pool.release_locked(req)
             self._cond.notify_all()
         err = e if isinstance(e, ServingError) else KVTransferError(
             f"KV import failed: {type(e).__name__}: {e}")
@@ -3807,7 +2713,7 @@ class DecodeEngine:
                 if req.expired(now):
                     expired_queued.append(req)
                     self._pages_demand_queued -= req.n_pages
-                    self._free_request_pages_locked(req)
+                    self._pool.release_locked(req)
                 else:
                     keep.append(req)
             self._queue = keep
@@ -3823,7 +2729,7 @@ class DecodeEngine:
                 with self._cond:
                     self._slots[s] = None
                     self._active[s] = False
-                    self._free_request_pages_locked(req)
+                    self._pool.release_locked(req)
                     self.shed_deadline += 1
                     self._cond.notify_all()
                 if self.breaker is not None:
@@ -3878,7 +2784,7 @@ class DecodeEngine:
             with self._cond:
                 self._slots[s] = None
                 self._active[s] = False
-                self._free_request_pages_locked(req)
+                self._pool.release_locked(req)
                 self._cond.notify_all()
             self._finish_obs(req, err, phase="decode")
         if getattr(e, "_dispatch_failure", False):
@@ -3938,7 +2844,7 @@ class DecodeEngine:
                 self.failures += 1
                 self._slots[s] = None
                 self._active[s] = False
-                self._free_request_pages_locked(req)
+                self._pool.release_locked(req)
                 self._cond.notify_all()
             if self.breaker is not None:
                 self.breaker.record_failure(req.probe)
@@ -4188,7 +3094,7 @@ class DecodeEngine:
                             > self.max_len:
                         misfit.append(r)
                         continue
-                    r.n_pages = self._pages_for(
+                    r.n_pages = self._pool.pages_for(
                         r.prompt.shape[0],
                         max(1, r.n_tokens - r.resumed_at))
                     if r.n_pages > self.pool_pages or \

@@ -539,8 +539,7 @@ class DisaggCoordinator:
     When disagg pays: prefill-heavy mixes (long prompts, short
     completions) keep decode replicas' batch lanes dense instead of
     stalling them behind compute-bound prefills. Decode-heavy mixes pay
-    the wire cost for nothing — stay colocated (see
-    `bench.py serve_disagg`).
+    the wire cost for nothing — stay colocated.
     """
 
     def __init__(self, net, *, prefill_replicas: int = 1,

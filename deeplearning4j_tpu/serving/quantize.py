@@ -42,8 +42,7 @@ in, and rolled back for free by the PR-4/PR-7 reload machinery when
 breached.
 
 Kill switch: ``DL4J_TPU_NO_INT8_KV=1`` (checked by the engine at build
-time AND by the kernel dispatch) forces full-precision pools — the
-bench's ``int8_kv_vs_bf16_device_ms_per_token`` A/B lever.
+time AND by the kernel dispatch) forces full-precision pools.
 """
 from __future__ import annotations
 
@@ -63,8 +62,7 @@ BLOCK_MATMUL_KEYS = ("Wqkv", "Wo", "W1", "W2", "W3")
 def int8_kv_enabled() -> bool:
     """The int8-KV kill switch: ``DL4J_TPU_NO_INT8_KV=1`` makes the
     engine allocate full-precision pools (and the int8 kernel decline
-    dispatch) — the A/B lever `bench.py serve_generate` flips to price
-    ``int8_kv_vs_bf16_device_ms_per_token`` on identical traffic."""
+    dispatch)."""
     return os.environ.get(KV_KILL_ENV, "") not in ("1", "true", "yes")
 
 
@@ -128,8 +126,7 @@ def kv_bytes_per_token(kv_geometry: Sequence[Tuple[int, int]],
                        kv_quant: Optional[str],
                        cache_itemsize: int) -> int:
     """Resident KV bytes one generated token adds across all blocks —
-    the number `stats()["kv_bytes_per_token"]` and the bench satellite
-    report. int8 pools pay 1 byte/element plus the f32 scale sidecar
+    the number `stats()["kv_bytes_per_token"]` reports. int8 pools pay 1 byte/element plus the f32 scale sidecar
     (2 heads-worth of 4-byte scalars per position — ``8·Hkv`` vs the
     payload's ``2·Hkv·hd``, i.e. a 4/hd overhead); full-precision pools
     pay ``cache_itemsize`` per element. `kv_geometry` is

@@ -106,7 +106,7 @@ class SpeculativeDecoder:
             paged_attention_chunk_auto,
             paged_attention_step_auto,
         )
-        from deeplearning4j_tpu.serving.decode_engine import (
+        from deeplearning4j_tpu.serving.block_state import (
             _write_pages,
             _write_token,
         )

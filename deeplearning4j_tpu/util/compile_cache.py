@@ -1,7 +1,7 @@
 """Where this program keeps JAX's persistent compilation cache.
 
 One rule for every entry point that compiles (`chip_smoke.py`,
-`bench.py`, the replica child process, the test harness): where
+`perfbench/run.py`, the replica child process, the test harness): where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
 module sets no other path; otherwise the cache lives at one fixed,
 git-ignored directory inside the checkout. A cache directory that moves

@@ -227,19 +227,19 @@ def test_compile_cache_dir_rule(monkeypatch, tmp_path):
         jax.config.update("jax_compilation_cache_dir", saved)
 
 
-def test_bench_knows_its_peaks_and_refuses_the_cpu(monkeypatch):
-    """A measuring entry point that finds no TPU fails; an MFU is only
-    computed against a peak the table lists for the `device_kind`."""
-    import bench
+def test_the_benchmark_knows_its_peaks_and_refuses_the_cpu():
+    """The measuring entry point finds no TPU here and says so
+    (`NoChipError`: what makes `perfbench/run.py` exit 3 and print no
+    result); a share of a peak is only computed against a peak the
+    table lists for the `device_kind`."""
+    from perfbench.harness import device
 
-    assert bench._peak_flops("TPU v5 lite", bf16=True) == 197e12
+    assert device.peaks("TPU v5 lite")["bf16_flops"] == 197e12
     for kind in ("TPU v9 hypothetical", "cpu"):
-        with pytest.raises(ValueError, match="no peak FLOP/s"):
-            bench._peak_flops(kind, bf16=True)
-    monkeypatch.setattr(sys, "argv", ["bench.py", "gpt"])
-    with pytest.raises(SystemExit) as exit_info:
-        bench.main()
-    assert "found none" in str(exit_info.value)  # non-zero, names the gap
+        with pytest.raises(KeyError, match="no peaks for device_kind"):
+            device.peaks(kind)
+    with pytest.raises(device.NoChipError, match="measures only on a TPU"):
+        device.require_chips(1)
 
 
 def test_supervisor_states_each_childs_platform(tmp_path, monkeypatch):

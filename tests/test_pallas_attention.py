@@ -4,7 +4,7 @@ Runs the kernel in interpreter mode (tests execute on the virtual CPU mesh,
 conftest.py) against the XLA full-attention reference — the accelerated-path
 parity strategy of the reference's cuDNN tests
 (`deeplearning4j-cuda/src/test/.../TestConvolution.java`). A real-TPU
-compile/run of the same kernel happens via bench.py / the driver.
+compile/run of the same kernel happens via `chip_smoke.py train`.
 """
 import jax.numpy as jnp
 import numpy as np

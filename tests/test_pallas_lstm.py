@@ -4,7 +4,7 @@ Reference strategy analogue: `CuDNNGradientChecks.java` /
 `TestConvolution.java` — the accelerated helper must produce the same
 outputs and pass gradient checks against the built-in path. Runs the
 kernel in Pallas interpret mode so the same math executes on the CPU CI
-mesh (Mosaic-compiled execution is exercised on-chip by `bench.py lstm`
+mesh (Mosaic-compiled execution is exercised on-chip by `chip_smoke.py lstm`
 and the probe)."""
 import jax
 import jax.numpy as jnp

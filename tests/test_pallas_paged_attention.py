@@ -16,9 +16,8 @@ Dispatch tests prove the CPU fallback is CLEAN: `paged_attention_or_none`
 declines, and the `*_auto` wrappers return bit-identical results to the
 gather path — tier-1 never executes a compiled Pallas-TPU path.
 
-A real-TPU compile/run of the same kernel happens via bench.py
-(`paged_kernel_vs_gather`) / the driver, gated by the parity-checking
-eager probe.
+A real-TPU compile/run of the same kernel happens via
+`chip_smoke.py serve`, gated by the parity-checking eager probe.
 """
 import numpy as np
 import pytest
@@ -244,7 +243,7 @@ def test_dispatch_declines_on_cpu_and_auto_is_bitwise_gather():
 
 
 def test_kill_switch_forces_gather_path(monkeypatch):
-    """`DL4J_TPU_NO_PALLAS_PAGED_ATTENTION` — the bench's A/B lever —
+    """`DL4J_TPU_NO_PALLAS_PAGED_ATTENTION`
     must decline dispatch before any platform probing."""
     monkeypatch.setenv("DL4J_TPU_NO_PALLAS_PAGED_ATTENTION", "1")
     from deeplearning4j_tpu.ops.pallas_paged_attention import (

@@ -562,25 +562,3 @@ def test_streaming_stats_exposed_on_metrics_page_with_ttft():
         client.close()
     finally:
         server.stop()
-
-
-# --------------------------------------------------- bench smoke
-
-
-@pytest.mark.slow
-def test_bench_serve_stream_smoke(monkeypatch):
-    import bench
-
-    shrunk = dict(
-        bench._SERVE_STREAM_SHAPE, n_tokens=8, n_requests=2, repeats=1,
-        tax_vocab=VOCAB, tax_d_model=32, tax_n_heads=2, tax_n_layers=2,
-        tax_prompt_len=8, tax_max_len=32, tax_n_slots=2,
-        tax_n_requests=2, tax_out_lengths=(4, 6), tax_repeats=1)
-    monkeypatch.setattr(bench, "_SERVE_STREAM_SHAPE", shrunk)
-    metric, value, _, spread = bench.bench_serve_stream()
-    assert metric == "serve_stream_tokens_per_sec" and value > 0
-    b = bench.bench_serve_stream
-    assert set(b.ttft_ms) == {"p50", "p99"}
-    assert set(b.unary_latency_ms) == {"p50", "p99"}
-    assert b.goodput_tax_pct >= 0 and b.publish_us > 0
-    assert b.resume_after_tear_ms >= 0
