@@ -37,10 +37,20 @@ from the pool, the longest cached prefix bound), one-shot prefills short
 prompts at a pow-2 bucket and parks long or prefix-hit ones for
 `_step_prefills` (one chunk an iteration), then `_step_active` advances
 ALL active slots one token — or `decode_chunk` tokens in one dispatch
-when no scheduling event can land inside — syncs once, and
-`_retire_or_poison` delivers each slot's tokens (`_emit_token`), retires
-on EOS / max-tokens and fails a slot whose logits went non-finite, typed,
-while its neighbours keep decoding. The thread is always in one leaf
+when no scheduling event can land inside. A dispatch is two halves:
+*issue* hands the program to the chip and keeps the device handles of
+its tokens in an `_InFlight` record; *collect* is the one host sync,
+after which `_retire_or_poison` delivers each slot's tokens
+(`_emit_token`), retires on EOS / max-tokens and fails a slot whose
+logits went non-finite, typed, while its neighbours keep decoding.
+`_step_active` issues dispatch n+1 BEFORE it collects dispatch n (and
+the one-shot prefills admitted between them), so delivery, retirement
+and admission happen while the chip runs: every input of the next
+program is a device array the last one returned. The host counts the
+tokens it has issued (`_GenRequest.in_flight`), a slot's pages go back
+to the pool only after the last uncollected dispatch that had it active
+(`_vacate_locked`), and whatever reads or rewrites slot state from the
+host `_drain`s the pipeline first. The thread is always in one leaf
 phase of `observability.LEAF_PHASES`; the hand-off plane's share of an
 iteration is one `plane.step()` under `housekeeping`.
 
@@ -91,7 +101,8 @@ class _GenRequest:
     held for a batch. `n_pages` is the page reservation taken at
     submit; `pages` the pool pages held from admission to
     retirement; `prefill_pos` the next chunk offset while a long
-    prompt is mid-prefill (None once decoding)."""
+    prompt is mid-prefill (None once decoding); `in_flight` the tokens
+    issued to the chip for it and not yet collected."""
 
     __slots__ = ("prompt", "n_tokens", "temperature", "seed", "deadline",
                  "event", "tokens", "error", "enqueued_at", "probe",
@@ -99,7 +110,8 @@ class _GenRequest:
                  "prefill_pos", "hit_len", "n_shared", "nodes", "digests",
                  "trace", "tenant", "priority", "resumed_at",
                  "preempted", "handoff", "import_state", "prefix_import",
-                 "sink", "logprobs", "logprob_values", "decode_span")
+                 "sink", "logprobs", "logprob_values", "decode_span",
+                 "in_flight")
 
     def __init__(self, prompt: np.ndarray, n_tokens: int,
                  temperature: float, seed: int,
@@ -131,6 +143,7 @@ class _GenRequest:
         self.n_pages = 0
         self.pages: Optional[List[int]] = None
         self.prefill_pos: Optional[int] = None
+        self.in_flight = 0
         # prefix-cache binding: hit_len prompt positions ride shared
         # pages (the first n_shared entries of `pages`, refcounted via
         # `nodes`); only pages[n_shared:] are this request's to free
@@ -256,19 +269,40 @@ class _TenantState:
 
 
 def _dispatched(thunk):
-    """Run one compiled dispatch INCLUDING its host materialization,
-    tagging any exception raised so the caller can tell a FAILED
-    DISPATCH (which, under buffer donation, may have invalidated the
-    donated pool buffers) apart from failures raised after the results
-    landed (non-finite screens, hooks) — only the former justifies
-    failing other slots. The device_get must live inside the thunk: on
-    asynchronous backends a device-side error surfaces at
-    materialization, not at the dispatch call."""
+    """Run one half of a compiled dispatch, the jitted call of its issue
+    or the `device_get` of its collect, tagging any exception raised so
+    the caller can tell a FAILED DISPATCH (which, under buffer donation,
+    may have invalidated the donated pool buffers) apart from failures
+    raised around it (non-finite screens, hooks) — only the former
+    justifies failing other slots. On asynchronous backends a
+    device-side error surfaces at materialization: in the collect, one
+    dispatch after the issue that caused it, so the collect is tagged
+    as the call is."""
     try:
         return thunk()
     except BaseException as e:
         e._dispatch_failure = True
         raise
+
+
+class _InFlight:
+    """One dispatch the chip has been handed and the host has not read
+    back: what an issue leaves for its collect. `program` is
+    ``"prefill"``, ``"decode_step"`` or ``"decode_chunked"``; `live` the
+    ``(slot, request)`` pairs it was issued for; `handles` the device
+    arrays the collect reads, ``(tok0, ok[, lp0])`` or ``(toks, oks,
+    lps, counts)``; `t0` the issue time; `info` the hooks' dict, the
+    same object at `pre_*` and `post_*`; `n_steps` the tokens a slot
+    gets from it; `draft` the speculative draft's prefill inputs."""
+
+    __slots__ = ("program", "live", "handles", "t0", "info", "n_steps",
+                 "draft")
+
+    def __init__(self, program, live, handles, t0, info, n_steps=1,
+                 draft=None):
+        self.program, self.live, self.handles = program, live, handles
+        self.t0, self.info, self.n_steps = t0, info, n_steps
+        self.draft = draft
 
 
 class DecodeEngine:
@@ -321,7 +355,10 @@ class DecodeEngine:
         admission is rejected while open, device failures count.
     step_hooks : chaos/observability seam — called as `hook(phase,
         info)` at pre/post_prefill (info carries `chunk_off`/`final`
-        for chunked prefill) and pre/post_decode.
+        for chunked prefill) and pre/post_decode. `pre_*` fires when a
+        dispatch is issued, `post_*` with the same `info` object when it
+        is collected, one decode dispatch later: `pre_decode(n+1)`
+        precedes `post_decode(n)`.
     decode_chunk : fuse up to this many decode iterations into ONE
         dispatch (a `lax.scan` over the same step body — identical
         numerics) whenever no scheduling event can fall inside the
@@ -555,6 +592,11 @@ class DecodeEngine:
         # the scheduler thread's account of its own time; written by
         # that thread alone, read by stats()
         self._phases = observability.ThreadPhases()
+        # dispatches issued and not yet collected, oldest first: at most
+        # one decode dispatch and the prefills issued before it while
+        # the scheduler blocks in a collect (scheduler-thread-owned)
+        self._inflight: collections.deque = collections.deque()
+        self._collected_at = 0.0  # monotonic time of the last collect
         self._chunk_ewma = 0.0  # guarded by: _cond
         self._role = role
         # counters (observable state for tests/telemetry)
@@ -891,10 +933,12 @@ class DecodeEngine:
         dispatch may have invalidated donated buffers). Callers
         guarantee no slot holds a request when this runs, so the free
         list rebuilds to the full pool; queued requests keep their
-        reservations (they hold no device state)."""
+        reservations (they hold no device state). What the chip still
+        holds unread ran on the state this replaces: dropped."""
         import jax
         import jax.numpy as jnp
 
+        self._discard_in_flight()
         S = self.n_slots
         caches = [st.alloc() for st in self._states]
         if self._tp is not None:
@@ -1868,7 +1912,20 @@ class DecodeEngine:
                     # one span per stay, not one per 50 ms wake
                     ph.enter("wait-work")
                     self._cond.wait(0.05)
-                if self._kill:
+                kill = self._kill
+            if kill:
+                # what the chip was handed is delivered, outside the
+                # lock, before the rest is failed
+                try:
+                    self._drain()
+                # graftlint: disable=typed-error  the thread is leaving:
+                # whatever the last collect raises, the requests below
+                # are failed typed all the same
+                except BaseException:
+                    logger.exception("decode engine: the last collect "
+                                     "before a kill failed")
+            with self._cond:
+                if kill:
                     self._fail_all_locked(ServerClosedError(
                         "engine shut down before this request finished"))
                     self._abort_pending_swap_locked()
@@ -1946,6 +2003,8 @@ class DecodeEngine:
                 self._slots[s] = None
                 self._active[s] = False
                 self._pool.release_locked(req)
+                if req.completed_at is not None:
+                    continue  # ended at a collect; only its pages stayed
                 if self.breaker is not None:
                     # release the request's breaker token — a dropped
                     # half-open probe would wedge the shared breaker in
@@ -1996,6 +2055,14 @@ class DecodeEngine:
         self._wfq_pass[req.tenant] = start + span / max(weight, 1e-9)
         self._wfq_floor = start
 
+    def _may_preempt(self, head: _GenRequest) -> bool:
+        """Whether a blocked `head` is one a batch-lane slot could yield
+        to: what `_admit` asks before it drains for a preemption."""
+        return self._preempt_enabled and head.priority == "interactive" \
+            and not head.expired() \
+            and any(v is not None and v.priority == "batch"
+                    for v in self._slots)
+
     def _maybe_preempt_locked(self, head: _GenRequest, reason: str):
         """Retire-to-queue one DECODING batch-lane slot so a blocked
         interactive head can take its slot and pages. The victim's
@@ -2005,12 +2072,12 @@ class DecodeEngine:
         re-prefill re-binds them instead of recomputing, and it rejoins
         the queue FRONT with its position preserved. Mid-prefill slots
         are never preempted: their pages hold partial KV, which must
-        not reach the prefix cache. Returns ``(victim, old_probe,
-        reason, slot)`` or None (caller releases the breaker token
-        outside the lock)."""
+        not reach the prefix cache. The victim's `tokens` must be all
+        the chip has computed for it: the caller drains the pipeline
+        first. Returns ``(victim, old_probe, reason, slot)`` or None
+        (caller releases the breaker token outside the lock)."""
         assert_owned(self._cond, "DecodeEngine._maybe_preempt_locked")
-        if not self._preempt_enabled or head.priority != "interactive" \
-                or head.expired():
+        if not self._may_preempt(head):
             return None
         best = None
         for s in range(self.n_slots):
@@ -2032,9 +2099,7 @@ class DecodeEngine:
         # latest decoded token's KV is not written yet, so pages
         # touching the decoded tail are not provably complete
         self._pool.promote_locked(v, v.prompt, v.tenant)
-        self._pool.release_locked(v)
-        self._slots[best] = None
-        self._active[best] = False
+        self._vacate_locked(best, v)
         emitted = len(v.tokens)
         if emitted > v.resumed_at:
             v.prompt = np.concatenate(
@@ -2089,7 +2154,13 @@ class DecodeEngine:
             # again after each one-shot prefill, which has phases of its
             # own
             self._phases.enter("admit")
+            if self._prefix_cache is not None and any(
+                    rec.program == "prefill" for rec in self._inflight):
+                # a prefill's pages reach the prefix cache at its
+                # collect, and the lookup below has to find them
+                self._drain()
             preempt = None
+            blocked = None
             with self._cond:
                 if not self._queue:
                     return
@@ -2105,9 +2176,7 @@ class DecodeEngine:
                     # every slot taken, an interactive head waiting: the
                     # batch lane yields a slot (retire-to-queue) or we
                     # wait for a retirement like any full house
-                    preempt = self._maybe_preempt_locked(head, "slots")
-                    if preempt is None:
-                        return
+                    blocked = "slots"
                 elif not head.expired():
                     if head.import_state is not None and head.nodes:
                         # delta handoff: its prefix-chain pages were
@@ -2143,11 +2212,15 @@ class DecodeEngine:
                         # were reclaimed: a batch slot's pages can
                         # cover an interactive head (preemption), else
                         # wait for a retirement to free pages
-                        preempt = self._maybe_preempt_locked(head,
-                                                             "pages")
+                        blocked = "pages"
+                if blocked is not None:
+                    if not self._may_preempt(head):
+                        return
+                    if not self._inflight:
+                        preempt = self._maybe_preempt_locked(head, blocked)
                         if preempt is None:
                             return
-                if preempt is None:
+                else:
                     req = head
                     del self._queue[head_idx]
                     self._pages_demand_queued -= req.n_pages
@@ -2157,6 +2230,11 @@ class DecodeEngine:
                         # capacity (preempted re-admissions re-charge:
                         # they consume capacity again)
                         self._wfq_charge_locked(req)
+            if blocked is not None and preempt is None:
+                # a victim is chosen, and its tokens folded into its
+                # prompt, only once the host has seen all of them
+                self._drain()
+                continue
             if preempt is not None:
                 victim, old_probe, reason, vslot = preempt
                 if self.breaker is not None:
@@ -2263,16 +2341,20 @@ class DecodeEngine:
                     self._slots[slot] = req
                     # _active stays False until the final chunk lands
                 continue
-            try:
-                self._prefill_into(slot, req)
-            # graftlint: disable=typed-error  converts to a typed failure:
-            # _prefill_failure wraps non-ServingError causes in
-            # InferenceFailedError and fails only the one request
-            except BaseException as e:
-                self._prefill_failure(slot, req, e, attached=False)
+            self._issue_prefill(slot, req)
+            if req.handoff or self._spec is not None:
+                # a prefill that leaves under a lease, or that the draft
+                # mirrors, is read back before anything else is issued
+                self._drain()
 
     # graftlint: hot-loop
-    def _prefill_into(self, slot: int, req: _GenRequest) -> None:
+    def _issue_prefill(self, slot: int, req: _GenRequest) -> None:
+        """First half of a one-shot prefill: hand the program to the
+        chip and put the request into its slot, active for the next
+        decode dispatch unless this token is its last by count (or it
+        leaves under a lease). Nothing is read back: the first token is
+        `_collect_prefill`'s, and what only the token can say (EOS, a
+        non-finite prompt) costs the slot one dispatch of overshoot."""
         import jax
         import jax.numpy as jnp
 
@@ -2290,73 +2372,100 @@ class DecodeEngine:
         key = jax.random.PRNGKey(req.seed)
         kp, kdec = jax.random.split(key)  # generate()'s prefill/decode split
         info = {"slot": slot, "bucket": bucket, "t0": t0}
-        self._hook("pre_prefill", info)
-
-        def run():
+        tp0 = time.monotonic()
+        try:
+            self._hook("pre_prefill", info)
             args = (self._weights, self._caches, jnp.asarray(ids),
                     jnp.asarray(t0, jnp.int32),
                     jnp.asarray(slot, jnp.int32),
                     wpids, self._tok, self._pos, self._keys, self._temps,
                     kp, kdec, jnp.asarray(req.temperature, jnp.float32))
-            if self._logprobs_k:
-                (self._caches, self._tok, self._pos, self._keys,
-                 self._temps, tok0, ok, lp0) = self._prefill(*args)
-            else:
-                (self._caches, self._tok, self._pos, self._keys,
-                 self._temps, tok0, ok) = self._prefill(*args)
-                lp0 = None
-            ph.enter("prefill.wait")
-            return jax.device_get((tok0, ok, lp0))
-
-        tp0 = time.monotonic()
-        first, ok, lp0 = _dispatched(run)
-        ph.enter("prefill.deliver")
-        tp1 = time.monotonic()
-        # host clock around the dispatch+materialization — already
-        # synced, so the span costs no extra device round-trip
-        req.trace.add_timed("prefill", tp0, tp1,
-                            bucket=bucket, prompt_len=t0)
-        first = int(first[0])
-        if not bool(ok):
-            raise InferenceFailedError(
-                "model produced non-finite logits during prefill "
-                "(poisoned parameters or a numerically broken graph)")
-        if self._spec is not None:
-            # mirror the prompt into the draft's pools (same pages, same
-            # padded ids) so proposing can start from a complete context
-            ph.enter("prefill.dispatch", program="draft_prefill",
-                     chunk=bucket, tp=self._tp_degree,
-                     trace_id=req.trace.trace_id)
-            _dispatched(lambda: self._spec.prefill_one_shot(ids, wpids))
-            ph.enter("prefill.deliver")
-        self._hook("post_prefill", info)
-        with self._cond:
-            self.prefills += 1
-            self.tokens_generated += 1
-            self.state_resets += int(self._recurrent)
-            # a one-shot prefill grounds the SLO estimator as a single
-            # chunk observation (same dispatch scale as a chunk)
-            self._chunk_ewma = 0.8 * self._chunk_ewma + 0.2 * (tp1 - tp0)
-            self._pool.promote_locked(req, req.prompt, req.tenant)
-        if self._spec is not None:
-            self._spec.seed_slot(slot, req.seed)
-        req.tokens.append(first)
-        self._emit_token(req, lp0)
-        # >= len comparison, not n_tokens == 1: a preempted request
-        # re-prefills with its emitted tokens folded into the prompt,
-        # so this "first" token may already be its last
-        if len(req.tokens) >= req.n_tokens or first == self.eos_token:
-            self._retire(slot, req, attached=False)
+            out = _dispatched(lambda: self._prefill(*args))
+        # graftlint: disable=typed-error  converts to a typed failure:
+        # _prefill_failure wraps non-ServingError causes in
+        # InferenceFailedError and fails only the one request
+        except BaseException as e:
+            self._drain()  # what was issued before it is still good
+            self._prefill_failure(slot, req, e, attached=False)
             return
-        if req.handoff:
-            # prefill-role (disagg): the freshly computed KV leaves
-            # under a lease instead of entering this engine's decode loop
-            self._hand_off(slot, req, attached=False, reason="disagg")
-            return
+        (self._caches, self._tok, self._pos, self._keys,
+         self._temps) = out[:5]
+        req.in_flight = 1
         with self._cond:
             req.slot = slot
             self._slots[slot] = req
-            self._active[slot] = True
+            # >= len comparison, not n_tokens == 1: a preempted request
+            # re-prefills with its emitted tokens folded into the prompt,
+            # so this "first" token may already be its last
+            self._active[slot] = not req.handoff \
+                and len(req.tokens) + 1 < req.n_tokens
+        ph.ahead_n += bool(self._inflight)
+        self._inflight.append(_InFlight(
+            "prefill", [(slot, req)], out[5:], tp0, info,
+            draft=(ids, wpids) if self._spec is not None else None))
+
+    # graftlint: hot-loop
+    def _collect_prefill(self, rec: _InFlight) -> None:
+        """Second half of a one-shot prefill: wait for its first token,
+        read it back and deliver it."""
+        import jax
+
+        ph = self._phases
+        (slot, req), = rec.live
+        info = rec.info
+        req.in_flight -= 1
+        ph.enter("prefill.wait")
+        try:
+            got = _dispatched(lambda: jax.device_get(rec.handles))
+            ph.enter("prefill.deliver")
+            tp1 = time.monotonic()
+            # host clock from the issue to the materialization — already
+            # synced, so the span costs no extra device round-trip
+            req.trace.add_timed("prefill", rec.t0, tp1,
+                                bucket=info["bucket"], prompt_len=info["t0"])
+            first = int(got[0][0])
+            lp0 = got[2] if self._logprobs_k else None
+            if not bool(got[1]):
+                raise InferenceFailedError(
+                    "model produced non-finite logits during prefill "
+                    "(poisoned parameters or a numerically broken graph)")
+            if rec.draft is not None:
+                # mirror the prompt into the draft's pools (same pages,
+                # same padded ids) so proposing can start from a complete
+                # context
+                ph.enter("prefill.dispatch", program="draft_prefill",
+                         chunk=info["bucket"], tp=self._tp_degree,
+                         trace_id=req.trace.trace_id)
+                _dispatched(lambda: self._spec.prefill_one_shot(*rec.draft))
+                ph.enter("prefill.deliver")
+            self._hook("post_prefill", info)
+            with self._cond:
+                self.prefills += 1
+                self.tokens_generated += 1
+                self.state_resets += int(self._recurrent)
+                # a one-shot prefill grounds the SLO estimator as a
+                # single chunk observation (same dispatch scale as a
+                # chunk): the time the chip had it to itself
+                self._chunk_ewma = 0.8 * self._chunk_ewma \
+                    + 0.2 * (tp1 - max(rec.t0, self._collected_at))
+                self._pool.promote_locked(req, req.prompt, req.tenant)
+            self._collected_at = tp1
+            if self._spec is not None:
+                self._spec.seed_slot(slot, req.seed)
+            req.tokens.append(first)
+            self._emit_token(req, lp0)
+            if len(req.tokens) >= req.n_tokens or first == self.eos_token:
+                self._retire(slot, req)
+            elif req.handoff:
+                # prefill-role (disagg): the freshly computed KV leaves
+                # under a lease instead of entering this engine's decode
+                # loop
+                self._hand_off(slot, req, reason="disagg")
+        # graftlint: disable=typed-error  converts to a typed failure:
+        # _prefill_failure wraps non-ServingError causes in
+        # InferenceFailedError and fails only the one request
+        except BaseException as e:
+            self._prefill_failure(slot, req, e, attached=True)
 
     # graftlint: hot-loop
     def _step_prefills(self) -> None:
@@ -2374,6 +2483,9 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
+        # a path of its own, synchronous: the chunk is issued and read
+        # back with nothing else uncollected
+        self._drain()
         ph = self._phases
         ph.enter("prefill.dispatch", program="prefill_chunk_fn",
                  chunk=self.prefill_chunk,
@@ -2487,22 +2599,26 @@ class DecodeEngine:
         """Shared give-up path for one-shot and chunked prefill: free
         the slot + pages, count the failure, and — on a failed DISPATCH
         under donation — fail every in-flight slot (the donated pool
-        buffers may be gone with it) and rebuild device state."""
+        buffers may be gone with it, and whatever was issued behind it
+        ran on them) and rebuild device state."""
+        lost = self._donate and getattr(e, "_dispatch_failure", False)
+        if lost:
+            self._discard_in_flight()
         if self.breaker is not None:
             self.breaker.record_failure(req.probe)
         with self._cond:
             self.failures += 1
             if attached:
-                self._slots[slot] = None
-                self._active[slot] = False
-            self._pool.release_locked(req)
+                self._vacate_locked(slot, req)
+            else:
+                self._pool.release_locked(req)
             self._cond.notify_all()
         err = e if isinstance(e, ServingError) else \
             InferenceFailedError(
                 f"prefill failed: {type(e).__name__}: {e}")
         logger.warning("decode engine: prefill failure (%s)", err)
         self._finish_obs(req, err, phase="prefill")
-        if self._donate and getattr(e, "_dispatch_failure", False):
+        if lost:
             # the raised DISPATCH may have invalidated the DONATED page
             # pools — every in-flight slot's KV is gone with them, so
             # those requests must fail too (queued ones survive: they
@@ -2526,20 +2642,32 @@ class DecodeEngine:
                     self._active[s] = False
                     r.pages = None  # pools rebuild wholesale after this
                     r.nodes = None  # ... and the prefix cache clears
+                    if r.completed_at is not None:
+                        continue  # ended at a collect; only pages stayed
                     if self.breaker is not None:
                         self.breaker.record_failure(r.probe)
                     self._finish_obs(r, err)
             self._cond.notify_all()
 
-    def _retire(self, slot: int, req: _GenRequest, *,
-                attached: bool = True) -> None:
+    def _vacate_locked(self, slot: int, req: _GenRequest) -> None:
+        """`req` leaves `slot`: no later dispatch has it active, and its
+        pages (and with the slot its recurrent state) go back to the
+        pool — now, or, while a dispatch that had the slot active is
+        uncollected, when `_collect` has read the last such one: its
+        overshoot still writes through those pages. Until then the slot
+        stays taken."""
+        assert_owned(self._cond, "DecodeEngine._vacate_locked")
+        self._active[slot] = False
+        if any(s == slot for rec in self._inflight for s, _ in rec.live):
+            return
+        self._slots[slot] = None
+        self._pool.release_locked(req)
+
+    def _retire(self, slot: int, req: _GenRequest) -> None:
         """Successful completion: free the slot AND its pages, credit
         the breaker, deliver the tokens."""
         with self._cond:
-            if attached:
-                self._slots[slot] = None
-                self._active[slot] = False
-            self._pool.release_locked(req)
+            self._vacate_locked(slot, req)
             self.served += 1
             ts = self._tenants.get(req.tenant)
             if ts is not None:
@@ -2561,10 +2689,12 @@ class DecodeEngine:
         buffers functionally): `(registers, blocks, n_pages)` — the
         pool pages `pages` of every block as host arrays, and, for a
         slot, its (position, last token, live PRNG key, temperature)
-        with only the pages its position has reached."""
+        with only the pages its position has reached. Drained first:
+        the registers have to match the tokens the host has seen."""
         import jax
         import jax.numpy as jnp
 
+        self._drain()
         regs = None
         if slot is not None:
             pos_, tok_, key_, temp_ = jax.device_get(
@@ -2585,9 +2715,10 @@ class DecodeEngine:
         """The reverse of `_read_slot`: scatter `blocks` into the pool
         pages `pages` (eager `.at[].set`, not a donated dispatch: a
         failure leaves the pools valid) and, for a slot, restore its
-        registers."""
+        registers. Drained first, like `_read_slot`."""
         import jax.numpy as jnp
 
+        self._drain()
         jidx = jnp.asarray(np.asarray(pages, np.int32))
         names = ("k", "v", "ks", "vs") if self._kv_quant else ("k", "v")
         new_caches = []
@@ -2608,17 +2739,16 @@ class DecodeEngine:
             self._temps = self._temps.at[slot].set(temp)
 
     def _hand_off(self, slot: int, req: _GenRequest, *,
-                  attached: bool = True, reason: str = "migrate") -> None:
+                  reason: str = "migrate") -> None:
         """A decoding slot leaves under a lease (the prefill role's
         finished prefill, or the migration pass): the plane takes its
         state and its pages, then the slot is released and the request
         finished with the `SlotMigratedError` redirect."""
         err = self._plane.export_slot(slot, req, reason)
-        if attached:
-            with self._cond:
-                self._slots[slot] = None
-                self._active[slot] = False
-                self._cond.notify_all()
+        with self._cond:
+            self._slots[slot] = None
+            self._active[slot] = False
+            self._cond.notify_all()
         self._finish_obs(req, err)
 
     def _migrate_in_flight(self) -> None:
@@ -2626,6 +2756,7 @@ class DecodeEngine:
         armed: decoding slots export warm (their KV pages ship), queued
         and mid-prefill requests export cold (partial KV is never
         shipped — it is not provably complete)."""
+        self._drain()
         with self._cond:
             queued = list(self._queue)
             self._queue.clear()
@@ -2642,9 +2773,7 @@ class DecodeEngine:
                 else:
                     parked.append((s, r))
             for s, r in parked:
-                self._slots[s] = None
-                self._active[s] = False
-                self._pool.release_locked(r)
+                self._vacate_locked(s, r)
             self._cond.notify_all()
         for r in queued:
             self._finish_obs(r, self._plane.export_cold(r, "migrate"))
@@ -2688,9 +2817,7 @@ class DecodeEngine:
             self.breaker.record_success(req.probe)
         with self._cond:
             self.failures += 1
-            self._slots[slot] = None
-            self._active[slot] = False
-            self._pool.release_locked(req)
+            self._vacate_locked(slot, req)
             self._cond.notify_all()
         err = e if isinstance(e, ServingError) else KVTransferError(
             f"KV import failed: {type(e).__name__}: {e}")
@@ -2723,13 +2850,16 @@ class DecodeEngine:
             self._finish_obs(req, DeadlineExceededError(
                 "deadline expired while queued; request shed before "
                 "prefill"))
+        if self._inflight and any(r is not None and r.expired(now)
+                                  for r in self._slots):
+            # the tokens it got in time are delivered, and its pages
+            # released with nothing uncollected writing through them
+            self._drain()
         for s in range(self.n_slots):
             req = self._slots[s]
             if req is not None and req.expired(now):
                 with self._cond:
-                    self._slots[s] = None
-                    self._active[s] = False
-                    self._pool.release_locked(req)
+                    self._vacate_locked(s, req)
                     self.shed_deadline += 1
                     self._cond.notify_all()
                 if self.breaker is not None:
@@ -2742,15 +2872,17 @@ class DecodeEngine:
 
     def _chunk_eligible(self, live, now: float) -> bool:
         """A chunked decode dispatch is allowed only when no scheduling
-        event can land inside it: every live request needs at least a
-        full chunk more tokens, no deadline could expire before the
-        chunk returns, no prompt is mid-prefill (its chunks must
-        interleave with decode, not wait behind a fused run), and —
-        when EOS can retire a slot mid-chunk — no queued request is
-        waiting to take a freed slot (without an eos_token, the
-        remaining-tokens bound already proves nothing retires
-        mid-chunk). Admission waits at most one chunk — `_admit` runs
-        before every dispatch."""
+        event the host can foresee lands inside it: every request of
+        `live` has at least a full chunk of tokens still to ASK for
+        (`n_tokens` less what it has and what is in flight for it: the
+        count is known at issue, whatever the tokens turn out to be), no
+        deadline could expire before the chunk returns, no prompt is
+        mid-prefill (its chunks must interleave with decode, not wait
+        behind a fused run), and — when EOS can retire a slot mid-chunk
+        — no queued request is waiting to take a freed slot (without an
+        eos_token, the count already proves nothing retires mid-chunk).
+        Admission waits at most one chunk — `_admit` runs before every
+        dispatch."""
         if self.decode_chunk <= 1:
             return False
         with self._cond:
@@ -2761,33 +2893,38 @@ class DecodeEngine:
                 return False  # a mid-chunk EOS would strand the slot
         margin = 2.0 * self.decode_chunk * max(self._step_ewma, 1e-4)
         for _, r in live:
-            if r.n_tokens - len(r.tokens) < self.decode_chunk:
+            if r.n_tokens - len(r.tokens) - r.in_flight < self.decode_chunk:
                 return False
             if r.deadline is not None and r.deadline - now < margin:
                 return False
         return True
 
     def _decode_failure(self, live, e: BaseException) -> None:
-        """Shared decode-step give-up: fail every live request typed,
-        free slots + pages, and — on a failed DISPATCH under donation —
-        fail mid-prefill slots too and rebuild the device state (the
+        """Shared decode-step give-up: fail every request of `live`, the
+        failed dispatch's own list, typed, free slots + pages, and — on
+        a failed DISPATCH under donation — drop what was issued behind
+        it, fail mid-prefill slots too and rebuild the device state (the
         donated pools back all of them)."""
+        lost = getattr(e, "_dispatch_failure", False)
+        if lost:
+            self._discard_in_flight()
         err = e if isinstance(e, ServingError) else \
             InferenceFailedError(
                 f"decode step failed: {type(e).__name__}: {e}")
         logger.warning("decode engine: decode failure (%s)", err)
+        # a request that ended at an earlier collect (EOS, a poisoned
+        # step) rode this dispatch as overshoot: it has its verdict
+        live = [(s, req) for s, req in live if req.completed_at is None]
         with self._cond:
             self.failures += len(live)
         for s, req in live:
             if self.breaker is not None:
                 self.breaker.record_failure(req.probe)
             with self._cond:
-                self._slots[s] = None
-                self._active[s] = False
-                self._pool.release_locked(req)
+                self._vacate_locked(s, req)
                 self._cond.notify_all()
             self._finish_obs(req, err, phase="decode")
-        if getattr(e, "_dispatch_failure", False):
+        if lost:
             # only a failed DISPATCH can have invalidated the donated
             # pool buffers; hook failures leave them valid. Mid-prefill
             # slots are backed by the same pools — they go down with
@@ -2813,15 +2950,16 @@ class DecodeEngine:
 
     # graftlint: hot-loop
     def _retire_or_poison(self, s: int, req: _GenRequest, toks, oks,
-                          n_steps: int, lps=None) -> None:
+                          n_steps: int, lps=None) -> int:
         """Consume one slot's emitted tokens from a decode/verify
         dispatch: append until done (count or EOS — overshoot dropped
         with the slot) or until a poisoned step fails the request typed
         while healthy neighbors keep decoding. `lps` is the slot's
         per-step (chosen, top_values, top_ids) logprob batch when the
-        engine computes logprobs."""
+        engine computes logprobs. Returns the steps consumed."""
         done = False
         poisoned = False
+        t = -1
         for t in range(n_steps):
             if not bool(oks[t]):
                 poisoned = True
@@ -2842,15 +2980,14 @@ class DecodeEngine:
             logger.warning("decode engine: %s", nf_err)
             with self._cond:
                 self.failures += 1
-                self._slots[s] = None
-                self._active[s] = False
-                self._pool.release_locked(req)
+                self._vacate_locked(s, req)
                 self._cond.notify_all()
             if self.breaker is not None:
                 self.breaker.record_failure(req.probe)
             self._finish_obs(req, nf_err, phase="decode")
         elif done:
             self._retire(s, req)
+        return t + 1
 
     # graftlint: hot-loop
     def _step_active_spec(self, live) -> bool:
@@ -2956,59 +3093,100 @@ class DecodeEngine:
             self.moe_experts_hit += int(counts[:, 1].sum())
             self.moe_steps += counts.shape[0]
 
+    # graftlint: hot-loop
     def _step_active(self) -> None:
+        """One decode dispatch ahead: issue the next program for every
+        slot that still has tokens to ask for, and only then wait for,
+        read back and deliver what was issued before it — the last
+        decode dispatch and the prefills admitted since — while the
+        chip runs the new one. A request whose count is already covered
+        by what is in flight sits this dispatch out and retires at its
+        collect. The speculative step needs each dispatch's `n_emit`
+        before it can issue the next: it stays synchronous."""
+        live = [(s, r) for s, r in enumerate(self._slots)
+                if r is not None and self._active[s]
+                and len(r.tokens) + r.in_flight < r.n_tokens]
+        if not live:
+            self._collect()
+        elif self._spec is not None:
+            if not self._step_active_spec(live):
+                self._issue_decode(live)
+                self._drain()
+        else:
+            self._issue_decode(live)
+            self._collect(keep=1)
+
+    # graftlint: hot-loop
+    def _issue_decode(self, live) -> None:
+        """First half of a decode dispatch: one step, or a fused chunk,
+        for the slots of `live`. Every input is a device array the last
+        program returned, so nothing here waits for the chip."""
         import jax.numpy as jnp
 
-        live = [(s, r) for s, r in enumerate(self._slots)
-                if r is not None and r.prefill_pos is None]
-        if not live:
-            return
-        if self._spec is not None and self._step_active_spec(live):
-            return
-        now = time.monotonic()
-        chunked = self._spec is None and self._chunk_eligible(live, now)
+        chunked = self._spec is None \
+            and self._chunk_eligible(live, time.monotonic())
+        n_steps = self.decode_chunk if chunked else 1
+        program = "decode_chunked" if chunked else "decode_step"
         ph = self._phases
-        ph.enter("decode.dispatch",
-                 program="decode_chunked" if chunked else "decode_step",
-                 chunk=self.decode_chunk if chunked else 1,
+        ph.enter("decode.dispatch", program=program, chunk=n_steps,
                  active=len(live), tp=self._tp_degree)
-        info = {"active": len(live), "step": self.decode_steps,
-                "chunk": self.decode_chunk if chunked else 1}
+        info = {"active": len(live), "chunk": n_steps,
+                "step": self.decode_steps + sum(
+                    rec.n_steps for rec in self._inflight
+                    if rec.program != "prefill")}
+        mask = np.zeros((self.n_slots,), bool)
+        mask[[s for s, _ in live]] = True
         t0 = time.monotonic()
         try:
-            import jax
-
             self._hook("pre_decode", info)
+            fn = self._decode_chunked if chunked else self._decode_step
+            out = _dispatched(lambda: fn(
+                self._weights, self._caches, self._page_table, self._tok,
+                self._pos, self._keys, self._temps, jnp.asarray(mask)))
+        # graftlint: disable=typed-error  converts to a typed failure:
+        # _decode_failure wraps the cause in InferenceFailedError for the
+        # affected slots and recovers the pool
+        except BaseException as e:
+            self._drain()  # what was issued before it is still good
+            self._decode_failure(live, e)
+            return
+        self._caches, self._tok, self._pos, self._keys = out[:4]
+        # after the state: (toks,) oks[, logprobs][, counts]
+        rest = list(out[4:])
+        toks_d = rest.pop(0) if chunked else self._tok
+        oks_d = rest.pop(0)
+        lps_d = rest.pop(0) if self._logprobs_k else None
+        counts_d = rest.pop(0) if self._n_held else None
+        for _, r in live:
+            r.in_flight += n_steps
+        ph.ahead_n += bool(self._inflight)
+        self._inflight.append(_InFlight(
+            program, live, (toks_d, oks_d, lps_d, counts_d), t0, info,
+            n_steps))
 
-            def run():
-                fn = self._decode_chunked if chunked else self._decode_step
-                out = fn(self._weights, self._caches, self._page_table,
-                         self._tok, self._pos, self._keys, self._temps,
-                         jnp.asarray(self._active))
-                self._caches, self._tok, self._pos, self._keys = out[:4]
-                # after the state: (toks,) oks[, logprobs][, counts]
-                rest = list(out[4:])
-                toks_d = rest.pop(0) if chunked else self._tok
-                oks_d = rest.pop(0)
-                lps_d = rest.pop(0) if self._logprobs_k else None
-                counts_d = rest.pop(0) if self._n_held else None
-                # THE per-iteration host sync — the price of
-                # iteration-level scheduling; chunking amortizes it to
-                # (chunk, S) tokens + per-step flags in ONE sync, and
-                # the experts' counts ride the same one
-                ph.enter("decode.wait")
-                t, o, lp, counts = jax.device_get(
-                    (toks_d, oks_d, lps_d, counts_d))
-                if counts is not None:
-                    self._count_experts(counts, len(live))
-                if chunked:
-                    return t, o, lp
-                return t[None], o[None], (None if lp is None else
-                                          tuple(a[None] for a in lp))
+    # graftlint: hot-loop
+    def _collect_decode(self, rec: _InFlight) -> None:
+        """Second half of a decode dispatch: wait for it, read its
+        tokens back and deliver them slot by slot."""
+        import jax
 
-            toks, oks, lps = _dispatched(run)
+        ph = self._phases
+        live, n_steps = rec.live, rec.n_steps
+        for _, r in live:
+            r.in_flight -= n_steps
+        # THE host sync of the hot loop, one a dispatch — the price of
+        # iteration-level scheduling; chunking amortizes it to
+        # (chunk, S) tokens + per-step flags in ONE sync, the experts'
+        # counts ride the same one, and the next dispatch is already on
+        # the chip while the host waits here
+        ph.enter("decode.wait")
+        try:
+            toks, oks, lps, counts = _dispatched(
+                lambda: jax.device_get(rec.handles))
             ph.enter("decode.deliver")
-            self._hook("post_decode", info)
+            if counts is not None:
+                self._count_experts(counts, len(live))
+            self._hook("post_decode", rec.info)
         # graftlint: disable=typed-error  converts to a typed failure:
         # _decode_failure wraps the cause in InferenceFailedError for the
         # affected slots and recovers the pool
@@ -3016,14 +3194,26 @@ class DecodeEngine:
             self._decode_failure(live, e)
             return
         t1 = time.monotonic()
-        n_steps = toks.shape[0]
+        if rec.program == "decode_step":
+            toks, oks = toks[None], oks[None]
+            lps = None if lps is None else tuple(a[None] for a in lps)
         with self._cond:
-            self._step_ewma = (0.8 * self._step_ewma
-                               + 0.2 * (t1 - t0) / n_steps)
+            # the time the chip had this dispatch to itself: it started
+            # when the one before it was done
+            self._step_ewma = (0.8 * self._step_ewma + 0.2
+                               * (t1 - max(rec.t0, self._collected_at))
+                               / n_steps)
             self.decode_steps += n_steps
             self.active_slot_steps += len(live) * n_steps
+        self._collected_at = t1
+        dropped = 0
         for s, req in live:
-            self._extend_decode_span(req, "decode", t0, t1, n_steps)
+            if req.completed_at is not None:
+                # it ended at the collect before (EOS, a poisoned step,
+                # a failed hook), after this dispatch was issued
+                dropped += n_steps
+                continue
+            self._extend_decode_span(req, "decode", rec.t0, t1, n_steps)
             # per-step, per-slot non-finite screen (predict's breaker
             # discipline): a poisoned step fails THIS request typed —
             # unless it already completed via EOS at an earlier step of
@@ -3031,8 +3221,63 @@ class DecodeEngine:
             # pages are untouched)
             lp_s = None if lps is None else \
                 (lps[0][:, s], lps[1][:, s], lps[2][:, s])
-            self._retire_or_poison(s, req, toks[:, s], oks[:, s],
-                                   n_steps, lps=lp_s)
+            dropped += n_steps - self._retire_or_poison(
+                s, req, toks[:, s], oks[:, s], n_steps, lps=lp_s)
+        ph.overshoot_tokens += dropped
+
+    # graftlint: hot-loop
+    def _collect(self, keep: int = 0) -> None:
+        """Read back and deliver, strictly in issue order, all but the
+        newest `keep` of the dispatches in flight. A slot whose request
+        ended while a later dispatch still had it active is released
+        here, after the last such dispatch: release follows the last
+        uncollected dispatch."""
+        while len(self._inflight) > keep:
+            rec = self._inflight.popleft()
+            if rec.program == "prefill":
+                self._collect_prefill(rec)
+            else:
+                self._collect_decode(rec)
+            ended = [(s, req) for s, req in rec.live
+                     if self._slots[s] is req
+                     and req.completed_at is not None]
+            if ended:
+                with self._cond:
+                    for s, req in ended:
+                        self._vacate_locked(s, req)
+                    self._cond.notify_all()
+
+    def _drain(self) -> None:
+        """Collect everything in flight. Called by whatever reads or
+        rewrites slot state from the host, which has to see every token
+        the chip has computed first: preemption, expiry, migration and
+        hand-off, slot reads and writes, the chunked prefill and the
+        speculative step, a failed issue, kill. The thread goes back to
+        the phase it was in."""
+        if not self._inflight:
+            return
+        ph = self._phases
+        ph.drained_n += 1
+        phase = ph.current
+        self._collect()
+        ph.enter(phase)
+
+    def _discard_in_flight(self) -> None:
+        """Drop what the chip still holds unread: it ran on device state
+        a failed dispatch lost, or that is about to be rebuilt."""
+        import jax
+
+        while self._inflight:
+            rec = self._inflight.popleft()
+            for _, r in rec.live:
+                r.in_flight = 0
+            self._phases.overshoot_tokens += rec.n_steps * len(rec.live)
+            try:
+                jax.block_until_ready(rec.handles)
+            # graftlint: disable=typed-error  deliberate absorb: the
+            # results are thrown away, and so is whatever they raise
+            except Exception:
+                pass
 
     # graftlint: hot-loop
     def _maybe_swap(self) -> None:
@@ -3041,6 +3286,8 @@ class DecodeEngine:
         with self._cond:
             if any(r is not None for r in self._slots):
                 return  # still draining: in-flight finish on old weights
+            # a slot stays taken until its last dispatch is collected,
+            # so nothing is in flight here
             net = self._swap_net
             if net is None:  # drain abandoned (timeout in drain_and_swap)
                 self._draining = False

@@ -834,6 +834,12 @@ class ThreadPhases:
         self.iterations = 0
         self.sink_s = 0.0
         self.sink_n = 0
+        # the decode scheduler's dispatch-ahead: dispatches issued while
+        # an earlier one was unread, times the pipeline was drained for
+        # a host read or write of slot state, tokens computed and dropped
+        self.ahead_n = 0
+        self.drained_n = 0
+        self.overshoot_tokens = 0
         self._on = tracing_enabled()
         self._tid = None
         # (name, t0, cause, attrs) of the phase the thread is in
@@ -864,6 +870,11 @@ class ThreadPhases:
             self._annotation = _TraceAnnotation(self._labels[name])
             self._annotation.__enter__()
 
+    @property
+    def current(self) -> Optional[str]:
+        """The phase the thread is in."""
+        return None if self._open is None else self._open[0]
+
     def close(self) -> None:
         """End the open phase; the thread is leaving."""
         self._end(time.perf_counter())
@@ -884,8 +895,9 @@ class ThreadPhases:
 
     def counters(self) -> dict:
         """``{"iterations", "<phase>_s", "<phase>_n", "sink_s",
-        "sink_n", "spans_dropped"}``: cumulative, so the difference of
-        two readings is a window's account. The phase still open counts
+        "sink_n", "ahead_n", "drained_n", "overshoot_tokens",
+        "spans_dropped"}``: cumulative, so the difference of two
+        readings is a window's account. The phase still open counts
         with the seconds it has lasted so far, so the `_s` of all
         phases add up to the time since the first `enter`."""
         out = {"iterations": self.iterations}
@@ -897,6 +909,9 @@ class ThreadPhases:
             out[open_[0] + "_s"] += time.perf_counter() - open_[1]
         out["sink_s"] = self.sink_s
         out["sink_n"] = self.sink_n
+        out["ahead_n"] = self.ahead_n
+        out["drained_n"] = self.drained_n
+        out["overshoot_tokens"] = self.overshoot_tokens
         out["spans_dropped"] = self._timeline.dropped
         return out
 

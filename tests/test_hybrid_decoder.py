@@ -355,6 +355,54 @@ def test_preemption_by_replay_gives_the_same_tokens(model):
         eng.shutdown(drain_timeout=30.0)
 
 
+def test_a_dispatch_ahead_carries_the_recurrent_state(model):
+    """The next step is issued before the last one's tokens are read:
+    the state it starts from is the device's, carried from program to
+    program, and what is served is still the reference's."""
+    prompts = [_ids(n, seed=40 + n) for n in (9, 13)]
+    eng = DecodeEngine(model[3], **dict(ENGINE, n_slots=2))
+    try:
+        reqs = [eng.submit(p, 15, logprobs=4) for p in prompts]
+        for r, p in zip(reqs, prompts):
+            toks = r.result(timeout=120.0)
+            _assert_served_equals_reference(
+                model, p, {"tokens": toks, "logprobs": r.logprob_values})
+        loop = eng.stats()["loop"]
+        assert loop["ahead_n"] > 0 and loop["overshoot_tokens"] == 0
+        # 14 decode steps a request, top-2 in each of 3 blocks
+        assert eng.stats()["moe_routed"] == 2 * 14 * 2 * 3
+    finally:
+        eng.shutdown(drain_timeout=30.0)
+
+
+def test_overshoot_leaves_the_next_occupants_state_alone(model):
+    """EOS is seen one dispatch late: the slot steps on through the
+    dropped dispatch, state and all, stays taken until that dispatch is
+    collected, and the request admitted after it starts from zeros: its
+    tokens are a fresh engine's."""
+    first, second = _ids(14, seed=8), _ids(12, seed=9)
+    whole, _ = _served(model[3], first, 16, n_slots=1)
+    eos = int(whole["tokens"][6])
+    stop = int(np.argmax(np.asarray(whole["tokens"]) == eos))
+    eng = DecodeEngine(model[3], **dict(ENGINE, n_slots=1, eos_token=eos))
+    try:
+        a = eng.submit(first, 16, logprobs=4)
+        b = eng.submit(second, 10, logprobs=4)
+        np.testing.assert_array_equal(a.result(timeout=120.0),
+                                      whole["tokens"][:stop + 1])
+        got = b.result(timeout=120.0)
+        st = eng.stats()
+        assert st["state_resets"] == 2
+        assert st["loop"]["overshoot_tokens"] > 0
+    finally:
+        eng.shutdown(drain_timeout=30.0)
+    fresh, _ = _served(model[3], second, 10, n_slots=1, eos_token=eos)
+    np.testing.assert_array_equal(got, fresh["tokens"])
+    np.testing.assert_allclose([e["logprob"] for e in b.logprob_values],
+                               [e["logprob"] for e in fresh["logprobs"]],
+                               atol=1e-6)
+
+
 @pytest.mark.parametrize("kw,what", [
     ({"prefix_cache": True}, "prefix_cache"),
     ({"speculative": {"draft": "self", "k": 2}}, "speculative"),

@@ -36,11 +36,15 @@ import numpy as np
 import pytest
 
 import deeplearning4j_tpu as dl4j
-from deeplearning4j_tpu.models.transformer import gpt_configuration
+from deeplearning4j_tpu.models.transformer import (
+    generate,
+    gpt_configuration,
+)
 from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
 from deeplearning4j_tpu.ops.activations import Activation
 from deeplearning4j_tpu.ops.losses import LossFunction
 from deeplearning4j_tpu.serving import (
+    DeadlineExceededError,
     DecodeEngine,
     InferenceFailedError,
     InjectedServingFault,
@@ -802,14 +806,74 @@ def test_scheduler_leaf_spans_partition_its_thread(net):
                                                   "decode_step"}
     assert all(1 <= s[5]["active"] <= 2 and s[5]["chunk"] in (1, 4)
                for s in decodes)
-    # within an iteration: dispatch, then wait, then deliver
+    # within an iteration: the issue of one dispatch, then the wait for
+    # and the delivery of the one before it; the first dispatch has
+    # nothing to collect and the last collect nothing to issue
     by_cause = {}
     for s in spans:
         by_cause.setdefault(s[3], []).append(s[0])
+    shapes = set()
     for names in by_cause.values():
-        d = [n for n in names if n.startswith("decode.")]
-        assert d in ([], ["decode.dispatch", "decode.wait",
-                          "decode.deliver"])
+        d = tuple(n[7:] for n in names if n.startswith("decode."))
+        assert d in ((), ("dispatch",), ("dispatch", "wait", "deliver"),
+                     ("wait", "deliver"))
+        shapes.add(d)
+    assert ("dispatch", "wait", "deliver") in shapes
+    assert loop["decode.dispatch_n"] == loop["decode.wait_n"] \
+        == loop["decode.deliver_n"]
+    assert 0 < loop["ahead_n"] \
+        <= loop["decode.dispatch_n"] + loop["prefill.dispatch_n"]
+    assert loop["overshoot_tokens"] == 0 and loop["drained_n"] == 0
+
+
+def test_leaf_spans_partition_the_thread_through_drains_and_overshoot(net):
+    """The partition holds where the pipeline is drained (a chunked
+    prefill, an expired request) and where dispatches are dropped (EOS
+    seen one dispatch late): no overlap, no gap, and the counters' `_s`
+    add up to the thread's time between two readings."""
+    since = time.perf_counter()
+    rng = np.random.default_rng(3)
+    full = generate(net, _prompts(1, 5, seed=13), 12, temperature=0.0)[0]
+    def drag(phase, info):
+        if phase == "pre_decode":
+            time.sleep(0.01)
+
+    eng = DecodeEngine(net, n_slots=2, max_len=64, prompt_buckets=(8,),
+                       prefill_chunk=8, page_size=8, decode_chunk=4,
+                       eos_token=int(full[5]), step_hooks=[drag])
+    try:
+        first = eng.stats()["loop"]
+        t_first = time.perf_counter()
+        # alone, so EOS is seen with the next dispatch in flight
+        eng.submit(_prompts(1, 5, seed=13)[0], 12).result(timeout=120.0)
+        # expires while it decodes: its release drains
+        with pytest.raises(DeadlineExceededError):
+            eng.submit(_prompts(1, 5, seed=14)[0], 50,
+                       timeout=0.08).result(timeout=120.0)
+        # a prompt prefilled in chunks beside a decoding request: every
+        # chunk drains
+        beside = eng.submit(_prompts(1, 5, seed=15)[0], 40)
+        while not beside.tokens:
+            time.sleep(0.002)
+        eng.submit(rng.integers(0, VOCAB, 21).astype(np.int32),
+                   9).result(timeout=120.0)
+        beside.result(timeout=120.0)
+        last = eng.stats()["loop"]
+        t_last = time.perf_counter()
+    finally:
+        eng.shutdown()
+    assert last["drained_n"] >= first["drained_n"] + 3
+    assert last["overshoot_tokens"] > 0
+    seconds = sum(last[k] - first[k] for k in last
+                  if k.endswith("_s") and k != "sink_s")
+    assert seconds == pytest.approx(t_last - t_first, abs=5e-3)
+    spans = _scheduler_spans(eng, since)
+    assert {s[0] for s in spans} <= set(obs.LEAF_PHASES)
+    gaps = 0.0
+    for a, b in zip(spans, spans[1:]):
+        assert b[1] >= a[2], f"{a} overlaps {b}"
+        gaps += b[1] - a[2]
+    assert gaps <= 0.01 * (spans[-1][2] - spans[0][1])
 
 
 def test_request_carries_one_decode_span_however_many_dispatches(net):
@@ -869,7 +933,8 @@ def test_loop_and_front_keys_in_contract_and_exposition(net):
     try:
         eng.submit(_prompts(1, 5, seed=9)[0], 3).result(timeout=120.0)
         loop = eng.metrics_snapshot()["components"]["decode_engine"]["loop"]
-        assert set(loop) == {"iterations", "sink_s", "sink_n",
+        assert set(loop) == {"iterations", "sink_s", "sink_n", "ahead_n",
+                             "drained_n", "overshoot_tokens",
                              "spans_dropped"} \
             | {p + sfx for p in obs.LEAF_PHASES for sfx in ("_s", "_n")}
         text = eng.metrics_text()

@@ -448,6 +448,336 @@ def test_shutdown_rejects_new_and_fails_queued(net):
         eng.submit(_prompts(1, 5)[0], 4)
 
 
+# ------------------------------------------------- one dispatch ahead
+#
+# The scheduler issues decode dispatch n+1 (and the one-shot prefills
+# admitted before it) BEFORE it waits for, reads back and delivers
+# dispatch n. The tokens a request gets must not know: they are
+# `generate`'s, bit for bit, whatever ends the request.
+
+
+def _ahead(eng) -> dict:
+    return eng.stats()["loop"]
+
+
+def _await(cond, what, timeout=60.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.002)
+
+
+def _drag(dt=0.01):
+    """Slow every decode issue down, so a request stays in flight long
+    enough for the test's next move to land mid-decode."""
+    def hook(phase, info):
+        if phase == "pre_decode":
+            time.sleep(dt)
+    return hook
+
+
+@pytest.mark.parametrize("case", ["greedy", "sampled", "one-token",
+                                  "two-tokens", "eos-inside-a-chunk",
+                                  "eos-as-first-token"])
+def test_dispatch_ahead_tokens_equal_generate(net, case):
+    prompts = _prompts(3, 5, seed=29)
+    n = {"one-token": 1, "two-tokens": 2}.get(case, 14)
+    temp, seed = (0.8, 7) if case == "sampled" else (0.0, 0)
+    want = [generate(net, prompts[i:i + 1], n, temperature=temp,
+                     seed=seed)[0] for i in range(3)]
+    eos = None
+    if case.startswith("eos"):
+        # a token the first rollout emits inside its second fused chunk,
+        # or as its very first token
+        eos = int(want[0][6 if case == "eos-inside-a-chunk" else 0])
+        want = [w[:int(np.argmax(w == eos)) + 1] if eos in w else w
+                for w in want]
+    eng = _engine(net, n_slots=2, eos_token=eos)
+    try:
+        reqs = [eng.submit(prompts[i], n, temperature=temp, seed=seed)
+                for i in range(3)]
+        for r, w in zip(reqs, want):
+            np.testing.assert_array_equal(r.result(timeout=120.0), w)
+        _await(lambda: eng.stats()["active_slots"] == 0, "the last collect")
+        loop, st = _ahead(eng), eng.stats()
+        assert st["pages_in_use"] == 0 and st["served"] == 3
+        assert loop["ahead_n"] > 0
+        # a count is known when a dispatch is issued: only what the
+        # tokens themselves say (EOS) costs computed-and-dropped tokens
+        if eos is None:
+            assert loop["overshoot_tokens"] == 0
+        else:
+            assert loop["overshoot_tokens"] > 0
+        assert st["tokens_generated"] == sum(len(w) for w in want)
+    finally:
+        eng.shutdown()
+
+
+def test_next_dispatch_is_issued_before_the_last_is_collected(net):
+    """`pre_decode(n+1)` fires before `post_decode(n)`, each `post_*`
+    gets the `info` object its `pre_*` got, and dispatches are collected
+    strictly in issue order."""
+    events = []
+    eng = _engine(net, n_slots=2, decode_chunk=1,
+                  step_hooks=[lambda ph, info: events.append((ph, info))])
+    try:
+        reqs = [eng.submit(p, 10) for p in _prompts(2, 5, seed=31)]
+        for r in reqs:
+            assert r.result(timeout=120.0).shape == (10,)
+        _await(lambda: eng.stats()["active_slots"] == 0, "the last collect")
+        loop = _ahead(eng)
+    finally:
+        eng.shutdown()
+    pre = [i for i, (ph, _) in enumerate(events) if ph == "pre_decode"]
+    post = [i for i, (ph, _) in enumerate(events) if ph == "post_decode"]
+    assert len(pre) == len(post) >= 9
+    # the same info object, in the same order
+    assert [id(events[i][1]) for i in pre] == \
+        [id(events[i][1]) for i in post]
+    assert [events[i][1]["step"] for i in pre] == list(range(len(pre)))
+    # dispatch n is collected after dispatch n+1 was issued, and before
+    # dispatch n+2 is
+    for k in range(len(pre) - 1):
+        assert pre[k + 1] < post[k]
+        if k + 2 < len(pre):
+            assert post[k] < pre[k + 2]
+    for kind in ("prefill", "decode"):
+        issued = [id(i) for ph, i in events if ph == "pre_" + kind]
+        collected = [id(i) for ph, i in events if ph == "post_" + kind]
+        assert issued == collected
+    n_dispatches = loop["decode.dispatch_n"] + loop["prefill.dispatch_n"]
+    assert 0 < loop["ahead_n"] <= n_dispatches
+    assert loop["overshoot_tokens"] == 0 and loop["drained_n"] == 0
+
+
+def test_a_poisoned_step_fails_one_request_one_dispatch_late(net):
+    """A non-finite step is seen at its collect, when the next dispatch
+    already has the slot active: the request fails typed with the tokens
+    it had, that one dispatch is dropped, its neighbour never notices,
+    and the slot's pages come back."""
+    prompts = _prompts(3, 5, seed=37)
+    want = generate(net, prompts, 12, temperature=0.0)
+    eng = _engine(net, n_slots=2, decode_chunk=1)
+    calls = {"n": 0}
+    step = eng._decode_step
+
+    def poisoned(*args):
+        out = step(*args)
+        calls["n"] += 1
+        if calls["n"] == 4:  # slot 0's fourth decode step
+            out = out[:4] + (out[4].at[0].set(False),) + out[5:]
+        return out
+
+    eng._decode_step = poisoned
+    try:
+        bad, good = eng.submit(prompts[0], 12), eng.submit(prompts[1], 12)
+        with pytest.raises(Exception, match="non-finite") as ei:
+            bad.result(timeout=120.0)
+        assert type(ei.value).__name__ == "InferenceFailedError"
+        np.testing.assert_array_equal(bad.tokens, want[0][:4])
+        np.testing.assert_array_equal(good.result(timeout=120.0), want[1])
+        np.testing.assert_array_equal(eng.generate(prompts[2], 12),
+                                      want[2])
+        _await(lambda: eng.stats()["active_slots"] == 0, "the last collect")
+        st = eng.stats()
+        assert st["failures"] == 1 and st["pages_in_use"] == 0
+        assert st["loop"]["overshoot_tokens"] == 1
+    finally:
+        eng.shutdown()
+
+
+def test_pages_are_released_after_the_last_uncollected_dispatch(net):
+    """Rule 3: while a dispatch that had a slot active is uncollected,
+    the slot stays taken and none of its request's pages is on the free
+    list, so nothing can be admitted onto them — checked from inside the
+    scheduler at every hook, on traffic where EOS retires requests with
+    a dispatch in flight and a queue waiting for the pages."""
+    prompts = _prompts(6, 9, seed=43)
+    full = generate(net, prompts, 12, temperature=0.0)
+    eos = int(full[0][5])
+    broken = []
+
+    def check(phase, info):
+        free = set(eng._free_pages)
+        for rec in eng._inflight:
+            for s, r in rec.live:
+                if eng._slots[s] is not r or r.pages is None \
+                        or free & set(r.pages):
+                    broken.append((phase, rec.program, s))
+        if phase == "pre_prefill" and any(
+                s == info["slot"] for rec in eng._inflight
+                for s, _ in rec.live):
+            broken.append((phase, "admitted under a dispatch", info))
+
+    # 9-token prompts at a 16-wide bucket, span 9+12-1=20: 3 pages each,
+    # and a pool of 6: the third request needs pages the first two hold
+    eng = _engine(net, n_slots=2, max_len=32, prompt_buckets=(16,),
+                  page_size=8, pool_pages=6, eos_token=eos,
+                  step_hooks=[check])
+    try:
+        reqs = [eng.submit(p, 12) for p in prompts]
+        for r, w in zip(reqs, full):
+            w = w[:int(np.argmax(w == eos)) + 1] if eos in w else w
+            np.testing.assert_array_equal(r.result(timeout=120.0), w)
+        _await(lambda: eng.stats()["active_slots"] == 0, "the last collect")
+        assert eng.stats()["pages_in_use"] == 0
+        assert _ahead(eng)["overshoot_tokens"] > 0
+    finally:
+        eng.shutdown()
+    assert not broken, broken[:5]
+
+
+def test_preemption_with_a_dispatch_in_flight(net):
+    """Preemption folds the victim's tokens into its prompt: it drains
+    first, so the fold holds every token the chip has computed."""
+    prompts = _prompts(2, 5, seed=47)
+    want = generate(net, prompts, 20, temperature=0.0)
+    eng = _engine(net, n_slots=1, prefix_cache=True,
+                  qos={"preempt": True}, step_hooks=[_drag()])
+    try:
+        victim = eng.submit(prompts[0], 20, tenant="bulk",
+                            priority="batch")
+        _await(lambda: len(victim.tokens) >= 3, "the victim to decode")
+        urgent = eng.submit(prompts[1], 6, tenant="live")
+        np.testing.assert_array_equal(urgent.result(timeout=120.0),
+                                      want[1][:6])
+        np.testing.assert_array_equal(victim.result(timeout=120.0), want[0])
+        st = eng.stats()
+        assert st["preemptions"] == 1 and st["loop"]["drained_n"] >= 1
+    finally:
+        eng.shutdown()
+
+
+def test_migrate_slots_with_a_dispatch_in_flight(net):
+    """The exported registers (position, last token, key) must match the
+    tokens the redirect carries: the migration pass drains first, and
+    the spliced sequence is `generate`'s."""
+    prompt = _prompts(1, 5, seed=53)[0]
+    want = generate(net, prompt[None], 20, temperature=0.7, seed=3)[0]
+    src = _engine(net, step_hooks=[_drag()])
+    dst = _engine(net)
+    try:
+        req = src.submit(prompt, 20, temperature=0.7, seed=3)
+        _await(lambda: len(req.tokens) >= 3, "tokens before the migration")
+        assert src.migrate_slots(wait=10.0) == 1
+        with pytest.raises(Exception) as ei:
+            req.result(timeout=60.0)
+        redirect = ei.value
+        assert type(redirect).__name__ == "SlotMigratedError"
+        tail = dst.resume_generate(src.fetch_handoff(redirect.handoff_id),
+                                   timeout=120.0)
+        np.testing.assert_array_equal(
+            np.concatenate([redirect.tokens, np.asarray(tail).reshape(-1)]),
+            want)
+        assert src.commit_handoff(redirect.handoff_id)
+        assert src.stats()["pages_in_use"] == 0
+        assert _ahead(src)["drained_n"] >= 1
+    finally:
+        src.shutdown()
+        dst.shutdown()
+
+
+def test_drain_and_swap_with_a_dispatch_in_flight():
+    old_net, new_net = _gpt_net(seed=1), _gpt_net(seed=2)
+    prompts = _prompts(2, 5, seed=59)
+    eng = _engine(old_net, step_hooks=[_drag()])
+    try:
+        req = eng.submit(prompts[0], 18)
+        _await(lambda: len(req.tokens) >= 2, "the request to decode")
+        eng.drain_and_swap(new_net, timeout=120.0)
+        np.testing.assert_array_equal(
+            req.result(timeout=120.0),
+            generate(old_net, prompts[:1], 18, temperature=0.0)[0])
+        np.testing.assert_array_equal(
+            eng.generate(prompts[1], 9),
+            generate(new_net, prompts[1:], 9, temperature=0.0)[0])
+        assert eng.stats()["swaps"] == 1
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("drain_timeout", [30.0, 0.0],
+                         ids=["drained", "killed"])
+def test_shutdown_with_a_dispatch_in_flight(net, drain_timeout):
+    """A drained shutdown finishes what is in flight; a kill delivers
+    what the chip was handed, then fails the request typed — and either
+    way the tokens are a prefix of `generate`'s, nothing is left in
+    flight and every page is back."""
+    prompt = _prompts(1, 5, seed=61)[0]
+    want = generate(net, prompt[None], 24, temperature=0.0)[0]
+    eng = _engine(net, step_hooks=[_drag()])
+    req = eng.submit(prompt, 24)
+    _await(lambda: len(req.tokens) >= 2, "the request to decode")
+    clean = eng.shutdown(drain_timeout=drain_timeout)
+    if drain_timeout:
+        assert clean
+        np.testing.assert_array_equal(req.result(timeout=10.0), want)
+    else:
+        assert not clean
+        with pytest.raises(ServerClosedError):
+            req.result(timeout=10.0)
+        assert 2 <= len(req.tokens) < 24
+        np.testing.assert_array_equal(req.tokens, want[:len(req.tokens)])
+    assert not eng._thread.is_alive() and not eng._inflight
+    assert eng.stats()["pages_in_use"] == 0
+
+
+class _Lost:
+    """A device handle whose read-back raises: how a dispatch that
+    failed on the chip looks from the host."""
+
+    def __array__(self, *a, **kw):
+        raise RuntimeError("injected device failure")
+
+
+@pytest.mark.parametrize("where", ["issue-hook", "issue-call", "collect"])
+def test_a_failing_dispatch_with_one_in_flight(net, where):
+    """A failure at the issue of dispatch n+1 (a hook, the call itself)
+    or at the collect of dispatch n (where a device error surfaces):
+    every request the failed dispatch had fails typed, what was
+    delivered before is `generate`'s, the state is rebuilt where the
+    pools may be lost, and the engine serves on."""
+    prompts = _prompts(3, 5, seed=67)
+    want = generate(net, prompts, 12, temperature=0.0)
+    armed = {"at": 4, "n": 0}
+
+    def hook(phase, info):
+        if phase == "pre_decode" and where == "issue-hook":
+            armed["n"] += 1
+            if armed["n"] == armed["at"]:
+                raise RuntimeError("injected hook failure")
+
+    eng = _engine(net, n_slots=2, decode_chunk=1, step_hooks=[hook])
+    step = eng._decode_step
+
+    def failing(*args):
+        armed["n"] += 1
+        if armed["n"] == armed["at"] and where == "issue-call":
+            raise RuntimeError("injected dispatch failure")
+        out = step(*args)
+        if armed["n"] == armed["at"]:
+            out = out[:4] + (_Lost(),) + out[5:]
+        return out
+
+    if where != "issue-hook":
+        eng._decode_step = failing
+    try:
+        reqs = [eng.submit(prompts[i], 12) for i in range(2)]
+        for i, r in enumerate(reqs):
+            with pytest.raises(Exception, match="decode step failed") as ei:
+                r.result(timeout=120.0)
+            assert type(ei.value).__name__ == "InferenceFailedError"
+            assert 1 <= len(r.tokens) < 12
+            np.testing.assert_array_equal(r.tokens, want[i][:len(r.tokens)])
+        np.testing.assert_array_equal(eng.generate(prompts[2], 12), want[2])
+        _await(lambda: eng.stats()["active_slots"] == 0, "the last collect")
+        st = eng.stats()
+        assert st["failures"] == 2 and st["pages_in_use"] == 0
+        assert st["served"] == 1 and not eng._inflight
+    finally:
+        eng.shutdown()
+
+
 # ------------------------------------------- ModelServer integration
 
 
