@@ -25,6 +25,14 @@ repo supports — `gpt_configuration(vocab 256, d_model 1024, 8 heads of
   the XLA expert products; the grouped-expert, paged-attention and
   KV-write kernels engaged at its shape classes; no state- or
   pool-shaped copy in the decode programs.
+- **linear**: two post-norm blocks at Olmo-Hybrid-7B's published widths
+  (one gated delta-rule mixer of 30 heads of 96 x 192, one 30-head full
+  attention with QK-norm, each with the 11008-wide gated MLP) through
+  `DecodeEngine`, on the benchmark family's seeded weights: served
+  tokens against the family's plain float32 reference and against an
+  engine on the XLA form of the step; the `gdn_step`, paged-attention
+  and KV-write kernels engaged at its shape classes (30 K/V heads); no
+  state- or pool-shaped copy in the decode programs.
 - **lstm**: `lstm_large` (H=1024, T=64, B=2048) with the fused cell.
 - **multichip** (>= 4 devices): the train step through `ParallelWrapper`
   on a {data 2, model 2} mesh against the one-chip loss, tp=4 decode
@@ -42,7 +50,7 @@ with exactly those keys. A failed phase prints the same line with
 `"ok": false` and re-raises.
 
 `python3 chip_smoke.py train lstm` runs only the named phases
-(`train serve hybrid lstm multichip`).
+(`train serve hybrid linear lstm multichip`).
 """
 from __future__ import annotations
 
@@ -78,6 +86,18 @@ HYBRID = dict(vocab_size=256, d_model=4096, layer_types=("mamba", "attention"),
 HYBRID_SERVE = dict(n_slots=64, max_len=1024, page_size=128,
                     prefill_chunk=256, n_short=5, short_len=100,
                     long_len=600, n_tokens=24)
+# Olmo-Hybrid-7B's published widths under its config's own keys
+# (`perfbench/families/olmo_hybrid.py` reads them), one layer of each kind
+LINEAR = dict(vocab_size=256, hidden_size=3840, intermediate_size=11008,
+              num_hidden_layers=2,
+              layer_types=("linear_attention", "full_attention"),
+              num_attention_heads=30, num_key_value_heads=30,
+              linear_num_key_heads=30, linear_num_value_heads=30,
+              linear_key_head_dim=96, linear_value_head_dim=192,
+              linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+              rms_norm_eps=1e-6, tie_word_embeddings=False,
+              attention_bias=False)
+LINEAR_SERVE = HYBRID_SERVE
 LSTM = dict(vocab=256, hidden=1024, T=64, batch=2048, steps=2)
 
 # A greedy token may differ between two correct attention paths only
@@ -85,6 +105,9 @@ LSTM = dict(vocab=256, hidden=1024, T=64, batch=2048, steps=2)
 # the two candidates, read off the net's own forward pass, must be inside
 # bf16 noise (8 bits of mantissa on d_model-long sums).
 TIE_MARGIN_NATS = 0.1
+# how far a served token's logit may lie under the float32 reference's
+# best at its position, two bf16 blocks deep (logits are of order 1)
+REFERENCE_GAP = 0.1
 # bf16 tolerance for one loss computed two ways (1 chip vs the mesh)
 LOSS_RTOL = 2e-2
 
@@ -528,6 +551,18 @@ def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
     return out
 
 
+def _check_recurrent_run(stats: dict, shape: dict, n_prompts: int) -> None:
+    """The long prompt rode chunked prefill, and every admission
+    overwrote its slot's recurrent state."""
+    n_chunks = -(-shape["long_len"] // shape["prefill_chunk"])
+    _check(stats["prefill_chunks"] >= n_chunks,
+           f"long prompt did not ride chunked prefill: "
+           f"{stats['prefill_chunks']} chunks < {n_chunks}")
+    _check(stats["state_resets"] == n_prompts,
+           f"{stats['state_resets']} slot states reset for "
+           f"{n_prompts} admissions")
+
+
 def _hybrid_net(hyb: dict, dtype):
     """Composed blocks from `hybrid_moe_configuration`, `init()`'s own
     weights, the embedding scaled down so that a token's own logit does
@@ -558,13 +593,7 @@ def phase_hybrid(hyb: dict, shape: dict, *, kernels: bool,
     gen = _engine_kwargs(shape)
     toks, stats = _through_engine(net, prompts, n_tokens, **gen)
     _check_tokens(toks, n_tokens, vocab, stats, len(prompts), "hybrid")
-    n_chunks = -(-shape["long_len"] // shape["prefill_chunk"])
-    _check(stats["prefill_chunks"] >= n_chunks,
-           f"long prompt did not ride chunked prefill: "
-           f"{stats['prefill_chunks']} chunks < {n_chunks}")
-    _check(stats["state_resets"] == len(prompts),
-           f"{stats['state_resets']} slot states reset for "
-           f"{len(prompts)} admissions")
+    _check_recurrent_run(stats, shape, len(prompts))
     share = stats["moe_held_choices"] / max(1, stats["moe_routed"])
     held = hyb["experts_held"][1] / hyb["n_experts"]
     _check(0.5 * held < share < 2.0 * held,
@@ -606,6 +635,102 @@ def phase_hybrid(hyb: dict, shape: dict, *, kernels: bool,
         _check(engaged("paged_attention", lambda k: k == key),
                f"paged kernel did not engage for shape class {key}")
         key = ("bfloat16", Hkv, d // H, shape["page_size"], "dense")
+        _check(engaged("paged_kv_write", lambda k: k == key),
+               f"in-place KV write did not engage for {key}")
+        _check(not any(out["pool_layout_copies"].values()),
+               f"the decode programs copy their state or pools: "
+               f"{out['pool_layout_copies']}")
+    return out
+
+
+def _reference_gaps(fam, ref, cfg, sz, weights, prompts, toks) -> list:
+    """For each request, the most by which a served token's logit lies
+    under the plain reference's best logit at its position (0: every
+    served token is the reference's own argmax)."""
+    import jax.numpy as jnp
+
+    c = ref.consts_from_config(cfg)
+    gaps = []
+    for prompt, served in zip(prompts, toks):
+        ids = np.concatenate([prompt, served[:-1]])
+        rows = np.arange(len(prompt) - 1, len(ids))
+        logits = np.asarray(ref.logits_at(
+            weights, jnp.asarray(ids)[None], jnp.asarray(rows), c=c,
+            n_heads=sz["H"], eps=sz["eps"]))
+        gaps.append(float(np.max(
+            logits.max(-1) - logits[np.arange(len(served)), served])))
+    return gaps
+
+
+def phase_linear(lin: dict, shape: dict, *, kernels: bool,
+                 dtype=None) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.serving.decode_engine import DecodeEngine
+    from perfbench.families import olmo_hybrid as fam
+    from perfbench.families import olmo_hybrid_reference as ref
+
+    dtype = dtype or jnp.bfloat16
+    vocab, n_tokens = lin["vocab_size"], shape["n_tokens"]
+    sz = fam.sizes(lin)
+    weights = fam.make_weights(0, sz)
+    net = fam.build_net(sz, training=False, dtype=dtype)
+    fam.install(net, jax.tree.map(lambda a: a.astype(dtype), weights))
+    prompts = _serve_prompts(vocab, shape)
+    gen = _engine_kwargs(shape)
+    toks, stats = _through_engine(net, prompts, n_tokens, **gen)
+    _check_tokens(toks, n_tokens, vocab, stats, len(prompts), "linear")
+    _check_recurrent_run(stats, shape, len(prompts))
+    n_linear = sz["layer_types"].count("linear_attention")
+    _check(stats["recurrent_blocks"] == n_linear
+           and stats["kv_blocks"] == sz["L"] - n_linear,
+           f"blocks by cache kind: {stats['recurrent_blocks']} recurrent, "
+           f"{stats['kv_blocks']} K/V of {sz['layer_types']}")
+    out = {"requests": len(prompts), "tokens": int(sum(map(len, toks))),
+           "prefill_chunks": stats["prefill_chunks"],
+           "decode_steps": stats["decode_steps"],
+           "state_bytes_per_slot": stats["state_bytes_per_slot"],
+           "kv_bytes_per_token": stats["kv_bytes_per_token"]}
+    gc.collect()
+
+    # a bucketed and the chunked prompt against the plain reference's
+    # full forward: prefill, then decode through both caches
+    picked = (0, len(prompts) - 1)
+    out["reference_gaps"] = [round(g, 5) for g in _reference_gaps(
+        fam, ref, lin, sz, weights, [prompts[i] for i in picked],
+        [toks[i] for i in picked])]
+    _check(max(out["reference_gaps"]) < REFERENCE_GAP,
+           f"served tokens lie {out['reference_gaps']} under the "
+           f"reference's best logit")
+
+    # the same prompts with the step as XLA's elementwise form
+    os.environ["DL4J_TPU_NO_PALLAS_GDN_STEP"] = "1"
+    try:
+        xla, xla_stats = _through_engine(net, prompts, n_tokens, **gen)
+    finally:
+        del os.environ["DL4J_TPU_NO_PALLAS_GDN_STEP"]
+    _check_tokens(xla, n_tokens, vocab, xla_stats, len(prompts), "xla-step")
+    out["agreement"] = _agreement(net, prompts, toks, xla,
+                                  "kernel and XLA delta-rule steps")
+    gc.collect()
+
+    engine = DecodeEngine(net, **gen)
+    try:
+        out.update(_decode_program_counts(engine))
+    finally:
+        engine.shutdown(drain_timeout=30.0)
+    print(f"linear: state and pool copies in the decode programs "
+          f"{out['pool_layout_copies']}", flush=True)
+    if kernels:
+        key = ("bfloat16", sz["lh"], sz["lk"], sz["lv"])
+        _check(engaged("gdn_step", lambda k: k == key),
+               f"gated delta step kernel did not engage for {key}")
+        H, hd = sz["H"], sz["hd"]
+        key = ("bfloat16", 1, H, H, hd, shape["page_size"], "dense")
+        _check(engaged("paged_attention", lambda k: k == key),
+               f"paged kernel did not engage for shape class {key}")
+        key = ("bfloat16", H, hd, shape["page_size"], "dense")
         _check(engaged("paged_kv_write", lambda k: k == key),
                f"in-place KV write did not engage for {key}")
         _check(not any(out["pool_layout_copies"].values()),
@@ -713,10 +838,12 @@ def main(argv=None) -> int:
     from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
 
     names = list(sys.argv[1:] if argv is None else argv) \
-        or ["train", "serve", "hybrid", "lstm", "multichip"]
-    unknown = set(names) - {"train", "serve", "hybrid", "lstm", "multichip"}
+        or ["train", "serve", "hybrid", "linear", "lstm", "multichip"]
+    unknown = set(names) - {"train", "serve", "hybrid", "linear", "lstm",
+                            "multichip"}
     if unknown or ("multichip" in names and "train" not in names):
-        print(f"chip_smoke: phases are train serve hybrid lstm multichip "
+        print(f"chip_smoke: phases are train serve hybrid linear lstm "
+              f"multichip "
               f"(multichip compares against train's loss, so name both); "
               f"got {names}", file=sys.stderr)
         return 2
@@ -755,6 +882,8 @@ def main(argv=None) -> int:
             run("serve", phase_serve, GPT, SERVE, kernels=True)
         if "hybrid" in names:
             run("hybrid", phase_hybrid, HYBRID, HYBRID_SERVE, kernels=True)
+        if "linear" in names:
+            run("linear", phase_linear, LINEAR, LINEAR_SERVE, kernels=True)
         if "lstm" in names:
             run("lstm", phase_lstm, LSTM, kernels=True)
         if "multichip" in names:

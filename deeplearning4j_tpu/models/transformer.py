@@ -29,6 +29,8 @@ from deeplearning4j_tpu.nn.conf import (
 from deeplearning4j_tpu.nn.conf.decoder_block import (
     AttentionMixer,
     DecoderBlock,
+    GatedDeltaNetMixer,
+    GatedMLP,
     Mamba2Mixer,
     MoEFeedForward,
     RMSNorm,
@@ -162,6 +164,56 @@ def hybrid_moe_configuration(vocab_size: int, d_model: int,
             .build())
 
 
+def hybrid_linear_configuration(vocab_size: int, d_model: int,
+                                layer_types, *,
+                                n_heads: int, n_kv_heads: int = 0,
+                                linear_heads: int, linear_key_dim: int,
+                                linear_value_dim: int, linear_conv: int = 4,
+                                allow_neg_eigval: bool = False,
+                                ffn_width: int, eps: float = 1e-6,
+                                seed: int = 12345,
+                                learning_rate: float = 3e-4,
+                                updater: Updater = Updater.ADAM,
+                                ) -> MultiLayerConfiguration:
+    """Causal LM of composed post-norm `DecoderBlock`s, one per entry of
+    `layer_types` ("linear_attention": a gated delta-rule mixer,
+    "full_attention": multi-head attention with QK-norm and without
+    positions), each followed by a dense gated MLP, under RMSNorm; no
+    positional layer, one trailing norm and an untied, bias-free output
+    head (the Hugging Face `olmo_hybrid` family's layout)."""
+    b = (NeuralNetConfiguration.Builder()
+         .seed(seed)
+         .learning_rate(learning_rate)
+         .updater(updater)
+         .drop_out(0.0)
+         .list()
+         .layer(TokenEmbedding(n_in=vocab_size, n_out=d_model,
+                               positional=False)))
+    mixers = {
+        "linear_attention": GatedDeltaNetMixer(
+            n_heads=linear_heads, key_dim=linear_key_dim,
+            value_dim=linear_value_dim, d_conv=linear_conv,
+            allow_neg_eigval=allow_neg_eigval, eps=eps),
+        "full_attention": AttentionMixer(n_heads=n_heads,
+                                         n_kv_heads=n_kv_heads,
+                                         qk_norm=True, eps=eps)}
+    for kind in layer_types:
+        b = b.layer(DecoderBlock(n_in=d_model, n_out=d_model,
+                                 mixer=mixers[kind],
+                                 ffn=GatedMLP(width=ffn_width),
+                                 norm=RMSNorm(eps=eps),
+                                 norm_placement="post"))
+    return (b
+            .layer(RMSNormalization(n_in=d_model, n_out=d_model, eps=eps,
+                                    dropout=0.0))
+            .layer(RnnOutputLayer(n_in=d_model, n_out=vocab_size,
+                                  has_bias=False,
+                                  activation=Activation.SOFTMAX,
+                                  loss=LossFunction.MCXENT, dropout=0.0))
+            .set_input_type(InputType.recurrent(vocab_size))
+            .build())
+
+
 # ---------------------------------------------------------------------------
 # shared decode plan + per-block compute (generate() AND the serving
 # decode engine trace through these — one implementation of the numerics)
@@ -283,6 +335,8 @@ class GPTPlan:
             head = self.layers[self.out_i]
             if isinstance(head, TiedRnnOutputLayer):
                 return head.pre_output(params[head.tied_to], x)
+            if not head.has_bias:
+                return head.pre_output(params[self.out_i], x)
             return x @ params[self.out_i]["W"] + params[self.out_i]["b"]
 
 

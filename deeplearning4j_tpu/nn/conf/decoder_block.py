@@ -1,7 +1,8 @@
 """A decoder block composed of kinds: a sequence mixer, a feed-forward
 and a norm, each a small config object of its own.
 
-    h <- h + r * mixer(norm(h));  h <- h + r * ffn(norm(h))
+    h <- h + r * mixer(norm(h));  h <- h + r * ffn(norm(h))      "pre"
+    h <- h + r * norm(mixer(h));  h <- h + r * norm(ffn(h))      "post"
 
 `TransformerBlock` is one fixed composition (LayerNorm, biased
 multi-head attention, dense MLP) and stays as it is; a model whose block
@@ -13,7 +14,9 @@ must keep for it, `state`:
     "kv"         paged key/value pools, one position a token
                  (`AttentionMixer`; `kv_geometry` gives heads and width)
     "recurrent"  per-slot arrays of fixed size, overwritten in place
-                 (`Mamba2Mixer`; `state_shapes` gives them)
+                 (`Mamba2Mixer`, `GatedDeltaNetMixer`; `state_shapes`
+                 gives them: the state, slot axis first, then the
+                 convolution tail, tap-major)
 
 and `serving/block_state.py` turns that declaration into the engine's
 allocation and its prefill / decode steps. A kind serialises as
@@ -38,7 +41,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
     register_layer,
     rms_norm,
 )
-from deeplearning4j_tpu.ops import ssm
+from deeplearning4j_tpu.ops import delta_rule, ssm
 
 _KINDS = {}
 _FLASH_FROM = 1024  # keys: beyond it attention takes the flash / blockwise path
@@ -95,17 +98,33 @@ class RMSNorm(_Kind):
 
 
 # -------------------------------------------------------------- mixer kinds
+def _decay_params(k_dt, k_a, n_heads: int, dtype) -> dict:
+    """A recurrent mixer's per-head decay as Mamba-2 draws it: `dt_bias`
+    the inverse softplus of a step log-uniform in [1e-3, 1e-1], `A_log`
+    the log of a uniform in [1, 16]."""
+    dt = jnp.exp(jax.random.uniform(k_dt, (n_heads,)) *
+                 (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+    return {"dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+            "A_log": jnp.log(jax.random.uniform(
+                k_a, (n_heads,), minval=1.0, maxval=16.0)).astype(dtype)}
+
+
 @_kind
 @dataclass(frozen=True)
 class AttentionMixer(_Kind):
     """Causal grouped-query attention without biases and without
     positional encoding; `scale` multiplies the scores (None: the usual
-    1 / sqrt(head_dim)). Keeps paged K/V."""
+    1 / sqrt(head_dim)). With `qk_norm` the query and key projections
+    each pass an RMSNorm over their whole width (one gain an element,
+    before the split into heads: the Olmo 2/3 convention), and the
+    normed keys are what the pages hold. Keeps paged K/V."""
     KIND = "attention"
     state = "kv"
     n_heads: int = 4
     n_kv_heads: int = 0          # 0: as many as n_heads
     scale: Optional[float] = None
+    qk_norm: bool = False
+    eps: float = 1e-6            # of the query / key norms
 
     @property
     def _kv_heads(self) -> int:
@@ -118,8 +137,18 @@ class AttentionMixer(_Kind):
         hd = d // self.n_heads
         qw, kvw = self.n_heads * hd, self._kv_heads * hd
         k1, k2 = jax.random.split(key)
-        return {"Wqkv": winit(k1, (d, qw + 2 * kvw), d, qw + 2 * kvw),
-                "Wo": winit(k2, (qw, d), qw, d)}
+        p = {"Wqkv": winit(k1, (d, qw + 2 * kvw), d, qw + 2 * kvw),
+             "Wo": winit(k2, (qw, d), qw, d)}
+        if self.qk_norm:
+            p.update(qn_w=jnp.ones((qw,), dtype),
+                     kn_w=jnp.ones((kvw,), dtype))
+        return p
+
+    def _qk_normed(self, p, gain: str, u):
+        if not self.qk_norm:
+            return u
+        with jax.named_scope("attn.qk_norm"):
+            return rms_norm(u, p[gain], self.eps)
 
     def heads(self, p, x):
         """(..., d) -> q (..., H, hd), k and v (..., Hkv, hd). The
@@ -129,9 +158,10 @@ class AttentionMixer(_Kind):
         qw, kvw = self.n_heads * hd, self._kv_heads * hd
         with jax.named_scope("attn.qkv"):
             qkv = x @ p["Wqkv"]
-            q = qkv[..., :qw].reshape(*x.shape[:-1], self.n_heads, hd)
-            k = qkv[..., qw:qw + kvw].reshape(*x.shape[:-1],
-                                              self._kv_heads, hd)
+            q = self._qk_normed(p, "qn_w", qkv[..., :qw]) \
+                .reshape(*x.shape[:-1], self.n_heads, hd)
+            k = self._qk_normed(p, "kn_w", qkv[..., qw:qw + kvw]) \
+                .reshape(*x.shape[:-1], self._kv_heads, hd)
             v = qkv[..., qw + kvw:].reshape(*x.shape[:-1],
                                             self._kv_heads, hd)
             if self.scale is not None:
@@ -190,17 +220,11 @@ class Mamba2Mixer(_Kind):
         di, cw, H = self.d_inner, self.conv_width, self.n_heads
         k = jax.random.split(key, 5)
         width = di + cw + H
-        # dt_bias: inverse softplus of a step drawn log-uniform in
-        # [1e-3, 1e-1]; A_log: log of uniform [1, 16] (Mamba-2's init)
-        dt = jnp.exp(jax.random.uniform(k[3], (H,)) *
-                     (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
         return {"Win": winit(k[0], (d, width), d, width),
                 "conv_w": (jax.random.normal(k[1], (cw, self.d_conv))
                            / math.sqrt(self.d_conv)).astype(dtype),
                 "conv_b": jnp.zeros((cw,), dtype),
-                "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
-                "A_log": jnp.log(jax.random.uniform(
-                    k[4], (H,), minval=1.0, maxval=16.0)).astype(dtype),
+                **_decay_params(k[3], k[4], H, dtype),
                 "D": jnp.ones((H,), dtype),
                 "norm_w": jnp.ones((di,), dtype),
                 "Wout": winit(k[2], (di, d), di, d)}
@@ -273,7 +297,169 @@ class Mamba2Mixer(_Kind):
         return self.scan(p, x)[0]
 
 
+@_kind
+@dataclass(frozen=True)
+class GatedDeltaNetMixer(_Kind):
+    """The gated delta-rule layer (Gated DeltaNet, arXiv:2412.06464) as
+    the `fla` / Hugging Face layers that use the `linear_*` config keys
+    write it: one in-projection to [q | k | v | gate | a | b], a
+    depthwise causal convolution and silu over [q | k | v], q and k
+    L2-normed per head (q also scaled by d_k^-1/2), `beta = sigmoid(b)`
+    (doubled with `allow_neg_eigval`), log decay `g = -exp(A_log) *
+    softplus(a + dt_bias)`, the recurrence of `ops/delta_rule.py`, an
+    RMSNorm per head over d_v whose output the gate multiplies (AFTER
+    the norm; `Mamba2Mixer` gates before it), out-projection. Keeps a
+    per-slot matrix state (float32, `(d_k, H * d_v)`: no lane of it is
+    padding) and the convolution's last inputs."""
+    KIND = "gated_delta_net"
+    state = "recurrent"
+    n_heads: int = 4
+    key_dim: int = 8
+    value_dim: int = 16
+    d_conv: int = 4
+    chunk: int = 64
+    allow_neg_eigval: bool = False
+    eps: float = 1e-6
+
+    @property
+    def qk_width(self) -> int:
+        return self.n_heads * self.key_dim
+
+    @property
+    def v_width(self) -> int:
+        return self.n_heads * self.value_dim
+
+    @property
+    def conv_width(self) -> int:
+        return 2 * self.qk_width + self.v_width
+
+    def state_shapes(self, n_slots: int, dtype) -> tuple:
+        """((shape, dtype), ...) of what one block keeps for `n_slots`
+        slots: the matrix states (`ops/delta_rule.py`'s layout), then
+        the convolution tail (tap-major: `ops/ssm.conv_step`)."""
+        return (((n_slots, self.key_dim, self.v_width), jnp.float32),
+                ((self.d_conv - 1, n_slots, self.conv_width), dtype))
+
+    def init_params(self, key, d: int, dtype, winit) -> dict:
+        cw, vw, H = self.conv_width, self.v_width, self.n_heads
+        k = jax.random.split(key, 5)
+        width = cw + vw + 2 * H
+        return {"Win": winit(k[0], (d, width), d, width),
+                "conv_w": (jax.random.normal(k[1], (cw, self.d_conv))
+                           / math.sqrt(self.d_conv)).astype(dtype),
+                **_decay_params(k[3], k[4], H, dtype),
+                "norm_w": jnp.ones((self.value_dim,), dtype),
+                "Wout": winit(k[2], (vw, d), vw, d)}
+
+    def _split_in(self, p, x):
+        with jax.named_scope("gdn.in_proj"):
+            z = x @ p["Win"]
+        cw, vw, H = self.conv_width, self.v_width, self.n_heads
+        return (z[..., :cw], z[..., cw:cw + vw],
+                z[..., cw + vw:cw + vw + H], z[..., cw + vw + H:])
+
+    def _heads(self, qkv):
+        """silu(conv) output (..., Cw) -> q, k (..., H, d_k) float32,
+        normalised, and v (..., H, d_v)."""
+        qw, H = self.qk_width, self.n_heads
+        lead = qkv.shape[:-1]
+
+        def unit(u):
+            u = u.astype(jnp.float32).reshape(*lead, H, self.key_dim)
+            return u * jax.lax.rsqrt(
+                jnp.sum(u * u, axis=-1, keepdims=True) + 1e-6)
+
+        return (unit(qkv[..., :qw]) * self.key_dim ** -0.5,
+                unit(qkv[..., qw:2 * qw]),
+                qkv[..., 2 * qw:].reshape(*lead, H, self.value_dim))
+
+    def _gates(self, p, a_raw, b_raw, keep=None):
+        """(g, beta) float32; where `keep` is False both are 0 and the
+        slot's state stays as it was."""
+        beta = jax.nn.sigmoid(b_raw.astype(jnp.float32))
+        if self.allow_neg_eigval:
+            beta = 2.0 * beta
+        g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+            a_raw.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+        if keep is None:
+            return g, beta
+        return jnp.where(keep, g, 0.0), jnp.where(keep, beta, 0.0)
+
+    def _finish(self, p, o, gate):
+        with jax.named_scope("gdn.gate_norm"):
+            y = rms_norm(o, p["norm_w"], self.eps).reshape(gate.shape) \
+                * jax.nn.silu(gate)
+        with jax.named_scope("gdn.out_proj"):
+            return y @ p["Wout"]
+
+    def scan(self, p, x, h0=None, tail=None, n_valid=None):
+        """A whole stretch (B, T, d) from state `h0` and convolution
+        tail `tail` (None: the start of a sequence). Positions at and
+        after `n_valid` (a traced scalar, default T) are padding: they
+        move neither the state nor the tail. Returns (out (B, T, d),
+        state, tail)."""
+        qkv, gate, a_raw, b_raw = self._split_in(p, x)
+        with jax.named_scope("gdn.conv"):
+            qkv, tail = ssm.causal_conv(qkv, p["conv_w"], None, tail,
+                                        n_valid)
+            qkv = jax.nn.silu(qkv)
+        with jax.named_scope("gdn.scan"):
+            o, h = delta_rule.delta_chunked(
+                *self._heads(qkv), *self._gates(p, a_raw, b_raw),
+                chunk=self.chunk, h0=h0, n_valid=n_valid)
+        return self._finish(p, o, gate), h, tail
+
+    def step(self, p, x, h, tail, active=None):
+        """One token for every slot: `x` (S, d), `h` (S, d_k, H * d_v),
+        `tail` (K - 1, S, Cw). Slots that `active` (S,) bool leaves out
+        keep state and tail as they are."""
+        from deeplearning4j_tpu.ops.pallas_delta_step import (
+            gdn_step_or_none,
+        )
+
+        qkv, gate, a_raw, b_raw = self._split_in(p, x)
+        with jax.named_scope("gdn.conv"):
+            qkv, new_tail = ssm.conv_step(qkv, p["conv_w"], None, tail)
+            qkv = jax.nn.silu(qkv)
+            if active is not None:
+                new_tail = jnp.where(active[None, :, None], new_tail,
+                                     tail)
+        keep = None if active is None else active[:, None]
+        with jax.named_scope("gdn.step"):
+            args = (h, *self._heads(qkv),
+                    *self._gates(p, a_raw, b_raw, keep))
+            out = gdn_step_or_none(*args)
+            o, h = delta_rule.delta_step(*args) if out is None else out
+        return self._finish(p, o, gate), h, new_tail.astype(tail.dtype)
+
+    def forward(self, p, x):
+        return self.scan(p, x)[0]
+
+
 # ------------------------------------------------------- feed-forward kinds
+@_kind
+@dataclass(frozen=True)
+class GatedMLP(_Kind):
+    """One dense gated MLP of width `width`, no bias:
+    `(silu(x Wg) * (x Wu)) Wd`."""
+    KIND = "gated_mlp"
+    width: int = 64
+
+    def init_params(self, key, d: int, dtype, winit) -> dict:
+        f = self.width
+        k = jax.random.split(key, 3)
+        return {"Wg": winit(k[0], (d, f), d, f),
+                "Wu": winit(k[1], (d, f), d, f),
+                "Wd": winit(k[2], (f, d), f, d)}
+
+    def forward(self, p, x, count_mask=None):
+        """`x` (..., d) -> (y, None): no router, nothing to count."""
+        from deeplearning4j_tpu.parallel.experts import gated_mlp
+
+        with jax.named_scope("mlp"):
+            return gated_mlp(x, p["Wg"], p["Wu"], p["Wd"]), None
+
+
 @_kind
 @dataclass(frozen=True)
 class MoEFeedForward(_Kind):
@@ -332,9 +518,12 @@ class MoEFeedForward(_Kind):
 @register_layer
 @dataclass
 class DecoderBlock(FeedForwardLayer):
-    """One pre-norm decoder block composed of a mixer kind, a
-    feed-forward kind and a norm kind (module docstring);
-    `residual_multiplier` scales both branches before they are added."""
+    """One decoder block composed of a mixer kind, a feed-forward kind
+    and a norm kind (module docstring); `norm_placement` says whether
+    the norm stands before each sub-layer ("pre": its INPUT is normed)
+    or after it ("post": its OUTPUT is normed before the residual add,
+    the Olmo 2/3 convention); `residual_multiplier` scales both
+    branches before they are added."""
 
     TYPE = "decoder_block"
     input_kind = "rnn"
@@ -344,8 +533,12 @@ class DecoderBlock(FeedForwardLayer):
     ffn: object = None
     norm: object = None
     residual_multiplier: float = 1.0
+    norm_placement: str = "pre"
 
     def __post_init__(self):
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(f"norm_placement {self.norm_placement!r}: "
+                             "'pre' or 'post'")
         self.mixer = kind_from_json(self.mixer)
         self.ffn = kind_from_json(self.ffn)
         self.norm = kind_from_json(self.norm) or RMSNorm()
@@ -378,11 +571,21 @@ class DecoderBlock(FeedForwardLayer):
         with jax.named_scope("norm1"):
             return self.norm.apply(p["n1_w"], x)
 
+    def mixer_in(self, p, x):
+        """What the mixer reads: the block's input, normed first where
+        the norm stands before the sub-layer."""
+        return self.norm1(p, x) if self.norm_placement == "pre" else x
+
     def finish(self, p, x, mixed, count_mask=None):
         """The block from the mixer's output on: first residual, norm,
         feed-forward, second residual. Returns (h, the feed-forward's
         counts under `count_mask`, or None)."""
         r = jnp.asarray(self.residual_multiplier, x.dtype)
+        if self.norm_placement == "post":
+            h = x + r * self.norm1(p, mixed)
+            f, counts = self.ffn.forward(sub(p, "ff_"), h, count_mask)
+            with jax.named_scope("norm2"):
+                return h + r * self.norm.apply(p["n2_w"], f), counts
         h = x + r * mixed
         with jax.named_scope("norm2"):
             u = self.norm.apply(p["n2_w"], h)
@@ -392,11 +595,12 @@ class DecoderBlock(FeedForwardLayer):
     def forward(self, params, state, x, *, train=False, rng=None,
                 mask=None):
         mixed = self.mixer.forward(sub(params, "mx_"),
-                                   self.norm1(params, x))
+                                   self.mixer_in(params, x))
         return self.finish(params, x, mixed)[0], state
 
     def param_flags(self, name):
         vector = name in ("n1_w", "n2_w", "mx_norm_w", "mx_conv_b",
-                          "mx_dt_bias", "mx_A_log", "mx_D")
+                          "mx_dt_bias", "mx_A_log", "mx_D", "mx_qn_w",
+                          "mx_kn_w")
         return {"is_bias": name in ("mx_conv_b", "mx_dt_bias"),
                 "regularizable": not vector}

@@ -333,10 +333,27 @@ class OutputLayer(DenseLayer):
 class RnnOutputLayer(OutputLayer):
     """Per-timestep output layer (reference
     `nn/conf/layers/RnnOutputLayer.java`): labels are (B, T, nOut), score is
-    masked mean over valid (b, t) rows."""
+    masked mean over valid (b, t) rows. `has_bias=False` is the
+    bias-free head of the composed-block language models: it holds `W`
+    alone, and its product accumulates and comes out in float32
+    whatever `W`'s dtype, as the tied head's does."""
 
     TYPE = "rnn_output"
     input_kind = "rnn"
+    has_bias: bool = True
+
+    def init_params(self, key, it, dtype=jnp.float32) -> Params:
+        p = super().init_params(key, it, dtype)
+        if not self.has_bias:
+            del p["b"]
+        return p
+
+    def pre_output(self, params, x, *, train=False, rng=None):
+        if self.has_bias:
+            return super().pre_output(params, x, train=train, rng=rng)
+        x = self._maybe_dropout(x, train, rng)
+        return jnp.einsum("...d,dv->...v", x, params["W"],
+                          preferred_element_type=jnp.float32)
 
     def output_type(self, it: InputType) -> InputType:
         t = it.timeseries_length if isinstance(it, InputTypeRecurrent) else -1

@@ -126,7 +126,7 @@ def ssd_chunked(x, dt, A, Bm, Cm, D, *, chunk: int, h0=None):
 
 def causal_conv(u, w, b, tail=None, n_valid=None):
     """Depthwise causal convolution over time with a carried tail.
-    `u` (B, T, Cw); `w` (Cw, K); `b` (Cw,); `tail` (B, K - 1, Cw), the
+    `u` (B, T, Cw); `w` (Cw, K); `b` (Cw,) or None; `tail` (B, K - 1, Cw), the
     K - 1 inputs before u[:, 0] (zeros at the start of a sequence; the
     channel axis is minor, as a TPU lays it out anyway). Returns
     (out (B, T, Cw), the tail after `n_valid` positions: the last K - 1
@@ -137,7 +137,7 @@ def causal_conv(u, w, b, tail=None, n_valid=None):
     if tail is None:
         tail = jnp.zeros((B, K - 1, Cw), u.dtype)
     full = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
-    out = b.astype(F32)
+    out = 0.0 if b is None else b.astype(F32)
     for k in range(K):  # four taps, summed in float32
         out = out + full[:, k:k + T].astype(F32) * w[:, k].astype(F32)
     n = T if n_valid is None else n_valid
@@ -152,6 +152,7 @@ def conv_step(u, w, b, tail):
     is padded to a tile, and the step's programs copied the array into
     this layout and back). Returns (out (S, Cw), new tail)."""
     window = jnp.concatenate([tail.astype(u.dtype), u[None]], axis=0)
-    out = jnp.sum(window.astype(F32) * w.astype(F32).T[:, None, :], axis=0) \
-        + b.astype(F32)
+    out = jnp.sum(window.astype(F32) * w.astype(F32).T[:, None, :], axis=0)
+    if b is not None:
+        out = out + b.astype(F32)
     return out.astype(u.dtype), window[1:]

@@ -11,9 +11,12 @@ kind each block keeps (`GPTPlan.state_kinds`):
                     one position a token, read through the page table
                     (`TransformerBlock`, or a composed block whose mixer
                     is attention);
-    RecurrentSlots  per-slot arrays of fixed size (a Mamba-2 mixer's
-                    float32 state `(S, H, P, N)` and its convolution
-                    tail `(K-1, S, Cw)`): allocated by slot, OVERWRITTEN
+    RecurrentSlots  per-slot arrays of fixed size, as the mixer's
+                    `state_shapes` declares them (a Mamba-2 mixer's
+                    float32 state `(S, H, P, N)`, a gated delta-rule
+                    mixer's `(S, d_k, H * d_v)`: any rank, the slot
+                    axis first; and the convolution tail `(K-1, S,
+                    Cw)`, tap-major): allocated by slot, OVERWRITTEN
                     when a slot is admitted (a one-shot prefill, or the
                     first chunk of a chunked one, starts from zeros),
                     carried from one prefill chunk to the next, left
@@ -85,7 +88,7 @@ class _ComposedAttention:
 
     def heads(self, p, x, positions):
         return self.layer.mixer.heads(sub(p, "mx_"),
-                                      self.layer.norm1(p, x))
+                                      self.layer.mixer_in(p, x))
 
     def finish(self, p, x, att, d):
         return _finish_composed(self.layer, p, x,
@@ -304,13 +307,14 @@ class RecurrentSlots:
 
     def decode(self, p, x, cache, d):
         h, tail = cache
-        y, h, tail = self.mixer.step(sub(p, "mx_"), self.layer.norm1(p, x),
-                                     h, tail, d.active)
+        y, h, tail = self.mixer.step(
+            sub(p, "mx_"), self.layer.mixer_in(p, x), h, tail, d.active)
         return _finish_composed(self.layer, p, x, y, d), (h, tail)
 
     def _store(self, cache, h1, tail1, slot):
         z = jnp.zeros((), jnp.int32)
-        h = jax.lax.dynamic_update_slice(cache[0], h1, (slot, z, z, z))
+        h = jax.lax.dynamic_update_slice(
+            cache[0], h1, (slot,) + (z,) * (cache[0].ndim - 1))
         tail = jax.lax.dynamic_update_slice(
             cache[1], jnp.swapaxes(tail1, 0, 1).astype(cache[1].dtype),
             (z, slot, z))
@@ -321,7 +325,7 @@ class RecurrentSlots:
         # overwritten, not accumulated into; pad positions past t0 do
         # not move the state
         y, h1, tail1 = self.mixer.scan(sub(p, "mx_"),
-                                       self.layer.norm1(p, x),
+                                       self.layer.mixer_in(p, x),
                                        n_valid=d.t0)
         x = _finish_composed(self.layer, p, x, y, d)
         return x, self._store(cache, h1, tail1, d.slot)
@@ -330,15 +334,16 @@ class RecurrentSlots:
         Cw = x.shape[1]
         z = jnp.zeros((), jnp.int32)
         first = d.off == 0
-        h0 = jax.lax.dynamic_slice(cache[0], (d.slot, z, z, z),
-                                   (1,) + cache[0].shape[1:])
+        h0 = jax.lax.dynamic_slice(
+            cache[0], (d.slot,) + (z,) * (cache[0].ndim - 1),
+            (1,) + cache[0].shape[1:])
         tail0 = jnp.swapaxes(jax.lax.dynamic_slice(
             cache[1], (z, d.slot, z),
             (cache[1].shape[0], 1, cache[1].shape[2])), 0, 1)
         h0 = jnp.where(first, jnp.zeros_like(h0), h0)
         tail0 = jnp.where(first, jnp.zeros_like(tail0), tail0)
         y, h1, tail1 = self.mixer.scan(
-            sub(p, "mx_"), self.layer.norm1(p, x), h0, tail0,
+            sub(p, "mx_"), self.layer.mixer_in(p, x), h0, tail0,
             n_valid=jnp.clip(d.t0 - d.off, 0, Cw))
         x = _finish_composed(self.layer, p, x, y, d)
         return x, self._store(cache, h1, tail1, d.slot)

@@ -807,6 +807,8 @@ class DecodeEngine:
         self._recurrent = any(st.kind == "recurrent" for st in states)
         self._state_bytes_per_slot = sum(st.bytes_per_slot()
                                          for st in states)
+        self._recurrent_blocks = sum(st.kind == "recurrent"
+                                     for st in states)
         routed = block_state.routed_ffns(plan)
         self._moe_blocks = len(routed)
         self._moe_top_k = max([ffn.top_k for ffn in routed], default=0)
@@ -1733,6 +1735,9 @@ class DecodeEngine:
                # blocks' state and convolution tails (0: none)
                "state_bytes_per_slot": self._state_bytes_per_slot,
                "state_resets": self.state_resets,
+               # how many blocks keep which kind of cache
+               "recurrent_blocks": self._recurrent_blocks,
+               "kv_blocks": len(self._states) - self._recurrent_blocks,
                # routed experts, decode steps only: top-k choices of
                # active slots, those on experts held here, held experts
                # hit (summed over blocks and steps) and the steps

@@ -80,6 +80,40 @@ def test_hybrid_phase_toy():
     json.dumps(out)
 
 
+def test_linear_phase_toy():
+    import jax.numpy as jnp
+
+    lin = dict(chip_smoke.LINEAR, vocab_size=64, hidden_size=64,
+               intermediate_size=48, num_attention_heads=4,
+               num_key_value_heads=4, linear_num_key_heads=2,
+               linear_num_value_heads=2, linear_key_head_dim=8,
+               linear_value_head_dim=16)
+    out = chip_smoke.phase_linear(
+        lin, dict(n_slots=4, max_len=96, page_size=8, prefill_chunk=16,
+                  n_short=3, short_len=8, long_len=40, n_tokens=8),
+        kernels=False, dtype=jnp.float32)
+    assert out["requests"] == 4 and out["tokens"] == 4 * 8
+    assert out["prefill_chunks"] >= 3
+    # float32 against the float32 reference: the served tokens are its own
+    assert max(out["reference_gaps"]) < 1e-4
+    # on the CPU both engines ARE the XLA form of the step
+    assert out["agreement"]["common_prefix_tokens"] == [8] * 4
+    assert out["pool_layout_copies"].keys() == {"decode_step",
+                                                "decode_chunked"}
+    assert out["state_bytes_per_slot"] == 8 * 32 * 4 + 3 * 64 * 4
+    assert out["kv_bytes_per_token"] == 2 * 4 * 16 * 4
+    json.dumps(out)
+
+
+def test_linear_phase_publishes_olmo_hybrids_widths():
+    from perfbench.families import olmo_hybrid as fam
+
+    sz = fam.sizes(chip_smoke.LINEAR)
+    assert (sz["d"], sz["f"], sz["H"], sz["hd"]) == (3840, 11008, 30, 128)
+    assert (sz["lh"], sz["lk"], sz["lv"]) == (30, 96, 192)
+    assert sz["layer_types"] == ("linear_attention", "full_attention")
+
+
 # lines as XLA:TPU prints them (PR 26's parent, layouts and configs
 # kept, operand lists cut): what the count must and must not see
 _CANNED_HLO = """\

@@ -41,3 +41,93 @@ def test_grouped_expert_kernel_compiles_at_the_published_widths(one_chip,
             S((36, 4096, 768)), S((36, 4096, 768)),
             S((36, 768, 4096))).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _shapes(one_chip):
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    return S
+
+
+def test_gated_delta_step_kernel_compiles_at_the_published_widths(one_chip):
+    """Olmo-Hybrid-7B's linear layers: 64 slots, 30 heads of 96 x 192,
+    the state `(64, 96, 5760)` float32 updated in place."""
+    from deeplearning4j_tpu.ops.pallas_delta_step import gdn_step
+
+    S = _shapes(one_chip)
+    f32 = jnp.float32
+    with jax.enable_x64(False):
+        compiled = jax.jit(gdn_step.__wrapped__, donate_argnums=(0,)).lower(
+            S((64, 96, 5760), f32), S((64, 30, 96), f32),
+            S((64, 30, 96), f32), S((64, 30, 192)), S((64, 30), f32),
+            S((64, 30), f32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    stats = compiled.memory_analysis()
+    # the state goes in and comes out through one buffer, unpadded, and
+    # the kernel keeps nothing of its size beside it
+    assert stats.alias_size_in_bytes == 64 * 96 * 5760 * 4
+    assert stats.temp_size_in_bytes < 1 << 20
+
+
+def test_decode_step_compiles_at_the_published_widths(one_chip,
+                                                      monkeypatch):
+    """One block of each kind of Olmo-Hybrid-7B at its published widths
+    (64 slots, 576 pages of 128, 30 K/V heads of 128) through
+    `build_programs`, with the three kernel families of the decode path
+    steered on as they are on the chip (the dispatch asks
+    `jax.default_backend()`, which is the CPU here, and the probes need
+    a chip to run): the gated delta step, the paged write and the paged
+    attention at a head count that is no multiple of 8. No state- or
+    pool-shaped copy is left in the program."""
+    import json
+    from pathlib import Path
+    from types import SimpleNamespace
+
+    import chip_smoke
+    from deeplearning4j_tpu.models.transformer import GPTPlan
+    from deeplearning4j_tpu.ops import (
+        pallas_delta_step,
+        pallas_paged_attention,
+        pallas_paged_kv_write,
+    )
+    from deeplearning4j_tpu.serving import block_state, decode_programs
+    from perfbench.families import olmo_hybrid as fam
+
+    for mod in (pallas_delta_step, pallas_paged_attention,
+                pallas_paged_kv_write):
+        monkeypatch.setattr(mod, "_platform_supported", lambda: True)
+        monkeypatch.setattr(mod, "_probe_verdict", lambda *a, **k: True)
+    cfg = json.loads((Path(__file__).resolve().parents[1]
+                      / "perfbench/configs/olmo-hybrid-7b.json").read_text())
+    cfg.update(num_hidden_layers=2,
+               layer_types=["linear_attention", "full_attention"])
+    sz = fam.sizes(cfg)
+    S = _shapes(one_chip)
+    shapes = fam._leaf_shapes(sz)
+    tree = {n: S(shapes[n]) for n in fam.TOP_LEAVES}
+    tree["layers"] = [{n: S(shapes[n])
+                       for n in fam.MIXER_LEAVES[kind] + fam.FFN_LEAVES}
+                      for kind in sz["layer_types"]]
+    net = fam.build_net(sz, training=False)
+    net._params = fam.to_program(tree)
+    plan = GPTPlan(net)
+    n_slots, page = 64, 128
+    states = block_state.block_states(plan, SimpleNamespace(
+        n_slots=n_slots, page=page, pool_pages=576, cdt=plan.cdt,
+        kv_quant=None, tp_shard=None, tp_axis=None))
+    with jax.enable_x64(False):
+        programs = decode_programs.build_programs(
+            plan, states, n_slots=n_slots, page=page, L_logical=2048,
+            decode_chunk=4, top_k=0, logprobs=0, tp=None, donate=True)
+        caches = [tuple(S(a.shape, a.dtype) for a in jax.eval_shape(st.alloc))
+                  for st in states]
+        i32, f32 = jnp.int32, jnp.float32
+        text = programs.decode_step.lower(
+            net._params, caches, S((n_slots, 2048 // page), i32),
+            S((n_slots,), i32), S((n_slots,), i32),
+            S((n_slots, 2), jnp.uint32), S((n_slots,), f32),
+            S((n_slots,), jnp.bool_)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "gdn_step" in text
+    kept = {"f32[64,96,5760]", "bf16[577,30,128,128]"}
+    assert chip_smoke.pool_layout_copies(text, kept) == 0
