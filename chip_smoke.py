@@ -477,7 +477,13 @@ def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
     out = {"requests": len(prompts), "tokens": int(sum(map(len, toks))),
            "prefill_chunks": stats["prefill_chunks"],
            "decode_steps": stats["decode_steps"],
-           "dispatches": dict(dispatches)}
+           "dispatches": dict(dispatches),
+           # pages the attend kernel walked, of the page table's width
+           "kv_pages": [stats["loop"]["kv_pages_walked"],
+                        stats["loop"]["kv_pages_table"]]}
+    _check(0 < out["kv_pages"][0] <= out["kv_pages"][1],
+           f"decode steps walked {out['kv_pages'][0]} KV pages of "
+           f"{out['kv_pages'][1]} table entries")
     gc.collect()  # free the device state just dropped
 
     # the same prompts down the gather path — the XLA reference the

@@ -11,13 +11,15 @@ index to pool page id. The portable XLA path
 dense transient, then attends: every cache byte moves through HBM twice
 (pool → transient write, transient → compute read) on a path that is
 cache-bandwidth-bound by construction. This kernel is the PagedAttention
-move (Kwon et al., SOSP 2023): the page ids ride the grid as
-scalar-prefetch operands (`pltpu.PrefetchScalarGridSpec`), the BlockSpec
-index map dereferences `page_table[slot, j]` directly, and the pipeline
-DMAs each referenced page from the pool into VMEM exactly once — the
+move (Kwon et al., SOSP 2023): the pools stay in HBM, the page table
+rides in as a scalar-prefetch operand (`pltpu.PrefetchScalarGridSpec`),
+and for each slot the kernel copies pages `page_table[slot, 0 ..
+(pos + C - 1) // page]` — the slot's LIVE pages, however wide the table
+— from the pool into VMEM exactly once, double-buffered, while the
 flash-style online-softmax accumulator (the `ops/pallas_attention.py`
-recurrence) runs over pages in logical order with no intermediate
-materialization.
+recurrence) runs over them in logical order with no intermediate
+materialization. A table entry past a slot's live pages costs nothing:
+no grid step, no fetch.
 
 One kernel serves every paged shape of the serving hot path via the
 chunk width `C` of the query block `(S, C, H, hd)`:
@@ -34,11 +36,11 @@ All three mask identically because the serving paths only ever issue
 CONTIGUOUS query positions: row `c` of slot `s` attends to entries
 `<= positions[s] + c`. GQA contracts the un-repeated `Hkv` pool heads
 against query groups of `G = H // Hkv` heads folded into the matmul's
-sublane axis. The trash-page convention holds for free: unallocated
-page-table entries point at page 0, whose logical positions are always
-past the slot's limit and therefore masked; inactive lanes (optional
-`active` mask) skip the page loop entirely and emit zeros via the
-`l == 0` finalization, the same discipline the flash kernel uses for
+sublane axis. Unallocated page-table entries (page 0, the trash page)
+lie past the slot's live pages and are never read; within the last live
+page, positions past the slot's limit are masked; inactive lanes
+(optional `active` mask) walk no page and emit zeros via the `l == 0`
+finalization, the same discipline the flash kernel uses for
 fully-masked rows.
 
 Dispatch rides the `ops/kernel_dispatch.py` contract: the probe
@@ -75,52 +77,95 @@ FAMILY = "paged_attention"  # this module's row in kernel_verdicts()
 NEG_INF = -1e30  # matches ops/attention.py: exp()/where() stay NaN-free
 
 
-def _paged_kernel(pt_ref, p0_ref, gate_ref, q_ref, k_ref, v_ref, *rest,
+def _paged_kernel(pt_ref, p0_ref, gate_ref, q_ref, k_hbm, v_hbm, *rest,
                   page: int, C: int, G: int, Hkv: int, hd: int,
-                  sm_scale: float, quantized: bool = False):
-    """Grid (S, n_pages), pages sequential: one (C·G, page) score tile
-    per KV head per page, accumulated with the online-softmax
-    recurrence in VMEM scratch. Scalar-prefetch refs: the page table
-    (drives the K/V BlockSpec index maps — the in-place walk), the
-    per-slot start positions, and the active gate.
+                  n_pages: int, sm_scale: float, quantized: bool = False):
+    """Grid (S,), slots in order: slot `s` walks pages
+    `0 .. (p0 + C - 1) // page` of its page-table row and no others — a
+    loop whose trip count comes from the slot's position, not from the
+    table's width — with one (C·G, page) score tile per KV head per
+    page, accumulated with the online-softmax recurrence in VMEM
+    scratch. The pools stay in HBM; each live page is copied once into
+    one of two VMEM buffers while the page before it is computed on,
+    and a slot's last iteration starts the next slot's first copy, so
+    only the call's very first copy is waited for in full. Scalar-
+    prefetch refs: the page table (the page ids the copies dereference),
+    the per-slot start positions, and the active gate. `state` carries
+    across grid steps which buffer the slot's first page lands in and
+    whether the slot before it already started that copy.
 
     `quantized=True` is the int8-KV variant (ROADMAP item 1's
-    "dequant inside the page loop"): `k_ref`/`v_ref` hold int8 pages —
-    HALF the DMA bytes of bf16, the decode path's bandwidth bound on
-    top of PR 9's no-gather win — and two extra (1, Hkv, page) f32
-    scale refs ride the same page-table index map. Dequant happens
-    in VMEM right before each matmul: one f32 multiply per element by
-    the per-(head, position) scale row, then the cast to the MXU feed
+    "dequant inside the page loop"): the pools hold int8 pages —
+    HALF the DMA bytes of bf16 — and each page's two (Hkv, page) f32
+    scale tiles are copied with their payload. Dequant happens in VMEM
+    right before each matmul: one f32 multiply per element by the
+    per-(head, position) scale row, then the cast to the MXU feed
     dtype. Numerics are pinned against the `paged_gather_quant` + dense
     reference by the dispatch probe and the interpret-mode tests."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     if quantized:
-        ks_ref, vs_ref, o_ref, acc_scr, m_scr, l_scr = rest
+        ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf = rest[:7]
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
+                 (vs_hbm, vs_buf))
     else:
-        o_ref, acc_scr, m_scr, l_scr = rest
+        o_ref, k_buf, v_buf = rest[:3]
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+    sem, state, acc_scr, m_scr, l_scr = rest[-5:]
 
     s = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
+    S = pl.num_programs(0)
     CG = C * G
 
-    @pl.when(j == 0)
-    def _init():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+    def live_pages(slot):
+        # pages holding a position some query row can see; an inactive
+        # lane walks none: its l stays 0 and the finalize emits exact
+        # zeros (the flash kernel's fully-masked-row discipline)
+        n = jnp.minimum((p0_ref[slot] + C - 1) // page + 1, n_pages)
+        return jnp.where(gate_ref[slot] != 0, n, 0)
+
+    def copies(slot, j, buf):
+        pid = pt_ref[slot, j]
+        return [pltpu.make_async_copy(pool.at[pid], dst.at[buf],
+                                      sem.at[i, buf])
+                for i, (pool, dst) in enumerate(pools)]
+
+    @pl.when(s == 0)
+    def _first_slot():
+        state[0] = 0   # the buffer this slot's first page lands in
+        state[1] = 0   # 1: the slot before already started that copy
+
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
 
     p0 = p0_ref[s]
-    # skip pages whose every position is past the last query's limit
-    # (p0 + C - 1) and skip inactive lanes outright: their l stays 0 and
-    # the finalize emits exact zeros (the flash kernel's fully-masked-row
-    # discipline). The DMA for skipped steps still lands (plain indexing
-    # + compute skip measured faster than index-map clamping for the
-    # flash kernel; the same trade holds here) — correctness never
-    # depends on it because masking is positional.
-    @pl.when((j * page <= p0 + C - 1) & (gate_ref[s] != 0))
-    def _step():
+    n_live = live_pages(s)
+    nxt = jnp.minimum(s + 1, S - 1)
+    n_next = jnp.where(s + 1 < S, live_pages(nxt), 0)
+    buf0 = state[0]
+
+    @pl.when((n_live > 0) & (state[1] == 0))
+    def _start_cold():
+        for c in copies(s, 0, buf0):
+            c.start()
+
+    def _page(j, carry):
+        buf = (buf0 + j) % 2
+
+        @pl.when(j + 1 < n_live)
+        def _fetch_next_page():
+            for c in copies(s, j + 1, 1 - buf):
+                c.start()
+
+        @pl.when((j + 1 == n_live) & (n_next > 0))
+        def _fetch_next_slot():
+            for c in copies(nxt, 0, 1 - buf):
+                c.start()
+
+        for c in copies(s, j, buf):
+            c.wait()
         dt = _mxu_dtype(q_ref.dtype)
         q = q_ref[0]                                       # (C, H, hd)
         kpos = j * page + jax.lax.broadcasted_iota(
@@ -135,13 +180,13 @@ def _paged_kernel(pt_ref, p0_ref, gate_ref, q_ref, k_ref, v_ref, *rest,
                 # dequant-in-VMEM: int8 page × per-position f32 scale
                 # row, then the MXU-feed cast — the DMA moved 1 byte
                 # per element, the matmul sees full-precision values
-                ks = ks_ref[0, h].reshape(1, page)
-                kh = (k_ref[0, h].astype(jnp.float32) * ks).astype(dt)
-                vs = vs_ref[0, h].reshape(page, 1)
-                vh = (v_ref[0, h].astype(jnp.float32) * vs).astype(dt)
+                ks = ks_buf[buf, h].reshape(1, page)
+                kh = (k_buf[buf, h].astype(jnp.float32) * ks).astype(dt)
+                vs = vs_buf[buf, h].reshape(page, 1)
+                vh = (v_buf[buf, h].astype(jnp.float32) * vs).astype(dt)
             else:
-                kh = k_ref[0, h].astype(dt)                # (hd, page)
-                vh = v_ref[0, h].astype(dt)                # (page, hd)
+                kh = k_buf[buf, h].astype(dt)              # (hd, page)
+                vh = v_buf[buf, h].astype(dt)              # (page, hd)
             sc = _dot(qh, kh, ((1,), (0,)), dt) * sm_scale
             sc = jnp.where(mask, sc, NEG_INF)
             m_prev = m_scr[h][:, :1]
@@ -158,17 +203,27 @@ def _paged_kernel(pt_ref, p0_ref, gate_ref, q_ref, k_ref, v_ref, *rest,
                                                   ((1,), (0,)), dt)
             m_scr[h] = jnp.broadcast_to(m_new, m_scr[h].shape)
             l_scr[h] = jnp.broadcast_to(l_new, l_scr[h].shape)
+        return carry
 
-    @pl.when(j == nj - 1)
-    def _finalize():
-        for h in range(Hkv):
-            l = l_scr[h][:, :1]
-            o = jnp.where(l > 0, acc_scr[h] / jnp.where(l > 0, l, 1.0),
-                          0.0)
-            o_ref[0, :, h * G:(h + 1) * G, :] = \
-                o.reshape(C, G, hd).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_live, _page, 0)
+
+    @pl.when(n_live > 0)
+    def _hand_over():
+        state[0] = (buf0 + n_live) % 2
+        state[1] = (n_next > 0).astype(jnp.int32)
+
+    for h in range(Hkv):
+        l = l_scr[h][:, :1]
+        o = jnp.where(l > 0, acc_scr[h] / jnp.where(l > 0, l, 1.0), 0.0)
+        o_ref[0, :, h * G:(h + 1) * G, :] = \
+            o.reshape(C, G, hd).astype(o_ref.dtype)
 
 
+# jitted, like the family's other serving kernels, so that a program
+# which attends in 24 layers traces and lowers the kernel once and calls
+# it 24 times: lowering is paid on every start-up, compile cache or not
+# (PERF.md, PR 26 and PR 33)
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                     v_pool: jnp.ndarray, page_table: jnp.ndarray,
                     positions: jnp.ndarray, *,
@@ -181,20 +236,22 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     `q`: (S, C, H, hd) — C contiguous query tokens per slot (C=1 for
     the decode step). `k_pool`/`v_pool`: (P+1, Hkv, hd, page) /
     (P+1, Hkv, page, hd) — the resident pool layouts, page 0 = trash.
-    `page_table`: (S, n_pages) int32 pool page ids in logical order
-    (unallocated entries 0). `positions`: (S,) int32 — row c of slot s
-    attends to cache entries `<= positions[s] + c`, exactly
-    `cached_attention_step` (C=1, positions=pos) and
-    `cached_attention_chunk` (positions=first query position) over the
-    gathered view. `active`: optional (S,) bool — False lanes skip all
-    compute and emit zeros (their output is discarded downstream by the
-    engine's masking; the gather path computes garbage-but-finite
-    values for them instead, equally discarded).
+    `page_table`: (S, n_pages) int32 pool page ids in logical order.
+    `positions`: (S,) int32 — row c of slot s attends to cache entries
+    `<= positions[s] + c`, exactly `cached_attention_step` (C=1,
+    positions=pos) and `cached_attention_chunk` (positions=first query
+    position) over the gathered view. Only the table's entries
+    `0 .. (positions[s] + C - 1) // page` are read: what lies past a
+    slot's live pages (unallocated entries, 0 by the engine's
+    convention) is never dereferenced. `active`: optional (S,) bool —
+    False lanes read no page and emit zeros (their output is discarded
+    downstream by the engine's masking; the gather path computes
+    garbage-but-finite values for them instead, equally discarded).
 
-    int8 pools pass `k_scale`/`v_scale` ((P+1, Hkv, page) f32): the
-    scale pages ride the SAME page-table index map as the payload
-    pages and the kernel dequantizes in VMEM inside the page loop —
-    the `serving/quantize.py` tier's fast path.
+    int8 pools pass `k_scale`/`v_scale` ((P+1, Hkv, page) f32): each
+    page's scale tiles are copied with its payload and the kernel
+    dequantizes in VMEM inside the page loop — the
+    `serving/quantize.py` tier's fast path.
 
     Returns (S, C, H, hd) in q.dtype.
     """
@@ -211,37 +268,25 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         else jnp.asarray(active).astype(jnp.int32)
     kernel = functools.partial(
         _paged_kernel, page=page, C=C, G=G, Hkv=Hkv, hd=hd,
-        sm_scale=1.0 / float(hd) ** 0.5, quantized=quantized)
-    in_specs = [
-        pl.BlockSpec((1, C, H, hd),
-                     lambda s, j, pt, p0, g: (s, 0, 0, 0)),
-        # THE page-table walk: the block index map dereferences the
-        # prefetched table, so the pipeline DMAs pool page
-        # `page_table[s, j]` straight into VMEM — no dense transient
-        pl.BlockSpec((1, Hkv, hd, page),
-                     lambda s, j, pt, p0, g: (pt[s, j], 0, 0, 0)),
-        pl.BlockSpec((1, Hkv, page, hd),
-                     lambda s, j, pt, p0, g: (pt[s, j], 0, 0, 0)),
-    ]
-    operands = [page_table.astype(jnp.int32),
-                positions.astype(jnp.int32), gate, q, k_pool, v_pool]
-    if quantized:
-        # the scale pages walk the same table: one (Hkv, page) f32 tile
-        # per referenced page, prefetched alongside its int8 payload
-        in_specs += [
-            pl.BlockSpec((1, Hkv, page),
-                         lambda s, j, pt, p0, g: (pt[s, j], 0, 0)),
-            pl.BlockSpec((1, Hkv, page),
-                         lambda s, j, pt, p0, g: (pt[s, j], 0, 0)),
-        ]
-        operands += [k_scale, v_scale]
+        n_pages=n_pages, sm_scale=1.0 / float(hd) ** 0.5,
+        quantized=quantized)
+
+    def slot(s, pt, p0, g):
+        return (s, 0, 0, 0)
+
+    # the pools stay where they are: the kernel copies the pages it
+    # walks, and nothing else of them moves
+    pools = [k_pool, v_pool] + ([k_scale, v_scale] if quantized else [])
+    buffers = [pltpu.VMEM((2,) + p.shape[1:], p.dtype) for p in pools]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S, n_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, C, H, hd),
-                               lambda s, j, pt, p0, g: (s, 0, 0, 0)),
-        scratch_shapes=[
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, C, H, hd), slot)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=pl.BlockSpec((1, C, H, hd), slot),
+        scratch_shapes=buffers + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.SMEM((2,), jnp.int32),         # buffer, copy started
             pltpu.VMEM((Hkv, C * G, hd), sdt),   # unnormalised output
             pltpu.VMEM((Hkv, C * G, 128), sdt),  # running max m
             pltpu.VMEM((Hkv, C * G, 128), sdt),  # running denom l
@@ -252,10 +297,13 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, C, H, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # in order: a slot's last iteration starts the next slot's
+            # first copy
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
-    )(*operands)
+    )(page_table.astype(jnp.int32), positions.astype(jnp.int32), gate, q,
+      *pools)
 
 
 def vmem_bytes_estimate(C: int, H: int, Hkv: int, hd: int, page: int,
@@ -314,34 +362,49 @@ def _eager_probe(dtype, C: int, H: int, Hkv: int, hd: int, page: int,
         paged_gather_quant,
     )
 
-    S, n_pages = 2, 2
-    P = S * n_pages
+    # a table wider than any slot's live pages, as the engine's is: one
+    # slot ends on a page's last position, one starts on a page's
+    # first. The entries past a slot's live pages name a page of NaNs,
+    # so a read of a page no query can see fails the comparison; the
+    # reference gathers the trash page there
+    S = 2
+    p0 = np.asarray([max(page - C, 0), page], np.int32)
+    live = (p0 + C - 1) // page + 1
+    n_pages = int(live.max()) + 2
+    P = int(live.sum())
+    dead = P + 1
+    pt = np.full((S, n_pages), dead, np.int32)
+    pt[0, :live[0]] = 1 + np.arange(live[0])
+    pt[1, :live[1]] = 1 + live[0] + np.arange(live[1])
+    pt_ref = jnp.asarray(np.where(pt == dead, 0, pt))
+    pt, p0 = jnp.asarray(pt), jnp.asarray(p0)
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((S, C, H, hd)), dtype)
-    pt = jnp.asarray(1 + np.arange(P).reshape(S, n_pages), jnp.int32)
-    p0 = jnp.asarray([page - 1, 2 * page - 1], jnp.int32)
     qpos = p0[:, None] + jnp.arange(C)[None, :]
     if quantized:
         k_pool = jnp.asarray(rng.integers(
-            -127, 128, (P + 1, Hkv, hd, page)), jnp.int8)
+            -127, 128, (P + 2, Hkv, hd, page)), jnp.int8)
         v_pool = jnp.asarray(rng.integers(
-            -127, 128, (P + 1, Hkv, page, hd)), jnp.int8)
+            -127, 128, (P + 2, Hkv, page, hd)), jnp.int8)
         k_scale = jnp.asarray(
-            rng.uniform(0.005, 0.02, (P + 1, Hkv, page)), jnp.float32)
+            rng.uniform(0.005, 0.02, (P + 2, Hkv, page)), jnp.float32)
         v_scale = jnp.asarray(
-            rng.uniform(0.005, 0.02, (P + 1, Hkv, page)), jnp.float32)
+            rng.uniform(0.005, 0.02, (P + 2, Hkv, page)), jnp.float32)
         out = np.asarray(paged_attention(
-            q, k_pool, v_pool, pt, p0, k_scale=k_scale,
-            v_scale=v_scale))
+            q, k_pool, v_pool, pt, p0,
+            k_scale=k_scale.at[dead].set(jnp.nan),
+            v_scale=v_scale.at[dead].set(jnp.nan)))
         kd, vd = paged_gather_quant(k_pool, v_pool, k_scale, v_scale,
-                                    pt, dtype)
+                                    pt_ref, dtype)
     else:
         k_pool = jnp.asarray(
-            rng.standard_normal((P + 1, Hkv, hd, page)), dtype)
+            rng.standard_normal((P + 2, Hkv, hd, page)), dtype)
         v_pool = jnp.asarray(
-            rng.standard_normal((P + 1, Hkv, page, hd)), dtype)
-        out = np.asarray(paged_attention(q, k_pool, v_pool, pt, p0))
-        kd, vd = paged_gather(k_pool, v_pool, pt)
+            rng.standard_normal((P + 2, Hkv, page, hd)), dtype)
+        out = np.asarray(paged_attention(
+            q, k_pool.at[dead].set(jnp.nan), v_pool.at[dead].set(jnp.nan),
+            pt, p0))
+        kd, vd = paged_gather(k_pool, v_pool, pt_ref)
     ref = np.asarray(jax.vmap(cached_attention_chunk)(q, kd, vd, qpos))
     ref = ref.reshape(S, C, H, hd).astype(np.float32)
     out = out.astype(np.float32)
@@ -389,6 +452,8 @@ def paged_attention_or_none(q, k_pool, v_pool, page_table, positions,
     if not _probe_verdict(FAMILY, key, _eager_probe,
                           (q.dtype, C, H, Hkv, hd, page, quantized)):
         return None
+    if active is None:  # one traced function a shape class
+        active = jnp.ones((S,), jnp.bool_)
     try:
         return paged_attention(q, k_pool, v_pool, page_table, positions,
                                k_scale=k_scale, v_scale=v_scale,

@@ -14,8 +14,7 @@ device time). This kernel takes the layout choice away from the
 compiler: the pools are `input_output_aliases` operands in the same
 row-major layout the attention kernel reads, the grid runs over slots,
 `pids`/`loff` ride as scalar-prefetch operands and the BlockSpec index
-maps dereference them (the `pallas_paged_attention` page walk, pointed
-at one tile), and each grid step reads the tile that holds the
+maps dereference them, and each grid step reads the tile that holds the
 position, replaces one lane (K, scales) or one row (V) and writes it
 back. K moves a whole `(Hkv, hd, page)` page tile per slot — a lane
 cannot be addressed below the 128-wide tile — V only the sublane tile

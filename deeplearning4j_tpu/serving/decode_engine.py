@@ -3162,8 +3162,16 @@ class DecodeEngine:
         oks_d = rest.pop(0)
         lps_d = rest.pop(0) if self._logprobs_k else None
         counts_d = rest.pop(0) if self._n_held else None
+        page, width = self.page_size, self._n_pages_max
         for _, r in live:
+            # the slot's position at the dispatch's first step, from
+            # what the host holds: the pages `kv.attend` walks there
+            pos = r.prompt.shape[0] - r.resumed_at + len(r.tokens) \
+                + r.in_flight - 1
+            ph.kv_pages_walked += sum(min((pos + j) // page + 1, width)
+                                      for j in range(n_steps))
             r.in_flight += n_steps
+        ph.kv_pages_table += len(live) * n_steps * width
         ph.ahead_n += bool(self._inflight)
         self._inflight.append(_InFlight(
             program, live, (toks_d, oks_d, lps_d, counts_d), t0, info,

@@ -840,6 +840,11 @@ class ThreadPhases:
         self.ahead_n = 0
         self.drained_n = 0
         self.overshoot_tokens = 0
+        # the page walk of `kv.attend`, reckoned on the host at each
+        # decode dispatch: live pages of the dispatched slots over its
+        # steps, and those slots x steps x the page table's width
+        self.kv_pages_walked = 0
+        self.kv_pages_table = 0
         self._on = tracing_enabled()
         self._tid = None
         # (name, t0, cause, attrs) of the phase the thread is in
@@ -896,7 +901,8 @@ class ThreadPhases:
     def counters(self) -> dict:
         """``{"iterations", "<phase>_s", "<phase>_n", "sink_s",
         "sink_n", "ahead_n", "drained_n", "overshoot_tokens",
-        "spans_dropped"}``: cumulative, so the difference of two
+        "kv_pages_walked", "kv_pages_table", "spans_dropped"}``:
+        cumulative, so the difference of two
         readings is a window's account. The phase still open counts
         with the seconds it has lasted so far, so the `_s` of all
         phases add up to the time since the first `enter`."""
@@ -912,6 +918,8 @@ class ThreadPhases:
         out["ahead_n"] = self.ahead_n
         out["drained_n"] = self.drained_n
         out["overshoot_tokens"] = self.overshoot_tokens
+        out["kv_pages_walked"] = self.kv_pages_walked
+        out["kv_pages_table"] = self.kv_pages_table
         out["spans_dropped"] = self._timeline.dropped
         return out
 

@@ -46,6 +46,8 @@ def test_serve_phase_toy():
     assert out["requests"] == 4 and out["tokens"] == 4 * 8
     assert out["prefill_chunks"] >= 3  # the 40-token prompt, 16 at a time
     assert out["dispatches"]["decode_chunk"] >= 1
+    walked, table = out["kv_pages"]
+    assert 0 < walked < table
     # on the CPU both engines ARE the gather path: tokens identical
     assert out["agreement"]["common_prefix_tokens"] == [8] * 4
     assert out["agreement"]["tie_margins_nats"] == []

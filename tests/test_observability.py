@@ -935,6 +935,7 @@ def test_loop_and_front_keys_in_contract_and_exposition(net):
         loop = eng.metrics_snapshot()["components"]["decode_engine"]["loop"]
         assert set(loop) == {"iterations", "sink_s", "sink_n", "ahead_n",
                              "drained_n", "overshoot_tokens",
+                             "kv_pages_walked", "kv_pages_table",
                              "spans_dropped"} \
             | {p + sfx for p in obs.LEAF_PHASES for sfx in ("_s", "_n")}
         text = eng.metrics_text()
@@ -945,3 +946,7 @@ def test_loop_and_front_keys_in_contract_and_exposition(net):
     assert "dl4j_stats_decode_engine_loop_iterations " in text
     assert "dl4j_stats_decode_engine_loop_decode_deliver_s " in text
     assert "dl4j_stats_decode_engine_loop_wait_work_n " in text
+    # 3 tokens: the prefill's, then two decode steps at positions 5 and
+    # 6 of a 32-position table whose page is the whole of it
+    assert "dl4j_stats_decode_engine_loop_kv_pages_walked 2" in text
+    assert "dl4j_stats_decode_engine_loop_kv_pages_table 2" in text

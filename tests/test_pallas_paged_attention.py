@@ -212,6 +212,144 @@ def test_kernel_chunk_width_matches_prefill_chunk_semantics():
     np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
 
 
+def _walk_case(rng, p0, active, C, H, Hkv, hd, page, n_pages, quantized):
+    """Pools, a table WIDER than any slot's live pages whose entries
+    past them name a page of NaNs, and the gather reference (which
+    reads the trash page there, as the engine's table has it)."""
+    S = len(p0)
+    live = np.minimum((p0 + C - 1) // page + 1, n_pages)
+    P = int(live.sum())
+    dead = P + 1
+    pt = np.full((S, n_pages), dead, np.int32)
+    ids = iter(rng.permutation(np.arange(1, P + 1)))
+    for s in range(S):
+        pt[s, :live[s]] = [next(ids) for _ in range(live[s])]
+    pt_ref = np.where(pt == dead, 0, pt)
+    q = rng.standard_normal((S, C, H, hd)).astype(np.float32)
+    if quantized:
+        k_pool, v_pool, ks, vs = _rand_quant_pools(rng, P + 1, Hkv, hd,
+                                                   page)
+        ref = _gather_quant_chunk_ref(q, k_pool, v_pool, ks, vs, pt_ref,
+                                      p0)
+        ks[dead] = vs[dead] = np.nan
+        scales = dict(k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs))
+    else:
+        k_pool, v_pool = _rand_pools(rng, P + 1, Hkv, hd, page)
+        ref = _gather_chunk_ref(q, k_pool, v_pool, pt_ref, p0)
+        k_pool[dead] = v_pool[dead] = np.nan
+        scales = {}
+    got = np.asarray(paged_attention(
+        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+        jnp.asarray(pt), jnp.asarray(p0), active=jnp.asarray(active),
+        interpret=True, **scales))
+    return got, ref, live
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["dense", "int8"])
+@pytest.mark.parametrize("H,Hkv", [(2, 2), (8, 2)], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("C", [1, 3])
+def test_walk_reads_only_the_live_pages_of_a_wider_table(C, H, Hkv,
+                                                         quantized):
+    """One call over slots with 1, 2 and all pages live, first
+    positions at `page - 1`, `page` and 0, and an inactive lane, under
+    a table six pages wide: equal to the gather reference, exact zeros
+    on the inactive lane, and no NaN — every entry past a slot's live
+    pages names a page of NaNs, so one read of a dead page shows."""
+    rng = np.random.default_rng(1000 + 100 * H + 10 * C + quantized)
+    hd, page, n_pages = 8, 4, 6
+    p0 = np.array([page - 1, page, 0, n_pages * page - C, 2 * page],
+                  np.int32)
+    active = np.array([True, True, True, True, False])
+    got, ref, live = _walk_case(rng, p0, active, C, H, Hkv, hd, page,
+                                n_pages, quantized)
+    assert {1, 2, n_pages} <= set(live.tolist())
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got[:4], ref[:4], rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(got[4], np.zeros_like(got[4]))
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["dense", "int8"])
+def test_walk_serves_a_page_crossing_prefill_chunk(quantized):
+    """The chunked-prefill shape: one slot, 256 query rows from the
+    middle of the first 128-position page to the middle of its third,
+    under a table six pages wide: three pages read, not six."""
+    rng = np.random.default_rng(41 + quantized)
+    C, H, Hkv, hd, page, n_pages = 256, 4, 1, 8, 128, 6
+    got, ref, live = _walk_case(
+        rng, np.array([64], np.int32), np.array([True]), C, H, Hkv, hd,
+        page, n_pages, quantized)
+    assert live.tolist() == [3]
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+
+
+def test_walk_stops_at_the_table_width():
+    """A position at or past the table's last page (the engine clamps
+    its writes there) walks every page and no further."""
+    rng = np.random.default_rng(43)
+    page, n_pages = 4, 3
+    p0 = np.array([n_pages * page - 1, n_pages * page + 5], np.int32)
+    got, ref, live = _walk_case(rng, p0, np.array([True, True]), 1, 2, 2,
+                                8, page, n_pages, False)
+    assert live.tolist() == [n_pages, n_pages]
+    np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+
+
+def test_probe_checks_the_walk_under_a_wider_table(monkeypatch):
+    """The probe as the chip runs it (interpreted here): passes on the
+    real kernel at the decode, verify and page-crossing chunk widths,
+    dense and int8, and declines a kernel that reads the table's whole
+    width (the dead entries name a page of NaNs)."""
+    import functools
+
+    import deeplearning4j_tpu.ops.pallas_paged_attention as pk
+
+    real = pk.paged_attention
+    monkeypatch.setattr(pk, "paged_attention",
+                        functools.partial(real, interpret=True))
+    f32 = jnp.dtype(jnp.float32)
+    assert pk._eager_probe(f32, 1, 4, 2, 8, 4)
+    assert pk._eager_probe(f32, 3, 2, 2, 8, 4)
+    assert pk._eager_probe(f32, 6, 2, 2, 8, 4)   # wider than a page
+    assert pk._eager_probe(f32, 1, 2, 2, 8, 4, True)
+
+    def whole_table(q, k_pool, v_pool, pt, p0, **kw):
+        kd, vd = paged_gather(k_pool, v_pool, pt)
+        qpos = p0[:, None] + jnp.arange(q.shape[1])[None, :]
+        return jax.vmap(cached_attention_chunk)(q, kd, vd, qpos) \
+            .reshape(q.shape)
+
+    monkeypatch.setattr(pk, "paged_attention", whole_table)
+    assert pk._eager_probe(f32, 1, 2, 2, 8, 4) is False
+
+
+def test_dispatch_hands_the_kernel_one_signature_a_class(monkeypatch):
+    """`active=None` reaches the jitted entry as a mask of ones, so a
+    shape class is one traced function whether or not the caller
+    gates."""
+    import deeplearning4j_tpu.ops.pallas_paged_attention as pk
+
+    seen = []
+
+    def fake(q, k_pool, v_pool, pt, p0, **kw):
+        seen.append(kw)
+        return q
+
+    monkeypatch.setattr(pk, "_platform_supported", lambda: True)
+    monkeypatch.setattr(pk, "_probe_verdict", lambda *a, **k: True)
+    monkeypatch.setattr(pk, "_vmem_limit", lambda: 1 << 30)
+    monkeypatch.setattr(pk, "paged_attention", fake)
+    rng = np.random.default_rng(47)
+    k_pool, v_pool = _rand_pools(rng, 2, 2, 8, 4)
+    q = jnp.zeros((2, 1, 2, 8), jnp.float32)
+    pt = jnp.zeros((2, 2), jnp.int32)
+    pk.paged_attention_or_none(q, jnp.asarray(k_pool), jnp.asarray(v_pool),
+                               pt, jnp.zeros((2,), jnp.int32))
+    (kw,) = seen
+    assert kw["active"].dtype == jnp.bool_ and bool(kw["active"].all())
+    assert kw["k_scale"] is None and kw["v_scale"] is None
+
+
 def test_dispatch_declines_on_cpu_and_auto_is_bitwise_gather():
     """Tier-1 contract: on the CPU backend `paged_attention_or_none`
     returns None (never a compiled Pallas-TPU path), and the `*_auto`
@@ -412,3 +550,54 @@ def test_int8_kill_switch_gates_dispatch_before_probing(monkeypatch):
         jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
         jnp.asarray(pt), jnp.asarray([1, 3], np.int32),
         k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs)) is None
+
+
+@pytest.mark.parametrize("quantize", [None, {"kv": "int8"}],
+                         ids=["dense", "int8"])
+def test_engine_tokens_equal_gather_build(monkeypatch, quantize):
+    """A `DecodeEngine` whose `kv.attend` rides the (interpreted) kernel
+    emits the gather build's tokens: single steps and fused chunks, a
+    prompt that rides the chunked prefill (C = prefill_chunk over a
+    page edge), slot and page reuse, inactive lanes, under a page table
+    eight wide of which a slot holds two to four pages."""
+    import deeplearning4j_tpu.ops.pallas_paged_attention as pk
+    from deeplearning4j_tpu.models.transformer import gpt_configuration
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+    from deeplearning4j_tpu.serving import DecodeEngine
+
+    net = MultiLayerNetwork(gpt_configuration(
+        seed=7, vocab_size=48, d_model=32, n_heads=2, n_layers=2,
+        max_length=64))
+    net.init()
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 48, (n,)).astype(np.int32)
+               for n in (6, 6, 21, 6, 6)]
+    n_tokens = [9, 4, 12, 7, 5]
+
+    def tokens():
+        eng = DecodeEngine(net, n_slots=2, max_len=64, page_size=8,
+                           prompt_buckets=(8,), prefill_chunk=8,
+                           decode_chunk=4, quantize=quantize)
+        try:
+            reqs = [eng.submit(p, n) for p, n in zip(prompts, n_tokens)]
+            return [np.asarray(r.result(timeout=300.0)) for r in reqs]
+        finally:
+            eng.shutdown(drain_timeout=10.0)
+
+    want = tokens()
+    widths = set()
+    real = pk.paged_attention
+
+    def interpreted(q, *a, **kw):
+        widths.add((q.shape[1], a[0].dtype, a[2].shape[1]))
+        return real(q, *a, interpret=True, **kw)
+
+    monkeypatch.setattr(pk, "_platform_supported", lambda: True)
+    monkeypatch.setattr(pk, "_probe_verdict", lambda *a: True)
+    monkeypatch.setattr(pk, "paged_attention", interpreted)
+    got = tokens()
+    pool = jnp.dtype(jnp.int8 if quantize else jnp.float32)
+    assert widths == {(1, pool, 8), (8, pool, 8)}
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+

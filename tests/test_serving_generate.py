@@ -550,6 +550,36 @@ def test_next_dispatch_is_issued_before_the_last_is_collected(net):
     assert loop["overshoot_tokens"] == 0 and loop["drained_n"] == 0
 
 
+@pytest.mark.parametrize("decode_chunk", [1, 4])
+def test_page_walk_counters_equal_what_the_lengths_imply(net,
+                                                         decode_chunk):
+    """`kv_pages_walked` / `kv_pages_table` are reckoned on the host at
+    each decode issue, with dispatches still in flight: after a run
+    without EOS they equal the live pages of every decode step of every
+    request (positions `t0 .. t0 + n - 2`: the first token is the
+    prefill's) and those steps x the table's width, single steps or
+    fused chunks alike."""
+    page, max_len = 4, 32
+    shapes = [(5, 12), (3, 9), (7, 14)]           # (prompt length, n)
+    eng = _engine(net, n_slots=2, page_size=page, max_len=max_len,
+                  decode_chunk=decode_chunk)
+    try:
+        reqs = [eng.submit(_prompts(1, t0, seed=53 + i)[0], n)
+                for i, (t0, n) in enumerate(shapes)]
+        for r, (_, n) in zip(reqs, shapes):
+            assert r.result(timeout=120.0).shape == (n,)
+        _await(lambda: eng.stats()["active_slots"] == 0, "the last collect")
+        loop = _ahead(eng)
+    finally:
+        eng.shutdown()
+    assert loop["overshoot_tokens"] == 0
+    steps = sum(n - 1 for _, n in shapes)
+    assert loop["kv_pages_table"] == steps * (max_len // page)
+    assert loop["kv_pages_walked"] == sum(
+        (t0 + k) // page + 1 for t0, n in shapes for k in range(n - 1))
+    assert loop["kv_pages_walked"] < loop["kv_pages_table"]
+
+
 def test_a_poisoned_step_fails_one_request_one_dispatch_late(net):
     """A non-finite step is seen at its collect, when the next dispatch
     already has the slot active: the request fails typed with the tokens

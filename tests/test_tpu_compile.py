@@ -69,6 +69,93 @@ def test_gated_delta_step_kernel_compiles_at_the_published_widths(one_chip):
     assert stats.temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize("slots,H,Hkv,pool", [
+    (32, 16, 16, 136), (64, 30, 30, 576), (64, 32, 8, 576)],
+    ids=("cgpt1.3b-16-heads", "olmo-30-heads", "granite-8-of-32"))
+def test_paged_attention_kernel_compiles_at_the_published_widths(
+        one_chip, slots, H, Hkv, pool):
+    """The page walk at the three serve cells' head counts, head size
+    128, pages of 128, under a page table 16 wide: pools left in HBM,
+    two page buffers a pool in VMEM, a loop of dynamic length."""
+    from deeplearning4j_tpu.ops.pallas_paged_attention import (
+        paged_attention,
+    )
+
+    S = _shapes(one_chip)
+    i32 = jnp.int32
+    with jax.enable_x64(False):
+        compiled = paged_attention.lower(
+            S((slots, 1, H, 128)), S((pool + 1, Hkv, 128, 128)),
+            S((pool + 1, Hkv, 128, 128)), S((slots, 16), i32),
+            S((slots,), i32), active=S((slots,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the pools are read where they lie: nothing of their size is made
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_decode_step_lowers_the_attend_kernel_once(one_chip, monkeypatch):
+    """A toy of four attention blocks (head size and page 128, so that
+    Mosaic lowers it) through `build_programs`: the lowered text of
+    `decode_step` and of `decode_chunked` holds the attend kernel's
+    `tpu_custom_call` in ONE private function, called once a block.
+    Lowering is paid on every start, compile cache or not, and a kernel
+    traced inline is lowered once a layer (PERF.md, PR 26 and PR 33)."""
+    import re
+    from types import SimpleNamespace
+
+    from deeplearning4j_tpu.models.transformer import GPTPlan
+    from deeplearning4j_tpu.ops import (
+        pallas_paged_attention,
+        pallas_paged_kv_write,
+    )
+    from deeplearning4j_tpu.serving import block_state, decode_programs
+    from perfbench.families import gpt_dense as fam
+
+    for mod in (pallas_paged_attention, pallas_paged_kv_write):
+        monkeypatch.setattr(mod, "_platform_supported", lambda: True)
+        monkeypatch.setattr(mod, "_probe_verdict", lambda *a, **k: True)
+    sz = fam.sizes({"n_embd": 256, "n_head": 2, "n_inner": 1024,
+                    "n_layer": 4, "vocab_size": 512, "n_positions": 2048,
+                    "layer_norm_epsilon": 1e-5})
+    S = _shapes(one_chip)
+    f32, i32 = jnp.float32, jnp.int32
+    shapes = fam._leaf_shapes(sz)
+    tree = {n: S(shapes[n], f32) for n in fam.TOP_LEAVES}
+    tree["blocks"] = [{n: S(shapes[n], f32) for n in fam.BLOCK_LEAVES}
+                      for _ in range(sz["L"])]
+    net = fam.build_net(sz, training=False)
+    net._params = fam.to_program(tree)
+    plan = GPTPlan(net)
+    n_slots, page = 8, 128
+    states = block_state.block_states(plan, SimpleNamespace(
+        n_slots=n_slots, page=page, pool_pages=32, cdt=plan.cdt,
+        kv_quant=None, tp_shard=None, tp_axis=None))
+    with jax.enable_x64(False):
+        weights = jax.tree_util.tree_map(
+            lambda a: S(a.shape, a.dtype),
+            jax.eval_shape(plan.resident_weights, net._params))
+        programs = decode_programs.build_programs(
+            plan, states, n_slots=n_slots, page=page, L_logical=2048,
+            decode_chunk=4, top_k=0, logprobs=0, tp=None, donate=True)
+        caches = [tuple(S(a.shape, a.dtype) for a in jax.eval_shape(st.alloc))
+                  for st in states]
+        args = (weights, caches, S((n_slots, 2048 // page), i32),
+                S((n_slots,), i32), S((n_slots,), i32),
+                S((n_slots, 2), jnp.uint32), S((n_slots,), f32),
+                S((n_slots,), jnp.bool_))
+        texts = [getattr(programs, name).lower(*args).as_text()
+                 for name in ("decode_step", "decode_chunked")]
+    for text in texts:
+        # the write and the attend: one body each, whatever the depth
+        assert text.count("tpu_custom_call") == 2
+        bodies = re.findall(
+            r"func\.func private @(\w+)\([^\n]*\n(?:(?!func\.func).)*?"
+            r"tpu_custom_call", text, re.S)
+        assert sorted(bodies) == ["paged_attention", "paged_kv_write"]
+        assert len(re.findall(r"call @paged_attention\(", text)) == 4
+        assert len(re.findall(r"call @paged_kv_write\(", text)) == 4
+
+
 def test_decode_step_compiles_at_the_published_widths(one_chip,
                                                       monkeypatch):
     """One block of each kind of Olmo-Hybrid-7B at its published widths
