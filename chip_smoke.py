@@ -33,6 +33,18 @@ repo supports — `gpt_configuration(vocab 256, d_model 1024, 8 heads of
   engine on the XLA form of the step; the `gdn_step`, paged-attention
   and KV-write kernels engaged at its shape classes (30 K/V heads); no
   state- or pool-shaped copy in the decode programs.
+- **sublayer**: three blocks of ONE sub-layer each at
+  NVIDIA-Nemotron-3-Nano-30B-A3B's published widths (a Mamba-2 mixer of
+  64 heads in 8 B/C groups, a 32-over-2-head attention with heads of 128
+  on a hidden size of 2688, and 64 of 128 sigmoid-routed top-6 ungated
+  relu^2 experts 1856 wide plus the shared one) through `DecodeEngine`,
+  on the benchmark family's seeded weights: recurrent state, paged K/V
+  and a block that keeps nothing side by side; served tokens against the
+  family's plain float32 reference and against an engine on the XLA
+  expert products; about half the router's choices on the experts held;
+  the ungated grouped-expert kernel (a width off the 128-lane grid),
+  paged attention and the KV write at 2 K/V heads engaged; no state- or
+  pool-shaped copy in the decode programs.
 - **lstm**: `lstm_large` (H=1024, T=64, B=2048) with the fused cell.
 - **multichip** (>= 4 devices): the train step through `ParallelWrapper`
   on a {data 2, model 2} mesh against the one-chip loss, tp=4 decode
@@ -50,7 +62,7 @@ with exactly those keys. A failed phase prints the same line with
 `"ok": false` and re-raises.
 
 `python3 chip_smoke.py train lstm` runs only the named phases
-(`train serve hybrid linear lstm multichip`).
+(`train serve hybrid linear sublayer lstm multichip`).
 """
 from __future__ import annotations
 
@@ -98,6 +110,25 @@ LINEAR = dict(vocab_size=256, hidden_size=3840, intermediate_size=11008,
               rms_norm_eps=1e-6, tie_word_embeddings=False,
               attention_bias=False)
 LINEAR_SERVE = HYBRID_SERVE
+# NVIDIA-Nemotron-3-Nano-30B-A3B's published widths under its config's
+# own keys (`perfbench/families/nemotron_h.py` reads them), one layer of
+# each kind, half of the 128 routed experts held as in the benchmark cell
+SUBLAYER = dict(vocab_size=256, hidden_size=2688, num_hidden_layers=3,
+                hybrid_override_pattern="M*E", num_attention_heads=32,
+                num_key_value_heads=2, head_dim=128, mamba_num_heads=64,
+                mamba_head_dim=64, ssm_state_size=128, n_groups=8,
+                conv_kernel=4, chunk_size=128, n_routed_experts=64,
+                num_experts_per_tok=6, moe_intermediate_size=1856,
+                moe_shared_expert_intermediate_size=3712,
+                routed_scaling_factor=2.5, n_group=1, topk_group=1,
+                norm_topk_prob=True, n_shared_experts=1,
+                mlp_hidden_act="relu2", layer_norm_epsilon=1e-5,
+                norm_eps=1e-5, tie_word_embeddings=False,
+                attention_bias=False, mlp_bias=False, use_bias=False,
+                mamba_proj_bias=False, use_conv_bias=True,
+                deployment=dict(n_routed_experts_published=128,
+                                experts_held_first=0))
+SUBLAYER_SERVE = HYBRID_SERVE
 LSTM = dict(vocab=256, hidden=1024, T=64, batch=2048, steps=2)
 
 # A greedy token may differ between two correct attention paths only
@@ -108,6 +139,11 @@ TIE_MARGIN_NATS = 0.1
 # how far a served token's logit may lie under the float32 reference's
 # best at its position, two bf16 blocks deep (logits are of order 1)
 REFERENCE_GAP = 0.1
+# the same under routed experts whose output dominates the hidden state:
+# bf16 rounding now and then flips one of a token's six experts against
+# the float32 reference, which moves a logit by a few tenths (0.25 read
+# on the chip, PR 36); a net that computes something else reads 2 to 3
+ROUTED_REFERENCE_GAP = 1.0
 # bf16 tolerance for one loss computed two ways (1 chip vs the mesh)
 LOSS_RTOL = 2e-2
 
@@ -745,6 +781,95 @@ def phase_linear(lin: dict, shape: dict, *, kernels: bool,
     return out
 
 
+def phase_sublayer(sub: dict, shape: dict, *, kernels: bool,
+                   dtype=None) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.serving.decode_engine import DecodeEngine
+    from perfbench.families import nemotron_h as fam
+    from perfbench.families import nemotron_h_reference as ref
+
+    dtype = dtype or jnp.bfloat16
+    vocab, n_tokens = sub["vocab_size"], shape["n_tokens"]
+    sz = fam.sizes(sub)
+    weights = fam.make_weights(0, sz)
+    net = fam.build_net(sz, training=False, dtype=dtype)
+    fam.install(net, jax.tree.map(
+        lambda a: a if a.dtype == jnp.float32 else a.astype(dtype), weights))
+    prompts = _serve_prompts(vocab, shape)
+    gen = _engine_kwargs(shape)
+    toks, stats = _through_engine(net, prompts, n_tokens, **gen)
+    _check_tokens(toks, n_tokens, vocab, stats, len(prompts), "sublayer")
+    _check_recurrent_run(stats, shape, len(prompts))
+    by_kind = [sz["pattern"].count(c) for c in (fam.MAMBA, fam.ATTENTION,
+                                                fam.EXPERTS)]
+    got = [stats[k] for k in ("recurrent_blocks", "kv_blocks",
+                              "stateless_blocks")]
+    _check(got == by_kind, f"blocks by cache kind (recurrent, K/V, none): "
+                           f"{got} of {sz['pattern']!r}")
+    share = stats["moe_held_choices"] / max(1, stats["moe_routed"])
+    held = sz["held"][1] / sz["E"]
+    _check(0.5 * held < share < min(1.0, 2.0 * held) + 1e-9,
+           f"{share:.3f} of the router's choices fell on the "
+           f"{held:.3f} of the experts held")
+    out = {"requests": len(prompts), "tokens": int(sum(map(len, toks))),
+           "prefill_chunks": stats["prefill_chunks"],
+           "decode_steps": stats["decode_steps"],
+           "state_bytes_per_slot": stats["state_bytes_per_slot"],
+           "kv_bytes_per_token": stats["kv_bytes_per_token"],
+           "stateless_blocks": stats["stateless_blocks"],
+           "held_share_of_choices": round(share, 4)}
+    gc.collect()
+
+    # a bucketed and the chunked prompt against the plain reference's
+    # full forward: prefill, then decode through state, pages and the
+    # block that keeps neither
+    picked = (0, len(prompts) - 1)
+    out["reference_gaps"] = [round(g, 5) for g in _reference_gaps(
+        fam, ref, sub, sz, weights, [prompts[i] for i in picked],
+        [toks[i] for i in picked])]
+    _check(max(out["reference_gaps"]) < ROUTED_REFERENCE_GAP,
+           f"served tokens lie {out['reference_gaps']} under the "
+           f"reference's best logit")
+
+    # the same prompts with the experts as XLA batched products
+    os.environ["DL4J_TPU_NO_PALLAS_MOE_EXPERTS"] = "1"
+    try:
+        xla, xla_stats = _through_engine(net, prompts, n_tokens, **gen)
+    finally:
+        del os.environ["DL4J_TPU_NO_PALLAS_MOE_EXPERTS"]
+    _check_tokens(xla, n_tokens, vocab, xla_stats, len(prompts), "xla-moe")
+    out["agreement"] = _agreement(net, prompts, toks, xla,
+                                  "kernel and XLA expert products")
+    gc.collect()
+
+    engine = DecodeEngine(net, **gen)
+    try:
+        out.update(_decode_program_counts(engine))
+    finally:
+        engine.shutdown(drain_timeout=30.0)
+    print(f"sublayer: state and pool copies in the decode programs "
+          f"{out['pool_layout_copies']}", flush=True)
+    if kernels:
+        for rows in (shape["n_slots"], shape["prefill_chunk"]):
+            key = ("bfloat16", rows, sz["d"], sz["f"], "relu2")
+            _check(engaged("moe_experts", lambda k: k == key),
+                   f"ungated grouped expert kernel did not engage for "
+                   f"{key}")
+        H, Hkv, hd = sz["H"], sz["Hkv"], sz["hd"]
+        key = ("bfloat16", 1, H, Hkv, hd, shape["page_size"], "dense")
+        _check(engaged("paged_attention", lambda k: k == key),
+               f"paged kernel did not engage for shape class {key}")
+        key = ("bfloat16", Hkv, hd, shape["page_size"], "dense")
+        _check(engaged("paged_kv_write", lambda k: k == key),
+               f"in-place KV write did not engage for {key}")
+        _check(not any(out["pool_layout_copies"].values()),
+               f"the decode programs copy their state or pools: "
+               f"{out['pool_layout_copies']}")
+    return out
+
+
 # -------------------------------------------------------------- multichip
 def phase_multichip(gpt: dict, train: dict, serve: dict,
                     one_chip_loss: float) -> dict:
@@ -844,12 +969,13 @@ def main(argv=None) -> int:
     from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
 
     names = list(sys.argv[1:] if argv is None else argv) \
-        or ["train", "serve", "hybrid", "linear", "lstm", "multichip"]
-    unknown = set(names) - {"train", "serve", "hybrid", "linear", "lstm",
-                            "multichip"}
+        or ["train", "serve", "hybrid", "linear", "sublayer", "lstm",
+            "multichip"]
+    unknown = set(names) - {"train", "serve", "hybrid", "linear",
+                            "sublayer", "lstm", "multichip"}
     if unknown or ("multichip" in names and "train" not in names):
-        print(f"chip_smoke: phases are train serve hybrid linear lstm "
-              f"multichip "
+        print(f"chip_smoke: phases are train serve hybrid linear sublayer "
+              f"lstm multichip "
               f"(multichip compares against train's loss, so name both); "
               f"got {names}", file=sys.stderr)
         return 2
@@ -890,6 +1016,9 @@ def main(argv=None) -> int:
             run("hybrid", phase_hybrid, HYBRID, HYBRID_SERVE, kernels=True)
         if "linear" in names:
             run("linear", phase_linear, LINEAR, LINEAR_SERVE, kernels=True)
+        if "sublayer" in names:
+            run("sublayer", phase_sublayer, SUBLAYER, SUBLAYER_SERVE,
+                kernels=True)
         if "lstm" in names:
             run("lstm", phase_lstm, LSTM, kernels=True)
         if "multichip" in names:

@@ -214,6 +214,70 @@ def hybrid_linear_configuration(vocab_size: int, d_model: int,
             .build())
 
 
+def hybrid_sublayer_configuration(vocab_size: int, d_model: int,
+                                  pattern: str, *,
+                                  n_heads: int, n_kv_heads: int,
+                                  head_dim: int,
+                                  mamba_heads: int, mamba_head_dim: int,
+                                  mamba_state: int, mamba_groups: int = 1,
+                                  mamba_conv: int = 4,
+                                  mamba_chunk: int = 256,
+                                  n_experts: int, top_k: int,
+                                  expert_width: int, shared_width: int = 0,
+                                  routed_scale: float = 1.0,
+                                  experts_held=None,
+                                  eps: float = 1e-5, seed: int = 12345,
+                                  learning_rate: float = 3e-4,
+                                  updater: Updater = Updater.ADAM,
+                                  ) -> MultiLayerConfiguration:
+    """Causal LM of `DecoderBlock`s of ONE sub-layer each, one per
+    character of `pattern`: "M" a Mamba-2 mixer with `mamba_groups` B/C
+    groups, "*" grouped-query attention with heads of `head_dim`
+    (whatever `d_model // n_heads` is) and without positions, "E"
+    sigmoid-routed ungated relu^2 experts plus a shared one; each under
+    one pre-RMSNorm and one residual, no multipliers, no positional
+    layer, one trailing norm and an untied, bias-free output head (the
+    Hugging Face `nemotron_h` family's layout). `experts_held = (first,
+    count)`: the share of each expert layer's experts this network
+    holds."""
+    b = (NeuralNetConfiguration.Builder()
+         .seed(seed)
+         .learning_rate(learning_rate)
+         .updater(updater)
+         .drop_out(0.0)
+         .list()
+         .layer(TokenEmbedding(n_in=vocab_size, n_out=d_model,
+                               positional=False)))
+    kinds = {
+        "M": dict(mixer=Mamba2Mixer(
+            n_heads=mamba_heads, head_dim=mamba_head_dim,
+            d_state=mamba_state, d_conv=mamba_conv, chunk=mamba_chunk,
+            eps=eps, n_groups=mamba_groups)),
+        "*": dict(mixer=AttentionMixer(n_heads=n_heads,
+                                       n_kv_heads=n_kv_heads,
+                                       head_dim=head_dim)),
+        "E": dict(ffn=MoEFeedForward(
+            n_experts=n_experts, top_k=top_k, expert_width=expert_width,
+            shared_width=shared_width, experts_held=experts_held,
+            activation="relu2", scoring="sigmoid",
+            routed_scale=routed_scale))}
+    if set(pattern) - set(kinds):
+        raise ValueError(f"pattern {pattern!r}: each layer is M (Mamba-2), "
+                         "* (attention) or E (experts)")
+    for kind in pattern:
+        b = b.layer(DecoderBlock(n_in=d_model, n_out=d_model,
+                                 norm=RMSNorm(eps=eps), **kinds[kind]))
+    return (b
+            .layer(RMSNormalization(n_in=d_model, n_out=d_model, eps=eps,
+                                    dropout=0.0))
+            .layer(RnnOutputLayer(n_in=d_model, n_out=vocab_size,
+                                  has_bias=False,
+                                  activation=Activation.SOFTMAX,
+                                  loss=LossFunction.MCXENT, dropout=0.0))
+            .set_input_type(InputType.recurrent(vocab_size))
+            .build())
+
+
 # ---------------------------------------------------------------------------
 # shared decode plan + per-block compute (generate() AND the serving
 # decode engine trace through these — one implementation of the numerics)
@@ -249,11 +313,12 @@ class GPTPlan:
 
     def state_kinds(self):
         """Per block, the cache state a decode engine keeps for it:
-        "kv" (paged key/value pools) or "recurrent" (per-slot arrays).
-        A `TransformerBlock` keeps K/V; a composed `DecoderBlock` keeps
-        what its mixer kind declares."""
+        "kv" (paged key/value pools), "recurrent" (per-slot arrays) or
+        "none". A `TransformerBlock` keeps K/V; a composed
+        `DecoderBlock` keeps what its mixer kind declares, and nothing
+        where it has no mixer."""
         return ["kv" if isinstance(self.layers[i], TransformerBlock)
-                else self.layers[i].mixer.state for i in self.block_is]
+                else self.layers[i].state for i in self.block_is]
 
     @property
     def composed(self) -> bool:
@@ -262,8 +327,9 @@ class GPTPlan:
                    for i in self.block_is)
 
     def kv_geometry(self):
-        """Per-block (Hkv, head_dim) pairs — the KV-cache geometry the
-        paged pools allocate per block. One source of truth for the
+        """(Hkv, head_dim) pairs of the blocks that keep K/V — the
+        KV-cache geometry the paged pools allocate per block. One source
+        of truth for the
         serving tier's byte accounting (`quantize.kv_bytes_per_token`,
         the engine's ``kv_bytes_per_token`` stat, the bench's
         slots-per-chip line) so a GQA or head-width change reprices all
@@ -273,7 +339,7 @@ class GPTPlan:
             layer = self.layers[i]
             if isinstance(layer, TransformerBlock):
                 out.append((layer._kv_heads, layer.n_out // layer.n_heads))
-            elif layer.mixer.state == "kv":
+            elif layer.state == "kv":
                 out.append(layer.mixer.kv_geometry(layer._d))
         return out
 

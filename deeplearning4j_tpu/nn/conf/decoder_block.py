@@ -4,6 +4,10 @@ and a norm, each a small config object of its own.
     h <- h + r * mixer(norm(h));  h <- h + r * ffn(norm(h))      "pre"
     h <- h + r * norm(mixer(h));  h <- h + r * norm(ffn(h))      "post"
 
+A block may also be ONE sub-layer, a mixer and no feed-forward or a
+feed-forward and no mixer (the `nemotron_h` family's layers): it then
+has one norm, `n1_w`, and one residual.
+
 `TransformerBlock` is one fixed composition (LayerNorm, biased
 multi-head attention, dense MLP) and stays as it is; a model whose block
 differs in kind (a state-space mixer, routed experts, RMSNorm) composes
@@ -18,12 +22,14 @@ must keep for it, `state`:
                  gives them: the state, slot axis first, then the
                  convolution tail, tap-major)
 
-and `serving/block_state.py` turns that declaration into the engine's
+a block without a mixer declares `DecoderBlock.state` "none": it keeps
+nothing between tokens; and `serving/block_state.py` turns that declaration into the engine's
 allocation and its prefill / decode steps. A kind serialises as
 `{"kind": <name>, ...fields}` inside the layer's JSON.
 
 Parameters of a block are one flat dict, as every layer's: the mixer's
-under `mx_`, the feed-forward's under `ff_`, the norms' `n1_w`, `n2_w`.
+under `mx_`, the feed-forward's under `ff_`, the norms' `n1_w`, `n2_w`
+(a block of one sub-layer has no `n2_w`).
 """
 from __future__ import annotations
 
@@ -113,8 +119,10 @@ def _decay_params(k_dt, k_a, n_heads: int, dtype) -> dict:
 @dataclass(frozen=True)
 class AttentionMixer(_Kind):
     """Causal grouped-query attention without biases and without
-    positional encoding; `scale` multiplies the scores (None: the usual
-    1 / sqrt(head_dim)). With `qk_norm` the query and key projections
+    positional encoding; `head_dim` 0 is `d // n_heads`, any other is
+    the heads' own width (the projections are then `n_heads * head_dim`
+    wide, which need not be `d`); `scale` multiplies the scores (None:
+    the usual 1 / sqrt(head_dim)). With `qk_norm` the query and key projections
     each pass an RMSNorm over their whole width (one gain an element,
     before the split into heads: the Olmo 2/3 convention), and the
     normed keys are what the pages hold. Keeps paged K/V."""
@@ -125,16 +133,17 @@ class AttentionMixer(_Kind):
     scale: Optional[float] = None
     qk_norm: bool = False
     eps: float = 1e-6            # of the query / key norms
+    head_dim: int = 0            # 0: d // n_heads
 
     @property
     def _kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
     def kv_geometry(self, d: int) -> Tuple[int, int]:
-        return self._kv_heads, d // self.n_heads
+        return self._kv_heads, self.head_dim or d // self.n_heads
 
     def init_params(self, key, d: int, dtype, winit) -> dict:
-        hd = d // self.n_heads
+        hd = self.kv_geometry(d)[1]
         qw, kvw = self.n_heads * hd, self._kv_heads * hd
         k1, k2 = jax.random.split(key)
         p = {"Wqkv": winit(k1, (d, qw + 2 * kvw), d, qw + 2 * kvw),
@@ -185,12 +194,16 @@ class AttentionMixer(_Kind):
 @_kind
 @dataclass(frozen=True)
 class Mamba2Mixer(_Kind):
-    """Mamba-2 (arXiv:2405.21060) as Hugging Face's `GraniteMoeHybrid`
-    and `Mamba2` layers write it, one B/C group: in-projection to
+    """Mamba-2 (arXiv:2405.21060) as Hugging Face's `GraniteMoeHybrid`,
+    `NemotronH` and `Mamba2` layers write it: in-projection to
     [z | xBC | dt], depthwise causal convolution and silu over xBC, the
-    selective state-space recurrence of `ops/ssm.py`, a gated RMSNorm
-    over the whole inner width, out-projection. Keeps a per-slot
-    recurrent state (float32) and the convolution's last inputs."""
+    selective state-space recurrence of `ops/ssm.py`, a gated RMSNorm,
+    out-projection. `n_groups` G: B and C are (G, d_state) each, head
+    `h` reads group `h // (n_heads / G)`, and the gated norm is taken
+    over each group's `d_inner / G` channels on its own (one gain an
+    element either way); at one group that is the whole inner width.
+    Keeps a per-slot recurrent state (float32) and the convolution's
+    last inputs."""
     KIND = "mamba2"
     state = "recurrent"
     n_heads: int = 8
@@ -199,6 +212,7 @@ class Mamba2Mixer(_Kind):
     d_conv: int = 4
     chunk: int = 256
     eps: float = 1e-5
+    n_groups: int = 1
 
     @property
     def d_inner(self) -> int:
@@ -206,7 +220,7 @@ class Mamba2Mixer(_Kind):
 
     @property
     def conv_width(self) -> int:
-        return self.d_inner + 2 * self.d_state
+        return self.d_inner + 2 * self.n_groups * self.d_state
 
     def state_shapes(self, n_slots: int, dtype) -> tuple:
         """((shape, dtype), ...) of what one block keeps for `n_slots`
@@ -240,8 +254,23 @@ class Mamba2Mixer(_Kind):
                              + p["dt_bias"].astype(jnp.float32))
         return dt if keep is None else jnp.where(keep, dt, 0.0)
 
+    def _bc(self, xbc):
+        """The B and C parts of silu(conv) output (..., Cw): (..., N)
+        each at one group, (..., G, N) at more."""
+        di, w = self.d_inner, self.n_groups * self.d_state
+        Bm, Cm = xbc[..., di:di + w], xbc[..., di + w:]
+        if self.n_groups == 1:
+            return Bm, Cm
+        shape = (*xbc.shape[:-1], self.n_groups, self.d_state)
+        return Bm.reshape(shape), Cm.reshape(shape)
+
     def _finish(self, p, y, z):
-        y = rms_norm(y * jax.nn.silu(z), p["norm_w"], self.eps)
+        with jax.named_scope("ssm.gate_norm"):
+            y = y * jax.nn.silu(z)
+            by_group = (*y.shape[:-1], self.n_groups, -1)
+            y = rms_norm(y.reshape(by_group),
+                         p["norm_w"].reshape(by_group[-2:]),
+                         self.eps).reshape(y.shape)
         with jax.named_scope("ssm.out_proj"):
             return y @ p["Wout"]
 
@@ -257,7 +286,7 @@ class Mamba2Mixer(_Kind):
             xbc, tail = ssm.causal_conv(xbc, p["conv_w"], p["conv_b"],
                                         tail, n_valid)
             xbc = jax.nn.silu(xbc)
-        di, N = self.d_inner, self.d_state
+        di = self.d_inner
         keep = None if n_valid is None else \
             (jnp.arange(T) < n_valid)[None, :, None]
         with jax.named_scope("ssm.scan"):
@@ -265,8 +294,7 @@ class Mamba2Mixer(_Kind):
                 xbc[..., :di].reshape(B, T, self.n_heads, self.head_dim),
                 self._dt(p, dt_raw, keep),
                 -jnp.exp(p["A_log"].astype(jnp.float32)),
-                xbc[..., di:di + N], xbc[..., di + N:], p["D"],
-                chunk=self.chunk, h0=h0)
+                *self._bc(xbc), p["D"], chunk=self.chunk, h0=h0)
         return self._finish(p, y.reshape(B, T, di), z), h, tail
 
     def step(self, p, x, h, tail, active=None):
@@ -282,14 +310,14 @@ class Mamba2Mixer(_Kind):
             if active is not None:
                 new_tail = jnp.where(active[None, :, None], new_tail,
                                      tail)
-        di, N = self.d_inner, self.d_state
+        di = self.d_inner
         keep = None if active is None else active[:, None]
         with jax.named_scope("ssm.step"):
             y, h = ssm.ssm_step(
                 h, xbc[..., :di].reshape(S, self.n_heads, self.head_dim),
                 self._dt(p, dt_raw, keep),
                 -jnp.exp(p["A_log"].astype(jnp.float32)),
-                xbc[..., di:di + N], xbc[..., di + N:], p["D"])
+                *self._bc(xbc), p["D"])
         return self._finish(p, y.reshape(S, di), z), h, \
             new_tail.astype(tail.dtype)
 
@@ -463,36 +491,67 @@ class GatedMLP(_Kind):
 @_kind
 @dataclass(frozen=True)
 class MoEFeedForward(_Kind):
-    """`n_experts` routed gated MLPs of width `expert_width`, `top_k` a
-    token, gates a softmax over the chosen logits, no capacity and no
-    token dropped; plus one shared gated MLP of width `shared_width`
-    (0: none) added ungated. `experts_held = (first, count)` is the
-    share of the experts whose weights this layer holds and computes
-    (None: all of them): it routes over all `n_experts` and leaves the
-    absent experts' part of the sum out (`parallel/experts.py`)."""
+    """`n_experts` routed MLPs of width `expert_width`, `top_k` a token,
+    no capacity and no token dropped; plus one shared MLP of width
+    `shared_width` (0: none) added unweighted. `experts_held = (first,
+    count)` is the share of the experts whose weights this layer holds
+    and computes (None: all of them): it routes over all `n_experts` and
+    leaves the absent experts' part of the sum out
+    (`parallel/experts.py`).
+
+    `activation`, of the routed and the shared experts alike:
+    "gated_silu", `(silu(x Wg) * (x Wu)) Wd`, three matrices; or
+    "relu2", ungated `relu(x Wu)^2 Wd`, two matrices (no `Wg` leaf, the
+    routed `Wu` held (E, f, d): `parallel/experts.py`).
+    `scoring`, of the router: "softmax", gates a softmax over the chosen
+    logits; or "sigmoid", the experts chosen on `sigmoid(logit) +
+    router_b` (a float32 leaf, one number an expert: it moves the choice
+    and never the weight) and weighed by their unbiased scores
+    normalised to sum 1, times `routed_scale`."""
     KIND = "moe"
     n_experts: int = 8
     top_k: int = 2
     expert_width: int = 64
     shared_width: int = 0
     experts_held: Optional[Tuple[int, int]] = None
+    activation: str = "gated_silu"
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+
+    def __post_init__(self):
+        if self.activation not in ("gated_silu", "relu2"):
+            raise ValueError(f"activation {self.activation!r}: "
+                             "'gated_silu' or 'relu2'")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {self.scoring!r}: 'softmax' or "
+                             "'sigmoid'")
 
     @property
     def held(self) -> Tuple[int, int]:
         return tuple(self.experts_held) if self.experts_held \
             else (0, self.n_experts)
 
+    @property
+    def _gated(self) -> bool:
+        return self.activation == "gated_silu"
+
     def init_params(self, key, d: int, dtype, winit) -> dict:
         E, f, s = self.held[1], self.expert_width, self.shared_width
         k = jax.random.split(key, 7)
         p = {"router": winit(k[0], (d, self.n_experts), d, self.n_experts),
-             "Wg": winit(k[1], (E, d, f), d, f),
-             "Wu": winit(k[2], (E, d, f), d, f),
              "Wd": winit(k[3], (E, f, d), f, d)}
+        if self._gated:
+            p.update(Wg=winit(k[1], (E, d, f), d, f),
+                     Wu=winit(k[2], (E, d, f), d, f))
+        else:
+            p["Wu"] = winit(k[2], (E, f, d), d, f)
+        if self.scoring == "sigmoid":
+            p["router_b"] = jnp.zeros((self.n_experts,), jnp.float32)
         if s:
-            p.update({"sWg": winit(k[4], (d, s), d, s),
-                      "sWu": winit(k[5], (d, s), d, s),
+            p.update({"sWu": winit(k[5], (d, s), d, s),
                       "sWd": winit(k[6], (s, d), s, d)})
+            if self._gated:
+                p["sWg"] = winit(k[4], (d, s), d, s)
         return p
 
     def forward(self, p, x, count_mask=None):
@@ -502,15 +561,20 @@ class MoEFeedForward(_Kind):
         from deeplearning4j_tpu.parallel.experts import (
             dropless_moe,
             gated_mlp,
+            relu2_mlp,
         )
 
         flat = x.reshape(-1, x.shape[-1])
         y, counts = dropless_moe(
-            flat, p["router"], p["Wg"], p["Wu"], p["Wd"], top_k=self.top_k,
-            experts_held=self.held, count_mask=count_mask)
+            flat, p["router"], p.get("Wg"), p["Wu"], p["Wd"],
+            top_k=self.top_k, experts_held=self.held, count_mask=count_mask,
+            act=self.activation, router_bias=p.get("router_b"),
+            routed_scale=self.routed_scale)
         if self.shared_width:
             with jax.named_scope("moe.shared"):
-                y = y + gated_mlp(flat, p["sWg"], p["sWu"], p["sWd"])
+                y = y + (gated_mlp(flat, p["sWg"], p["sWu"], p["sWd"])
+                         if self._gated
+                         else relu2_mlp(flat, p["sWu"], p["sWd"]))
         return y.reshape(x.shape), counts
 
 
@@ -519,11 +583,12 @@ class MoEFeedForward(_Kind):
 @dataclass
 class DecoderBlock(FeedForwardLayer):
     """One decoder block composed of a mixer kind, a feed-forward kind
-    and a norm kind (module docstring); `norm_placement` says whether
-    the norm stands before each sub-layer ("pre": its INPUT is normed)
-    or after it ("post": its OUTPUT is normed before the residual add,
-    the Olmo 2/3 convention); `residual_multiplier` scales both
-    branches before they are added."""
+    and a norm kind (module docstring), or of ONE of the two sub-layers
+    (`mixer` or `ffn` None) under one norm; `norm_placement` says
+    whether the norm stands before each sub-layer ("pre": its INPUT is
+    normed) or after it ("post": its OUTPUT is normed before the
+    residual add, the Olmo 2/3 convention); `residual_multiplier` scales
+    every branch before it is added."""
 
     TYPE = "decoder_block"
     input_kind = "rnn"
@@ -542,15 +607,21 @@ class DecoderBlock(FeedForwardLayer):
         self.mixer = kind_from_json(self.mixer)
         self.ffn = kind_from_json(self.ffn)
         self.norm = kind_from_json(self.norm) or RMSNorm()
-        if self.mixer is None or self.ffn is None:
-            raise ValueError("DecoderBlock needs a mixer kind and a "
-                             "feed-forward kind")
+        if self.mixer is None and self.ffn is None:
+            raise ValueError("DecoderBlock needs a mixer kind or a "
+                             "feed-forward kind (or both)")
         if self.n_in and self.n_out and self.n_in != self.n_out:
             raise ValueError("DecoderBlock keeps width: n_in == n_out")
 
     @property
     def _d(self) -> int:
         return self.n_out or self.n_in
+
+    @property
+    def state(self) -> str:
+        """The cache state a decode engine keeps for this block: what
+        its mixer declares, "none" for a block without a mixer."""
+        return "none" if self.mixer is None else self.mixer.state
 
     def output_type(self, it):
         return it
@@ -559,17 +630,24 @@ class DecoderBlock(FeedForwardLayer):
         d = self._d
         k1, k2 = jax.random.split(key)
         mk = lambda k, shape, fi, fo: self._winit(k, shape, fi, fo, dtype)
-        p = {"n1_w": self.norm.init_params(d, dtype)["w"],
-             "n2_w": self.norm.init_params(d, dtype)["w"]}
-        p.update({"mx_" + n: v for n, v in
-                  self.mixer.init_params(k1, d, dtype, mk).items()})
-        p.update({"ff_" + n: v for n, v in
-                  self.ffn.init_params(k2, d, dtype, mk).items()})
+        p = {"n1_w": self.norm.init_params(d, dtype)["w"]}
+        if self.mixer is not None:
+            p.update({"mx_" + n: v for n, v in
+                      self.mixer.init_params(k1, d, dtype, mk).items()})
+        if self.ffn is not None:
+            p.update({"ff_" + n: v for n, v in
+                      self.ffn.init_params(k2, d, dtype, mk).items()})
+        if self.mixer is not None and self.ffn is not None:
+            p["n2_w"] = self.norm.init_params(d, dtype)["w"]
         return p
 
     def norm1(self, p, x):
         with jax.named_scope("norm1"):
             return self.norm.apply(p["n1_w"], x)
+
+    def norm2(self, p, x):
+        with jax.named_scope("norm2"):
+            return self.norm.apply(p["n2_w"], x)
 
     def mixer_in(self, p, x):
         """What the mixer reads: the block's input, normed first where
@@ -577,30 +655,33 @@ class DecoderBlock(FeedForwardLayer):
         return self.norm1(p, x) if self.norm_placement == "pre" else x
 
     def finish(self, p, x, mixed, count_mask=None):
-        """The block from the mixer's output on: first residual, norm,
-        feed-forward, second residual. Returns (h, the feed-forward's
-        counts under `count_mask`, or None)."""
+        """The block from the mixer's output `mixed` on (None: the block
+        has no mixer): the mixer's residual, then norm, feed-forward and
+        its residual where the block has a feed-forward. Returns (h, the
+        feed-forward's counts under `count_mask`, or None)."""
         r = jnp.asarray(self.residual_multiplier, x.dtype)
-        if self.norm_placement == "post":
-            h = x + r * self.norm1(p, mixed)
-            f, counts = self.ffn.forward(sub(p, "ff_"), h, count_mask)
-            with jax.named_scope("norm2"):
-                return h + r * self.norm.apply(p["n2_w"], f), counts
-        h = x + r * mixed
-        with jax.named_scope("norm2"):
-            u = self.norm.apply(p["n2_w"], h)
-        f, counts = self.ffn.forward(sub(p, "ff_"), u, count_mask)
-        return h + r * f, counts
+        post = self.norm_placement == "post"
+        h = x
+        if self.mixer is not None:
+            h = x + r * (self.norm1(p, mixed) if post else mixed)
+        if self.ffn is None:
+            return h, None
+        # the block's second norm, or the one norm of a feed-forward alone
+        norm = self.norm1 if self.mixer is None else self.norm2
+        f, counts = self.ffn.forward(sub(p, "ff_"),
+                                     h if post else norm(p, h), count_mask)
+        return h + r * (norm(p, f) if post else f), counts
 
     def forward(self, params, state, x, *, train=False, rng=None,
                 mask=None):
-        mixed = self.mixer.forward(sub(params, "mx_"),
-                                   self.mixer_in(params, x))
+        mixed = None if self.mixer is None else self.mixer.forward(
+            sub(params, "mx_"), self.mixer_in(params, x))
         return self.finish(params, x, mixed)[0], state
 
     def param_flags(self, name):
         vector = name in ("n1_w", "n2_w", "mx_norm_w", "mx_conv_b",
                           "mx_dt_bias", "mx_A_log", "mx_D", "mx_qn_w",
-                          "mx_kn_w")
-        return {"is_bias": name in ("mx_conv_b", "mx_dt_bias"),
+                          "mx_kn_w", "ff_router_b")
+        return {"is_bias": name in ("mx_conv_b", "mx_dt_bias",
+                                    "ff_router_b"),
                 "regularizable": not vector}

@@ -1,12 +1,22 @@
-"""Pallas TPU grouped expert product: the gated FFNs of the experts a
-chip holds, summed under the router's gates.
+"""Pallas TPU grouped expert product: the FFNs of the experts a chip
+holds, summed under the router's gates, in the two variants
+`parallel/experts.py` names:
 
-    y = sum_e gates[:, e] * (silu(x Wg[e]) * (x Wu[e])) Wd[e]
+    gated_silu  y = sum_e gates[:, e] * (silu(x Wg[e]) * (x Wu[e])) Wd[e]
+    relu2       y = sum_e gates[:, e] * relu(x Wu[e]^T)^2 Wd[e]
 
 `x` (N, d) tokens, `gates` (N, E) float32 (zero where the router did
-not choose expert e), `Wg`/`Wu` (E, d, f), `Wd` (E, f, d). The grid runs
+not choose expert e), `Wd` (E, f, d); gated: `Wg`/`Wu` (E, d, f);
+ungated: no `Wg`, and `Wu` (E, f, d) like `Wd`, so that the first
+product contracts over `d` on both operands and `f` lies on sublanes in
+both matrices: an expert width off the 128-lane grid (1856 = 232 x 8)
+then costs no padding, where a (d, 1856) block compiles too but makes
+XLA copy the whole stack into a lane-padded layout before every call
+(my sandbox compile, PR 36: 0.66 GB of temporaries at 64 experts of
+2688 x 1856). The variant is static: one kernel body a variant, and the
+probe's key and `kernel_verdicts()` row name it. The grid runs
 over (token tiles, experts): one grid step brings one whole expert
-(three (d, f)-sized matrices) into VMEM, while the token tile and a
+(its three, or two, (d, f)-sized matrices) into VMEM, while the token tile and a
 float32 accumulator stay resident, so each held expert's weights cross
 HBM once per token tile: the least a decode step can move when every
 held expert is chosen by some slot, which at 64 slots x top-10 of 72 is
@@ -37,12 +47,14 @@ from deeplearning4j_tpu.ops.kernel_dispatch import (
 )
 
 FAMILY = "moe_experts"  # this module's row in kernel_verdicts()
+GATED_SILU, RELU2 = "gated_silu", "relu2"  # the experts' activations
 _MAX_ROWS = 512         # token rows per tile
 
 
-def _experts_kernel(x_ref, g_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref):
+def _experts_kernel(x_ref, g_ref, *refs, act: str):
     from jax.experimental import pallas as pl
 
+    *w_refs, o_ref, acc_ref = refs
     e = pl.program_id(1)
 
     @pl.when(e == 0)
@@ -50,9 +62,17 @@ def _experts_kernel(x_ref, g_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...]
-    g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-    u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-    h = g * jax.nn.sigmoid(g) * u * g_ref[0]
+    if act == RELU2:
+        wu_ref, wd_ref = w_refs
+        u = jax.lax.dot_general(x, wu_ref[0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        u = jnp.maximum(u, 0.0)
+        h = u * u * g_ref[0]
+    else:
+        wg_ref, wu_ref, wd_ref = w_refs
+        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        h = g * jax.nn.sigmoid(g) * u * g_ref[0]
     acc_ref[...] += jnp.dot(h.astype(x.dtype), wd_ref[0],
                             preferred_element_type=jnp.float32)
 
@@ -74,24 +94,28 @@ def _row_tile(n: int) -> int:
 
 # jitted so that a step over many layers traces and lowers the kernel
 # once and calls it once a layer (`pallas_paged_kv_write`'s lesson)
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def moe_experts(x, gates, Wg, Wu, Wd, *, interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("act", "interpret"))
+def moe_experts(x, gates, Wg, Wu, Wd, *, act: str = GATED_SILU,
+                interpret: bool = False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     N, d = x.shape
-    E, _, f = Wg.shape
+    E, f, _ = Wd.shape
     tn = _row_tile(N)
     # (E, N, 1): one expert's gate column arrives as a (tn, 1) block
     g3 = jnp.swapaxes(gates.astype(jnp.float32), 0, 1)[..., None]
+    whole = lambda *shape: pl.BlockSpec((1,) + shape,
+                                        lambda n, e: (e, 0, 0))
+    weights, specs = ((Wu, Wd), [whole(f, d), whole(f, d)]) \
+        if act == RELU2 else \
+        ((Wg, Wu, Wd), [whole(d, f), whole(d, f), whole(f, d)])
     return pl.pallas_call(
-        _experts_kernel,
+        functools.partial(_experts_kernel, act=act),
         grid=(N // tn, E),
         in_specs=[pl.BlockSpec((tn, d), lambda n, e: (n, 0)),
                   pl.BlockSpec((1, tn, 1), lambda n, e: (e, n, 0)),
-                  pl.BlockSpec((1, d, f), lambda n, e: (e, 0, 0)),
-                  pl.BlockSpec((1, d, f), lambda n, e: (e, 0, 0)),
-                  pl.BlockSpec((1, f, d), lambda n, e: (e, 0, 0))],
+                  *specs],
         out_specs=pl.BlockSpec((tn, d), lambda n, e: (n, 0)),
         out_shape=jax.ShapeDtypeStruct((N, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((tn, d), jnp.float32)],
@@ -99,23 +123,26 @@ def moe_experts(x, gates, Wg, Wu, Wd, *, interpret: bool = False):
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
-    )(x, g3, Wg, Wu, Wd)
+    )(x, g3, *weights)
 
 
-def vmem_bytes_estimate(tn: int, d: int, f: int, dtype) -> int:
-    """Resident VMEM of one grid step: three expert matrices, double-
-    buffered; the token tile and the output tile, double-buffered; the
-    float32 accumulator and the two (tn, f) float32 intermediates."""
+def vmem_bytes_estimate(tn: int, d: int, f: int, dtype,
+                        act: str = GATED_SILU) -> int:
+    """Resident VMEM of one grid step: the expert's matrices (three, or
+    two ungated), double-buffered; the token tile and the output tile,
+    double-buffered; the float32 accumulator and the (tn, f) float32
+    intermediates."""
     item = jnp.dtype(dtype).itemsize
-    return 2 * 3 * d * f * item + 4 * tn * d * item + 4 * tn * d \
-        + 3 * 4 * tn * f
+    n_mat = 2 if act == RELU2 else 3
+    return 2 * n_mat * d * f * item + 4 * tn * d * item + 4 * tn * d \
+        + n_mat * 4 * tn * f
 
 
 def _platform_supported() -> bool:
     return _kernels_dispatch("DL4J_TPU_NO_PALLAS_MOE_EXPERTS")
 
 
-def _eager_probe(dtype, tn: int, d: int, f: int) -> bool:
+def _eager_probe(dtype, tn: int, d: int, f: int, act: str) -> bool:
     """Compile and run the kernel at this shape class (two experts, one
     of them chosen by no token) and hold it to the XLA products."""
     import numpy as np
@@ -125,13 +152,16 @@ def _eager_probe(dtype, tn: int, d: int, f: int) -> bool:
     rng = np.random.default_rng(0)
     E = 2
     x = jnp.asarray(rng.standard_normal((tn, d)), dtype)
-    Wg, Wu = (jnp.asarray(rng.standard_normal((E, d, f)) / d ** 0.5, dtype)
+    up = (E, f, d) if act == RELU2 else (E, d, f)
+    Wg, Wu = (jnp.asarray(rng.standard_normal(up) / d ** 0.5, dtype)
               for _ in range(2))
+    if act == RELU2:
+        Wg = None
     Wd = jnp.asarray(rng.standard_normal((E, f, d)) / f ** 0.5, dtype)
     gates = jnp.asarray(np.stack([rng.random(tn), np.zeros(tn)], 1),
                         jnp.float32)
-    got = np.asarray(moe_experts(x, gates, Wg, Wu, Wd), np.float32)
-    want = np.asarray(grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd),
+    got = np.asarray(moe_experts(x, gates, Wg, Wu, Wd, act=act), np.float32)
+    want = np.asarray(grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act),
                       np.float32)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
     err = float(np.max(np.abs(got - want))) / float(np.max(np.abs(want)))
@@ -141,33 +171,41 @@ def _eager_probe(dtype, tn: int, d: int, f: int) -> bool:
     return True
 
 
-def moe_experts_or_none(x, gates, Wg, Wu, Wd):
+def moe_experts_or_none(x, gates, Wg, Wu, Wd, act: str = GATED_SILU):
     """Dispatch probe: the grouped product, or None when the kernel
     cannot serve this call (CPU backend, kill switch, a dtype Mosaic
-    does not tile, widths off the 128-lane grid, VMEM overflow) or its
-    shape class failed the compile+parity probe."""
+    does not tile, a width off the tile grid of the axis it lies on,
+    VMEM overflow) or its shape class failed the compile+parity probe.
+    The gated variant's key is `(dtype, rows, d, f)` as it always was;
+    the ungated one's ends in its name."""
     N, d = x.shape
-    E, _, f = Wg.shape
+    E, f, _ = Wd.shape
     dtype = x.dtype
-    if not _platform_supported() or Wg.dtype != dtype \
+    if not _platform_supported() or Wu.dtype != dtype \
             or dtype not in (jnp.float32, jnp.bfloat16):
         return None
     tn = _row_tile(N)
     key = (jnp.dtype(dtype).name, tn, d, f)
-    if not tn or tn % 8 or d % 128 or f % 128:
+    if act == RELU2:
+        key += (RELU2,)
+    # `f` lies on lanes in the gated variant's (d, f) matrices and on
+    # sublanes (16 rows a bf16 tile) in the ungated one's (f, d)
+    f_grid = 128 if act != RELU2 else 32 // jnp.dtype(dtype).itemsize
+    if not tn or tn % 8 or d % 128 or f % f_grid:
         _record_decline(FAMILY, key, f"{N} rows, widths {d} x {f}: off "
                                      "the (8, 128) tile grid")
         return None
-    est = vmem_bytes_estimate(tn, d, f, dtype)
+    est = vmem_bytes_estimate(tn, d, f, dtype, act)
     if est > _vmem_limit():
         _record_decline(FAMILY, key,
                         f"needs ~{est >> 20} MiB VMEM > "
                         f"{_vmem_limit() >> 20} MiB ceiling")
         return None
-    if not _probe_verdict(FAMILY, key, _eager_probe, (dtype, tn, d, f)):
+    if not _probe_verdict(FAMILY, key, _eager_probe,
+                          (dtype, tn, d, f, act)):
         return None
     try:
-        return moe_experts(x, gates, Wg, Wu, Wd)
+        return moe_experts(x, gates, Wg, Wu, Wd, act=act)
     except Exception as e:  # per-shape staging failure: fall back
         _record_decline(FAMILY, key, f"staging at {x.shape}: "
                                      f"{type(e).__name__}: {e}")

@@ -8,8 +8,11 @@ One head's recurrence, with scalar decay per head and step:
     y_t = h_t C_t + D * x_t                                     (P,)
 
 `x` is (B, T, H, P), `dt` (B, T, H) already through softplus, `A` (H,)
-negative, `Bm`/`Cm` (B, T, N) (one group: every head shares them), `D`
-(H,). Three forms compute it: `ssm_sequential` (a `lax.scan` over time,
+negative, `D` (H,); `Bm`/`Cm` are (B, T, N) where every head shares them
+(one group) or (B, T, G, N) where head `h` reads group `h // (H / G)`
+(each of the three forms then takes the heads as (G, H / G) and leaves
+the one-group arithmetic as it is). Three forms compute it:
+`ssm_sequential` (a `lax.scan` over time,
 the definition), `ssd_chunked` (the block-decomposed form of the Mamba-2
 paper, arXiv:2405.21060 §6: quadratic attention-like products inside a
 chunk, the recurrence only between chunks) and `ssm_step` (one token for
@@ -33,6 +36,22 @@ import jax.numpy as jnp
 F32 = jnp.float32
 
 
+def _by_group(Bm, x):
+    """How the three forms read `Bm`/`Cm` against `x`'s head axis: (the
+    einsum letters of the head axis, of the group axis, and the function
+    that splits an array's head axis `axis` into (G, H / G))."""
+    if Bm.ndim == x.ndim - 1:           # one group: no group axis
+        return "h", "", lambda u, axis: u
+    G = Bm.shape[-2]
+
+    def split(u, axis):
+        axis %= u.ndim
+        return u.reshape(*u.shape[:axis], G, u.shape[axis] // G,
+                         *u.shape[axis + 1:])
+
+    return "gr", "g", split
+
+
 def ssm_sequential(x, dt, A, Bm, Cm, D, h0=None):
     """The recurrence as written, one step at a time. Returns
     (y (B, T, H, P) in x's dtype, final state (B, H, P, N) float32)."""
@@ -53,17 +72,18 @@ def ssm_sequential(x, dt, A, Bm, Cm, D, h0=None):
 
 def ssm_step(h, x, dt, A, Bm, Cm, D):
     """One position for every row: `h` (S, H, P, N) float32, `x`
-    (S, H, P), `dt` (S, H), `Bm`/`Cm` (S, N). Returns (y (S, H, P) in
-    x's dtype, new state). A row with `dt == 0` keeps its state bit for
-    bit."""
-    dt = dt.astype(F32)
-    decay = jnp.exp(dt * A.astype(F32))                       # (S, H)
-    dx = dt[..., None] * x.astype(F32)                        # (S, H, P)
-    h = decay[..., None, None] * h \
-        + dx[..., None] * Bm.astype(F32)[:, None, None, :]
-    y = jnp.einsum("shpn,sn->shp", h, Cm.astype(F32)) \
-        + D.astype(F32)[None, :, None] * x.astype(F32)
-    return y.astype(x.dtype), h
+    (S, H, P), `dt` (S, H), `Bm`/`Cm` (S, N) or (S, G, N). Returns
+    (y (S, H, P) in x's dtype, new state). A row with `dt == 0` keeps
+    its state bit for bit."""
+    hd, g, split = _by_group(Bm, x)
+    xf, dt = split(x.astype(F32), 1), split(dt.astype(F32), 1)
+    decay = jnp.exp(dt * split(A.astype(F32), 0))             # (S, H)
+    dx = dt[..., None] * xf                                   # (S, H, P)
+    hs = decay[..., None, None] * split(h, 1) \
+        + dx[..., None] * jnp.expand_dims(Bm.astype(F32), (-3, -2))
+    y = jnp.einsum(f"s{hd}pn,s{g}n->s{hd}p", hs, Cm.astype(F32)) \
+        + split(D.astype(F32), 0)[..., None] * xf
+    return y.reshape(x.shape).astype(x.dtype), hs.reshape(h.shape)
 
 
 def _segsum(a):
@@ -79,32 +99,33 @@ def _segsum(a):
 def ssd_chunked(x, dt, A, Bm, Cm, D, *, chunk: int, h0=None):
     """The same recurrence by chunks of `chunk` positions. T need not be
     a multiple of the chunk: the tail is padded with `dt = 0`, which
-    leaves the state alone. Returns (y, final state) as
-    `ssm_sequential`."""
+    leaves the state alone. `Bm`/`Cm` (B, T, N) or (B, T, G, N). Returns
+    (y, final state) as `ssm_sequential`."""
     B, T, H, P = x.shape
     N = Bm.shape[-1]
+    hd, g, split = _by_group(Bm, x)
     Q = min(int(chunk), T)
     pad = -T % Q
     if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0)))
-        Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
+        along_t = lambda u: jnp.pad(
+            u, ((0, 0), (0, pad)) + ((0, 0),) * (u.ndim - 2))
+        x, dt, Bm, Cm = along_t(x), along_t(dt), along_t(Bm), along_t(Cm)
     nc = (T + pad) // Q
     dtf = dt.astype(F32)
-    xd = (x.astype(F32) * dtf[..., None]).reshape(B, nc, Q, H, P)
+    xd = split((x.astype(F32) * dtf[..., None]).reshape(B, nc, Q, H, P), 3)
     a = (dtf * A.astype(F32)).reshape(B, nc, Q, H)
     a = jnp.transpose(a, (0, 3, 1, 2))                        # (B, H, c, Q)
-    Bc = Bm.astype(F32).reshape(B, nc, Q, N)
-    Cc = Cm.astype(F32).reshape(B, nc, Q, N)
+    Bc = Bm.astype(F32).reshape(B, nc, Q, *Bm.shape[2:])
+    Cc = Cm.astype(F32).reshape(B, nc, Q, *Cm.shape[2:])
     a_cum = jnp.cumsum(a, axis=-1)
     # inside each chunk: y_i += sum_{j<=i} (C_i . B_j) decay(j->i) dt_j x_j
-    decay_in = jnp.exp(_segsum(a))                            # (B,H,c,Q,Q)
-    cb = jnp.einsum("bcln,bcsn->bcls", Cc, Bc)
-    y = jnp.einsum("bcls,bhcls,bcshp->bclhp", cb, decay_in, xd)
+    decay_in = split(jnp.exp(_segsum(a)), 1)                  # (B,H,c,Q,Q)
+    cb = jnp.einsum(f"bcl{g}n,bcs{g}n->b{g}cls", Cc, Bc)
+    y = jnp.einsum(f"b{g}cls,b{hd}cls,bcs{hd}p->bcl{hd}p", cb, decay_in, xd)
     # what each chunk adds to the state by its end
-    to_end = jnp.exp(a_cum[..., -1:] - a_cum)                 # (B,H,c,Q)
-    states = jnp.einsum("bcln,bhcl,bclhp->bchpn", Bc, to_end, xd)
+    to_end = split(jnp.exp(a_cum[..., -1:] - a_cum), 1)       # (B,H,c,Q)
+    states = jnp.einsum(f"bcl{g}n,b{hd}cl,bcl{hd}p->bc{hd}pn", Bc, to_end,
+                        xd).reshape(B, nc, H, P, N)
     # the recurrence between chunks: the state entering each chunk
     h0 = jnp.zeros((B, H, P, N), F32) if h0 is None else h0.astype(F32)
     chunk_decay = jnp.exp(a_cum[..., -1])                     # (B, H, c)
@@ -117,8 +138,8 @@ def ssd_chunked(x, dt, A, Bm, Cm, D, *, chunk: int, h0=None):
         carry, h0, (jnp.swapaxes(states, 0, 1),
                     jnp.moveaxis(chunk_decay, 2, 0)))
     entering = jnp.swapaxes(entering, 0, 1)                   # (B,c,H,P,N)
-    y = y + jnp.einsum("bcln,bchpn,bhcl->bclhp", Cc, entering,
-                       jnp.exp(a_cum))
+    y = y + jnp.einsum(f"bcl{g}n,bc{hd}pn,b{hd}cl->bcl{hd}p", Cc,
+                       split(entering, 2), split(jnp.exp(a_cum), 1))
     y = y.reshape(B, nc * Q, H, P)[:, :T] \
         + D.astype(F32)[None, None, :, None] * x[:, :T].astype(F32)
     return y.astype(x.dtype), h

@@ -26,6 +26,8 @@ from jax import lax
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from deeplearning4j_tpu.ops.pallas_moe_experts import GATED_SILU, RELU2
+
 # -- expert-parallel mesh scope ------------------------------------------
 # ParallelWrapper enters this scope inside its (traced) step so that
 # MoELayer.forward — which has no mesh in its signature — can discover the
@@ -282,30 +284,63 @@ def topk_gates(logits: jnp.ndarray, top_k: int) -> jnp.ndarray:
     return jnp.zeros(logits.shape, jnp.float32).at[rows, top_i].set(w)
 
 
-def held_gates(logits: jnp.ndarray, top_k: int, experts_held) -> jnp.ndarray:
+def sigmoid_topk_gates(logits: jnp.ndarray, bias: jnp.ndarray, top_k: int,
+                       scale: float) -> jnp.ndarray:
+    """(N, E) router logits -> (N, E) float32 gates, scored by sigmoid
+    (the DeepSeek-V3 / `NemotronH` router with one group): the `top_k`
+    experts are chosen on `sigmoid(logits) + bias` (`bias` (E,), the
+    score-correction bias: it moves the choice and never the weight),
+    and a chosen expert's gate is its UNBIASED score over the chosen
+    scores' sum, times `scale`; zero elsewhere."""
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, top_i = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    rows = jnp.arange(logits.shape[0])[:, None]
+    top_s = s[rows, top_i]
+    w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20) * scale
+    return jnp.zeros(logits.shape, jnp.float32).at[rows, top_i].set(w)
+
+
+def held_gates(logits: jnp.ndarray, top_k: int, experts_held, *,
+               bias=None, scale: float = 1.0) -> jnp.ndarray:
     """The gates of the experts held here, (N, count): a column of
-    zeros for a held expert that no token chose."""
+    zeros for a held expert that no token chose. With a `bias` the
+    router scores by sigmoid (`sigmoid_topk_gates`), else by softmax
+    over the chosen (`topk_gates`)."""
     first, count = experts_held
-    return topk_gates(logits, top_k)[:, first:first + count]
+    gates = topk_gates(logits, top_k) if bias is None \
+        else sigmoid_topk_gates(logits, bias, top_k, scale)
+    return gates[:, first:first + count]
 
 
-def grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd):
-    """sum_e gates[:, e] * (silu(x Wg[e]) * (x Wu[e])) Wd[e] over the
-    experts held, as batched products: every token meets every held
-    expert and the gate (zero where the router did not choose it)
-    weighs the result. `x` (N, d); `gates` (N, E) float32; `Wg`, `Wu`
-    (E, d, f); `Wd` (E, f, d). Products accumulate in float32."""
-    g = jnp.einsum("nd,edf->enf", x, Wg,
-                   preferred_element_type=jnp.float32)
-    u = jnp.einsum("nd,edf->enf", x, Wu,
-                   preferred_element_type=jnp.float32)
-    h = jax.nn.silu(g) * u * jnp.swapaxes(gates, 0, 1)[..., None]
+def grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act: str = GATED_SILU):
+    """sum_e gates[:, e] * expert_e(x) over the experts held, as batched
+    products: every token meets every held expert and the gate (zero
+    where the router did not choose it) weighs the result. `x` (N, d);
+    `gates` (N, E) float32; products accumulate in float32.
+
+    `act` "gated_silu": expert_e(x) = (silu(x Wg[e]) * (x Wu[e])) Wd[e],
+    `Wg`, `Wu` (E, d, f), `Wd` (E, f, d). "relu2": ungated,
+    expert_e(x) = relu(x Wu[e]^T)^2 Wd[e]; there is no `Wg` (None) and
+    the up matrices are held (E, f, d) like the down matrices, so that a
+    width `f` off the 128-lane grid lies on sublanes in both
+    (`ops/pallas_moe_experts.py`)."""
+    scale = jnp.swapaxes(gates, 0, 1)[..., None]
+    if act == RELU2:
+        u = jnp.einsum("nd,efd->enf", x, Wu,
+                       preferred_element_type=jnp.float32)
+        h = jnp.square(jax.nn.relu(u)) * scale
+    else:
+        g = jnp.einsum("nd,edf->enf", x, Wg,
+                       preferred_element_type=jnp.float32)
+        u = jnp.einsum("nd,edf->enf", x, Wu,
+                       preferred_element_type=jnp.float32)
+        h = jax.nn.silu(g) * u * scale
     y = jnp.einsum("enf,efd->nd", h.astype(x.dtype), Wd,
                    preferred_element_type=jnp.float32)
     return y.astype(x.dtype)
 
 
-def grouped_expert_ffn(x, gates, Wg, Wu, Wd):
+def grouped_expert_ffn(x, gates, Wg, Wu, Wd, act: str = GATED_SILU):
     """The grouped product behind the kernel-dispatch contract: the
     Pallas kernel of `ops/pallas_moe_experts.py` on a TPU (each held
     expert's weights streamed through VMEM once), the batched XLA
@@ -314,8 +349,8 @@ def grouped_expert_ffn(x, gates, Wg, Wu, Wd):
         moe_experts_or_none,
     )
 
-    out = moe_experts_or_none(x, gates, Wg, Wu, Wd)
-    return grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd) \
+    out = moe_experts_or_none(x, gates, Wg, Wu, Wd, act)
+    return grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act) \
         if out is None else out
 
 
@@ -327,17 +362,28 @@ def gated_mlp(x, Wg, Wu, Wd):
                    preferred_element_type=jnp.float32).astype(x.dtype)
 
 
+def relu2_mlp(x, Wu, Wd):
+    """relu(x Wu)^2 Wd: the ungated shared expert (`Wu` (d, f))."""
+    u = jnp.dot(x, Wu, preferred_element_type=jnp.float32)
+    return jnp.dot(jnp.square(jax.nn.relu(u)).astype(x.dtype), Wd,
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+
+
 def dropless_moe(x, router, Wg, Wu, Wd, *, top_k: int, experts_held,
-                 count_mask=None):
+                 count_mask=None, act: str = GATED_SILU, router_bias=None,
+                 routed_scale: float = 1.0):
     """Top-k dropless routing over `router.shape[1]` experts, computed
-    for the experts held. `x` (N, d). Returns (y (N, d), counts): with
-    a `count_mask` (N,) bool, `counts` is an int32 (count,) vector, how
-    many of the masked-in tokens chose each held expert; else None."""
+    for the experts held. `x` (N, d). `act` and the matrices as
+    `grouped_expert_ffn_xla`; `router_bias` and `routed_scale` as
+    `held_gates`. Returns (y (N, d), counts): with a `count_mask` (N,)
+    bool, `counts` is an int32 (count,) vector, how many of the
+    masked-in tokens chose each held expert; else None."""
     with jax.named_scope("moe.route"):
         logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
-        gates = held_gates(logits, top_k, experts_held)
+        gates = held_gates(logits, top_k, experts_held, bias=router_bias,
+                           scale=routed_scale)
     with jax.named_scope("moe.experts"):
-        y = grouped_expert_ffn(x, gates, Wg, Wu, Wd)
+        y = grouped_expert_ffn(x, gates, Wg, Wu, Wd, act)
     if count_mask is None:
         return y, None
     chose = (gates > 0) & count_mask[:, None]

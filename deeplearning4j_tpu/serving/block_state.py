@@ -21,7 +21,11 @@ kind each block keeps (`GPTPlan.state_kinds`):
                     first chunk of a chunked one, starts from zeros),
                     carried from one prefill chunk to the next, left
                     alone by pad positions and by inactive slots, and
-                    advanced in place by every decode step.
+                    advanced in place by every decode step;
+    Stateless       nothing: a composed block without a mixer (a
+                    feed-forward under its norm and residual) reads no
+                    cache and writes none, whatever the slot or the
+                    position.
 
 Each has `alloc()`, `decode(p, x, cache, d)`, `prefill(p, x, cache, d)`
 and `prefill_chunk(p, x, cache, d)`, the last three returning
@@ -349,7 +353,25 @@ class RecurrentSlots:
         return x, self._store(cache, h1, tail1, d.slot)
 
 
-_KINDS = {"kv": KVPages, "recurrent": RecurrentSlots}
+class Stateless:
+    kind = "none"
+
+    def __init__(self, layer, env):
+        self.layer = layer
+
+    def alloc(self) -> tuple:
+        return ()
+
+    def bytes_per_slot(self) -> int:
+        return 0
+
+    def decode(self, p, x, cache, d):
+        return _finish_composed(self.layer, p, x, None, d), cache
+
+    prefill = prefill_chunk = decode
+
+
+_KINDS = {"kv": KVPages, "recurrent": RecurrentSlots, "none": Stateless}
 
 
 def block_states(plan, env) -> list:
@@ -368,11 +390,13 @@ def routed_ffns(plan) -> list:
 
 
 def moe_held(plan) -> int:
-    """How many routed experts each block holds (0: the net has none;
-    blocks must agree, since the step returns one count vector)."""
+    """How many routed experts each routed block holds (0: the net has
+    none; the blocks that route must agree, since the step returns one
+    count vector; blocks that do not route add nothing to it)."""
     held = {ffn.held[1] for ffn in routed_ffns(plan)}
     if len(held) > 1:
         raise ValueError(
-            f"blocks hold different numbers of experts {sorted(held)}: "
-            "the decode step returns one per-expert count vector")
+            f"routed blocks hold different numbers of experts "
+            f"{sorted(held)}: the decode step returns one per-expert "
+            "count vector")
     return held.pop() if held else 0

@@ -807,8 +807,8 @@ class DecodeEngine:
         self._recurrent = any(st.kind == "recurrent" for st in states)
         self._state_bytes_per_slot = sum(st.bytes_per_slot()
                                          for st in states)
-        self._recurrent_blocks = sum(st.kind == "recurrent"
-                                     for st in states)
+        self._blocks_by_kind = collections.Counter(st.kind
+                                                   for st in states)
         routed = block_state.routed_ffns(plan)
         self._moe_blocks = len(routed)
         self._moe_top_k = max([ffn.top_k for ffn in routed], default=0)
@@ -1736,8 +1736,9 @@ class DecodeEngine:
                "state_bytes_per_slot": self._state_bytes_per_slot,
                "state_resets": self.state_resets,
                # how many blocks keep which kind of cache
-               "recurrent_blocks": self._recurrent_blocks,
-               "kv_blocks": len(self._states) - self._recurrent_blocks,
+               "recurrent_blocks": self._blocks_by_kind["recurrent"],
+               "kv_blocks": self._blocks_by_kind["kv"],
+               "stateless_blocks": self._blocks_by_kind["none"],
                # routed experts, decode steps only: top-k choices of
                # active slots, those on experts held here, held experts
                # hit (summed over blocks and steps) and the steps

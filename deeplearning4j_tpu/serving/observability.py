@@ -958,11 +958,12 @@ DECODE_ENGINE_STATS_KEYS = frozenset({
     "kv_quant_bits", "kv_bytes_per_token",
     # composed blocks: bytes a slot holds whatever its length (recurrent
     # state + convolution tails), slot states overwritten at admission,
-    # blocks by the kind of cache they keep,
-    # and the routed experts' decode-step counts (choices made, choices
-    # on experts held here, held experts hit, steps, experts held)
+    # blocks by the kind of cache they keep (none: a block without a
+    # mixer), and the routed experts' decode-step counts (choices made,
+    # choices on experts held here, held experts hit, steps, experts
+    # held)
     "state_bytes_per_slot", "state_resets", "recurrent_blocks",
-    "kv_blocks", "moe_routed",
+    "kv_blocks", "stateless_blocks", "moe_routed",
     "moe_held_choices", "moe_experts_hit", "moe_steps", "moe_experts_held",
     # tensor-parallel tier: mesh degree (1 = single-device engine, so
     # capacity dashboards never branch on key presence) and the

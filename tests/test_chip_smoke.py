@@ -116,6 +116,52 @@ def test_linear_phase_publishes_olmo_hybrids_widths():
     assert sz["layer_types"] == ("linear_attention", "full_attention")
 
 
+def test_sublayer_phase_toy():
+    import jax.numpy as jnp
+
+    sub = dict(chip_smoke.SUBLAYER, vocab_size=64, hidden_size=64,
+               num_hidden_layers=5, hybrid_override_pattern="MEM*E",
+               num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+               mamba_num_heads=4, mamba_head_dim=16, ssm_state_size=16,
+               n_groups=2, chunk_size=8, n_routed_experts=4,
+               num_experts_per_tok=2, moe_intermediate_size=24,
+               moe_shared_expert_intermediate_size=40,
+               deployment=dict(n_routed_experts_published=8,
+                               experts_held_first=4))
+    out = chip_smoke.phase_sublayer(
+        sub, dict(n_slots=4, max_len=96, page_size=8, prefill_chunk=16,
+                  n_short=3, short_len=8, long_len=40, n_tokens=8),
+        kernels=False, dtype=jnp.float32)
+    assert out["requests"] == 4 and out["tokens"] == 4 * 8
+    assert out["prefill_chunks"] >= 3
+    # float32 against the float32 reference: the served tokens are its own
+    assert max(out["reference_gaps"]) < 1e-4
+    # on the CPU both engines ARE the XLA products
+    assert out["agreement"]["common_prefix_tokens"] == [8] * 4
+    assert out["pool_layout_copies"].keys() == {"decode_step",
+                                                "decode_chunked"}
+    # two Mamba blocks: a float32 (4, 16, 16) state and three taps of the
+    # 64 + 2 x 2 x 16 convolution channels; one attention block of 2 K/V
+    # heads of 32; two blocks that keep nothing
+    assert out["state_bytes_per_slot"] == 2 * (4 * 16 * 16 * 4
+                                               + 3 * 128 * 4)
+    assert out["kv_bytes_per_token"] == 2 * 2 * 32 * 4
+    assert out["stateless_blocks"] == 2
+    assert 0.25 < out["held_share_of_choices"] < 0.75
+    json.dumps(out)
+
+
+def test_sublayer_phase_publishes_nemotron_nanos_widths():
+    from perfbench.families import nemotron_h as fam
+
+    sz = fam.sizes(chip_smoke.SUBLAYER)
+    assert (sz["d"], sz["H"], sz["Hkv"], sz["hd"]) == (2688, 32, 2, 128)
+    assert (sz["mh"], sz["mp"], sz["mn"], sz["mg"]) == (64, 64, 128, 8)
+    assert (sz["E"], sz["held"], sz["topk"]) == (128, (0, 64), 6)
+    assert (sz["f"], sz["fs"], sz["route_scale"]) == (1856, 3712, 2.5)
+    assert sz["pattern"] == "M*E"
+
+
 # lines as XLA:TPU prints them (PR 26's parent, layouts and configs
 # kept, operand lists cut): what the count must and must not see
 _CANNED_HLO = """\

@@ -43,6 +43,28 @@ def test_grouped_expert_kernel_compiles_at_the_published_widths(one_chip,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows", (64, 512), ids=("decode-64-slots",
+                                                "prefill-512-tokens"))
+def test_ungated_expert_kernel_compiles_off_the_lane_grid(one_chip, rows):
+    """NVIDIA-Nemotron-3-Nano-30B-A3B's routed experts as one chip holds
+    them: 64 ungated relu^2 experts of 2688 x 1856 (14.5 x 128), both
+    matrices held (1856, 2688), bfloat16. Nothing of a stack's size is
+    made beside it: a (2688, 1856) block compiles too, behind a
+    lane-padded copy of the whole stack every call."""
+    from deeplearning4j_tpu.ops.pallas_moe_experts import moe_experts
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with jax.enable_x64(False):
+        compiled = moe_experts.lower(
+            S((rows, 2688)), S((rows, 64), jnp.float32), None,
+            S((64, 1856, 2688)), S((64, 1856, 2688)),
+            act="relu2").compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
 def _shapes(one_chip):
     def S(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
@@ -218,3 +240,71 @@ def test_decode_step_compiles_at_the_published_widths(one_chip,
     assert "gdn_step" in text
     kept = {"f32[64,96,5760]", "bf16[577,30,128,128]"}
     assert chip_smoke.pool_layout_copies(text, kept) == 0
+
+
+def test_single_sub_layer_decode_step_compiles_at_the_published_widths(
+        one_chip, monkeypatch):
+    """One block of each kind of NVIDIA-Nemotron-3-Nano-30B-A3B at its
+    published widths (64 slots, 576 pages of 128; a Mamba-2 step over 64
+    heads in 8 B/C groups, 32 query heads over 2 K/V heads of 128 on a
+    hidden size of 2688, 64 held experts 1856 wide) through
+    `build_programs`, the three kernel families of its decode path
+    steered on as they are on the chip. The grouped state update stays
+    one fusion that reads and writes the state once, and no state- or
+    pool-shaped copy is left in the program."""
+    import re
+    from types import SimpleNamespace
+
+    import chip_smoke
+    from deeplearning4j_tpu.models.transformer import GPTPlan
+    from deeplearning4j_tpu.ops import (
+        pallas_moe_experts,
+        pallas_paged_attention,
+        pallas_paged_kv_write,
+    )
+    from deeplearning4j_tpu.serving import block_state, decode_programs
+    from perfbench.families import nemotron_h as fam
+
+    for mod in (pallas_moe_experts, pallas_paged_attention,
+                pallas_paged_kv_write):
+        monkeypatch.setattr(mod, "_platform_supported", lambda: True)
+        monkeypatch.setattr(mod, "_probe_verdict", lambda *a, **k: True)
+    monkeypatch.setattr(pallas_moe_experts, "_vmem_limit",
+                        lambda: 112 << 20)
+    sz = fam.sizes(chip_smoke.SUBLAYER)
+    S = _shapes(one_chip)
+    shapes = fam._leaf_shapes(sz)
+    tree = {n: S(shapes[n]) for n in fam.TOP_LEAVES}
+    tree["layers"] = [
+        {n: S(shapes[n], jnp.float32 if n in fam.FLOAT32_LEAVES
+              else jnp.bfloat16) for n in fam.LAYER_LEAVES[kind]}
+        for kind in sz["pattern"]]
+    net = fam.build_net(sz, training=False)
+    net._params = fam.to_program(tree)
+    plan = GPTPlan(net)
+    assert plan.state_kinds() == ["recurrent", "kv", "none"]
+    assert plan.kv_geometry() == [(2, 128)]
+    n_slots, page = 64, 128
+    states = block_state.block_states(plan, SimpleNamespace(
+        n_slots=n_slots, page=page, pool_pages=576, cdt=plan.cdt,
+        kv_quant=None, tp_shard=None, tp_axis=None))
+    with jax.enable_x64(False):
+        programs = decode_programs.build_programs(
+            plan, states, n_slots=n_slots, page=page, L_logical=2048,
+            decode_chunk=4, top_k=0, logprobs=0, tp=None, donate=True)
+        caches = [tuple(S(a.shape, a.dtype) for a in jax.eval_shape(st.alloc))
+                  for st in states]
+        assert caches[2] == ()
+        i32, f32 = jnp.int32, jnp.float32
+        text = programs.decode_step.lower(
+            net._params, caches, S((n_slots, 2048 // page), i32),
+            S((n_slots,), i32), S((n_slots,), i32),
+            S((n_slots, 2), jnp.uint32), S((n_slots,), f32),
+            S((n_slots,), jnp.bool_)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "moe_experts" in text
+    kept = {"f32[64,64,64,128]", "bf16[577,2,128,128]"}
+    assert chip_smoke.pool_layout_copies(text, kept) == 0
+    sweeps = re.findall(r"^\s*%[\w.\-]+ = \(f32\[64,64,64\]\{.*, "
+                        r"f32\[64,64,64,128\]\{.*\) fusion\(", text, re.M)
+    assert len(sweeps) == 1
