@@ -623,6 +623,18 @@ def _hybrid_net(hyb: dict, dtype):
     return net
 
 
+def _experts_read(stats: dict, phase: str) -> dict:
+    """The held experts some live slot chose over the decode steps, and
+    those the grouped product was told to read: one number."""
+    hit, read = stats["moe_experts_hit"], stats["moe_experts_read"]
+    print(f"{phase}: moe_experts_hit {hit} moe_experts_read {read} of "
+          f"{stats['moe_steps'] * stats['moe_experts_held']} held",
+          flush=True)
+    _check(read == hit, f"the grouped product was told to read {read} "
+                        f"held experts where live slots chose {hit}")
+    return {"moe_experts_hit": hit, "moe_experts_read": read}
+
+
 def phase_hybrid(hyb: dict, shape: dict, *, kernels: bool,
                  dtype=None) -> dict:
     import jax.numpy as jnp
@@ -645,7 +657,8 @@ def phase_hybrid(hyb: dict, shape: dict, *, kernels: bool,
            "prefill_chunks": stats["prefill_chunks"],
            "decode_steps": stats["decode_steps"],
            "state_bytes_per_slot": stats["state_bytes_per_slot"],
-           "held_share_of_choices": round(share, 4)}
+           "held_share_of_choices": round(share, 4),
+           **_experts_read(stats, "hybrid")}
     gc.collect()
 
     # the same prompts with the experts as XLA batched products
@@ -819,7 +832,8 @@ def phase_sublayer(sub: dict, shape: dict, *, kernels: bool,
            "state_bytes_per_slot": stats["state_bytes_per_slot"],
            "kv_bytes_per_token": stats["kv_bytes_per_token"],
            "stateless_blocks": stats["stateless_blocks"],
-           "held_share_of_choices": round(share, 4)}
+           "held_share_of_choices": round(share, 4),
+           **_experts_read(stats, "sublayer")}
     gc.collect()
 
     # a bucketed and the chunked prompt against the plain reference's

@@ -556,8 +556,9 @@ class MoEFeedForward(_Kind):
 
     def forward(self, p, x, count_mask=None):
         """`x` (..., d) -> (y, counts): with `count_mask` (one bool a
-        token), how many masked-in tokens chose each held expert; else
-        None."""
+        token: the rows anyone will read), how many masked-in tokens
+        chose each held expert and whether it was read, (2, held); else
+        None (`parallel.experts.dropless_moe`)."""
         from deeplearning4j_tpu.parallel.experts import (
             dropless_moe,
             gated_mlp,

@@ -17,13 +17,30 @@ XLA copy the whole stack into a lane-padded layout before every call
 probe's key and `kernel_verdicts()` row name it. The grid runs
 over (token tiles, experts): one grid step brings one whole expert
 (its three, or two, (d, f)-sized matrices) into VMEM, while the token tile and a
-float32 accumulator stay resident, so each held expert's weights cross
-HBM once per token tile: the least a decode step can move when every
-held expert is chosen by some slot, which at 64 slots x top-10 of 72 is
-every step. Every token meets every held expert (the gate weighs the
-result), so the kernel does `E / hit` times the multiply-adds a sorted
-grouped product would: at decode sizes the weights' bytes bound it, not
-the MXU. As XLA batched einsums the same product materialises the
+float32 accumulator stay resident, so an expert's weights cross HBM once
+per token tile.
+
+The walk is hit-first. `hit` (E,) bool says which held experts some row
+that matters chose (`parallel.experts.dropless_moe`: a gate that is not
+zero on a live row). A Pallas TPU grid is static, so the expert axis
+keeps its `E` steps, but the block indices of the weights and of the
+gate column come from a scalar-prefetched vector: step `j` names the
+`j`-th hit expert in the experts' own order, and every step past the
+last hit one names that one again, which copies nothing (a block whose
+index is the previous step's stays where it is) and computes nothing.
+So the kernel moves the matrices of the experts that were hit and no
+others: the least a decode step can move, whether every held expert is
+chosen (64 slots x top-10 of 72: every step) or a fifth of them are not
+(64 slots x top-6 of 128 with tokens that choose alike). An expert left
+out added exactly 0.0 to every row that matters, and the hit ones are
+summed in the order they always were: those rows come out bit for bit
+as from a walk over all `E`. A row that does not matter (an inactive
+slot's) loses what the skipped experts would have added to it.
+
+Every token meets every hit expert (the gate weighs the result), so the
+kernel does as many times the multiply-adds of a sorted grouped product
+as experts are hit for each one a token chose: at decode sizes the
+weights' bytes bound it, not the MXU. As XLA batched einsums the same product materialises the
 (E, N, f) intermediates in HBM.
 
 Dispatch rides `ops/kernel_dispatch.py` under the family name
@@ -51,9 +68,10 @@ GATED_SILU, RELU2 = "gated_silu", "relu2"  # the experts' activations
 _MAX_ROWS = 512         # token rows per tile
 
 
-def _experts_kernel(x_ref, g_ref, *refs, act: str):
+def _experts_kernel(walk_ref, n_hit_ref, x_ref, g_ref, *refs, act: str):
     from jax.experimental import pallas as pl
 
+    del walk_ref  # the index maps read it
     *w_refs, o_ref, acc_ref = refs
     e = pl.program_id(1)
 
@@ -61,20 +79,22 @@ def _experts_kernel(x_ref, g_ref, *refs, act: str):
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]
-    if act == RELU2:
-        wu_ref, wd_ref = w_refs
-        u = jax.lax.dot_general(x, wu_ref[0], (((1,), (1,)), ((), ())),
+    @pl.when(e < n_hit_ref[0])
+    def _():
+        x = x_ref[...]
+        if act == RELU2:
+            wu_ref, wd_ref = w_refs
+            u = jax.lax.dot_general(x, wu_ref[0], (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            u = jnp.maximum(u, 0.0)
+            h = u * u * g_ref[0]
+        else:
+            wg_ref, wu_ref, wd_ref = w_refs
+            g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+            u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+            h = g * jax.nn.sigmoid(g) * u * g_ref[0]
+        acc_ref[...] += jnp.dot(h.astype(x.dtype), wd_ref[0],
                                 preferred_element_type=jnp.float32)
-        u = jnp.maximum(u, 0.0)
-        h = u * u * g_ref[0]
-    else:
-        wg_ref, wu_ref, wd_ref = w_refs
-        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-        h = g * jax.nn.sigmoid(g) * u * g_ref[0]
-    acc_ref[...] += jnp.dot(h.astype(x.dtype), wd_ref[0],
-                            preferred_element_type=jnp.float32)
 
     @pl.when(e == pl.num_programs(1) - 1)
     def _():
@@ -92,11 +112,35 @@ def _row_tile(n: int) -> int:
     return 0
 
 
+def hit_first_walk(hit):
+    """`hit` (E,) bool -> (walk (E,) int32, n_hit (1,) int32): `walk[j]`
+    is the `j`-th hit expert in the experts' own order, and past the
+    last hit one that one again (with none hit, expert E - 1 all
+    along). Two (E, E) compare-and-count reductions: no sort, no
+    scatter, no gather; int32 under x64 too (Mosaic takes 32-bit block
+    indices only)."""
+    E = hit.shape[0]
+    e = jnp.arange(E, dtype=jnp.int32)
+    # hit experts among 0..e
+    upto = jnp.sum(hit[None, :] & (e[None, :] <= e[:, None]), axis=1,
+                   dtype=jnp.int32)
+    n_hit = upto[-1:]
+    # step j wants the expert at which that count first reaches j + 1:
+    # as many experts as lie before it; counted over the first E - 1,
+    # which is all that can, and keeps the index inside E when none does
+    want = jnp.minimum(e + 1, jnp.maximum(n_hit, 1))
+    walk = jnp.sum(upto[None, :-1] < want[:, None], axis=1, dtype=jnp.int32)
+    return walk, n_hit
+
+
 # jitted so that a step over many layers traces and lowers the kernel
 # once and calls it once a layer (`pallas_paged_kv_write`'s lesson)
 @functools.partial(jax.jit, static_argnames=("act", "interpret"))
-def moe_experts(x, gates, Wg, Wu, Wd, *, act: str = GATED_SILU,
+def moe_experts(x, gates, Wg, Wu, Wd, hit, *, act: str = GATED_SILU,
                 interpret: bool = False):
+    """The grouped product over the experts `hit` (E,) bool marks; the
+    caller marks every expert whose gate is not zero on a row it will
+    read."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -105,25 +149,29 @@ def moe_experts(x, gates, Wg, Wu, Wd, *, act: str = GATED_SILU,
     tn = _row_tile(N)
     # (E, N, 1): one expert's gate column arrives as a (tn, 1) block
     g3 = jnp.swapaxes(gates.astype(jnp.float32), 0, 1)[..., None]
-    whole = lambda *shape: pl.BlockSpec((1,) + shape,
-                                        lambda n, e: (e, 0, 0))
+    whole = lambda *shape: pl.BlockSpec(
+        (1,) + shape, lambda n, e, walk, n_hit: (walk[e], 0, 0))
     weights, specs = ((Wu, Wd), [whole(f, d), whole(f, d)]) \
         if act == RELU2 else \
         ((Wg, Wu, Wd), [whole(d, f), whole(d, f), whole(f, d)])
+    tile = pl.BlockSpec((tn, d), lambda n, e, walk, n_hit: (n, 0))
     return pl.pallas_call(
         functools.partial(_experts_kernel, act=act),
-        grid=(N // tn, E),
-        in_specs=[pl.BlockSpec((tn, d), lambda n, e: (n, 0)),
-                  pl.BlockSpec((1, tn, 1), lambda n, e: (e, n, 0)),
-                  *specs],
-        out_specs=pl.BlockSpec((tn, d), lambda n, e: (n, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N // tn, E),
+            in_specs=[tile,
+                      pl.BlockSpec((1, tn, 1), lambda n, e, walk, n_hit:
+                                   (walk[e], n, 0)),
+                      *specs],
+            out_specs=tile,
+            scratch_shapes=[pltpu.VMEM((tn, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((N, d), x.dtype),
-        scratch_shapes=[pltpu.VMEM((tn, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
-    )(x, g3, *weights)
+    )(*hit_first_walk(hit), x, g3, *weights)
 
 
 def vmem_bytes_estimate(tn: int, d: int, f: int, dtype,
@@ -160,7 +208,9 @@ def _eager_probe(dtype, tn: int, d: int, f: int, act: str) -> bool:
     Wd = jnp.asarray(rng.standard_normal((E, f, d)) / f ** 0.5, dtype)
     gates = jnp.asarray(np.stack([rng.random(tn), np.zeros(tn)], 1),
                         jnp.float32)
-    got = np.asarray(moe_experts(x, gates, Wg, Wu, Wd, act=act), np.float32)
+    got = np.asarray(moe_experts(x, gates, Wg, Wu, Wd,
+                                 jnp.any(gates != 0, axis=0), act=act),
+                     np.float32)
     want = np.asarray(grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act),
                       np.float32)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
@@ -171,7 +221,7 @@ def _eager_probe(dtype, tn: int, d: int, f: int, act: str) -> bool:
     return True
 
 
-def moe_experts_or_none(x, gates, Wg, Wu, Wd, act: str = GATED_SILU):
+def moe_experts_or_none(x, gates, Wg, Wu, Wd, hit, act: str = GATED_SILU):
     """Dispatch probe: the grouped product, or None when the kernel
     cannot serve this call (CPU backend, kill switch, a dtype Mosaic
     does not tile, a width off the tile grid of the axis it lies on,
@@ -205,7 +255,7 @@ def moe_experts_or_none(x, gates, Wg, Wu, Wd, act: str = GATED_SILU):
                           (dtype, tn, d, f, act)):
         return None
     try:
-        return moe_experts(x, gates, Wg, Wu, Wd, act=act)
+        return moe_experts(x, gates, Wg, Wu, Wd, hit, act=act)
     except Exception as e:  # per-shape staging failure: fall back
         _record_decline(FAMILY, key, f"staging at {x.shape}: "
                                      f"{type(e).__name__}: {e}")

@@ -340,16 +340,18 @@ def grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act: str = GATED_SILU):
     return y.astype(x.dtype)
 
 
-def grouped_expert_ffn(x, gates, Wg, Wu, Wd, act: str = GATED_SILU):
+def grouped_expert_ffn(x, gates, Wg, Wu, Wd, hit, act: str = GATED_SILU):
     """The grouped product behind the kernel-dispatch contract: the
-    Pallas kernel of `ops/pallas_moe_experts.py` on a TPU (each held
-    expert's weights streamed through VMEM once), the batched XLA
-    products elsewhere."""
+    Pallas kernel of `ops/pallas_moe_experts.py` on a TPU (the weights
+    of each expert that `hit` (E,) bool marks streamed through VMEM
+    once, the others left in HBM), the batched XLA products over every
+    expert elsewhere. A row whose gate is not zero on an unmarked
+    expert comes out without that expert's part."""
     from deeplearning4j_tpu.ops.pallas_moe_experts import (
         moe_experts_or_none,
     )
 
-    out = moe_experts_or_none(x, gates, Wg, Wu, Wd, act)
+    out = moe_experts_or_none(x, gates, Wg, Wu, Wd, hit, act)
     return grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act) \
         if out is None else out
 
@@ -375,16 +377,24 @@ def dropless_moe(x, router, Wg, Wu, Wd, *, top_k: int, experts_held,
     """Top-k dropless routing over `router.shape[1]` experts, computed
     for the experts held. `x` (N, d). `act` and the matrices as
     `grouped_expert_ffn_xla`; `router_bias` and `routed_scale` as
-    `held_gates`. Returns (y (N, d), counts): with a `count_mask` (N,)
-    bool, `counts` is an int32 (count,) vector, how many of the
-    masked-in tokens chose each held expert; else None."""
+    `held_gates`. `count_mask` (N,) bool says which rows anyone will
+    read (a decode step's active slots; None: all): an expert that none
+    of them chose is not read, and a masked-out row comes out without
+    it. Returns (y (N, d), counts): with a `count_mask`, `counts` is
+    int32 (2, count), how many of the masked-in tokens chose each held
+    expert, and whether the grouped product was told to read it; else
+    None."""
     with jax.named_scope("moe.route"):
         logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
         gates = held_gates(logits, top_k, experts_held, bias=router_bias,
                            scale=routed_scale)
+        # both routers' gates are >= 0: not zero is chosen
+        chose = gates != 0
+        if count_mask is not None:
+            chose &= count_mask[:, None]
+        hit = jnp.any(chose, axis=0)
     with jax.named_scope("moe.experts"):
-        y = grouped_expert_ffn(x, gates, Wg, Wu, Wd, act)
+        y = grouped_expert_ffn(x, gates, Wg, Wu, Wd, hit, act)
     if count_mask is None:
         return y, None
-    chose = (gates > 0) & count_mask[:, None]
-    return y, jnp.sum(chose, axis=0).astype(jnp.int32)
+    return y, jnp.stack([jnp.sum(chose, axis=0), hit]).astype(jnp.int32)

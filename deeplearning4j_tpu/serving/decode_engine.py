@@ -627,12 +627,14 @@ class DecodeEngine:
         self.prefix_hit_tokens = 0  # guarded by: _cond
         # routed experts and recurrent state (composed blocks): top-k
         # choices made by active slots in decode steps, those that fell
-        # on experts held here, held experts hit (summed over blocks
-        # and steps), the steps counted, and slot states overwritten
-        # from zeros at admission
+        # on experts held here, held experts hit and held experts the
+        # grouped product was told to read (both summed over blocks and
+        # steps), the steps counted, and slot states overwritten from
+        # zeros at admission
         self.moe_routed = 0  # guarded by: _cond
         self.moe_held_choices = 0  # guarded by: _cond
         self.moe_experts_hit = 0  # guarded by: _cond
+        self.moe_experts_read = 0  # guarded by: _cond
         self.moe_steps = 0  # guarded by: _cond
         self.state_resets = 0  # guarded by: _cond
         self.spec_steps = 0  # guarded by: _cond
@@ -1741,10 +1743,12 @@ class DecodeEngine:
                "stateless_blocks": self._blocks_by_kind["none"],
                # routed experts, decode steps only: top-k choices of
                # active slots, those on experts held here, held experts
-               # hit (summed over blocks and steps) and the steps
+               # hit and held experts the grouped product was told to
+               # read (summed over blocks and steps) and the steps
                "moe_routed": self.moe_routed,
                "moe_held_choices": self.moe_held_choices,
                "moe_experts_hit": self.moe_experts_hit,
+               "moe_experts_read": self.moe_experts_read,
                "moe_steps": self.moe_steps,
                "moe_experts_held": self._n_held * self._moe_blocks,
                # tensor-parallel tier: degree 1 when off, so dashboards
@@ -3088,15 +3092,17 @@ class DecodeEngine:
 
     # graftlint: hot-loop
     def _count_experts(self, counts, n_live: int) -> None:
-        """One dispatch's routing counts (`step_math`): (..., 2, held),
-        choices that fell on each held expert and in how many blocks
-        each was hit, for one step or a chunk of them."""
-        counts = np.asarray(counts).reshape(-1, 2, self._n_held)
+        """One dispatch's routing counts (`step_math`): (..., 3, held),
+        choices that fell on each held expert, in how many blocks each
+        was hit and in how many the grouped product was told to read
+        it, for one step or a chunk of them."""
+        counts = np.asarray(counts).reshape(-1, 3, self._n_held)
         with self._cond:
             self.moe_routed += counts.shape[0] * n_live \
                 * self._moe_top_k * self._moe_blocks
             self.moe_held_choices += int(counts[:, 0].sum())
             self.moe_experts_hit += int(counts[:, 1].sum())
+            self.moe_experts_read += int(counts[:, 2].sum())
             self.moe_steps += counts.shape[0]
 
     # graftlint: hot-loop

@@ -144,11 +144,12 @@ def build_programs(plan, states, *, n_slots: int, page: int,
         if K:
             out += (token_logprobs(logits, nxt, K),)
         if n_held:
-            # (2, held): choices that fell on each held expert,
-            # summed over blocks, and in how many blocks it was hit
-            c = jnp.stack(d.counts)
-            out += (jnp.stack([c.sum(0), (c > 0).sum(0)])
-                    .astype(jnp.int32),)
+            # (3, held): choices that fell on each held expert,
+            # summed over blocks, in how many blocks it was hit, and
+            # in how many the grouped product was told to read it
+            chosen, read = jnp.stack(d.counts, axis=1)
+            out += (jnp.stack([chosen.sum(0), (chosen > 0).sum(0),
+                               read.sum(0)]).astype(jnp.int32),)
         return out
 
     # the chunk scans the step's body, not the jitted program the

@@ -960,11 +960,12 @@ DECODE_ENGINE_STATS_KEYS = frozenset({
     # state + convolution tails), slot states overwritten at admission,
     # blocks by the kind of cache they keep (none: a block without a
     # mixer), and the routed experts' decode-step counts (choices made,
-    # choices on experts held here, held experts hit, steps, experts
-    # held)
+    # choices on experts held here, held experts hit, held experts the
+    # grouped product was told to read, steps, experts held)
     "state_bytes_per_slot", "state_resets", "recurrent_blocks",
     "kv_blocks", "stateless_blocks", "moe_routed",
-    "moe_held_choices", "moe_experts_hit", "moe_steps", "moe_experts_held",
+    "moe_held_choices", "moe_experts_hit", "moe_experts_read", "moe_steps",
+    "moe_experts_held",
     # tensor-parallel tier: mesh degree (1 = single-device engine, so
     # capacity dashboards never branch on key presence) and the
     # per-shard slice of kv_bytes_per_token — each device's actual

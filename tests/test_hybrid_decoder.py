@@ -211,7 +211,9 @@ def test_a_router_that_sends_every_token_to_one_expert_loses_none():
     p["router"] = p["router"].at[:, 2].set(3.0).at[:, 5].set(2.0) \
         .at[:, 0].set(1.0)
     y, counts = ffn.forward(p, x, jnp.ones((40,), bool))
-    np.testing.assert_array_equal(counts, [40, 0, 40, 0, 0, 40, 0, 0])
+    # how many tokens chose each held expert, and whether it was read
+    np.testing.assert_array_equal(counts, [[40, 0, 40, 0, 0, 40, 0, 0],
+                                           [1, 0, 1, 0, 0, 1, 0, 0]])
     # every token got its three experts' gated outputs: rebuild them
     from deeplearning4j_tpu.parallel.experts import gated_mlp, topk_gates
 
@@ -237,7 +239,8 @@ def test_grouped_product_kernel_equals_the_batched_products():
     Wg, Wu = (jax.random.normal(k[i], (E, d, f)) / 11 for i in (1, 2))
     Wd = jax.random.normal(k[3], (E, f, d)) / 11
     gates = held_gates(jax.random.normal(k[4], (N, 8)), 2, (2, 4))
-    got = moe_experts(x, gates, Wg, Wu, Wd, interpret=True)
+    got = moe_experts(x, gates, Wg, Wu, Wd, jnp.any(gates != 0, axis=0),
+                      interpret=True)
     np.testing.assert_allclose(
         got, grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd), atol=1e-5)
 
@@ -288,6 +291,8 @@ def test_engine_prefill_and_decode_equal_the_reference(model, t0, kw):
     assert st["moe_routed"] == 12 * 2 * 3
     assert 0 < st["moe_held_choices"] <= st["moe_routed"]
     assert st["moe_experts_hit"] <= st["moe_held_choices"]
+    # two of the three slots stand empty: what only they chose is not read
+    assert st["moe_experts_read"] == st["moe_experts_hit"]
     assert st["moe_experts_held"] == 12
 
 
@@ -335,6 +340,46 @@ def test_concurrent_requests_do_not_touch_each_others_state(model):
                 [e["logprob"] for e in want["logprobs"]], atol=2e-5)
     finally:
         eng.shutdown(drain_timeout=30.0)
+
+
+def test_a_batch_served_through_the_kernel(model, monkeypatch):
+    """Three requests of different lengths, so that slots stand empty
+    while others decode, with the grouped product as the kernel a TPU
+    would dispatch (interpreted): told what the live slots chose, and
+    told to read every held expert, as it did before it was told
+    anything. Both serve the batched products' tokens, and each other's
+    logprobs to the bit: an expert no live slot chose adds exactly 0.0
+    to a live slot's row."""
+    from deeplearning4j_tpu.ops import pallas_moe_experts as pme
+
+    def batch():
+        eng = DecodeEngine(model[3], **ENGINE)
+        try:
+            reqs = [eng.submit(_ids(n, seed=20 + n), m, logprobs=4)
+                    for n, m in ((7, 5), (19, 14), (33, 9))]
+            toks = [list(r.result(timeout=120.0)) for r in reqs]
+            return toks, [[e["logprob"] for e in r.logprob_values]
+                          for r in reqs], eng.stats()
+        finally:
+            eng.shutdown(drain_timeout=30.0)
+
+    def through(mark):
+        monkeypatch.setattr(
+            pme, "moe_experts_or_none",
+            lambda x, gates, Wg, Wu, Wd, hit, act=pme.GATED_SILU:
+            pme.moe_experts(x, gates, Wg, Wu, Wd, mark(hit), act=act,
+                            interpret=True))
+        return batch()
+
+    want_toks, want_lps, _ = batch()
+    every_toks, every_lps, _ = through(jnp.ones_like)
+    toks, lps, st = through(lambda hit: hit)
+    assert toks == every_toks == want_toks
+    assert lps == every_lps
+    for got, want in zip(lps, want_lps):
+        np.testing.assert_allclose(got, want, atol=2e-5)
+    assert st["moe_experts_read"] == st["moe_experts_hit"] \
+        < st["moe_steps"] * st["moe_experts_held"]
 
 
 def test_preemption_by_replay_gives_the_same_tokens(model):

@@ -1,0 +1,142 @@
+"""The grouped expert kernel's hit-first walk (`ops/pallas_moe_experts`),
+in interpret mode, both variants: it reads the experts it is told were
+hit and no others, rows that chose only hit experts come out bit for bit
+as from a walk over every expert, and `dropless_moe` tells it what the
+rows that count chose. Also the benchmark's reader of the counter that
+says so."""
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.ops import pallas_moe_experts as pme
+from deeplearning4j_tpu.parallel import experts
+
+N, D, F, E = 16, 128, 40, 6
+VARIANTS = (pme.GATED_SILU, pme.RELU2)
+
+
+def _weights(act, seed=0, n_experts=E):
+    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+    up = (n_experts, F, D) if act == pme.RELU2 else (n_experts, D, F)
+    Wg = None if act == pme.RELU2 else \
+        jax.random.normal(k[0], up, jnp.float32) / 11
+    Wu = jax.random.normal(k[1], up, jnp.float32) / 11
+    Wd = jax.random.normal(k[2], (n_experts, F, D), jnp.float32) / F ** 0.5
+    return jax.random.normal(k[3], (N, D), jnp.float32), Wg, Wu, Wd
+
+
+def _gates(unchosen, seed=1):
+    """Every row weighs every expert but the `unchosen` ones."""
+    g = jax.random.uniform(jax.random.PRNGKey(seed), (N, E), jnp.float32,
+                           0.1, 1.0)
+    return g.at[:, list(unchosen)].set(0.0)
+
+
+def _through_the_kernel(monkeypatch):
+    """What a TPU would dispatch, interpreted."""
+    monkeypatch.setattr(
+        pme, "moe_experts_or_none",
+        lambda x, gates, Wg, Wu, Wd, hit, act=pme.GATED_SILU:
+        pme.moe_experts(x, gates, Wg, Wu, Wd, hit, act=act, interpret=True))
+
+
+@pytest.mark.parametrize("hit", [
+    (0, 1, 0, 1), (0, 0, 0, 0), (1, 1, 1, 1), (1, 0, 0, 0), (0, 0, 0, 1),
+    (0, 1, 1, 0, 0, 1, 0), (1,), (0,),
+], ids=lambda h: "".join(map(str, h)))
+def test_the_walk_names_the_hit_experts_first_and_in_order(hit):
+    walk, n_hit = pme.hit_first_walk(jnp.asarray(hit, bool))
+    idx = np.flatnonzero(hit)
+    # past the last hit expert that one again, so that nothing is copied
+    want = np.full(len(hit), idx[-1] if len(idx) else len(hit) - 1)
+    want[:len(idx)] = idx
+    np.testing.assert_array_equal(walk, want)
+    assert n_hit.tolist() == [len(idx)]
+    # Mosaic takes 32-bit block indices only, and the suite runs under x64
+    assert walk.dtype == n_hit.dtype == jnp.int32
+
+
+@pytest.mark.parametrize("unchosen", [(), (2,), (0, 3, 5), tuple(range(E))],
+                         ids=["none", "one", "some", "all"])
+@pytest.mark.parametrize("act", VARIANTS)
+def test_the_walk_equals_the_batched_products(act, unchosen):
+    x, Wg, Wu, Wd = _weights(act)
+    gates = _gates(unchosen)
+    hit = jnp.any(gates != 0, axis=0)
+    assert int(hit.sum()) == E - len(unchosen)
+    got = pme.moe_experts(x, gates, Wg, Wu, Wd, hit, act=act,
+                          interpret=True)
+    want = experts.grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # an expert left out added exactly 0.0: bit for bit the walk over all
+    every = pme.moe_experts(x, gates, Wg, Wu, Wd, jnp.ones(E, bool),
+                            act=act, interpret=True)
+    assert np.array_equal(got, every)
+    if len(unchosen) == E:
+        assert not np.any(np.asarray(got))
+    else:
+        assert float(jnp.max(jnp.abs(want))) > 0.1
+
+
+@pytest.mark.parametrize("act", VARIANTS)
+def test_an_unmarked_expert_is_not_read(act):
+    """The kernel believes `hit`, not the gates: poison in an unmarked
+    expert's matrices reaches no row, where the walk over all spreads
+    it."""
+    x, Wg, Wu, Wd = _weights(act)
+    gates = _gates((2,))
+    Wd = Wd.at[2].set(jnp.nan)
+    hit = jnp.any(gates != 0, axis=0)
+    got = pme.moe_experts(x, gates, Wg, Wu, Wd, hit, act=act,
+                          interpret=True)
+    assert np.all(np.isfinite(got))
+    every = pme.moe_experts(x, gates, Wg, Wu, Wd, jnp.ones(E, bool),
+                            act=act, interpret=True)
+    assert np.all(np.isnan(every))
+
+
+@pytest.mark.parametrize("act", VARIANTS)
+def test_rows_nobody_reads_choose_nothing_for_the_kernel(act, monkeypatch):
+    """A decode step's inactive slots route like any row; what only they
+    chose is not read, the live rows come out bit for bit as without the
+    mask, and the counts say what was read."""
+    _through_the_kernel(monkeypatch)
+    n_experts, live_rows = 8, 3
+    x, Wg, Wu, Wd = _weights(act, seed=3, n_experts=n_experts)
+    router = jax.random.normal(jax.random.PRNGKey(7), (D, n_experts))
+    kw = dict(top_k=1, experts_held=(0, n_experts), act=act)
+    live = jnp.arange(N) < live_rows
+    y, counts = experts.dropless_moe(x, router, Wg, Wu, Wd,
+                                     count_mask=live, **kw)
+    every, none = experts.dropless_moe(x, router, Wg, Wu, Wd, **kw)
+    assert none is None
+    chosen, read = np.asarray(counts)
+    gates = experts.held_gates(x @ router, 1, (0, n_experts))
+    np.testing.assert_array_equal(
+        chosen, np.sum(np.asarray(gates)[:live_rows] != 0, axis=0))
+    np.testing.assert_array_equal(read, chosen > 0)
+    # the dead rows chose experts that no live row did
+    assert read.sum() < np.any(np.asarray(gates) != 0, axis=0).sum()
+    assert np.array_equal(y[:live_rows], every[:live_rows])
+    assert not np.array_equal(y[live_rows:], every[live_rows:])
+    np.testing.assert_allclose(
+        every, experts.grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act),
+        atol=2e-5)
+
+
+@pytest.mark.parametrize("stats,want", [
+    ({"moe_steps": 5, "moe_experts_read": 5 * 3 * 48}, 75.0),
+    ({"moe_steps": 5}, None),                 # a program without it
+    ({"moe_steps": 0, "moe_experts_read": 0}, None),
+], ids=["read", "no-counter", "no-steps"])
+def test_the_benchmark_reads_the_share_told_to_be_read(stats, want):
+    from perfbench.harness.manifest import Manifest
+
+    read = Manifest().reader("moe.experts_read_pct.chat")
+    after = dict(stats, moe_experts_held=3 * 64)
+    run = SimpleNamespace(facts={"stats_before": {k: 0 for k in stats},
+                                 "stats_after": after})
+    assert read(run) == want

@@ -361,7 +361,8 @@ def test_the_layer_equals_its_experts_one_by_one():
     x = jax.random.normal(jax.random.PRNGKey(5), (40, 32))
     y, counts = ffn.forward(p, x, jnp.ones((40,), bool))
     gates = _hand_router(x @ p["router"], np.asarray(p["router_b"]), 3, 2.5)
-    np.testing.assert_array_equal(counts, (gates > 0).sum(0))
+    chosen = (gates > 0).sum(0)
+    np.testing.assert_array_equal(counts, [chosen, chosen > 0])
     want = experts.relu2_mlp(x, p["sWu"], p["sWd"])
     for e in range(8):
         want = want + gates[:, e:e + 1] * experts.relu2_mlp(
@@ -383,7 +384,8 @@ def test_ungated_kernel_equals_the_batched_products(f):
     gates = experts.held_gates(jax.random.normal(k[3], (N, 8)), 2, (2, 4),
                                bias=0.2 * jax.random.normal(k[4], (8,)),
                                scale=2.5)
-    got = moe_experts(x, gates, None, Wu, Wd, act="relu2", interpret=True)
+    got = moe_experts(x, gates, None, Wu, Wd, jnp.any(gates != 0, axis=0),
+                      act="relu2", interpret=True)
     want = experts.grouped_expert_ffn_xla(x, gates, None, Wu, Wd, "relu2")
     np.testing.assert_allclose(got, want, atol=2e-5)
     assert float(jnp.max(jnp.abs(want))) > 0.1
@@ -402,13 +404,13 @@ def test_the_dispatch_tells_the_variants_apart(monkeypatch):
     monkeypatch.setattr(pme, "_record_decline",
                         lambda fam_, key, msg: seen.append(("no", key)))
     S = lambda *s: jnp.zeros(s, jnp.bfloat16)
-    x, g = S(64, 256), jnp.zeros((64, 4), jnp.float32)
+    x, g, hit = S(64, 256), jnp.zeros((64, 4), jnp.float32), jnp.ones(4, bool)
     pme.moe_experts_or_none(x, g, S(4, 256, 1856), S(4, 256, 1856),
-                            S(4, 1856, 256))
+                            S(4, 1856, 256), hit)
     pme.moe_experts_or_none(x, g, None, S(4, 1856, 256), S(4, 1856, 256),
-                            "relu2")
+                            hit, "relu2")
     pme.moe_experts_or_none(x, g, S(4, 256, 768), S(4, 256, 768),
-                            S(4, 768, 256))
+                            S(4, 768, 256), hit)
     assert seen == [("no", ("bfloat16", 64, 256, 1856)),
                     ("bfloat16", 64, 256, 1856, "relu2"),
                     ("bfloat16", 64, 256, 768)]
@@ -507,6 +509,8 @@ def test_engine_prefill_and_decode_equal_the_reference(model, t0, kw):
     # every expert held
     assert st["moe_routed"] == st["moe_held_choices"] == 12 * 2 * 2
     assert st["moe_experts_hit"] == st["moe_held_choices"]
+    # two of the three slots stand empty: what only they chose is not read
+    assert st["moe_experts_read"] == st["moe_experts_hit"]
     assert st["moe_experts_held"] == 2 * 8
 
 
@@ -573,6 +577,46 @@ def test_concurrent_requests_do_not_touch_each_others_state(model):
         assert loop["ahead_n"] > 0 and loop["overshoot_tokens"] == 0
     finally:
         eng.shutdown(drain_timeout=30.0)
+
+
+def test_a_batch_served_through_the_kernel(model, monkeypatch):
+    """Three requests of different lengths, so that slots stand empty
+    while others decode, with the grouped product as the kernel a TPU
+    would dispatch (interpreted): told what the live slots chose, and
+    told to read every held expert, as it did before it was told
+    anything. Both serve the batched products' tokens, and each other's
+    logprobs to the bit: an expert no live slot chose adds exactly 0.0
+    to a live slot's row."""
+    from deeplearning4j_tpu.ops import pallas_moe_experts as pme
+
+    def batch():
+        eng = DecodeEngine(model[3], **ENGINE)
+        try:
+            reqs = [eng.submit(_ids(n, seed=20 + n), m, logprobs=4)
+                    for n, m in ((7, 5), (19, 14), (33, 9))]
+            toks = [list(r.result(timeout=120.0)) for r in reqs]
+            return toks, [[e["logprob"] for e in r.logprob_values]
+                          for r in reqs], eng.stats()
+        finally:
+            eng.shutdown(drain_timeout=30.0)
+
+    def through(mark):
+        monkeypatch.setattr(
+            pme, "moe_experts_or_none",
+            lambda x, gates, Wg, Wu, Wd, hit, act=pme.GATED_SILU:
+            pme.moe_experts(x, gates, Wg, Wu, Wd, mark(hit), act=act,
+                            interpret=True))
+        return batch()
+
+    want_toks, want_lps, _ = batch()
+    every_toks, every_lps, _ = through(jnp.ones_like)
+    toks, lps, st = through(lambda hit: hit)
+    assert toks == every_toks == want_toks
+    assert lps == every_lps
+    for got, want in zip(lps, want_lps):
+        np.testing.assert_allclose(got, want, atol=2e-5)
+    assert st["moe_experts_read"] == st["moe_experts_hit"] \
+        < st["moe_steps"] * st["moe_experts_held"]
 
 
 @pytest.mark.parametrize("kw,what", [

@@ -34,12 +34,13 @@ def test_grouped_expert_kernel_compiles_at_the_published_widths(one_chip,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     # the suite runs with x64 on (conftest); the chip's processes do not,
-    # and Mosaic takes 32-bit block indices only
+    # and Mosaic takes 32-bit block indices only (the hit-first walk's
+    # prefetched indices are int32 either way)
     with jax.enable_x64(False):
         compiled = moe_experts.lower(
             S((rows, 4096)), S((rows, 36), jnp.float32),
             S((36, 4096, 768)), S((36, 4096, 768)),
-            S((36, 768, 4096))).compile()
+            S((36, 768, 4096)), S((36,), jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -60,7 +61,7 @@ def test_ungated_expert_kernel_compiles_off_the_lane_grid(one_chip, rows):
         compiled = moe_experts.lower(
             S((rows, 2688)), S((rows, 64), jnp.float32), None,
             S((64, 1856, 2688)), S((64, 1856, 2688)),
-            act="relu2").compile()
+            S((64,), jnp.bool_), act="relu2").compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
 
