@@ -166,34 +166,14 @@ def _hbm_in_use(n_devices: int = 1) -> list:
             for d in jax.devices()[:n_devices]]
 
 
-class CompileMeter:
+def _compile_reading(account) -> dict:
     """Backend-compile seconds and persistent-cache hits/misses of this
-    process, from JAX's own monitoring events."""
-
-    def __init__(self):
-        import jax.monitoring
-
-        self._lock = threading.Lock()
-        self._n = collections.Counter()  # guarded by: _lock
-        jax.monitoring.register_event_listener(self._on_event)
-        jax.monitoring.register_event_duration_secs_listener(
-            self._on_duration)
-
-    def _on_event(self, event: str, **_kw) -> None:
-        if event.startswith("/jax/compilation_cache/cache_"):
-            with self._lock:
-                self._n[event.rsplit("/", 1)[1]] += 1
-
-    def _on_duration(self, event: str, secs: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            with self._lock:
-                self._n["compile_seconds"] += secs
-
-    def read(self) -> dict:
-        with self._lock:
-            return {"compile_seconds": round(self._n["compile_seconds"], 2),
-                    "cache_hits": self._n["cache_hits"],
-                    "cache_misses": self._n["cache_misses"]}
+    process, from the program's own account of JAX's compile pipeline
+    (`serving.observability.compile_account`)."""
+    c = account.counters()
+    return {"compile_seconds": round(c["backend_s"], 2),
+            "cache_hits": c["cache_hits"],
+            "cache_misses": c["cache_misses"]}
 
 
 def _gpt_net(gpt: dict, max_length: int, block: int = 1024):
@@ -980,6 +960,7 @@ def main(argv=None) -> int:
         verdicts_as_json,
         vmem_limit_for_kind,
     )
+    from deeplearning4j_tpu.serving.observability import compile_account
     from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
 
     names = list(sys.argv[1:] if argv is None else argv) \
@@ -1003,17 +984,17 @@ def main(argv=None) -> int:
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices())}
     cache_dir = enable_compile_cache()
-    meter = CompileMeter()
+    account = compile_account()
     print(f"chip_smoke: {device}, {_versions()}, compile cache {cache_dir}",
           flush=True)
 
     phases: dict = {}
 
     def run(name: str, fn, *args, **kw) -> None:
-        before, t0 = meter.read(), time.perf_counter()
+        before, t0 = _compile_reading(account), time.perf_counter()
         result = fn(*args, **kw)
         gc.collect()  # free the device state just dropped
-        after = meter.read()
+        after = _compile_reading(account)
         result.update(
             ok=True, seconds=round(time.perf_counter() - t0, 1),
             hbm_in_use_after=_hbm_in_use()[0],
@@ -1051,7 +1032,7 @@ def main(argv=None) -> int:
     print(json.dumps({
         "ok": True, "device": device, "versions": _versions(),
         "phases": phases, "kernels": verdicts_as_json(),
-        "compile": dict(meter.read(), cache_dir=cache_dir),
+        "compile": dict(_compile_reading(account), cache_dir=cache_dir),
         "native": {"gxx": shutil.which("g++") is not None,
                    "loaded": native_available()},
         "claim": None}), flush=True)

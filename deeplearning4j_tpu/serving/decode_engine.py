@@ -389,7 +389,9 @@ class DecodeEngine:
         one decode/spec-verify span) ride `_GenRequest.trace`; the
         scheduler thread's own timeline (leaf phases, one set per
         dispatch) goes to `observability.TIMELINE` and, as counters,
-        to ``stats()["loop"]``. All recording is host-side and
+        to ``stats()["loop"]``; `_build`'s phases and JAX's compile
+        pipeline go the same two ways, to ``stats()["build"]`` and the
+        process-wide ``stats()["compile"]``. All recording is host-side and
         kill-switched by ``DL4J_TPU_NO_TRACING=1``; counters stay on.
     quantize : None or ``{"kv": "int8"}`` — the quantized KV tier
         (`serving/quantize.py`): pools allocate int8 elements plus
@@ -591,7 +593,15 @@ class DecodeEngine:
         self.admitted = 0  # guarded by: _cond
         # the scheduler thread's account of its own time; written by
         # that thread alone, read by stats()
-        self._phases = observability.ThreadPhases()
+        self._phases = observability.SchedulerPhases()
+        # set-up's account: `_build`'s own leaf phases, on whichever
+        # thread builds (the constructor's, or the scheduler's at a
+        # swap that rebuilds), and JAX's compile pipeline, which the
+        # whole process shares
+        self._build_phases = observability.ThreadPhases(
+            observability.BUILD_PHASES)
+        self._weight_hash_bytes = 0  # cumulative over builds
+        self._compile = observability.compile_account()
         # dispatches issued and not yet collected, oldest first: at most
         # one decode dispatch and the prefills issued before it while
         # the scheduler blocks in a collect (scheduler-thread-owned)
@@ -706,7 +716,21 @@ class DecodeEngine:
         """(Re)build the compiled prefill/decode machinery and the paged
         device state for `net`. Called at construction and after a
         drained weight swap; jit caches are per-engine closures, so a
-        swap to a differently-shaped net recompiles cleanly."""
+        swap to a differently-shaped net recompiles cleanly.
+
+        The calling thread is in one phase of
+        `observability.BUILD_PHASES` from the first statement to the
+        last (docs/observability.md, "Set-up and rebuilds"); they time
+        the host and add no sync."""
+        ph = self._build_phases
+        ph.begin_iteration()
+        ph.enter("build.plan")
+        try:
+            self._build_in_phases(net, ph)
+        finally:
+            ph.close()
+
+    def _build_in_phases(self, net, ph) -> None:
         import jax
         import jax.numpy as jnp
 
@@ -793,6 +817,7 @@ class DecodeEngine:
         # are equal, the net's own tree. A rebuild drops the old
         # resident trees (the draft's with its decoder) before it makes
         # the new: two of them beside two nets' masters may not fit
+        ph.enter("build.weights")
         self._weights = self._spec = None
         placed = tp.shard_params(net._params) if tp is not None \
             else net._params
@@ -803,6 +828,7 @@ class DecodeEngine:
             if id(x) not in uncast)
         with self._cond:
             self.weight_casts += int(self._weights is not placed)
+        ph.enter("build.plan")
         self._plan = plan
         self._states = states
         self._n_held = n_held
@@ -837,13 +863,19 @@ class DecodeEngine:
         # with the sender's digest and refused typed on mismatch — a
         # page of KV computed under other weights must never re-bind
         # here (and never seed this engine's prefix cache)
+        _leaves = jax.tree_util.tree_leaves(net._params)
+        _nbytes = sum(int(_leaf.nbytes) for _leaf in _leaves)
+        ph.enter("build.weight_hash", bytes=_nbytes)
         _wh = hashlib.blake2b(digest_size=8)
-        for _leaf in jax.tree_util.tree_leaves(net._params):
+        for _leaf in _leaves:
             _arr = np.ascontiguousarray(np.asarray(_leaf))
             _wh.update(str(_arr.dtype).encode())
             _wh.update(str(_arr.shape).encode())
             _wh.update(_arr.tobytes())
         self._weight_version = _wh.hexdigest()
+        with self._cond:
+            self._weight_hash_bytes += _nbytes
+        ph.enter("build.plan")
         # latency tier: prefix cache + speculative decoder are rebuilt
         # with the geometry on every (re)build, so a weight swap always
         # starts them cold — stale pages can never serve new weights
@@ -881,6 +913,7 @@ class DecodeEngine:
                 L_logical=L_logical, pool_pages=pool_pages,
                 top_k=self.top_k, donate=donate, kv_quant=kv_quant,
                 tp=tp, target_weights=self._weights)
+        ph.enter("build.state")
         old = self._pool
         self._pool = PagePool(
             self._cond, n_slots=S, page_size=page, pool_pages=pool_pages,
@@ -1780,7 +1813,14 @@ class DecodeEngine:
                # window
                "loop": self._phases.counters(),
                "queue_wait_s": self.queue_wait_s,
-               "admitted": self.admitted}
+               "admitted": self.admitted,
+               # set-up's account, cumulative over builds: seconds and
+               # spans of each phase of `_build`, and JAX's compile
+               # pipeline (the PROCESS's, not this engine's alone)
+               "build": dict(self._build_phases.counters(),
+                             builds=self._build_phases.iterations,
+                             weight_hash_bytes=self._weight_hash_bytes),
+               "compile": self._compile.counters()}
         if self._prefix_cache is not None:
             hit_pct = (100.0 * self.prefix_hit_tokens / self.prompt_tokens
                        if self.prompt_tokens else 0.0)

@@ -45,6 +45,10 @@ spirit of Dapper-style always-on tracing:
   on under the kill switch. Every phase also opens a
   `jax.profiler.TraceAnnotation("dl4j:<phase>")`, so any profiler
   capture shows the phases beside the XLA op timeline with no knob.
+  Set-up is on the same ring: `DecodeEngine._build` owns a second
+  `ThreadPhases` over `BUILD_PHASES`, and the process's one
+  `CompileAccount` (`compile_account()`) turns JAX's own trace / lower /
+  backend-compile events into `compile.*` spans and cumulative counters.
 
 Hot-path discipline: every recording call is pure host-side arithmetic
 (monotonic reads, int/str attrs, deque appends). Nothing here may
@@ -66,11 +70,12 @@ from contextlib import contextmanager
 from typing import Callable, Dict, List, Optional, Sequence
 
 __all__ = [
-    "Counter", "FlightRecorder", "Gauge", "Histogram", "LEAF_PHASES",
-    "MetricsRegistry", "NULL_TRACE", "Span", "TIMELINE", "ThreadPhases",
-    "Timeline", "Trace", "attach_trace", "current_trace",
-    "graft_remote_trace", "maybe_trace", "new_trace_id", "tracing_enabled",
-    "use_trace", "wire_trace_context",
+    "BUILD_PHASES", "COMPILE_SPANS", "CompileAccount", "Counter",
+    "FlightRecorder", "Gauge", "Histogram", "LEAF_PHASES",
+    "MetricsRegistry", "NULL_TRACE", "SchedulerPhases", "Span", "TIMELINE",
+    "ThreadPhases", "Timeline", "Trace", "attach_trace", "compile_account",
+    "current_trace", "graft_remote_trace", "maybe_trace", "new_trace_id",
+    "tracing_enabled", "use_trace", "wire_trace_context",
 ]
 
 _KILL_ENV = "DL4J_TPU_NO_TRACING"
@@ -810,41 +815,35 @@ LEAF_PHASES = (
     "prefill.dispatch", "prefill.wait", "prefill.deliver",
     "decode.dispatch", "decode.wait", "decode.deliver")
 
+#: what the thread inside `DecodeEngine._build` can be doing, from the
+#: build's first statement to its last (construction, and every weight
+#: swap that rebuilds)
+BUILD_PHASES = (
+    "build.plan", "build.weights", "build.weight_hash", "build.state")
+
 _TraceAnnotation = None
 
 
 class ThreadPhases:
-    """One thread's time, cut into leaf phases. `enter(name)` ends the
-    phase the thread was in and starts the next at the same instant, so
-    phases cannot overlap and leave no gap between the first `enter` and
-    `close`. Each ended phase becomes a span on `timeline`, a
-    `jax.profiler.TraceAnnotation("dl4j:<name>")` while it lasts (a
-    flag test when no profiler session is open) and two cumulative
-    counters, `<name>_s` and `<name>_n`; the kill switch, read once per
-    `begin_iteration`, stops the spans and annotations and leaves the
-    counters.
+    """One thread's time, cut into the leaf phases `phases` names.
+    `enter(name)` ends the phase the thread was in and starts the next
+    at the same instant, so phases cannot overlap and leave no gap
+    between an `enter` and the next `close`. Each ended phase becomes a
+    span on `timeline`, a `jax.profiler.TraceAnnotation("dl4j:<name>")`
+    while it lasts (a flag test when no profiler session is open) and
+    two cumulative counters, `<name>_s` and `<name>_n`; the kill switch,
+    read once per `begin_iteration`, stops the spans and annotations
+    and leaves the counters.
 
-    Only the owning thread calls `begin_iteration`/`enter`/`close`;
-    `counters()` may be read from any thread."""
+    One thread at a time calls `begin_iteration`/`enter`/`close` (the
+    thread that makes the first `enter` after a `close` is the one the
+    spans name); `counters()` may be read from any thread."""
 
-    def __init__(self, timeline: Timeline = TIMELINE):
+    def __init__(self, phases: Sequence[str], timeline: Timeline = TIMELINE):
         self._timeline = timeline
-        self._acc = {p: [0.0, 0] for p in LEAF_PHASES}
-        self._labels = {p: "dl4j:" + p for p in LEAF_PHASES}
+        self._acc = {p: [0.0, 0] for p in phases}
+        self._labels = {p: "dl4j:" + p for p in phases}
         self.iterations = 0
-        self.sink_s = 0.0
-        self.sink_n = 0
-        # the decode scheduler's dispatch-ahead: dispatches issued while
-        # an earlier one was unread, times the pipeline was drained for
-        # a host read or write of slot state, tokens computed and dropped
-        self.ahead_n = 0
-        self.drained_n = 0
-        self.overshoot_tokens = 0
-        # the page walk of `kv.attend`, reckoned on the host at each
-        # decode dispatch: live pages of the dispatched slots over its
-        # steps, and those slots x steps x the page table's width
-        self.kv_pages_walked = 0
-        self.kv_pages_table = 0
         self._on = tracing_enabled()
         self._tid = None
         # (name, t0, cause, attrs) of the phase the thread is in
@@ -862,12 +861,13 @@ class ThreadPhases:
         if self._open is not None and self._open[0] == name:
             return
         if name not in self._acc:
-            raise KeyError(f"{name!r} is no leaf phase of LEAF_PHASES")
+            raise KeyError(f"{name!r} is none of the phases "
+                           f"{tuple(self._acc)}")
         now = time.perf_counter()
+        if self._open is None:
+            self._tid = threading.get_ident()
         self._end(now)
         self._open = (name, now, self.iterations, attrs or None)
-        if self._tid is None:
-            self._tid = threading.get_ident()
         if self._on:
             global _TraceAnnotation
             if _TraceAnnotation is None:
@@ -899,20 +899,48 @@ class ThreadPhases:
             self._timeline.record(name, t0, now, cause, self._tid, attrs)
 
     def counters(self) -> dict:
-        """``{"iterations", "<phase>_s", "<phase>_n", "sink_s",
-        "sink_n", "ahead_n", "drained_n", "overshoot_tokens",
-        "kv_pages_walked", "kv_pages_table", "spans_dropped"}``:
-        cumulative, so the difference of two
-        readings is a window's account. The phase still open counts
-        with the seconds it has lasted so far, so the `_s` of all
-        phases add up to the time since the first `enter`."""
-        out = {"iterations": self.iterations}
+        """``{"<phase>_s", "<phase>_n"}`` of every phase: cumulative, so
+        the difference of two readings is a window's account. The phase
+        still open counts with the seconds it has lasted so far, so the
+        `_s` of all phases add up to the time spent between a first
+        `enter` and the `close` that followed it (or now)."""
+        out = {}
         for name, (seconds, n) in self._acc.items():
             out[name + "_s"] = seconds
             out[name + "_n"] = n
         open_ = self._open
         if open_ is not None:
             out[open_[0] + "_s"] += time.perf_counter() - open_[1]
+        return out
+
+
+class SchedulerPhases(ThreadPhases):
+    """The decode scheduler's `ThreadPhases` over `LEAF_PHASES`, and the
+    counts the scheduler keeps beside them (written by its thread
+    alone)."""
+
+    def __init__(self, timeline: Timeline = TIMELINE):
+        super().__init__(LEAF_PHASES, timeline)
+        self.sink_s = 0.0
+        self.sink_n = 0
+        # the decode scheduler's dispatch-ahead: dispatches issued while
+        # an earlier one was unread, times the pipeline was drained for
+        # a host read or write of slot state, tokens computed and dropped
+        self.ahead_n = 0
+        self.drained_n = 0
+        self.overshoot_tokens = 0
+        # the page walk of `kv.attend`, reckoned on the host at each
+        # decode dispatch: live pages of the dispatched slots over its
+        # steps, and those slots x steps x the page table's width
+        self.kv_pages_walked = 0
+        self.kv_pages_table = 0
+
+    def counters(self) -> dict:
+        """The phases' `<phase>_s`, `<phase>_n` and ``{"iterations",
+        "sink_s", "sink_n", "ahead_n", "drained_n", "overshoot_tokens",
+        "kv_pages_walked", "kv_pages_table", "spans_dropped"}``."""
+        out = {"iterations": self.iterations}
+        out.update(super().counters())
         out["sink_s"] = self.sink_s
         out["sink_n"] = self.sink_n
         out["ahead_n"] = self.ahead_n
@@ -922,6 +950,157 @@ class ThreadPhases:
         out["kv_pages_table"] = self.kv_pages_table
         out["spans_dropped"] = self._timeline.dropped
         return out
+
+
+# -- JAX's compile pipeline ------------------------------------------------
+
+# JAX's own duration events (`jax.monitoring`), by the counter each adds
+# to. The backend's event wraps the look in the persistent cache, so a
+# program the cache held counts under `backend` with its load's seconds
+# and under `cache_load` again.
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+
+#: the spans a `CompileAccount` writes; they lie INSIDE whatever phase
+#: the thread that compiled was in (`build.weights`, a first
+#: `prefill.dispatch` or `decode.dispatch`), so a reader of a thread's
+#: leaf phases selects by name
+COMPILE_SPANS = ("compile.trace", "compile.lower", "compile.backend")
+
+
+class CompileAccount:
+    """What JAX's compile pipeline cost this process, from JAX's own
+    monitoring events: seconds and counts of tracing, lowering and the
+    backend's compile-or-load (`trace_*`, `lower_*`, `backend_*`), of the
+    persistent cache's reads that hit (`cache_load_*`), the cache's
+    `cache_hits` / `cache_misses`, and the first three again by the
+    function's name (`by_fun`: at most `MAX_FUNS` names, the ones that
+    cost least under `"other"`). Each traced, lowered or compiled
+    function also leaves a span `compile.<stage>` on `timeline` with
+    ``t1`` the callback's `perf_counter()`, ``t0 = t1 - seconds`` and
+    attr ``fun``; the kill switch stops the spans and leaves the
+    counters.
+
+    Sums of JAX's events as they come: a jitted function traced inside
+    another's trace counts in both `trace_s`. Process-wide: every engine
+    of the process reads the same account (`compile_account()`)."""
+
+    MAX_FUNS = 64
+    STAGES = ("trace", "lower", "backend")
+
+    def __init__(self, timeline: Timeline = TIMELINE):
+        self._timeline = timeline
+        self._lock = threading.Lock()
+        # guarded by: _lock
+        self._totals = {k: [0.0, 0] for k in self.STAGES + ("cache_load",)}
+        self._cache = dict.fromkeys(_CACHE_EVENTS.values(), 0)
+        self._by_fun: Dict[str, dict] = {}
+        self._other = {k: [0.0, 0] for k in self.STAGES}
+        self._floor = 0.0
+
+    def on_event(self, event: str, **_kw) -> None:
+        key = _CACHE_EVENTS.get(event)
+        if key is not None:
+            with self._lock:
+                self._cache[key] += 1
+
+    def on_duration(self, event: str, secs: float, **kw) -> None:
+        stage = _COMPILE_EVENTS.get(event)
+        if stage is None:
+            return
+        t1 = time.perf_counter()
+        fun = kw.get("fun_name")
+        if fun is not None and fun.startswith("jit(") and fun.endswith(")"):
+            fun = fun[4:-1]  # lowering and the backend name it `jit(f)`
+        with self._lock:
+            total = self._totals[stage]
+            total[0] += secs
+            total[1] += 1
+            if stage not in self.STAGES:  # the cache's read names nothing
+                return
+            if fun is not None:
+                row = self._row(fun, secs)[stage]
+                row[0] += secs
+                row[1] += 1
+        if tracing_enabled():
+            self._timeline.record("compile." + stage, t1 - secs, t1, None,
+                                  threading.get_ident(), {"fun": fun})
+
+    def _row(self, fun: str, secs: float) -> dict:
+        """`fun`'s row of `by_fun`. A name that finds the table full
+        takes the place of the one that has cost least so far if this
+        one event cost more, and counts under `"other"` if not: the
+        programs that cost seconds get their rows, the hundreds of
+        one-line `jax.numpy` functions traced inside them do not push
+        them out. `_floor` is a lower bound of every row's cost (rows
+        only grow), so a cheap event looks at no row."""
+        rows = self._by_fun
+        row = rows.get(fun)
+        if row is not None:
+            return row
+        if len(rows) >= self.MAX_FUNS:
+            if secs <= self._floor:
+                return self._other
+
+            def cost(f):
+                return sum(v[0] for v in rows[f].values())
+
+            least = min(rows, key=cost)
+            self._floor = cost(least)
+            if secs <= self._floor:
+                return self._other
+            for k, (seconds, n) in rows.pop(least).items():
+                self._other[k][0] += seconds
+                self._other[k][1] += n
+        row = rows[fun] = {k: [0.0, 0] for k in self.STAGES}
+        return row
+
+    def counters(self) -> dict:
+        """``{"trace_s", "trace_n", "lower_s", "lower_n", "backend_s",
+        "backend_n", "cache_load_s", "cache_load_n", "cache_hits",
+        "cache_misses", "by_fun": {name: {"<stage>_s", "<stage>_n"}}}``,
+        cumulative since the account was made."""
+        def flat(rows):
+            return {f"{k}{sfx}": v[i] for k, v in rows.items()
+                    for i, sfx in enumerate(("_s", "_n"))}
+
+        with self._lock:
+            out = flat(self._totals)
+            out.update(self._cache)
+            out["by_fun"] = {f: flat(rows)
+                             for f, rows in self._by_fun.items()}
+            if any(n for _, n in self._other.values()):
+                out["by_fun"]["other"] = flat(self._other)
+        return out
+
+
+_compile_account: Optional[CompileAccount] = None
+_compile_account_lock = threading.Lock()
+
+
+def compile_account() -> CompileAccount:
+    """The process's one `CompileAccount`, made and registered with
+    `jax.monitoring` the first time it is asked for; events from before
+    that are not in it."""
+    global _compile_account
+    with _compile_account_lock:
+        if _compile_account is None:
+            import jax.monitoring
+
+            account = CompileAccount()
+            jax.monitoring.register_event_listener(account.on_event)
+            jax.monitoring.register_event_duration_secs_listener(
+                account.on_duration)
+            _compile_account = account
+        return _compile_account
 
 
 # -- stats-schema contracts ------------------------------------------------
@@ -994,6 +1173,10 @@ DECODE_ENGINE_STATS_KEYS = frozenset({
     # wait summed where admission happens: seconds queued over requests
     # that left the queue for a slot
     "loop", "queue_wait_s", "admitted",
+    # set-up's account: `_build`'s phases (`ThreadPhases.counters` over
+    # BUILD_PHASES, with `builds` and `weight_hash_bytes`), and the
+    # process-wide `CompileAccount.counters`
+    "build", "compile",
 })
 
 # Per-tenant counters nested under DecodeEngine ``stats()["tenants"]``
