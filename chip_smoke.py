@@ -176,6 +176,15 @@ def _compile_reading(account) -> dict:
             "cache_misses": c["cache_misses"]}
 
 
+def _host_copies(net) -> int:
+    """Leaves of `net._params` that hold a host copy of themselves
+    (`np.asarray` of a device array leaves one on the array)."""
+    import jax
+
+    return sum(getattr(x, "_npy_value", None) is not None
+               for x in jax.tree_util.tree_leaves(net._params))
+
+
 def _gpt_net(gpt: dict, max_length: int, block: int = 1024):
     import jax.numpy as jnp
 
@@ -533,12 +542,42 @@ def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
     # step is half the step's device time (PERF.md, PR 26)
     from deeplearning4j_tpu.serving.decode_engine import DecodeEngine
 
+    host_copies = _host_copies(net)
     engine = DecodeEngine(net, **gen)
     try:
         out.update(_decode_program_counts(engine))
         built = engine.stats()
+        out["weight_version"] = engine._weight_version
     finally:
         engine.shutdown(drain_timeout=30.0)
+    # the digest of the served weights is folded where they lie: a few
+    # words a leaf reach the host and no leaf gains a host copy, and the
+    # integers are the CPU's (PERF.md, PR 39)
+    import jax
+
+    from deeplearning4j_tpu.serving import weight_digest
+
+    n_leaves = len(jax.tree_util.tree_leaves(net._params))
+    out["weight_hash"] = {
+        "s": round(built["build"]["build.weight_hash_s"], 4),
+        "bytes": built["build"]["weight_hash_bytes"],
+        "host_bytes": built["build"]["weight_hash_host_bytes"],
+        "golden": weight_digest.weight_version(jax.tree_util.tree_leaves(
+            weight_digest.known_answer_tree()))[0]}
+    print(f"serve: weight version {out['weight_version']}, "
+          f"build.weight_hash_s {out['weight_hash']['s']} for "
+          f"{out['weight_hash']['bytes']} bytes in {n_leaves} leaves, "
+          f"{out['weight_hash']['host_bytes']} of them to the host; the "
+          f"golden tree reads {out['weight_hash']['golden']}", flush=True)
+    _check(out["weight_hash"]["golden"] == weight_digest.KNOWN_ANSWER,
+           f"the golden tree's digest is {out['weight_hash']['golden']} "
+           f"here and {weight_digest.KNOWN_ANSWER} on the CPU")
+    gained = _host_copies(net) - host_copies
+    _check(0 < out["weight_hash"]["host_bytes"] <= 64 * n_leaves
+           and not gained,
+           f"the weight hash took {out['weight_hash']['host_bytes']} bytes "
+           f"of {n_leaves} leaves to the host and left {gained} host "
+           f"copies behind")
     # f32 masters, bf16 compute: cast once when the engine is built,
     # and by no decode program (PERF.md, PR 29)
     out["weight_casts"] = built["weight_casts"]

@@ -68,7 +68,6 @@ k tokens a slot, verified in one batched chunk).
 from __future__ import annotations
 
 import collections
-import hashlib
 import logging
 import threading
 import time
@@ -601,6 +600,7 @@ class DecodeEngine:
         self._build_phases = observability.ThreadPhases(
             observability.BUILD_PHASES)
         self._weight_hash_bytes = 0  # cumulative over builds
+        self._weight_hash_host_bytes = 0  # of them, crossed to the host
         self._compile = observability.compile_account()
         # dispatches issued and not yet collected, oldest first: at most
         # one decode dispatch and the prefills issued before it while
@@ -735,7 +735,11 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         from deeplearning4j_tpu.models.transformer import GPTPlan
-        from deeplearning4j_tpu.serving import block_state, decode_programs
+        from deeplearning4j_tpu.serving import (
+            block_state,
+            decode_programs,
+            weight_digest,
+        )
         from deeplearning4j_tpu.serving.page_pool import PagePool
 
         plan = GPTPlan(net)
@@ -859,22 +863,19 @@ class DecodeEngine:
             else 8 * jnp.dtype(cdt).itemsize
         self._kv_bytes_per_token = _qz.kv_bytes_per_token(
             plan.kv_geometry(), kv_quant, jnp.dtype(cdt).itemsize)
-        # content digest of the served weights: KV handoffs are stamped
-        # with the sender's digest and refused typed on mismatch — a
-        # page of KV computed under other weights must never re-bind
-        # here (and never seed this engine's prefix cache)
+        # content digest of the served weights, folded on the device
+        # (`weight_digest`): KV handoffs are stamped with the sender's
+        # digest and refused typed on mismatch — a page of KV computed
+        # under other weights must never re-bind here (and never seed
+        # this engine's prefix cache)
         _leaves = jax.tree_util.tree_leaves(net._params)
         _nbytes = sum(int(_leaf.nbytes) for _leaf in _leaves)
         ph.enter("build.weight_hash", bytes=_nbytes)
-        _wh = hashlib.blake2b(digest_size=8)
-        for _leaf in _leaves:
-            _arr = np.ascontiguousarray(np.asarray(_leaf))
-            _wh.update(str(_arr.dtype).encode())
-            _wh.update(str(_arr.shape).encode())
-            _wh.update(_arr.tobytes())
-        self._weight_version = _wh.hexdigest()
+        self._weight_version, _crossed = weight_digest.weight_version(
+            _leaves)
         with self._cond:
             self._weight_hash_bytes += _nbytes
+            self._weight_hash_host_bytes += _crossed
         ph.enter("build.plan")
         # latency tier: prefix cache + speculative decoder are rebuilt
         # with the geometry on every (re)build, so a weight swap always
@@ -1817,9 +1818,11 @@ class DecodeEngine:
                # set-up's account, cumulative over builds: seconds and
                # spans of each phase of `_build`, and JAX's compile
                # pipeline (the PROCESS's, not this engine's alone)
-               "build": dict(self._build_phases.counters(),
-                             builds=self._build_phases.iterations,
-                             weight_hash_bytes=self._weight_hash_bytes),
+               "build": dict(
+                   self._build_phases.counters(),
+                   builds=self._build_phases.iterations,
+                   weight_hash_bytes=self._weight_hash_bytes,
+                   weight_hash_host_bytes=self._weight_hash_host_bytes),
                "compile": self._compile.counters()}
         if self._prefix_cache is not None:
             hit_pct = (100.0 * self.prefix_hit_tokens / self.prompt_tokens

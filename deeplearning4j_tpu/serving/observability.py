@@ -1174,8 +1174,9 @@ DECODE_ENGINE_STATS_KEYS = frozenset({
     # that left the queue for a slot
     "loop", "queue_wait_s", "admitted",
     # set-up's account: `_build`'s phases (`ThreadPhases.counters` over
-    # BUILD_PHASES, with `builds` and `weight_hash_bytes`), and the
-    # process-wide `CompileAccount.counters`
+    # BUILD_PHASES, with `builds`, `weight_hash_bytes` and
+    # `weight_hash_host_bytes`), and the process-wide
+    # `CompileAccount.counters`
     "build", "compile",
 })
 

@@ -253,7 +253,8 @@ def test_build_and_compile_keys_in_contract_and_exposition(net):
     finally:
         eng.shutdown()
     # the build's counters, and none of the scheduler's own among them
-    assert set(comp["build"]) == {"builds", "weight_hash_bytes"} \
+    assert set(comp["build"]) == {"builds", "weight_hash_bytes",
+                                  "weight_hash_host_bytes"} \
         | {p + sfx for p in obs.BUILD_PHASES for sfx in ("_s", "_n")}
     assert not set(comp["loop"]) & set(comp["build"])
     assert set(comp["compile"]) == {"cache_hits", "cache_misses", "by_fun"} \
@@ -264,5 +265,6 @@ def test_build_and_compile_keys_in_contract_and_exposition(net):
     assert "dl4j_stats_decode_engine_build_builds 1" in text
     assert "dl4j_stats_decode_engine_build_build_weight_hash_s " in text
     assert "dl4j_stats_decode_engine_build_weight_hash_bytes " in text
+    assert "dl4j_stats_decode_engine_build_weight_hash_host_bytes " in text
     assert "dl4j_stats_decode_engine_compile_backend_s " in text
     assert "dl4j_stats_decode_engine_compile_trace_n " in text

@@ -309,3 +309,30 @@ def test_single_sub_layer_decode_step_compiles_at_the_published_widths(
     sweeps = re.findall(r"^\s*%[\w.\-]+ = \(f32\[64,64,64\]\{.*, "
                         r"f32\[64,64,64,128\]\{.*\) fusion\(", text, re.M)
     assert len(sweeps) == 1
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((64, 1856, 2688), jnp.bfloat16), ((131072, 2688), jnp.bfloat16),
+    ((50257, 2048), jnp.float32), ((2048,), jnp.float32)],
+    ids=("nemotron-expert-stack", "nemotron-embedding", "cgpt-f32-head",
+         "a-bias"))
+def test_weight_fold_reads_a_leaf_once_and_writes_no_copy(one_chip, shape,
+                                                          dtype):
+    """The largest leaves any cell serves, folded for the digest: one
+    fusion takes the leaf, and the program's temporaries are a tile or
+    two, not a widened copy (olmo's cell stands at 91% of HBM)."""
+    import re
+
+    from deeplearning4j_tpu.serving.weight_digest import fold_leaf
+
+    leaf = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    with jax.enable_x64(False):
+        compiled = fold_leaf.lower(leaf).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= leaf.size * leaf.dtype.itemsize
+    assert memory.temp_size_in_bytes < 1 << 20
+    assert memory.output_size_in_bytes <= 512  # four words, one tile
+    takes_the_leaf = re.findall(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = .* (?:fusion|reduce|copy|convert)"
+        r"\([^)]*%x\.\d+", compiled.as_text(), re.M)
+    assert len(takes_the_leaf) == 1 and " fusion(" in takes_the_leaf[0]

@@ -6,9 +6,12 @@
 Runs one serve cell of `BENCHMARK.json` as `perfbench/run.py` does (same
 harness call, same clock from the process's start) and prints, beside
 the cell's metrics, `setup_s` cut into what lies outside the program,
-`_build`'s four phases and the scheduler thread's warm-up and filling;
+`_build`'s four phases and the scheduler thread's warm-up and filling
+(`weight_hash`: the fold of the served leaves on the device, its bytes,
+the bytes of them that crossed to the host and the rate);
 JAX's compile pipeline by function and by the phase each event fell in;
-what the spans and events of set-up numbered, and what one costs here.
+what the spans and events of set-up numbered, and what one costs here;
+the process's resident set after the run.
 The whole table also goes to `chiprun_out/setup_table.<cell>.<seed>.json`.
 Needs a TPU, as the benchmark does.
 """
@@ -70,6 +73,15 @@ def spans_of_setup(t_open: float) -> dict:
         "compile_under": dict(sorted(under.items())),
         "trace_union_s": sum(_union_s(v) for v in traced.values()),
         "dropped": obs.TIMELINE.dropped}
+
+
+def resident_set_bytes() -> int | None:
+    """`VmRSS` of this process now, where `/proc/self/status` gives it
+    (a sealed machine's may leave fields out)."""
+    for line in Path("/proc/self/status").read_text().splitlines():
+        if line.startswith("VmRSS:"):
+            return int(line.split()[1]) * 1024
+    return None
 
 
 def cost_of_one(n: int = 20000) -> dict:
@@ -137,6 +149,14 @@ def main(argv=None) -> int:
         "outside_the_program_s": run.setup_s - build_s - loop_s,
         "build_s": build_s, "warm_and_fill_s": loop_s,
         "build": build,
+        "weight_hash": {
+            "s": build["build.weight_hash_s"],
+            "bytes": build["weight_hash_bytes"],
+            # absent at a commit whose hash pulls every leaf to the host
+            "host_bytes": build.get("weight_hash_host_bytes"),
+            "GB_per_s": build["weight_hash_bytes"] / 1e9
+            / max(build["build.weight_hash_s"], 1e-9)},
+        "host_rss_after_the_run_bytes": resident_set_bytes(),
         "loop_at_open": {k: v for k, v in loop.items() if v},
         "compile": {k: v for k, v in comp.items() if k != "by_fun"},
         "by_fun": by_fun[:16], "by_fun_names": len(by_fun),
