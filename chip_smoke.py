@@ -45,6 +45,16 @@ repo supports — `gpt_configuration(vocab 256, d_model 1024, 8 heads of
   the ungated grouped-expert kernel (a width off the 128-lane grid),
   paged attention and the KV write at 2 K/V heads engaged; no state- or
   pool-shaped copy in the decode programs.
+- **latent**: one shortcut layer at LongCat-Flash-Chat's published
+  widths (two 64-head latent-attention sub-layers over a 512 + 64 latent,
+  two 12288-wide dense FFNs, 16 of 512 real experts 2048 wide and 256
+  zero-compute ones on the shortcut, top-12) through `DecodeEngine`, on
+  the benchmark family's seeded weights: two pools of latent pages from
+  one page table; served tokens against the family's plain float32
+  reference and against an engine on the XLA forms (gather-and-attend,
+  the scatter); about a third of the router's choices on zero experts;
+  the `mla_attend`, `latent_write` and f-tiled grouped-expert kernels
+  engaged; no pool-shaped copy in the decode programs.
 - **lstm**: `lstm_large` (H=1024, T=64, B=2048) with the fused cell.
 - **multichip** (>= 4 devices): the train step through `ParallelWrapper`
   on a {data 2, model 2} mesh against the one-chip loss, tp=4 decode
@@ -62,7 +72,7 @@ with exactly those keys. A failed phase prints the same line with
 `"ok": false` and re-raises.
 
 `python3 chip_smoke.py train lstm` runs only the named phases
-(`train serve hybrid linear sublayer lstm multichip`).
+(`train serve hybrid linear sublayer latent lstm multichip`).
 """
 from __future__ import annotations
 
@@ -129,6 +139,21 @@ SUBLAYER = dict(vocab_size=256, hidden_size=2688, num_hidden_layers=3,
                 deployment=dict(n_routed_experts_published=128,
                                 experts_held_first=0))
 SUBLAYER_SERVE = HYBRID_SERVE
+# LongCat-Flash-Chat's published widths under its config's own keys
+# (`perfbench/families/longcat_flash.py` reads them), one layer, 16 of
+# the 512 real experts held as in the benchmark cell
+LATENT = dict(vocab_size=256, hidden_size=6144, num_layers=1,
+              num_attention_heads=64, q_lora_rank=1536, kv_lora_rank=512,
+              qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+              mla_scale_q_lora=True, mla_scale_kv_lora=True,
+              rope_theta=1e7, ffn_hidden_size=12288,
+              expert_ffn_hidden_size=2048, n_routed_experts=16,
+              zero_expert_num=256, zero_expert_type="identity",
+              moe_topk=12, routed_scaling_factor=6.0, rms_norm_eps=1e-5,
+              attention_bias=False, attention_method="MLA",
+              deployment=dict(n_routed_experts_published=512,
+                              experts_held_first=0))
+LATENT_SERVE = HYBRID_SERVE
 LSTM = dict(vocab=256, hidden=1024, T=64, batch=2048, steps=2)
 
 # A greedy token may differ between two correct attention paths only
@@ -903,6 +928,97 @@ def phase_sublayer(sub: dict, shape: dict, *, kernels: bool,
     return out
 
 
+def phase_latent(lat: dict, shape: dict, *, kernels: bool,
+                 dtype=None) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.serving.decode_engine import DecodeEngine
+    from perfbench.families import longcat_flash as fam
+    from perfbench.families import longcat_flash_reference as ref
+
+    dtype = dtype or jnp.bfloat16
+    vocab, n_tokens = lat["vocab_size"], shape["n_tokens"]
+    sz = fam.sizes(lat)
+    weights = fam.make_weights(0, sz)
+    net = fam.build_net(sz, training=False, dtype=dtype)
+    fam.install(net, jax.tree.map(
+        lambda a: a if a.dtype == jnp.float32 else a.astype(dtype), weights))
+    prompts = _serve_prompts(vocab, shape)
+    gen = _engine_kwargs(shape)
+    toks, stats = _through_engine(net, prompts, n_tokens, **gen)
+    _check_tokens(toks, n_tokens, vocab, stats, len(prompts), "latent")
+    n_chunks = -(-shape["long_len"] // shape["prefill_chunk"])
+    _check(stats["prefill_chunks"] >= n_chunks,
+           f"long prompt did not ride chunked prefill: "
+           f"{stats['prefill_chunks']} chunks < {n_chunks}")
+    _check(stats["latent_blocks"] == 2 * sz["L"],
+           f"{stats['latent_blocks']} pools of latent pages for "
+           f"{sz['L']} layers of two sub-layers")
+    zero = stats["moe_zero_choices"] / max(1, stats["moe_routed"])
+    want = sz["Z"] / (sz["E"] + sz["Z"])
+    _check(0.5 * want < zero < min(1.0, 2.0 * want),
+           f"{zero:.3f} of the router's choices fell on the {want:.3f} "
+           f"of its outputs that are zero experts")
+    out = {"requests": len(prompts), "tokens": int(sum(map(len, toks))),
+           "prefill_chunks": stats["prefill_chunks"],
+           "decode_steps": stats["decode_steps"],
+           "latent_blocks": stats["latent_blocks"],
+           "latent_bytes_per_token": stats["latent_bytes_per_token"],
+           "zero_share_of_choices": round(zero, 4),
+           "held_share_of_choices": round(
+               stats["moe_held_choices"] / max(1, stats["moe_routed"]), 4),
+           **_experts_read(stats, "latent")}
+    gc.collect()
+
+    # a bucketed and the chunked prompt against the plain reference's
+    # full forward: expanded prefill, attention over cached latents in
+    # the chunks, then the absorbed step through both pools
+    picked = (0, len(prompts) - 1)
+    out["reference_gaps"] = [round(g, 5) for g in _reference_gaps(
+        fam, ref, lat, sz, weights, [prompts[i] for i in picked],
+        [toks[i] for i in picked])]
+    _check(max(out["reference_gaps"]) < ROUTED_REFERENCE_GAP,
+           f"served tokens lie {out['reference_gaps']} under the "
+           f"reference's best logit")
+
+    # the same prompts on gather-and-attend and the scatter
+    os.environ["DL4J_TPU_NO_PALLAS_MLA_ATTEND"] = "1"
+    try:
+        xla, xla_stats = _through_engine(net, prompts, n_tokens, **gen)
+    finally:
+        del os.environ["DL4J_TPU_NO_PALLAS_MLA_ATTEND"]
+    _check_tokens(xla, n_tokens, vocab, xla_stats, len(prompts), "xla-mla")
+    out["agreement"] = _agreement(net, prompts, toks, xla,
+                                  "kernel and XLA latent attention")
+    gc.collect()
+
+    engine = DecodeEngine(net, **gen)
+    try:
+        out.update(_decode_program_counts(engine))
+    finally:
+        engine.shutdown(drain_timeout=30.0)
+    print(f"latent: pool copies in the decode programs "
+          f"{out['pool_layout_copies']}", flush=True)
+    if kernels:
+        R, page = sz["kr"] + sz["rope"], shape["page_size"]
+        key = ("bfloat16", sz["H"], R, sz["kr"], page)
+        _check(engaged("mla_attend", lambda k: k == key),
+               f"paged latent attention did not engage for {key}")
+        key = ("bfloat16", R, page)
+        _check(engaged("latent_write", lambda k: k == key),
+               f"in-place latent write did not engage for {key}")
+        for rows in (shape["n_slots"], shape["prefill_chunk"]):
+            key = ("bfloat16", rows, sz["d"], sz["f"])
+            _check(engaged("moe_experts", lambda k: k == key),
+                   f"f-tiled grouped expert kernel did not engage for "
+                   f"{key}")
+        _check(not any(out["pool_layout_copies"].values()),
+               f"the decode programs copy their pools: "
+               f"{out['pool_layout_copies']}")
+    return out
+
+
 # -------------------------------------------------------------- multichip
 def phase_multichip(gpt: dict, train: dict, serve: dict,
                     one_chip_loss: float) -> dict:
@@ -1003,13 +1119,13 @@ def main(argv=None) -> int:
     from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
 
     names = list(sys.argv[1:] if argv is None else argv) \
-        or ["train", "serve", "hybrid", "linear", "sublayer", "lstm",
-            "multichip"]
+        or ["train", "serve", "hybrid", "linear", "sublayer", "latent",
+            "lstm", "multichip"]
     unknown = set(names) - {"train", "serve", "hybrid", "linear",
-                            "sublayer", "lstm", "multichip"}
+                            "sublayer", "latent", "lstm", "multichip"}
     if unknown or ("multichip" in names and "train" not in names):
         print(f"chip_smoke: phases are train serve hybrid linear sublayer "
-              f"lstm multichip "
+              f"latent lstm multichip "
               f"(multichip compares against train's loss, so name both); "
               f"got {names}", file=sys.stderr)
         return 2
@@ -1053,6 +1169,8 @@ def main(argv=None) -> int:
         if "sublayer" in names:
             run("sublayer", phase_sublayer, SUBLAYER, SUBLAYER_SERVE,
                 kernels=True)
+        if "latent" in names:
+            run("latent", phase_latent, LATENT, LATENT_SERVE, kernels=True)
         if "lstm" in names:
             run("lstm", phase_lstm, LSTM, kernels=True)
         if "multichip" in names:

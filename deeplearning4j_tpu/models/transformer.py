@@ -31,9 +31,11 @@ from deeplearning4j_tpu.nn.conf.decoder_block import (
     DecoderBlock,
     GatedDeltaNetMixer,
     GatedMLP,
+    LatentAttentionMixer,
     Mamba2Mixer,
     MoEFeedForward,
     RMSNorm,
+    ShortcutDecoderBlock,
 )
 from deeplearning4j_tpu.nn.conf.layers import (
     LayerNormalization,
@@ -278,6 +280,63 @@ def hybrid_sublayer_configuration(vocab_size: int, d_model: int,
             .build())
 
 
+def longcat_configuration(vocab_size: int, d_model: int, n_layers: int, *,
+                          n_heads: int, q_rank: int, kv_rank: int,
+                          nope_dim: int, rope_dim: int, v_dim: int,
+                          rope_theta: float = 1e7,
+                          scale_q_lora: bool = True,
+                          scale_kv_lora: bool = True,
+                          ffn_width: int, n_experts: int,
+                          n_zero_experts: int, top_k: int,
+                          expert_width: int, routed_scale: float = 1.0,
+                          experts_held=None, eps: float = 1e-5,
+                          seed: int = 12345, learning_rate: float = 3e-4,
+                          updater: Updater = Updater.ADAM,
+                          ) -> MultiLayerConfiguration:
+    """Causal LM of `n_layers` `ShortcutDecoderBlock`s: each layer two
+    (latent attention, dense gated MLP) pairs and, on a shortcut around
+    the second pair, `n_experts` routed gated-silu experts plus
+    `n_zero_experts` zero-compute ones, `top_k` a token chosen on a
+    softmax over all of them; rotary on the latent attention's rope
+    dimensions, RMSNorm, no multipliers, no positional layer, one
+    trailing norm and an untied, bias-free output head (the Hugging
+    Face `longcat_flash` family's layout). `experts_held = (first,
+    count)`: the share of each layer's real experts this network
+    holds."""
+    b = (NeuralNetConfiguration.Builder()
+         .seed(seed)
+         .learning_rate(learning_rate)
+         .updater(updater)
+         .drop_out(0.0)
+         .list()
+         .layer(TokenEmbedding(n_in=vocab_size, n_out=d_model,
+                               positional=False)))
+    mixer = LatentAttentionMixer(
+        n_heads=n_heads, q_rank=q_rank, kv_rank=kv_rank, nope_dim=nope_dim,
+        rope_dim=rope_dim, v_dim=v_dim, rope_theta=rope_theta,
+        scale_q_lora=scale_q_lora, scale_kv_lora=scale_kv_lora, eps=eps)
+    pair = lambda: DecoderBlock(n_in=d_model, n_out=d_model, mixer=mixer,
+                                ffn=GatedMLP(width=ffn_width),
+                                norm=RMSNorm(eps=eps))
+    shortcut = MoEFeedForward(
+        n_experts=n_experts, n_zero_experts=n_zero_experts, top_k=top_k,
+        expert_width=expert_width, experts_held=experts_held,
+        scoring="softmax_all", routed_scale=routed_scale)
+    for _ in range(n_layers):
+        b = b.layer(ShortcutDecoderBlock(n_in=d_model, n_out=d_model,
+                                         first=pair(), second=pair(),
+                                         shortcut=shortcut))
+    return (b
+            .layer(RMSNormalization(n_in=d_model, n_out=d_model, eps=eps,
+                                    dropout=0.0))
+            .layer(RnnOutputLayer(n_in=d_model, n_out=vocab_size,
+                                  has_bias=False,
+                                  activation=Activation.SOFTMAX,
+                                  loss=LossFunction.MCXENT, dropout=0.0))
+            .set_input_type(InputType.recurrent(vocab_size))
+            .build())
+
+
 # ---------------------------------------------------------------------------
 # shared decode plan + per-block compute (generate() AND the serving
 # decode engine trace through these — one implementation of the numerics)
@@ -302,7 +361,8 @@ class GPTPlan:
         self.emb_i = 0
         self.emb = layers[0]
         self.block_is = [i for i, l in enumerate(layers)
-                        if isinstance(l, (TransformerBlock, DecoderBlock))]
+                        if isinstance(l, (TransformerBlock, DecoderBlock,
+                                          ShortcutDecoderBlock))]
         self.ln_is = [i for i, l in enumerate(layers)
                       if isinstance(l, (LayerNormalization,
                                         RMSNormalization))]
@@ -313,17 +373,20 @@ class GPTPlan:
 
     def state_kinds(self):
         """Per block, the cache state a decode engine keeps for it:
-        "kv" (paged key/value pools), "recurrent" (per-slot arrays) or
-        "none". A `TransformerBlock` keeps K/V; a composed
-        `DecoderBlock` keeps what its mixer kind declares, and nothing
-        where it has no mixer."""
+        "kv" (paged key/value pools), "recurrent" (per-slot arrays),
+        "latent" (one paged pool of latents) or "none". A
+        `TransformerBlock` keeps K/V; a composed `DecoderBlock` keeps
+        what its mixer kind declares, and nothing where it has no
+        mixer; a `ShortcutDecoderBlock` the pair of its two mixers'
+        kinds."""
         return ["kv" if isinstance(self.layers[i], TransformerBlock)
                 else self.layers[i].state for i in self.block_is]
 
     @property
     def composed(self) -> bool:
-        """Whether any block is a composed `DecoderBlock`."""
-        return any(isinstance(self.layers[i], DecoderBlock)
+        """Whether any block is composed of kinds (`DecoderBlock`,
+        `ShortcutDecoderBlock`)."""
+        return any(not isinstance(self.layers[i], TransformerBlock)
                    for i in self.block_is)
 
     def kv_geometry(self):
@@ -342,6 +405,14 @@ class GPTPlan:
             elif layer.state == "kv":
                 out.append(layer.mixer.kv_geometry(layer._d))
         return out
+
+    def latent_geometry(self):
+        """(kv_rank, rope_dim) pairs of the sub-layers that keep a pool
+        of latents, in order: a position's cache there is their sum, in
+        the compute dtype, for all heads."""
+        return [m.latent_geometry() for i in self.block_is
+                for m in getattr(self.layers[i], "mixers", list)()
+                if m.state == "latent"]
 
     def _cast(self, params, wrap):
         """`params` with the layers that are read in the compute dtype

@@ -26,9 +26,11 @@ from deeplearning4j_tpu.nn.conf.layers import (  # noqa: F401
 from deeplearning4j_tpu.nn.conf.decoder_block import (  # noqa: F401
     AttentionMixer,
     DecoderBlock,
+    LatentAttentionMixer,
     Mamba2Mixer,
     MoEFeedForward,
     RMSNorm,
+    ShortcutDecoderBlock,
 )
 from deeplearning4j_tpu.nn.conf.variational import (  # noqa: F401
     BernoulliReconstructionDistribution,

@@ -22,14 +22,22 @@ must keep for it, `state`:
                  gives them: the state, slot axis first, then the
                  convolution tail, tap-major)
 
+    "latent"     ONE paged pool of compressed key/value latents, one
+                 position a token, shared by all heads
+                 (`LatentAttentionMixer`; `latent_geometry` gives the
+                 latent's and the rope key's widths)
+
 a block without a mixer declares `DecoderBlock.state` "none": it keeps
-nothing between tokens; and `serving/block_state.py` turns that declaration into the engine's
+nothing between tokens; a `ShortcutDecoderBlock` declares the pair of its
+two mixers' kinds; and `serving/block_state.py` turns that declaration into the engine's
 allocation and its prefill / decode steps. A kind serialises as
 `{"kind": <name>, ...fields}` inside the layer's JSON.
 
 Parameters of a block are one flat dict, as every layer's: the mixer's
 under `mx_`, the feed-forward's under `ff_`, the norms' `n1_w`, `n2_w`
-(a block of one sub-layer has no `n2_w`).
+(a block of one sub-layer has no `n2_w`); a `ShortcutDecoderBlock`'s
+first block's under `a_`, its second's under `b_`, the shortcut
+feed-forward's under `sc_`.
 """
 from __future__ import annotations
 
@@ -79,7 +87,7 @@ class _Kind:
         return out
 
 
-for _field in ("mixer", "ffn", "norm"):
+for _field in ("mixer", "ffn", "norm", "shortcut"):
     _FIELD_DECODERS[_field] = kind_from_json
 
 
@@ -189,6 +197,195 @@ class AttentionMixer(_Kind):
             att = multi_head_attention(q, k, v, causal=True,
                                        block_size=_FLASH_FROM)
         return self.out(p, att.reshape(*x.shape[:-1], -1))
+
+
+@_kind
+@dataclass(frozen=True)
+class LatentAttentionMixer(_Kind):
+    """Multi-head latent attention (MLA, DeepSeek-V2 arXiv:2405.04434) as
+    the Hugging Face `DeepseekV3Attention` / `LongcatFlashMLA` layers
+    write it, no bias. Queries through a low-rank pair with an RMSNorm
+    between, `[q_n | q_r]_h = (s_q N(x Wqa)) Wqb` (`nope_dim` +
+    `rope_dim` a head); keys and values through ONE latent a position,
+    `[c_kv | k_r] = x Wkva`, `c = N(c_kv)` (`kv_rank` wide) and a single
+    rope key `k_r` (`rope_dim`) shared by all heads, expanded by
+    `[k_n | v]_h = (s_kv c) Wkvb` (`nope_dim` + `v_dim` a head); each
+    of `Wqb`, `Wkva`, `Wkvb` is held as its two parts (`init_params`).
+    Rotary
+    (`ops/rope.py`, interleaved pairs as published: features 2i and
+    2i + 1 turn together) on `q_r` and `k_r` at the positions the caller
+    gives; scores `(q_n.k_n + q_r.k_r) / sqrt(nope_dim + rope_dim)`,
+    causal, float32 softmax; `o = concat_h(P v_h) Wo`. `scale_q_lora` /
+    `scale_kv_lora` switch `s_q = sqrt(d / q_rank)` and `s_kv = sqrt(d /
+    kv_rank)` on (else 1).
+
+    Three forms of the same attention, held to one another by
+    `tests/test_latent_attention.py`: `forward` (expanded keys and
+    values, a whole sequence), `attend_latents` with a chunk of queries
+    against cached latents, and the same with one query a slot, the
+    ABSORBED step: `q~_h = s_kv Wkvb_h^K q_n,h` so that scores are
+    `q~_h.c + q_r,h.k_r` and `o_h = s_kv (Wkvb_h^V)^T sum_s p c(s)`:
+    nothing is expanded, and a position's cache is `[c | k_r]`,
+    `kv_rank + rope_dim` numbers for all heads. Keeps those latents,
+    paged."""
+    KIND = "latent_attention"
+    state = "latent"
+    n_heads: int = 4
+    q_rank: int = 32
+    kv_rank: int = 16
+    nope_dim: int = 8
+    rope_dim: int = 4
+    v_dim: int = 8
+    rope_theta: float = 10000.0
+    scale_q_lora: bool = False
+    scale_kv_lora: bool = False
+    eps: float = 1e-5
+
+    def latent_geometry(self) -> Tuple[int, int]:
+        return self.kv_rank, self.rope_dim
+
+    @property
+    def sm_scale(self) -> float:
+        return 1.0 / math.sqrt(self.nope_dim + self.rope_dim)
+
+    def init_params(self, key, d: int, dtype, winit) -> dict:
+        """The published `q_b_proj`, `kv_a_proj_with_mqa` and `kv_b_proj`
+        are each held as their two parts, in the layouts the absorbed
+        step reads them in (fused, XLA re-lays a 38 MB matrix out every
+        decode step to split heads of 128 + 64 off the lane grid: my
+        sandbox compile, PR 40): `Wqn` / `Wqr` the queries' nope and
+        rope columns, `Wkvc` / `Wkr` the latent's and the rope key's,
+        `Wkb` (H, nope, kv_rank) / `Wvb` (H, kv_rank, v) a head's key
+        and value expansions."""
+        H, qr, kr = self.n_heads, self.q_rank, self.kv_rank
+        k = jax.random.split(key, 8)
+        kvw = H * (self.nope_dim + self.v_dim)
+        return {"Wqa": winit(k[0], (d, qr), d, qr),
+                "qn_w": jnp.ones((qr,), dtype),
+                "Wqn": winit(k[1], (qr, H * self.nope_dim), qr,
+                             H * (self.nope_dim + self.rope_dim)),
+                "Wqr": winit(k[2], (qr, H * self.rope_dim), qr,
+                             H * (self.nope_dim + self.rope_dim)),
+                "Wkvc": winit(k[3], (d, kr), d, kr + self.rope_dim),
+                "Wkr": winit(k[4], (d, self.rope_dim), d,
+                             kr + self.rope_dim),
+                "kvn_w": jnp.ones((kr,), dtype),
+                "Wkb": winit(k[5], (H, self.nope_dim, kr), kr, kvw),
+                "Wvb": winit(k[6], (H, kr, self.v_dim), kr, kvw),
+                "Wo": winit(k[7], (H * self.v_dim, d), H * self.v_dim, d)}
+
+    def _lora_scale(self, p, on: bool, rank: int) -> float:
+        return math.sqrt(p["Wqa"].shape[0] / rank) if on else 1.0
+
+    def _s_kv(self, p) -> float:
+        return self._lora_scale(p, self.scale_kv_lora, self.kv_rank)
+
+    def _rope(self, u, positions, heads: bool):
+        """Rotary on (..., T, H, rope_dim) (`heads`) or (..., T,
+        rope_dim) at `positions` (..., T) or (T,):
+        pairs (2i, 2i + 1) turn by `pos * theta^(-2i / rope_dim)`; the
+        result lies evens-first, the same for queries and keys, so
+        their product is the published one."""
+        from deeplearning4j_tpu.ops.rope import rope_angles, rope_rotate
+
+        half = self.rope_dim // 2
+        u = jnp.swapaxes(u.reshape(*u.shape[:-1], half, 2), -1, -2) \
+            .reshape(u.shape)
+        cos, sin = rope_angles(positions, self.rope_dim, self.rope_theta)
+        if not heads:                   # one key a position
+            return rope_rotate(u[..., None, :], cos, sin)[..., 0, :]
+        return rope_rotate(u, cos, sin)
+
+    def project(self, p, x, positions):
+        """`x` (..., T, d) at `positions` (..., T) -> (q_n (..., T, H,
+        nope), q_r (..., T, H, rope) turned, latent (..., T, kv_rank +
+        rope): `[c | k_r]`, the normed latent and the turned rope key:
+        what a position's cache holds)."""
+        H = self.n_heads
+        with jax.named_scope("mla.q"):
+            cq = rms_norm(x @ p["Wqa"], p["qn_w"], self.eps)
+            cq = cq * jnp.asarray(
+                self._lora_scale(p, self.scale_q_lora, self.q_rank),
+                cq.dtype)
+            q_n = (cq @ p["Wqn"]).reshape(*x.shape[:-1], H, self.nope_dim)
+            q_r = self._rope(
+                (cq @ p["Wqr"]).reshape(*x.shape[:-1], H, self.rope_dim),
+                positions, True)
+        with jax.named_scope("mla.kv_down"):
+            c = rms_norm(x @ p["Wkvc"], p["kvn_w"], self.eps)
+            k_r = self._rope(x @ p["Wkr"], positions, False)
+            latent = jnp.concatenate([c, k_r.astype(c.dtype)], axis=-1)
+        return q_n, q_r, latent
+
+    def absorb(self, p, q_n, q_r):
+        """The absorbed queries (..., H, kv_rank + rope): `[s_kv W^K_h
+        q_n,h | q_r,h]`, so that one product with a cached latent is the
+        whole score."""
+        with jax.named_scope("mla.absorb"):
+            qt = jnp.einsum("...hn,hnr->...hr", q_n, p["Wkb"]) \
+                * jnp.asarray(self._s_kv(p), q_n.dtype)
+            return jnp.concatenate([qt, q_r], axis=-1)
+
+    def out(self, p, u):
+        """Latent-space values `u` (..., H, kv_rank), `sum_s p c(s)` a
+        head, -> (..., d): up through `s_kv W^V_h`, then `Wo`."""
+        with jax.named_scope("mla.out"):
+            o = jnp.einsum("...hr,hrv->...hv", u, p["Wvb"]) \
+                * jnp.asarray(self._s_kv(p), u.dtype)
+            return o.reshape(*u.shape[:-2], -1) @ p["Wo"]
+
+    def attend_latents(self, q_abs, latents, q_pos):
+        """Absorbed queries `q_abs` (..., C, H, kv_rank + rope) at
+        positions `q_pos` (..., C) against cached `latents` (..., Tk,
+        kv_rank + rope), entry `s` the position `s`: causal, float32
+        softmax. Returns `u` (..., C, H, kv_rank) in the queries'
+        dtype."""
+        with jax.named_scope("mla.attend"):
+            s = jnp.einsum("...chr,...sr->...hcs", q_abs, latents,
+                           preferred_element_type=jnp.float32) \
+                * self.sm_scale
+            seen = jnp.arange(latents.shape[-2]) <= q_pos[..., None]
+            s = jnp.where(seen[..., None, :, :], s, -1e30)
+            prob = jax.nn.softmax(s, axis=-1).astype(latents.dtype)
+            return jnp.einsum("...hcs,...sr->...chr", prob,
+                              latents[..., :self.kv_rank],
+                              preferred_element_type=jnp.float32) \
+                .astype(q_abs.dtype)
+
+    def attend_expanded(self, p, q_n, q_r, latent):
+        """One sequence's queries (B, T, H, .) against its own latents
+        (B, T, kv_rank + rope), keys and values expanded per head:
+        causal, float32 softmax. Returns (B, T, d)."""
+        c, k_r = latent[..., :self.kv_rank], latent[..., self.kv_rank:]
+        with jax.named_scope("mla.kv_up"):
+            cs = c * jnp.asarray(self._s_kv(p), c.dtype)
+            k_n = jnp.einsum("btr,hnr->bthn", cs, p["Wkb"],
+                             preferred_element_type=jnp.float32) \
+                .astype(c.dtype)
+            v = jnp.einsum("btr,hrv->bthv", cs, p["Wvb"],
+                           preferred_element_type=jnp.float32) \
+                .astype(c.dtype)
+        T = c.shape[1]
+        with jax.named_scope("mla.attend"):
+            s = (jnp.einsum("bthn,bshn->bhts", q_n, k_n,
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("bthr,bsr->bhts", q_r, k_r,
+                              preferred_element_type=jnp.float32)) \
+                * self.sm_scale
+            s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
+            prob = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+            o = jnp.einsum("bhts,bshv->bthv", prob, v,
+                           preferred_element_type=jnp.float32) \
+                .astype(v.dtype)
+        with jax.named_scope("mla.out"):
+            return o.reshape(*o.shape[:2], -1) @ p["Wo"]
+
+    def forward(self, p, x, positions=None):
+        """`x` (B, T, d), a whole sequence from position 0 (or at
+        `positions` (T,))."""
+        if positions is None:
+            positions = jnp.arange(x.shape[1])
+        return self.attend_expanded(p, *self.project(p, x, positions))
 
 
 @_kind
@@ -517,14 +714,15 @@ class MoEFeedForward(_Kind):
     activation: str = "gated_silu"
     scoring: str = "softmax"
     routed_scale: float = 1.0
+    n_zero_experts: int = 0
 
     def __post_init__(self):
         if self.activation not in ("gated_silu", "relu2"):
             raise ValueError(f"activation {self.activation!r}: "
                              "'gated_silu' or 'relu2'")
-        if self.scoring not in ("softmax", "sigmoid"):
-            raise ValueError(f"scoring {self.scoring!r}: 'softmax' or "
-                             "'sigmoid'")
+        if self.scoring not in ("softmax", "sigmoid", "softmax_all"):
+            raise ValueError(f"scoring {self.scoring!r}: 'softmax', "
+                             "'sigmoid' or 'softmax_all'")
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -538,15 +736,16 @@ class MoEFeedForward(_Kind):
     def init_params(self, key, d: int, dtype, winit) -> dict:
         E, f, s = self.held[1], self.expert_width, self.shared_width
         k = jax.random.split(key, 7)
-        p = {"router": winit(k[0], (d, self.n_experts), d, self.n_experts),
+        n_out = self.n_experts + self.n_zero_experts
+        p = {"router": winit(k[0], (d, n_out), d, n_out),
              "Wd": winit(k[3], (E, f, d), f, d)}
         if self._gated:
             p.update(Wg=winit(k[1], (E, d, f), d, f),
                      Wu=winit(k[2], (E, d, f), d, f))
         else:
             p["Wu"] = winit(k[2], (E, f, d), d, f)
-        if self.scoring == "sigmoid":
-            p["router_b"] = jnp.zeros((self.n_experts,), jnp.float32)
+        if self.scoring != "softmax":
+            p["router_b"] = jnp.zeros((n_out,), jnp.float32)
         if s:
             p.update({"sWu": winit(k[5], (d, s), d, s),
                       "sWd": winit(k[6], (s, d), s, d)})
@@ -557,8 +756,9 @@ class MoEFeedForward(_Kind):
     def forward(self, p, x, count_mask=None):
         """`x` (..., d) -> (y, counts): with `count_mask` (one bool a
         token: the rows anyone will read), how many masked-in tokens
-        chose each held expert and whether it was read, (2, held); else
-        None (`parallel.experts.dropless_moe`)."""
+        chose each held expert and whether it was read, (2, held) (with
+        zero experts: that and how many of their choices fell on zero
+        experts, a pair); else None (`parallel.experts.dropless_moe`)."""
         from deeplearning4j_tpu.parallel.experts import (
             dropless_moe,
             gated_mlp,
@@ -570,7 +770,8 @@ class MoEFeedForward(_Kind):
             flat, p["router"], p.get("Wg"), p["Wu"], p["Wd"],
             top_k=self.top_k, experts_held=self.held, count_mask=count_mask,
             act=self.activation, router_bias=p.get("router_b"),
-            routed_scale=self.routed_scale)
+            routed_scale=self.routed_scale, scoring=self.scoring,
+            n_zero=self.n_zero_experts)
         if self.shared_width:
             with jax.named_scope("moe.shared"):
                 y = y + (gated_mlp(flat, p["sWg"], p["sWu"], p["sWd"])
@@ -655,23 +856,54 @@ class DecoderBlock(FeedForwardLayer):
         the norm stands before the sub-layer."""
         return self.norm1(p, x) if self.norm_placement == "pre" else x
 
+    def after_mixer(self, p, x, mixed):
+        """The stream after the mixer's residual (`mixed` None: the
+        block has no mixer, the stream as it came)."""
+        if self.mixer is None:
+            return x
+        r = jnp.asarray(self.residual_multiplier, x.dtype)
+        post = self.norm_placement == "post"
+        return x + r * (self.norm1(p, mixed) if post else mixed)
+
+    def _ffn_norm(self):
+        # the block's second norm, or the one norm of a feed-forward alone
+        return self.norm1 if self.mixer is None else self.norm2
+
+    def ffn_in(self, p, h):
+        """What the feed-forward reads of the stream `h`."""
+        return h if self.norm_placement == "post" \
+            else self._ffn_norm()(p, h)
+
+    def after_ffn(self, p, h, f):
+        """The stream after the feed-forward's residual."""
+        r = jnp.asarray(self.residual_multiplier, h.dtype)
+        post = self.norm_placement == "post"
+        return h + r * (self._ffn_norm()(p, f) if post else f)
+
     def finish(self, p, x, mixed, count_mask=None):
         """The block from the mixer's output `mixed` on (None: the block
         has no mixer): the mixer's residual, then norm, feed-forward and
         its residual where the block has a feed-forward. Returns (h, the
         feed-forward's counts under `count_mask`, or None)."""
-        r = jnp.asarray(self.residual_multiplier, x.dtype)
-        post = self.norm_placement == "post"
-        h = x
-        if self.mixer is not None:
-            h = x + r * (self.norm1(p, mixed) if post else mixed)
+        h = self.after_mixer(p, x, mixed)
         if self.ffn is None:
             return h, None
-        # the block's second norm, or the one norm of a feed-forward alone
-        norm = self.norm1 if self.mixer is None else self.norm2
-        f, counts = self.ffn.forward(sub(p, "ff_"),
-                                     h if post else norm(p, h), count_mask)
-        return h + r * (norm(p, f) if post else f), counts
+        f, counts = self.ffn.forward(sub(p, "ff_"), self.ffn_in(p, h),
+                                     count_mask)
+        return self.after_ffn(p, h, f), counts
+
+    def feed_forwards(self) -> list:
+        """The block's feed-forward kinds, in order."""
+        return [] if self.ffn is None else [self.ffn]
+
+    def mixers(self) -> list:
+        """The block's mixer kinds, in order."""
+        return [] if self.mixer is None else [self.mixer]
+
+    def to_json(self) -> dict:
+        from deeplearning4j_tpu.nn.conf.layers import layer_to_json
+
+        return layer_to_json(self)
 
     def forward(self, params, state, x, *, train=False, rng=None,
                 mask=None):
@@ -682,7 +914,130 @@ class DecoderBlock(FeedForwardLayer):
     def param_flags(self, name):
         vector = name in ("n1_w", "n2_w", "mx_norm_w", "mx_conv_b",
                           "mx_dt_bias", "mx_A_log", "mx_D", "mx_qn_w",
-                          "mx_kn_w", "ff_router_b")
+                          "mx_kn_w", "mx_kvn_w", "ff_router_b")
         return {"is_bias": name in ("mx_conv_b", "mx_dt_bias",
                                     "ff_router_b"),
                 "regularizable": not vector}
+
+
+def _block_from_json(d):
+    from deeplearning4j_tpu.nn.conf.layers import layer_from_json
+
+    return layer_from_json(d) if isinstance(d, dict) else d
+
+
+for _field in ("first", "second"):
+    _FIELD_DECODERS[_field] = _block_from_json
+
+
+@register_layer
+@dataclass
+class ShortcutDecoderBlock(FeedForwardLayer):
+    """Two pre-norm `DecoderBlock`s in a row and one more feed-forward
+    on a SHORTCUT around the second (the shortcut-connected
+    mixture-of-experts layer of LongCat-Flash, arXiv:2509.01322): the
+    shortcut reads what the first block's feed-forward reads, leaves the
+    residual stream there and joins it after the second block,
+
+        h1 = h + mixer_a(N(h));   x1 = N(h1)
+        m  = shortcut(x1);        h2 = h1 + ffn_a(x1)
+        out = second(h2) + m
+
+    so neither sub-layer of `second` sees `m` (in a deployment the
+    routed experts' exchange overlaps them). `x1` is normed once and
+    feeds both. ONE layer of the net with two mixers: `state` is the
+    pair of their kinds, and a decode engine keeps a cache for each.
+    Parameters: `first`'s under `a_`, `second`'s under `b_`, the
+    shortcut's under `sc_`."""
+
+    TYPE = "shortcut_decoder_block"
+    input_kind = "rnn"
+    n_in: int = 0
+    n_out: int = 0
+    first: object = None
+    second: object = None
+    shortcut: object = None
+
+    def __post_init__(self):
+        self.first = _block_from_json(self.first)
+        self.second = _block_from_json(self.second)
+        self.shortcut = kind_from_json(self.shortcut)
+        a, b = self.first, self.second
+        if not (isinstance(a, DecoderBlock) and isinstance(b, DecoderBlock)
+                and self.shortcut is not None):
+            raise ValueError("ShortcutDecoderBlock needs two DecoderBlocks "
+                             "and a shortcut feed-forward kind")
+        if a.mixer is None or a.ffn is None or b.mixer is None \
+                or a.norm_placement != "pre" or b.norm_placement != "pre":
+            raise ValueError(
+                "ShortcutDecoderBlock: `first` is a pre-norm block with a "
+                "mixer and a feed-forward (the shortcut reads its "
+                "feed-forward's input), `second` a pre-norm block with a "
+                "mixer")
+        widths = {w for blk in (self, a, b) for w in (blk.n_in, blk.n_out)
+                  if w}
+        if len(widths) > 1:
+            raise ValueError("ShortcutDecoderBlock keeps width: every "
+                             "n_in and n_out equal")
+        for blk in (self, a, b):      # one width, stated anywhere
+            blk.n_in = blk.n_out = max(widths, default=0)
+
+    @property
+    def _d(self) -> int:
+        return self.n_out
+
+    @property
+    def state(self) -> tuple:
+        """The pair of the two mixers' cache kinds."""
+        return (self.first.state, self.second.state)
+
+    def output_type(self, it):
+        return it
+
+    def feed_forwards(self) -> list:
+        return self.first.feed_forwards() + [self.shortcut] \
+            + self.second.feed_forwards()
+
+    def mixers(self) -> list:
+        return self.first.mixers() + self.second.mixers()
+
+    def init_params(self, key, it, dtype=jnp.float32):
+        d = self._d
+        ka, kb, ks = jax.random.split(key, 3)
+        mk = lambda k, shape, fi, fo: self._winit(k, shape, fi, fo, dtype)
+        p = {}
+        for pre, blk, k in (("a_", self.first, ka), ("b_", self.second, kb)):
+            p.update({pre + n: v
+                      for n, v in blk.init_params(k, it, dtype).items()})
+        p.update({"sc_" + n: v for n, v in
+                  self.shortcut.init_params(ks, d, dtype, mk).items()})
+        return p
+
+    def compose(self, p, x, mix_a, mix_b, count_mask=None):
+        """The layer with its two mixers given as callables `(mixer's
+        parameters, its normed input) -> its output`, so that the whole
+        forward and the decode engine's cached steps are one arithmetic.
+        Returns (out, the shortcut feed-forward's counts under
+        `count_mask`, or None); a routed `first.ffn` / `second.ffn` is
+        not counted."""
+        a, b = self.first, self.second
+        pa, pb = sub(p, "a_"), sub(p, "b_")
+        h1 = a.after_mixer(pa, x, mix_a(sub(pa, "mx_"), a.mixer_in(pa, x)))
+        x1 = a.ffn_in(pa, h1)
+        with jax.named_scope("moe.shortcut"):
+            m, counts = self.shortcut.forward(sub(p, "sc_"), x1, count_mask)
+        h2 = a.after_ffn(pa, h1, a.ffn.forward(sub(pa, "ff_"), x1)[0])
+        h4, _ = b.finish(pb, h2,
+                         mix_b(sub(pb, "mx_"), b.mixer_in(pb, h2)))
+        return h4 + m, counts
+
+    def forward(self, params, state, x, *, train=False, rng=None,
+                mask=None):
+        out, _ = self.compose(params, x, self.first.mixer.forward,
+                              self.second.mixer.forward)
+        return out, state
+
+    def param_flags(self, name):
+        if name.startswith("sc_"):
+            return self.first.param_flags("ff_" + name[3:])
+        return self.first.param_flags(name[2:])

@@ -1,0 +1,409 @@
+"""Pallas TPU paged latent attention: the absorbed decode step of
+multi-head latent attention (`nn/conf/decoder_block.LatentAttentionMixer`)
+over a paged pool of latents, and the pool's one-position write.
+
+A position's cache is ONE vector for all heads, `[c | k_r]`: the normed
+key/value latent (`kv_rank`, 512) and the turned rope key (`rope`, 64).
+A sub-layer's pool is `(P+1, kv_rank + rope, page)`: the latent's 576
+numbers on sublanes (36 bfloat16 tiles of 16 rows: none of it padding,
+where a `(page, 576)` page would be stored 640 lanes wide), the page's
+positions on lanes, page 0 the trash page, allocated and named by the
+same page table as every other paged pool. The existing
+`pallas_paged_attention` kernel has separate K and V pools of one head
+size: through it the latent would be stored and read twice.
+
+`mla_attend`: grid over slots; for each slot a loop over its LIVE pages
+(`pos // page + 1` of them, whatever the table's width), each page
+copied from HBM into one of two VMEM buffers once, while the page before
+it is computed on, and used twice: all `H` heads' absorbed queries
+`(H, 576)` against the whole page for the scores, and the probabilities
+against its first `kv_rank` rows for the values, under the online-softmax
+recurrence. Out comes `u (S, H, kv_rank)`, the probabilities' sum of
+latents a head; the mixer takes it up through `W^V` (`mla.out`).
+
+`latent_write`: one decode position a slot, written into the donated
+pool in place (`pallas_paged_kv_write`'s discipline: the pool is an
+aliased operand in the layout the attend kernel reads, so neither decode
+program copies it; as an XLA scatter on the lane axis the write would
+cost two whole-pool copies a step). A lane cannot be addressed below the
+128-wide tile, so a slot's write moves its page tile in and out.
+
+Dispatch rides `ops/kernel_dispatch.py` under the families `mla_attend`
+and `latent_write`: each probe compiles and runs its kernel at the exact
+shape class and holds it to the `jax.numpy` form beside it
+(`mla_attend_xla`: gather the slot's pages, then attend; `latent_write_xla`:
+the scatter); `DL4J_TPU_NO_PALLAS_MLA_ATTEND` forces the XLA forms; CPU
+backends never dispatch.
+"""
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.ops.kernel_dispatch import (
+    platform_supported as _kernels_dispatch,
+    probe_verdict as _probe_verdict,
+    record_decline as _record_decline,
+    vmem_limit_bytes as _vmem_limit,
+)
+
+FAMILY = "mla_attend"          # this module's rows in kernel_verdicts()
+WRITE_FAMILY = "latent_write"
+NEG_INF = -1e30
+
+
+# ------------------------------------------------------------ XLA forms
+def gather_latents(pool, page_table):
+    """Each row of `page_table` (S, n_pages) gathered into a dense run
+    of latents (S, n_pages * page, kv_rank + rope), entry `s` the
+    position `s`."""
+    S, n_pages = page_table.shape
+    lat = jnp.swapaxes(pool[page_table], 2, 3)   # (S, n_pages, page, R)
+    return lat.reshape(S, n_pages * pool.shape[2], pool.shape[1])
+
+
+def mla_attend_xla(q_abs, pool, page_table, pos, *, kv_rank: int,
+                   sm_scale: float):
+    """Gather-and-attend: each slot's page-table row gathered into a
+    dense `(Tk, kv_rank + rope)` run of latents, then one absorbed query
+    a head against it. `q_abs` (S, H, kv_rank + rope); `pool` (P+1,
+    kv_rank + rope, page); `page_table` (S, n_pages); `pos` (S,): slot
+    `s` sees entries `<= pos[s]`. Returns `u` (S, H, kv_rank)."""
+    lat = gather_latents(pool, page_table)
+    s = jnp.einsum("shr,str->sht", q_abs, lat,
+                   preferred_element_type=jnp.float32) * sm_scale
+    seen = jnp.arange(lat.shape[1])[None, :] <= pos[:, None]
+    s = jnp.where(seen[:, None, :], s, NEG_INF)
+    prob = jax.nn.softmax(s, axis=-1).astype(lat.dtype)
+    return jnp.einsum("sht,str->shr", prob, lat[..., :kv_rank],
+                      preferred_element_type=jnp.float32).astype(q_abs.dtype)
+
+
+def latent_write_xla(pool, new, pids, loff):
+    """One position a slot scattered into the pool: `new` (S, kv_rank +
+    rope) lands at in-page offset `loff[s]` of page `pids[s]`."""
+    return pool.at[pids, :, loff].set(new.astype(pool.dtype))
+
+
+# ------------------------------------------------------- attend kernel
+def _attend_kernel(pt_ref, pos_ref, gate_ref, q_ref, pool_hbm, o_ref,
+                   buf, sem, state, acc_scr, m_scr, l_scr, *, page: int,
+                   kv_rank: int, n_pages: int, sm_scale: float):
+    """Grid (S,), slots in order: slot `s` walks pages `0 .. pos //
+    page` of its page-table row. The pool stays in HBM; each live page
+    is copied once into one of two VMEM buffers while the page before it
+    is computed on, and a slot's last iteration starts the next slot's
+    first copy (`pallas_paged_attention`'s hand-over: `state` carries
+    which buffer the slot's first page lands in and whether the slot
+    before it started that copy)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s = pl.program_id(0)
+    S = pl.num_programs(0)
+
+    def live_pages(slot):
+        n = jnp.minimum(pos_ref[slot] // page + 1, n_pages)
+        return jnp.where(gate_ref[slot] != 0, n, 0)
+
+    def copy(slot, j, b):
+        return pltpu.make_async_copy(pool_hbm.at[pt_ref[slot, j]],
+                                     buf.at[b], sem.at[b])
+
+    @pl.when(s == 0)
+    def _first_slot():
+        state[0] = 0
+        state[1] = 0
+
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+
+    p0 = pos_ref[s]
+    n_live = live_pages(s)
+    nxt = jnp.minimum(s + 1, S - 1)
+    n_next = jnp.where(s + 1 < S, live_pages(nxt), 0)
+    buf0 = state[0]
+
+    @pl.when((n_live > 0) & (state[1] == 0))
+    def _start_cold():
+        copy(s, 0, buf0).start()
+
+    q = q_ref[0]                                          # (H, R)
+    H = q.shape[0]
+
+    def _page(j, carry):
+        b = (buf0 + j) % 2
+
+        @pl.when(j + 1 < n_live)
+        def _fetch_next_page():
+            copy(s, j + 1, 1 - b).start()
+
+        @pl.when((j + 1 == n_live) & (n_next > 0))
+        def _fetch_next_slot():
+            copy(nxt, 0, 1 - b).start()
+
+        copy(s, j, b).wait()
+        c = buf[b]                                        # (R, page)
+        sc = jnp.dot(q, c, preferred_element_type=jnp.float32) * sm_scale
+        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (H, page), 1)
+        sc = jnp.where(kpos <= p0, sc, NEG_INF)
+        m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.where(sc <= NEG_INF / 2, 0.0, jnp.exp(sc - m_new))
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        # the page again, its first kv_rank rows: positions on lanes in
+        # both operands, so the product contracts the lane axes
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p.astype(c.dtype), c[:kv_rank], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
+
+    jax.lax.fori_loop(0, n_live, _page, 0)
+
+    @pl.when(n_live > 0)
+    def _hand_over():
+        state[0] = (buf0 + n_live) % 2
+        state[1] = (n_next > 0).astype(jnp.int32)
+
+    l = l_scr[:, :1]
+    o_ref[0] = jnp.where(l > 0, acc_scr[...] / jnp.where(l > 0, l, 1.0),
+                         0.0).astype(o_ref.dtype)
+
+
+# jitted, like the family's other serving kernels, so that a program
+# which attends in eight sub-layers traces and lowers the kernel once
+# and calls it eight times (PERF.md, PR 26 and PR 34)
+@functools.partial(jax.jit, static_argnames=("kv_rank", "sm_scale",
+                                             "interpret"))
+def mla_attend(q_abs, pool, page_table, pos, active, *, kv_rank: int,
+               sm_scale: float, interpret: bool = False):
+    """`mla_attend_xla` streamed from the pool: only entries `0 ..
+    pos[s] // page` of a slot's table row are read; a slot that `active`
+    (S,) bool leaves out reads no page and comes out zeros."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, H, R = q_abs.shape
+    page = pool.shape[2]
+    kernel = functools.partial(
+        _attend_kernel, page=page, kv_rank=kv_rank,
+        n_pages=page_table.shape[1], sm_scale=sm_scale)
+    slot = lambda s, pt, p0, g: (s, 0, 0)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((1, H, R), slot),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, H, kv_rank), slot),
+            scratch_shapes=[
+                pltpu.VMEM((2, R, page), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2,), jnp.int32),        # buffer, copy started
+                pltpu.VMEM((H, kv_rank), jnp.float32),
+                pltpu.VMEM((H, 128), jnp.float32),  # running max m
+                pltpu.VMEM((H, 128), jnp.float32),  # running denom l
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S, H, kv_rank), q_abs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # in order: a slot's last iteration starts the next slot's
+            # first copy
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit()),
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
+      jnp.asarray(active).astype(jnp.int32), q_abs, pool)
+
+
+# -------------------------------------------------------- write kernel
+def _write_kernel(pid_ref, off_ref, new_ref, pool_in, pool_out):
+    from jax.experimental import pallas as pl
+
+    del pid_ref  # the index maps read it
+    off = off_ref[pl.program_id(0)]
+    _, R, page = pool_in.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (R, page), 1)
+    # the select runs on 32-bit values: bfloat16 widens and narrows
+    # back exactly
+    pool_out[0] = jnp.where(lane == off, new_ref[0].astype(jnp.float32),
+                            pool_in[0].astype(jnp.float32)) \
+        .astype(pool_out.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def latent_write(pool, new, pids, loff, *, interpret: bool = False):
+    """`latent_write_xla` as one in-place kernel call: the pool is
+    aliased input to output, so under donation (or inside a loop's
+    carry) it is not copied, and pages no slot names are never touched.
+    Inactive lanes arrive redirected to the trash page 0, where they may
+    collide; what it holds afterwards is unspecified."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, R = new.shape
+    page = pool.shape[2]
+    tile = pl.BlockSpec((1, R, page), lambda s, pid, off: (pid[s], 0, 0))
+    return pl.pallas_call(
+        _write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((1, R, 1), lambda s, pid, off:
+                                   (s, 0, 0)), tile],
+            out_specs=tile),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            # sequential: colliding trash-page writes stay ordered
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit()),
+        interpret=interpret,
+    )(pids.astype(jnp.int32), loff.astype(jnp.int32),
+      new.astype(pool.dtype)[..., None], pool)
+
+
+# ------------------------------------------------------------ dispatch
+def _platform_supported() -> bool:
+    return _kernels_dispatch("DL4J_TPU_NO_PALLAS_MLA_ATTEND")
+
+
+def _attend_probe(dtype, H: int, R: int, kv_rank: int, page: int,
+                  sm_scale: float) -> bool:
+    """Compile and run the attend kernel at this shape class and hold it
+    to gather-and-attend: a table wider than any slot's live pages whose
+    dead entries name a page of NaNs (a read of a page no query can see
+    fails the comparison), one slot ending on a page's last position,
+    one on the next page's first, one inactive."""
+    import numpy as np
+
+    pos = np.asarray([page - 1, page, 5], np.int32)
+    live = pos // page + 1
+    n_pages = int(live.max()) + 2
+    P = int(live.sum())
+    dead = P + 1
+    pt = np.full((3, n_pages), dead, np.int32)
+    at = 1
+    for s, n in enumerate(live):
+        pt[s, :n] = at + np.arange(n)
+        at += n
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((3, H, R)) / R ** 0.25, dtype)
+    pool = jnp.asarray(rng.standard_normal((P + 2, R, page)), dtype)
+    active = jnp.asarray([True, True, False])
+    got = np.asarray(mla_attend(
+        q, pool.at[dead].set(jnp.nan), jnp.asarray(pt), jnp.asarray(pos),
+        active, kv_rank=kv_rank, sm_scale=sm_scale), np.float32)
+    want = np.asarray(mla_attend_xla(
+        q, pool, jnp.asarray(np.where(pt == dead, 0, pt)), jnp.asarray(pos),
+        kv_rank=kv_rank, sm_scale=sm_scale), np.float32)
+    if not np.all(np.isfinite(got)):
+        return False
+    if np.any(got[2] != 0):
+        raise ValueError("an inactive slot did not come out zeros")
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
+    if not np.allclose(got[:2], want[:2], atol=tol, rtol=tol):
+        raise ValueError(
+            "kernel compiled but disagrees with gather-and-attend: max "
+            f"abs err {np.max(np.abs(got[:2] - want[:2])):.3g} at "
+            f"atol=rtol={tol:g}")
+    return True
+
+
+def _write_probe(dtype, R: int, page: int) -> bool:
+    """Compile and run the write kernel at this shape class and check
+    every page but the trash page against the scatter, bit for bit: a
+    page's first and last offsets, two inactive lanes colliding on page
+    0, then a second call over the first call's output."""
+    import numpy as np
+
+    S, P = 4, 4
+    rng = np.random.default_rng(0)
+    got = want = jnp.asarray(rng.standard_normal((P + 1, R, page)), dtype)
+    pids = jnp.asarray([3, 0, 1, 0], jnp.int32)
+    for loff in ([0, 5, page - 2, 7], [1, 6, page - 1, 8]):
+        loff = jnp.asarray(loff, jnp.int32)
+        new = jnp.asarray(rng.standard_normal((S, R)), dtype)
+        got = latent_write(got, new, pids, loff)
+        want = latent_write_xla(want, new, pids, loff)
+    g, w = np.asarray(got[1:]), np.asarray(want[1:])
+    if g.tobytes() != w.tobytes():
+        raise ValueError("kernel compiled but its pool differs from the "
+                         "scatter's outside the trash page")
+    return True
+
+
+def mla_attend_or_none(q_abs, pool, page_table, pos, active, *,
+                       kv_rank: int, sm_scale: float) -> Optional[jnp.ndarray]:
+    """Dispatch probe: the streamed attention, or None when the kernel
+    cannot serve this call (CPU backend, kill switch, a dtype or widths
+    Mosaic does not tile) or its shape class failed the compile+parity
+    probe; callers run `mla_attend_xla`."""
+    S, H, R = q_abs.shape
+    page = pool.shape[2]
+    dtype = q_abs.dtype
+    if not _platform_supported() or pool.dtype != dtype \
+            or dtype not in (jnp.float32, jnp.bfloat16):
+        return None
+    key = (jnp.dtype(dtype).name, H, R, kv_rank, page)
+    rows = 32 // jnp.dtype(dtype).itemsize
+    if H % 8 or R % rows or kv_rank % 128 or page % 128:
+        _record_decline(FAMILY, key, f"{H} heads, latent {kv_rank} of {R}, "
+                                     f"page {page}: off the tile grid")
+        return None
+    if not _probe_verdict(FAMILY, key, _attend_probe,
+                          (dtype, H, R, kv_rank, page, sm_scale)):
+        return None
+    try:
+        return mla_attend(q_abs, pool, page_table, pos, active,
+                          kv_rank=kv_rank, sm_scale=sm_scale)
+    except Exception as e:  # per-shape staging failure: fall back
+        _record_decline(FAMILY, key, f"staging at {q_abs.shape}: "
+                                     f"{type(e).__name__}: {e}")
+        return None
+
+
+def latent_write_or_none(pool, new, pids, loff) -> Optional[jnp.ndarray]:
+    """Dispatch probe: the written pool, or None (callers run
+    `latent_write_xla`)."""
+    _, R, page = pool.shape
+    dtype = pool.dtype
+    if not _platform_supported() \
+            or dtype not in (jnp.float32, jnp.bfloat16):
+        return None
+    key = (jnp.dtype(dtype).name, R, page)
+    if R % (32 // jnp.dtype(dtype).itemsize) or page % 128:
+        _record_decline(WRITE_FAMILY, key, f"latent {R}, page {page}: off "
+                                           "the tile grid")
+        return None
+    if not _probe_verdict(WRITE_FAMILY, key, _write_probe,
+                          (dtype, R, page)):
+        return None
+    try:
+        return latent_write(pool, new, pids, loff)
+    except Exception as e:  # per-shape staging failure: fall back
+        _record_decline(WRITE_FAMILY, key, f"staging at {pool.shape}: "
+                                           f"{type(e).__name__}: {e}")
+        return None
+
+
+def attend(q_abs, pool, page_table, pos, active, *, kv_rank: int,
+           sm_scale: float):
+    """The kernel on a TPU, gather-and-attend elsewhere."""
+    out = mla_attend_or_none(q_abs, pool, page_table, pos, active,
+                             kv_rank=kv_rank, sm_scale=sm_scale)
+    return mla_attend_xla(q_abs, pool, page_table, pos, kv_rank=kv_rank,
+                          sm_scale=sm_scale) if out is None else out
+
+
+def write(pool, new, pids, loff):
+    """The in-place kernel on a TPU, the scatter elsewhere."""
+    out = latent_write_or_none(pool, new, pids, loff)
+    return latent_write_xla(pool, new, pids, loff) if out is None else out

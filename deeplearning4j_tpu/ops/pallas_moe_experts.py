@@ -20,14 +20,25 @@ over (token tiles, experts): one grid step brings one whole expert
 float32 accumulator stay resident, so an expert's weights cross HBM once
 per token tile.
 
+An expert too large for VMEM (three 6144 x 2048 bfloat16 matrices are
+75.5 MB, 151 MB double-buffered, over the 112 MiB ceiling) is brought in
+TILES of its width `f`: a third grid axis, innermost, over `f / tf`
+column tiles of `Wg` / `Wu` and row tiles of `Wd` (the gated product is
+a sum over `f`, so each tile adds its part to the same accumulator).
+`f_tile` picks `tf`: all of `f` where one whole expert fits, as every
+shape did before there were tiles, and the program is then the
+two-axis one it always was; else the largest divisor of `f` on the
+tile grid that fits.
+
 The walk is hit-first. `hit` (E,) bool says which held experts some row
 that matters chose (`parallel.experts.dropless_moe`: a gate that is not
 zero on a live row). A Pallas TPU grid is static, so the expert axis
 keeps its `E` steps, but the block indices of the weights and of the
 gate column come from a scalar-prefetched vector: step `j` names the
 `j`-th hit expert in the experts' own order, and every step past the
-last hit one names that one again, which copies nothing (a block whose
-index is the previous step's stays where it is) and computes nothing.
+last hit one names that one again (and its last tile), which copies
+nothing (a block whose index is the previous step's stays where it is)
+and computes nothing.
 So the kernel moves the matrices of the experts that were hit and no
 others: the least a decode step can move, whether every held expert is
 chosen (64 slots x top-10 of 72: every step) or a fifth of them are not
@@ -68,14 +79,18 @@ GATED_SILU, RELU2 = "gated_silu", "relu2"  # the experts' activations
 _MAX_ROWS = 512         # token rows per tile
 
 
-def _experts_kernel(walk_ref, n_hit_ref, x_ref, g_ref, *refs, act: str):
+def _experts_kernel(walk_ref, n_hit_ref, x_ref, g_ref, *refs, act: str,
+                    tiled: bool):
     from jax.experimental import pallas as pl
 
     del walk_ref  # the index maps read it
     *w_refs, o_ref, acc_ref = refs
     e = pl.program_id(1)
+    first = e == 0
+    if tiled:
+        first &= pl.program_id(2) == 0
 
-    @pl.when(e == 0)
+    @pl.when(first)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -96,7 +111,11 @@ def _experts_kernel(walk_ref, n_hit_ref, x_ref, g_ref, *refs, act: str):
         acc_ref[...] += jnp.dot(h.astype(x.dtype), wd_ref[0],
                                 preferred_element_type=jnp.float32)
 
-    @pl.when(e == pl.num_programs(1) - 1)
+    last = e == pl.num_programs(1) - 1
+    if tiled:
+        last &= pl.program_id(2) == pl.num_programs(2) - 1
+
+    @pl.when(last)
     def _():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
@@ -133,57 +152,106 @@ def hit_first_walk(hit):
     return walk, n_hit
 
 
+def _grid_specs(N: int, d: int, E: int, f: int, tn: int, tf: int, act: str):
+    """(grid, in_specs, out_spec) of the walk: two axes (token tiles,
+    experts) where one tile is the whole width, and a third, innermost,
+    over the `f // tf` tiles where it is not."""
+    from jax.experimental import pallas as pl
+
+    nf = f // tf
+    if nf > 1:
+        # past the last hit expert its last tile again: nothing moves
+        def held(e, j, n_hit):
+            return jnp.where(e < n_hit[0], j, nf - 1)
+
+        rows = lambda *shape: pl.BlockSpec(       # a tile of f on rows
+            (1,) + shape, lambda n, e, j, walk, n_hit:
+            (walk[e], held(e, j, n_hit), 0))
+        cols = lambda *shape: pl.BlockSpec(       # a tile of f on lanes
+            (1,) + shape, lambda n, e, j, walk, n_hit:
+            (walk[e], 0, held(e, j, n_hit)))
+        tile = pl.BlockSpec((tn, d), lambda n, e, j, walk, n_hit: (n, 0))
+        # (E, N, 1): one expert's gate column arrives as a (tn, 1) block
+        gate = pl.BlockSpec((1, tn, 1), lambda n, e, j, walk, n_hit:
+                            (walk[e], n, 0))
+    else:
+        rows = cols = lambda *shape: pl.BlockSpec(
+            (1,) + shape, lambda n, e, walk, n_hit: (walk[e], 0, 0))
+        tile = pl.BlockSpec((tn, d), lambda n, e, walk, n_hit: (n, 0))
+        gate = pl.BlockSpec((1, tn, 1), lambda n, e, walk, n_hit:
+                            (walk[e], n, 0))
+    specs = [rows(tf, d), rows(tf, d)] if act == RELU2 \
+        else [cols(d, tf), cols(d, tf), rows(tf, d)]
+    return (N // tn, E) + ((nf,) if nf > 1 else ()), \
+        [tile, gate, *specs], tile
+
+
 # jitted so that a step over many layers traces and lowers the kernel
 # once and calls it once a layer (`pallas_paged_kv_write`'s lesson)
-@functools.partial(jax.jit, static_argnames=("act", "interpret"))
+@functools.partial(jax.jit, static_argnames=("act", "interpret", "tf"))
 def moe_experts(x, gates, Wg, Wu, Wd, hit, *, act: str = GATED_SILU,
-                interpret: bool = False):
+                interpret: bool = False, tf: int = 0):
     """The grouped product over the experts `hit` (E,) bool marks; the
     caller marks every expert whose gate is not zero on a row it will
-    read."""
+    read. `tf`: the tile of the experts' width `f` one grid step brings
+    (0: `f_tile`'s choice)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     N, d = x.shape
     E, f, _ = Wd.shape
     tn = _row_tile(N)
-    # (E, N, 1): one expert's gate column arrives as a (tn, 1) block
+    tf = tf or f_tile(tn, d, f, x.dtype, act) or f
+    grid, in_specs, out_spec = _grid_specs(N, d, E, f, tn, tf, act)
     g3 = jnp.swapaxes(gates.astype(jnp.float32), 0, 1)[..., None]
-    whole = lambda *shape: pl.BlockSpec(
-        (1,) + shape, lambda n, e, walk, n_hit: (walk[e], 0, 0))
-    weights, specs = ((Wu, Wd), [whole(f, d), whole(f, d)]) \
-        if act == RELU2 else \
-        ((Wg, Wu, Wd), [whole(d, f), whole(d, f), whole(f, d)])
-    tile = pl.BlockSpec((tn, d), lambda n, e, walk, n_hit: (n, 0))
     return pl.pallas_call(
-        functools.partial(_experts_kernel, act=act),
+        functools.partial(_experts_kernel, act=act, tiled=len(grid) == 3),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(N // tn, E),
-            in_specs=[tile,
-                      pl.BlockSpec((1, tn, 1), lambda n, e, walk, n_hit:
-                                   (walk[e], n, 0)),
-                      *specs],
-            out_specs=tile,
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_spec,
             scratch_shapes=[pltpu.VMEM((tn, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((N, d), x.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel",)
+            + ("arbitrary",) * (len(grid) - 1),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
-    )(*hit_first_walk(hit), x, g3, *weights)
+    )(*hit_first_walk(hit), x, g3,
+      *((Wu, Wd) if act == RELU2 else (Wg, Wu, Wd)))
 
 
 def vmem_bytes_estimate(tn: int, d: int, f: int, dtype,
                         act: str = GATED_SILU) -> int:
-    """Resident VMEM of one grid step: the expert's matrices (three, or
-    two ungated), double-buffered; the token tile and the output tile,
+    """Resident VMEM of one grid step that brings `f` of an expert's
+    width (all of it, or one tile): the expert's matrices (three, or two
+    ungated), double-buffered; the token tile and the output tile,
     double-buffered; the float32 accumulator and the (tn, f) float32
     intermediates."""
     item = jnp.dtype(dtype).itemsize
     n_mat = 2 if act == RELU2 else 3
     return 2 * n_mat * d * f * item + 4 * tn * d * item + 4 * tn * d \
         + n_mat * 4 * tn * f
+
+
+def _f_grid(dtype, act: str) -> int:
+    """`f` lies on lanes in the gated variant's (d, f) matrices and on
+    sublanes (16 rows a bf16 tile) in the ungated one's (f, d)."""
+    return 128 if act != RELU2 else 32 // jnp.dtype(dtype).itemsize
+
+
+def f_tile(tn: int, d: int, f: int, dtype, act: str = GATED_SILU) -> int:
+    """The tile of `f` one grid step brings: `f` itself where a whole
+    expert fits under the VMEM ceiling (one tile: the two-axis program),
+    else the largest divisor of `f` on the tile grid that fits; 0 where
+    none does."""
+    limit, grid = _vmem_limit(), _f_grid(dtype, act)
+    if vmem_bytes_estimate(tn, d, f, dtype, act) <= limit:
+        return f
+    for n in range(2, f // grid + 1):
+        if f % n == 0 and (f // n) % grid == 0 and \
+                vmem_bytes_estimate(tn, d, f // n, dtype, act) <= limit:
+            return f // n
+    return 0
 
 
 def _platform_supported() -> bool:
@@ -227,7 +295,8 @@ def moe_experts_or_none(x, gates, Wg, Wu, Wd, hit, act: str = GATED_SILU):
     does not tile, a width off the tile grid of the axis it lies on,
     VMEM overflow) or its shape class failed the compile+parity probe.
     The gated variant's key is `(dtype, rows, d, f)` as it always was;
-    the ungated one's ends in its name."""
+    the ungated one's ends in its name. An expert that does not fit
+    whole is brought in tiles of `f` (`f_tile`)."""
     N, d = x.shape
     E, f, _ = Wd.shape
     dtype = x.dtype
@@ -238,18 +307,15 @@ def moe_experts_or_none(x, gates, Wg, Wu, Wd, hit, act: str = GATED_SILU):
     key = (jnp.dtype(dtype).name, tn, d, f)
     if act == RELU2:
         key += (RELU2,)
-    # `f` lies on lanes in the gated variant's (d, f) matrices and on
-    # sublanes (16 rows a bf16 tile) in the ungated one's (f, d)
-    f_grid = 128 if act != RELU2 else 32 // jnp.dtype(dtype).itemsize
-    if not tn or tn % 8 or d % 128 or f % f_grid:
+    if not tn or tn % 8 or d % 128 or f % _f_grid(dtype, act):
         _record_decline(FAMILY, key, f"{N} rows, widths {d} x {f}: off "
                                      "the (8, 128) tile grid")
         return None
-    est = vmem_bytes_estimate(tn, d, f, dtype, act)
-    if est > _vmem_limit():
+    if not f_tile(tn, d, f, dtype, act):
+        est = vmem_bytes_estimate(tn, d, _f_grid(dtype, act), dtype, act)
         _record_decline(FAMILY, key,
-                        f"needs ~{est >> 20} MiB VMEM > "
-                        f"{_vmem_limit() >> 20} MiB ceiling")
+                        f"needs ~{est >> 20} MiB VMEM at the smallest "
+                        f"tile of f > {_vmem_limit() >> 20} MiB ceiling")
         return None
     if not _probe_verdict(FAMILY, key, _eager_probe,
                           (dtype, tn, d, f, act)):

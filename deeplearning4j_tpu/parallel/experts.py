@@ -300,16 +300,45 @@ def sigmoid_topk_gates(logits: jnp.ndarray, bias: jnp.ndarray, top_k: int,
     return jnp.zeros(logits.shape, jnp.float32).at[rows, top_i].set(w)
 
 
+def softmax_all_topk_gates(logits: jnp.ndarray, bias: jnp.ndarray,
+                           top_k: int, scale: float) -> jnp.ndarray:
+    """(N, E) router logits -> (N, E) float32 gates, scored by a
+    softmax over ALL the logits (the LongCat-Flash router): the `top_k`
+    experts are chosen on `softmax(logits) + bias` (`bias` (E,): it
+    moves the choice and never the weight), and a chosen expert's gate
+    is its unbiased score times `scale`, NOT renormalised over the
+    chosen; zero elsewhere."""
+    s = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, top_i = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    rows = jnp.arange(logits.shape[0])[:, None]
+    return jnp.zeros(logits.shape, jnp.float32).at[rows, top_i].set(
+        s[rows, top_i] * scale)
+
+
+def routed_gates(logits: jnp.ndarray, top_k: int, *, bias=None,
+                 scale: float = 1.0, scoring: str = None) -> jnp.ndarray:
+    """(N, E) float32 gates over everything the router scores, by
+    `scoring`: "softmax" (over the chosen: `topk_gates`), "sigmoid"
+    (`sigmoid_topk_gates`) or "softmax_all"
+    (`softmax_all_topk_gates`); None: sigmoid where there is a `bias`,
+    else softmax."""
+    scoring = scoring or ("softmax" if bias is None else "sigmoid")
+    if scoring == "softmax":
+        return topk_gates(logits, top_k)
+    fn = sigmoid_topk_gates if scoring == "sigmoid" \
+        else softmax_all_topk_gates
+    return fn(logits, bias, top_k, scale)
+
+
 def held_gates(logits: jnp.ndarray, top_k: int, experts_held, *,
-               bias=None, scale: float = 1.0) -> jnp.ndarray:
+               bias=None, scale: float = 1.0,
+               scoring: str = None) -> jnp.ndarray:
     """The gates of the experts held here, (N, count): a column of
-    zeros for a held expert that no token chose. With a `bias` the
-    router scores by sigmoid (`sigmoid_topk_gates`), else by softmax
-    over the chosen (`topk_gates`)."""
+    zeros for a held expert that no token chose. `scoring`, `bias` and
+    `scale` as `routed_gates`."""
     first, count = experts_held
-    gates = topk_gates(logits, top_k) if bias is None \
-        else sigmoid_topk_gates(logits, bias, top_k, scale)
-    return gates[:, first:first + count]
+    return routed_gates(logits, top_k, bias=bias, scale=scale,
+                        scoring=scoring)[:, first:first + count]
 
 
 def grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act: str = GATED_SILU):
@@ -373,28 +402,46 @@ def relu2_mlp(x, Wu, Wd):
 
 def dropless_moe(x, router, Wg, Wu, Wd, *, top_k: int, experts_held,
                  count_mask=None, act: str = GATED_SILU, router_bias=None,
-                 routed_scale: float = 1.0):
+                 routed_scale: float = 1.0, scoring: str = None,
+                 n_zero: int = 0):
     """Top-k dropless routing over `router.shape[1]` experts, computed
     for the experts held. `x` (N, d). `act` and the matrices as
-    `grouped_expert_ffn_xla`; `router_bias` and `routed_scale` as
-    `held_gates`. `count_mask` (N,) bool says which rows anyone will
+    `grouped_expert_ffn_xla`; `router_bias`, `routed_scale` and
+    `scoring` as `routed_gates`. The router's last `n_zero` outputs are
+    zero-compute experts: one that a token chose adds the token itself
+    under its gate, `(sum of its chosen zero experts' gates) * x`. That
+    part is the chip's own tokens', whatever share of the real experts
+    it holds, and is never exchanged. `count_mask` (N,) bool says which
+    rows anyone will
     read (a decode step's active slots; None: all): an expert that none
     of them chose is not read, and a masked-out row comes out without
     it. Returns (y (N, d), counts): with a `count_mask`, `counts` is
     int32 (2, count), how many of the masked-in tokens chose each held
-    expert, and whether the grouped product was told to read it; else
-    None."""
+    expert, and whether the grouped product was told to read it (with
+    `n_zero`, the pair of that and an int32 scalar: how many of the
+    masked-in tokens' choices fell on zero experts); else None."""
+    first, count = experts_held
     with jax.named_scope("moe.route"):
         logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
-        gates = held_gates(logits, top_k, experts_held, bias=router_bias,
-                           scale=routed_scale)
-        # both routers' gates are >= 0: not zero is chosen
+        all_gates = routed_gates(logits, top_k, bias=router_bias,
+                                 scale=routed_scale, scoring=scoring)
+        gates = all_gates[:, first:first + count]
+        # every router's gates are >= 0: not zero is chosen
         chose = gates != 0
         if count_mask is not None:
             chose &= count_mask[:, None]
         hit = jnp.any(chose, axis=0)
     with jax.named_scope("moe.experts"):
         y = grouped_expert_ffn(x, gates, Wg, Wu, Wd, hit, act)
+    if n_zero:
+        with jax.named_scope("moe.zero"):
+            zero_gates = all_gates[:, all_gates.shape[1] - n_zero:]
+            y = y + (jnp.sum(zero_gates, axis=1, keepdims=True)
+                     * x.astype(jnp.float32)).astype(y.dtype)
     if count_mask is None:
         return y, None
-    return y, jnp.stack([jnp.sum(chose, axis=0), hit]).astype(jnp.int32)
+    counts = jnp.stack([jnp.sum(chose, axis=0), hit]).astype(jnp.int32)
+    if not n_zero:
+        return y, counts
+    return y, (counts, jnp.sum((zero_gates != 0) & count_mask[:, None],
+                               dtype=jnp.int32))

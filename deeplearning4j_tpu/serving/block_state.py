@@ -22,6 +22,18 @@ kind each block keeps (`GPTPlan.state_kinds`):
                     carried from one prefill chunk to the next, left
                     alone by pad positions and by inactive slots, and
                     advanced in place by every decode step;
+    LatentPages     ONE paged pool of latents a sub-layer, `(P+1,
+                    kv_rank + rope, page)` (`ops/pallas_mla_attend.py`):
+                    a position's cache is one vector for all heads, the
+                    normed key/value latent and the turned rope key
+                    (`LatentAttentionMixer`); allocated by page from the
+                    same page table as K/V pools, written one position a
+                    token, read through the page table. Expanded
+                    attention over the prompt in `prefill`, a chunk's
+                    absorbed queries against the cached latents in
+                    `prefill_chunk`, the absorbed step in `decode`;
+    ShortcutPair    a `ShortcutDecoderBlock`'s two mixers' states, each
+                    with its own cache: the block's cache is the pair;
     Stateless       nothing: a composed block without a mixer (a
                     feed-forward under its norm and residual) reads no
                     cache and writes none, whatever the slot or the
@@ -53,8 +65,8 @@ from deeplearning4j_tpu.serving.quantize import (
 
 class RecurrentStateUnsupported(ValueError):
     """An engine feature was asked for that cannot hold a block with
-    per-slot recurrent state yet (or a composed block at all). Raised
-    when the engine is built, never from inside a step."""
+    per-slot recurrent state or latent pages yet (or a composed block at
+    all). Raised when the engine is built, never from inside a step."""
 
 
 class _DenseBlock:
@@ -106,9 +118,20 @@ def _finish_composed(layer, p, x, mixed, d):
     """The composed block after its mixer; a decode step's `d.counts`
     collects the feed-forward's per-expert counts over active slots."""
     out, counts = layer.finish(p, x, mixed, getattr(d, "count_mask", None))
-    if counts is not None:
-        d.counts.append(counts)
+    _collect_counts(d, counts)
     return out
+
+
+def _collect_counts(d, counts) -> None:
+    """A routed feed-forward's counts into the step's lists: per-expert
+    `(2, held)` into `d.counts`, and, where the router has zero experts
+    (then `counts` is the pair), their scalar into `d.zero_counts`."""
+    if counts is None:
+        return
+    if isinstance(counts, tuple):
+        counts, zero = counts
+        d.zero_counts.append(zero)
+    d.counts.append(counts)
 
 
 def _write_pages(kp_, vp_, kcol, vrow, wpids, woff, page):
@@ -353,6 +376,140 @@ class RecurrentSlots:
         return x, self._store(cache, h1, tail1, d.slot)
 
 
+class _ByPhase:
+    """`decode`, `prefill` and `prefill_chunk` as one `_block(which, p, x,
+    cache, d)`: the state objects whose three programs differ only in
+    which of a mixer's `mix_<which>` they run."""
+
+    def decode(self, p, x, cache, d):
+        return self._block("decode", p, x, cache, d)
+
+    def prefill(self, p, x, cache, d):
+        return self._block("prefill", p, x, cache, d)
+
+    def prefill_chunk(self, p, x, cache, d):
+        return self._block("prefill_chunk", p, x, cache, d)
+
+
+class LatentPages(_ByPhase):
+    """A block whose mixer is latent attention. The mixing itself is
+    `mix_decode` / `mix_prefill` / `mix_prefill_chunk`, `(mixer's
+    parameters, its normed input, cache, d) -> (mixed, cache)`, so that a
+    block of two mixers (`ShortcutPair`) runs each through its own."""
+    kind = "latent"
+
+    def __init__(self, layer, env):
+        self.layer, self.env, self.mixer = layer, env, layer.mixer
+        self.kv_rank, self.rope = self.mixer.latent_geometry()
+
+    def alloc(self) -> tuple:
+        env = self.env
+        # +1: page 0 is the reserved trash page for masked writes
+        return (jnp.zeros((env.pool_pages + 1, self.kv_rank + self.rope,
+                           env.page), env.cdt),)
+
+    def bytes_per_slot(self) -> int:
+        return 0  # pages are held by length, not by slot
+
+    def bytes_per_token(self) -> int:
+        return (self.kv_rank + self.rope) * jnp.dtype(self.env.cdt).itemsize
+
+    def _write_span(self, pool, latent, wpids, woff):
+        """One contiguous prefill span (1, W, R) into the pool pages
+        `wpids` (`_write_pages`' discipline: aligned full pages, then a
+        partial tail at in-page offset `woff`)."""
+        page = self.env.page
+        cols = jnp.swapaxes(latent, 1, 2).astype(pool.dtype)  # (1, R, W)
+        W = cols.shape[2]
+        z = jnp.zeros((), jnp.int32)
+        nfull = W // page
+        for j in range(nfull):
+            pool = jax.lax.dynamic_update_slice(
+                pool, cols[..., j * page:(j + 1) * page], (wpids[j], z, z))
+        if W % page:
+            pool = jax.lax.dynamic_update_slice(
+                pool, cols[..., nfull * page:], (wpids[nfull], z, woff))
+        return pool
+
+    def mix_decode(self, mp, u, cache, d):
+        from deeplearning4j_tpu.ops import pallas_mla_attend as mla
+
+        m = self.mixer
+        q_n, q_r, latent = m.project(mp, u[:, None, :], d.pos[:, None])
+        with jax.named_scope("mla.write"):
+            pool = mla.write(cache[0], latent[:, 0], d.pids, d.loff)
+        q_abs = m.absorb(mp, q_n[:, 0], q_r[:, 0])
+        with jax.named_scope("mla.attend"):
+            att = mla.attend(q_abs, pool, d.page_table, d.pos, d.active,
+                             kv_rank=self.kv_rank, sm_scale=m.sm_scale)
+        return m.out(mp, att), (pool,)
+
+    def mix_prefill(self, mp, u, cache, d):
+        m = self.mixer
+        q_n, q_r, latent = m.project(mp, u, jnp.arange(u.shape[1]))
+        mixed = m.attend_expanded(mp, q_n, q_r, latent)
+        with jax.named_scope("mla.write"):
+            pool = self._write_span(cache[0], latent, d.wpids,
+                                    jnp.zeros((), jnp.int32))
+        return mixed, (pool,)
+
+    def mix_prefill_chunk(self, mp, u, cache, d):
+        m = self.mixer
+        q_n, q_r, latent = m.project(mp, u, d.qpos)
+        with jax.named_scope("mla.write"):
+            pool = self._write_span(cache[0], latent, d.wpids, d.woff)
+        # attend AFTER the write: the chunk attends to itself through
+        # the cache, which is exactly causal with the <= qpos mask
+        from deeplearning4j_tpu.ops.pallas_mla_attend import gather_latents
+
+        att = m.attend_latents(m.absorb(mp, q_n, q_r),
+                               gather_latents(pool, d.page_row[None]),
+                               d.qpos[None])
+        return m.out(mp, att), (pool,)
+
+    def _block(self, which, p, x, cache, d):
+        mixed, cache = getattr(self, "mix_" + which)(
+            sub(p, "mx_"), self.layer.mixer_in(p, x), cache, d)
+        return _finish_composed(self.layer, p, x, mixed, d), cache
+
+
+class ShortcutPair(_ByPhase):
+    """A `ShortcutDecoderBlock`: its two mixers' states, each with a
+    cache of its own; the block's cache is the pair of theirs."""
+
+    def __init__(self, layer, env):
+        self.layer = layer
+        kinds = layer.state
+        if set(kinds) != {"latent"}:
+            raise RecurrentStateUnsupported(
+                f"a shortcut block's mixers keep {kinds}: only latent "
+                "pages are held for it yet")
+        self.parts = (LatentPages(layer.first, env),
+                      LatentPages(layer.second, env))
+        self.kind = kinds
+
+    def alloc(self) -> tuple:
+        return tuple(part.alloc() for part in self.parts)
+
+    def bytes_per_slot(self) -> int:
+        return sum(part.bytes_per_slot() for part in self.parts)
+
+    def _block(self, which, p, x, cache, d):
+        new = [None, None]
+
+        def mix(i):
+            def run(mp, u):
+                mixed, new[i] = getattr(self.parts[i], "mix_" + which)(
+                    mp, u, cache[i], d)
+                return mixed
+            return run
+
+        out, counts = self.layer.compose(
+            p, x, mix(0), mix(1), getattr(d, "count_mask", None))
+        _collect_counts(d, counts)
+        return out, tuple(new)
+
+
 class Stateless:
     kind = "none"
 
@@ -371,22 +528,31 @@ class Stateless:
     prefill = prefill_chunk = decode
 
 
-_KINDS = {"kv": KVPages, "recurrent": RecurrentSlots, "none": Stateless}
+_KINDS = {"kv": KVPages, "recurrent": RecurrentSlots, "none": Stateless,
+          "latent": LatentPages}
 
 
 def block_states(plan, env) -> list:
-    """One state object per block of the plan, by the kind it declares."""
-    return [_KINDS[kind](plan.layers[i], env)
+    """One state object per block of the plan, by the kind it declares
+    (a pair of kinds: the shortcut block's)."""
+    return [(ShortcutPair if isinstance(kind, tuple) else _KINDS[kind])(
+                plan.layers[i], env)
             for kind, i in zip(plan.state_kinds(), plan.block_is)]
+
+
+def sub_states(states) -> list:
+    """The state objects that keep a cache of their own: a block's, or
+    each of a two-mixer block's."""
+    return [part for st in states for part in getattr(st, "parts", (st,))]
 
 
 def routed_ffns(plan) -> list:
     """The routed-expert feed-forward kinds of the plan's blocks."""
     from deeplearning4j_tpu.nn.conf.decoder_block import MoEFeedForward
 
-    return [plan.layers[i].ffn for i in plan.block_is
-            if isinstance(getattr(plan.layers[i], "ffn", None),
-                          MoEFeedForward)]
+    return [ffn for i in plan.block_is
+            for ffn in getattr(plan.layers[i], "feed_forwards", list)()
+            if isinstance(ffn, MoEFeedForward)]
 
 
 def moe_held(plan) -> int:
@@ -400,3 +566,9 @@ def moe_held(plan) -> int:
             f"{sorted(held)}: the decode step returns one per-expert "
             "count vector")
     return held.pop() if held else 0
+
+
+def moe_zero_experts(plan) -> int:
+    """How many zero-compute experts the plan's routers score, all
+    blocks together (0: the step returns no count of their choices)."""
+    return sum(ffn.n_zero_experts for ffn in routed_ffns(plan))

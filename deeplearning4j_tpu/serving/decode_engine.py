@@ -646,6 +646,7 @@ class DecodeEngine:
         self.moe_experts_hit = 0  # guarded by: _cond
         self.moe_experts_read = 0  # guarded by: _cond
         self.moe_steps = 0  # guarded by: _cond
+        self.moe_zero_choices = 0  # guarded by: _cond
         self.state_resets = 0  # guarded by: _cond
         self.spec_steps = 0  # guarded by: _cond
         self.spec_proposed = 0  # guarded by: _cond
@@ -836,11 +837,15 @@ class DecodeEngine:
         self._plan = plan
         self._states = states
         self._n_held = n_held
-        self._recurrent = any(st.kind == "recurrent" for st in states)
+        kept = block_state.sub_states(states)
+        self._recurrent = any(st.kind == "recurrent" for st in kept)
         self._state_bytes_per_slot = sum(st.bytes_per_slot()
                                          for st in states)
-        self._blocks_by_kind = collections.Counter(st.kind
-                                                   for st in states)
+        # a two-mixer block counts once for each cache it keeps
+        self._blocks_by_kind = collections.Counter(st.kind for st in kept)
+        self._latent_bytes_per_token = sum(
+            st.bytes_per_token() for st in kept if st.kind == "latent")
+        self._n_zero = block_state.moe_zero_experts(plan)
         routed = block_state.routed_ffns(plan)
         self._moe_blocks = len(routed)
         self._moe_top_k = max([ffn.top_k for ffn in routed], default=0)
@@ -927,7 +932,8 @@ class DecodeEngine:
         self._plane.on_rebuild(
             pool=self._pool, weight_version=self._weight_version,
             kv_quant=kv_quant, max_len=L, n_blocks=len(states),
-            recurrent=self._recurrent)
+            kv_only=not (self._recurrent
+                         or self._blocks_by_kind["latent"]))
         self._reset_device_state()
 
     def _refuse_unsupported(self, plan) -> None:
@@ -949,17 +955,21 @@ class DecodeEngine:
         if self._tp_degree > 1:
             asked.append("parallel={'tp': N} (no sharding rule for "
                          "composed blocks)")
-        if "recurrent" in plan.state_kinds():
+        kinds = {k for kind in plan.state_kinds()
+                 for k in (kind if isinstance(kind, tuple) else (kind,))}
+        for kind, what in (("recurrent", "the recurrent state"),
+                           ("latent", "latent pages")):
+            if kind not in kinds:
+                continue
             if self._prefix_cache_cfg not in (None, False):
-                asked.append("prefix_cache (a hit needs the recurrent "
-                             "state at the shared boundary; only K/V "
-                             "pages are kept)")
+                asked.append(f"prefix_cache (a hit needs {what} at the "
+                             "shared boundary; only K/V pages are kept)")
             if self._quantize_cfg and self._quantize_cfg.get("kv"):
                 asked.append("quantize={'kv': 'int8'} (no quantized form "
-                             "of the recurrent state)")
+                             f"of {what})")
             if self._role != "both":
                 asked.append(f"role={self._role!r} (KV handoff does not "
-                             "carry recurrent state)")
+                             f"carry {what})")
         if asked:
             raise RecurrentStateUnsupported(
                 "not supported for this network's blocks yet: "
@@ -1775,6 +1785,10 @@ class DecodeEngine:
                "recurrent_blocks": self._blocks_by_kind["recurrent"],
                "kv_blocks": self._blocks_by_kind["kv"],
                "stateless_blocks": self._blocks_by_kind["none"],
+               # sub-layers that keep a paged pool of latents, and what
+               # one position costs in all of them together
+               "latent_blocks": self._blocks_by_kind["latent"],
+               "latent_bytes_per_token": self._latent_bytes_per_token,
                # routed experts, decode steps only: top-k choices of
                # active slots, those on experts held here, held experts
                # hit and held experts the grouped product was told to
@@ -1784,6 +1798,9 @@ class DecodeEngine:
                "moe_experts_hit": self.moe_experts_hit,
                "moe_experts_read": self.moe_experts_read,
                "moe_steps": self.moe_steps,
+               # of `moe_routed`, the choices that fell on zero-compute
+               # experts (0: the routers score none)
+               "moe_zero_choices": self.moe_zero_choices,
                "moe_experts_held": self._n_held * self._moe_blocks,
                # tensor-parallel tier: degree 1 when off, so dashboards
                # can chart capacity without branching on key presence;
@@ -3138,9 +3155,15 @@ class DecodeEngine:
         """One dispatch's routing counts (`step_math`): (..., 3, held),
         choices that fell on each held expert, in how many blocks each
         was hit and in how many the grouped product was told to read
-        it, for one step or a chunk of them."""
+        it, for one step or a chunk of them; where the routers score
+        zero-compute experts, the pair of that and the choices that
+        fell on those."""
+        zero = 0
+        if self._n_zero:
+            counts, zero = counts
         counts = np.asarray(counts).reshape(-1, 3, self._n_held)
         with self._cond:
+            self.moe_zero_choices += int(np.sum(zero))
             self.moe_routed += counts.shape[0] * n_live \
                 * self._moe_top_k * self._moe_blocks
             self.moe_held_choices += int(counts[:, 0].sum())

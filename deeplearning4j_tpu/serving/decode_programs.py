@@ -97,6 +97,7 @@ def build_programs(plan, states, *, n_slots: int, page: int,
     emb_i, block_is = plan.emb_i, plan.block_is
     emb, cdt = plan.emb, plan.cdt
     n_held = block_state.moe_held(plan)
+    n_zero = block_state.moe_zero_experts(plan)
 
     def _shard(fn, n_in, n_out):
         """Identity on one device; under TP the body becomes the
@@ -128,7 +129,8 @@ def build_programs(plan, states, *, n_slots: int, page: int,
             pids=jnp.where(active, page_table[rows, lpage], 0),
             # per-expert counts of the active slots' choices, where
             # the net routes
-            count_mask=active if n_held else None, counts=[])
+            count_mask=active if n_held else None, counts=[],
+            zero_counts=[])
         new_caches = []
         for bi, i in enumerate(block_is):
             x, cache = states[bi].decode(bp[i], x, caches[bi], d)
@@ -148,8 +150,11 @@ def build_programs(plan, states, *, n_slots: int, page: int,
             # summed over blocks, in how many blocks it was hit, and
             # in how many the grouped product was told to read it
             chosen, read = jnp.stack(d.counts, axis=1)
-            out += (jnp.stack([chosen.sum(0), (chosen > 0).sum(0),
-                               read.sum(0)]).astype(jnp.int32),)
+            counts = jnp.stack([chosen.sum(0), (chosen > 0).sum(0),
+                                read.sum(0)]).astype(jnp.int32)
+            # where the routers score zero-compute experts: the pair of
+            # that and how many choices fell on those, all blocks
+            out += ((counts, sum(d.zero_counts)) if n_zero else counts,)
         return out
 
     # the chunk scans the step's body, not the jitted program the

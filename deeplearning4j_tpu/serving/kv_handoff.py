@@ -91,7 +91,7 @@ class HandoffPlane:
         self._page_size = 0
         self._max_len = 0
         self._n_blocks = 0
-        self._recurrent = False
+        self._kv_only = True
         # KV migration counters: slots exported under lease / imported
         # and resumed, lease resolutions, and outbound KV wire bytes
         self.migrations_out = 0  # guarded by: _cond
@@ -136,7 +136,7 @@ class HandoffPlane:
     # -- what the scheduler tells the plane --------------------------------
     def on_rebuild(self, *, pool, weight_version: str,
                    kv_quant: Optional[str], max_len: int, n_blocks: int,
-                   recurrent: bool) -> None:
+                   kv_only: bool) -> None:
         """The engine was (re)built: a new pool and prefix cache, maybe
         new weights and geometry. A rebuild keeps the engine's cluster
         membership: the fresh cache re-publishes under the NEW weight
@@ -147,7 +147,7 @@ class HandoffPlane:
         self._page_size = pool.page_size
         self._max_len = max_len
         self._n_blocks = n_blocks
-        self._recurrent = recurrent
+        self._kv_only = kv_only
         if self._directory is not None and pool.prefix_cache is not None:
             pool.prefix_cache.bind_directory(self._directory,
                                              self._holder_id)
@@ -217,10 +217,10 @@ class HandoffPlane:
         }
 
     def _require_kv_only(self, what: str) -> None:
-        if self._recurrent:
+        if not self._kv_only:
             raise RecurrentStateUnsupported(
                 f"{what} moves K/V pages only; this engine's blocks also "
-                "keep per-slot recurrent state")
+                "keep per-slot recurrent state or latent pages")
 
     # -- cluster-global prefix cache (prefix_directory) --------------------
     def bind_prefix_directory(self, directory, holder_id: str,

@@ -162,6 +162,47 @@ def test_sublayer_phase_publishes_nemotron_nanos_widths():
     assert sz["pattern"] == "M*E"
 
 
+def test_latent_phase_toy():
+    import jax.numpy as jnp
+
+    lat = dict(chip_smoke.LATENT, vocab_size=64, hidden_size=64,
+               num_layers=2, num_attention_heads=4, q_lora_rank=24,
+               kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+               v_head_dim=8, ffn_hidden_size=48, expert_ffn_hidden_size=24,
+               n_routed_experts=4, zero_expert_num=4, moe_topk=3,
+               rope_theta=1e4,
+               deployment=dict(n_routed_experts_published=8,
+                               experts_held_first=4))
+    out = chip_smoke.phase_latent(
+        lat, dict(n_slots=4, max_len=96, page_size=8, prefill_chunk=16,
+                  n_short=3, short_len=8, long_len=40, n_tokens=8),
+        kernels=False, dtype=jnp.float32)
+    assert out["requests"] == 4 and out["tokens"] == 4 * 8
+    assert out["prefill_chunks"] >= 3
+    # float32 against the float32 reference: the served tokens are its own
+    assert max(out["reference_gaps"]) < 1e-4
+    # on the CPU both engines ARE the XLA forms
+    assert out["agreement"]["common_prefix_tokens"] == [8] * 4
+    assert out["pool_layout_copies"].keys() == {"decode_step",
+                                                "decode_chunked"}
+    # two layers of two sub-layers: a float32 latent of 16 + 4 in each
+    assert out["latent_blocks"] == 4
+    assert out["latent_bytes_per_token"] == 4 * 20 * 4
+    assert 0.1 < out["zero_share_of_choices"] < 0.6
+    json.dumps(out)
+
+
+def test_latent_phase_publishes_longcat_flashs_widths():
+    from perfbench.families import longcat_flash as fam
+
+    sz = fam.sizes(chip_smoke.LATENT)
+    assert (sz["d"], sz["H"], sz["qr"], sz["kr"]) == (6144, 64, 1536, 512)
+    assert (sz["nope"], sz["rope"], sz["vd"]) == (128, 64, 128)
+    assert (sz["ffn"], sz["f"], sz["L"]) == (12288, 2048, 1)
+    assert (sz["E"], sz["Z"], sz["held"], sz["topk"], sz["route_scale"]) \
+        == (512, 256, (0, 16), 12, 6.0)
+
+
 # lines as XLA:TPU prints them (PR 26's parent, layouts and configs
 # kept, operand lists cut): what the count must and must not see
 _CANNED_HLO = """\

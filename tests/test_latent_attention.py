@@ -1,0 +1,206 @@
+"""Multi-head latent attention (`LatentAttentionMixer`): its three forms
+held to one another and to the plain reference the benchmark keeps
+(`perfbench/families/longcat_flash_reference.mla`: expanded keys and
+values, a full score matrix a head), the paged pool of latents, and the
+two kernels of `ops/pallas_mla_attend.py` in interpret mode."""
+import dataclasses
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.nn.conf.decoder_block import (
+    LatentAttentionMixer,
+    kind_from_json,
+)
+from deeplearning4j_tpu.ops import pallas_mla_attend as mla
+from perfbench.families import longcat_flash_reference as ref
+
+D, T = 48, 21
+MIXER = LatentAttentionMixer(n_heads=4, q_rank=24, kv_rank=16, nope_dim=8,
+                             rope_dim=4, v_dim=8, rope_theta=1e4,
+                             scale_q_lora=True, scale_kv_lora=True)
+CONSTS = ref.Consts(q_rank=24, kv_rank=16, nope=8, rope=4, v_dim=8,
+                    rope_theta=1e4, scale_q_lora=True, scale_kv_lora=True,
+                    n_experts=0, n_zero=0, top_k=0, routed_scale=1.0,
+                    held_first=0)
+
+
+def _params(mixer=MIXER, seed=0):
+    p = mixer.init_params(
+        jax.random.PRNGKey(seed), D, jnp.float32,
+        lambda k, shape, fi, fo: jax.random.normal(k, shape) / fi ** 0.5)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed + 1))
+    p["qn_w"] = 1.0 + 0.1 * jax.random.normal(k1, p["qn_w"].shape)
+    p["kvn_w"] = 1.0 + 0.1 * jax.random.normal(k2, p["kvn_w"].shape)
+    return p
+
+
+def _x(seed=3, t=T):
+    return jax.random.normal(jax.random.PRNGKey(seed), (1, t, D))
+
+
+def _reference(p, x, c=CONSTS):
+    names = {"Wqa": "Wqa0", "qn_w": "qn0", "Wqn": "Wqn0", "Wqr": "Wqr0",
+             "Wkvc": "Wkvc0", "Wkr": "Wkr0", "kvn_w": "kvn0", "Wkb": "Wkb0",
+             "Wvb": "Wvb0", "Wo": "Wo0"}
+    with jax.default_matmul_precision("highest"):
+        return ref.mla({names[k]: v for k, v in p.items()}, 0, x[0],
+                       jnp.arange(x.shape[1]), c, n_heads=MIXER.n_heads,
+                       eps=MIXER.eps, precision="float32")
+
+
+def test_the_kind_round_trips_through_json():
+    kind = LatentAttentionMixer(n_heads=64, q_rank=1536, kv_rank=512,
+                                nope_dim=128, rope_dim=64, v_dim=128,
+                                rope_theta=1e7, scale_q_lora=True,
+                                scale_kv_lora=True)
+    d = json.loads(json.dumps(kind.to_json()))
+    assert d["kind"] == "latent_attention"
+    assert kind_from_json(d) == kind
+    assert kind.state == "latent"
+    assert kind.latent_geometry() == (512, 64)
+    assert abs(kind.sm_scale - 192 ** -0.5) < 1e-12
+
+
+def test_the_expanded_forward_equals_the_reference():
+    p, x = _params(), _x()
+    np.testing.assert_allclose(MIXER.forward(p, x)[0], _reference(p, x),
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("drop", ["rope_theta", "scale_q_lora",
+                                  "scale_kv_lora"])
+def test_rotary_and_both_lora_scales_matter(drop):
+    """Each is in the arithmetic: without it the forward leaves the
+    reference by far more than the tolerance."""
+    p, x = _params(), _x()
+    broken = dataclasses.replace(
+        MIXER, **{drop: 1.0 if drop == "rope_theta" else False})
+    off = np.max(np.abs(broken.forward(p, x)[0] - _reference(p, x)))
+    assert off > 100 * 2e-5
+
+
+def test_the_three_forms_agree():
+    """The whole sequence expanded; its second half as a chunk of
+    absorbed queries against the cached latents; its last position as
+    the absorbed one-token step."""
+    p, x = _params(), _x()
+    want = MIXER.forward(p, x)[0]
+    pos = jnp.arange(T)
+    q_n, q_r, latent = MIXER.project(p, x, pos)
+    # the chunk form: positions 10.. against every cached latent (those
+    # past a query's position are masked)
+    q_abs = MIXER.absorb(p, q_n[:, 10:], q_r[:, 10:])
+    got = MIXER.out(p, MIXER.attend_latents(q_abs, latent, pos[None, 10:]))
+    np.testing.assert_allclose(got[0], want[10:], atol=2e-5)
+    # the absorbed step: ONE query, projected alone at its own position
+    q_n1, q_r1, lat1 = MIXER.project(p, x[:, -1:], pos[None, -1:])
+    np.testing.assert_allclose(lat1[0, 0], latent[0, -1], atol=1e-6)
+    step = MIXER.out(p, MIXER.attend_latents(
+        MIXER.absorb(p, q_n1, q_r1), latent, pos[None, -1:]))
+    np.testing.assert_allclose(step[0, 0], want[-1], atol=2e-5)
+
+
+def test_a_cached_latent_is_the_normed_latent_and_the_turned_rope_key():
+    p, x = _params(), _x()
+    _, _, latent = MIXER.project(p, x, jnp.arange(T))
+    assert latent.shape == (1, T, 16 + 4)
+    c = x[0] @ p["Wkvc"]
+    c = c / jnp.sqrt(jnp.mean(c * c, -1, keepdims=True) + MIXER.eps) \
+        * p["kvn_w"]
+    np.testing.assert_allclose(latent[0, :, :16], c, atol=1e-5)
+    # the program keeps the turned pairs evens-first; the norm of each
+    # pair is what a rotation leaves alone
+    raw = x[0] @ p["Wkr"]
+    k_r = raw.reshape(T, 2, 2)
+    got = latent[0, :, 16:].reshape(T, 2, 2)        # [evens | odds]
+    np.testing.assert_allclose(
+        got[:, 0] ** 2 + got[:, 1] ** 2,
+        k_r[..., 0] ** 2 + k_r[..., 1] ** 2, atol=1e-5)
+    np.testing.assert_allclose(latent[0, 0, 16:],
+                               raw[0].reshape(2, 2).T.reshape(-1),
+                               atol=1e-6)        # position 0: no turn
+
+
+# ------------------------------------------------------------ the kernels
+PAGE, R, KV, H = 8, 20, 16, 4
+
+
+def _pool_case(seed=0, S=3):
+    """Slots at positions 7 (a page's last), 8 (the next one's first)
+    and 19, over a table four pages wide whose dead entries name a page
+    of NaNs."""
+    rng = np.random.default_rng(seed)
+    pos = np.asarray([7, 8, 19][:S], np.int32)
+    live = pos // PAGE + 1
+    P = int(live.sum())
+    dead = P + 1
+    pt = np.full((S, 4), dead, np.int32)
+    at = 1
+    for s, n in enumerate(live):
+        pt[s, :n] = at + np.arange(n)
+        at += n
+    pool = jnp.asarray(rng.standard_normal((P + 2, R, PAGE)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((S, H, R)), jnp.float32)
+    return q, pool, pt, pos, dead
+
+
+def test_the_attend_kernel_equals_gather_and_attend():
+    q, pool, pt, pos, dead = _pool_case()
+    kw = dict(kv_rank=KV, sm_scale=0.3)
+    want = mla.mla_attend_xla(q, pool, jnp.asarray(np.where(pt == dead, 0,
+                                                            pt)),
+                              jnp.asarray(pos), **kw)
+    got = mla.mla_attend(q, pool.at[dead].set(jnp.nan), jnp.asarray(pt),
+                         jnp.asarray(pos), jnp.ones(3, bool),
+                         interpret=True, **kw)
+    # a page past a slot's live ones is never read: no NaN came through
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_gather_and_attend_is_the_mixers_chunk_form():
+    q, pool, pt, pos, dead = _pool_case()
+    pt = jnp.asarray(np.where(pt == dead, 0, pt))
+    want = mla.mla_attend_xla(q, pool, pt, jnp.asarray(pos), kv_rank=KV,
+                              sm_scale=MIXER.sm_scale)
+    lat = mla.gather_latents(pool, pt)
+    got = MIXER.attend_latents(q[:, None], lat, jnp.asarray(pos)[:, None])
+    np.testing.assert_allclose(got[:, 0], want, atol=2e-5)
+
+
+def test_an_inactive_slot_reads_no_page_and_comes_out_zeros():
+    q, pool, pt, pos, dead = _pool_case()
+    pt[1] = dead                         # its whole row names the NaN page
+    got = mla.mla_attend(q, pool.at[dead].set(jnp.nan), jnp.asarray(pt),
+                         jnp.asarray(pos), jnp.asarray([True, False, True]),
+                         kv_rank=KV, sm_scale=0.3, interpret=True)
+    assert np.all(np.isfinite(got))
+    assert np.all(np.asarray(got[1]) == 0)
+    assert np.any(np.asarray(got[0]) != 0)
+
+
+def test_the_write_kernel_equals_the_scatter_off_the_trash_page():
+    rng = np.random.default_rng(1)
+    got = want = jnp.asarray(rng.standard_normal((5, R, PAGE)), jnp.float32)
+    pids = jnp.asarray([3, 0, 1, 0], jnp.int32)     # two collide on page 0
+    for loff in ([0, 5, PAGE - 2, 7], [1, 6, PAGE - 1, 0]):
+        new = jnp.asarray(rng.standard_normal((4, R)), jnp.float32)
+        loff = jnp.asarray(loff, jnp.int32)
+        got = mla.latent_write(got, new, pids, loff, interpret=True)
+        want = mla.latent_write_xla(want, new, pids, loff)
+    np.testing.assert_array_equal(got[1:], want[1:])
+    # pages nobody named are as they were
+    np.testing.assert_array_equal(got[2], want[2])
+    np.testing.assert_array_equal(got[4], want[4])
+
+
+def test_the_kernels_never_dispatch_on_the_cpu():
+    q, pool, pt, pos, _ = _pool_case()
+    assert mla.mla_attend_or_none(q, pool, jnp.asarray(pt),
+                                  jnp.asarray(pos), jnp.ones(3, bool),
+                                  kv_rank=KV, sm_scale=0.3) is None
+    assert mla.latent_write_or_none(pool, q[:, 0], jnp.zeros(3, jnp.int32),
+                                    jnp.zeros(3, jnp.int32)) is None

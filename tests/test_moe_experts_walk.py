@@ -140,3 +140,86 @@ def test_the_benchmark_reads_the_share_told_to_be_read(stats, want):
     run = SimpleNamespace(facts={"stats_before": {k: 0 for k in stats},
                                  "stats_after": after})
     assert read(run) == want
+
+
+# ------------------------------------------------------- tiles of the width
+@pytest.mark.parametrize("unchosen", [(), (0, 3, 5), tuple(range(E))],
+                         ids=["none", "some", "all"])
+@pytest.mark.parametrize("tf", (8, 20), ids=["5-tiles", "2-tiles"])
+@pytest.mark.parametrize("act", VARIANTS)
+def test_the_tiled_walk_equals_the_batched_products(act, tf, unchosen):
+    """An expert brought in tiles of its width `f` (40 = 5 x 8 = 2 x 20)
+    sums to the whole expert's product, under the same hit-first walk."""
+    x, Wg, Wu, Wd = _weights(act)
+    gates = _gates(unchosen)
+    hit = jnp.any(gates != 0, axis=0)
+    got = pme.moe_experts(x, gates, Wg, Wu, Wd, hit, act=act, tf=tf,
+                          interpret=True)
+    want = experts.grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # one tile is the program it always was
+    whole = pme.moe_experts(x, gates, Wg, Wu, Wd, hit, act=act,
+                            interpret=True)
+    np.testing.assert_allclose(got, whole, atol=2e-5)
+    every = pme.moe_experts(x, gates, Wg, Wu, Wd, jnp.ones(E, bool),
+                            act=act, tf=tf, interpret=True)
+    assert np.array_equal(got, every)
+
+
+@pytest.mark.parametrize("act", VARIANTS)
+def test_the_tiled_walk_skips_an_unmarked_expert(act):
+    x, Wg, Wu, Wd = _weights(act)
+    gates = _gates((2, 5))               # the last expert among them
+    Wd = Wd.at[2].set(jnp.nan).at[5].set(jnp.nan)
+    Wu = Wu.at[5].set(jnp.nan)
+    hit = jnp.any(gates != 0, axis=0)
+    got = pme.moe_experts(x, gates, Wg, Wu, Wd, hit, act=act, tf=8,
+                          interpret=True)
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(
+        got, experts.grouped_expert_ffn_xla(
+            x, gates, Wg, jnp.nan_to_num(Wu), jnp.nan_to_num(Wd), act),
+        atol=2e-5)
+
+
+@pytest.mark.parametrize("rows,d,f,act,want", [
+    (64, 4096, 768, pme.GATED_SILU, 768),     # granite-4.0-h-small, decode
+    (512, 4096, 768, pme.GATED_SILU, 768),    # ... its widest prefill tile
+    (64, 2688, 1856, pme.RELU2, 1856),        # nemotron-3-nano, decode
+    (512, 2688, 1856, pme.RELU2, 1856),
+    (128, 6144, 2048, pme.GATED_SILU, 1024),  # 75.5 MB an expert: 2 tiles
+    (512, 6144, 2048, pme.GATED_SILU, 512),   # beside a 512-row token tile
+    (128, 8192, 8192, pme.GATED_SILU, 1024),
+], ids=["granite-decode", "granite-prefill", "nemotron-decode",
+        "nemotron-prefill", "d6144-f2048-decode", "d6144-f2048-prefill",
+        "d8192-f8192"])
+def test_the_tile_rule(rows, d, f, act, want):
+    """Shapes that fit whole take one tile, as they always did (the
+    two-axis program); an expert over the VMEM ceiling takes the largest
+    tile on the grid that fits."""
+    tf = pme.f_tile(rows, d, f, jnp.bfloat16, act)
+    assert tf == want
+    limit = pme._vmem_limit()
+    assert pme.vmem_bytes_estimate(rows, d, tf, jnp.bfloat16, act) <= limit
+    if tf < f:
+        assert pme.vmem_bytes_estimate(rows, d, f, jnp.bfloat16, act) > limit
+        assert f % tf == 0 and tf % 128 == 0
+
+
+def test_a_width_with_no_tile_that_fits_is_declined():
+    assert pme.f_tile(512, 1 << 17, 256, jnp.bfloat16) == 0
+
+
+@pytest.mark.parametrize("act", VARIANTS)
+def test_one_tile_keeps_the_two_axis_grid(act):
+    """A width brought whole is the program it always was: (token tiles,
+    experts), three (or two) whole matrices a step; tiles add a third,
+    innermost axis."""
+    grid, in_specs, _ = pme._grid_specs(64, 256, 6, 128, 64, 128, act)
+    assert grid == (1, 6)
+    assert [s.block_shape for s in in_specs[2:]] == (
+        [(1, 128, 256)] * 2 if act == pme.RELU2
+        else [(1, 256, 128)] * 2 + [(1, 128, 256)])
+    grid, in_specs, _ = pme._grid_specs(64, 256, 6, 128, 64, 32, act)
+    assert grid == (1, 6, 4)
+    assert in_specs[-1].block_shape == (1, 32, 256)

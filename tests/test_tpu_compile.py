@@ -336,3 +336,90 @@ def test_weight_fold_reads_a_leaf_once_and_writes_no_copy(one_chip, shape,
         r"^\s*(?:ROOT )?%[\w.\-]+ = .* (?:fusion|reduce|copy|convert)"
         r"\([^)]*%x\.\d+", compiled.as_text(), re.M)
     assert len(takes_the_leaf) == 1 and " fusion(" in takes_the_leaf[0]
+
+
+def test_latent_kernels_compile_at_the_published_widths(one_chip,
+                                                        monkeypatch):
+    """LongCat-Flash-Chat's latent attention as one chip serves it: 128
+    slots, 64 heads' absorbed queries over a pool of 2560 pages of 128
+    positions x (512 + 64), the pool's one-position write, and its
+    routed experts (16 of 6144 x 2048: 75.5 MB each, over the VMEM
+    ceiling whole) in tiles of their width."""
+    from deeplearning4j_tpu.ops import pallas_mla_attend as mla
+    from deeplearning4j_tpu.ops import pallas_moe_experts as pme
+
+    monkeypatch.setattr(mla, "_vmem_limit", lambda: 112 << 20)
+    monkeypatch.setattr(pme, "_vmem_limit", lambda: 112 << 20)
+    S = _shapes(one_chip)
+    i32 = jnp.int32
+    with jax.enable_x64(False):
+        attend = mla.mla_attend.lower(
+            S((128, 64, 576)), S((2561, 576, 128)), S((128, 32), i32),
+            S((128,), i32), S((128,), jnp.bool_), kv_rank=512,
+            sm_scale=192 ** -0.5).compile()
+        write = mla.latent_write.lower(
+            S((2561, 576, 128)), S((128, 576)), S((128,), i32),
+            S((128,), i32)).compile()
+        experts = pme.moe_experts.lower(
+            S((128, 6144)), S((128, 16), jnp.float32), S((16, 6144, 2048)),
+            S((16, 6144, 2048)), S((16, 2048, 6144)),
+            S((16,), jnp.bool_)).compile()
+    for compiled in (attend, write, experts):
+        assert "tpu_custom_call" in compiled.as_text()
+    # the pool stays where it is: nothing of its size is made beside it
+    assert attend.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert pme.f_tile(128, 6144, 2048, jnp.bfloat16) == 1024
+
+
+def test_shortcut_layer_decode_step_compiles_at_the_published_widths(
+        one_chip, monkeypatch):
+    """One layer of LongCat-Flash-Chat at its published widths (128
+    slots, 2560 pages of 128) through `build_programs`, its three kernel
+    families steered on as they are on the chip: two latent writes, two
+    paged latent attentions and the tiled grouped experts in one step,
+    and no pool-shaped copy left in the program."""
+    from types import SimpleNamespace
+
+    import chip_smoke
+    from deeplearning4j_tpu.models.transformer import GPTPlan
+    from deeplearning4j_tpu.ops import pallas_mla_attend, pallas_moe_experts
+    from deeplearning4j_tpu.serving import block_state, decode_programs
+    from perfbench.families import longcat_flash as fam
+
+    for mod in (pallas_mla_attend, pallas_moe_experts):
+        monkeypatch.setattr(mod, "_platform_supported", lambda: True)
+        monkeypatch.setattr(mod, "_probe_verdict", lambda *a, **k: True)
+        monkeypatch.setattr(mod, "_vmem_limit", lambda: 112 << 20)
+    sz = fam.sizes(chip_smoke.LATENT)
+    S = _shapes(one_chip)
+    shapes = fam._leaf_shapes(sz)
+    tree = {n: S(shapes[n]) for n in fam.TOP_LEAVES}
+    tree["layers"] = [
+        {n: S(shapes[n], jnp.float32 if n in fam.FLOAT32_LEAVES
+              else jnp.bfloat16) for n in fam.LAYER_LEAVES}]
+    net = fam.build_net(sz, training=False)
+    net._params = fam.to_program(tree)
+    plan = GPTPlan(net)
+    assert plan.state_kinds() == [("latent", "latent")]
+    assert plan.latent_geometry() == [(512, 64)] * 2
+    assert plan.kv_geometry() == []
+    n_slots, page = 128, 128
+    states = block_state.block_states(plan, SimpleNamespace(
+        n_slots=n_slots, page=page, pool_pages=2560, cdt=plan.cdt,
+        kv_quant=None, tp_shard=None, tp_axis=None))
+    with jax.enable_x64(False):
+        programs = decode_programs.build_programs(
+            plan, states, n_slots=n_slots, page=page, L_logical=4096,
+            decode_chunk=4, top_k=0, logprobs=0, tp=None, donate=True)
+        caches = [jax.tree.map(lambda a: S(a.shape, a.dtype),
+                               jax.eval_shape(st.alloc)) for st in states]
+        i32, f32 = jnp.int32, jnp.float32
+        text = programs.decode_step.lower(
+            net._params, caches, S((n_slots, 4096 // page), i32),
+            S((n_slots,), i32), S((n_slots,), i32),
+            S((n_slots, 2), jnp.uint32), S((n_slots,), f32),
+            S((n_slots,), jnp.bool_)).compile().as_text()
+    assert text.count("tpu_custom_call") == 5
+    for name in ("mla_attend", "latent_write", "moe_experts"):
+        assert name in text
+    assert chip_smoke.pool_layout_copies(text, {"bf16[2561,576,128]"}) == 0
