@@ -933,6 +933,7 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
     import jax
     import jax.numpy as jnp
 
+    from deeplearning4j_tpu.ops import pallas_mla_attend
     from deeplearning4j_tpu.serving.decode_engine import DecodeEngine
     from perfbench.families import longcat_flash as fam
     from perfbench.families import longcat_flash_reference as ref
@@ -1002,7 +1003,8 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
           f"{out['pool_layout_copies']}", flush=True)
     if kernels:
         R, page = sz["kr"] + sz["rope"], shape["page_size"]
-        key = ("bfloat16", sz["H"], R, sz["kr"], page)
+        key = pallas_mla_attend.attend_key(jnp.bfloat16, sz["H"], R,
+                                           sz["kr"], page)
         _check(engaged("mla_attend", lambda k: k == key),
                f"paged latent attention did not engage for {key}")
         key = ("bfloat16", R, page)

@@ -13,13 +13,20 @@ same page table as every other paged pool. The existing
 size: through it the latent would be stored and read twice.
 
 `mla_attend`: grid over slots; for each slot a loop over its LIVE pages
-(`pos // page + 1` of them, whatever the table's width), each page
-copied from HBM into one of two VMEM buffers once, while the page before
-it is computed on, and used twice: all `H` heads' absorbed queries
-`(H, 576)` against the whole page for the scores, and the probabilities
-against its first `kv_rank` rows for the values, under the online-softmax
-recurrence. Out comes `u (S, H, kv_rank)`, the probabilities' sum of
-latents a head; the mixer takes it up through `W^V` (`mla.out`).
+(`pos // page + 1` of them, whatever the table's width), a BLOCK of them
+an iteration (`block_pages`: `BLOCK_POSITIONS` positions, eight pages of
+128). A block's live pages are copied from HBM, once each, into the lane
+ranges of one of two VMEM block buffers while the block before it is
+computed on, and the block is used twice: all `H` heads' absorbed
+queries `(H, 576)` against the whole block for the scores, and the
+probabilities against its first `kv_rank` rows for the values, under
+the online-softmax recurrence, whose mask, reductions, rescale and
+stores are paid once a block (one page an iteration left every product
+64 rows wide against nine fresh weight tiles and every step waiting for
+the one before it: PERF.md section 6, PR 41). A slot's last block is part
+empty: only its live pages are copied, the mask covers the rest. Out
+comes `u (S, H, kv_rank)`, the probabilities' sum of latents a head; the
+mixer takes it up through `W^V` (`mla.out`).
 
 `latent_write`: one decode position a slot, written into the donated
 pool in place (`pallas_paged_kv_write`'s discipline: the pool is an
@@ -32,7 +39,8 @@ Dispatch rides `ops/kernel_dispatch.py` under the families `mla_attend`
 and `latent_write`: each probe compiles and runs its kernel at the exact
 shape class and holds it to the `jax.numpy` form beside it
 (`mla_attend_xla`: gather the slot's pages, then attend; `latent_write_xla`:
-the scatter); `DL4J_TPU_NO_PALLAS_MLA_ATTEND` forces the XLA forms; CPU
+the scatter), the attend's key ending in the block it ran with
+(`"block8"`); `DL4J_TPU_NO_PALLAS_MLA_ATTEND` forces the XLA forms; CPU
 backends never dispatch.
 """
 from __future__ import annotations
@@ -89,34 +97,68 @@ def latent_write_xla(pool, new, pids, loff):
 
 
 # ------------------------------------------------------- attend kernel
+# Positions one iteration of a slot's walk takes: `block_pages` makes it
+# whole pages. From the sweep of 1 / 2 / 4 / 8 pages of 128 on a TPU v5e
+# at LongCat's widths, where 8 was the fastest at every context
+# (PERF.md section 6, PR 41; `tools/mla_attend_bench.py`).
+BLOCK_POSITIONS = 1024
+_MAX_BLOCK_PAGES = 8     # copies an iteration starts and waits for, unrolled
+
+
+def block_pages(page: int, R: int, H: int, dtype) -> int:
+    """Pages an iteration of the walk takes (`B`): `BLOCK_POSITIONS` in
+    whole pages, halved until the two block buffers and the block's
+    float32 scores and probabilities leave half the VMEM ceiling free."""
+    B = max(1, min(_MAX_BLOCK_PAGES, BLOCK_POSITIONS // page))
+    item = jnp.dtype(dtype).itemsize
+    while B > 1 and B * page * (2 * R * item + 4 * H * 4) > _vmem_limit() // 2:
+        B //= 2
+    return B
+
+
 def _attend_kernel(pt_ref, pos_ref, gate_ref, q_ref, pool_hbm, o_ref,
                    buf, sem, state, acc_scr, m_scr, l_scr, *, page: int,
-                   kv_rank: int, n_pages: int, sm_scale: float):
+                   block: int, kv_rank: int, n_pages: int, sm_scale: float):
     """Grid (S,), slots in order: slot `s` walks pages `0 .. pos //
-    page` of its page-table row. The pool stays in HBM; each live page
-    is copied once into one of two VMEM buffers while the page before it
-    is computed on, and a slot's last iteration starts the next slot's
-    first copy (`pallas_paged_attention`'s hand-over: `state` carries
-    which buffer the slot's first page lands in and whether the slot
-    before it started that copy)."""
+    page` of its page-table row, `block` of them an iteration. The pool
+    stays in HBM; each live page is copied once, into its lane range of
+    one of two VMEM block buffers, while the block before it is computed
+    on, and a slot's last iteration starts the copies of the next slot's
+    first block (`pallas_paged_attention`'s hand-over: `state` carries
+    which buffer the slot's first block lands in and whether the slot
+    before it started those copies). A slot's last block is part empty:
+    only its live pages are copied, and the lanes past them hold what an
+    earlier block left there, under the mask."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     s = pl.program_id(0)
     S = pl.num_programs(0)
+    span = block * page
 
     def live_pages(slot):
         n = jnp.minimum(pos_ref[slot] // page + 1, n_pages)
         return jnp.where(gate_ref[slot] != 0, n, 0)
 
-    def copy(slot, j, b):
-        return pltpu.make_async_copy(pool_hbm.at[pt_ref[slot, j]],
-                                     buf.at[b], sem.at[b])
+    def copies(slot, i, n, b, wait=False):
+        """Block `i` of a slot with `n` live pages into buffer `b`: one
+        copy a live page, started or waited for."""
+        for k in range(block):
+            @pl.when(i * block + k < n)
+            def _live_page():
+                cp = pltpu.make_async_copy(
+                    pool_hbm.at[pt_ref[slot, i * block + k]],
+                    buf.at[b, :, pl.ds(k * page, page)], sem.at[b, k])
+                cp.wait() if wait else cp.start()
 
     @pl.when(s == 0)
     def _first_slot():
         state[0] = 0
         state[1] = 0
+        # a masked lane's probability is 0, and 0 * NaN is NaN in the
+        # value product: from here on the buffers hold zeros or pages of
+        # the pool, never what the VMEM held before the call
+        buf[...] = jnp.zeros_like(buf)
 
     acc_scr[...] = jnp.zeros_like(acc_scr)
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
@@ -124,39 +166,35 @@ def _attend_kernel(pt_ref, pos_ref, gate_ref, q_ref, pool_hbm, o_ref,
 
     p0 = pos_ref[s]
     n_live = live_pages(s)
+    n_blocks = (n_live + block - 1) // block
     nxt = jnp.minimum(s + 1, S - 1)
     n_next = jnp.where(s + 1 < S, live_pages(nxt), 0)
     buf0 = state[0]
 
-    @pl.when((n_live > 0) & (state[1] == 0))
+    @pl.when(state[1] == 0)
     def _start_cold():
-        copy(s, 0, buf0).start()
+        copies(s, 0, n_live, buf0)
 
     q = q_ref[0]                                          # (H, R)
     H = q.shape[0]
 
-    def _page(j, carry):
-        b = (buf0 + j) % 2
-
-        @pl.when(j + 1 < n_live)
-        def _fetch_next_page():
-            copy(s, j + 1, 1 - b).start()
-
-        @pl.when((j + 1 == n_live) & (n_next > 0))
-        def _fetch_next_slot():
-            copy(nxt, 0, 1 - b).start()
-
-        copy(s, j, b).wait()
-        c = buf[b]                                        # (R, page)
+    def _block(i, carry):
+        b = (buf0 + i) % 2
+        # the next block's copies, or the next slot's first block's
+        more = i + 1 < n_blocks
+        copies(jnp.where(more, s, nxt), jnp.where(more, i + 1, 0),
+               jnp.where(more, n_live, n_next), 1 - b)
+        copies(s, i, n_live, b, wait=True)
+        c = buf[b]                                        # (R, span)
         sc = jnp.dot(q, c, preferred_element_type=jnp.float32) * sm_scale
-        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (H, page), 1)
+        kpos = i * span + jax.lax.broadcasted_iota(jnp.int32, (H, span), 1)
         sc = jnp.where(kpos <= p0, sc, NEG_INF)
         m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
         p = jnp.where(sc <= NEG_INF / 2, 0.0, jnp.exp(sc - m_new))
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        # the page again, its first kv_rank rows: positions on lanes in
+        # the block again, its first kv_rank rows: positions on lanes in
         # both operands, so the product contracts the lane axes
         acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p.astype(c.dtype), c[:kv_rank], (((1,), (1,)), ((), ())),
@@ -165,16 +203,56 @@ def _attend_kernel(pt_ref, pos_ref, gate_ref, q_ref, pool_hbm, o_ref,
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
         return carry
 
-    jax.lax.fori_loop(0, n_live, _page, 0)
+    jax.lax.fori_loop(0, n_blocks, _block, 0)
 
     @pl.when(n_live > 0)
     def _hand_over():
-        state[0] = (buf0 + n_live) % 2
+        state[0] = (buf0 + n_blocks) % 2
         state[1] = (n_next > 0).astype(jnp.int32)
 
     l = l_scr[:, :1]
     o_ref[0] = jnp.where(l > 0, acc_scr[...] / jnp.where(l > 0, l, 1.0),
                          0.0).astype(o_ref.dtype)
+
+
+def _attend_call(q_abs, pool, page_table, pos, active, *, kv_rank: int,
+                 sm_scale: float, block: int, interpret: bool = False):
+    """The kernel call at `block` pages an iteration (`mla_attend` asks
+    `block_pages`; `tools/mla_attend_bench.py` sweeps it)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, H, R = q_abs.shape
+    page = pool.shape[2]
+    kernel = functools.partial(
+        _attend_kernel, page=page, block=block, kv_rank=kv_rank,
+        n_pages=page_table.shape[1], sm_scale=sm_scale)
+    slot = lambda s, pt, p0, g: (s, 0, 0)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((1, H, R), slot),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, H, kv_rank), slot),
+            scratch_shapes=[
+                pltpu.VMEM((2, R, block * page), pool.dtype),
+                pltpu.SemaphoreType.DMA((2, block)),
+                pltpu.SMEM((2,), jnp.int32),        # buffer, copies started
+                pltpu.VMEM((H, kv_rank), jnp.float32),
+                pltpu.VMEM((H, 128), jnp.float32),  # running max m
+                pltpu.VMEM((H, 128), jnp.float32),  # running denom l
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S, H, kv_rank), q_abs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # in order: a slot's last iteration starts the next slot's
+            # first copies
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit()),
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
+      jnp.asarray(active).astype(jnp.int32), q_abs, pool)
 
 
 # jitted, like the family's other serving kernels, so that a program
@@ -187,40 +265,11 @@ def mla_attend(q_abs, pool, page_table, pos, active, *, kv_rank: int,
     """`mla_attend_xla` streamed from the pool: only entries `0 ..
     pos[s] // page` of a slot's table row are read; a slot that `active`
     (S,) bool leaves out reads no page and comes out zeros."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    S, H, R = q_abs.shape
-    page = pool.shape[2]
-    kernel = functools.partial(
-        _attend_kernel, page=page, kv_rank=kv_rank,
-        n_pages=page_table.shape[1], sm_scale=sm_scale)
-    slot = lambda s, pt, p0, g: (s, 0, 0)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(S,),
-            in_specs=[pl.BlockSpec((1, H, R), slot),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, H, kv_rank), slot),
-            scratch_shapes=[
-                pltpu.VMEM((2, R, page), pool.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SMEM((2,), jnp.int32),        # buffer, copy started
-                pltpu.VMEM((H, kv_rank), jnp.float32),
-                pltpu.VMEM((H, 128), jnp.float32),  # running max m
-                pltpu.VMEM((H, 128), jnp.float32),  # running denom l
-            ]),
-        out_shape=jax.ShapeDtypeStruct((S, H, kv_rank), q_abs.dtype),
-        compiler_params=pltpu.CompilerParams(
-            # in order: a slot's last iteration starts the next slot's
-            # first copy
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_vmem_limit()),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
-      jnp.asarray(active).astype(jnp.int32), q_abs, pool)
+    _, H, R = q_abs.shape
+    return _attend_call(
+        q_abs, pool, page_table, pos, active, kv_rank=kv_rank,
+        sm_scale=sm_scale, interpret=interpret,
+        block=block_pages(pool.shape[2], R, H, pool.dtype))
 
 
 # -------------------------------------------------------- write kernel
@@ -281,38 +330,41 @@ def _attend_probe(dtype, H: int, R: int, kv_rank: int, page: int,
     to gather-and-attend: a table wider than any slot's live pages whose
     dead entries name a page of NaNs (a read of a page no query can see
     fails the comparison), one slot ending on a page's last position,
-    one on the next page's first, one inactive."""
+    one on the next page's first, one a page past a whole block, one
+    inactive, one of more than two blocks ending mid-page."""
     import numpy as np
 
-    pos = np.asarray([page - 1, page, 5], np.int32)
+    B = block_pages(page, R, H, dtype)
+    pos = np.asarray([page - 1, page, (B + 1) * page - 1, 5,
+                      (2 * B + 1) * page + 5], np.int32)
+    active = np.asarray([True, True, True, False, True])
     live = pos // page + 1
     n_pages = int(live.max()) + 2
     P = int(live.sum())
     dead = P + 1
-    pt = np.full((3, n_pages), dead, np.int32)
+    pt = np.full((len(pos), n_pages), dead, np.int32)
     at = 1
     for s, n in enumerate(live):
         pt[s, :n] = at + np.arange(n)
         at += n
     rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((3, H, R)) / R ** 0.25, dtype)
+    q = jnp.asarray(rng.standard_normal((len(pos), H, R)) / R ** 0.25, dtype)
     pool = jnp.asarray(rng.standard_normal((P + 2, R, page)), dtype)
-    active = jnp.asarray([True, True, False])
     got = np.asarray(mla_attend(
         q, pool.at[dead].set(jnp.nan), jnp.asarray(pt), jnp.asarray(pos),
-        active, kv_rank=kv_rank, sm_scale=sm_scale), np.float32)
+        jnp.asarray(active), kv_rank=kv_rank, sm_scale=sm_scale), np.float32)
     want = np.asarray(mla_attend_xla(
         q, pool, jnp.asarray(np.where(pt == dead, 0, pt)), jnp.asarray(pos),
         kv_rank=kv_rank, sm_scale=sm_scale), np.float32)
     if not np.all(np.isfinite(got)):
         return False
-    if np.any(got[2] != 0):
+    if np.any(got[~active] != 0):
         raise ValueError("an inactive slot did not come out zeros")
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
-    if not np.allclose(got[:2], want[:2], atol=tol, rtol=tol):
+    if not np.allclose(got[active], want[active], atol=tol, rtol=tol):
         raise ValueError(
             "kernel compiled but disagrees with gather-and-attend: max "
-            f"abs err {np.max(np.abs(got[:2] - want[:2])):.3g} at "
+            f"abs err {np.max(np.abs(got[active] - want[active])):.3g} at "
             f"atol=rtol={tol:g}")
     return True
 
@@ -340,6 +392,13 @@ def _write_probe(dtype, R: int, page: int) -> bool:
     return True
 
 
+def attend_key(dtype, H: int, R: int, kv_rank: int, page: int) -> tuple:
+    """The attend kernel's shape class in `kernel_verdicts()`; its last
+    entry names the form that ran: the pages an iteration takes."""
+    return (jnp.dtype(dtype).name, H, R, kv_rank, page,
+            f"block{block_pages(page, R, H, dtype)}")
+
+
 def mla_attend_or_none(q_abs, pool, page_table, pos, active, *,
                        kv_rank: int, sm_scale: float) -> Optional[jnp.ndarray]:
     """Dispatch probe: the streamed attention, or None when the kernel
@@ -352,7 +411,7 @@ def mla_attend_or_none(q_abs, pool, page_table, pos, active, *,
     if not _platform_supported() or pool.dtype != dtype \
             or dtype not in (jnp.float32, jnp.bfloat16):
         return None
-    key = (jnp.dtype(dtype).name, H, R, kv_rank, page)
+    key = attend_key(dtype, H, R, kv_rank, page)
     rows = 32 // jnp.dtype(dtype).itemsize
     if H % 8 or R % rows or kv_rank % 128 or page % 128:
         _record_decline(FAMILY, key, f"{H} heads, latent {kv_rank} of {R}, "

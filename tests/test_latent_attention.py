@@ -161,6 +161,98 @@ def test_the_attend_kernel_equals_gather_and_attend():
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+B = mla.block_pages(PAGE, R, H, jnp.float32)   # pages an iteration takes
+
+
+def _walk_case(lives, ends, seed=0):
+    """Slots of `lives[s]` live pages ending on their last page's
+    `ends[s]` ("last" / "first") entry, over a table two entries wider
+    than the longest whose dead entries name a page of NaNs."""
+    rng = np.random.default_rng(seed)
+    lives = np.asarray(lives)
+    pos = np.asarray([n * PAGE - 1 if e == "last" else (n - 1) * PAGE
+                      for n, e in zip(lives, ends)], np.int32)
+    P = int(lives.sum())
+    dead = P + 1
+    pt = np.full((len(lives), int(lives.max()) + 2), dead, np.int32)
+    at = 1
+    for s, n in enumerate(lives):
+        pt[s, :n] = at + np.arange(n)
+        at += n
+    pool = jnp.asarray(rng.standard_normal((P + 2, R, PAGE)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((len(lives), H, R)), jnp.float32)
+    return q, pool, pt, pos, dead
+
+
+def _kernel_and_gather(q, pool, pt, pos, dead, active):
+    """The kernel over the pool with its NaN page, gather-and-attend
+    over the same pool with the dead entries turned to the trash page."""
+    kw = dict(kv_rank=KV, sm_scale=0.3)
+    want = mla.mla_attend_xla(
+        q, pool, jnp.asarray(np.where(pt == dead, 0, pt)), jnp.asarray(pos),
+        **kw)
+    got = mla.mla_attend(q, pool.at[dead].set(jnp.nan), jnp.asarray(pt),
+                         jnp.asarray(pos), jnp.asarray(active),
+                         interpret=True, **kw)
+    return got, want
+
+
+def test_the_block_is_a_few_pages_and_fits():
+    assert B == 8                  # toy pages: the unrolled copies' bound
+    assert mla.block_pages(128, 576, 64, jnp.bfloat16) \
+        == mla.BLOCK_POSITIONS // 128
+    assert mla.block_pages(1024, 576, 64, jnp.bfloat16) == 1
+
+
+@pytest.mark.parametrize("end", ["last", "first"])
+@pytest.mark.parametrize("n_live", [1, B - 1, B, B + 1, 2 * B + 1])
+def test_a_walk_of_blocks_equals_gather_and_attend(n_live, end):
+    """A slot of `n_live` pages between two others: whole blocks, a
+    part-empty last block, one page more than a block."""
+    case = _walk_case([2, n_live, 3], ["first", end, "last"], seed=n_live)
+    got, want = _kernel_and_gather(*case, [True] * 3)
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_the_hand_over_crosses_an_inactive_slot():
+    """The slot after an inactive one starts its own first copies, into
+    the buffer the walk before left free."""
+    q, pool, pt, pos, dead = _walk_case([B + 1, 2, B + 2],
+                                        ["last", "first", "first"])
+    pt[1] = dead
+    got, want = _kernel_and_gather(q, pool, pt, pos, dead,
+                                   [True, False, True])
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.all(got[1] == 0)
+    np.testing.assert_allclose(got[[0, 2]], want[[0, 2]], atol=2e-5)
+
+
+def test_a_part_empty_block_after_a_full_one_sees_no_stale_lane():
+    """A short first slot (its block's other lanes are the zeros the
+    call began with), whole blocks in both buffers, then part-empty
+    blocks over what those left: the mask covers pool pages, and a dead
+    entry's NaN page is in no buffer."""
+    case = _walk_case([1, B, B + 1, 2 * B + 1, 2],
+                      ["first", "last", "first", "last", "first"])
+    got, want = _kernel_and_gather(*case, [True] * 5)
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_the_probe_walks_part_empty_and_many_blocks(monkeypatch):
+    """The dispatch probe's own slots, through the kernel in interpret
+    mode: a page past one block, more than two blocks, an inactive slot,
+    the dead entries naming the NaN page."""
+    import functools
+
+    monkeypatch.setattr(mla, "mla_attend", functools.partial(
+        mla.mla_attend, interpret=True))
+    assert mla._attend_probe(jnp.float32, H, R, KV, PAGE, 0.3)
+    assert mla.attend_key(jnp.float32, H, R, KV, PAGE) \
+        == ("float32", H, R, KV, PAGE, f"block{B}")
+
+
 def test_gather_and_attend_is_the_mixers_chunk_form():
     q, pool, pt, pos, dead = _pool_case()
     pt = jnp.asarray(np.where(pt == dead, 0, pt))
@@ -204,3 +296,30 @@ def test_the_kernels_never_dispatch_on_the_cpu():
                                   kv_rank=KV, sm_scale=0.3) is None
     assert mla.latent_write_or_none(pool, q[:, 0], jnp.zeros(3, jnp.int32),
                                     jnp.zeros(3, jnp.int32)) is None
+
+
+def test_the_kernel_bench_rehearses_in_interpret_mode(tmp_path, capsys):
+    """`tools/mla_attend_bench.py` end to end at toy shapes, so that a
+    chip call is not lost to a typo: a row a block and context, each
+    within rounding of gather-and-attend, and no time printed as a
+    device's."""
+    from tools import mla_attend_bench as bench
+
+    out = tmp_path / "bench.json"
+    assert bench.main(
+        ["--slots", "3", "--heads", str(H), "--kv-rank", str(KV), "--rope",
+         str(R - KV), "--page", str(PAGE), "--dtype", "float32",
+         "--contexts", "9,20,5-40", "--blocks", "1,2,0", "--calls", "2",
+         "--iters", "1", "--interpret", "--out", str(out)]) == 0
+    table = json.loads(out.read_text())
+    assert [r["block"] for r in table["rows"]] == [1] * 3 + [2] * 3 + [B] * 3
+    assert all(r["gap_to_xla"] < 2e-5 for r in table["rows"])
+    assert not any("call_ms" in r or "roofline_pct" in r
+                   for r in table["rows"]) and table["fits"] == []
+    assert len(capsys.readouterr().out.splitlines()) == 9
+    # with times, a block's rows fit to a cost a slot and a cost a page
+    rows = [{"block": 4, "live_pages": n, "slot_us": 1.0 + 0.25 * n}
+            for n in (5, 8, 13)]
+    (fit,) = bench.fits(rows)
+    assert abs(fit["fit_slot_us"] - 1.0) < 1e-9
+    assert abs(fit["fit_page_us"] - 0.25) < 1e-9

@@ -366,6 +366,10 @@ def test_latent_kernels_compile_at_the_published_widths(one_chip,
             S((16,), jnp.bool_)).compile()
     for compiled in (attend, write, experts):
         assert "tpu_custom_call" in compiled.as_text()
+    # the walk takes eight pages an iteration here: two block buffers of
+    # 576 x 1024 where the one-page walk had two pages
+    assert mla.block_pages(128, 576, 64, jnp.bfloat16) == 8
+    assert mla.attend_key(jnp.bfloat16, 64, 576, 512, 128)[-1] == "block8"
     # the pool stays where it is: nothing of its size is made beside it
     assert attend.memory_analysis().temp_size_in_bytes < 1 << 20
     assert pme.f_tile(128, 6144, 2048, jnp.bfloat16) == 1024
