@@ -54,7 +54,14 @@ repo supports — `gpt_configuration(vocab 256, d_model 1024, 8 heads of
   reference and against an engine on the XLA forms (gather-and-attend,
   the scatter); about a third of the router's choices on zero experts;
   the `mla_attend`, `latent_write` and f-tiled grouped-expert kernels
-  engaged; no pool-shaped copy in the decode programs.
+  engaged; no pool-shaped copy in the decode programs. Then the same
+  stage (`latent_h128` in the summary) at DeepSeek-V2's published widths:
+  a dense and a routed layer of ONE 128-head latent-attention sub-layer
+  each under YaRN, group 0 of 8 groups of 20 experts 1536 wide held,
+  3 groups reached, top-6, two shared experts; a bucket of 2,048 tokens,
+  so that the prompt goes through the `mla_prefill` kernel and the sorted
+  expert product; the pools are counted from the net's mixers, so the one stage
+  serves both families.
 - **lstm**: `lstm_large` (H=1024, T=64, B=2048) with the fused cell.
 - **multichip** (>= 4 devices): the train step through `ParallelWrapper`
   on a {data 2, model 2} mesh against the one-chip loss, tp=4 decode
@@ -154,6 +161,35 @@ LATENT = dict(vocab_size=256, hidden_size=6144, num_layers=1,
               deployment=dict(n_routed_experts_published=512,
                               experts_held_first=0))
 LATENT_SERVE = HYBRID_SERVE
+# DeepSeek-V2's published widths under its config's own keys
+# (`perfbench/families/deepseek_v2.py` reads them): the leading dense
+# layer and one routed layer, group 0 of the 8 groups of 20 experts held
+# as in the benchmark cell, 128 heads, YaRN
+LATENT_H128 = dict(vocab_size=256, hidden_size=5120, num_hidden_layers=2,
+                   first_k_dense_replace=1, moe_layer_freq=1,
+                   hidden_act="silu", intermediate_size=12288,
+                   moe_intermediate_size=1536, num_attention_heads=128,
+                   num_key_value_heads=128, q_lora_rank=1536,
+                   kv_lora_rank=512, qk_nope_head_dim=128,
+                   qk_rope_head_dim=64, v_head_dim=128, rope_theta=10000,
+                   rope_scaling=dict(type="yarn", factor=40,
+                                     original_max_position_embeddings=4096,
+                                     beta_fast=32, beta_slow=1, mscale=0.707,
+                                     mscale_all_dim=0.707),
+                   n_routed_experts=20, n_shared_experts=2, n_group=8,
+                   topk_group=3, num_experts_per_tok=6,
+                   topk_method="group_limited_greedy",
+                   scoring_func="softmax", norm_topk_prob=False,
+                   routed_scaling_factor=16, rms_norm_eps=1e-6,
+                   attention_bias=False, tie_word_embeddings=False,
+                   deployment=dict(n_routed_experts_published=160,
+                                   experts_held_first=0))
+# a bucket of 2,048: at 128 heads its prefill is too long for one array of
+# scores (the prefill kernel) and its rows go to the experts sorted; the
+# long prompt still rides chunks of 256 against a row of 32 pages
+LATENT_H128_SERVE = dict(n_slots=64, max_len=4096, page_size=128,
+                         prefill_chunk=256, n_short=3, short_len=2048,
+                         long_len=2304, n_tokens=24)
 LSTM = dict(vocab=256, hidden=1024, T=64, batch=2048, steps=2)
 
 # A greedy token may differ between two correct attention paths only
@@ -729,6 +765,18 @@ def phase_hybrid(hyb: dict, shape: dict, *, kernels: bool,
             key = ("bfloat16", rows, d, f)
             _check(engaged("moe_experts", lambda k: k == key),
                    f"grouped expert kernel did not engage for {key}")
+        from deeplearning4j_tpu.ops import pallas_moe_experts as pme
+
+        bucket, mixer = shape["short_len"], net.layers[1].mixers()[0]
+        if pme.sorted_serves(bucket, sz["held"][1], sz["topk"],
+                             pme.GATED_SILU):
+            key = ("bfloat16", pme.SORTED_ROWS, sz["d"], sz["f"], "sorted")
+            _check(engaged("moe_experts", lambda k: k == key),
+                   f"sorted expert kernel did not engage for {key}")
+        if mixer.query_block(bucket) < bucket:
+            _check(engaged("mla_prefill", lambda k: True),
+                   f"the prefill kernel did not serve the {bucket}-token "
+                   "prompt")
         H, Hkv = hyb["n_heads"], hyb["n_kv_heads"]
         key = ("bfloat16", 1, H, Hkv, d // H, shape["page_size"], "dense")
         _check(engaged("paged_attention", lambda k: k == key),
@@ -929,15 +977,22 @@ def phase_sublayer(sub: dict, shape: dict, *, kernels: bool,
 
 
 def phase_latent(lat: dict, shape: dict, *, kernels: bool,
-                 dtype=None) -> dict:
+                 dtype=None, family: str = "longcat_flash") -> dict:
+    """A net whose mixers are latent attention, of the benchmark's
+    `family` (`longcat_flash`: two sub-layers a layer and zero-compute
+    experts; `deepseek_v2`: one, under YaRN, behind device-limited
+    routing), through the engine, against the family's reference and
+    against its own XLA forms."""
+    import importlib
+
     import jax
     import jax.numpy as jnp
 
     from deeplearning4j_tpu.ops import pallas_mla_attend
     from deeplearning4j_tpu.serving.decode_engine import DecodeEngine
-    from perfbench.families import longcat_flash as fam
-    from perfbench.families import longcat_flash_reference as ref
 
+    fam = importlib.import_module(f"perfbench.families.{family}")
+    ref = importlib.import_module(f"perfbench.families.{family}_reference")
     dtype = dtype or jnp.bfloat16
     vocab, n_tokens = lat["vocab_size"], shape["n_tokens"]
     sz = fam.sizes(lat)
@@ -953,14 +1008,22 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
     _check(stats["prefill_chunks"] >= n_chunks,
            f"long prompt did not ride chunked prefill: "
            f"{stats['prefill_chunks']} chunks < {n_chunks}")
-    _check(stats["latent_blocks"] == 2 * sz["L"],
-           f"{stats['latent_blocks']} pools of latent pages for "
-           f"{sz['L']} layers of two sub-layers")
-    zero = stats["moe_zero_choices"] / max(1, stats["moe_routed"])
-    want = sz["Z"] / (sz["E"] + sz["Z"])
-    _check(0.5 * want < zero < min(1.0, 2.0 * want),
+    # one pool a latent mixer, whatever the family puts in a layer
+    n_mixers = sum(len(layer.mixers()) for layer in net.layers
+                   if hasattr(layer, "mixers"))
+    _check(stats["latent_blocks"] == n_mixers > 0,
+           f"{stats['latent_blocks']} pools of latent pages for the "
+           f"net's {n_mixers} latent mixers")
+    routed = max(1, stats["moe_routed"])
+    zero = stats["moe_zero_choices"] / routed
+    want = sz.get("Z", 0) / (sz["E"] + sz.get("Z", 0))
+    _check(0.5 * want < zero < min(1.0, 2.0 * want) if want else not zero,
            f"{zero:.3f} of the router's choices fell on the {want:.3f} "
            f"of its outputs that are zero experts")
+    rows_local = stats["moe_rows_local"] * sz["topk"] / routed
+    _check(stats["moe_held_choices"] / routed <= rows_local <= 1.0,
+           f"{rows_local:.3f} of the routed rows chose a held expert, "
+           f"under the held share of the choices")
     out = {"requests": len(prompts), "tokens": int(sum(map(len, toks))),
            "prefill_chunks": stats["prefill_chunks"],
            "decode_steps": stats["decode_steps"],
@@ -968,7 +1031,8 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
            "latent_bytes_per_token": stats["latent_bytes_per_token"],
            "zero_share_of_choices": round(zero, 4),
            "held_share_of_choices": round(
-               stats["moe_held_choices"] / max(1, stats["moe_routed"]), 4),
+               stats["moe_held_choices"] / routed, 4),
+           "rows_local_share": round(rows_local, 4),
            **_experts_read(stats, "latent")}
     gc.collect()
 
@@ -1013,8 +1077,19 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
         for rows in (shape["n_slots"], shape["prefill_chunk"]):
             key = ("bfloat16", rows, sz["d"], sz["f"])
             _check(engaged("moe_experts", lambda k: k == key),
-                   f"f-tiled grouped expert kernel did not engage for "
-                   f"{key}")
+                   f"grouped expert kernel did not engage for {key}")
+        from deeplearning4j_tpu.ops import pallas_moe_experts as pme
+
+        bucket, mixer = shape["short_len"], net.layers[1].mixers()[0]
+        if pme.sorted_serves(bucket, sz["held"][1], sz["topk"],
+                             pme.GATED_SILU):
+            key = ("bfloat16", pme.SORTED_ROWS, sz["d"], sz["f"], "sorted")
+            _check(engaged("moe_experts", lambda k: k == key),
+                   f"sorted expert kernel did not engage for {key}")
+        if mixer.query_block(bucket) < bucket:
+            _check(engaged("mla_prefill", lambda k: True),
+                   f"the prefill kernel did not serve the {bucket}-token "
+                   "prompt")
         _check(not any(out["pool_layout_copies"].values()),
                f"the decode programs copy their pools: "
                f"{out['pool_layout_copies']}")
@@ -1173,6 +1248,8 @@ def main(argv=None) -> int:
                 kernels=True)
         if "latent" in names:
             run("latent", phase_latent, LATENT, LATENT_SERVE, kernels=True)
+            run("latent_h128", phase_latent, LATENT_H128,
+                LATENT_H128_SERVE, kernels=True, family="deepseek_v2")
         if "lstm" in names:
             run("lstm", phase_lstm, LSTM, kernels=True)
         if "multichip" in names:

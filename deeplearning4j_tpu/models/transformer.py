@@ -337,6 +337,63 @@ def longcat_configuration(vocab_size: int, d_model: int, n_layers: int, *,
             .build())
 
 
+def deepseek_v2_configuration(vocab_size: int, d_model: int, n_layers: int,
+                              *, n_heads: int, q_rank: int, kv_rank: int,
+                              nope_dim: int, rope_dim: int, v_dim: int,
+                              rope_theta: float = 10000.0,
+                              rope_scaling=None, n_dense_layers: int = 1,
+                              ffn_width: int, n_experts: int, top_k: int,
+                              expert_width: int, shared_width: int = 0,
+                              routed_scale: float = 1.0, n_groups: int = 1,
+                              topk_groups: int = 1, experts_held=None,
+                              eps: float = 1e-6, seed: int = 12345,
+                              learning_rate: float = 3e-4,
+                              updater: Updater = Updater.ADAM,
+                              ) -> MultiLayerConfiguration:
+    """Causal LM of `n_layers` pre-norm `DecoderBlock`s, each a latent
+    attention (MLA) mixer and a feed-forward: the first `n_dense_layers`
+    a dense gated-silu MLP of `ffn_width`, the rest `n_experts` routed
+    gated-silu experts, `top_k` a token chosen on a softmax over all of
+    them among the token's `topk_groups` best of `n_groups` groups
+    (device-limited routing), gates the scores times `routed_scale`, plus
+    a shared MLP of `shared_width`; rotary on the rope dimensions,
+    stretched by `rope_scaling` (a `YarnScaling`, its JSON dict, or
+    None); RMSNorm, no positional layer, one trailing norm and an
+    untied, bias-free output head (the Hugging Face `deepseek_v2`
+    family's layout). `experts_held = (first, count)`: the share of each
+    routed layer's experts this network holds."""
+    b = (NeuralNetConfiguration.Builder()
+         .seed(seed)
+         .learning_rate(learning_rate)
+         .updater(updater)
+         .drop_out(0.0)
+         .list()
+         .layer(TokenEmbedding(n_in=vocab_size, n_out=d_model,
+                               positional=False)))
+    mixer = LatentAttentionMixer(
+        n_heads=n_heads, q_rank=q_rank, kv_rank=kv_rank, nope_dim=nope_dim,
+        rope_dim=rope_dim, v_dim=v_dim, rope_theta=rope_theta, eps=eps,
+        rope_scaling=rope_scaling)
+    routed = MoEFeedForward(
+        n_experts=n_experts, top_k=top_k, expert_width=expert_width,
+        shared_width=shared_width, experts_held=experts_held,
+        scoring="softmax_all", routed_scale=routed_scale,
+        n_groups=n_groups, topk_groups=topk_groups)
+    for i in range(n_layers):
+        b = b.layer(DecoderBlock(
+            n_in=d_model, n_out=d_model, mixer=mixer, norm=RMSNorm(eps=eps),
+            ffn=GatedMLP(width=ffn_width) if i < n_dense_layers else routed))
+    return (b
+            .layer(RMSNormalization(n_in=d_model, n_out=d_model, eps=eps,
+                                    dropout=0.0))
+            .layer(RnnOutputLayer(n_in=d_model, n_out=vocab_size,
+                                  has_bias=False,
+                                  activation=Activation.SOFTMAX,
+                                  loss=LossFunction.MCXENT, dropout=0.0))
+            .set_input_type(InputType.recurrent(vocab_size))
+            .build())
+
+
 # ---------------------------------------------------------------------------
 # shared decode plan + per-block compute (generate() AND the serving
 # decode engine trace through these — one implementation of the numerics)

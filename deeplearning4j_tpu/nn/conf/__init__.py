@@ -31,6 +31,7 @@ from deeplearning4j_tpu.nn.conf.decoder_block import (  # noqa: F401
     MoEFeedForward,
     RMSNorm,
     ShortcutDecoderBlock,
+    YarnScaling,
 )
 from deeplearning4j_tpu.nn.conf.variational import (  # noqa: F401
     BernoulliReconstructionDistribution,

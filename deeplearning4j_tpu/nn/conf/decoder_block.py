@@ -55,7 +55,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
     register_layer,
     rms_norm,
 )
-from deeplearning4j_tpu.ops import delta_rule, ssm
+from deeplearning4j_tpu.ops import delta_rule, rope, ssm
 
 _KINDS = {}
 _FLASH_FROM = 1024  # keys: beyond it attention takes the flash / blockwise path
@@ -74,8 +74,9 @@ def kind_from_json(d):
     d = dict(d)
     cls = _KINDS[d.pop("kind")]
     names = {f.name for f in dataclasses.fields(cls)}
-    return cls(**{k: tuple(v) if isinstance(v, list) else v
-                  for k, v in d.items() if k in names})
+    value = lambda v: tuple(v) if isinstance(v, list) else \
+        kind_from_json(v) if isinstance(v, dict) and "kind" in v else v
+    return cls(**{k: value(v) for k, v in d.items() if k in names})
 
 
 class _Kind:
@@ -83,7 +84,8 @@ class _Kind:
         out = {"kind": self.KIND}
         for f in dataclasses.fields(self):
             v = getattr(self, f.name)
-            out[f.name] = list(v) if isinstance(v, tuple) else v
+            out[f.name] = list(v) if isinstance(v, tuple) else \
+                v.to_json() if isinstance(v, _Kind) else v
         return out
 
 
@@ -111,7 +113,47 @@ class RMSNorm(_Kind):
         return rms_norm(x, w, self.eps)
 
 
+# ----------------------------------------------------- rotary scaling kinds
+@_kind
+@dataclass(frozen=True)
+class YarnScaling(_Kind):
+    """YaRN (arXiv:2309.00071) as a published `rope_scaling` of type
+    "yarn" states it (`ops/rope.py` has the arithmetic): the rotary
+    pairs' inverse frequencies blended between `f_i` and `f_i / factor`
+    by how often a pair turns over the `original_max` positions the
+    model was trained on, the cos and sin tables times `m(mscale) /
+    m(mscale_all_dim)`, and the attention's softmax scale times
+    `m(mscale_all_dim)^2` (`mscale_all_dim` 0: times 1), `m(x) = 0.1 x
+    ln(factor) + 1`."""
+    KIND = "yarn"
+    factor: float = 1.0
+    original_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def inv_freq(self, dim: int, base: float):
+        return rope.yarn_inv_freq(dim, base, self.factor, self.original_max,
+                                  self.beta_fast, self.beta_slow)
+
+    @property
+    def table_scale(self) -> float:
+        return rope.yarn_mscale(self.factor, self.mscale) \
+            / rope.yarn_mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def softmax_scale(self) -> float:
+        return rope.yarn_mscale(self.factor, self.mscale_all_dim) ** 2 \
+            if self.mscale_all_dim else 1.0
+
+
 # -------------------------------------------------------------- mixer kinds
+# float32 scores one block of a prompt's queries may take in the expanded
+# attention: a prompt whose whole (H, T, T) is larger goes by blocks
+_SCORE_BYTES = 1 << 29
+
+
 def _decay_params(k_dt, k_a, n_heads: int, dtype) -> dict:
     """A recurrent mixer's per-head decay as Mamba-2 draws it: `dt_bias`
     the inverse softplus of a step log-uniform in [1e-3, 1e-1], `A_log`
@@ -240,13 +282,23 @@ class LatentAttentionMixer(_Kind):
     scale_q_lora: bool = False
     scale_kv_lora: bool = False
     eps: float = 1e-5
+    rope_scaling: Optional[YarnScaling] = None
+
+    def __post_init__(self):
+        object.__setattr__(self, "rope_scaling",
+                           kind_from_json(self.rope_scaling))
 
     def latent_geometry(self) -> Tuple[int, int]:
         return self.kv_rank, self.rope_dim
 
     @property
     def sm_scale(self) -> float:
-        return 1.0 / math.sqrt(self.nope_dim + self.rope_dim)
+        """What multiplies the scores, in all three forms and in the
+        kernel: `(nope + rope)^-1/2`, times the rotary scaling's
+        temperature where the mixer has one."""
+        s = 1.0 / math.sqrt(self.nope_dim + self.rope_dim)
+        return s if self.rope_scaling is None \
+            else s * self.rope_scaling.softmax_scale
 
     def init_params(self, key, d: int, dtype, winit) -> dict:
         """The published `q_b_proj`, `kv_a_proj_with_mqa` and `kv_b_proj`
@@ -283,18 +335,26 @@ class LatentAttentionMixer(_Kind):
     def _rope(self, u, positions, heads: bool):
         """Rotary on (..., T, H, rope_dim) (`heads`) or (..., T,
         rope_dim) at `positions` (..., T) or (T,):
-        pairs (2i, 2i + 1) turn by `pos * theta^(-2i / rope_dim)`; the
-        result lies evens-first, the same for queries and keys, so
-        their product is the published one."""
-        from deeplearning4j_tpu.ops.rope import rope_angles, rope_rotate
-
-        half = self.rope_dim // 2
-        u = jnp.swapaxes(u.reshape(*u.shape[:-1], half, 2), -1, -2) \
-            .reshape(u.shape)
-        cos, sin = rope_angles(positions, self.rope_dim, self.rope_theta)
-        if not heads:                   # one key a position
-            return rope_rotate(u[..., None, :], cos, sin)[..., 0, :]
-        return rope_rotate(u, cos, sin)
+        pairs (2i, 2i + 1) turn by `pos * theta^(-2i / rope_dim)`, or
+        by the blended frequencies of `rope_scaling`; the result lies
+        evens-first, the same for queries and keys, so their product is
+        the published one."""
+        half, scaling = self.rope_dim // 2, self.rope_scaling
+        with jax.named_scope("mla.rope"):
+            u = jnp.swapaxes(u.reshape(*u.shape[:-1], half, 2), -1, -2) \
+                .reshape(u.shape)
+            if scaling is None:
+                cos, sin = rope.rope_angles(positions, self.rope_dim,
+                                            self.rope_theta)
+            else:
+                cos, sin = rope.rope_angles(
+                    positions, self.rope_dim, inv_freq=scaling.inv_freq(
+                        self.rope_dim, self.rope_theta))
+                if scaling.table_scale != 1.0:
+                    cos, sin = (t * scaling.table_scale for t in (cos, sin))
+            if not heads:                   # one key a position
+                return rope.rope_rotate(u[..., None, :], cos, sin)[..., 0, :]
+            return rope.rope_rotate(u, cos, sin)
 
     def project(self, p, x, positions):
         """`x` (..., T, d) at `positions` (..., T) -> (q_n (..., T, H,
@@ -352,10 +412,41 @@ class LatentAttentionMixer(_Kind):
                               preferred_element_type=jnp.float32) \
                 .astype(q_abs.dtype)
 
-    def attend_expanded(self, p, q_n, q_r, latent):
+    def query_block(self, T: int) -> int:
+        """Queries the expanded attention takes at a time over a prompt
+        of `T` positions: all of them where the heads' float32 scores
+        (H, T, T) fit `_SCORE_BYTES`, else the power of two of rows
+        whose scores against all `T` keys do (at least 128)."""
+        rows = _SCORE_BYTES // (4 * self.n_heads * T)
+        return T if rows >= T else max(128, 1 << (rows.bit_length() - 1))
+
+    def _attend_kernel(self, q_n, q_r, k_n, k_r, v, n_valid):
+        """The expanded attention of ONE prompt through the prefill
+        kernel of `ops/pallas_mla_attend.py`, heads first, or None where
+        it cannot serve (a CPU, the kill switch, a length off its
+        blocks, a batch)."""
+        from deeplearning4j_tpu.ops.pallas_mla_attend import (
+            mla_prefill_or_none,
+        )
+
+        B, T = q_n.shape[:2]
+        if B != 1:
+            return None
+        heads_first = lambda a: jnp.swapaxes(a[0], 0, 1)
+        n_valid = jnp.full((1,), T if n_valid is None else n_valid,
+                           jnp.int32)
+        o = mla_prefill_or_none(
+            heads_first(q_n), heads_first(q_r), heads_first(k_n), k_r[0],
+            heads_first(v), n_valid, sm_scale=self.sm_scale)
+        return None if o is None else jnp.swapaxes(o, 0, 1)[None]
+
+    def attend_expanded(self, p, q_n, q_r, latent, n_valid=None):
         """One sequence's queries (B, T, H, .) against its own latents
         (B, T, kv_rank + rope), keys and values expanded per head:
-        causal, float32 softmax. Returns (B, T, d)."""
+        causal, float32 softmax; where the heads' scores over the whole
+        prompt pass `_SCORE_BYTES`, through the prefill kernel (which
+        leaves the rows from `n_valid` on, a bucket's padding, zeros) or
+        by blocks of `query_block(T)` queries. Returns (B, T, d)."""
         c, k_r = latent[..., :self.kv_rank], latent[..., self.kv_rank:]
         with jax.named_scope("mla.kv_up"):
             cs = c * jnp.asarray(self._s_kv(p), c.dtype)
@@ -366,17 +457,38 @@ class LatentAttentionMixer(_Kind):
                            preferred_element_type=jnp.float32) \
                 .astype(c.dtype)
         T = c.shape[1]
-        with jax.named_scope("mla.attend"):
-            s = (jnp.einsum("bthn,bshn->bhts", q_n, k_n,
+
+        def attend(t0, t1):
+            """Queries t0..t1-1 against keys 0..t1-1 (all of both: the
+            arrays as they are, so that a prompt that goes whole lowers
+            to the program it always did)."""
+            whole = (t0, t1) == (0, T)
+            cut = (lambda a, lo: a) if whole else (lambda a, lo: a[:, lo:t1])
+            s = (jnp.einsum("bthn,bshn->bhts", cut(q_n, t0), cut(k_n, 0),
                             preferred_element_type=jnp.float32)
-                 + jnp.einsum("bthr,bsr->bhts", q_r, k_r,
+                 + jnp.einsum("bthr,bsr->bhts", cut(q_r, t0), cut(k_r, 0),
                               preferred_element_type=jnp.float32)) \
                 * self.sm_scale
-            s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
+            seen = jnp.tril(jnp.ones((T, T), bool)) if whole else \
+                jnp.arange(t0, t1)[:, None] >= jnp.arange(t1)
+            s = jnp.where(seen, s, -1e30)
             prob = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-            o = jnp.einsum("bhts,bshv->bthv", prob, v,
-                           preferred_element_type=jnp.float32) \
+            return jnp.einsum("bhts,bshv->bthv", prob, cut(v, 0),
+                              preferred_element_type=jnp.float32) \
                 .astype(v.dtype)
+
+        block = self.query_block(T)
+        with jax.named_scope("mla.attend"):
+            # a prompt too long for one (H, T, T) array: the prefill
+            # kernel where it serves, else blocks of queries, each
+            # against the keys up to its own last position (the keys a
+            # block cannot see are not multiplied at all)
+            o = attend(0, T) if block >= T else \
+                self._attend_kernel(q_n, q_r, k_n, k_r, v, n_valid)
+            if o is None:
+                o = jnp.concatenate(
+                    [attend(t0, min(t0 + block, T))
+                     for t0 in range(0, T, block)], axis=1)
         with jax.named_scope("mla.out"):
             return o.reshape(*o.shape[:2], -1) @ p["Wo"]
 
@@ -704,7 +816,13 @@ class MoEFeedForward(_Kind):
     logits; or "sigmoid", the experts chosen on `sigmoid(logit) +
     router_b` (a float32 leaf, one number an expert: it moves the choice
     and never the weight) and weighed by their unbiased scores
-    normalised to sum 1, times `routed_scale`."""
+    normalised to sum 1, times `routed_scale`; or "softmax_all", chosen
+    on a softmax over all the router's outputs (`n_zero_experts` of them
+    zero-compute experts after the real ones) and weighed by that score
+    times `routed_scale`. `n_groups` > 1 (with "softmax_all"): the
+    experts lie in that many equal groups and a token chooses among its
+    `topk_groups` best groups only, a group scored by its largest score
+    (device-limited routing: `parallel.experts.group_limited`)."""
     KIND = "moe"
     n_experts: int = 8
     top_k: int = 2
@@ -715,14 +833,20 @@ class MoEFeedForward(_Kind):
     scoring: str = "softmax"
     routed_scale: float = 1.0
     n_zero_experts: int = 0
+    n_groups: int = 1
+    topk_groups: int = 1
 
     def __post_init__(self):
+        from deeplearning4j_tpu.parallel.experts import check_groups
+
         if self.activation not in ("gated_silu", "relu2"):
             raise ValueError(f"activation {self.activation!r}: "
                              "'gated_silu' or 'relu2'")
         if self.scoring not in ("softmax", "sigmoid", "softmax_all"):
             raise ValueError(f"scoring {self.scoring!r}: 'softmax', "
                              "'sigmoid' or 'softmax_all'")
+        check_groups(self.scoring, self.n_experts, self.n_groups,
+                     self.topk_groups, self.n_zero_experts)
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -755,10 +879,11 @@ class MoEFeedForward(_Kind):
 
     def forward(self, p, x, count_mask=None):
         """`x` (..., d) -> (y, counts): with `count_mask` (one bool a
-        token: the rows anyone will read), how many masked-in tokens
-        chose each held expert and whether it was read, (2, held) (with
-        zero experts: that and how many of their choices fell on zero
-        experts, a pair); else None (`parallel.experts.dropless_moe`)."""
+        token: the rows anyone will read), a `RouteCounts` over the
+        masked-in tokens (how many chose each held expert and whether it
+        was read, (2, held); how many chose any held expert; with zero
+        experts, how many choices fell on those); else None
+        (`parallel.experts.dropless_moe`)."""
         from deeplearning4j_tpu.parallel.experts import (
             dropless_moe,
             gated_mlp,
@@ -771,7 +896,8 @@ class MoEFeedForward(_Kind):
             top_k=self.top_k, experts_held=self.held, count_mask=count_mask,
             act=self.activation, router_bias=p.get("router_b"),
             routed_scale=self.routed_scale, scoring=self.scoring,
-            n_zero=self.n_zero_experts)
+            n_zero=self.n_zero_experts, n_groups=self.n_groups,
+            topk_groups=self.topk_groups)
         if self.shared_width:
             with jax.named_scope("moe.shared"):
                 y = y + (gated_mlp(flat, p["sWg"], p["sWu"], p["sWd"])

@@ -35,8 +35,19 @@ program copies it; as an XLA scatter on the lane axis the write would
 cost two whole-pool copies a step). A lane cannot be addressed below the
 128-wide tile, so a slot's write moves its page tile in and out.
 
-Dispatch rides `ops/kernel_dispatch.py` under the families `mla_attend`
-and `latent_write`: each probe compiles and runs its kernel at the exact
+`mla_prefill`: the EXPANDED attention of a whole prompt too long for one
+array of scores (`LatentAttentionMixer.attend_expanded`): a flash walk
+over (head, block of queries, block of keys) with the operands at their
+own widths: a head's nope queries and keys (128), the rope queries (64)
+against the ONE rope key of a position, shared by all heads and never
+broadcast, and values of 128, so the multiply-adds are the attention's
+own (the flash kernel of `ops/pallas_attention.py` takes one head size
+for all three and would need 256 for each). Blocks of queries at and
+past `n_valid`, the prompt's padding in its bucket, compute nothing and
+come out zeros.
+
+Dispatch rides `ops/kernel_dispatch.py` under the families `mla_attend`,
+`mla_prefill` and `latent_write`: each probe compiles and runs its kernel at the exact
 shape class and holds it to the `jax.numpy` form beside it
 (`mla_attend_xla`: gather the slot's pages, then attend; `latent_write_xla`:
 the scatter), the attend's key ending in the block it ran with
@@ -59,6 +70,7 @@ from deeplearning4j_tpu.ops.kernel_dispatch import (
 )
 
 FAMILY = "mla_attend"          # this module's rows in kernel_verdicts()
+PREFILL_FAMILY = "mla_prefill"
 WRITE_FAMILY = "latent_write"
 NEG_INF = -1e30
 
@@ -319,6 +331,104 @@ def latent_write(pool, new, pids, loff, *, interpret: bool = False):
       new.astype(pool.dtype)[..., None], pool)
 
 
+# ------------------------------------------------- the prompt's own attention
+PREFILL_BLOCK = 512     # queries and keys a step of the prefill walk takes
+
+
+def _prefill_kernel(n_valid_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
+                    o_ref, m_scr, l_scr, acc_scr, *, sm_scale: float,
+                    block: int):
+    from jax.experimental import pallas as pl
+
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    # keys past the diagonal add nothing, queries past the prompt are padding
+    @pl.when((ki <= qi) & (qi * block < n_valid_ref[0]))
+    def _step():
+        dims = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(qn_ref[0], kn_ref[0], dims,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr_ref[0], kr_ref[...], dims,
+                                   preferred_element_type=jnp.float32)) \
+            * sm_scale
+        q_pos = qi * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 0)
+        k_pos = ki * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 1)
+        s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+        m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # every row sees its own position, so no row of a step is all masked
+        prob = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(prob, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jnp.dot(
+            prob.astype(v_ref.dtype), v_ref[0],
+            preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finalize():
+        l = l_scr[:, :1]
+        o_ref[0] = jnp.where(l > 0, acc_scr[...] / jnp.where(l > 0, l, 1.0),
+                             0.0).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def mla_prefill(q_n, q_r, k_n, k_r, v, n_valid, *, sm_scale: float,
+                interpret: bool = False):
+    """Causal attention of one prompt, heads first: `q_n`, `k_n` (H, T,
+    nope), `q_r` (H, T, rope), `k_r` (T, rope) the one rope key a
+    position, `v` (H, T, v_dim); `n_valid` (1,) int32 the prompt's length
+    in its bucket of `T` (a multiple of `PREFILL_BLOCK`). Returns (H, T,
+    v_dim): rows from the first block at or past `n_valid` on are
+    zeros."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    H, T, nope = q_n.shape
+    rope, vd, B = q_r.shape[2], v.shape[2], PREFILL_BLOCK
+    head_q = lambda w: pl.BlockSpec((1, B, w), lambda h, i, j, n: (h, i, 0))
+    head_k = lambda w: pl.BlockSpec((1, B, w), lambda h, i, j, n: (h, j, 0))
+    return pl.pallas_call(
+        functools.partial(_prefill_kernel, sm_scale=sm_scale, block=B),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(H, T // B, T // B),
+            in_specs=[head_q(nope), head_q(rope), head_k(nope),
+                      pl.BlockSpec((B, rope), lambda h, i, j, n: (j, 0)),
+                      head_k(vd)],
+            out_specs=head_q(vd),
+            scratch_shapes=[pltpu.VMEM((B, 128), jnp.float32),
+                            pltpu.VMEM((B, 128), jnp.float32),
+                            pltpu.VMEM((B, vd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((H, T, vd), v.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit()),
+        interpret=interpret,
+    )(n_valid, q_n, q_r, k_n, k_r, v)
+
+
+def mla_prefill_xla(q_n, q_r, k_n, k_r, v, *, sm_scale: float):
+    """The same attention as one array of scores a head: what the kernel
+    is held to (small shapes only)."""
+    s = (jnp.einsum("htn,hsn->hts", q_n, k_n,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("htr,sr->hts", q_r, k_r,
+                      preferred_element_type=jnp.float32)) * sm_scale
+    T = s.shape[1]
+    s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, NEG_INF)
+    return jnp.einsum("hts,hsv->htv", jax.nn.softmax(s, -1).astype(v.dtype),
+                      v, preferred_element_type=jnp.float32).astype(v.dtype)
+
+
 # ------------------------------------------------------------ dispatch
 def _platform_supported() -> bool:
     return _kernels_dispatch("DL4J_TPU_NO_PALLAS_MLA_ATTEND")
@@ -450,6 +560,58 @@ def latent_write_or_none(pool, new, pids, loff) -> Optional[jnp.ndarray]:
     except Exception as e:  # per-shape staging failure: fall back
         _record_decline(WRITE_FAMILY, key, f"staging at {pool.shape}: "
                                            f"{type(e).__name__}: {e}")
+        return None
+
+
+def _prefill_probe(dtype, nope: int, rope: int, vd: int) -> bool:
+    """Compile and run the prefill kernel at this shape class (two heads,
+    three blocks, the last one padding) and hold it to one array of
+    scores."""
+    import numpy as np
+
+    rng = np.random.default_rng(0)
+    H, T = 2, 3 * PREFILL_BLOCK
+    mk = lambda *shape: jnp.asarray(rng.standard_normal(shape) / 4, dtype)
+    args = (mk(H, T, nope), mk(H, T, rope), mk(H, T, nope), mk(T, rope),
+            mk(H, T, vd))
+    live = 2 * PREFILL_BLOCK - 5
+    got = np.asarray(mla_prefill(*args, jnp.asarray([live], jnp.int32),
+                                 sm_scale=0.1), np.float32)
+    want = np.asarray(mla_prefill_xla(*args, sm_scale=0.1), np.float32)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
+    if np.any(got[:, 2 * PREFILL_BLOCK:]) or not np.allclose(
+            got[:, :live], want[:, :live], atol=tol, rtol=tol):
+        raise ValueError("prefill kernel compiled but disagrees with the "
+                         "whole scores: max abs err "
+                         f"{np.max(np.abs(got - want)[:, :live]):.3g}")
+    return True
+
+
+def mla_prefill_or_none(q_n, q_r, k_n, k_r, v, n_valid, *, sm_scale: float):
+    """Dispatch probe: the prompt's attention, or None when the kernel
+    cannot serve this call (CPU backend, kill switch, a dtype, widths off
+    the tile grid, a length off its blocks) or its shape class failed the
+    probe; callers take blocks of queries in `jax.numpy`."""
+    H, T, nope = q_n.shape
+    rope, vd, dtype = q_r.shape[2], v.shape[2], q_n.dtype
+    if not _platform_supported() or dtype not in (jnp.float32, jnp.bfloat16):
+        return None
+    key = (jnp.dtype(dtype).name, nope, rope, vd, PREFILL_BLOCK)
+    if nope % 128 or vd % 128 or rope % 8 or T % PREFILL_BLOCK:
+        # under the length's own key: a prompt off the blocks says nothing
+        # of the class the bucketed prompts run in
+        _record_decline(PREFILL_FAMILY, key[:-1] + (f"{T} positions",),
+                        f"widths {nope} + {rope} / {vd}: off the tile grid "
+                        f"or the blocks of {PREFILL_BLOCK}")
+        return None
+    if not _probe_verdict(PREFILL_FAMILY, key, _prefill_probe,
+                          (dtype, nope, rope, vd)):
+        return None
+    try:
+        return mla_prefill(q_n, q_r, k_n, k_r, v, n_valid, sm_scale=sm_scale)
+    except Exception as e:  # per-shape staging failure: fall back
+        _record_decline(PREFILL_FAMILY, key, f"staging at {q_n.shape}: "
+                        f"{type(e).__name__}: {e}")
         return None
 
 

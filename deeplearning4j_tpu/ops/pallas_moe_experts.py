@@ -54,6 +54,16 @@ as experts are hit for each one a token chose: at decode sizes the
 weights' bytes bound it, not the MXU. As XLA batched einsums the same product materialises the
 (E, N, f) intermediates in HBM.
 
+A prefill of thousands of tokens is the other case: every held expert
+is hit and each token chose few of them, so the walk above would run
+every token through every expert. `moe_experts_sorted` takes the (token,
+expert) choices SORTED by expert instead, each expert's rows padded to
+whole tiles of `SORTED_ROWS`: a grid over row tiles, a scalar-prefetched
+vector naming each tile's expert (consecutive tiles of one expert bring
+its weights once), nothing computed past the last used tile. The
+multiply-adds are those of the choices made; `parallel.experts` sorts,
+gathers and adds the rows back.
+
 Dispatch rides `ops/kernel_dispatch.py` under the family name
 `moe_experts`: the probe compiles and runs the kernel at the exact shape
 class and checks it against `parallel.experts.grouped_expert_ffn_xla`;
@@ -220,6 +230,73 @@ def moe_experts(x, gates, Wg, Wu, Wd, hit, *, act: str = GATED_SILU,
       *((Wu, Wd) if act == RELU2 else (Wg, Wu, Wd)))
 
 
+SORTED_ROWS = 128       # rows a tile of the sorted product holds
+
+
+def _sorted_kernel(expert_ref, n_used_ref, x_ref, g_ref, wg_ref, wu_ref,
+                   wd_ref, o_ref):
+    from jax.experimental import pallas as pl
+
+    del expert_ref  # the index maps read it
+    used = pl.program_id(0) < n_used_ref[0]
+
+    @pl.when(used)
+    def _():
+        x = x_ref[...]
+        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        h = g * jax.nn.sigmoid(g) * u * g_ref[...]
+        o_ref[...] = jnp.dot(h.astype(x.dtype), wd_ref[0],
+                             preferred_element_type=jnp.float32) \
+            .astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(used))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def moe_experts_sorted(xs, gs, tile_expert, n_used, Wg, Wu, Wd, *,
+                       interpret: bool = False):
+    """The gated-silu product over rows sorted by expert: `xs` (M, d),
+    tile `t` (rows `t * SORTED_ROWS` on) all of expert `tile_expert[t]`
+    (int32, (M / SORTED_ROWS,)), `gs` (M, 1) float32 each row's gate (0
+    on a padding row), `n_used` (1,) int32 the tiles that hold rows;
+    tiles from there on come out zeros and name the last used tile's
+    expert, so that nothing is copied for them. Returns (M, d)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    M, d = xs.shape
+    _, f, _ = Wd.shape
+    tn = SORTED_ROWS
+    tile = pl.BlockSpec((tn, d), lambda t, expert, n_used: (t, 0))
+    gate = pl.BlockSpec((tn, 1), lambda t, expert, n_used: (t, 0))
+    whole = lambda *shape: pl.BlockSpec(
+        (1,) + shape, lambda t, expert, n_used: (expert[t], 0, 0))
+    return pl.pallas_call(
+        _sorted_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(M // tn,),
+            in_specs=[tile, gate, whole(d, f), whole(d, f), whole(f, d)],
+            out_specs=tile),
+        out_shape=jax.ShapeDtypeStruct((M, d), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit()),
+        interpret=interpret,
+    )(tile_expert, n_used, xs, gs, Wg, Wu, Wd)
+
+
+def sorted_serves(N: int, E: int, k: int, act: str) -> bool:
+    """Whether the sorted product is the cheaper walk: more than one
+    token tile (a prefill; a decode step's rows are one tile and its
+    cost is the weights' bytes), gated experts, and so few choices a
+    token that even if every one fell on a held expert the sorted rows
+    were under half of what the hit-first walk multiplies."""
+    return act == GATED_SILU and N > _MAX_ROWS and 2 * k <= E
+
+
 def vmem_bytes_estimate(tn: int, d: int, f: int, dtype,
                         act: str = GATED_SILU) -> int:
     """Resident VMEM of one grid step that brings `f` of an expert's
@@ -324,5 +401,64 @@ def moe_experts_or_none(x, gates, Wg, Wu, Wd, hit, act: str = GATED_SILU):
         return moe_experts(x, gates, Wg, Wu, Wd, hit, act=act)
     except Exception as e:  # per-shape staging failure: fall back
         _record_decline(FAMILY, key, f"staging at {x.shape}: "
+                                     f"{type(e).__name__}: {e}")
+        return None
+
+
+def _sorted_probe(dtype, d: int, f: int) -> bool:
+    """Compile and run the sorted kernel at this shape class (three
+    experts: one of two tiles, one chosen by no row, one of one tile;
+    then an unused tile) and hold it to the XLA products."""
+    import numpy as np
+
+    from deeplearning4j_tpu.parallel.experts import grouped_expert_ffn_xla
+
+    rng = np.random.default_rng(0)
+    E, tn = 3, SORTED_ROWS
+    tile_expert = jnp.asarray([0, 0, 2, 2], jnp.int32)
+    xs = jnp.asarray(rng.standard_normal((4 * tn, d)), dtype)
+    Wg, Wu = (jnp.asarray(rng.standard_normal((E, d, f)) / d ** 0.5, dtype)
+              for _ in range(2))
+    Wd = jnp.asarray(rng.standard_normal((E, f, d)) / f ** 0.5, dtype)
+    gs = jnp.asarray(rng.random((4 * tn, 1)), jnp.float32)
+    got = np.asarray(moe_experts_sorted(
+        xs, gs, tile_expert, jnp.asarray([3], jnp.int32), Wg, Wu, Wd),
+        np.float32)
+    gates = jnp.zeros((4 * tn, E), jnp.float32) \
+        .at[:2 * tn, 0].set(gs[:2 * tn, 0]) \
+        .at[2 * tn:3 * tn, 2].set(gs[2 * tn:3 * tn, 0])
+    want = np.asarray(grouped_expert_ffn_xla(xs, gates, Wg, Wu, Wd),
+                      np.float32)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
+    err = float(np.max(np.abs(got - want))) / float(np.max(np.abs(want)))
+    if not np.isfinite(err) or err > tol or np.any(got[3 * tn:]):
+        raise ValueError("sorted kernel compiled but lies %.3g of the "
+                         "largest output from the XLA products" % err)
+    return True
+
+
+def moe_experts_sorted_or_none(xs, gs, tile_expert, n_used, Wg, Wu, Wd):
+    """Dispatch probe of the sorted product: its rows, or None when the
+    kernel cannot serve (CPU backend, kill switch, a dtype or widths off
+    the tile grid, an expert that does not fit VMEM whole beside a tile)
+    or its shape class failed the probe. Its key ends in "sorted"."""
+    M, d = xs.shape
+    _, f, _ = Wd.shape
+    dtype = xs.dtype
+    if not _platform_supported() or Wu.dtype != dtype \
+            or dtype not in (jnp.float32, jnp.bfloat16):
+        return None
+    key = (jnp.dtype(dtype).name, SORTED_ROWS, d, f, "sorted")
+    if d % 128 or f % 128 or M % SORTED_ROWS or \
+            vmem_bytes_estimate(SORTED_ROWS, d, f, dtype) > _vmem_limit():
+        _record_decline(FAMILY, key, f"widths {d} x {f}: off the tile "
+                                     "grid or over the VMEM ceiling whole")
+        return None
+    if not _probe_verdict(FAMILY, key, _sorted_probe, (dtype, d, f)):
+        return None
+    try:
+        return moe_experts_sorted(xs, gs, tile_expert, n_used, Wg, Wu, Wd)
+    except Exception as e:  # per-shape staging failure: fall back
+        _record_decline(FAMILY, key, f"staging at {xs.shape}: "
                                      f"{type(e).__name__}: {e}")
         return None

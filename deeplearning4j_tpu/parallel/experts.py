@@ -17,7 +17,7 @@ routing is by sort/scatter, no data-dependent control flow.
 from __future__ import annotations
 
 import contextlib
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -300,34 +300,80 @@ def sigmoid_topk_gates(logits: jnp.ndarray, bias: jnp.ndarray, top_k: int,
     return jnp.zeros(logits.shape, jnp.float32).at[rows, top_i].set(w)
 
 
+def group_limited(scores: jnp.ndarray, n_groups: int,
+                  topk_groups: int) -> jnp.ndarray:
+    """Device-limited routing's choice of groups (DeepSeek-V2,
+    arXiv:2405.04434 section 2.1.3; the published `group_limited_greedy`):
+    the E experts lie in `n_groups` groups of `E / n_groups` (expert `e`
+    in group `e // (E / n_groups)`), a group's score is the LARGEST
+    score in it, each row keeps its `topk_groups` best groups and every
+    other group's scores are set to 0. `scores` (N, E) float32, >= 0."""
+    N, E = scores.shape
+    with jax.named_scope("moe.groups"):
+        by_group = scores.reshape(N, n_groups, E // n_groups)
+        _, top_g = lax.top_k(jnp.max(by_group, axis=-1), topk_groups)
+        keep = jnp.zeros((N, n_groups), bool).at[
+            jnp.arange(N)[:, None], top_g].set(True)
+        return jnp.where(keep[:, :, None], by_group, 0.0).reshape(N, E)
+
+
 def softmax_all_topk_gates(logits: jnp.ndarray, bias: jnp.ndarray,
-                           top_k: int, scale: float) -> jnp.ndarray:
+                           top_k: int, scale: float, n_groups: int = 1,
+                           topk_groups: int = 1) -> jnp.ndarray:
     """(N, E) router logits -> (N, E) float32 gates, scored by a
-    softmax over ALL the logits (the LongCat-Flash router): the `top_k`
-    experts are chosen on `softmax(logits) + bias` (`bias` (E,): it
-    moves the choice and never the weight), and a chosen expert's gate
-    is its unbiased score times `scale`, NOT renormalised over the
-    chosen; zero elsewhere."""
+    softmax over ALL the logits (the LongCat-Flash router, and
+    DeepSeek-V2's): the `top_k` experts are chosen on `softmax(logits) +
+    bias` (`bias` (E,): it moves the choice and never the weight), and a
+    chosen expert's gate is its unbiased score times `scale`, NOT
+    renormalised over the chosen; zero elsewhere. With `n_groups` > 1
+    the choice is made among each row's `topk_groups` best groups only
+    (`group_limited`)."""
     s = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    _, top_i = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    choose_on = s + bias.astype(jnp.float32)
+    if n_groups > 1:
+        choose_on = group_limited(choose_on, n_groups, topk_groups)
+    _, top_i = lax.top_k(choose_on, top_k)
     rows = jnp.arange(logits.shape[0])[:, None]
     return jnp.zeros(logits.shape, jnp.float32).at[rows, top_i].set(
         s[rows, top_i] * scale)
 
 
+def check_groups(scoring: str, n_experts: int, n_groups: int,
+                 topk_groups: int, n_zero: int = 0) -> None:
+    """Refuse a choice of groups the routers here are not written for:
+    the group rule (a group's score its largest) is "softmax_all"'s,
+    over real experts alone."""
+    if n_groups == 1:
+        return
+    if scoring != "softmax_all" or n_zero:
+        raise ValueError(
+            f"n_groups {n_groups} with scoring {scoring!r} and {n_zero} "
+            "zero-compute experts: groups are chosen by their largest "
+            "score under 'softmax_all' over real experts only")
+    if n_groups < 1 or n_experts % n_groups \
+            or not 1 <= topk_groups <= n_groups:
+        raise ValueError(
+            f"{n_experts} experts in {n_groups} groups, {topk_groups} "
+            "chosen: the groups are equal and at least one is chosen")
+
+
 def routed_gates(logits: jnp.ndarray, top_k: int, *, bias=None,
-                 scale: float = 1.0, scoring: str = None) -> jnp.ndarray:
+                 scale: float = 1.0, scoring: str = None,
+                 n_groups: int = 1, topk_groups: int = 1) -> jnp.ndarray:
     """(N, E) float32 gates over everything the router scores, by
     `scoring`: "softmax" (over the chosen: `topk_gates`), "sigmoid"
     (`sigmoid_topk_gates`) or "softmax_all"
     (`softmax_all_topk_gates`); None: sigmoid where there is a `bias`,
-    else softmax."""
+    else softmax. `n_groups`, `topk_groups`: the experts' groups and how
+    many of them a row may reach (1: no groups)."""
     scoring = scoring or ("softmax" if bias is None else "sigmoid")
+    check_groups(scoring, logits.shape[1], n_groups, topk_groups)
     if scoring == "softmax":
         return topk_gates(logits, top_k)
-    fn = sigmoid_topk_gates if scoring == "sigmoid" \
-        else softmax_all_topk_gates
-    return fn(logits, bias, top_k, scale)
+    if scoring == "sigmoid":
+        return sigmoid_topk_gates(logits, bias, top_k, scale)
+    return softmax_all_topk_gates(logits, bias, top_k, scale, n_groups,
+                                  topk_groups)
 
 
 def held_gates(logits: jnp.ndarray, top_k: int, experts_held, *,
@@ -369,18 +415,87 @@ def grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act: str = GATED_SILU):
     return y.astype(x.dtype)
 
 
-def grouped_expert_ffn(x, gates, Wg, Wu, Wd, hit, act: str = GATED_SILU):
-    """The grouped product behind the kernel-dispatch contract: the
-    Pallas kernel of `ops/pallas_moe_experts.py` on a TPU (the weights
-    of each expert that `hit` (E,) bool marks streamed through VMEM
-    once, the others left in HBM), the batched XLA products over every
-    expert elsewhere. A row whose gate is not zero on an unmarked
-    expert comes out without that expert's part."""
+def sort_by_expert(gates, k: int, tile: int):
+    """The choices behind `gates` (N, E) (at most `k` a row not zero),
+    sorted by expert with each expert's rows padded to whole tiles of
+    `tile`: (rows (M,) int32, the token each sorted row is (0 on
+    padding); gs (M, 1) float32, its gate (0 on padding); tile_expert
+    (M / tile,) int32; n_used (1,) int32, the tiles that hold rows; back
+    (N, k) int32, where each of a token's `k` choices lies among the
+    sorted rows (a choice it did not make: a row whose gate is 0)),
+    with M = N * k in whole tiles + E tiles, what the rows take if every
+    choice is made and every expert's last tile is all but empty."""
+    N, E = gates.shape
+    M = (-(-N * k // tile) + E) * tile
+    vals, idx = lax.top_k(gates, k)
+    expert = jnp.where(vals != 0, idx, E).reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(expert, stable=True).astype(jnp.int32)
+    by_expert = expert[order]
+    counts = jnp.sum(expert[:, None] == jnp.arange(E, dtype=jnp.int32),
+                     axis=0, dtype=jnp.int32)
+    padded = -(-counts // tile) * tile
+    ends = jnp.cumsum(padded)
+    first = jnp.concatenate([ends - padded, ends[-1:]])       # (E + 1,)
+    first_unpadded = jnp.concatenate([jnp.cumsum(counts) - counts,
+                                      jnp.sum(counts, dtype=jnp.int32)[None]])
+    # a choice not made goes to the last row: past every expert's rows
+    dest = jnp.where(by_expert < E, first[by_expert]
+                     + jnp.arange(N * k, dtype=jnp.int32)
+                     - first_unpadded[by_expert], M - 1)
+    rows = jnp.zeros((M,), jnp.int32).at[dest].set(order // k)
+    gs = jnp.zeros((M,), jnp.float32).at[dest].set(
+        jnp.where(by_expert < E, vals.reshape(-1)[order], 0.0))
+    n_used = ends[-1:] // tile
+    tile_expert = jnp.searchsorted(
+        ends, jnp.arange(M // tile, dtype=jnp.int32) * tile, side="right")
+    last = jnp.searchsorted(ends, ends[-1] - 1, side="right")
+    tile_expert = jnp.minimum(tile_expert, last).astype(jnp.int32)
+    back = jnp.zeros((N * k,), jnp.int32).at[order].set(dest)
+    return rows, gs[:, None], tile_expert, n_used.astype(jnp.int32), \
+        back.reshape(N, k)
+
+
+def sorted_expert_ffn_or_none(x, gates, Wg, Wu, Wd, k: int):
+    """`grouped_expert_ffn_xla`'s sum through the sorted kernel of
+    `ops/pallas_moe_experts.py`: every choice one row, the rows sorted by
+    expert, each expert's FFN over its own rows only, and a token's rows
+    added back in float32. None where the kernel cannot serve."""
     from deeplearning4j_tpu.ops.pallas_moe_experts import (
-        moe_experts_or_none,
+        SORTED_ROWS,
+        moe_experts_sorted_or_none,
     )
 
-    out = moe_experts_or_none(x, gates, Wg, Wu, Wd, hit, act)
+    rows, gs, tile_expert, n_used, back = sort_by_expert(gates, k,
+                                                         SORTED_ROWS)
+    ys = moe_experts_sorted_or_none(x[rows], gs, tile_expert, n_used,
+                                    Wg, Wu, Wd)
+    if ys is None:
+        return None
+    return jnp.sum(ys[back].astype(jnp.float32), axis=1).astype(x.dtype)
+
+
+def grouped_expert_ffn(x, gates, Wg, Wu, Wd, hit, act: str = GATED_SILU,
+                       top_k: int = 0):
+    """The grouped product behind the kernel-dispatch contract: the
+    Pallas kernels of `ops/pallas_moe_experts.py` on a TPU, the batched
+    XLA products over every expert elsewhere. A decode step's rows walk
+    the experts that `hit` (E,) bool marks (each one's weights streamed
+    through VMEM once, the others left in HBM); a prefill's thousands of
+    rows, each with at most `top_k` choices among many experts, go
+    sorted by expert instead (`pallas_moe_experts.sorted_serves`). A row
+    whose gate is not zero on an unmarked expert comes out without that
+    expert's part."""
+    from deeplearning4j_tpu.ops.pallas_moe_experts import (
+        moe_experts_or_none,
+        sorted_serves,
+    )
+
+    N, E = gates.shape
+    k = min(top_k, E)
+    out = sorted_expert_ffn_or_none(x, gates, Wg, Wu, Wd, k) \
+        if k and sorted_serves(N, E, k, act) else None
+    if out is None:
+        out = moe_experts_or_none(x, gates, Wg, Wu, Wd, hit, act)
     return grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act) \
         if out is None else out
 
@@ -400,10 +515,18 @@ def relu2_mlp(x, Wu, Wd):
                    preferred_element_type=jnp.float32).astype(x.dtype)
 
 
+class RouteCounts(NamedTuple):
+    """What one routed block counted over the rows under its
+    `count_mask`."""
+    experts: jnp.ndarray        # int32 (2, held): chosen, read
+    rows_local: jnp.ndarray     # int32 (): rows that chose a held expert
+    zero: Optional[jnp.ndarray]  # int32 (): choices on zero experts
+
+
 def dropless_moe(x, router, Wg, Wu, Wd, *, top_k: int, experts_held,
                  count_mask=None, act: str = GATED_SILU, router_bias=None,
                  routed_scale: float = 1.0, scoring: str = None,
-                 n_zero: int = 0):
+                 n_zero: int = 0, n_groups: int = 1, topk_groups: int = 1):
     """Top-k dropless routing over `router.shape[1]` experts, computed
     for the experts held. `x` (N, d). `act` and the matrices as
     `grouped_expert_ffn_xla`; `router_bias`, `routed_scale` and
@@ -424,7 +547,8 @@ def dropless_moe(x, router, Wg, Wu, Wd, *, top_k: int, experts_held,
     with jax.named_scope("moe.route"):
         logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
         all_gates = routed_gates(logits, top_k, bias=router_bias,
-                                 scale=routed_scale, scoring=scoring)
+                                 scale=routed_scale, scoring=scoring,
+                                 n_groups=n_groups, topk_groups=topk_groups)
         gates = all_gates[:, first:first + count]
         # every router's gates are >= 0: not zero is chosen
         chose = gates != 0
@@ -432,7 +556,7 @@ def dropless_moe(x, router, Wg, Wu, Wd, *, top_k: int, experts_held,
             chose &= count_mask[:, None]
         hit = jnp.any(chose, axis=0)
     with jax.named_scope("moe.experts"):
-        y = grouped_expert_ffn(x, gates, Wg, Wu, Wd, hit, act)
+        y = grouped_expert_ffn(x, gates, Wg, Wu, Wd, hit, act, top_k)
     if n_zero:
         with jax.named_scope("moe.zero"):
             zero_gates = all_gates[:, all_gates.shape[1] - n_zero:]
@@ -440,8 +564,8 @@ def dropless_moe(x, router, Wg, Wu, Wd, *, top_k: int, experts_held,
                      * x.astype(jnp.float32)).astype(y.dtype)
     if count_mask is None:
         return y, None
-    counts = jnp.stack([jnp.sum(chose, axis=0), hit]).astype(jnp.int32)
-    if not n_zero:
-        return y, counts
-    return y, (counts, jnp.sum((zero_gates != 0) & count_mask[:, None],
-                               dtype=jnp.int32))
+    return y, RouteCounts(
+        jnp.stack([jnp.sum(chose, axis=0), hit]).astype(jnp.int32),
+        jnp.sum(jnp.any(chose, axis=1), dtype=jnp.int32),
+        jnp.sum((zero_gates != 0) & count_mask[:, None], dtype=jnp.int32)
+        if n_zero else None)

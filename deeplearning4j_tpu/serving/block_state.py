@@ -123,15 +123,16 @@ def _finish_composed(layer, p, x, mixed, d):
 
 
 def _collect_counts(d, counts) -> None:
-    """A routed feed-forward's counts into the step's lists: per-expert
-    `(2, held)` into `d.counts`, and, where the router has zero experts
-    (then `counts` is the pair), their scalar into `d.zero_counts`."""
+    """A routed feed-forward's `RouteCounts` into the step's lists:
+    per-expert `(2, held)` into `d.counts`, the rows that chose a held
+    expert into `d.rows_local`, and, where the router has zero experts,
+    their scalar into `d.zero_counts`."""
     if counts is None:
         return
-    if isinstance(counts, tuple):
-        counts, zero = counts
-        d.zero_counts.append(zero)
-    d.counts.append(counts)
+    d.counts.append(counts.experts)
+    d.rows_local.append(counts.rows_local)
+    if counts.zero is not None:
+        d.zero_counts.append(counts.zero)
 
 
 def _write_pages(kp_, vp_, kcol, vrow, wpids, woff, page):
@@ -447,7 +448,7 @@ class LatentPages(_ByPhase):
     def mix_prefill(self, mp, u, cache, d):
         m = self.mixer
         q_n, q_r, latent = m.project(mp, u, jnp.arange(u.shape[1]))
-        mixed = m.attend_expanded(mp, q_n, q_r, latent)
+        mixed = m.attend_expanded(mp, q_n, q_r, latent, n_valid=d.t0)
         with jax.named_scope("mla.write"):
             pool = self._write_span(cache[0], latent, d.wpids,
                                     jnp.zeros((), jnp.int32))

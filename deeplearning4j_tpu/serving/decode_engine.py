@@ -647,6 +647,7 @@ class DecodeEngine:
         self.moe_experts_read = 0  # guarded by: _cond
         self.moe_steps = 0  # guarded by: _cond
         self.moe_zero_choices = 0  # guarded by: _cond
+        self.moe_rows_local = 0  # guarded by: _cond
         self.state_resets = 0  # guarded by: _cond
         self.spec_steps = 0  # guarded by: _cond
         self.spec_proposed = 0  # guarded by: _cond
@@ -1801,6 +1802,10 @@ class DecodeEngine:
                # of `moe_routed`, the choices that fell on zero-compute
                # experts (0: the routers score none)
                "moe_zero_choices": self.moe_zero_choices,
+               # of the live (slot, routed block) rows, `moe_routed` /
+               # top_k of them, those that chose at least one held
+               # expert: the rows an exchange would bring to this chip
+               "moe_rows_local": self.moe_rows_local,
                "moe_experts_held": self._n_held * self._moe_blocks,
                # tensor-parallel tier: degree 1 when off, so dashboards
                # can chart capacity without branching on key presence;
@@ -3155,15 +3160,14 @@ class DecodeEngine:
         """One dispatch's routing counts (`step_math`): (..., 3, held),
         choices that fell on each held expert, in how many blocks each
         was hit and in how many the grouped product was told to read
-        it, for one step or a chunk of them; where the routers score
-        zero-compute experts, the pair of that and the choices that
-        fell on those."""
-        zero = 0
-        if self._n_zero:
-            counts, zero = counts
+        it, for one step or a chunk of them; beside it the (slot,
+        block) rows that chose a held expert and, where the routers
+        score zero-compute experts, the choices that fell on those."""
+        counts, rows_local, *zero = counts
         counts = np.asarray(counts).reshape(-1, 3, self._n_held)
         with self._cond:
             self.moe_zero_choices += int(np.sum(zero))
+            self.moe_rows_local += int(np.sum(rows_local))
             self.moe_routed += counts.shape[0] * n_live \
                 * self._moe_top_k * self._moe_blocks
             self.moe_held_choices += int(counts[:, 0].sum())

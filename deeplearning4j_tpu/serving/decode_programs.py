@@ -130,7 +130,7 @@ def build_programs(plan, states, *, n_slots: int, page: int,
             # per-expert counts of the active slots' choices, where
             # the net routes
             count_mask=active if n_held else None, counts=[],
-            zero_counts=[])
+            rows_local=[], zero_counts=[])
         new_caches = []
         for bi, i in enumerate(block_is):
             x, cache = states[bi].decode(bp[i], x, caches[bi], d)
@@ -152,9 +152,11 @@ def build_programs(plan, states, *, n_slots: int, page: int,
             chosen, read = jnp.stack(d.counts, axis=1)
             counts = jnp.stack([chosen.sum(0), (chosen > 0).sum(0),
                                 read.sum(0)]).astype(jnp.int32)
-            # where the routers score zero-compute experts: the pair of
-            # that and how many choices fell on those, all blocks
-            out += ((counts, sum(d.zero_counts)) if n_zero else counts,)
+            # beside it, how many (active slot, block) rows chose a
+            # held expert at all and, where the routers score
+            # zero-compute experts, how many choices fell on those
+            out += ((counts, sum(d.rows_local))
+                    + ((sum(d.zero_counts),) if n_zero else ()),)
         return out
 
     # the chunk scans the step's body, not the jitted program the

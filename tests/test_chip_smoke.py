@@ -192,6 +192,53 @@ def test_latent_phase_toy():
     json.dumps(out)
 
 
+def test_latent_phase_toy_of_the_one_sub_layer_family():
+    """The same stage on the `deepseek_v2` family: one pool a layer,
+    counted from the net's mixers; no zero experts; one group held."""
+    import jax.numpy as jnp
+
+    lat = dict(chip_smoke.LATENT_H128, vocab_size=64, hidden_size=64,
+               num_hidden_layers=3, num_attention_heads=4,
+               num_key_value_heads=4, q_lora_rank=24, kv_lora_rank=16,
+               qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+               intermediate_size=48, moe_intermediate_size=24,
+               n_routed_experts=4, n_group=4, topk_group=2,
+               num_experts_per_tok=3,
+               rope_scaling=dict(chip_smoke.LATENT_H128["rope_scaling"],
+                                 factor=4,
+                                 original_max_position_embeddings=16,
+                                 beta_fast=4),
+               deployment=dict(n_routed_experts_published=16,
+                               experts_held_first=4))
+    out = chip_smoke.phase_latent(
+        lat, dict(n_slots=4, max_len=96, page_size=8, prefill_chunk=16,
+                  n_short=3, short_len=8, long_len=40, n_tokens=8),
+        kernels=False, dtype=jnp.float32, family="deepseek_v2")
+    assert out["requests"] == 4 and out["tokens"] == 4 * 8
+    assert out["prefill_chunks"] >= 3
+    assert max(out["reference_gaps"]) < 1e-4
+    assert out["agreement"]["common_prefix_tokens"] == [8] * 4
+    # three layers of one sub-layer: a float32 latent of 16 + 8 in each
+    assert out["latent_blocks"] == 3
+    assert out["latent_bytes_per_token"] == 3 * 24 * 4
+    assert out["zero_share_of_choices"] == 0
+    assert out["held_share_of_choices"] <= out["rows_local_share"] < 1.0
+    json.dumps(out)
+
+
+def test_latent_phase_publishes_deepseek_v2s_widths():
+    from perfbench.families import deepseek_v2 as fam
+
+    sz = fam.sizes(chip_smoke.LATENT_H128)
+    assert (sz["d"], sz["H"], sz["qr"], sz["kr"]) == (5120, 128, 1536, 512)
+    assert (sz["nope"], sz["rope"], sz["vd"]) == (128, 64, 128)
+    assert (sz["ffn"], sz["f"], sz["shared"]) == (12288, 1536, 3072)
+    assert (sz["L"], sz["L_dense"], sz["L_moe"]) == (2, 1, 1)
+    assert (sz["E"], sz["held"], sz["groups"], sz["topk_groups"],
+            sz["topk"], sz["route_scale"]) == (160, (0, 20), 8, 3, 6, 16.0)
+    assert sz["yarn"] == (40.0, 4096, 32.0, 1.0, 0.707, 0.707)
+
+
 def test_latent_phase_publishes_longcat_flashs_widths():
     from perfbench.families import longcat_flash as fam
 

@@ -212,8 +212,9 @@ def test_a_router_that_sends_every_token_to_one_expert_loses_none():
         .at[:, 0].set(1.0)
     y, counts = ffn.forward(p, x, jnp.ones((40,), bool))
     # how many tokens chose each held expert, and whether it was read
-    np.testing.assert_array_equal(counts, [[40, 0, 40, 0, 0, 40, 0, 0],
-                                           [1, 0, 1, 0, 0, 1, 0, 0]])
+    np.testing.assert_array_equal(
+        counts.experts, [[40, 0, 40, 0, 0, 40, 0, 0],
+                         [1, 0, 1, 0, 0, 1, 0, 0]])
     # every token got its three experts' gated outputs: rebuild them
     from deeplearning4j_tpu.parallel.experts import gated_mlp, topk_gates
 
@@ -387,8 +388,10 @@ def test_preemption_by_replay_gives_the_same_tokens(model):
     eng = DecodeEngine(model[3], **dict(ENGINE, n_slots=1, logprobs=0,
                                         qos={"preempt": True}))
     try:
-        want = eng.submit(p_batch, 24).result(timeout=120.0)
-        victim = eng.submit(p_batch, 24, tenant="bulk", priority="batch")
+        # 64 tokens: long enough that the victim is still decoding when
+        # the polling thread, starved on a loaded machine, submits `urgent`
+        want = eng.submit(p_batch, 64).result(timeout=120.0)
+        victim = eng.submit(p_batch, 64, tenant="bulk", priority="batch")
         deadline = time.monotonic() + 60.0
         while not victim.tokens and time.monotonic() < deadline:
             time.sleep(0.002)

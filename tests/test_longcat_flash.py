@@ -225,16 +225,18 @@ def test_a_chosen_zero_expert_returns_the_token_under_its_gate():
 
 def test_zero_choices_are_counted_over_the_rows_that_count():
     live = jnp.arange(24) < 10
-    x, (y, (counts, zero)) = _moe((0, 8), count_mask=live)
+    x, (y, (counts, rows_local, zero)) = _moe((0, 8), count_mask=live)
     _, router, bias, *_ = _moe_args()
     g = np.asarray(experts.routed_gates(x @ router, 3, bias=bias, scale=6.0,
                                         scoring="softmax_all")) != 0
     assert counts.shape == (2, 8)
     assert int(zero) == g[:10, 8:].sum() > 0
     assert int(counts[0].sum()) + int(zero) == 10 * 3
-    # without zero experts the counts are the array they always were
+    assert int(rows_local) == g[:10, :8].any(axis=1).sum()
+    # without zero experts the per-expert counts are the array they
+    # always were, and there is no count of zero choices
     _, (_, plain) = _moe((0, 8), count_mask=live, n_zero=0, n_real=12)
-    assert plain.shape == (2, 8)
+    assert plain.experts.shape == (2, 8) and plain.zero is None
 
 
 def test_the_shares_add_up_with_the_zero_part_counted_once():

@@ -113,7 +113,7 @@ def test_rows_nobody_reads_choose_nothing_for_the_kernel(act, monkeypatch):
                                      count_mask=live, **kw)
     every, none = experts.dropless_moe(x, router, Wg, Wu, Wd, **kw)
     assert none is None
-    chosen, read = np.asarray(counts)
+    chosen, read = np.asarray(counts.experts)
     gates = experts.held_gates(x @ router, 1, (0, n_experts))
     np.testing.assert_array_equal(
         chosen, np.sum(np.asarray(gates)[:live_rows] != 0, axis=0))
@@ -223,3 +223,100 @@ def test_one_tile_keeps_the_two_axis_grid(act):
     grid, in_specs, _ = pme._grid_specs(64, 256, 6, 128, 64, 32, act)
     assert grid == (1, 6, 4)
     assert in_specs[-1].block_shape == (1, 32, 256)
+
+
+# ------------------------------------------------- the sorted product
+def _sorted_case(n=700, n_experts=12, k=3, seed=5, held=(0, 12)):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(ks[0], (n, D))
+    router = jax.random.normal(ks[1], (D, n_experts))
+    Wg, Wu = (jax.random.normal(kk, (n_experts, D, F)) / 8 for kk in ks[2:4])
+    Wd = jax.random.normal(ks[4], (n_experts, F, D)) / 5
+    gates = experts.held_gates(x @ router, k, held)
+    lo, cnt = held
+    return x, gates, Wg[lo:lo + cnt], Wu[lo:lo + cnt], Wd[lo:lo + cnt]
+
+
+def _sorted_interpreted(monkeypatch):
+    from deeplearning4j_tpu.ops import pallas_moe_experts as pme
+
+    calls = []
+
+    def served(xs, gs, tile_expert, n_used, Wg, Wu, Wd):
+        calls.append((xs.shape, int(n_used[0])))
+        return pme.moe_experts_sorted(xs, gs, tile_expert, n_used, Wg, Wu,
+                                      Wd, interpret=True)
+
+    monkeypatch.setattr(pme, "moe_experts_sorted_or_none", served)
+    return calls
+
+
+@pytest.mark.parametrize("held", [(0, 12), (4, 6)], ids=["all", "a-share"])
+def test_the_sorted_product_equals_the_batched_products(held, monkeypatch):
+    """A prefill's rows sorted by expert, each expert over its own rows
+    only: the sum the batched products give, whether every expert is held
+    or a share (rows that chose no held expert come out zeros)."""
+    calls = _sorted_interpreted(monkeypatch)
+    x, gates, Wg, Wu, Wd = _sorted_case(held=held)
+    want = experts.grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd)
+    got = experts.sorted_expert_ffn_or_none(x, gates, Wg, Wu, Wd, 3)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    (shape, n_used), = calls
+    E = held[1]
+    assert shape == ((-(-700 * 3 // 128) + E) * 128, D)
+    # the tiles that hold rows: every expert's choices in whole tiles
+    counts = np.sum(np.asarray(gates) != 0, axis=0)
+    assert n_used == int(np.sum(-(-counts // 128)))
+    none = np.all(np.asarray(gates) == 0, axis=1)
+    assert none.any() == (held != (0, 12))
+    assert not np.any(np.asarray(got)[none])
+
+
+def test_the_sort_lays_every_choice_in_its_experts_tiles():
+    x, gates, *_ = _sorted_case(n=300, n_experts=5, k=2, held=(0, 5))
+    rows, gs, tile_expert, n_used, back = experts.sort_by_expert(gates, 2, 16)
+    rows, gs, tile_expert, back = map(np.asarray,
+                                      (rows, gs[:, 0], tile_expert, back))
+    g = np.asarray(gates)
+    assert rows.shape == ((-(-300 * 2 // 16) + 5) * 16,)
+    assert back.shape == (300, 2)
+    used = int(n_used[0]) * 16
+    assert not gs[used:].any()
+    for at in np.flatnonzero(gs):
+        assert gs[at] == g[rows[at], tile_expert[at // 16]]
+    # every choice made is somewhere, once
+    assert np.count_nonzero(gs) == np.count_nonzero(g)
+    np.testing.assert_allclose(gs[back].sum(1), g.sum(1), rtol=1e-6)
+    assert np.all(np.diff(tile_expert[:used // 16]) >= 0)
+    assert np.all(tile_expert[used // 16:] == tile_expert[used // 16 - 1])
+
+
+def test_the_sorted_product_serves_prefills_of_sparse_choices_only():
+    from deeplearning4j_tpu.ops.pallas_moe_experts import sorted_serves
+
+    # DeepSeek-V2's share: 20 held, top-6; a prefill, not a decode step
+    assert sorted_serves(4096, 20, 6, "gated_silu")
+    assert sorted_serves(1024, 20, 6, "gated_silu")
+    assert not sorted_serves(128, 20, 6, "gated_silu")
+    # granite's, LongCat's and nemotron's prefills keep the walk
+    assert not sorted_serves(512, 36, 10, "gated_silu")
+    assert not sorted_serves(1024, 16, 12, "gated_silu")
+    assert not sorted_serves(512, 64, 6, "relu2")
+
+
+def test_a_prefill_goes_sorted_and_a_decode_step_walks(monkeypatch):
+    from deeplearning4j_tpu.ops import pallas_moe_experts as pme
+
+    calls = _sorted_interpreted(monkeypatch)
+    walked = []
+    monkeypatch.setattr(
+        pme, "moe_experts_or_none",
+        lambda x, *a, **k: walked.append(x.shape) or None)
+    x, gates, Wg, Wu, Wd = _sorted_case()
+    router = jax.random.normal(jax.random.PRNGKey(1), (D, 12))
+    kw = dict(top_k=3, experts_held=(0, 12))
+    want, _ = experts.dropless_moe(x[:64], router, Wg, Wu, Wd, **kw)
+    assert walked == [(64, D)] and not calls
+    got, _ = experts.dropless_moe(x, router, Wg, Wu, Wd, **kw)
+    assert len(calls) == 1 and len(walked) == 1
+    np.testing.assert_allclose(got[:64], want, atol=2e-5)

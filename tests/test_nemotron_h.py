@@ -362,7 +362,7 @@ def test_the_layer_equals_its_experts_one_by_one():
     y, counts = ffn.forward(p, x, jnp.ones((40,), bool))
     gates = _hand_router(x @ p["router"], np.asarray(p["router_b"]), 3, 2.5)
     chosen = (gates > 0).sum(0)
-    np.testing.assert_array_equal(counts, [chosen, chosen > 0])
+    np.testing.assert_array_equal(counts.experts, [chosen, chosen > 0])
     want = experts.relu2_mlp(x, p["sWu"], p["sWd"])
     for e in range(8):
         want = want + gates[:, e:e + 1] * experts.relu2_mlp(

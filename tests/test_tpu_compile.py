@@ -427,3 +427,118 @@ def test_shortcut_layer_decode_step_compiles_at_the_published_widths(
     for name in ("mla_attend", "latent_write", "moe_experts"):
         assert name in text
     assert chip_smoke.pool_layout_copies(text, {"bf16[2561,576,128]"}) == 0
+
+
+def test_latent_kernels_compile_at_128_heads(one_chip, monkeypatch):
+    """DeepSeek-V2's latent attention as one chip serves it: 128 slots,
+    128 heads' absorbed queries under the YaRN temperature over a pool
+    of 8448 pages of 128 positions x (512 + 64) through a table 96 pages
+    wide (`max_len` 12,288), and its routed experts (20 of 5120 x 1536:
+    47.2 MB each): whole at a decode step's 128 rows, in two tiles of
+    their width at a prefill's 512-row tiles."""
+    from deeplearning4j_tpu.ops import pallas_mla_attend as mla
+    from deeplearning4j_tpu.ops import pallas_moe_experts as pme
+
+    monkeypatch.setattr(mla, "_vmem_limit", lambda: 112 << 20)
+    monkeypatch.setattr(pme, "_vmem_limit", lambda: 112 << 20)
+    S = _shapes(one_chip)
+    i32 = jnp.int32
+    with jax.enable_x64(False):
+        attend = mla.mla_attend.lower(
+            S((128, 128, 576)), S((8449, 576, 128)), S((128, 96), i32),
+            S((128,), i32), S((128,), jnp.bool_), kv_rank=512,
+            sm_scale=0.114722).compile()
+        experts = [pme.moe_experts.lower(
+            S((rows, 5120)), S((rows, 20), jnp.float32), S((20, 5120, 1536)),
+            S((20, 5120, 1536)), S((20, 1536, 5120)),
+            S((20,), jnp.bool_)).compile() for rows in (128, 4096)]
+        # a 4,096-token prefill's choices sorted by expert: 6 a token in
+        # whole tiles of 128 rows and a tile an expert to spare
+        M = (4096 * 6 // pme.SORTED_ROWS + 20) * pme.SORTED_ROWS
+        experts.append(pme.moe_experts_sorted.lower(
+            S((M, 5120)), S((M, 1), jnp.float32),
+            S((M // pme.SORTED_ROWS,), i32), S((1,), i32),
+            S((20, 5120, 1536)), S((20, 5120, 1536)),
+            S((20, 1536, 5120))).compile())
+        # a 4,096-token prompt's own attention, heads first
+        experts.append(mla.mla_prefill.lower(
+            S((128, 4096, 128)), S((128, 4096, 64)), S((128, 4096, 128)),
+            S((4096, 64)), S((128, 4096, 128)), S((1,), i32),
+            sm_scale=0.114722).compile())
+    for compiled in (attend, *experts):
+        assert "tpu_custom_call" in compiled.as_text()
+    assert mla.attend_key(jnp.bfloat16, 128, 576, 512, 128) \
+        == ("bfloat16", 128, 576, 512, 128, "block8")
+    # the pool (1.25 GB) stays where it is; what is made beside it is the
+    # queries' and the outputs' size
+    assert attend.memory_analysis().temp_size_in_bytes < 32 << 20
+    assert pme.vmem_bytes_estimate(128, 5120, 1536, jnp.bfloat16) \
+        < 112 << 20
+    assert pme.f_tile(128, 5120, 1536, jnp.bfloat16) == 1536
+    assert pme.f_tile(512, 5120, 1536, jnp.bfloat16) == 768
+
+
+def test_one_sub_layer_latent_net_compiles_at_the_published_widths(
+        one_chip, monkeypatch):
+    """DeepSeek-V2's leading dense layer and one routed layer at their
+    published widths (128 slots, 8448 pages of 128, rows of 96 pages)
+    through `build_programs`, the kernel families steered on as they are
+    on the chip: a decode step of two latent writes, two paged latent
+    attentions and the grouped experts with no pool-shaped copy, and a
+    4,096-token prefill whose attention goes through the `mla_prefill`
+    kernel and whose experts take their rows sorted: no
+    (128, 4096, 4096) array, under 1.5 GB of temporaries."""
+    from types import SimpleNamespace
+
+    import chip_smoke
+    from deeplearning4j_tpu.models.transformer import GPTPlan
+    from deeplearning4j_tpu.ops import pallas_mla_attend, pallas_moe_experts
+    from deeplearning4j_tpu.serving import block_state, decode_programs
+    from perfbench.families import deepseek_v2 as fam
+
+    for mod in (pallas_mla_attend, pallas_moe_experts):
+        monkeypatch.setattr(mod, "_platform_supported", lambda: True)
+        monkeypatch.setattr(mod, "_probe_verdict", lambda *a, **k: True)
+        monkeypatch.setattr(mod, "_vmem_limit", lambda: 112 << 20)
+    sz = fam.sizes(chip_smoke.LATENT_H128)
+    S = _shapes(one_chip)
+    shapes = fam._leaf_shapes(sz)
+    tree = {n: S(shapes[n]) for n in fam.TOP_LEAVES}
+    tree["layers"] = [{n: S(shapes[n]) for n in names}
+                      for names in (fam.DENSE_LEAVES, fam.MOE_LEAVES)]
+    net = fam.build_net(sz, training=False)
+    net._params = [{k: S(v.shape, v.dtype) for k, v in p.items()}
+                   for p in fam.to_program(tree)]
+    plan = GPTPlan(net)
+    assert plan.state_kinds() == ["latent", "latent"]
+    assert plan.latent_geometry() == [(512, 64)] * 2
+    n_slots, page, max_len = 128, 128, 12288
+    states = block_state.block_states(plan, SimpleNamespace(
+        n_slots=n_slots, page=page, pool_pages=8448, cdt=plan.cdt,
+        kv_quant=None, tp_shard=None, tp_axis=None))
+    i32, f32 = jnp.int32, jnp.float32
+    slot_args = (S((n_slots,), i32), S((n_slots,), i32),
+                 S((n_slots, 2), jnp.uint32), S((n_slots,), f32))
+    with jax.enable_x64(False):
+        programs = decode_programs.build_programs(
+            plan, states, n_slots=n_slots, page=page, L_logical=max_len,
+            decode_chunk=4, top_k=0, logprobs=0, tp=None, donate=True)
+        caches = [jax.tree.map(lambda a: S(a.shape, a.dtype),
+                               jax.eval_shape(st.alloc)) for st in states]
+        step = programs.decode_step.lower(
+            net._params, caches, S((n_slots, max_len // page), i32),
+            *slot_args, S((n_slots,), jnp.bool_)).compile().as_text()
+        prefill = programs.prefill.lower(
+            net._params, caches, S((1, 4096), i32), S((), i32), S((), i32),
+            S((4096 // page,), i32), *slot_args, S((2,), jnp.uint32),
+            S((2,), jnp.uint32), S((), f32)).compile()
+    assert step.count("tpu_custom_call") == 5
+    for name in ("mla_attend", "latent_write", "moe_experts"):
+        assert name in step
+    assert chip_smoke.pool_layout_copies(step, {"bf16[8449,576,128]"}) == 0
+    text = prefill.as_text()
+    assert "[128,4096,4096]" not in text
+    for name in ("mla_prefill", "moe_experts_sorted"):
+        assert name in text
+    assert "moe_experts_sorted" not in step and "mla_prefill" not in step
+    assert prefill.memory_analysis().temp_size_in_bytes < 1.5e9
