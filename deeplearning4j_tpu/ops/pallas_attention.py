@@ -7,18 +7,17 @@ in `kernel_dispatch.kernel_verdicts()`) otherwise
 (`ConvolutionLayer.initializeHelper`, `ConvolutionLayer.java:69-79`). Here
 the built-in paths are `ops/attention.py` full/blockwise attention (XLA);
 this module is the Mosaic/Pallas fast path for the no-mask case — and since
-it carries a custom VJP (two backward kernels, the standard dQ / dKV
-split), it serves TRAINING too, the analogue of the cuDNN backward helpers
-gradient-checked in `CuDNNGradientChecks.java`. Measured on a v5e before
-this round's records began (not in `PERF_LEDGER.jsonl`; `PERF.md` holds
-what the chip reads now): 2.6-3.0x the XLA blockwise path for causal
-fwd+bwd at T=4096, block 1024 (block-512 tiles measured 1.9x). Block
+it carries a custom VJP (a Pallas backward), it serves TRAINING too, the
+analogue of the cuDNN backward helpers gradient-checked in
+`CuDNNGradientChecks.java`. What the chip reads for it is in `PERF.md`
+(§5's train cell, `flash_attention_roofline`; §6, PR 43) and comes from
+`tools/flash_attention_bench.py`, which times each kernel alone. Block
 sizes beyond 1024 are exhausted as a lever: with the scoped-VMEM ceiling
 raised to admit them, (bq, bk) in {2048x1024, 1024x2048, 2048x2048,
-4096x2048} all time within 0.3% of 1024x1024 at the gpt_long shape
-(B=8, H=8, T=4096, D=128) — the kernel is HBM/matmul-bound there, so
-the ladder keeps 1024 as its top candidate and the raised limit exists
-to stop spurious probe declines at wider head dims, not for speed.
+4096x2048} all timed within 0.3% of 1024x1024 at B=8, H=8, T=4096, D=128
+(before this round's records began), so the ladder keeps 1024 as its top
+candidate and the raised limit exists to stop spurious probe declines at
+wider head dims, not for speed.
 
 Kernel shape (fwd): grid (B·H, Tq/block_q, Tk/block_k), innermost KV
 dimension sequential so the online-softmax accumulator lives in VMEM
@@ -31,7 +30,16 @@ Backward recomputes P = exp(S - L) tile by tile (no O(T²) residual):
   D  = rowsum(dO ∘ O)
   dV = Pᵀ dO          dP = dO Vᵀ       dS = P ∘ (dP - D)
   dQ = dS K · scale   dK = dSᵀ Q · scale
-dQ runs on the fwd grid (KV inner); dK/dV run with the Q dimension inner.
+in ONE kernel (`_flash_bwd_dkv_kernel` with dQ): S, P, dP and dS of a tile
+pair are computed once, five products, one `exp`, one mask. The grid is
+(B·H, Tk/block_k, Tq/block_q) with the query blocks innermost, so dK and dV
+of a key block accumulate in (block_k, D) scratch; dQ's rows are revisited
+once a key block, so it accumulates in float32 for the whole sequence of
+the (batch, head), (Tq, D) of VMEM, and is written out once. A sequence too
+long for that (`_backward_form`, read off the shape) takes the standard
+split: a dQ kernel on the forward's grid and a dK/dV kernel, each computing
+S and dP for itself, seven products a pair. `kernel_verdicts()` names the
+form a process engaged (the key's last entry).
 
 Dtype policy: bf16 inputs feed the MXU natively; f32 multiplies at HIGHEST
 precision (measured ~100x more accurate gradients than the XLA
@@ -69,8 +77,8 @@ FAMILY = "flash_attention"  # this module's row in kernel_verdicts()
 def _masked_scores(q_ref, k_ref, qi, ki, *, sm_scale, causal, block_q,
                    block_k):
     """One (block_q, block_k) tile of scaled scores with the causal mask
-    applied — the SINGLE implementation shared by the forward and both
-    backward kernels, so mask/scale semantics cannot drift between them."""
+    applied — the SINGLE implementation shared by the forward and every
+    backward kernel, so mask/scale semantics cannot drift between them."""
     dt = _mxu_dtype(q_ref.dtype)
     q = q_ref[0].astype(dt)
     k = k_ref[0].astype(dt)
@@ -183,19 +191,36 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
-                          dk_ref, dv_ref, dk_scr, dv_scr, *,
-                          sm_scale: float, causal: bool, block_q: int,
-                          block_k: int):
+                          *rest, sm_scale: float, causal: bool, block_q: int,
+                          block_k: int, with_dq: bool):
+    """dK and dV of a key block, the query blocks innermost (`dk_scr` /
+    `dv_scr` accumulate over them). `with_dq`: the WHOLE backward of a
+    (batch, head), dQ from the same S, P, dP and dS. Its rows come round
+    again once a key block, so it accumulates in `dq_acc`, float32 for the
+    whole sequence, and the `(1, Tq, D)` output block, whose index is
+    constant over both inner axes, is written once, at the (batch, head)'s
+    last step."""
     from jax.experimental import pallas as pl
+
+    if with_dq:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_scr, dv_scr = rest
+    else:
+        dk_ref, dv_ref, dk_scr, dv_scr = rest
 
     kj = pl.program_id(1)
     qi = pl.program_id(2)
+    nk = pl.num_programs(1)
     nq = pl.num_programs(2)
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    if with_dq:
+        @pl.when((kj == 0) & (qi == 0))
+        def _init_dq():
+            dq_acc[:] = jnp.zeros_like(dq_acc)
 
     @pl.when(_causal_needed_kv(qi, kj, block_q, block_k, causal))
     def _step():
@@ -209,11 +234,20 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
         ds = (p * (dp - dsum_ref[0][:, :1])).astype(dt)
         dk_scr[:] += _dot(ds, q_ref[0].astype(dt),
                           ((0,), (0,)), dt) * sm_scale          # (bk, D)
+        if with_dq:
+            rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+            dq_acc[rows, :] += _dot(ds, k_ref[0].astype(dt),
+                                    ((1,), (0,)), dt) * sm_scale  # (bq, D)
 
     @pl.when(qi == nq - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    if with_dq:
+        @pl.when((kj == nk - 1) & (qi == nq - 1))
+        def _finalize_dq():
+            dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _to_slabs(x):
@@ -281,8 +315,28 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     return _from_slabs(res, B, H), None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_mha(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+# The fused backward keeps dQ of one (batch, head)'s WHOLE sequence in VMEM:
+# the float32 accumulator and the two buffers of its output block. It serves
+# while those are within this share of the ceiling (a half: bf16 sequences
+# up to 57,344 at head size 128, float32 ones to 38k; the tiles and their
+# score slabs need the rest), and the split serves what lies beyond.
+_DQ_RESIDENT_SHARE = 2
+
+
+def _backward_form(Tq: int, D: int, dtype) -> str:
+    """`"fused"` (one kernel) or `"split"` (a dQ and a dK/dV kernel), read
+    off the shape alone: whether what the fused form holds for the whole
+    sequence fits its share of `vmem_limit_bytes()`."""
+    per_element = (jnp.dtype(_stat_dtype(dtype)).itemsize
+                   + 2 * jnp.dtype(dtype).itemsize)
+    resident = Tq * D * per_element
+    return ("fused" if resident * _DQ_RESIDENT_SHARE <= _vmem_limit()
+            else "split")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_mha(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+               bwd_form):
     # inference primal: no lse output (skips an f32 HBM write larger than
     # the attention output itself)
     out, _ = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
@@ -290,33 +344,47 @@ def _flash_mha(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     return out
 
 
-def _flash_mha_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_mha_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                   bwd_form):
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
                               interpret, with_lse=True)
     return out, (q, k, v, out, lse)
 
 
-def _flash_mha_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
+def _kv_major_in_specs(block_q, block_k, D):
+    """q, k, v, dO, lse, dsum on a (batch·head, key block, query block)
+    grid: the dK/dV kernel's and the fused kernel's."""
+    from jax.experimental import pallas as pl
+
+    return [
+        pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
+        pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
+        pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
+        pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
+        pl.BlockSpec((1, block_q, 128), lambda b, j, i: (b, i, 0)),
+        pl.BlockSpec((1, block_q, 128), lambda b, j, i: (b, i, 0)),
+    ]
+
+
+def _backward_split(qf, kf, vf, dof, lse, dsum, *, causal, sm_scale,
+                    block_q, block_k, interpret):
+    """dQ on the forward's grid (key blocks innermost), then dK and dV with
+    the query blocks innermost: each kernel computes S and dP for itself.
+    For a sequence whose dQ the fused kernel cannot hold."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    q, k, v, out, lse = res
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    sdt = _stat_dtype(q.dtype)
-    # D_i = rowsum(dO ∘ O), broadcast along the 128-lane stat axis like lse
-    dsum = jnp.sum(do.astype(sdt) * out.astype(sdt), axis=-1)  # (B, Tq, H)
-    dsum = dsum.transpose(0, 2, 1).reshape(B * H, Tq, 1)
-    dsum = jnp.broadcast_to(dsum, (B * H, Tq, 128))
-    qf, kf, vf = _to_slabs(q), _to_slabs(k), _to_slabs(v)
-    dof = _to_slabs(do)
-
-    dq_kernel = functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
-                                  causal=causal, block_q=block_q,
-                                  block_k=block_k)
+    BH, Tq, D = qf.shape
+    Tk = kf.shape[1]
+    sdt = _stat_dtype(qf.dtype)
+    tiles = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                 block_k=block_k)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_vmem_limit())
     dqf = pl.pallas_call(
-        dq_kernel,
-        grid=(B * H, Tq // block_q, Tk // block_k),
+        functools.partial(_flash_bwd_dq_kernel, **tiles),
+        grid=(BH, Tq // block_q, Tk // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
@@ -326,48 +394,90 @@ def _flash_mha_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
             pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((BH, Tq, D), qf.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), sdt)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit()),
+        compiler_params=params,
         interpret=interpret,
     )(qf, kf, vf, dof, lse, dsum)
-
-    dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
-                                   causal=causal, block_q=block_q,
-                                   block_k=block_k)
     dkf, dvf = pl.pallas_call(
-        dkv_kernel,
-        grid=(B * H, Tk // block_k, Tq // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, j, i: (b, i, 0)),
-        ],
+        functools.partial(_flash_bwd_dkv_kernel, with_dq=False, **tiles),
+        grid=(BH, Tk // block_k, Tq // block_q),
+        in_specs=_kv_major_in_specs(block_q, block_k, D),
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype),
+            jax.ShapeDtypeStruct((BH, Tk, D), kf.dtype),
+            jax.ShapeDtypeStruct((BH, Tk, D), vf.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), sdt),
             pltpu.VMEM((block_k, D), sdt),
         ],
+        compiler_params=params,
+        interpret=interpret,
+    )(qf, kf, vf, dof, lse, dsum)
+    return dqf, dkf, dvf
+
+
+def _backward_fused(qf, kf, vf, dof, lse, dsum, *, causal, sm_scale,
+                    block_q, block_k, interpret):
+    """dQ, dK and dV from ONE kernel (`_flash_bwd_dkv_kernel` with dQ)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    BH, Tq, D = qf.shape
+    Tk = kf.shape[1]
+    sdt = _stat_dtype(qf.dtype)
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
+                          causal=causal, block_q=block_q, block_k=block_k,
+                          with_dq=True),
+        grid=(BH, Tk // block_k, Tq // block_q),
+        in_specs=_kv_major_in_specs(block_q, block_k, D),
+        out_specs=[
+            # dQ's block is the (batch, head)'s whole row: a (block_q, D)
+            # block indexed by the query block would be written back each
+            # time the index moved, once a key block, with a part sum
+            pl.BlockSpec((1, Tq, D), lambda b, j, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, Tq, D), qf.dtype),
+            jax.ShapeDtypeStruct((BH, Tk, D), kf.dtype),
+            jax.ShapeDtypeStruct((BH, Tk, D), vf.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((Tq, D), sdt),
+            pltpu.VMEM((block_k, D), sdt),
+            pltpu.VMEM((block_k, D), sdt),
+        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
     )(qf, kf, vf, dof, lse, dsum)
 
-    return (_from_slabs(dqf, B, H), _from_slabs(dkf, B, H),
-            _from_slabs(dvf, B, H))
+
+_BACKWARD = {"fused": _backward_fused, "split": _backward_split}
+
+
+def _flash_mha_bwd(causal, sm_scale, block_q, block_k, interpret, bwd_form,
+                   res, do):
+    q, k, v, out, lse = res
+    B, Tq, H, D = q.shape
+    sdt = _stat_dtype(q.dtype)
+    # D_i = rowsum(dO ∘ O), broadcast along the 128-lane stat axis like lse
+    dsum = jnp.sum(do.astype(sdt) * out.astype(sdt), axis=-1)  # (B, Tq, H)
+    dsum = dsum.transpose(0, 2, 1).reshape(B * H, Tq, 1)
+    dsum = jnp.broadcast_to(dsum, (B * H, Tq, 128))
+    grads = _BACKWARD[bwd_form](
+        _to_slabs(q), _to_slabs(k), _to_slabs(v), _to_slabs(do), lse, dsum,
+        causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+        interpret=interpret)
+    return tuple(_from_slabs(g, B, H) for g in grads)
 
 
 _flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
@@ -378,8 +488,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False) -> jnp.ndarray:
     """Exact attention, (B, T, H, D) layout, no key mask; differentiable
-    (custom VJP with Pallas backward kernels). Requires Tq/Tk divisible by
-    the block sizes (callers pad or fall back)."""
+    (custom VJP: one Pallas backward kernel, or the dQ / dKV pair where the
+    sequence is too long for it, `_backward_form`). Requires Tq/Tk
+    divisible by the block sizes (callers pad or fall back)."""
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     if Tq % block_q or Tk % block_k:
@@ -388,7 +499,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if causal and Tq != Tk:
         raise ValueError("causal flash path requires Tq == Tk")
     scale = sm_scale if sm_scale is not None else 1.0 / (D ** 0.5)
-    return _flash_mha(q, k, v, causal, scale, block_q, block_k, interpret)
+    return _flash_mha(q, k, v, causal, scale, block_q, block_k, interpret,
+                      _backward_form(Tq, D, q.dtype))
 
 
 def _platform_supported() -> bool:
@@ -396,21 +508,23 @@ def _platform_supported() -> bool:
     return _kernels_dispatch("DL4J_TPU_NO_PALLAS_ATTENTION")
 
 
-def _eager_probe(dtype, block: int, head_dim: int) -> bool:
+def _eager_probe(dtype, block: int, head_dim: int, bwd_form: str) -> bool:
     """Compile + run the forward AND backward kernels once on tiny
     concrete inputs, OUTSIDE any trace. The dispatch itself usually runs
     inside a jit trace, where a Mosaic compile failure would surface at
     the OUTER jit's compile — far from any try/except here. Probing
     eagerly up front turns a platform that can't compile the kernels into
     a recorded XLA fallback instead of a training crash. Probed per
-    (dtype, block) at T=block so the exact tile configuration that will
-    run is the one proven to compile."""
+    (dtype, block, backward form) at T=block so the exact tile
+    configuration and kernels that will run are the ones proven to
+    compile."""
     B, T, H = 1, block, 1
     x = jnp.zeros((B, T, H, head_dim), dtype)
 
     def l(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True, block_q=block,
-                                       block_k=block).astype(jnp.float32))
+        out = _flash_mha(q, k, v, True, head_dim ** -0.5, block, block,
+                         False, bwd_form)
+        return jnp.sum(out.astype(jnp.float32))
 
     g = jax.grad(l, argnums=(0, 1, 2))(x, x, x)
     return bool(jnp.all(jnp.isfinite(g[0].astype(jnp.float32))))
@@ -423,7 +537,13 @@ def _eager_probe(dtype, block: int, head_dim: int) -> bool:
 _BLOCK_CANDIDATES = (1024, 512, 256, 128)
 
 
-def _probed_block(dtype, Tq: int, Tk: int, D: int) -> Optional[int]:
+def _verdict_key(dtype, block: int, D: int, bwd_form: str) -> tuple:
+    # the backward's form last: `kernel_verdicts()` says which one engaged
+    return (jnp.dtype(dtype).name, block, D, bwd_form)
+
+
+def _probed_block(dtype, Tq: int, Tk: int, D: int,
+                  bwd_form: str) -> Optional[int]:
     """Largest candidate tile that divides the sequence AND passes the
     fwd+bwd compile probe. A block whose probe fails (e.g. VMEM overflow
     at a bigger head dim) falls through to the next smaller candidate
@@ -431,8 +551,8 @@ def _probed_block(dtype, Tq: int, Tk: int, D: int) -> Optional[int]:
     for block in _BLOCK_CANDIDATES:
         if Tq % block or Tk % block:
             continue
-        key = (jnp.dtype(dtype).name, block, D)
-        if _probe_verdict(FAMILY, key, _eager_probe, (dtype, block, D)):
+        if _probe_verdict(FAMILY, _verdict_key(dtype, block, D, bwd_form),
+                          _eager_probe, (dtype, block, D, bwd_form)):
             return block
     return None
 
@@ -468,18 +588,18 @@ def flash_attention_or_none(q, k, v, *,
     """Dispatch probe (the reflective cuDNN-helper load): returns None when
     the kernel can't serve this call — wrong platform, non-divisible shapes,
     tiny sequences — or when every candidate tile failed its fwd+bwd
-    compile probe. Biggest tile first: fwd+bwd at T=4096/D=128 measured
-    31.5 ms (b1024) vs 37.5 (b512) vs 54.6 (b256) vs XLA blockwise 70.6 —
-    larger tiles amortise the per-grid-step overhead that dominates on
-    v5e."""
+    compile probe. Biggest tile first: larger tiles amortise the
+    per-grid-step overhead that dominates on v5e."""
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     if (not _platform_supported() or (causal and Tq != Tk)
             or D % 128 or q.dtype not in (jnp.float32, jnp.bfloat16)):
         return None
-    block = _probed_block(q.dtype, Tq, Tk, D)
+    bwd_form = _backward_form(Tq, D, q.dtype)
+    block = _probed_block(q.dtype, Tq, Tk, D, bwd_form)
     if block is None:
         return None
+    key = _verdict_key(q.dtype, block, D, bwd_form)
     try:
         scope = _traced_mesh()
         if scope is None:
@@ -489,11 +609,11 @@ def flash_attention_or_none(q, k, v, *,
                                         block=block)
         if out is None:
             _record_decline(
-                FAMILY, (jnp.dtype(q.dtype).name, block, D, "mesh"),
+                FAMILY, key + ("mesh",),
                 f"batch {B} does not divide mesh axis {scope[1]!r} of "
                 f"{dict(scope[0].shape)}")
         return out
     except Exception as e:  # per-shape staging failure: fall back
-        _record_decline(FAMILY, (jnp.dtype(q.dtype).name, block, D),
+        _record_decline(FAMILY, key,
                         f"staging at {q.shape}: {type(e).__name__}: {e}")
         return None

@@ -1,4 +1,4 @@
-"""Kernels of the serving path compiled at real widths for a TPU v5e that
+"""Kernels of the serving and training paths compiled at real widths for a TPU v5e that
 is described, not attached (libtpu's compiler runs in the sandbox):
 Mosaic refuses here what it would refuse on the chip (tiling, VMEM), at
 no chip time. Nothing runs, so nothing here says anything about results
@@ -90,6 +90,31 @@ def test_gated_delta_step_kernel_compiles_at_the_published_widths(one_chip):
     # the kernel keeps nothing of its size beside it
     assert stats.alias_size_in_bytes == 64 * 96 * 5760 * 4
     assert stats.temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("B,H,T,kernels", [
+    (4, 12, 2048, 2), (1, 1, 57344, 2), (1, 1, 58368, 3)],
+    ids=("cgpt590m-t2048", "longest-fused", "first-split"))
+def test_flash_attention_backward_compiles_as_one_kernel(one_chip, B, H, T,
+                                                         kernels):
+    """The train cell's attention layer, forward and backward, in blocks
+    of 1,024 at head size 128, bfloat16: the forward and ONE backward
+    kernel, dQ of a (batch, head) whole in VMEM; so at the longest
+    sequence whose dQ the fused form may hold, and the two-kernel split
+    one block past it."""
+    from deeplearning4j_tpu.ops.pallas_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, block_q=1024,
+                                       block_k=1024).astype(jnp.float32))
+
+    x = jax.ShapeDtypeStruct((B, T, H, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    with jax.enable_x64(False):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == kernels
 
 
 @pytest.mark.parametrize("slots,H,Hkv,pool", [
