@@ -127,6 +127,9 @@ LINEAR = dict(vocab_size=256, hidden_size=3840, intermediate_size=11008,
               rms_norm_eps=1e-6, tie_word_embeddings=False,
               attention_bias=False)
 LINEAR_SERVE = HYBRID_SERVE
+# the routed nets' short prompts fill a bucket of 512: the row count from
+# which a prefill's experts go sorted (`pallas_moe_experts.sorted_serves`)
+ROUTED_SERVE = dict(HYBRID_SERVE, short_len=512, n_short=3)
 # NVIDIA-Nemotron-3-Nano-30B-A3B's published widths under its config's
 # own keys (`perfbench/families/nemotron_h.py` reads them), one layer of
 # each kind, half of the 128 routed experts held as in the benchmark cell
@@ -145,7 +148,7 @@ SUBLAYER = dict(vocab_size=256, hidden_size=2688, num_hidden_layers=3,
                 mamba_proj_bias=False, use_conv_bias=True,
                 deployment=dict(n_routed_experts_published=128,
                                 experts_held_first=0))
-SUBLAYER_SERVE = HYBRID_SERVE
+SUBLAYER_SERVE = ROUTED_SERVE
 # LongCat-Flash-Chat's published widths under its config's own keys
 # (`perfbench/families/longcat_flash.py` reads them), one layer, 16 of
 # the 512 real experts held as in the benchmark cell
@@ -160,7 +163,7 @@ LATENT = dict(vocab_size=256, hidden_size=6144, num_layers=1,
               attention_bias=False, attention_method="MLA",
               deployment=dict(n_routed_experts_published=512,
                               experts_held_first=0))
-LATENT_SERVE = HYBRID_SERVE
+LATENT_SERVE = ROUTED_SERVE
 # DeepSeek-V2's published widths under its config's own keys
 # (`perfbench/families/deepseek_v2.py` reads them): the leading dense
 # layer and one routed layer, group 0 of the 8 groups of 20 experts held
@@ -715,6 +718,33 @@ def _experts_read(stats: dict, phase: str) -> dict:
     return {"moe_experts_hit": hit, "moe_experts_read": read}
 
 
+def _sorted_prefills(net, shape: dict, stats: dict) -> dict:
+    """Where the rule over shapes (`pallas_moe_experts.sorted_serves`)
+    sends the bucket's rows to the sorted expert product, its kernel's
+    verdict at the net's shape class is a pass and the engine counted
+    every bucketed prefill as sorted; where it does not, none."""
+    from deeplearning4j_tpu.models.transformer import GPTPlan
+    from deeplearning4j_tpu.ops import pallas_moe_experts as pme
+    from deeplearning4j_tpu.serving.block_state import routed_ffns
+
+    ffn = routed_ffns(GPTPlan(net))[0]
+    bucket, d = shape["short_len"], net.layers[1].n_out
+    rule = pme.sorted_serves(bucket, ffn.held[1], ffn.top_k,
+                             ffn.n_experts + ffn.n_zero_experts)
+    key = pme.sorted_key("bfloat16", d, ffn.expert_width, ffn.activation)
+    counted = stats["loop"]["prefill_sorted_n"]
+    if rule:
+        _check(engaged("moe_experts", lambda k: k == key),
+               f"sorted expert kernel did not engage for {key}")
+        _check(counted >= shape["n_short"],
+               f"{counted} prefill dispatches counted as sorted of the "
+               f"{shape['n_short']} prompts in the {bucket}-token bucket")
+    else:
+        _check(not counted, f"{counted} prefill dispatches counted as "
+                            "sorted where the rule keeps the walk")
+    return {"sorted_rule": rule, "prefill_sorted_n": counted}
+
+
 def phase_hybrid(hyb: dict, shape: dict, *, kernels: bool,
                  dtype=None) -> dict:
     import jax.numpy as jnp
@@ -765,18 +795,7 @@ def phase_hybrid(hyb: dict, shape: dict, *, kernels: bool,
             key = ("bfloat16", rows, d, f)
             _check(engaged("moe_experts", lambda k: k == key),
                    f"grouped expert kernel did not engage for {key}")
-        from deeplearning4j_tpu.ops import pallas_moe_experts as pme
-
-        bucket, mixer = shape["short_len"], net.layers[1].mixers()[0]
-        if pme.sorted_serves(bucket, sz["held"][1], sz["topk"],
-                             pme.GATED_SILU):
-            key = ("bfloat16", pme.SORTED_ROWS, sz["d"], sz["f"], "sorted")
-            _check(engaged("moe_experts", lambda k: k == key),
-                   f"sorted expert kernel did not engage for {key}")
-        if mixer.query_block(bucket) < bucket:
-            _check(engaged("mla_prefill", lambda k: True),
-                   f"the prefill kernel did not serve the {bucket}-token "
-                   "prompt")
+        out.update(_sorted_prefills(net, shape, stats))
         H, Hkv = hyb["n_heads"], hyb["n_kv_heads"]
         key = ("bfloat16", 1, H, Hkv, d // H, shape["page_size"], "dense")
         _check(engaged("paged_attention", lambda k: k == key),
@@ -963,6 +982,7 @@ def phase_sublayer(sub: dict, shape: dict, *, kernels: bool,
             _check(engaged("moe_experts", lambda k: k == key),
                    f"ungated grouped expert kernel did not engage for "
                    f"{key}")
+        out.update(_sorted_prefills(net, shape, stats))
         H, Hkv, hd = sz["H"], sz["Hkv"], sz["hd"]
         key = ("bfloat16", 1, H, Hkv, hd, shape["page_size"], "dense")
         _check(engaged("paged_attention", lambda k: k == key),
@@ -1078,14 +1098,8 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
             key = ("bfloat16", rows, sz["d"], sz["f"])
             _check(engaged("moe_experts", lambda k: k == key),
                    f"grouped expert kernel did not engage for {key}")
-        from deeplearning4j_tpu.ops import pallas_moe_experts as pme
-
+        out.update(_sorted_prefills(net, shape, stats))
         bucket, mixer = shape["short_len"], net.layers[1].mixers()[0]
-        if pme.sorted_serves(bucket, sz["held"][1], sz["topk"],
-                             pme.GATED_SILU):
-            key = ("bfloat16", pme.SORTED_ROWS, sz["d"], sz["f"], "sorted")
-            _check(engaged("moe_experts", lambda k: k == key),
-                   f"sorted expert kernel did not engage for {key}")
         if mixer.query_block(bucket) < bucket:
             _check(engaged("mla_prefill", lambda k: True),
                    f"the prefill kernel did not serve the {bucket}-token "
@@ -1240,7 +1254,7 @@ def main(argv=None) -> int:
         if "serve" in names:
             run("serve", phase_serve, GPT, SERVE, kernels=True)
         if "hybrid" in names:
-            run("hybrid", phase_hybrid, HYBRID, HYBRID_SERVE, kernels=True)
+            run("hybrid", phase_hybrid, HYBRID, ROUTED_SERVE, kernels=True)
         if "linear" in names:
             run("linear", phase_linear, LINEAR, LINEAR_SERVE, kernels=True)
         if "sublayer" in names:

@@ -877,6 +877,22 @@ class MoEFeedForward(_Kind):
                 p["sWg"] = winit(k[4], (d, s), d, s)
         return p
 
+    def goes_sorted(self, rows: int, d: int, dtype) -> bool:
+        """Whether `rows` rows of width `d` take these experts sorted by
+        expert: the rule over shapes says so
+        (`pallas_moe_experts.sorted_serves`) and the sorted kernel's
+        probe passed at this shape class in this process. Asked after
+        the program that holds the rows was traced: the probe runs
+        then."""
+        from deeplearning4j_tpu.ops import kernel_dispatch
+        from deeplearning4j_tpu.ops import pallas_moe_experts as pme
+
+        key = pme.sorted_key(dtype, d, self.expert_width, self.activation)
+        return pme.sorted_serves(rows, self.held[1], self.top_k,
+                                 self.n_experts + self.n_zero_experts) \
+            and bool(kernel_dispatch.engaged(pme.FAMILY,
+                                             lambda k: k == key))
+
     def forward(self, p, x, count_mask=None):
         """`x` (..., d) -> (y, counts): with `count_mask` (one bool a
         token: the rows anyone will read), a `RouteCounts` over the

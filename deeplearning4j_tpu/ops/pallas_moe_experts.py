@@ -54,15 +54,19 @@ as experts are hit for each one a token chose: at decode sizes the
 weights' bytes bound it, not the MXU. As XLA batched einsums the same product materialises the
 (E, N, f) intermediates in HBM.
 
-A prefill of thousands of tokens is the other case: every held expert
-is hit and each token chose few of them, so the walk above would run
-every token through every expert. `moe_experts_sorted` takes the (token,
-expert) choices SORTED by expert instead, each expert's rows padded to
-whole tiles of `SORTED_ROWS`: a grid over row tiles, a scalar-prefetched
-vector naming each tile's expert (consecutive tiles of one expert bring
-its weights once), nothing computed past the last used tile. The
+A prefill is the other case: every held expert is hit and each token
+chose few of them, so the walk above runs every token through every
+expert, compute-bound from about 256 rows. `moe_experts_sorted` takes
+the (token, expert) choices SORTED by expert instead, each expert's rows
+padded to whole tiles of `SORTED_ROWS`: a grid over row tiles, a
+scalar-prefetched vector naming each tile's expert (consecutive tiles of
+one expert bring its weights once), nothing computed or copied past the
+last used tile; both variants, an expert whole or in tiles of `f` (a
+second, innermost axis adding into the float32 output tile). The
 multiply-adds are those of the choices made; `parallel.experts` sorts,
-gathers and adds the rows back.
+gathers and adds a token's float32 rows back. `sorted_serves` says from
+the shapes alone which product a block's rows take, `sorted_bound` how
+many sorted rows the program holds.
 
 Dispatch rides `ops/kernel_dispatch.py` under the family name
 `moe_experts`: the probe compiles and runs the kernel at the exact shape
@@ -73,6 +77,7 @@ never dispatch.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -87,6 +92,26 @@ from deeplearning4j_tpu.ops.kernel_dispatch import (
 FAMILY = "moe_experts"  # this module's row in kernel_verdicts()
 GATED_SILU, RELU2 = "gated_silu", "relu2"  # the experts' activations
 _MAX_ROWS = 512         # token rows per tile
+
+
+def _hidden(x_ref, g_ref, w_refs, act: str):
+    """A row tile's gated hidden activations over what `w_refs` hold of
+    ONE expert's width `f` (all of it, or a tile), weighed by the rows'
+    gates `g_ref` (1, rows, 1): (rows, f) float32, for the caller's
+    product with the expert's `Wd`. The one copy of the experts'
+    arithmetic: the walk and the sorted product differ in which rows
+    meet which expert."""
+    x = x_ref[...]
+    if act == RELU2:
+        wu_ref, _ = w_refs
+        u = jax.lax.dot_general(x, wu_ref[0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        u = jnp.maximum(u, 0.0)
+        return u * u * g_ref[0]
+    wg_ref, wu_ref, _ = w_refs
+    g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+    return g * jax.nn.sigmoid(g) * u * g_ref[0]
 
 
 def _experts_kernel(walk_ref, n_hit_ref, x_ref, g_ref, *refs, act: str,
@@ -106,19 +131,8 @@ def _experts_kernel(walk_ref, n_hit_ref, x_ref, g_ref, *refs, act: str,
 
     @pl.when(e < n_hit_ref[0])
     def _():
-        x = x_ref[...]
-        if act == RELU2:
-            wu_ref, wd_ref = w_refs
-            u = jax.lax.dot_general(x, wu_ref[0], (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            u = jnp.maximum(u, 0.0)
-            h = u * u * g_ref[0]
-        else:
-            wg_ref, wu_ref, wd_ref = w_refs
-            g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-            u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-            h = g * jax.nn.sigmoid(g) * u * g_ref[0]
-        acc_ref[...] += jnp.dot(h.astype(x.dtype), wd_ref[0],
+        h = _hidden(x_ref, g_ref, w_refs, act)
+        acc_ref[...] += jnp.dot(h.astype(x_ref.dtype), w_refs[-1][0],
                                 preferred_element_type=jnp.float32)
 
     last = e == pl.num_programs(1) - 1
@@ -231,70 +245,159 @@ def moe_experts(x, gates, Wg, Wu, Wd, hit, *, act: str = GATED_SILU,
 
 
 SORTED_ROWS = 128       # rows a tile of the sorted product holds
+# What a row of the sorted product costs, its experts' half-empty last
+# tiles counted, in rows of the walk: the walk multiplies tiles of 512
+# rows near the MXU's peak (155-192 TFLOP/s); the sorted product's tiles
+# of 128 rows are bound by their expert's bytes, and the sort, the gather
+# of the rows and the add-back ride on it. The largest that the
+# benchmark's shapes read (3.1 nemotron's, 3.3 granite's, 4.1-4.7
+# DeepSeek-V2's, 4.7-4.8 LongCat's: `tools/moe_experts_bench.py`, PERF.md
+# section 6).
+SORTED_ROW_COST = 4.8
+# How far over their mean the static size of the sorted rows reaches, in
+# standard deviations of independent choices (`sorted_bound`).
+SORTED_MARGIN = 6.0
 
 
-def _sorted_kernel(expert_ref, n_used_ref, x_ref, g_ref, wg_ref, wu_ref,
-                   wd_ref, o_ref):
+def _sorted_kernel(expert_ref, n_used_ref, x_ref, g_ref, *refs, act: str,
+                   tiled: bool):
     from jax.experimental import pallas as pl
 
     del expert_ref  # the index maps read it
-    used = pl.program_id(0) < n_used_ref[0]
+    *w_refs, o_ref = refs
+    t, n_used = pl.program_id(0), n_used_ref[0]
+    j = pl.program_id(1) if tiled else 0
 
-    @pl.when(used)
+    @pl.when(t < n_used)
     def _():
-        x = x_ref[...]
-        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-        h = g * jax.nn.sigmoid(g) * u * g_ref[...]
-        o_ref[...] = jnp.dot(h.astype(x.dtype), wd_ref[0],
-                             preferred_element_type=jnp.float32) \
-            .astype(o_ref.dtype)
+        h = _hidden(x_ref, g_ref, w_refs, act)
+        part = jnp.dot(h.astype(x_ref.dtype), w_refs[-1][0],
+                       preferred_element_type=jnp.float32)
+        if not tiled:
+            o_ref[...] = part
+            return
 
-    @pl.when(jnp.logical_not(used))
+        @pl.when(j == 0)
+        def _():
+            o_ref[...] = part
+
+        @pl.when(j > 0)
+        def _():
+            o_ref[...] += part
+
+    # every tile past the used ones is the LAST tile (the index maps):
+    # zeroed once, it stays where it is and is written back once
+    @pl.when((t == n_used) & (j == 0))
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("act", "interpret", "tf"))
 def moe_experts_sorted(xs, gs, tile_expert, n_used, Wg, Wu, Wd, *,
-                       interpret: bool = False):
-    """The gated-silu product over rows sorted by expert: `xs` (M, d),
+                       act: str = GATED_SILU, interpret: bool = False,
+                       tf: int = 0):
+    """The experts' product over rows sorted by expert: `xs` (M, d),
     tile `t` (rows `t * SORTED_ROWS` on) all of expert `tile_expert[t]`
     (int32, (M / SORTED_ROWS,)), `gs` (M, 1) float32 each row's gate (0
-    on a padding row), `n_used` (1,) int32 the tiles that hold rows;
-    tiles from there on come out zeros and name the last used tile's
-    expert, so that nothing is copied for them. Returns (M, d)."""
+    on a padding row), `n_used` (1,) int32 the tiles that hold rows,
+    fewer than there are (`sort_by_expert` keeps the last one free);
+    the matrices as `moe_experts` takes them for `act`. Returns (M, d)
+    FLOAT32, so that a token's rows are rounded once, after their sum:
+    the used tiles' rows, zeros in the LAST tile, and nothing defined in
+    the unused tiles before it: those steps name the last used tile's
+    expert (and its last tile of `f`) and the last tile's rows, so that
+    nothing is copied in or out for them. `tf`: the tile of `f` a grid
+    step brings (0: `f_tile`'s choice at `SORTED_ROWS` rows): all of `f`
+    is the one-axis program, less a second, innermost axis over `f / tf`
+    tiles that add into the output tile."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     M, d = xs.shape
     _, f, _ = Wd.shape
     tn = SORTED_ROWS
-    tile = pl.BlockSpec((tn, d), lambda t, expert, n_used: (t, 0))
-    gate = pl.BlockSpec((tn, 1), lambda t, expert, n_used: (t, 0))
-    whole = lambda *shape: pl.BlockSpec(
-        (1,) + shape, lambda t, expert, n_used: (expert[t], 0, 0))
+    T = M // tn
+    tf = tf or f_tile(tn, d, f, xs.dtype, act) or f
+    nf = f // tf
+
+    def at(t, rest):
+        """(expert, tile of f, row tile) of grid step `t` (and `j`, the
+        first of `rest` under two axes; then the prefetched vectors):
+        past the used tiles the last used tile's expert, its last tile
+        of `f`, and the LAST row tile."""
+        expert, n_used = rest[-2:]
+        used = t < n_used[0]
+        return expert[t], jnp.where(used, rest[0] if nf > 1 else 0, nf - 1), \
+            jnp.where(used, t, T - 1)
+
+    rows = lambda *shape: pl.BlockSpec(           # a tile of f on rows
+        (1,) + shape, lambda t, *rest: (*at(t, rest)[:2], 0))
+    cols = lambda *shape: pl.BlockSpec(           # a tile of f on lanes
+        (1,) + shape, lambda t, *rest: (at(t, rest)[0], 0, at(t, rest)[1]))
+    tile = pl.BlockSpec((tn, d), lambda t, *rest: (at(t, rest)[2], 0))
+    gate = pl.BlockSpec((1, tn, 1), lambda t, *rest: (0, at(t, rest)[2], 0))
+    specs = [rows(tf, d), rows(tf, d)] if act == RELU2 \
+        else [cols(d, tf), cols(d, tf), rows(tf, d)]
+    grid = (T,) + ((nf,) if nf > 1 else ())
     return pl.pallas_call(
-        _sorted_kernel,
+        functools.partial(_sorted_kernel, act=act, tiled=nf > 1),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(M // tn,),
-            in_specs=[tile, gate, whole(d, f), whole(d, f), whole(f, d)],
-            out_specs=tile),
-        out_shape=jax.ShapeDtypeStruct((M, d), xs.dtype),
+            num_scalar_prefetch=2, grid=grid,
+            in_specs=[tile, gate, *specs], out_specs=tile),
+        out_shape=jax.ShapeDtypeStruct((M, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
-    )(tile_expert, n_used, xs, gs, Wg, Wu, Wd)
+    )(tile_expert, n_used, xs, gs.astype(jnp.float32)[None],
+      *((Wu, Wd) if act == RELU2 else (Wg, Wu, Wd)))
 
 
-def sorted_serves(N: int, E: int, k: int, act: str) -> bool:
-    """Whether the sorted product is the cheaper walk: more than one
-    token tile (a prefill; a decode step's rows are one tile and its
-    cost is the weights' bytes), gated experts, and so few choices a
-    token that even if every one fell on a held expert the sorted rows
-    were under half of what the hit-first walk multiplies."""
-    return act == GATED_SILU and N > _MAX_ROWS and 2 * k <= E
+def sorted_serves(N: int, E: int, k: int, router_width: int) -> bool:
+    """Whether `N` rows that each chose `k` of the `router_width`
+    experts the router scores, `E` of them held here, are cheaper sorted
+    by expert than walked. Arithmetic on two row counts an expert: the
+    walk multiplies all `N` rows with it; the sorted product the
+    `N * k / router_width` expected to choose it and half a tile of
+    padding, each at `SORTED_ROW_COST` rows of the walk. Half a tile at
+    that cost is 307 rows: a decode step's rows and every bucket at or
+    under the 256 from which a walk is compute-bound (2 operations a
+    weight element a row against its 2 bytes: 197e12 / 819e9 = 240 rows)
+    keep the walk and its program; under it an expert costs its bytes,
+    which the sorted product reads too."""
+    if not k or not E:
+        return False
+    chosen = N * min(k, E) / max(router_width, E)
+    return SORTED_ROW_COST * (chosen + SORTED_ROWS / 2) < N
+
+
+def sorted_worst(N: int, E: int, k: int) -> tuple:
+    """(tiles, choices a row) of the sorted rows if every choice falls
+    on a held expert: no routing overflows it."""
+    return -(-N * k // SORTED_ROWS) + E, k
+
+
+def sorted_bound(N: int, E: int, k: int, router_width: int) -> tuple:
+    """(tiles, choices a row): the static size of the sorted rows
+    (`parallel.experts.sort_by_expert`). The worst case
+    (`sorted_worst`: `N * k` rows in whole tiles and a tile an expert,
+    `k` choices a row) costs `k / E` of the walk's rows at the slower
+    rate, with copies of `N * k` rows around the kernel. Where the router
+    is so much wider than the share held that independent choices,
+    `SORTED_MARGIN` standard deviations over their mean, number under
+    half of `k` a row, that and the rows they fill: the add-back, the
+    larger copy, shrinks with `k` and the gather of the rows with the
+    tiles, and the caller keeps the walk as the other branch of a
+    `lax.cond` for the routing that does not fit (tokens that choose
+    alike more than chance)."""
+    worst = sorted_worst(N, E, k)
+    p = min(1.0, E / max(router_width, E))
+    spread = lambda n: SORTED_MARGIN * (n * p * (1.0 - p)) ** 0.5
+    kk = min(k, math.ceil(k * p + spread(k)))
+    tiles = math.ceil((N * k * p + spread(N * k)) / SORTED_ROWS) + E + 1
+    # in eights: neighbouring buckets then share one program of the kernel
+    tiles = min(worst[0], -(-tiles // 8) * 8)
+    return (tiles, kk) if 2 * kk <= k else worst
 
 
 def vmem_bytes_estimate(tn: int, d: int, f: int, dtype,
@@ -335,6 +438,32 @@ def _platform_supported() -> bool:
     return _kernels_dispatch("DL4J_TPU_NO_PALLAS_MOE_EXPERTS")
 
 
+@functools.partial(jax.jit, static_argnames=("E", "rows", "fan", "shift",
+                                             "dtype"))
+def _rotations(base, *, E: int, rows: int, fan: int, shift: int, dtype):
+    m = base.reshape(rows, -1) * fan ** -0.5
+    return jnp.stack([jnp.roll(m, (e + 1) * shift, axis=0)
+                      for e in range(E)]).astype(dtype)
+
+
+def _probe_experts(rng, E: int, d: int, f: int, dtype, act: str):
+    """(Wg or None, Wu, Wd) of `E` probe experts in the variant's
+    layouts, entries of variance 1 / fan-in: ONE uniform float32 draw of
+    an expert's size, each matrix a rotation of it by rows made on the
+    device (three 75 MB experts drawn entry by entry in float64 on the
+    host cost a start-up seconds)."""
+    import numpy as np
+
+    base = jnp.asarray((rng.random(d * f, dtype=np.float32) - 0.5)
+                       * 12.0 ** 0.5)
+    dtype = jnp.dtype(dtype)
+    up = f if act == RELU2 else d
+    stack = lambda rows, fan, shift: _rotations(
+        base, E=E, rows=rows, fan=fan, shift=shift, dtype=dtype)
+    return None if act == RELU2 else stack(up, d, 3), stack(up, d, 5), \
+        stack(f, f, 7)
+
+
 def _eager_probe(dtype, tn: int, d: int, f: int, act: str) -> bool:
     """Compile and run the kernel at this shape class (two experts, one
     of them chosen by no token) and hold it to the XLA products."""
@@ -345,12 +474,7 @@ def _eager_probe(dtype, tn: int, d: int, f: int, act: str) -> bool:
     rng = np.random.default_rng(0)
     E = 2
     x = jnp.asarray(rng.standard_normal((tn, d)), dtype)
-    up = (E, f, d) if act == RELU2 else (E, d, f)
-    Wg, Wu = (jnp.asarray(rng.standard_normal(up) / d ** 0.5, dtype)
-              for _ in range(2))
-    if act == RELU2:
-        Wg = None
-    Wd = jnp.asarray(rng.standard_normal((E, f, d)) / f ** 0.5, dtype)
+    Wg, Wu, Wd = _probe_experts(rng, E, d, f, dtype, act)
     gates = jnp.asarray(np.stack([rng.random(tn), np.zeros(tn)], 1),
                         jnp.float32)
     got = np.asarray(moe_experts(x, gates, Wg, Wu, Wd,
@@ -405,10 +529,18 @@ def moe_experts_or_none(x, gates, Wg, Wu, Wd, hit, act: str = GATED_SILU):
         return None
 
 
-def _sorted_probe(dtype, d: int, f: int) -> bool:
+def sorted_key(dtype, d: int, f: int, act: str = GATED_SILU) -> tuple:
+    """The sorted product's row in `kernel_verdicts()`: the gated
+    variant's ends in "sorted", the ungated one's in ("relu2",
+    "sorted")."""
+    return (jnp.dtype(dtype).name, SORTED_ROWS, d, f) \
+        + ((RELU2,) if act == RELU2 else ()) + ("sorted",)
+
+
+def _sorted_probe(dtype, d: int, f: int, act: str) -> bool:
     """Compile and run the sorted kernel at this shape class (three
     experts: one of two tiles, one chosen by no row, one of one tile;
-    then an unused tile) and hold it to the XLA products."""
+    then an unused tile, the last) and hold it to the XLA products."""
     import numpy as np
 
     from deeplearning4j_tpu.parallel.experts import grouped_expert_ffn_xla
@@ -417,17 +549,15 @@ def _sorted_probe(dtype, d: int, f: int) -> bool:
     E, tn = 3, SORTED_ROWS
     tile_expert = jnp.asarray([0, 0, 2, 2], jnp.int32)
     xs = jnp.asarray(rng.standard_normal((4 * tn, d)), dtype)
-    Wg, Wu = (jnp.asarray(rng.standard_normal((E, d, f)) / d ** 0.5, dtype)
-              for _ in range(2))
-    Wd = jnp.asarray(rng.standard_normal((E, f, d)) / f ** 0.5, dtype)
+    Wg, Wu, Wd = _probe_experts(rng, E, d, f, dtype, act)
     gs = jnp.asarray(rng.random((4 * tn, 1)), jnp.float32)
     got = np.asarray(moe_experts_sorted(
-        xs, gs, tile_expert, jnp.asarray([3], jnp.int32), Wg, Wu, Wd),
-        np.float32)
+        xs, gs, tile_expert, jnp.asarray([3], jnp.int32), Wg, Wu, Wd,
+        act=act))
     gates = jnp.zeros((4 * tn, E), jnp.float32) \
         .at[:2 * tn, 0].set(gs[:2 * tn, 0]) \
         .at[2 * tn:3 * tn, 2].set(gs[2 * tn:3 * tn, 0])
-    want = np.asarray(grouped_expert_ffn_xla(xs, gates, Wg, Wu, Wd),
+    want = np.asarray(grouped_expert_ffn_xla(xs, gates, Wg, Wu, Wd, act),
                       np.float32)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
     err = float(np.max(np.abs(got - want))) / float(np.max(np.abs(want)))
@@ -437,27 +567,29 @@ def _sorted_probe(dtype, d: int, f: int) -> bool:
     return True
 
 
-def moe_experts_sorted_or_none(xs, gs, tile_expert, n_used, Wg, Wu, Wd):
-    """Dispatch probe of the sorted product: its rows, or None when the
-    kernel cannot serve (CPU backend, kill switch, a dtype or widths off
-    the tile grid, an expert that does not fit VMEM whole beside a tile)
-    or its shape class failed the probe. Its key ends in "sorted"."""
+def moe_experts_sorted_or_none(xs, gs, tile_expert, n_used, Wg, Wu, Wd,
+                               act: str = GATED_SILU):
+    """Dispatch probe of the sorted product: its rows in float32, or
+    None when the kernel cannot serve (CPU backend, kill switch, a dtype
+    or widths off the tile grid, no tile of `f` that fits VMEM beside a
+    row tile) or its shape class failed the probe (`sorted_key`)."""
     M, d = xs.shape
     _, f, _ = Wd.shape
     dtype = xs.dtype
     if not _platform_supported() or Wu.dtype != dtype \
             or dtype not in (jnp.float32, jnp.bfloat16):
         return None
-    key = (jnp.dtype(dtype).name, SORTED_ROWS, d, f, "sorted")
-    if d % 128 or f % 128 or M % SORTED_ROWS or \
-            vmem_bytes_estimate(SORTED_ROWS, d, f, dtype) > _vmem_limit():
+    key = sorted_key(dtype, d, f, act)
+    if d % 128 or f % _f_grid(dtype, act) or M % SORTED_ROWS or \
+            not f_tile(SORTED_ROWS, d, f, dtype, act):
         _record_decline(FAMILY, key, f"widths {d} x {f}: off the tile "
-                                     "grid or over the VMEM ceiling whole")
+                                     "grid, or no tile of f fits VMEM")
         return None
-    if not _probe_verdict(FAMILY, key, _sorted_probe, (dtype, d, f)):
+    if not _probe_verdict(FAMILY, key, _sorted_probe, (dtype, d, f, act)):
         return None
     try:
-        return moe_experts_sorted(xs, gs, tile_expert, n_used, Wg, Wu, Wd)
+        return moe_experts_sorted(xs, gs, tile_expert, n_used, Wg, Wu, Wd,
+                                  act=act)
     except Exception as e:  # per-shape staging failure: fall back
         _record_decline(FAMILY, key, f"staging at {xs.shape}: "
                                      f"{type(e).__name__}: {e}")

@@ -415,18 +415,25 @@ def grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act: str = GATED_SILU):
     return y.astype(x.dtype)
 
 
-def sort_by_expert(gates, k: int, tile: int):
+def sort_by_expert(gates, k: int, tile: int, tiles: int = 0):
     """The choices behind `gates` (N, E) (at most `k` a row not zero),
     sorted by expert with each expert's rows padded to whole tiles of
     `tile`: (rows (M,) int32, the token each sorted row is (0 on
     padding); gs (M, 1) float32, its gate (0 on padding); tile_expert
     (M / tile,) int32; n_used (1,) int32, the tiles that hold rows; back
     (N, k) int32, where each of a token's `k` choices lies among the
-    sorted rows (a choice it did not make: a row whose gate is 0)),
-    with M = N * k in whole tiles + E tiles, what the rows take if every
-    choice is made and every expert's last tile is all but empty."""
+    sorted rows (a choice it did not make: the last row, whose gate is
+    0); fits () bool). `tiles` is the static M / tile. 0: N * k in whole
+    tiles + E, more than the rows take if every choice is made and every
+    expert's last tile is all but empty, so the last tile never holds a
+    row, no routing overflows M and `fits` is true. Fewer (with a `k`
+    under the router's top-k: `pallas_moe_experts.sorted_bound`): `fits`
+    says whether no row made more than `k` choices and the rows left the
+    last tile free; where it is false the other outputs are not to be
+    used."""
     N, E = gates.shape
-    M = (-(-N * k // tile) + E) * tile
+    tiles = tiles or -(-N * k // tile) + E
+    M = tiles * tile
     vals, idx = lax.top_k(gates, k)
     expert = jnp.where(vals != 0, idx, E).reshape(-1).astype(jnp.int32)
     order = jnp.argsort(expert, stable=True).astype(jnp.int32)
@@ -442,62 +449,94 @@ def sort_by_expert(gates, k: int, tile: int):
     dest = jnp.where(by_expert < E, first[by_expert]
                      + jnp.arange(N * k, dtype=jnp.int32)
                      - first_unpadded[by_expert], M - 1)
+    # (a scatter drops what lies past a smaller M: `fits` is false then)
     rows = jnp.zeros((M,), jnp.int32).at[dest].set(order // k)
     gs = jnp.zeros((M,), jnp.float32).at[dest].set(
         jnp.where(by_expert < E, vals.reshape(-1)[order], 0.0))
     n_used = ends[-1:] // tile
     tile_expert = jnp.searchsorted(
-        ends, jnp.arange(M // tile, dtype=jnp.int32) * tile, side="right")
+        ends, jnp.arange(tiles, dtype=jnp.int32) * tile, side="right")
     last = jnp.searchsorted(ends, ends[-1] - 1, side="right")
     tile_expert = jnp.minimum(tile_expert, last).astype(jnp.int32)
-    back = jnp.zeros((N * k,), jnp.int32).at[order].set(dest)
+    back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.minimum(dest, M - 1))
+    fits = (n_used[0] < tiles) \
+        & (jnp.max(jnp.sum(gates != 0, axis=1)) <= k)
     return rows, gs[:, None], tile_expert, n_used.astype(jnp.int32), \
-        back.reshape(N, k)
+        back.reshape(N, k), fits
 
 
-def sorted_expert_ffn_or_none(x, gates, Wg, Wu, Wd, k: int):
+class _Declined(Exception):
+    """The sorted kernel cannot serve, said while a branch was traced."""
+
+
+def sorted_expert_ffn_or_none(x, gates, Wg, Wu, Wd, k: int,
+                              act: str = GATED_SILU, tiles: int = 0,
+                              walk=None):
     """`grouped_expert_ffn_xla`'s sum through the sorted kernel of
     `ops/pallas_moe_experts.py`: every choice one row, the rows sorted by
     expert, each expert's FFN over its own rows only, and a token's rows
-    added back in float32. None where the kernel cannot serve."""
+    added back in float32, as they left the kernel: one rounding a
+    token, the walk's. `k`, `tiles`: the static size of the sorted rows
+    (`sort_by_expert`); under the worst case's, `walk()` gives the same
+    sum another way and a `lax.cond` takes it for the routing that does
+    not fit. None where the kernel cannot serve."""
     from deeplearning4j_tpu.ops.pallas_moe_experts import (
         SORTED_ROWS,
         moe_experts_sorted_or_none,
     )
 
-    rows, gs, tile_expert, n_used, back = sort_by_expert(gates, k,
-                                                         SORTED_ROWS)
-    ys = moe_experts_sorted_or_none(x[rows], gs, tile_expert, n_used,
-                                    Wg, Wu, Wd)
-    if ys is None:
+    rows, gs, tile_expert, n_used, back, fits = sort_by_expert(
+        gates, k, SORTED_ROWS, tiles)
+
+    def product():
+        ys = moe_experts_sorted_or_none(x[rows], gs, tile_expert, n_used,
+                                        Wg, Wu, Wd, act)
+        if ys is None:
+            raise _Declined
+        # choice-major: (k, N, d) sums over its leading axis tile by tile
+        return jnp.sum(ys[back.T], axis=0).astype(x.dtype)
+
+    try:
+        return product() if walk is None else lax.cond(fits, product, walk)
+    except _Declined:
         return None
-    return jnp.sum(ys[back].astype(jnp.float32), axis=1).astype(x.dtype)
 
 
 def grouped_expert_ffn(x, gates, Wg, Wu, Wd, hit, act: str = GATED_SILU,
-                       top_k: int = 0):
+                       top_k: int = 0, router_width: int = 0):
     """The grouped product behind the kernel-dispatch contract: the
     Pallas kernels of `ops/pallas_moe_experts.py` on a TPU, the batched
     XLA products over every expert elsewhere. A decode step's rows walk
     the experts that `hit` (E,) bool marks (each one's weights streamed
-    through VMEM once, the others left in HBM); a prefill's thousands of
-    rows, each with at most `top_k` choices among many experts, go
-    sorted by expert instead (`pallas_moe_experts.sorted_serves`). A row
-    whose gate is not zero on an unmarked expert comes out without that
-    expert's part."""
+    through VMEM once, the others left in HBM); a prefill's rows, each
+    with at most `top_k` choices among the `router_width` experts the
+    router scores, go sorted by expert instead wherever that multiplies
+    clearly fewer rows (`pallas_moe_experts.sorted_serves`: shapes
+    alone decide), at the static size `sorted_bound` gives and with the
+    walk for the routing that overflows it. A row whose gate is not zero
+    on an unmarked expert comes out without that expert's part."""
     from deeplearning4j_tpu.ops.pallas_moe_experts import (
         moe_experts_or_none,
+        sorted_bound,
         sorted_serves,
+        sorted_worst,
     )
+
+    def walk():
+        out = moe_experts_or_none(x, gates, Wg, Wu, Wd, hit, act)
+        return grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act) \
+            if out is None else out
 
     N, E = gates.shape
     k = min(top_k, E)
-    out = sorted_expert_ffn_or_none(x, gates, Wg, Wu, Wd, k) \
-        if k and sorted_serves(N, E, k, act) else None
-    if out is None:
-        out = moe_experts_or_none(x, gates, Wg, Wu, Wd, hit, act)
-    return grouped_expert_ffn_xla(x, gates, Wg, Wu, Wd, act) \
-        if out is None else out
+    if not sorted_serves(N, E, k, router_width):
+        return walk()
+    tiles, kk = sorted_bound(N, E, k, router_width)
+    out = sorted_expert_ffn_or_none(
+        x, gates, Wg, Wu, Wd, kk, act, tiles,
+        walk if (tiles, kk) != sorted_worst(N, E, k) else None)
+    return walk() if out is None else out
 
 
 def gated_mlp(x, Wg, Wu, Wd):
@@ -556,7 +595,8 @@ def dropless_moe(x, router, Wg, Wu, Wd, *, top_k: int, experts_held,
             chose &= count_mask[:, None]
         hit = jnp.any(chose, axis=0)
     with jax.named_scope("moe.experts"):
-        y = grouped_expert_ffn(x, gates, Wg, Wu, Wd, hit, act, top_k)
+        y = grouped_expert_ffn(x, gates, Wg, Wu, Wd, hit, act, top_k,
+                               router.shape[1])
     if n_zero:
         with jax.named_scope("moe.zero"):
             zero_gates = all_gates[:, all_gates.shape[1] - n_zero:]

@@ -850,6 +850,8 @@ class DecodeEngine:
         routed = block_state.routed_ffns(plan)
         self._moe_blocks = len(routed)
         self._moe_top_k = max([ffn.top_k for ffn in routed], default=0)
+        self._routed_ffns = routed
+        self._sorted_rows = {}  # a prefill's rows -> its experts went sorted
         self._net = net
         self.max_len = L
         self.page_size = page
@@ -2465,6 +2467,7 @@ class DecodeEngine:
             return
         (self._caches, self._tok, self._pos, self._keys,
          self._temps) = out[:5]
+        ph.prefill_sorted_n += self._prefill_goes_sorted(bucket)
         req.in_flight = 1
         with self._cond:
             req.slot = slot
@@ -2478,6 +2481,19 @@ class DecodeEngine:
         self._inflight.append(_InFlight(
             "prefill", [(slot, req)], out[5:], tp0, info,
             draft=(ids, wpids) if self._spec is not None else None))
+
+    def _prefill_goes_sorted(self, rows: int) -> bool:
+        """Whether the prefill program of `rows` rows ran every routed
+        block's experts through the sorted product
+        (`MoEFeedForward.goes_sorted`; False for a net that routes
+        nowhere); asked after that program's first dispatch, when its
+        kernels' probes have run, and kept."""
+        went = self._sorted_rows.get(rows)
+        if went is None:
+            d, cdt = self._plan.emb.n_out, self._plan.cdt
+            went = self._sorted_rows[rows] = bool(self._routed_ffns) and all(
+                ffn.goes_sorted(rows, d, cdt) for ffn in self._routed_ffns)
+        return went
 
     # graftlint: hot-loop
     def _collect_prefill(self, rec: _InFlight) -> None:
@@ -2641,6 +2657,7 @@ class DecodeEngine:
             self._prefill_failure(slot, req, e, attached=True)
             return
         self._hook("post_prefill", info)
+        ph.prefill_sorted_n += self._prefill_goes_sorted(W)
         with self._cond:
             self.prefill_chunks += 1
             self.state_resets += int(self._recurrent and off == 0)

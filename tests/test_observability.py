@@ -946,7 +946,7 @@ def test_loop_and_front_keys_in_contract_and_exposition(net):
         assert set(loop) == {"iterations", "sink_s", "sink_n", "ahead_n",
                              "drained_n", "overshoot_tokens",
                              "kv_pages_walked", "kv_pages_table",
-                             "spans_dropped"} \
+                             "prefill_sorted_n", "spans_dropped"} \
             | {p + sfx for p in obs.LEAF_PHASES for sfx in ("_s", "_n")}
         text = eng.metrics_text()
     finally:

@@ -66,6 +66,40 @@ def test_ungated_expert_kernel_compiles_off_the_lane_grid(one_chip, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
 
 
+@pytest.mark.parametrize("d,f,E,act,tiles,tf", [
+    (6144, 2048, 16, "gated_silu", 24, 1024),
+    (2688, 1856, 64, "relu2", 88, 1856),
+], ids=("longcat-in-tiles-of-f", "nemotron-relu2"))
+def test_sorted_expert_kernel_compiles_at_the_published_widths(
+        one_chip, monkeypatch, d, f, E, act, tiles, tf):
+    """The sorted product's two other shapes: LongCat-Flash-Chat's
+    experts (6144 x 2048, 75.5 MB: over the VMEM ceiling whole) in two
+    tiles of their width under a second grid axis, at the rows a
+    1,024-token prefill's static size gives; nemotron's ungated relu^2
+    experts (2688 x 1856, both matrices held (1856, 2688)) whole, at a
+    512-token prefill's worst case. Float32 rows out."""
+    from deeplearning4j_tpu.ops import pallas_moe_experts as pme
+
+    monkeypatch.setattr(pme, "_vmem_limit", lambda: 112 << 20)
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert pme.f_tile(pme.SORTED_ROWS, d, f, jnp.bfloat16, act) == tf
+    M, up = tiles * pme.SORTED_ROWS, (E, f, d) if act == "relu2" \
+        else (E, d, f)
+    args = (S((M, d)), S((M, 1), jnp.float32), S((tiles,), jnp.int32),
+            S((1,), jnp.int32), None if act == "relu2" else S(up), S(up),
+            S((E, f, d)))
+    with jax.enable_x64(False):
+        lowered = pme.moe_experts_sorted.lower(*args, act=act)
+        compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert lowered.out_info.dtype == jnp.float32
+    assert lowered.out_info.shape == (M, d)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
 def _shapes(one_chip):
     def S(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
@@ -327,8 +361,17 @@ def test_single_sub_layer_decode_step_compiles_at_the_published_widths(
             S((n_slots,), i32), S((n_slots,), i32),
             S((n_slots, 2), jnp.uint32), S((n_slots,), f32),
             S((n_slots,), jnp.bool_)).compile().as_text()
+        # a 512-token prompt: the rule sends its rows to the experts
+        # sorted (top-6 of 128 scored, 64 held), the decode step's walk
+        prefill = programs.prefill.lower(
+            net._params, caches, S((1, 512), i32), S((), i32), S((), i32),
+            S((512 // page,), i32), S((n_slots,), i32), S((n_slots,), i32),
+            S((n_slots, 2), jnp.uint32), S((n_slots,), f32),
+            S((2,), jnp.uint32), S((2,), jnp.uint32),
+            S((), f32)).compile().as_text()
+    assert "moe_experts_sorted" in prefill
     assert text.count("tpu_custom_call") == 3
-    assert "moe_experts" in text
+    assert "moe_experts" in text and "moe_experts_sorted" not in text
     kept = {"f32[64,64,64,128]", "bf16[577,2,128,128]"}
     assert chip_smoke.pool_layout_copies(text, kept) == 0
     sweeps = re.findall(r"^\s*%[\w.\-]+ = \(f32\[64,64,64\]\{.*, "
@@ -448,9 +491,21 @@ def test_shortcut_layer_decode_step_compiles_at_the_published_widths(
             S((n_slots,), i32), S((n_slots,), i32),
             S((n_slots, 2), jnp.uint32), S((n_slots,), f32),
             S((n_slots,), jnp.bool_)).compile().as_text()
+        # a 512-token prompt: 1 choice in 48 falls on the 16 held of
+        # the router's 768, so its rows go sorted at a third of the worst
+        # case's size, the walk the other branch of a conditional
+        prefill = programs.prefill.lower(
+            net._params, caches, S((1, 512), i32), S((), i32), S((), i32),
+            S((512 // page,), i32), S((n_slots,), i32), S((n_slots,), i32),
+            S((n_slots, 2), jnp.uint32), S((n_slots,), f32),
+            S((2,), jnp.uint32), S((2,), jnp.uint32),
+            S((), f32)).compile().as_text()
+    assert "moe_experts_sorted" in prefill and "conditional" in prefill
+    assert f"f32[{24 * 128},6144]" in prefill
     assert text.count("tpu_custom_call") == 5
     for name in ("mla_attend", "latent_write", "moe_experts"):
         assert name in text
+    assert "moe_experts_sorted" not in text
     assert chip_smoke.pool_layout_copies(text, {"bf16[2561,576,128]"}) == 0
 
 
