@@ -103,6 +103,43 @@ def gpt_configuration(vocab_size: int,
             .build())
 
 
+def composed_configuration(vocab_size: int, d_model: int, blocks, *,
+                           eps: float, tied_head: bool = False,
+                           embedding_multiplier: float = 1.0,
+                           logits_scaling: float = 1.0, seed: int = 12345,
+                           learning_rate: float = 3e-4,
+                           updater: Updater = Updater.ADAM,
+                           ) -> MultiLayerConfiguration:
+    """Causal LM around the composed `blocks` (`DecoderBlock`s or
+    `ShortcutDecoderBlock`s of width `d_model`): a token embedding
+    without positions, scaled by `embedding_multiplier`; the blocks; one
+    trailing RMSNorm; and the output head, untied and bias-free, or with
+    `tied_head` the embedding, transposed, over `logits_scaling`. What
+    the named families below share."""
+    b = (NeuralNetConfiguration.Builder()
+         .seed(seed)
+         .learning_rate(learning_rate)
+         .updater(updater)
+         .drop_out(0.0)
+         .list()
+         .layer(TokenEmbedding(n_in=vocab_size, n_out=d_model,
+                               positional=False,
+                               multiplier=embedding_multiplier)))
+    for block in blocks:
+        b = b.layer(block)
+    head = dict(n_in=d_model, n_out=vocab_size,
+                activation=Activation.SOFTMAX, loss=LossFunction.MCXENT,
+                dropout=0.0)
+    return (b
+            .layer(RMSNormalization(n_in=d_model, n_out=d_model, eps=eps,
+                                    dropout=0.0))
+            .layer(TiedRnnOutputLayer(tied_to=0,
+                                      logits_scaling=logits_scaling, **head)
+                   if tied_head else RnnOutputLayer(has_bias=False, **head))
+            .set_input_type(InputType.recurrent(vocab_size))
+            .build())
+
+
 def hybrid_moe_configuration(vocab_size: int, d_model: int,
                              layer_types, *,
                              n_heads: int, n_kv_heads: int,
@@ -129,15 +166,6 @@ def hybrid_moe_configuration(vocab_size: int, d_model: int,
     transposed, over `logits_scaling` (the Hugging Face
     `granitemoehybrid` family's layout). `experts_held = (first,
     count)`: the share of each layer's experts this network holds."""
-    b = (NeuralNetConfiguration.Builder()
-         .seed(seed)
-         .learning_rate(learning_rate)
-         .updater(updater)
-         .drop_out(0.0)
-         .list()
-         .layer(TokenEmbedding(n_in=vocab_size, n_out=d_model,
-                               positional=False,
-                               multiplier=embedding_multiplier)))
     ffn = MoEFeedForward(n_experts=n_experts, top_k=top_k,
                          expert_width=expert_width,
                          shared_width=shared_width,
@@ -148,22 +176,15 @@ def hybrid_moe_configuration(vocab_size: int, d_model: int,
                              chunk=mamba_chunk, eps=eps),
         "attention": AttentionMixer(n_heads=n_heads, n_kv_heads=n_kv_heads,
                                     scale=attention_multiplier)}
-    for kind in layer_types:
-        b = b.layer(DecoderBlock(n_in=d_model, n_out=d_model,
-                                 mixer=mixers[kind], ffn=ffn,
-                                 norm=RMSNorm(eps=eps),
-                                 residual_multiplier=residual_multiplier))
-    return (b
-            .layer(RMSNormalization(n_in=d_model, n_out=d_model, eps=eps,
-                                    dropout=0.0))
-            .layer(TiedRnnOutputLayer(n_in=d_model, n_out=vocab_size,
-                                      tied_to=0,
-                                      logits_scaling=logits_scaling,
-                                      activation=Activation.SOFTMAX,
-                                      loss=LossFunction.MCXENT,
-                                      dropout=0.0))
-            .set_input_type(InputType.recurrent(vocab_size))
-            .build())
+    blocks = [DecoderBlock(n_in=d_model, n_out=d_model, mixer=mixers[kind],
+                           ffn=ffn, norm=RMSNorm(eps=eps),
+                           residual_multiplier=residual_multiplier)
+              for kind in layer_types]
+    return composed_configuration(
+        vocab_size, d_model, blocks, eps=eps, tied_head=True,
+        embedding_multiplier=embedding_multiplier,
+        logits_scaling=logits_scaling, seed=seed,
+        learning_rate=learning_rate, updater=updater)
 
 
 def hybrid_linear_configuration(vocab_size: int, d_model: int,
@@ -183,14 +204,6 @@ def hybrid_linear_configuration(vocab_size: int, d_model: int,
     positions), each followed by a dense gated MLP, under RMSNorm; no
     positional layer, one trailing norm and an untied, bias-free output
     head (the Hugging Face `olmo_hybrid` family's layout)."""
-    b = (NeuralNetConfiguration.Builder()
-         .seed(seed)
-         .learning_rate(learning_rate)
-         .updater(updater)
-         .drop_out(0.0)
-         .list()
-         .layer(TokenEmbedding(n_in=vocab_size, n_out=d_model,
-                               positional=False)))
     mixers = {
         "linear_attention": GatedDeltaNetMixer(
             n_heads=linear_heads, key_dim=linear_key_dim,
@@ -199,21 +212,13 @@ def hybrid_linear_configuration(vocab_size: int, d_model: int,
         "full_attention": AttentionMixer(n_heads=n_heads,
                                          n_kv_heads=n_kv_heads,
                                          qk_norm=True, eps=eps)}
-    for kind in layer_types:
-        b = b.layer(DecoderBlock(n_in=d_model, n_out=d_model,
-                                 mixer=mixers[kind],
-                                 ffn=GatedMLP(width=ffn_width),
-                                 norm=RMSNorm(eps=eps),
-                                 norm_placement="post"))
-    return (b
-            .layer(RMSNormalization(n_in=d_model, n_out=d_model, eps=eps,
-                                    dropout=0.0))
-            .layer(RnnOutputLayer(n_in=d_model, n_out=vocab_size,
-                                  has_bias=False,
-                                  activation=Activation.SOFTMAX,
-                                  loss=LossFunction.MCXENT, dropout=0.0))
-            .set_input_type(InputType.recurrent(vocab_size))
-            .build())
+    blocks = [DecoderBlock(n_in=d_model, n_out=d_model, mixer=mixers[kind],
+                           ffn=GatedMLP(width=ffn_width),
+                           norm=RMSNorm(eps=eps), norm_placement="post")
+              for kind in layer_types]
+    return composed_configuration(
+        vocab_size, d_model, blocks, eps=eps, seed=seed,
+        learning_rate=learning_rate, updater=updater)
 
 
 def hybrid_sublayer_configuration(vocab_size: int, d_model: int,
@@ -242,14 +247,6 @@ def hybrid_sublayer_configuration(vocab_size: int, d_model: int,
     Hugging Face `nemotron_h` family's layout). `experts_held = (first,
     count)`: the share of each expert layer's experts this network
     holds."""
-    b = (NeuralNetConfiguration.Builder()
-         .seed(seed)
-         .learning_rate(learning_rate)
-         .updater(updater)
-         .drop_out(0.0)
-         .list()
-         .layer(TokenEmbedding(n_in=vocab_size, n_out=d_model,
-                               positional=False)))
     kinds = {
         "M": dict(mixer=Mamba2Mixer(
             n_heads=mamba_heads, head_dim=mamba_head_dim,
@@ -266,18 +263,12 @@ def hybrid_sublayer_configuration(vocab_size: int, d_model: int,
     if set(pattern) - set(kinds):
         raise ValueError(f"pattern {pattern!r}: each layer is M (Mamba-2), "
                          "* (attention) or E (experts)")
-    for kind in pattern:
-        b = b.layer(DecoderBlock(n_in=d_model, n_out=d_model,
-                                 norm=RMSNorm(eps=eps), **kinds[kind]))
-    return (b
-            .layer(RMSNormalization(n_in=d_model, n_out=d_model, eps=eps,
-                                    dropout=0.0))
-            .layer(RnnOutputLayer(n_in=d_model, n_out=vocab_size,
-                                  has_bias=False,
-                                  activation=Activation.SOFTMAX,
-                                  loss=LossFunction.MCXENT, dropout=0.0))
-            .set_input_type(InputType.recurrent(vocab_size))
-            .build())
+    blocks = [DecoderBlock(n_in=d_model, n_out=d_model,
+                           norm=RMSNorm(eps=eps), **kinds[kind])
+              for kind in pattern]
+    return composed_configuration(
+        vocab_size, d_model, blocks, eps=eps, seed=seed,
+        learning_rate=learning_rate, updater=updater)
 
 
 def longcat_configuration(vocab_size: int, d_model: int, n_layers: int, *,
@@ -303,14 +294,6 @@ def longcat_configuration(vocab_size: int, d_model: int, n_layers: int, *,
     Face `longcat_flash` family's layout). `experts_held = (first,
     count)`: the share of each layer's real experts this network
     holds."""
-    b = (NeuralNetConfiguration.Builder()
-         .seed(seed)
-         .learning_rate(learning_rate)
-         .updater(updater)
-         .drop_out(0.0)
-         .list()
-         .layer(TokenEmbedding(n_in=vocab_size, n_out=d_model,
-                               positional=False)))
     mixer = LatentAttentionMixer(
         n_heads=n_heads, q_rank=q_rank, kv_rank=kv_rank, nope_dim=nope_dim,
         rope_dim=rope_dim, v_dim=v_dim, rope_theta=rope_theta,
@@ -322,19 +305,13 @@ def longcat_configuration(vocab_size: int, d_model: int, n_layers: int, *,
         n_experts=n_experts, n_zero_experts=n_zero_experts, top_k=top_k,
         expert_width=expert_width, experts_held=experts_held,
         scoring="softmax_all", routed_scale=routed_scale)
-    for _ in range(n_layers):
-        b = b.layer(ShortcutDecoderBlock(n_in=d_model, n_out=d_model,
-                                         first=pair(), second=pair(),
-                                         shortcut=shortcut))
-    return (b
-            .layer(RMSNormalization(n_in=d_model, n_out=d_model, eps=eps,
-                                    dropout=0.0))
-            .layer(RnnOutputLayer(n_in=d_model, n_out=vocab_size,
-                                  has_bias=False,
-                                  activation=Activation.SOFTMAX,
-                                  loss=LossFunction.MCXENT, dropout=0.0))
-            .set_input_type(InputType.recurrent(vocab_size))
-            .build())
+    blocks = [ShortcutDecoderBlock(n_in=d_model, n_out=d_model,
+                                   first=pair(), second=pair(),
+                                   shortcut=shortcut)
+              for _ in range(n_layers)]
+    return composed_configuration(
+        vocab_size, d_model, blocks, eps=eps, seed=seed,
+        learning_rate=learning_rate, updater=updater)
 
 
 def deepseek_v2_configuration(vocab_size: int, d_model: int, n_layers: int,
@@ -362,14 +339,6 @@ def deepseek_v2_configuration(vocab_size: int, d_model: int, n_layers: int,
     untied, bias-free output head (the Hugging Face `deepseek_v2`
     family's layout). `experts_held = (first, count)`: the share of each
     routed layer's experts this network holds."""
-    b = (NeuralNetConfiguration.Builder()
-         .seed(seed)
-         .learning_rate(learning_rate)
-         .updater(updater)
-         .drop_out(0.0)
-         .list()
-         .layer(TokenEmbedding(n_in=vocab_size, n_out=d_model,
-                               positional=False)))
     mixer = LatentAttentionMixer(
         n_heads=n_heads, q_rank=q_rank, kv_rank=kv_rank, nope_dim=nope_dim,
         rope_dim=rope_dim, v_dim=v_dim, rope_theta=rope_theta, eps=eps,
@@ -379,19 +348,13 @@ def deepseek_v2_configuration(vocab_size: int, d_model: int, n_layers: int,
         shared_width=shared_width, experts_held=experts_held,
         scoring="softmax_all", routed_scale=routed_scale,
         n_groups=n_groups, topk_groups=topk_groups)
-    for i in range(n_layers):
-        b = b.layer(DecoderBlock(
-            n_in=d_model, n_out=d_model, mixer=mixer, norm=RMSNorm(eps=eps),
-            ffn=GatedMLP(width=ffn_width) if i < n_dense_layers else routed))
-    return (b
-            .layer(RMSNormalization(n_in=d_model, n_out=d_model, eps=eps,
-                                    dropout=0.0))
-            .layer(RnnOutputLayer(n_in=d_model, n_out=vocab_size,
-                                  has_bias=False,
-                                  activation=Activation.SOFTMAX,
-                                  loss=LossFunction.MCXENT, dropout=0.0))
-            .set_input_type(InputType.recurrent(vocab_size))
-            .build())
+    blocks = [DecoderBlock(
+        n_in=d_model, n_out=d_model, mixer=mixer, norm=RMSNorm(eps=eps),
+        ffn=GatedMLP(width=ffn_width) if i < n_dense_layers else routed)
+        for i in range(n_layers)]
+    return composed_configuration(
+        vocab_size, d_model, blocks, eps=eps, seed=seed,
+        learning_rate=learning_rate, updater=updater)
 
 
 # ---------------------------------------------------------------------------

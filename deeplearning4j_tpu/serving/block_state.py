@@ -47,20 +47,38 @@ numbers the engine fixed at build time and the tensor-parallel axis
 (no function: the pool writers `_write_pages` / `_write_token` live
 here, beside `KVPages`, and the speculative draft and verifier import
 them from here too).
+
+What the engine needs to know ABOUT a kind it asks here, and names none
+itself: each class says which `stats()` keys count it (`blocks_key`,
+`token_bytes_key`) and which engine features it cannot hold, in what
+words (`refuses`); K/V pages copy themselves between pools and the host
+(`KVPages.read_pages` / `write_pages`, the first half of ROADMAP M5's
+`export` / `import_` contract: a kind without them cannot be moved).
+`refused` and `describe` sum that up for a plan and its built states, and
+`RoutingAccount` owns a plan's routed-expert counts from the step's
+blocks to the `moe_*` keys of `stats()`.
 """
 from __future__ import annotations
 
 import math
+import threading
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deeplearning4j_tpu.nn.conf.decoder_block import sub
 from deeplearning4j_tpu.nn.conf.layers import TransformerBlock
 from deeplearning4j_tpu.serving.quantize import (
     _write_scale_pages,
+    kv_bytes_per_token,
     quantize_heads,
 )
+
+# the engine features a kind may say it cannot hold (`refuses`): the
+# ones that keep, requantize or carry away what a block caches
+_CACHE_FEATURES = ("prefix_cache", "quantize_kv", "role")
 
 
 class RecurrentStateUnsupported(ValueError):
@@ -118,21 +136,8 @@ def _finish_composed(layer, p, x, mixed, d):
     """The composed block after its mixer; a decode step's `d.counts`
     collects the feed-forward's per-expert counts over active slots."""
     out, counts = layer.finish(p, x, mixed, getattr(d, "count_mask", None))
-    _collect_counts(d, counts)
+    RoutingAccount.collect(d, counts)
     return out
-
-
-def _collect_counts(d, counts) -> None:
-    """A routed feed-forward's `RouteCounts` into the step's lists:
-    per-expert `(2, held)` into `d.counts`, the rows that chose a held
-    expert into `d.rows_local`, and, where the router has zero experts,
-    their scalar into `d.zero_counts`."""
-    if counts is None:
-        return
-    d.counts.append(counts.experts)
-    d.rows_local.append(counts.rows_local)
-    if counts.zero is not None:
-        d.zero_counts.append(counts.zero)
 
 
 def _write_pages(kp_, vp_, kcol, vrow, wpids, woff, page):
@@ -187,14 +192,26 @@ def _write_token(cache, k, v, pids, loff, scales=None):
     return out[:len(cache)]
 
 
-class KVPages:
+class _Kind:
+    """What a kind says of itself where it has nothing to say."""
+    token_bytes_key = None  # the `stats()` key its bytes a token add to
+    refuses = {}  # engine feature -> what of this kind it cannot hold
+
+    def bytes_per_slot(self) -> int:
+        return 0  # pages are held by length, not by slot
+
+
+class KVPages(_Kind):
     kind = "kv"
+    blocks_key, token_bytes_key = "kv_blocks", "kv_bytes_per_token"
 
     def __init__(self, layer, env):
         self.env = env
         self.block = _DenseBlock(layer, env) \
             if isinstance(layer, TransformerBlock) \
             else _ComposedAttention(layer, env)
+        # a hand-off names the pools so; int8 pools bring their scales
+        self.names = ("k", "v", "ks", "vs") if env.kv_quant else ("k", "v")
 
     def alloc(self) -> tuple:
         env, b = self.env, self.block
@@ -213,8 +230,24 @@ class KVPages:
         return (jnp.zeros((P + 1, Hkv, hd, page), env.cdt),
                 jnp.zeros((P + 1, Hkv, page, hd), env.cdt))
 
-    def bytes_per_slot(self) -> int:
-        return 0  # pages are held by length, not by slot
+    def bytes_per_token(self) -> int:
+        return kv_bytes_per_token(
+            [(self.block.kv_heads, self.block.head_dim)], self.env.kv_quant,
+            jnp.dtype(self.env.cdt).itemsize)
+
+    def read_pages(self, cache, page_ids) -> dict:
+        """The pool pages `page_ids` of this block as host arrays, by
+        name."""
+        return {name: np.asarray(jax.device_get(arr[page_ids]))
+                for name, arr in zip(self.names, cache)}
+
+    def write_pages(self, cache, page_ids, block: dict) -> tuple:
+        """The reverse: `block` scattered into the pool pages `page_ids`
+        (eager `.at[].set`, not a donated dispatch: a failure leaves the
+        pools valid); the block's cache, written."""
+        return tuple(
+            arr.at[page_ids].set(jnp.asarray(np.asarray(block[name])))
+            for name, arr in zip(self.names, cache))
 
     def decode(self, p, x, cache, d):
         from deeplearning4j_tpu.ops.attention import (
@@ -317,8 +350,9 @@ class KVPages:
         return x, ((kp_, vp_, ks_, vs_) if env.kv_quant else (kp_, vp_))
 
 
-class RecurrentSlots:
-    kind = "recurrent"
+class RecurrentSlots(_Kind):
+    kind, blocks_key = "recurrent", "recurrent_blocks"
+    refuses = dict.fromkeys(_CACHE_FEATURES, "the recurrent state")
 
     def __init__(self, layer, env):
         self.layer, self.env, self.mixer = layer, env, layer.mixer
@@ -392,12 +426,14 @@ class _ByPhase:
         return self._block("prefill_chunk", p, x, cache, d)
 
 
-class LatentPages(_ByPhase):
+class LatentPages(_ByPhase, _Kind):
     """A block whose mixer is latent attention. The mixing itself is
     `mix_decode` / `mix_prefill` / `mix_prefill_chunk`, `(mixer's
     parameters, its normed input, cache, d) -> (mixed, cache)`, so that a
     block of two mixers (`ShortcutPair`) runs each through its own."""
     kind = "latent"
+    blocks_key, token_bytes_key = "latent_blocks", "latent_bytes_per_token"
+    refuses = dict.fromkeys(_CACHE_FEATURES, "latent pages")
 
     def __init__(self, layer, env):
         self.layer, self.env, self.mixer = layer, env, layer.mixer
@@ -408,9 +444,6 @@ class LatentPages(_ByPhase):
         # +1: page 0 is the reserved trash page for masked writes
         return (jnp.zeros((env.pool_pages + 1, self.kv_rank + self.rope,
                            env.page), env.cdt),)
-
-    def bytes_per_slot(self) -> int:
-        return 0  # pages are held by length, not by slot
 
     def bytes_per_token(self) -> int:
         return (self.kv_rank + self.rope) * jnp.dtype(self.env.cdt).itemsize
@@ -507,12 +540,12 @@ class ShortcutPair(_ByPhase):
 
         out, counts = self.layer.compose(
             p, x, mix(0), mix(1), getattr(d, "count_mask", None))
-        _collect_counts(d, counts)
+        RoutingAccount.collect(d, counts)
         return out, tuple(new)
 
 
-class Stateless:
-    kind = "none"
+class Stateless(_Kind):
+    kind, blocks_key = "none", "stateless_blocks"
 
     def __init__(self, layer, env):
         self.layer = layer
@@ -520,8 +553,11 @@ class Stateless:
     def alloc(self) -> tuple:
         return ()
 
-    def bytes_per_slot(self) -> int:
-        return 0
+    def read_pages(self, cache, page_ids) -> dict:
+        return {}  # nothing kept: nothing to move
+
+    def write_pages(self, cache, page_ids, block: dict) -> tuple:
+        return cache
 
     def decode(self, p, x, cache, d):
         return _finish_composed(self.layer, p, x, None, d), cache
@@ -541,10 +577,38 @@ def block_states(plan, env) -> list:
             for kind, i in zip(plan.state_kinds(), plan.block_is)]
 
 
-def sub_states(states) -> list:
-    """The state objects that keep a cache of their own: a block's, or
-    each of a two-mixer block's."""
-    return [part for st in states for part in getattr(st, "parts", (st,))]
+def refused(plan, asked: dict) -> list:
+    """What the kinds of `plan`'s blocks cannot hold of the engine
+    features `asked` (feature -> the engine's words for it, with
+    `{what}` where the kind's own go), as the phrases of a typed
+    refusal; kind by kind, each in the order asked."""
+    kinds = {k for kind in plan.state_kinds()
+             for k in (kind if isinstance(kind, tuple) else (kind,))}
+    return [words.format(what=cls.refuses[feature])
+            for name, cls in _KINDS.items() if name in kinds
+            for feature, words in asked.items() if feature in cls.refuses]
+
+
+def describe(states, env) -> SimpleNamespace:
+    """What the built `states` say of themselves: `counters`, the keys
+    of `stats()` about the caches (every kind's, 0 where no block is of
+    it; numbers, so that they survive into the Prometheus exposition);
+    whether an admission overwrites per-slot state; and whether every
+    block's pages can be moved (`kv_only`: what the hand-off plane
+    asks)."""
+    c = {key: 0 for cls in _KINDS.values()
+         for key in (cls.blocks_key, cls.token_bytes_key) if key}
+    # a two-mixer block counts once for each cache it keeps
+    for st in (part for st in states for part in getattr(st, "parts", (st,))):
+        c[st.blocks_key] += 1
+        if st.token_bytes_key:
+            c[st.token_bytes_key] += st.bytes_per_token()
+    c["state_bytes_per_slot"] = sum(st.bytes_per_slot() for st in states)
+    c["kv_quant_bits"] = 8 if env.kv_quant \
+        else 8 * jnp.dtype(env.cdt).itemsize  # of the BUILT pools
+    return SimpleNamespace(
+        counters=c, resets_on_admission=c["state_bytes_per_slot"] > 0,
+        kv_only=all(hasattr(st, "read_pages") for st in states))
 
 
 def routed_ffns(plan) -> list:
@@ -556,20 +620,111 @@ def routed_ffns(plan) -> list:
             if isinstance(ffn, MoEFeedForward)]
 
 
-def moe_held(plan) -> int:
-    """How many routed experts each routed block holds (0: the net has
-    none; the blocks that route must agree, since the step returns one
-    count vector; blocks that do not route add nothing to it)."""
-    held = {ffn.held[1] for ffn in routed_ffns(plan)}
-    if len(held) > 1:
-        raise ValueError(
-            f"routed blocks hold different numbers of experts "
-            f"{sorted(held)}: the decode step returns one per-expert "
-            "count vector")
-    return held.pop() if held else 0
+class RoutingAccount:
+    """A plan's routed-expert counts, from the blocks of a decode step
+    to `stats()`: the routers' facts; what a step's namespace carries
+    for counting (`step_fields`), what a routed block adds to it
+    (`collect`) and the entry the step returns of it (`packed`); on the
+    host, that entry added (`add`) to the totals `counters` reports
+    (docs/observability.md says what each counts). Packing and unpacking
+    live here alone, so a new count is one edit. `guard` is the owning
+    engine's lock (the programs' builder makes an account of its own,
+    for the traced side); `before`, the account this one replaces at a
+    swap: the totals are the engine's, the facts follow the plan."""
 
+    def __init__(self, plan, guard=None, before=None):
+        self._cond = guard if guard is not None else threading.Lock()
+        self._ffns = routed_ffns(plan)
+        held = {ffn.held[1] for ffn in self._ffns}
+        if len(held) > 1:
+            raise ValueError(
+                f"routed blocks hold different numbers of experts "
+                f"{sorted(held)}: the decode step returns one per-expert "
+                "count vector")
+        # experts held a routed block (0: the net routes nowhere and the
+        # step returns no counts) and zero-compute experts scored, all
+        # blocks together (0: no count of their choices either)
+        self.held = held.pop() if held else 0
+        self.n_zero = sum(ffn.n_zero_experts for ffn in self._ffns)
+        self.blocks = len(self._ffns)
+        self.top_k = max([ffn.top_k for ffn in self._ffns], default=0)
+        self._width, self._cdt = plan.emb.n_out, plan.cdt
+        self._sorted_rows = {}  # a prefill's rows -> its experts went sorted
+        # decode steps only; `prefill_sorted_n`, of the prefill
+        # dispatches those that went sorted, is the scheduler thread's
+        self.totals = dict(before.totals) if before else dict.fromkeys(
+            ("moe_routed", "moe_held_choices", "moe_experts_hit",
+             "moe_experts_read", "moe_steps", "moe_zero_choices",
+             "moe_rows_local"), 0)  # guarded by: _cond
+        self.prefill_sorted_n = before.prefill_sorted_n if before else 0
 
-def moe_zero_experts(plan) -> int:
-    """How many zero-compute experts the plan's routers score, all
-    blocks together (0: the step returns no count of their choices)."""
-    return sum(ffn.n_zero_experts for ffn in routed_ffns(plan))
+    def step_fields(self, active) -> dict:
+        """The mask of the rows that count (the active slots; None where
+        the net routes nowhere) and the lists routed blocks append to."""
+        return dict(count_mask=active if self.held else None, counts=[],
+                    rows_local=[], zero_counts=[])
+
+    @staticmethod
+    def collect(d, counts) -> None:
+        """A routed feed-forward's `RouteCounts` into the step's lists:
+        per-expert `(2, held)` into `d.counts`, the rows that chose a
+        held expert into `d.rows_local`, and, where the router has zero
+        experts, their scalar into `d.zero_counts`."""
+        if counts is None:
+            return
+        d.counts.append(counts.experts)
+        d.rows_local.append(counts.rows_local)
+        if counts.zero is not None:
+            d.zero_counts.append(counts.zero)
+
+    def packed(self, d) -> tuple:
+        """What the step appends to its outputs: nothing where the net
+        routes nowhere, else ONE entry `(counts (3, held), rows_local[,
+        zero])`: choices that fell on each held expert, summed over
+        blocks, in how many blocks it was hit, and in how many the
+        grouped product was told to read it; how many (active slot,
+        block) rows chose a held expert at all; and, where the routers
+        score zero-compute experts, how many choices fell on those."""
+        if not self.held:
+            return ()
+        chosen, read = jnp.stack(d.counts, axis=1)
+        counts = jnp.stack([chosen.sum(0), (chosen > 0).sum(0),
+                            read.sum(0)]).astype(jnp.int32)
+        return ((counts, sum(d.rows_local))
+                + ((sum(d.zero_counts),) if self.n_zero else ()),)
+
+    # graftlint: hot-loop
+    def add(self, packed, n_live: int) -> None:
+        """One dispatch's `packed` entry, read back: one step's or a
+        chunk's (a leading axis), of `n_live` live slots."""
+        counts, rows_local, *zero = packed
+        counts = np.asarray(counts).reshape(-1, 3, self.held)
+        with self._cond:
+            t = self.totals
+            t["moe_zero_choices"] += int(np.sum(zero))
+            t["moe_rows_local"] += int(np.sum(rows_local))
+            t["moe_routed"] += counts.shape[0] * n_live \
+                * self.top_k * self.blocks
+            t["moe_held_choices"] += int(counts[:, 0].sum())
+            t["moe_experts_hit"] += int(counts[:, 1].sum())
+            t["moe_experts_read"] += int(counts[:, 2].sum())
+            t["moe_steps"] += counts.shape[0]
+
+    def counters(self) -> dict:
+        """`stats()`'s `moe_*` keys: 0 for a net that routes nowhere."""
+        with self._cond:
+            return dict(self.totals,
+                        moe_experts_held=self.held * self.blocks)
+
+    def count_prefill(self, rows: int) -> None:
+        """A prefill program of `rows` rows went out: counted where it
+        runs every routed block's experts through the sorted product
+        (`MoEFeedForward.goes_sorted`; never for a net that routes
+        nowhere); asked after that program's first dispatch, when its
+        kernels' probes have run, and kept."""
+        went = self._sorted_rows.get(rows)
+        if went is None:
+            went = self._sorted_rows[rows] = bool(self._ffns) and all(
+                ffn.goes_sorted(rows, self._width, self._cdt)
+                for ffn in self._ffns)
+        self.prefill_sorted_n += went

@@ -28,6 +28,10 @@ prompts prefilled in **chunks interleaved with decode** (Sarathi-Serve,
           (all three stand on block_state, kv_transfer, prefix_cache,
            models/transformer and ops/, and never import this file)
 
+This file and the programs name no cache kind and no router: what a kind
+keeps, counts, refuses and copies, and what routed blocks count, is
+`block_state`'s to say; `stats()` merges what each part reports.
+
 **The path of a token.** `submit` judges a request at the door (typed
 sheds: `ServerOverloadedError`, `OutOfPagesError`,
 `TenantQuotaExceededError`, `DeadlineExceededError`, breaker) and
@@ -635,19 +639,7 @@ class DecodeEngine:
         self.prefix_hits = 0  # guarded by: _cond
         self.prefix_misses = 0  # guarded by: _cond
         self.prefix_hit_tokens = 0  # guarded by: _cond
-        # routed experts and recurrent state (composed blocks): top-k
-        # choices made by active slots in decode steps, those that fell
-        # on experts held here, held experts hit and held experts the
-        # grouped product was told to read (both summed over blocks and
-        # steps), the steps counted, and slot states overwritten from
-        # zeros at admission
-        self.moe_routed = 0  # guarded by: _cond
-        self.moe_held_choices = 0  # guarded by: _cond
-        self.moe_experts_hit = 0  # guarded by: _cond
-        self.moe_experts_read = 0  # guarded by: _cond
-        self.moe_steps = 0  # guarded by: _cond
-        self.moe_zero_choices = 0  # guarded by: _cond
-        self.moe_rows_local = 0  # guarded by: _cond
+        # slot states overwritten from zeros at admission
         self.state_resets = 0  # guarded by: _cond
         self.spec_steps = 0  # guarded by: _cond
         self.spec_proposed = 0  # guarded by: _cond
@@ -690,7 +682,8 @@ class DecodeEngine:
                 self.metrics.gauge(
                     'decode_engine_tp_shard_kv_bytes_per_token'
                     '{tp_rank="%d"}' % _r,
-                    lambda: self._kv_bytes_per_token // self._tp_degree)
+                    lambda: self._kinds.counters["kv_bytes_per_token"]
+                    // self._tp_degree)
         if self.breaker is not None \
                 and getattr(self.breaker, "on_event", None) is None:
             # standalone engines wire breaker transitions themselves; a
@@ -703,6 +696,7 @@ class DecodeEngine:
         # never the engine
         from deeplearning4j_tpu.serving.kv_handoff import HandoffPlane
         self._pool = None  # the PagePool of the current build
+        self._routing = None  # its RoutingAccount
         self._plane = HandoffPlane(
             self._cond, role=role, handoff_ttl=handoff_ttl,
             recorder=self.recorder, breaker=self.breaker,
@@ -734,7 +728,6 @@ class DecodeEngine:
 
     def _build_in_phases(self, net, ph) -> None:
         import jax
-        import jax.numpy as jnp
 
         from deeplearning4j_tpu.models.transformer import GPTPlan
         from deeplearning4j_tpu.serving import (
@@ -806,12 +799,15 @@ class DecodeEngine:
                               and _qz.int8_kv_enabled()) else None
 
         # what each block keeps between tokens, by the kind the plan
-        # declares for it (serving/block_state.py): paged K/V pools or
-        # per-slot recurrent arrays
-        states = block_state.block_states(plan, SimpleNamespace(
+        # declares for it (serving/block_state.py)
+        env = SimpleNamespace(
             n_slots=S, page=page, pool_pages=pool_pages, cdt=cdt,
-            kv_quant=kv_quant, tp_shard=tp_shard, tp_axis=tp_axis))
-        n_held = block_state.moe_held(plan)
+            kv_quant=kv_quant, tp_shard=tp_shard, tp_axis=tp_axis)
+        states = block_state.block_states(plan, env)
+        # the routed experts' counts: the new plan's facts, the totals
+        # the engine's across swaps
+        routing = block_state.RoutingAccount(plan, self._cond,
+                                             self._routing)
         programs = decode_programs.build_programs(
             plan, states, n_slots=S, page=page, L_logical=L_logical,
             decode_chunk=self.decode_chunk, top_k=self.top_k,
@@ -837,21 +833,8 @@ class DecodeEngine:
         ph.enter("build.plan")
         self._plan = plan
         self._states = states
-        self._n_held = n_held
-        kept = block_state.sub_states(states)
-        self._recurrent = any(st.kind == "recurrent" for st in kept)
-        self._state_bytes_per_slot = sum(st.bytes_per_slot()
-                                         for st in states)
-        # a two-mixer block counts once for each cache it keeps
-        self._blocks_by_kind = collections.Counter(st.kind for st in kept)
-        self._latent_bytes_per_token = sum(
-            st.bytes_per_token() for st in kept if st.kind == "latent")
-        self._n_zero = block_state.moe_zero_experts(plan)
-        routed = block_state.routed_ffns(plan)
-        self._moe_blocks = len(routed)
-        self._moe_top_k = max([ffn.top_k for ffn in routed], default=0)
-        self._routed_ffns = routed
-        self._sorted_rows = {}  # a prefill's rows -> its experts went sorted
+        self._kinds = block_state.describe(states, env)
+        self._routing = routing
         self._net = net
         self.max_len = L
         self.page_size = page
@@ -866,11 +849,6 @@ class DecodeEngine:
         self._decode_chunked = programs.decode_chunked
         self._prefill = programs.prefill
         self._prefill_chunk_fn = programs.prefill_chunk_fn
-        self._kv_quant = kv_quant
-        self._kv_quant_bits = 8 if kv_quant \
-            else 8 * jnp.dtype(cdt).itemsize
-        self._kv_bytes_per_token = _qz.kv_bytes_per_token(
-            plan.kv_geometry(), kv_quant, jnp.dtype(cdt).itemsize)
         # content digest of the served weights, folded on the device
         # (`weight_digest`): KV handoffs are stamped with the sender's
         # digest and refused typed on mismatch — a page of KV computed
@@ -935,48 +913,42 @@ class DecodeEngine:
         self._plane.on_rebuild(
             pool=self._pool, weight_version=self._weight_version,
             kv_quant=kv_quant, max_len=L, n_blocks=len(states),
-            kv_only=not (self._recurrent
-                         or self._blocks_by_kind["latent"]))
+            kv_only=self._kinds.kv_only)
         self._reset_device_state()
 
     def _refuse_unsupported(self, plan) -> None:
-        """Engine features that cannot hold a composed block, or a
-        block with per-slot recurrent state, yet: refused typed when
+        """Engine features that cannot hold a composed block, or what
+        one of the plan's kinds of block keeps, yet: refused typed when
         the engine is built (construction, weight swap), so nothing is
         silently wrong later. Replay (preemption folding emitted tokens
         back into the prompt) needs no state of the old slot and works."""
-        from deeplearning4j_tpu.serving.block_state import (
-            RecurrentStateUnsupported,
-        )
+        from deeplearning4j_tpu.serving import block_state
 
-        if not plan.composed:
-            return
-        asked = []
-        if self._speculative_cfg is not None:
-            asked.append("speculative decoding (draft and verifier assume "
-                         "TransformerBlock K/V)")
-        if self._tp_degree > 1:
-            asked.append("parallel={'tp': N} (no sharding rule for "
-                         "composed blocks)")
-        kinds = {k for kind in plan.state_kinds()
-                 for k in (kind if isinstance(kind, tuple) else (kind,))}
-        for kind, what in (("recurrent", "the recurrent state"),
-                           ("latent", "latent pages")):
-            if kind not in kinds:
-                continue
-            if self._prefix_cache_cfg not in (None, False):
-                asked.append(f"prefix_cache (a hit needs {what} at the "
-                             "shared boundary; only K/V pages are kept)")
-            if self._quantize_cfg and self._quantize_cfg.get("kv"):
-                asked.append("quantize={'kv': 'int8'} (no quantized form "
-                             f"of {what})")
-            if self._role != "both":
-                asked.append(f"role={self._role!r} (KV handoff does not "
-                             f"carry {what})")
-        if asked:
-            raise RecurrentStateUnsupported(
+        refused = []
+        if plan.composed and self._speculative_cfg is not None:
+            refused.append("speculative decoding (draft and verifier "
+                           "assume TransformerBlock K/V)")
+        if plan.composed and self._tp_degree > 1:
+            refused.append("parallel={'tp': N} (no sharding rule for "
+                           "composed blocks)")
+        # what keeps, requantizes or carries away a block's cache, in this
+        # engine's words; a kind that cannot hold one says `{what}` of it
+        asked = {}
+        if self._prefix_cache_cfg not in (None, False):
+            asked["prefix_cache"] = ("prefix_cache (a hit needs {what} at "
+                                     "the shared boundary; only K/V pages "
+                                     "are kept)")
+        if self._quantize_cfg and self._quantize_cfg.get("kv"):
+            asked["quantize_kv"] = ("quantize={{'kv': 'int8'}} (no "
+                                    "quantized form of {what})")
+        if self._role != "both":
+            asked["role"] = (f"role={self._role!r} (KV handoff does not "
+                             "carry {what})")
+        refused += block_state.refused(plan, asked)
+        if refused:
+            raise block_state.RecurrentStateUnsupported(
                 "not supported for this network's blocks yet: "
-                + "; ".join(asked))
+                + "; ".join(refused))
 
     def _reset_device_state(self) -> None:
         """Fresh page pools + page table + per-slot state (construction,
@@ -1775,46 +1747,19 @@ class DecodeEngine:
                "max_queued_pages": self.max_queued_pages,
                "page_fragmentation_pct": round(frag, 1),
                "prefill_chunk": self.prefill_chunk,
-               # quantized-KV tier: numeric (not string) so the keys
-               # survive `_flatten_numeric` into Prometheus exposition;
-               # bits reflect the BUILT pools (kill switch included)
-               "kv_quant_bits": self._kv_quant_bits,
-               "kv_bytes_per_token": self._kv_bytes_per_token,
-               # what a slot holds whatever its length: the recurrent
-               # blocks' state and convolution tails (0: none)
-               "state_bytes_per_slot": self._state_bytes_per_slot,
+               # what the caches say of themselves: bits and bytes a
+               # token and a slot hold, blocks by kind
+               **self._kinds.counters,
                "state_resets": self.state_resets,
-               # how many blocks keep which kind of cache
-               "recurrent_blocks": self._blocks_by_kind["recurrent"],
-               "kv_blocks": self._blocks_by_kind["kv"],
-               "stateless_blocks": self._blocks_by_kind["none"],
-               # sub-layers that keep a paged pool of latents, and what
-               # one position costs in all of them together
-               "latent_blocks": self._blocks_by_kind["latent"],
-               "latent_bytes_per_token": self._latent_bytes_per_token,
-               # routed experts, decode steps only: top-k choices of
-               # active slots, those on experts held here, held experts
-               # hit and held experts the grouped product was told to
-               # read (summed over blocks and steps) and the steps
-               "moe_routed": self.moe_routed,
-               "moe_held_choices": self.moe_held_choices,
-               "moe_experts_hit": self.moe_experts_hit,
-               "moe_experts_read": self.moe_experts_read,
-               "moe_steps": self.moe_steps,
-               # of `moe_routed`, the choices that fell on zero-compute
-               # experts (0: the routers score none)
-               "moe_zero_choices": self.moe_zero_choices,
-               # of the live (slot, routed block) rows, `moe_routed` /
-               # top_k of them, those that chose at least one held
-               # expert: the rows an exchange would bring to this chip
-               "moe_rows_local": self.moe_rows_local,
-               "moe_experts_held": self._n_held * self._moe_blocks,
+               # the routed experts' decode-step counts, `moe_*`
+               **self._routing.counters(),
                # tensor-parallel tier: degree 1 when off, so dashboards
                # can chart capacity without branching on key presence;
                # per-shard KV bytes is the per-chip residency claim
                "tp_degree": self._tp_degree,
                "tp_kv_bytes_per_token_per_shard":
-                   self._kv_bytes_per_token // self._tp_degree,
+                   self._kinds.counters["kv_bytes_per_token"]
+                   // self._tp_degree,
                # QoS control plane: unconditional (zero / empty when
                # qos is off) so dashboards and the stats-schema
                # contract never branch on key presence
@@ -1836,7 +1781,9 @@ class DecodeEngine:
                # the scheduler thread's time by leaf phase, and the
                # admission wait: cumulative, so two readings bracket a
                # window
-               "loop": self._phases.counters(),
+               "loop": dict(
+                   self._phases.counters(),
+                   prefill_sorted_n=self._routing.prefill_sorted_n),
                "queue_wait_s": self.queue_wait_s,
                "admitted": self.admitted,
                # set-up's account, cumulative over builds: seconds and
@@ -2467,7 +2414,7 @@ class DecodeEngine:
             return
         (self._caches, self._tok, self._pos, self._keys,
          self._temps) = out[:5]
-        ph.prefill_sorted_n += self._prefill_goes_sorted(bucket)
+        self._routing.count_prefill(bucket)
         req.in_flight = 1
         with self._cond:
             req.slot = slot
@@ -2481,19 +2428,6 @@ class DecodeEngine:
         self._inflight.append(_InFlight(
             "prefill", [(slot, req)], out[5:], tp0, info,
             draft=(ids, wpids) if self._spec is not None else None))
-
-    def _prefill_goes_sorted(self, rows: int) -> bool:
-        """Whether the prefill program of `rows` rows ran every routed
-        block's experts through the sorted product
-        (`MoEFeedForward.goes_sorted`; False for a net that routes
-        nowhere); asked after that program's first dispatch, when its
-        kernels' probes have run, and kept."""
-        went = self._sorted_rows.get(rows)
-        if went is None:
-            d, cdt = self._plan.emb.n_out, self._plan.cdt
-            went = self._sorted_rows[rows] = bool(self._routed_ffns) and all(
-                ffn.goes_sorted(rows, d, cdt) for ffn in self._routed_ffns)
-        return went
 
     # graftlint: hot-loop
     def _collect_prefill(self, rec: _InFlight) -> None:
@@ -2533,7 +2467,7 @@ class DecodeEngine:
             with self._cond:
                 self.prefills += 1
                 self.tokens_generated += 1
-                self.state_resets += int(self._recurrent)
+                self.state_resets += int(self._kinds.resets_on_admission)
                 # a one-shot prefill grounds the SLO estimator as a
                 # single chunk observation (same dispatch scale as a
                 # chunk): the time the chip had it to itself
@@ -2657,10 +2591,11 @@ class DecodeEngine:
             self._prefill_failure(slot, req, e, attached=True)
             return
         self._hook("post_prefill", info)
-        ph.prefill_sorted_n += self._prefill_goes_sorted(W)
+        self._routing.count_prefill(W)
         with self._cond:
             self.prefill_chunks += 1
-            self.state_resets += int(self._recurrent and off == 0)
+            self.state_resets += int(self._kinds.resets_on_admission
+                                     and off == 0)
             self._chunk_ewma = 0.8 * self._chunk_ewma + 0.2 * (tp1 - tp0)
         if not final:
             req.prefill_pos = off + C
@@ -2796,32 +2731,26 @@ class DecodeEngine:
                     float(temp_))
             pages = pages[:min(-(-regs[0] // self.page_size), len(pages))]
         jidx = jnp.asarray(np.asarray(pages, np.int32))
-        names = ("k", "v", "ks", "vs") if self._kv_quant else ("k", "v")
-        blocks = [{name: np.asarray(jax.device_get(arr[jidx]))
-                   for name, arr in zip(names, c)} for c in self._caches]
+        blocks = [st.read_pages(c, jidx)
+                  for st, c in zip(self._states, self._caches)]
         return regs, blocks, len(pages)
 
     # graftlint: hot-loop
     def _write_slot(self, slot: Optional[int], pages: List[int],
                     blocks: List[dict], regs) -> None:
-        """The reverse of `_read_slot`: scatter `blocks` into the pool
-        pages `pages` (eager `.at[].set`, not a donated dispatch: a
-        failure leaves the pools valid) and, for a slot, restore its
-        registers. Drained first, like `_read_slot`."""
+        """The reverse of `_read_slot`: each block writes its part of
+        `blocks` into the pool pages `pages` and, for a slot, its
+        registers are restored. Drained first, like `_read_slot`."""
         import jax.numpy as jnp
 
         self._drain()
         jidx = jnp.asarray(np.asarray(pages, np.int32))
-        names = ("k", "v", "ks", "vs") if self._kv_quant else ("k", "v")
         new_caches = []
-        for blk, c in zip(blocks, self._caches):
-            new_c = []
-            for name, arr in zip(names, c):
-                out = arr.at[jidx].set(jnp.asarray(np.asarray(blk[name])))
-                if self._tp is not None:
-                    out = self._tp.shard_pool(out)
-                new_c.append(out)
-            new_caches.append(tuple(new_c))
+        for st, blk, c in zip(self._states, blocks, self._caches):
+            c = st.write_pages(c, jidx, blk)
+            if self._tp is not None:
+                c = tuple(self._tp.shard_pool(arr) for arr in c)
+            new_caches.append(c)
         self._caches = new_caches
         if regs is not None:
             pos, tok, key, temp = regs
@@ -3173,26 +3102,6 @@ class DecodeEngine:
         return True
 
     # graftlint: hot-loop
-    def _count_experts(self, counts, n_live: int) -> None:
-        """One dispatch's routing counts (`step_math`): (..., 3, held),
-        choices that fell on each held expert, in how many blocks each
-        was hit and in how many the grouped product was told to read
-        it, for one step or a chunk of them; beside it the (slot,
-        block) rows that chose a held expert and, where the routers
-        score zero-compute experts, the choices that fell on those."""
-        counts, rows_local, *zero = counts
-        counts = np.asarray(counts).reshape(-1, 3, self._n_held)
-        with self._cond:
-            self.moe_zero_choices += int(np.sum(zero))
-            self.moe_rows_local += int(np.sum(rows_local))
-            self.moe_routed += counts.shape[0] * n_live \
-                * self._moe_top_k * self._moe_blocks
-            self.moe_held_choices += int(counts[:, 0].sum())
-            self.moe_experts_hit += int(counts[:, 1].sum())
-            self.moe_experts_read += int(counts[:, 2].sum())
-            self.moe_steps += counts.shape[0]
-
-    # graftlint: hot-loop
     def _step_active(self) -> None:
         """One decode dispatch ahead: issue the next program for every
         slot that still has tokens to ask for, and only then wait for,
@@ -3250,12 +3159,13 @@ class DecodeEngine:
             self._decode_failure(live, e)
             return
         self._caches, self._tok, self._pos, self._keys = out[:4]
-        # after the state: (toks,) oks[, logprobs][, counts]
+        # after the state: (toks,) oks[, logprobs][, the routed
+        # blocks' counts, as their account packed them]
         rest = list(out[4:])
         toks_d = rest.pop(0) if chunked else self._tok
         oks_d = rest.pop(0)
         lps_d = rest.pop(0) if self._logprobs_k else None
-        counts_d = rest.pop(0) if self._n_held else None
+        counts_d = rest.pop(0) if rest else None
         page, width = self.page_size, self._n_pages_max
         for _, r in live:
             # the slot's position at the dispatch's first step, from
@@ -3292,7 +3202,7 @@ class DecodeEngine:
                 lambda: jax.device_get(rec.handles))
             ph.enter("decode.deliver")
             if counts is not None:
-                self._count_experts(counts, len(live))
+                self._routing.add(counts, len(live))
             self._hook("post_decode", rec.info)
         # graftlint: disable=typed-error  converts to a typed failure:
         # _decode_failure wraps the cause in InferenceFailedError for the

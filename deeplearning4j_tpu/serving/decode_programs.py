@@ -96,8 +96,9 @@ def build_programs(plan, states, *, n_slots: int, page: int,
     S, K = n_slots, logprobs
     emb_i, block_is = plan.emb_i, plan.block_is
     emb, cdt = plan.emb, plan.cdt
-    n_held = block_state.moe_held(plan)
-    n_zero = block_state.moe_zero_experts(plan)
+    # the traced side of the routed blocks' counts: what the step's
+    # namespace carries for them and what the step returns of it
+    account = block_state.RoutingAccount(plan)
 
     def _shard(fn, n_in, n_out):
         """Identity on one device; under TP the body becomes the
@@ -127,10 +128,8 @@ def build_programs(plan, states, *, n_slots: int, page: int,
             loff=wpos % page,
             # inactive lanes write to the reserved trash page 0
             pids=jnp.where(active, page_table[rows, lpage], 0),
-            # per-expert counts of the active slots' choices, where
-            # the net routes
-            count_mask=active if n_held else None, counts=[],
-            rows_local=[], zero_counts=[])
+            # the active slots' choices are counted, where the net routes
+            **account.step_fields(active))
         new_caches = []
         for bi, i in enumerate(block_is):
             x, cache = states[bi].decode(bp[i], x, caches[bi], d)
@@ -145,19 +144,7 @@ def build_programs(plan, states, *, n_slots: int, page: int,
         out = (new_caches, nxt, new_pos, new_keys, step_ok)
         if K:
             out += (token_logprobs(logits, nxt, K),)
-        if n_held:
-            # (3, held): choices that fell on each held expert,
-            # summed over blocks, in how many blocks it was hit, and
-            # in how many the grouped product was told to read it
-            chosen, read = jnp.stack(d.counts, axis=1)
-            counts = jnp.stack([chosen.sum(0), (chosen > 0).sum(0),
-                                read.sum(0)]).astype(jnp.int32)
-            # beside it, how many (active slot, block) rows chose a
-            # held expert at all and, where the routers score
-            # zero-compute experts, how many choices fell on those
-            out += ((counts, sum(d.rows_local))
-                    + ((sum(d.zero_counts),) if n_zero else ()),)
-        return out
+        return out + account.packed(d)
 
     # the chunk scans the step's body, not the jitted program the
     # name is rebound to below

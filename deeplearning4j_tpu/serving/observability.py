@@ -934,15 +934,13 @@ class SchedulerPhases(ThreadPhases):
         # steps, and those slots x steps x the page table's width
         self.kv_pages_walked = 0
         self.kv_pages_table = 0
-        # of the `prefill.dispatch` phases, those whose routed blocks'
-        # rows went through the sorted expert product
-        self.prefill_sorted_n = 0
 
     def counters(self) -> dict:
         """The phases' `<phase>_s`, `<phase>_n` and ``{"iterations",
         "sink_s", "sink_n", "ahead_n", "drained_n", "overshoot_tokens",
-        "kv_pages_walked", "kv_pages_table", "prefill_sorted_n",
-        "spans_dropped"}``."""
+        "kv_pages_walked", "kv_pages_table", "spans_dropped"}`` (the
+        engine's `stats()["loop"]` adds `prefill_sorted_n`, which its
+        routing account keeps)."""
         out = {"iterations": self.iterations}
         out.update(super().counters())
         out["sink_s"] = self.sink_s
@@ -952,7 +950,6 @@ class SchedulerPhases(ThreadPhases):
         out["overshoot_tokens"] = self.overshoot_tokens
         out["kv_pages_walked"] = self.kv_pages_walked
         out["kv_pages_table"] = self.kv_pages_table
-        out["prefill_sorted_n"] = self.prefill_sorted_n
         out["spans_dropped"] = self._timeline.dropped
         return out
 

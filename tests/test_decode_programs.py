@@ -4,7 +4,10 @@
   and no thread, and its programs compute what the engine's do;
 - the modules the engine stands on never import it back;
 - `block_state`'s `env` carries numbers and the tp plan, no function;
-- the scheduler reaches the hand-off plane at one place an iteration.
+- the scheduler reaches the hand-off plane at one place an iteration;
+- the scheduler and the programs name no cache kind and no router: what a
+  kind keeps, counts, refuses and copies is `block_state`'s to say, and
+  `stats()` is what it was before that moved.
 """
 import ast
 import inspect
@@ -183,6 +186,188 @@ def test_the_scheduler_reaches_the_plane_at_one_place_an_iteration():
                .parameters.values() if p.kind is p.KEYWORD_ONLY]
     assert len(options) == 25
     assert "prefill_chunk_budget" not in {p.name for p in options}
+
+
+@pytest.mark.parametrize("module", ["decode_engine", "decode_programs"])
+def test_the_scheduler_and_the_programs_name_no_kind_and_no_router(module):
+    """Read with `ast`: comments and docstrings do not count. `"kv"`
+    stays: it is the key of the `quantize` option."""
+    named = []
+    for node in ast.walk(ast.parse((SERVING / f"{module}.py").read_text())):
+        if isinstance(node, ast.Constant) \
+                and node.value in ("recurrent", "latent", "ks", "vs"):
+            named.append((node.lineno, repr(node.value)))
+        elif isinstance(node, ast.Attribute) and (
+                node.attr == "kind"
+                or node.attr.startswith(("moe_", "_moe_"))
+                or node.attr in ("_n_held", "_n_zero", "_routed_ffns",
+                                 "_sorted_rows", "_recurrent",
+                                 "_blocks_by_kind", "moe_held",
+                                 "moe_zero_experts")):
+            named.append((node.lineno, "." + node.attr))
+    assert not named, f"{module}.py names {named}"
+
+
+def test_the_phase_clock_keeps_no_routers_verdict():
+    from deeplearning4j_tpu.serving import observability
+
+    phases = observability.SchedulerPhases()
+    assert not hasattr(phases, "prefill_sorted_n")
+    assert "prefill_sorted_n" not in phases.counters()
+    eng = DecodeEngine(_dense_net(), n_slots=S, max_len=L, page_size=PAGE)
+    try:
+        loop = eng.stats()["loop"]
+    finally:
+        eng.shutdown(1.0)
+    assert loop["prefill_sorted_n"] == 0
+    assert set(phases.counters()) | {"prefill_sorted_n"} == set(loop)
+
+
+def test_a_kind_made_here_is_refused_for_what_it_declares(monkeypatch):
+    """A fifth kind edits `block_state.py` only: here, not even that."""
+    class PagesOfATest(block_state.KVPages):
+        refuses = {"prefix_cache": "pages made in a test"}
+
+    monkeypatch.setitem(block_state._KINDS, "kv", PagesOfATest)
+    net = _dense_net()
+    with pytest.raises(
+            block_state.RecurrentStateUnsupported,
+            match=r"^not supported for this network's blocks yet: "
+                  r"prefix_cache \(a hit needs pages made in a test at the "
+                  r"shared boundary; only K/V pages are kept\)$"):
+        DecodeEngine(net, n_slots=S, max_len=L, page_size=PAGE,
+                     prefix_cache=True)
+    eng = DecodeEngine(net, n_slots=S, max_len=L, page_size=PAGE,
+                       quantize={"kv": "int8"})
+    try:
+        assert {type(st) for st in eng._states} == {PagesOfATest}
+        st = eng.stats()
+    finally:
+        eng.shutdown(1.0)
+    assert st["kv_blocks"] == 2 and st["kv_quant_bits"] == 8
+
+
+# `stats()` of the parent commit (PR 44) after one 6-token prompt and 6
+# tokens, as `{type name: keys}`: the same for a dense, a hybrid routed, a
+# latent and a stateless-block net, whatever moved behind `block_state`
+PARENT_STATS = {
+    "int": """active_slots admitted cluster_prefix_hit_tokens decode_steps
+        failures handoff_leases handoffs_aborted handoffs_committed
+        handoffs_expired handoffs_unfetched kv_blocks kv_bytes_per_token
+        kv_quant_bits kv_transfer_bytes latent_blocks latent_bytes_per_token
+        max_len max_queued_pages migrations_in migrations_out
+        moe_experts_held moe_experts_hit moe_experts_read moe_held_choices
+        moe_routed moe_rows_local moe_steps moe_zero_choices n_slots
+        page_size pages_in_use pages_in_use_peak pool_pages preemptions
+        prefill_chunk prefill_chunks prefills prefix_exports
+        prefix_fetch_bytes prefix_fetch_fallbacks prefix_fetches queued
+        queued_page_demand recurrent_blocks served shed_deadline
+        shed_out_of_pages shed_overload shed_page_quota shed_quota
+        shed_unavailable slo_sheds state_bytes_per_slot state_resets
+        stateless_blocks submitted swaps tokens_generated tp_degree
+        tp_kv_bytes_per_token_per_shard weight_casts
+        weights_resident_bytes""".split(),
+    "float": """cluster_prefix_hit_tokens_pct page_fragmentation_pct
+        prefix_fetch_ms queue_wait_s slot_occupancy_pct""".split(),
+    "list": ["prompt_buckets"],
+    "dict": ["build", "compile", "loop", "tenants"],
+}
+PARENT_NESTED = {
+    "build": {
+        "int": """build.plan_n build.state_n build.weight_hash_n
+            build.weights_n builds weight_hash_bytes
+            weight_hash_host_bytes""".split(),
+        "float": """build.plan_s build.state_s build.weight_hash_s
+            build.weights_s""".split()},
+    "compile": {
+        "int": """backend_n cache_hits cache_load_n cache_misses lower_n
+            trace_n""".split(),
+        "float": "backend_s cache_load_s lower_s trace_s".split(),
+        "dict": ["by_fun"]},
+    "loop": {
+        "int": """admit_n ahead_n decode.deliver_n decode.dispatch_n
+            decode.wait_n drained_n housekeeping_n iterations kv_pages_table
+            kv_pages_walked overshoot_tokens prefill.deliver_n
+            prefill.dispatch_n prefill.wait_n prefill_sorted_n sink_n
+            spans_dropped wait-work_n""".split(),
+        "float": """admit_s decode.deliver_s decode.dispatch_s decode.wait_s
+            housekeeping_s prefill.deliver_s prefill.dispatch_s
+            prefill.wait_s sink_s wait-work_s""".split()},
+    "tenants": {},
+}
+# and, of the keys that moved, the parent's numbers
+_DENSE = dict(
+    kv_quant_bits=32, kv_bytes_per_token=512, state_bytes_per_slot=0,
+    state_resets=0, recurrent_blocks=0, kv_blocks=2, stateless_blocks=0,
+    latent_blocks=0, latent_bytes_per_token=0, moe_routed=0,
+    moe_held_choices=0, moe_experts_hit=0, moe_experts_read=0, moe_steps=0,
+    moe_zero_choices=0, moe_rows_local=0, moe_experts_held=0,
+    tp_kv_bytes_per_token_per_shard=512)
+PARENT_NUMBERS = {
+    "dense": _DENSE,
+    "routed": dict(
+        _DENSE, kv_bytes_per_token=256, state_bytes_per_slot=20224,
+        state_resets=1, recurrent_blocks=2, kv_blocks=1, moe_routed=30,
+        moe_held_choices=23, moe_experts_hit=23, moe_experts_read=23,
+        moe_steps=5, moe_rows_local=15, moe_experts_held=12,
+        tp_kv_bytes_per_token_per_shard=256),
+    "latent": dict(
+        _DENSE, kv_bytes_per_token=0, kv_blocks=0, latent_blocks=4,
+        latent_bytes_per_token=320, moe_routed=30, moe_held_choices=17,
+        moe_experts_hit=17, moe_experts_read=17, moe_steps=5,
+        moe_zero_choices=13, moe_rows_local=10, moe_experts_held=16,
+        tp_kv_bytes_per_token_per_shard=0),
+    "stateless": dict(
+        _DENSE, state_bytes_per_slot=11264, state_resets=1,
+        recurrent_blocks=2, kv_blocks=1, stateless_blocks=2, moe_routed=20,
+        moe_held_choices=20, moe_experts_hit=20, moe_experts_read=20,
+        moe_steps=5, moe_rows_local=10, moe_experts_held=16),
+}
+
+
+def _by_type(d: dict) -> dict:
+    out = {}
+    for k, v in d.items():
+        out.setdefault(type(v).__name__, set()).add(k)
+    return out
+
+
+def _sets(by_type: dict) -> dict:
+    return {t: set(keys) for t, keys in by_type.items()}
+
+
+def _latent_net():
+    import test_longcat_flash as longcat
+
+    return longcat._build(longcat._config())[3]
+
+
+def _stateless_net():
+    import test_nemotron_h as nemotron
+
+    return nemotron._build(nemotron._config())[3]
+
+
+@pytest.mark.parametrize("name,make_net", [
+    ("dense", _dense_net), ("routed", _composed_net),
+    ("latent", _latent_net), ("stateless", _stateless_net)])
+def test_stats_are_the_parents_keys_types_and_numbers(name, make_net):
+    from deeplearning4j_tpu.serving import observability
+
+    eng = DecodeEngine(make_net(), n_slots=S, max_len=L, page_size=PAGE,
+                       pool_pages=POOL, prompt_buckets=(8, 16),
+                       prefill_chunk=16, decode_chunk=4)
+    try:
+        eng.generate(np.asarray([4, 9, 2, 30, 11, 7], np.int32), n_tokens=6,
+                     timeout=300.0)
+        st = eng.stats()
+    finally:
+        eng.shutdown(2.0)
+    assert _by_type(st) == _sets(PARENT_STATS)
+    for key, want in PARENT_NESTED.items():
+        assert _by_type(st[key]) == _sets(want), key
+    assert observability.DECODE_ENGINE_STATS_KEYS <= set(st)
+    assert {k: st[k] for k in PARENT_NUMBERS[name]} == PARENT_NUMBERS[name]
 
 
 def test_sampling_helpers_greedy_finite_screen_and_logprobs():
