@@ -60,8 +60,16 @@ repo supports — `gpt_configuration(vocab 256, d_model 1024, 8 heads of
   each under YaRN, group 0 of 8 groups of 20 experts 1536 wide held,
   3 groups reached, top-6, two shared experts; a bucket of 2,048 tokens,
   so that the prompt goes through the `mla_prefill` kernel and the sorted
-  expert product; the pools are counted from the net's mixers, so the one stage
-  serves both families.
+  expert product. Then (`latent_kda`) at Ling-3.0-flash's published
+  widths: a delta-rule layer with a decay a key channel (32 heads of
+  128 x 128) under the dense MLP and a 32-head latent-attention layer with
+  full-rank queries and a gate a head under group 0 of 8 groups of 64
+  sigmoid-routed experts 768 wide, 4 groups reached, top-8: recurrent
+  slots AND latent pages in one net, the `kda_step` kernel engaged, and
+  the XLA run on the XLA form of the step too. The blocks of each cache
+  kind are counted from the net's mixers (`_blocks_by_state`), here and
+  in `linear`, so the one stage serves the three families and a net with
+  both kinds passes both.
 - **lstm**: `lstm_large` (H=1024, T=64, B=2048) with the fused cell.
 - **multichip** (>= 4 devices): the train step through `ParallelWrapper`
   on a {data 2, model 2} mesh against the one-chip loss, tp=4 decode
@@ -193,6 +201,36 @@ LATENT_H128 = dict(vocab_size=256, hidden_size=5120, num_hidden_layers=2,
 LATENT_H128_SERVE = dict(n_slots=64, max_len=4096, page_size=128,
                          prefill_chunk=256, n_short=3, short_len=2048,
                          long_len=2304, n_tokens=24)
+# Ling-3.0-flash's published widths under its config's own keys
+# (`perfbench/families/ling_flash.py` reads them), a period of two so
+# that two layers hold one of each mixer: a KDA layer under the leading
+# dense MLP, a gated MLA layer under the routed experts, group 0 of the
+# 8 groups of 64 held as in the benchmark cell
+LATENT_KDA = dict(vocab_size=256, hidden_size=2560, num_hidden_layers=2,
+                  layer_group_size=2, first_k_dense_replace=1,
+                  hidden_act="silu", intermediate_size=6144,
+                  moe_intermediate_size=768,
+                  moe_shared_expert_intermediate_size=768,
+                  num_shared_experts=1, num_attention_heads=32,
+                  head_dim=128, short_conv_kernel_size=4,
+                  kda_lower_bound=-5, kda_safe_gate=True, no_kda_lora=True,
+                  linear_silu=True, group_norm_size=1, q_lora_rank=None,
+                  kv_lora_rank=512, qk_nope_head_dim=128,
+                  qk_rope_head_dim=64, v_head_dim=128, rope_theta=6000000,
+                  rope_scaling=None, rope_interleave=True,
+                  gated_attention_proj_granularity_type="head_wise",
+                  num_experts=64, n_group=8, topk_group=4,
+                  num_experts_per_tok=8, topk_method="noaux_tc",
+                  score_function="sigmoid", norm_topk_prob=True,
+                  moe_router_enable_expert_bias=True,
+                  routed_scaling_factor=2.5,
+                  expert_swiglu_limit_list=[0, 0],
+                  share_expert_swiglu_limit_list=[0, 0],
+                  rms_norm_eps=1e-6, use_bias=False, use_qkv_bias=False,
+                  tie_word_embeddings=False,
+                  deployment=dict(num_experts_published=512,
+                                  experts_held_first=0))
+LATENT_KDA_SERVE = ROUTED_SERVE
 LSTM = dict(vocab=256, hidden=1024, T=64, batch=2048, steps=2)
 
 # A greedy token may differ between two correct attention paths only
@@ -676,6 +714,15 @@ def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
     return out
 
 
+def _blocks_by_state(net) -> collections.Counter:
+    """The net's mixers by the cache state each declares (`"kv"`,
+    `"recurrent"`, `"latent"`): what the engine's block counts must
+    say, whatever the family puts in a layer."""
+    return collections.Counter(
+        mixer.state for layer in net.layers if hasattr(layer, "mixers")
+        for mixer in layer.mixers())
+
+
 def _check_recurrent_run(stats: dict, shape: dict, n_prompts: int) -> None:
     """The long prompt rode chunked prefill, and every admission
     overwrote its slot's recurrent state."""
@@ -848,11 +895,11 @@ def phase_linear(lin: dict, shape: dict, *, kernels: bool,
     toks, stats = _through_engine(net, prompts, n_tokens, **gen)
     _check_tokens(toks, n_tokens, vocab, stats, len(prompts), "linear")
     _check_recurrent_run(stats, shape, len(prompts))
-    n_linear = sz["layer_types"].count("linear_attention")
-    _check(stats["recurrent_blocks"] == n_linear
-           and stats["kv_blocks"] == sz["L"] - n_linear,
+    blocks = _blocks_by_state(net)
+    _check(stats["recurrent_blocks"] == blocks["recurrent"] > 0
+           and stats["kv_blocks"] == blocks["kv"],
            f"blocks by cache kind: {stats['recurrent_blocks']} recurrent, "
-           f"{stats['kv_blocks']} K/V of {sz['layer_types']}")
+           f"{stats['kv_blocks']} K/V of the net's {dict(blocks)}")
     out = {"requests": len(prompts), "tokens": int(sum(map(len, toks))),
            "prefill_chunks": stats["prefill_chunks"],
            "decode_steps": stats["decode_steps"],
@@ -1001,7 +1048,9 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
     """A net whose mixers are latent attention, of the benchmark's
     `family` (`longcat_flash`: two sub-layers a layer and zero-compute
     experts; `deepseek_v2`: one, under YaRN, behind device-limited
-    routing), through the engine, against the family's reference and
+    routing; `ling_flash`: one layer of it beside a delta-rule layer
+    with a decay a key channel, recurrent slots and latent pages in one
+    net), through the engine, against the family's reference and
     against its own XLA forms."""
     import importlib
 
@@ -1028,12 +1077,16 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
     _check(stats["prefill_chunks"] >= n_chunks,
            f"long prompt did not ride chunked prefill: "
            f"{stats['prefill_chunks']} chunks < {n_chunks}")
-    # one pool a latent mixer, whatever the family puts in a layer
-    n_mixers = sum(len(layer.mixers()) for layer in net.layers
-                   if hasattr(layer, "mixers"))
-    _check(stats["latent_blocks"] == n_mixers > 0,
-           f"{stats['latent_blocks']} pools of latent pages for the "
-           f"net's {n_mixers} latent mixers")
+    # one pool a latent mixer and one set of slot arrays a recurrent
+    # one, whatever the family puts in a layer
+    blocks = _blocks_by_state(net)
+    _check(stats["latent_blocks"] == blocks["latent"] > 0
+           and stats["recurrent_blocks"] == blocks["recurrent"],
+           f"blocks by cache kind: {stats['latent_blocks']} latent, "
+           f"{stats['recurrent_blocks']} recurrent of the net's "
+           f"{dict(blocks)}")
+    if blocks["recurrent"]:
+        _check_recurrent_run(stats, shape, len(prompts))
     routed = max(1, stats["moe_routed"])
     zero = stats["moe_zero_choices"] / routed
     want = sz.get("Z", 0) / (sz["E"] + sz.get("Z", 0))
@@ -1049,6 +1102,8 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
            "decode_steps": stats["decode_steps"],
            "latent_blocks": stats["latent_blocks"],
            "latent_bytes_per_token": stats["latent_bytes_per_token"],
+           "recurrent_blocks": stats["recurrent_blocks"],
+           "state_bytes_per_slot": stats["state_bytes_per_slot"],
            "zero_share_of_choices": round(zero, 4),
            "held_share_of_choices": round(
                stats["moe_held_choices"] / routed, 4),
@@ -1067,12 +1122,15 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
            f"served tokens lie {out['reference_gaps']} under the "
            f"reference's best logit")
 
-    # the same prompts on gather-and-attend and the scatter
-    os.environ["DL4J_TPU_NO_PALLAS_MLA_ATTEND"] = "1"
+    # the same prompts on gather-and-attend and the scatter (and, where
+    # the net has a delta-rule layer, on the XLA form of its step)
+    off = ("DL4J_TPU_NO_PALLAS_MLA_ATTEND", "DL4J_TPU_NO_PALLAS_GDN_STEP")
+    os.environ.update(dict.fromkeys(off, "1"))
     try:
         xla, xla_stats = _through_engine(net, prompts, n_tokens, **gen)
     finally:
-        del os.environ["DL4J_TPU_NO_PALLAS_MLA_ATTEND"]
+        for name in off:
+            del os.environ[name]
     _check_tokens(xla, n_tokens, vocab, xla_stats, len(prompts), "xla-mla")
     out["agreement"] = _agreement(net, prompts, toks, xla,
                                   "kernel and XLA latent attention")
@@ -1098,14 +1156,21 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
             key = ("bfloat16", rows, sz["d"], sz["f"])
             _check(engaged("moe_experts", lambda k: k == key),
                    f"grouped expert kernel did not engage for {key}")
+        if blocks["recurrent"]:
+            key = ("bfloat16", sz["lh"], sz["lk"], sz["lv"])
+            _check(engaged("kda_step", lambda k: k == key),
+                   f"channel-gated delta step kernel did not engage for "
+                   f"{key}")
         out.update(_sorted_prefills(net, shape, stats))
-        bucket, mixer = shape["short_len"], net.layers[1].mixers()[0]
+        bucket = shape["short_len"]
+        mixer = next(m for layer in net.layers if hasattr(layer, "mixers")
+                     for m in layer.mixers() if m.state == "latent")
         if mixer.query_block(bucket) < bucket:
             _check(engaged("mla_prefill", lambda k: True),
                    f"the prefill kernel did not serve the {bucket}-token "
                    "prompt")
         _check(not any(out["pool_layout_copies"].values()),
-               f"the decode programs copy their pools: "
+               f"the decode programs copy their state or pools: "
                f"{out['pool_layout_copies']}")
     return out
 
@@ -1264,6 +1329,8 @@ def main(argv=None) -> int:
             run("latent", phase_latent, LATENT, LATENT_SERVE, kernels=True)
             run("latent_h128", phase_latent, LATENT_H128,
                 LATENT_H128_SERVE, kernels=True, family="deepseek_v2")
+            run("latent_kda", phase_latent, LATENT_KDA, LATENT_KDA_SERVE,
+                kernels=True, family="ling_flash")
         if "lstm" in names:
             run("lstm", phase_lstm, LSTM, kernels=True)
         if "multichip" in names:

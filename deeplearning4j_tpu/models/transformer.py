@@ -28,6 +28,7 @@ from deeplearning4j_tpu.nn.conf import (
 )
 from deeplearning4j_tpu.nn.conf.decoder_block import (
     AttentionMixer,
+    ChannelGatedDeltaMixer,
     DecoderBlock,
     GatedDeltaNetMixer,
     GatedMLP,
@@ -350,6 +351,61 @@ def deepseek_v2_configuration(vocab_size: int, d_model: int, n_layers: int,
         n_groups=n_groups, topk_groups=topk_groups)
     blocks = [DecoderBlock(
         n_in=d_model, n_out=d_model, mixer=mixer, norm=RMSNorm(eps=eps),
+        ffn=GatedMLP(width=ffn_width) if i < n_dense_layers else routed)
+        for i in range(n_layers)]
+    return composed_configuration(
+        vocab_size, d_model, blocks, eps=eps, seed=seed,
+        learning_rate=learning_rate, updater=updater)
+
+
+def ling_flash_configuration(vocab_size: int, d_model: int, n_layers: int,
+                             *, layer_group_size: int, n_heads: int,
+                             kv_rank: int, nope_dim: int, rope_dim: int,
+                             v_dim: int, rope_theta: float = 6e6,
+                             linear_heads: int, linear_key_dim: int,
+                             linear_value_dim: int, linear_conv: int = 4,
+                             gate_lower_bound: float = -5.0,
+                             n_dense_layers: int = 2, ffn_width: int,
+                             n_experts: int, top_k: int, expert_width: int,
+                             shared_width: int = 0,
+                             routed_scale: float = 1.0, n_groups: int = 1,
+                             topk_groups: int = 1, experts_held=None,
+                             eps: float = 1e-6, seed: int = 12345,
+                             learning_rate: float = 3e-4,
+                             updater: Updater = Updater.ADAM,
+                             ) -> MultiLayerConfiguration:
+    """Causal LM of `n_layers` pre-norm `DecoderBlock`s, a mixer and a
+    feed-forward each. Layer `l`'s mixer is latent attention (MLA) with
+    full-rank queries and a sigmoid gate a head where `(l + 1) %
+    layer_group_size == 0`, else a delta-rule layer with a decay a key
+    channel under a gate bounded by `gate_lower_bound` (KDA): with 6,
+    five linear layers to one full. The first `n_dense_layers`
+    feed-forwards are a dense gated-silu MLP of `ffn_width`, the rest
+    `n_experts` routed gated-silu experts, `top_k` a token chosen on
+    sigmoid scores plus a correction bias among the token's
+    `topk_groups` best of `n_groups` groups, gates the unbiased scores
+    normalised to sum `routed_scale`, plus a shared MLP of
+    `shared_width`; rotary on the latent attention's rope dimensions
+    only, RMSNorm, no positional layer, one trailing norm and an untied,
+    bias-free output head (the Hugging Face `bailing_hybrid` family's
+    layout: Ling-3.0-flash). `experts_held = (first, count)`: the share
+    of each routed layer's experts this network holds."""
+    full = LatentAttentionMixer(
+        n_heads=n_heads, q_rank=None, kv_rank=kv_rank, nope_dim=nope_dim,
+        rope_dim=rope_dim, v_dim=v_dim, rope_theta=rope_theta, eps=eps,
+        head_gate=True)
+    linear = ChannelGatedDeltaMixer(
+        n_heads=linear_heads, key_dim=linear_key_dim,
+        value_dim=linear_value_dim, d_conv=linear_conv, eps=eps,
+        gate_lower_bound=gate_lower_bound)
+    routed = MoEFeedForward(
+        n_experts=n_experts, top_k=top_k, expert_width=expert_width,
+        shared_width=shared_width, experts_held=experts_held,
+        scoring="sigmoid", routed_scale=routed_scale, n_groups=n_groups,
+        topk_groups=topk_groups)
+    blocks = [DecoderBlock(
+        n_in=d_model, n_out=d_model, norm=RMSNorm(eps=eps),
+        mixer=full if (i + 1) % layer_group_size == 0 else linear,
         ffn=GatedMLP(width=ffn_width) if i < n_dense_layers else routed)
         for i in range(n_layers)]
     return composed_configuration(

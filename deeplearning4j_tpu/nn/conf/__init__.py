@@ -24,6 +24,7 @@ from deeplearning4j_tpu.nn.conf.layers import (  # noqa: F401
     TiedRnnOutputLayer,
 )
 from deeplearning4j_tpu.nn.conf.decoder_block import (  # noqa: F401
+    ChannelGatedDeltaMixer,
     AttentionMixer,
     DecoderBlock,
     LatentAttentionMixer,

@@ -18,7 +18,8 @@ must keep for it, `state`:
     "kv"         paged key/value pools, one position a token
                  (`AttentionMixer`; `kv_geometry` gives heads and width)
     "recurrent"  per-slot arrays of fixed size, overwritten in place
-                 (`Mamba2Mixer`, `GatedDeltaNetMixer`; `state_shapes`
+                 (`Mamba2Mixer`, `GatedDeltaNetMixer`,
+                 `ChannelGatedDeltaMixer`; `state_shapes`
                  gives them: the state, slot axis first, then the
                  convolution tail, tap-major)
 
@@ -259,7 +260,13 @@ class LatentAttentionMixer(_Kind):
     gives; scores `(q_n.k_n + q_r.k_r) / sqrt(nope_dim + rope_dim)`,
     causal, float32 softmax; `o = concat_h(P v_h) Wo`. `scale_q_lora` /
     `scale_kv_lora` switch `s_q = sqrt(d / q_rank)` and `s_kv = sqrt(d /
-    kv_rank)` on (else 1).
+    kv_rank)` on (else 1). `q_rank` None: FULL-RANK queries, `[q_n |
+    q_r]_h = x Wq` with no latent and no norm between (`Wq` held as its
+    nope and rope columns `Wqn`, `Wqr`, (d, H * nope) and (d, H * rope)).
+    `head_gate`: a sigmoid gate a head on the heads' outputs before
+    `Wo`, `o_h <- o_h * sigmoid((x Wa)_h)`, `Wa` (d, H), `x` the mixer's
+    input (the published `head_wise` gated attention); off, nothing of
+    it is traced.
 
     Three forms of the same attention, held to one another by
     `tests/test_latent_attention.py`: `forward` (expanded keys and
@@ -273,7 +280,7 @@ class LatentAttentionMixer(_Kind):
     KIND = "latent_attention"
     state = "latent"
     n_heads: int = 4
-    q_rank: int = 32
+    q_rank: Optional[int] = 32   # None: full-rank queries
     kv_rank: int = 16
     nope_dim: int = 8
     rope_dim: int = 4
@@ -283,10 +290,14 @@ class LatentAttentionMixer(_Kind):
     scale_kv_lora: bool = False
     eps: float = 1e-5
     rope_scaling: Optional[YarnScaling] = None
+    head_gate: bool = False
 
     def __post_init__(self):
         object.__setattr__(self, "rope_scaling",
                            kind_from_json(self.rope_scaling))
+        if self.q_rank is None and self.scale_q_lora:
+            raise ValueError("scale_q_lora scales a query latent: there "
+                             "is none with q_rank None")
 
     def latent_geometry(self) -> Tuple[int, int]:
         return self.kv_rank, self.rope_dim
@@ -309,11 +320,16 @@ class LatentAttentionMixer(_Kind):
         rope columns, `Wkvc` / `Wkr` the latent's and the rope key's,
         `Wkb` (H, nope, kv_rank) / `Wvb` (H, kv_rank, v) a head's key
         and value expansions."""
-        H, qr, kr = self.n_heads, self.q_rank, self.kv_rank
-        k = jax.random.split(key, 8)
+        H, kr = self.n_heads, self.kv_rank
+        k = jax.random.split(key, 9)
         kvw = H * (self.nope_dim + self.v_dim)
-        return {"Wqa": winit(k[0], (d, qr), d, qr),
-                "qn_w": jnp.ones((qr,), dtype),
+        # full-rank queries read the stream itself: no `Wqa`, no `qn_w`
+        qr = d if self.q_rank is None else self.q_rank
+        low = {} if self.q_rank is None else {
+            "Wqa": winit(k[0], (d, qr), d, qr),
+            "qn_w": jnp.ones((qr,), dtype)}
+        gate = {"Wa": winit(k[8], (d, H), d, H)} if self.head_gate else {}
+        return {**low, **gate,
                 "Wqn": winit(k[1], (qr, H * self.nope_dim), qr,
                              H * (self.nope_dim + self.rope_dim)),
                 "Wqr": winit(k[2], (qr, H * self.rope_dim), qr,
@@ -327,7 +343,7 @@ class LatentAttentionMixer(_Kind):
                 "Wo": winit(k[7], (H * self.v_dim, d), H * self.v_dim, d)}
 
     def _lora_scale(self, p, on: bool, rank: int) -> float:
-        return math.sqrt(p["Wqa"].shape[0] / rank) if on else 1.0
+        return math.sqrt(p["Wkvc"].shape[0] / rank) if on else 1.0
 
     def _s_kv(self, p) -> float:
         return self._lora_scale(p, self.scale_kv_lora, self.kv_rank)
@@ -363,10 +379,13 @@ class LatentAttentionMixer(_Kind):
         what a position's cache holds)."""
         H = self.n_heads
         with jax.named_scope("mla.q"):
-            cq = rms_norm(x @ p["Wqa"], p["qn_w"], self.eps)
-            cq = cq * jnp.asarray(
-                self._lora_scale(p, self.scale_q_lora, self.q_rank),
-                cq.dtype)
+            if self.q_rank is None:
+                cq = x
+            else:
+                cq = rms_norm(x @ p["Wqa"], p["qn_w"], self.eps)
+                cq = cq * jnp.asarray(
+                    self._lora_scale(p, self.scale_q_lora, self.q_rank),
+                    cq.dtype)
             q_n = (cq @ p["Wqn"]).reshape(*x.shape[:-1], H, self.nope_dim)
             q_r = self._rope(
                 (cq @ p["Wqr"]).reshape(*x.shape[:-1], H, self.rope_dim),
@@ -386,12 +405,24 @@ class LatentAttentionMixer(_Kind):
                 * jnp.asarray(self._s_kv(p), q_n.dtype)
             return jnp.concatenate([qt, q_r], axis=-1)
 
-    def out(self, p, u):
+    def _gated(self, p, o, x):
+        """The heads' outputs `o` (..., H, v) under the head gate of the
+        mixer's input `x` (..., d); as they are where there is none."""
+        if not self.head_gate:
+            return o
+        with jax.named_scope("mla.gate"):
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, p["Wa"], preferred_element_type=jnp.float32))
+            return o * gate[..., None].astype(o.dtype)
+
+    def out(self, p, u, x=None):
         """Latent-space values `u` (..., H, kv_rank), `sum_s p c(s)` a
-        head, -> (..., d): up through `s_kv W^V_h`, then `Wo`."""
+        head, -> (..., d): up through `s_kv W^V_h`, under the head gate
+        of the mixer's input `x` where the mixer has one, then `Wo`."""
         with jax.named_scope("mla.out"):
             o = jnp.einsum("...hr,hrv->...hv", u, p["Wvb"]) \
                 * jnp.asarray(self._s_kv(p), u.dtype)
+            o = self._gated(p, o, x)
             return o.reshape(*u.shape[:-2], -1) @ p["Wo"]
 
     def attend_latents(self, q_abs, latents, q_pos):
@@ -440,13 +471,14 @@ class LatentAttentionMixer(_Kind):
             heads_first(v), n_valid, sm_scale=self.sm_scale)
         return None if o is None else jnp.swapaxes(o, 0, 1)[None]
 
-    def attend_expanded(self, p, q_n, q_r, latent, n_valid=None):
+    def attend_expanded(self, p, q_n, q_r, latent, n_valid=None, x=None):
         """One sequence's queries (B, T, H, .) against its own latents
         (B, T, kv_rank + rope), keys and values expanded per head:
         causal, float32 softmax; where the heads' scores over the whole
         prompt pass `_SCORE_BYTES`, through the prefill kernel (which
         leaves the rows from `n_valid` on, a bucket's padding, zeros) or
-        by blocks of `query_block(T)` queries. Returns (B, T, d)."""
+        by blocks of `query_block(T)` queries; `x` (B, T, d) the mixer's
+        input, which the head gate reads. Returns (B, T, d)."""
         c, k_r = latent[..., :self.kv_rank], latent[..., self.kv_rank:]
         with jax.named_scope("mla.kv_up"):
             cs = c * jnp.asarray(self._s_kv(p), c.dtype)
@@ -490,6 +522,7 @@ class LatentAttentionMixer(_Kind):
                     [attend(t0, min(t0 + block, T))
                      for t0 in range(0, T, block)], axis=1)
         with jax.named_scope("mla.out"):
+            o = self._gated(p, o, x)
             return o.reshape(*o.shape[:2], -1) @ p["Wo"]
 
     def forward(self, p, x, positions=None):
@@ -497,7 +530,7 @@ class LatentAttentionMixer(_Kind):
         `positions` (T,))."""
         if positions is None:
             positions = jnp.arange(x.shape[1])
-        return self.attend_expanded(p, *self.project(p, x, positions))
+        return self.attend_expanded(p, *self.project(p, x, positions), x=x)
 
 
 @_kind
@@ -649,6 +682,7 @@ class GatedDeltaNetMixer(_Kind):
     per-slot matrix state (float32, `(d_k, H * d_v)`: no lane of it is
     padding) and the convolution's last inputs."""
     KIND = "gated_delta_net"
+    SCOPE = "gdn"  # the prefix of the mixer's named scopes
     state = "recurrent"
     n_heads: int = 4
     key_dim: int = 8
@@ -657,10 +691,17 @@ class GatedDeltaNetMixer(_Kind):
     chunk: int = 64
     allow_neg_eigval: bool = False
     eps: float = 1e-6
+    _out_gate = staticmethod(jax.nn.silu)  # on the gate, after the norm
 
     @property
     def qk_width(self) -> int:
         return self.n_heads * self.key_dim
+
+    @property
+    def decay_width(self) -> int:
+        """The in-projection's columns the log decay is made of: one a
+        head."""
+        return self.n_heads
 
     @property
     def v_width(self) -> int:
@@ -680,20 +721,23 @@ class GatedDeltaNetMixer(_Kind):
     def init_params(self, key, d: int, dtype, winit) -> dict:
         cw, vw, H = self.conv_width, self.v_width, self.n_heads
         k = jax.random.split(key, 5)
-        width = cw + vw + 2 * H
+        width = cw + vw + self.decay_width + H
         return {"Win": winit(k[0], (d, width), d, width),
                 "conv_w": (jax.random.normal(k[1], (cw, self.d_conv))
                            / math.sqrt(self.d_conv)).astype(dtype),
-                **_decay_params(k[3], k[4], H, dtype),
+                **self._decay_params(k[3], k[4], dtype),
                 "norm_w": jnp.ones((self.value_dim,), dtype),
                 "Wout": winit(k[2], (vw, d), vw, d)}
 
+    def _decay_params(self, k_dt, k_a, dtype) -> dict:
+        return _decay_params(k_dt, k_a, self.n_heads, dtype)
+
     def _split_in(self, p, x):
-        with jax.named_scope("gdn.in_proj"):
+        with jax.named_scope(f"{self.SCOPE}.in_proj"):
             z = x @ p["Win"]
-        cw, vw, H = self.conv_width, self.v_width, self.n_heads
+        cw, vw, dw = self.conv_width, self.v_width, self.decay_width
         return (z[..., :cw], z[..., cw:cw + vw],
-                z[..., cw + vw:cw + vw + H], z[..., cw + vw + H:])
+                z[..., cw + vw:cw + vw + dw], z[..., cw + vw + dw:])
 
     def _heads(self, qkv):
         """silu(conv) output (..., Cw) -> q, k (..., H, d_k) float32,
@@ -710,12 +754,14 @@ class GatedDeltaNetMixer(_Kind):
                 unit(qkv[..., qw:2 * qw]),
                 qkv[..., 2 * qw:].reshape(*lead, H, self.value_dim))
 
+    def _beta(self, b_raw):
+        beta = jax.nn.sigmoid(b_raw.astype(jnp.float32))
+        return 2.0 * beta if self.allow_neg_eigval else beta
+
     def _gates(self, p, a_raw, b_raw, keep=None):
         """(g, beta) float32; where `keep` is False both are 0 and the
         slot's state stays as it was."""
-        beta = jax.nn.sigmoid(b_raw.astype(jnp.float32))
-        if self.allow_neg_eigval:
-            beta = 2.0 * beta
+        beta = self._beta(b_raw)
         g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
             a_raw.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
         if keep is None:
@@ -723,10 +769,10 @@ class GatedDeltaNetMixer(_Kind):
         return jnp.where(keep, g, 0.0), jnp.where(keep, beta, 0.0)
 
     def _finish(self, p, o, gate):
-        with jax.named_scope("gdn.gate_norm"):
+        with jax.named_scope(f"{self.SCOPE}.gate_norm"):
             y = rms_norm(o, p["norm_w"], self.eps).reshape(gate.shape) \
-                * jax.nn.silu(gate)
-        with jax.named_scope("gdn.out_proj"):
+                * self._out_gate(gate)
+        with jax.named_scope(f"{self.SCOPE}.out_proj"):
             return y @ p["Wout"]
 
     def scan(self, p, x, h0=None, tail=None, n_valid=None):
@@ -736,11 +782,11 @@ class GatedDeltaNetMixer(_Kind):
         move neither the state nor the tail. Returns (out (B, T, d),
         state, tail)."""
         qkv, gate, a_raw, b_raw = self._split_in(p, x)
-        with jax.named_scope("gdn.conv"):
+        with jax.named_scope(f"{self.SCOPE}.conv"):
             qkv, tail = ssm.causal_conv(qkv, p["conv_w"], None, tail,
                                         n_valid)
             qkv = jax.nn.silu(qkv)
-        with jax.named_scope("gdn.scan"):
+        with jax.named_scope(f"{self.SCOPE}.scan"):
             o, h = delta_rule.delta_chunked(
                 *self._heads(qkv), *self._gates(p, a_raw, b_raw),
                 chunk=self.chunk, h0=h0, n_valid=n_valid)
@@ -749,28 +795,83 @@ class GatedDeltaNetMixer(_Kind):
     def step(self, p, x, h, tail, active=None):
         """One token for every slot: `x` (S, d), `h` (S, d_k, H * d_v),
         `tail` (K - 1, S, Cw). Slots that `active` (S,) bool leaves out
-        keep state and tail as they are."""
+        keep state and tail as they are. The update goes through the
+        kernel of the decay's shape where it serves
+        (`ops/pallas_delta_step.py`)."""
         from deeplearning4j_tpu.ops.pallas_delta_step import (
-            gdn_step_or_none,
+            delta_step_or_none,
         )
 
         qkv, gate, a_raw, b_raw = self._split_in(p, x)
-        with jax.named_scope("gdn.conv"):
+        with jax.named_scope(f"{self.SCOPE}.conv"):
             qkv, new_tail = ssm.conv_step(qkv, p["conv_w"], None, tail)
             qkv = jax.nn.silu(qkv)
             if active is not None:
                 new_tail = jnp.where(active[None, :, None], new_tail,
                                      tail)
         keep = None if active is None else active[:, None]
-        with jax.named_scope("gdn.step"):
+        with jax.named_scope(f"{self.SCOPE}.step"):
             args = (h, *self._heads(qkv),
                     *self._gates(p, a_raw, b_raw, keep))
-            out = gdn_step_or_none(*args)
+            out = delta_step_or_none(*args)
             o, h = delta_rule.delta_step(*args) if out is None else out
         return self._finish(p, o, gate), h, new_tail.astype(tail.dtype)
 
     def forward(self, p, x):
         return self.scan(p, x)[0]
+
+
+@_kind
+@dataclass(frozen=True)
+class ChannelGatedDeltaMixer(GatedDeltaNetMixer):
+    """The delta-rule layer with a decay a KEY CHANNEL (Kimi Delta
+    Attention, arXiv:2510.26692, as the `fla` layer writes it with
+    full-rank projections): `GatedDeltaNetMixer`'s in-projection,
+    convolution, L2-normed q and k and `beta = sigmoid(b)`, and in place
+    of one decay a head the vector `g_h = lower * sigmoid(exp(A_h) *
+    (f_h + bias_h))` over head h's `key_dim` channels (`f` a projection
+    of its own, `H * d_k` wide; `A` a number a head, `bias` one a
+    channel; `gate_lower_bound` = `lower` < 0 bounds every log decay in
+    (lower, 0), the "safe gate": `ops/delta_rule.py` says what the
+    bound buys the chunked form), the recurrence of `ops/delta_rule.py`
+    with that vector, and an RMSNorm per head over d_v whose output a
+    SIGMOID gate multiplies, a channel each. The in-projection is
+    [q | k | v | gate | f | b]; state, tail, `scan` and `step` are
+    `GatedDeltaNetMixer`'s, under the scopes `kda.*`."""
+    KIND = "channel_gated_delta"
+    SCOPE = "kda"
+    gate_lower_bound: float = -5.0
+    _out_gate = staticmethod(jax.nn.sigmoid)
+
+    def __post_init__(self):
+        if not self.gate_lower_bound < 0:
+            raise ValueError(f"gate_lower_bound {self.gate_lower_bound}: "
+                             "a log decay's bound lies below 0")
+
+    @property
+    def decay_width(self) -> int:
+        return self.qk_width
+
+    def _decay_params(self, k_dt, k_a, dtype) -> dict:
+        return {"A_log": _decay_params(k_dt, k_a, self.n_heads,
+                                       dtype)["A_log"],
+                "dt_bias": jnp.zeros((self.qk_width,), dtype)}
+
+    def _gates(self, p, f_raw, b_raw, keep=None):
+        """(g (..., H, d_k), beta (..., H)) float32; where `keep` is
+        False both are 0 and the slot's state stays as it was."""
+        H, dk = self.n_heads, self.key_dim
+        with jax.named_scope("kda.gate"):
+            beta = self._beta(b_raw)
+            f = (f_raw.astype(jnp.float32)
+                 + p["dt_bias"].astype(jnp.float32)) \
+                .reshape(*f_raw.shape[:-1], H, dk)
+            g = self.gate_lower_bound * jax.nn.sigmoid(
+                jnp.exp(p["A_log"].astype(jnp.float32))[:, None] * f)
+            if keep is None:
+                return g, beta
+            return jnp.where(keep[..., None], g, 0.0), \
+                jnp.where(keep, beta, 0.0)
 
 
 # ------------------------------------------------------- feed-forward kinds
@@ -819,10 +920,12 @@ class MoEFeedForward(_Kind):
     normalised to sum 1, times `routed_scale`; or "softmax_all", chosen
     on a softmax over all the router's outputs (`n_zero_experts` of them
     zero-compute experts after the real ones) and weighed by that score
-    times `routed_scale`. `n_groups` > 1 (with "softmax_all"): the
-    experts lie in that many equal groups and a token chooses among its
-    `topk_groups` best groups only, a group scored by its largest score
-    (device-limited routing: `parallel.experts.group_limited`)."""
+    times `routed_scale`. `n_groups` > 1 (with "softmax_all" or
+    "sigmoid"): the experts lie in that many equal groups and a token
+    chooses among its `topk_groups` best groups only, a group scored by
+    its largest score under "softmax_all" and by the sum of its two
+    largest biased scores under "sigmoid" (device-limited routing:
+    `parallel.experts.group_limited`)."""
     KIND = "moe"
     n_experts: int = 8
     top_k: int = 2

@@ -28,10 +28,28 @@ beside 2 x 2.2 MB of state), and `k`, `q` as one transposed `(S, d_k,
 float32 on the vector unit: a slot with `b = 0, a = 1` keeps its state
 bit for bit, as in the XLA form.
 
-Dispatch rides `ops/kernel_dispatch.py` under the family name
-`gdn_step`: the probe compiles and runs the kernel at the exact shape
-class and holds it to `delta_step`; `DL4J_TPU_NO_PALLAS_GDN_STEP` forces
-the XLA form; CPU backends never dispatch.
+**The decay's two shapes, two kernels.** `gdn_step` takes `g` (S, H),
+one decay a head: `a` rides the expanded row operand above. `kda_step`
+takes `g` (S, H, d_k), one decay a KEY CHANNEL (`ops/delta_rule.py`):
+
+    S' = S Diag(a) + (b (v - S Diag(a) k)) k^T;   o = S' q
+
+A key channel is a ROW of the resident tile, so the decay arrives the way
+`k` and `q` do, as a third block of columns of the transposed operand,
+`(S, d_k, 3 H)`: column 2 H + h is head h's `exp(g)`, broadcast along
+its head's lanes exactly as its key is; the row operand keeps `v` and
+`b`, `(S, 2, H / group, group * d_v)`. The tile is decayed once (`m *
+a`), and the rest is the scalar kernel's arithmetic on the decayed tile.
+At 32 heads of 128 x 128 a slot's state is `(128, 4096)` float32, whole
+lane tiles with one head a group, 2.1 MB in VMEM. Each is a static
+variant of its own, traced and probed under its own jitted entry, so a
+program lowers each once and a device trace names each.
+
+Dispatch rides `ops/kernel_dispatch.py` under the family names
+`gdn_step` and `kda_step`: the probe compiles and runs the kernel at the
+exact shape class and holds it to `delta_step`;
+`DL4J_TPU_NO_PALLAS_GDN_STEP` forces the XLA form of both; CPU backends
+never dispatch.
 """
 from __future__ import annotations
 
@@ -48,7 +66,8 @@ from deeplearning4j_tpu.ops.kernel_dispatch import (
     vmem_limit_bytes as _vmem_limit,
 )
 
-FAMILY = "gdn_step"  # this module's row in kernel_verdicts()
+FAMILY = "gdn_step"  # this module's rows in kernel_verdicts(): one decay
+KDA_FAMILY = "kda_step"  # a head, and one a key channel
 F32 = jnp.float32
 
 
@@ -58,6 +77,22 @@ def _group(dv: int) -> int:
     return 1 if dv % 128 == 0 else 2 if (2 * dv) % 128 == 0 else 0
 
 
+def _columns(cols, dk: int, dv: int, group: int):
+    """`column(c, p)`: column(s) `c` of group `p`'s heads of the
+    transposed operand `cols` (d_k, n H), each along its own head's
+    lanes, (d_k, group * d_v)."""
+    W = group * dv
+    first = jax.lax.broadcasted_iota(jnp.int32, (dk, W), 1) < dv
+
+    def column(c, p):
+        if group == 1:
+            return jnp.broadcast_to(cols[:, c + p:c + p + 1], (dk, W))
+        return jnp.where(first, cols[:, c + 2 * p:c + 2 * p + 1],
+                         cols[:, c + 2 * p + 1:c + 2 * p + 2])
+
+    return column
+
+
 def _step_kernel(kq_ref, vab_ref, s_ref, o_ref, s_out_ref, *, H: int,
                  dv: int, group: int):
     """Grid (S,): slot `s` owns its whole state tile. `kq_ref`
@@ -65,15 +100,7 @@ def _step_kernel(kq_ref, vab_ref, s_ref, o_ref, s_out_ref, *, H: int,
     `vab_ref` (1, 3, H / group, group * d_v): v, a, b by group row."""
     dk = s_ref.shape[1]
     W = group * dv
-    kq = kq_ref[0]
-    first = jax.lax.broadcasted_iota(jnp.int32, (dk, W), 1) < dv
-
-    def column(c, p):
-        """Column(s) `c` of the group's heads along their own lanes."""
-        if group == 1:
-            return jnp.broadcast_to(kq[:, c + p:c + p + 1], (dk, W))
-        return jnp.where(first, kq[:, c + 2 * p:c + 2 * p + 1],
-                         kq[:, c + 2 * p + 1:c + 2 * p + 2])
+    column = _columns(kq_ref[0], dk, dv, group)
 
     for p in range(H // group):
         m = s_ref[0, :, p * W:(p + 1) * W]                    # (dk, W)
@@ -87,31 +114,44 @@ def _step_kernel(kq_ref, vab_ref, s_ref, o_ref, s_out_ref, *, H: int,
         o_ref[0, p:p + 1, :] = jnp.sum(m * qx, axis=0, keepdims=True)
 
 
-# jitted so that a step over many layers traces and lowers the kernel
-# once and calls it once a layer (`pallas_paged_kv_write`'s lesson)
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def gdn_step(state, q, k, v, g, beta, *, interpret: bool = False):
-    """`delta_step` as one in-place kernel call (same arguments, same
-    return)."""
+def _channel_kernel(kqa_ref, vb_ref, s_ref, o_ref, s_out_ref, *, H: int,
+                    dv: int, group: int):
+    """`_step_kernel` with a decay a key channel. `kqa_ref` (1, d_k,
+    3H): column h is head h's key, H + h its query, 2H + h its decay
+    `exp(g)`; `vb_ref` (1, 2, H / group, group * d_v): v, b by group
+    row."""
+    dk = s_ref.shape[1]
+    W = group * dv
+    column = _columns(kqa_ref[0], dk, dv, group)
+
+    for p in range(H // group):
+        m = s_ref[0, :, p * W:(p + 1) * W] * column(2 * H, p)  # (dk, W)
+        kx, qx = column(0, p), column(H, p)
+        v = vb_ref[0, 0, p:p + 1, :]                           # (1, W)
+        b = vb_ref[0, 1, p:p + 1, :]
+        sk = jnp.sum(m * kx, axis=0, keepdims=True)
+        m = m + kx * (b * (v - sk))
+        s_out_ref[0, :, p * W:(p + 1) * W] = m
+        o_ref[0, p:p + 1, :] = jnp.sum(m * qx, axis=0, keepdims=True)
+
+
+def _call(kernel, state, cols, rows, H: int, interpret: bool):
+    """One grid step a slot over the aliased state: `cols` (S, d_k, n H)
+    the transposed column operand, `rows` (S, r, H / group, group * d_v)
+    the expanded rows. Returns (o (S, H, d_v) float32, state)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     S, dk, HV = state.shape
-    H = q.shape[1]
     dv = HV // H
     G = _group(dv)
     P, W = H // G, G * dv
-    kq = jnp.concatenate([jnp.swapaxes(k.astype(F32), 1, 2),
-                          jnp.swapaxes(q.astype(F32), 1, 2)], axis=2)
-    rows = jnp.stack(
-        [v.astype(F32).reshape(S, P, W)]
-        + [jnp.repeat(x.astype(F32), dv, axis=1).reshape(S, P, W)
-           for x in (jnp.exp(g.astype(F32)), beta)], axis=1)
     o, state = pl.pallas_call(
-        functools.partial(_step_kernel, H=H, dv=dv, group=G),
+        functools.partial(kernel, H=H, dv=dv, group=G),
         grid=(S,),
-        in_specs=[pl.BlockSpec((1, dk, 2 * H), lambda s: (s, 0, 0)),
-                  pl.BlockSpec((1, 3, P, W), lambda s: (s, 0, 0, 0)),
+        in_specs=[pl.BlockSpec((1, dk, cols.shape[2]), lambda s: (s, 0, 0)),
+                  pl.BlockSpec((1, rows.shape[1], P, W),
+                               lambda s: (s, 0, 0, 0)),
                   pl.BlockSpec((1, dk, HV), lambda s: (s, 0, 0))],
         out_specs=[pl.BlockSpec((1, P, W), lambda s: (s, 0, 0)),
                    pl.BlockSpec((1, dk, HV), lambda s: (s, 0, 0))],
@@ -122,8 +162,47 @@ def gdn_step(state, q, k, v, g, beta, *, interpret: bool = False):
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
-    )(kq, rows, state)
-    return o.reshape(S, H, dv).astype(v.dtype), state
+    )(cols, rows, state)
+    return o.reshape(S, H, dv), state
+
+
+def _transposed(*xs):
+    """(S, H, d_k) operands side by side as columns, (S, d_k, n H)."""
+    return jnp.concatenate([jnp.swapaxes(x.astype(F32), 1, 2) for x in xs],
+                           axis=2)
+
+
+# jitted so that a step over many layers traces and lowers the kernel
+# once and calls it once a layer (`pallas_paged_kv_write`'s lesson)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gdn_step(state, q, k, v, g, beta, *, interpret: bool = False):
+    """`delta_step` with one decay a head (`g` (S, H)) as one in-place
+    kernel call (same arguments, same return)."""
+    S, H = q.shape[:2]
+    dv = state.shape[2] // H
+    shape = (S, H // _group(dv), _group(dv) * dv)
+    cols = _transposed(k, q)
+    rows = jnp.stack(
+        [v.astype(F32).reshape(shape)]
+        + [jnp.repeat(x.astype(F32), dv, axis=1).reshape(shape)
+           for x in (jnp.exp(g.astype(F32)), beta)], axis=1)
+    o, state = _call(_step_kernel, state, cols, rows, H, interpret)
+    return o.astype(v.dtype), state
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kda_step(state, q, k, v, g, beta, *, interpret: bool = False):
+    """`delta_step` with one decay a key channel (`g` (S, H, d_k)) as
+    one in-place kernel call (same arguments, same return)."""
+    S, H = q.shape[:2]
+    dv = state.shape[2] // H
+    shape = (S, H // _group(dv), _group(dv) * dv)
+    cols = _transposed(k, q, jnp.exp(g.astype(F32)))
+    rows = jnp.stack(
+        [v.astype(F32).reshape(shape),
+         jnp.repeat(beta.astype(F32), dv, axis=1).reshape(shape)], axis=1)
+    o, state = _call(_channel_kernel, state, cols, rows, H, interpret)
+    return o.astype(v.dtype), state
 
 
 def vmem_bytes_estimate(H: int, dk: int, dv: int) -> int:
@@ -137,9 +216,11 @@ def _platform_supported() -> bool:
     return _kernels_dispatch("DL4J_TPU_NO_PALLAS_GDN_STEP")
 
 
-def _eager_probe(dtype, H: int, dk: int, dv: int) -> bool:
+def _eager_probe(dtype, H: int, dk: int, dv: int,
+                 channels: bool = False) -> bool:
     """Compile and run the kernel at this shape class (three slots, one
-    of them inactive) and hold it to `delta_step`."""
+    of them inactive) and hold it to `delta_step`; `channels`: the
+    kernel with a decay a key channel."""
     import numpy as np
 
     rng = np.random.default_rng(0)
@@ -149,10 +230,14 @@ def _eager_probe(dtype, H: int, dk: int, dv: int) -> bool:
             for _ in range(2))
     v = jnp.asarray(rng.standard_normal((S, H, dv)), dtype)
     live = np.array([1.0, 1.0, 0.0])[:, None]
-    g = jnp.asarray(-rng.random((S, H)) * live, F32)
     beta = jnp.asarray(2.0 * rng.random((S, H)) * live, F32)
+    if channels:
+        g = jnp.asarray(-5.0 * rng.random((S, H, dk)) * live[..., None], F32)
+    else:
+        g = jnp.asarray(-rng.random((S, H)) * live, F32)
     want_o, want_s = delta_step(state, q, k, v, g, beta)
-    got_o, got_s = gdn_step(state + 0.0, q, k, v, g, beta)
+    got_o, got_s = (kda_step if channels else gdn_step)(
+        state + 0.0, q, k, v, g, beta)
     if not bool(jnp.array_equal(got_s[2], state[2])):
         raise ValueError("kernel compiled but moved an inactive slot's "
                          "state")
@@ -165,33 +250,37 @@ def _eager_probe(dtype, H: int, dk: int, dv: int) -> bool:
     return True
 
 
-def gdn_step_or_none(state, q, k, v, g, beta):
-    """Dispatch probe: the step as the kernel computes it, or None when
-    the kernel cannot serve this call (CPU backend, kill switch, head
-    sizes off the tile grid, VMEM overflow) or its shape class failed
-    the compile+parity probe."""
+def delta_step_or_none(state, q, k, v, g, beta):
+    """Dispatch probe: the step through the kernel of `g`'s shape
+    (`gdn_step` for (S, H), `kda_step` for (S, H, d_k)), or None when it
+    cannot serve this call (CPU backend, kill switch, head sizes off the
+    tile grid, VMEM overflow) or its shape class failed the
+    compile+parity probe."""
     if not _platform_supported() or state.dtype != F32:
         return None
+    channels = g.ndim == 3
+    family = KDA_FAMILY if channels else FAMILY
     S, dk, HV = state.shape
     H = q.shape[1]
     dv = HV // H
     key = (jnp.dtype(v.dtype).name, H, dk, dv)
     G = _group(dv)
     if not G or H % G or dk % 8:
-        _record_decline(FAMILY, key, f"{H} heads of {dk} x {dv}: off the "
+        _record_decline(family, key, f"{H} heads of {dk} x {dv}: off the "
                                      "(8, 128) tile grid")
         return None
     est = vmem_bytes_estimate(H, dk, dv)
     if est > _vmem_limit():
-        _record_decline(FAMILY, key,
+        _record_decline(family, key,
                         f"needs ~{est >> 20} MiB VMEM > "
                         f"{_vmem_limit() >> 20} MiB ceiling")
         return None
-    if not _probe_verdict(FAMILY, key, _eager_probe, (v.dtype, H, dk, dv)):
+    if not _probe_verdict(family, key, _eager_probe,
+                          (v.dtype, H, dk, dv, channels)):
         return None
     try:
-        return gdn_step(state, q, k, v, g, beta)
+        return (kda_step if channels else gdn_step)(state, q, k, v, g, beta)
     except Exception as e:  # per-shape staging failure: fall back
-        _record_decline(FAMILY, key, f"staging at {state.shape}: "
+        _record_decline(family, key, f"staging at {state.shape}: "
                                      f"{type(e).__name__}: {e}")
         return None

@@ -285,36 +285,50 @@ def topk_gates(logits: jnp.ndarray, top_k: int) -> jnp.ndarray:
 
 
 def sigmoid_topk_gates(logits: jnp.ndarray, bias: jnp.ndarray, top_k: int,
-                       scale: float) -> jnp.ndarray:
+                       scale: float, n_groups: int = 1,
+                       topk_groups: int = 1) -> jnp.ndarray:
     """(N, E) router logits -> (N, E) float32 gates, scored by sigmoid
-    (the DeepSeek-V3 / `NemotronH` router with one group): the `top_k`
+    (the DeepSeek-V3 `noaux_tc` / `NemotronH` router): the `top_k`
     experts are chosen on `sigmoid(logits) + bias` (`bias` (E,), the
     score-correction bias: it moves the choice and never the weight),
     and a chosen expert's gate is its UNBIASED score over the chosen
-    scores' sum, times `scale`; zero elsewhere."""
+    scores' sum, times `scale`; zero elsewhere. With `n_groups` > 1 the
+    choice is made among each row's `topk_groups` best groups only, a
+    group scored by the SUM of its two largest biased scores
+    (`group_limited`); a biased score may be negative, so what lies
+    outside the kept groups is set to -inf, not 0, and no choice leaves
+    them."""
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, top_i = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    choose_on = s + bias.astype(jnp.float32)
+    if n_groups > 1:
+        choose_on = group_limited(choose_on, n_groups, topk_groups, best=2,
+                                  fill=-jnp.inf)
+    _, top_i = lax.top_k(choose_on, top_k)
     rows = jnp.arange(logits.shape[0])[:, None]
     top_s = s[rows, top_i]
     w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20) * scale
     return jnp.zeros(logits.shape, jnp.float32).at[rows, top_i].set(w)
 
 
-def group_limited(scores: jnp.ndarray, n_groups: int,
-                  topk_groups: int) -> jnp.ndarray:
+def group_limited(scores: jnp.ndarray, n_groups: int, topk_groups: int,
+                  best: int = 1, fill: float = 0.0) -> jnp.ndarray:
     """Device-limited routing's choice of groups (DeepSeek-V2,
-    arXiv:2405.04434 section 2.1.3; the published `group_limited_greedy`):
-    the E experts lie in `n_groups` groups of `E / n_groups` (expert `e`
-    in group `e // (E / n_groups)`), a group's score is the LARGEST
-    score in it, each row keeps its `topk_groups` best groups and every
-    other group's scores are set to 0. `scores` (N, E) float32, >= 0."""
+    arXiv:2405.04434 section 2.1.3): the E experts lie in `n_groups`
+    groups of `E / n_groups` (expert `e` in group `e // (E / n_groups)`),
+    a group's score is the sum of the `best` largest scores in it (1:
+    the LARGEST, the published `group_limited_greedy`; 2: DeepSeek-V3's
+    `noaux_tc`), each row keeps its `topk_groups` best groups and every
+    other group's scores are set to `fill` (0 where the scores are >= 0;
+    -inf where they may be negative). `scores` (N, E) float32."""
     N, E = scores.shape
     with jax.named_scope("moe.groups"):
         by_group = scores.reshape(N, n_groups, E // n_groups)
-        _, top_g = lax.top_k(jnp.max(by_group, axis=-1), topk_groups)
+        of_group = jnp.max(by_group, axis=-1) if best == 1 else \
+            jnp.sum(lax.top_k(by_group, best)[0], axis=-1)
+        _, top_g = lax.top_k(of_group, topk_groups)
         keep = jnp.zeros((N, n_groups), bool).at[
             jnp.arange(N)[:, None], top_g].set(True)
-        return jnp.where(keep[:, :, None], by_group, 0.0).reshape(N, E)
+        return jnp.where(keep[:, :, None], by_group, fill).reshape(N, E)
 
 
 def softmax_all_topk_gates(logits: jnp.ndarray, bias: jnp.ndarray,
@@ -341,15 +355,17 @@ def softmax_all_topk_gates(logits: jnp.ndarray, bias: jnp.ndarray,
 def check_groups(scoring: str, n_experts: int, n_groups: int,
                  topk_groups: int, n_zero: int = 0) -> None:
     """Refuse a choice of groups the routers here are not written for:
-    the group rule (a group's score its largest) is "softmax_all"'s,
-    over real experts alone."""
+    the group rules ("softmax_all": a group's score its largest;
+    "sigmoid": the sum of its two largest biased scores) are over real
+    experts alone, and "softmax" (over the chosen) has none."""
     if n_groups == 1:
         return
-    if scoring != "softmax_all" or n_zero:
+    if scoring not in ("softmax_all", "sigmoid") or n_zero:
         raise ValueError(
             f"n_groups {n_groups} with scoring {scoring!r} and {n_zero} "
-            "zero-compute experts: groups are chosen by their largest "
-            "score under 'softmax_all' over real experts only")
+            "zero-compute experts: groups are chosen under 'softmax_all' "
+            "(by their largest score) or 'sigmoid' (by the sum of their "
+            "two largest), over real experts only")
     if n_groups < 1 or n_experts % n_groups \
             or not 1 <= topk_groups <= n_groups:
         raise ValueError(
@@ -371,7 +387,8 @@ def routed_gates(logits: jnp.ndarray, top_k: int, *, bias=None,
     if scoring == "softmax":
         return topk_gates(logits, top_k)
     if scoring == "sigmoid":
-        return sigmoid_topk_gates(logits, bias, top_k, scale)
+        return sigmoid_topk_gates(logits, bias, top_k, scale, n_groups,
+                                  topk_groups)
     return softmax_all_topk_gates(logits, bias, top_k, scale, n_groups,
                                   topk_groups)
 

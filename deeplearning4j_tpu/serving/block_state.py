@@ -14,7 +14,8 @@ kind each block keeps (`GPTPlan.state_kinds`):
     RecurrentSlots  per-slot arrays of fixed size, as the mixer's
                     `state_shapes` declares them (a Mamba-2 mixer's
                     float32 state `(S, H, P, N)`, a gated delta-rule
-                    mixer's `(S, d_k, H * d_v)`: any rank, the slot
+                    mixer's `(S, d_k, H * d_v)`, one decay a head or
+                    one a key channel: any rank, the slot
                     axis first; and the convolution tail `(K-1, S,
                     Cw)`, tap-major): allocated by slot, OVERWRITTEN
                     when a slot is admitted (a one-shot prefill, or the
@@ -476,12 +477,12 @@ class LatentPages(_ByPhase, _Kind):
         with jax.named_scope("mla.attend"):
             att = mla.attend(q_abs, pool, d.page_table, d.pos, d.active,
                              kv_rank=self.kv_rank, sm_scale=m.sm_scale)
-        return m.out(mp, att), (pool,)
+        return m.out(mp, att, u), (pool,)
 
     def mix_prefill(self, mp, u, cache, d):
         m = self.mixer
         q_n, q_r, latent = m.project(mp, u, jnp.arange(u.shape[1]))
-        mixed = m.attend_expanded(mp, q_n, q_r, latent, n_valid=d.t0)
+        mixed = m.attend_expanded(mp, q_n, q_r, latent, n_valid=d.t0, x=u)
         with jax.named_scope("mla.write"):
             pool = self._write_span(cache[0], latent, d.wpids,
                                     jnp.zeros((), jnp.int32))
@@ -499,7 +500,7 @@ class LatentPages(_ByPhase, _Kind):
         att = m.attend_latents(m.absorb(mp, q_n, q_r),
                                gather_latents(pool, d.page_row[None]),
                                d.qpos[None])
-        return m.out(mp, att), (pool,)
+        return m.out(mp, att, u), (pool,)
 
     def _block(self, which, p, x, cache, d):
         mixed, cache = getattr(self, "mix_" + which)(

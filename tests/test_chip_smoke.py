@@ -226,6 +226,56 @@ def test_latent_phase_toy_of_the_one_sub_layer_family():
     json.dumps(out)
 
 
+def test_latent_phase_toy_of_the_family_with_both_cache_kinds():
+    """The same stage on the `ling_flash` family: a delta-rule layer with
+    a decay a key channel beside a gated latent-attention layer, so the
+    engine keeps recurrent slots AND latent pages; both block counts come
+    from the net's mixers."""
+    import jax.numpy as jnp
+
+    lat = dict(chip_smoke.LATENT_KDA, vocab_size=64, hidden_size=64,
+               num_hidden_layers=4, num_attention_heads=4, head_dim=8,
+               kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+               v_head_dim=8, intermediate_size=48, moe_intermediate_size=24,
+               moe_shared_expert_intermediate_size=24, num_experts=4,
+               n_group=4, topk_group=2, num_experts_per_tok=3,
+               rope_theta=1e4, expert_swiglu_limit_list=[0] * 4,
+               share_expert_swiglu_limit_list=[0] * 4,
+               deployment=dict(num_experts_published=16,
+                               experts_held_first=4))
+    out = chip_smoke.phase_latent(
+        lat, dict(n_slots=4, max_len=96, page_size=8, prefill_chunk=16,
+                  n_short=3, short_len=8, long_len=40, n_tokens=8),
+        kernels=False, dtype=jnp.float32, family="ling_flash")
+    assert out["requests"] == 4 and out["tokens"] == 4 * 8
+    assert out["prefill_chunks"] >= 3
+    assert max(out["reference_gaps"]) < 1e-4
+    assert out["agreement"]["common_prefix_tokens"] == [8] * 4
+    # four layers of period two: two float32 latents of 16 + 4, and two
+    # states of 4 heads of 8 x 8 with three taps of 3 x 32 columns
+    assert (out["latent_blocks"], out["recurrent_blocks"]) == (2, 2)
+    assert out["latent_bytes_per_token"] == 2 * 20 * 4
+    assert out["state_bytes_per_slot"] == 2 * (8 * 32 * 4 + 3 * 96 * 4)
+    assert out["zero_share_of_choices"] == 0
+    assert out["held_share_of_choices"] <= out["rows_local_share"] < 1.0
+    json.dumps(out)
+
+
+def test_latent_phase_publishes_ling_flashs_widths():
+    from perfbench.families import ling_flash as fam
+
+    sz = fam.sizes(chip_smoke.LATENT_KDA)
+    assert (sz["d"], sz["H"], sz["kr"]) == (2560, 32, 512)
+    assert (sz["nope"], sz["rope"], sz["vd"]) == (128, 64, 128)
+    assert (sz["lh"], sz["lk"], sz["lv"], sz["conv"], sz["gate_lower"]) \
+        == (32, 128, 128, 4, -5.0)
+    assert (sz["ffn"], sz["f"], sz["shared"]) == (6144, 768, 768)
+    assert (sz["L"], sz["L_dense"], sz["L_moe"]) == (2, 1, 1)
+    assert sz["layer_types"] == ("linear_attention", "full_attention")
+    assert (sz["E"], sz["held"], sz["groups"], sz["topk_groups"],
+            sz["topk"], sz["route_scale"]) == (512, (0, 64), 8, 4, 8, 2.5)
+
+
 def test_latent_phase_publishes_deepseek_v2s_widths():
     from perfbench.families import deepseek_v2 as fam
 

@@ -6,6 +6,7 @@ itself: `to_json()` text equal, held by its sha-256 recorded there.
 """
 import hashlib
 import inspect
+import re
 
 import pytest
 
@@ -55,7 +56,9 @@ CASES = {
 @pytest.mark.parametrize("family", sorted(CASES))
 def test_a_family_builds_the_configuration_it_built_alone(family):
     make, parents = CASES[family]
-    text = make().to_json()
+    # (PR 46 gave the latent mixer a field the parent's text lacks,
+    # `head_gate`, false in every family here)
+    text = re.sub(r',\s*"head_gate": false', "", make().to_json())
     assert hashlib.sha256(text.encode()).hexdigest() == parents, (
         f"{family}: to_json() is not the parent's ({len(text)} characters)")
     # and it is the shared builder's: no chain of its own

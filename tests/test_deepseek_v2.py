@@ -256,7 +256,7 @@ def test_one_group_is_softmax_all():
 
 
 @pytest.mark.parametrize("kw,what", [
-    (dict(scoring="sigmoid", n_groups=4, topk_groups=2), "largest"),
+    (dict(scoring="sigmoid", n_groups=3, topk_groups=2), "equal"),
     (dict(scoring="softmax", n_groups=4, topk_groups=2), "largest"),
     (dict(scoring="softmax_all", n_zero_experts=4, n_groups=4,
           topk_groups=2), "real experts"),

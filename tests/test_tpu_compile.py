@@ -126,6 +126,26 @@ def test_gated_delta_step_kernel_compiles_at_the_published_widths(one_chip):
     assert stats.temp_size_in_bytes < 1 << 20
 
 
+def test_channel_gated_delta_step_kernel_compiles_at_the_published_widths(
+        one_chip):
+    """Ling-3.0-flash's linear layers: 128 slots, 32 heads of 128 x 128
+    with a decay a key channel, the state `(128, 128, 4096)` float32
+    (whole lane tiles, one head a group) updated in place."""
+    from deeplearning4j_tpu.ops.pallas_delta_step import kda_step
+
+    S = _shapes(one_chip)
+    f32 = jnp.float32
+    with jax.enable_x64(False):
+        compiled = jax.jit(kda_step.__wrapped__, donate_argnums=(0,)).lower(
+            S((128, 128, 4096), f32), S((128, 32, 128), f32),
+            S((128, 32, 128), f32), S((128, 32, 128)),
+            S((128, 32, 128), f32), S((128, 32), f32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == 128 * 128 * 4096 * 4
+    assert stats.temp_size_in_bytes < 8 << 20
+
+
 @pytest.mark.parametrize("B,H,T,kernels", [
     (4, 12, 2048, 2), (1, 1, 57344, 2), (1, 1, 58368, 3)],
     ids=("cgpt590m-t2048", "longest-fused", "first-split"))
