@@ -1057,7 +1057,7 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
     import jax
     import jax.numpy as jnp
 
-    from deeplearning4j_tpu.ops import pallas_mla_attend
+    from deeplearning4j_tpu.ops import pallas_delta_step, pallas_mla_attend
     from deeplearning4j_tpu.serving.decode_engine import DecodeEngine
 
     fam = importlib.import_module(f"perfbench.families.{family}")
@@ -1157,7 +1157,8 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
             _check(engaged("moe_experts", lambda k: k == key),
                    f"grouped expert kernel did not engage for {key}")
         if blocks["recurrent"]:
-            key = ("bfloat16", sz["lh"], sz["lk"], sz["lv"])
+            key = pallas_delta_step.step_key(jnp.bfloat16, sz["lh"],
+                                             sz["lk"], sz["lv"], True)
             _check(engaged("kda_step", lambda k: k == key),
                    f"channel-gated delta step kernel did not engage for "
                    f"{key}")

@@ -20,34 +20,48 @@ heads' value columns side by side on lanes. Heads are handled
 `group` at a time so that a group's columns are whole 128-lane tiles
 (d_v 192: two heads, 384 lanes; d_v a multiple of 128: one). Inside a
 group a key or query column is broadcast along the lanes of its own
-head by one select on the lane index; the per-head scalars `a`, `b` and
-the row `v` arrive already expanded to a group's lanes, `(S, 3,
-H / group, group * d_v)`, computed by XLA outside (3 x 23 KB a slot
-beside 2 x 2.2 MB of state), and `k`, `q` as one transposed `(S, d_k,
-2 H)` operand, so the kernel needs no relayout. All arithmetic is
-float32 on the vector unit: a slot with `b = 0, a = 1` keeps its state
-bit for bit, as in the XLA form.
+head by one select on the lane index. All arithmetic is float32 on the
+vector unit: a slot with `b = 0, a = 1` keeps its state bit for bit, as
+in the XLA form. How the small operands arrive differs by kernel:
 
-**The decay's two shapes, two kernels.** `gdn_step` takes `g` (S, H),
-one decay a head: `a` rides the expanded row operand above. `kda_step`
-takes `g` (S, H, d_k), one decay a KEY CHANNEL (`ops/delta_rule.py`):
+`gdn_step` (one decay a head, `g` (S, H)) has XLA lay them out: the
+per-head scalars `a`, `b` and the row `v` expanded to a group's lanes,
+`(S, 3, H / group, group * d_v)` (3 x 23 KB a slot beside 2 x 2.2 MB of
+state), and `k`, `q` as one transposed `(S, d_k, 2 H)` operand, so the
+kernel needs no relayout. Its (30, 96) row tile lies off the (8, 128)
+grid on both axes, and whether Mosaic would transpose it is not known.
+
+`kda_step` (one decay a KEY CHANNEL, `g` (S, H, d_k):
+`ops/delta_rule.py`) takes its operands AS THE MIXER HAS THEM, and its
+entry makes nothing but `exp(g)`, elementwise on `g`'s own layout:
 
     S' = S Diag(a) + (b (v - S Diag(a) k)) k^T;   o = S' q
 
-A key channel is a ROW of the resident tile, so the decay arrives the way
-`k` and `q` do, as a third block of columns of the transposed operand,
-`(S, d_k, 3 H)`: column 2 H + h is head h's `exp(g)`, broadcast along
-its head's lanes exactly as its key is; the row operand keeps `v` and
-`b`, `(S, 2, H / group, group * d_v)`. The tile is decayed once (`m *
-a`), and the rest is the scalar kernel's arithmetic on the decayed tile.
-At 32 heads of 128 x 128 a slot's state is `(128, 4096)` float32, whole
-lane tiles with one head a group, 2.1 MB in VMEM. Each is a static
-variant of its own, traced and probed under its own jitted entry, so a
-program lowers each once and a device trace names each.
+`k`, `q` and `a = exp(g)` go in as `(S, H, d_k)` float32, a `(1, H,
+d_k)` block a slot; `v` `(S, H / group, group * d_v)` in its own dtype
+(a reshape of nothing where one head is a group); `beta` `(S, H)`,
+whole and resident, a slot's row read by its grid index. A key channel
+is a ROW of the resident state tile, so the kernel wants `k`, `q` and
+`a` as COLUMNS: once a slot it pads each `(H, d_k)` row tile to whole
+128-row tiles and transposes it in VMEM (`_as_columns`; the XLU is idle
+beside the state's DMA), then slices head h's column and broadcasts it
+along its head's lanes exactly as the scalar kernel does. The tile is
+decayed once (`m * a`), and the rest is the scalar kernel's arithmetic
+on the decayed tile, in its order: the values are those of columns
+made by XLA outside, which cost a layer three lane-padded transposes,
+three transposing fusions and two concatenates every decode step
+(`gdn_step`'s entry pays its share of them still). At 32 heads of 128
+x 128 a slot's state is `(128, 4096)` float32, whole lane tiles with one
+head a group, 2.1 MB in VMEM.
+
+Each kernel is a static variant of its own, traced and probed under its
+own jitted entry, so a program lowers each once and a device trace names
+each.
 
 Dispatch rides `ops/kernel_dispatch.py` under the family names
 `gdn_step` and `kda_step`: the probe compiles and runs the kernel at the
-exact shape class and holds it to `delta_step`;
+exact shape class and holds it to `delta_step` (`kda_step`'s class ends
+in `"rows"`, its operand form: `step_key`);
 `DL4J_TPU_NO_PALLAS_GDN_STEP` forces the XLA form of both; CPU backends
 never dispatch.
 """
@@ -114,31 +128,47 @@ def _step_kernel(kq_ref, vab_ref, s_ref, o_ref, s_out_ref, *, H: int,
         o_ref[0, p:p + 1, :] = jnp.sum(m * qx, axis=0, keepdims=True)
 
 
-def _channel_kernel(kqa_ref, vb_ref, s_ref, o_ref, s_out_ref, *, H: int,
-                    dv: int, group: int):
-    """`_step_kernel` with a decay a key channel. `kqa_ref` (1, d_k,
-    3H): column h is head h's key, H + h its query, 2H + h its decay
-    `exp(g)`; `vb_ref` (1, 2, H / group, group * d_v): v, b by group
-    row."""
+def _as_columns(rows):
+    """A `(H, d_k)` row tile as columns `(d_k, H')`, made in VMEM: the
+    rows padded to whole 128-row tiles and transposed once (column h is
+    row h; the columns past H are never read)."""
+    H, dk = rows.shape
+    pad = -H % 128
+    if pad:
+        rows = jnp.concatenate([rows, jnp.zeros((pad, dk), rows.dtype)],
+                               axis=0)
+    return rows.T
+
+
+def _channel_kernel(k_ref, q_ref, a_ref, v_ref, b_ref, s_ref, o_ref,
+                    s_out_ref, *, H: int, dv: int, group: int):
+    """`_step_kernel` with a decay a key channel, its operands as the
+    mixer has them: `k_ref`, `q_ref`, `a_ref` (1, H, d_k) the slot's
+    keys, queries and decays `exp(g)` by head ROW, turned into columns
+    here; `v_ref` (1, H / group, group * d_v) in its own dtype; `b_ref`
+    (S, H), every slot's beta, resident."""
+    from jax.experimental import pallas as pl
+
     dk = s_ref.shape[1]
     W = group * dv
-    column = _columns(kqa_ref[0], dk, dv, group)
+    key, query, decay = (_columns(_as_columns(r[0]), dk, dv, group)
+                         for r in (k_ref, q_ref, a_ref))
+    beta = _columns(b_ref[pl.ds(pl.program_id(0), 1), :], 1, dv, group)
 
     for p in range(H // group):
-        m = s_ref[0, :, p * W:(p + 1) * W] * column(2 * H, p)  # (dk, W)
-        kx, qx = column(0, p), column(H, p)
-        v = vb_ref[0, 0, p:p + 1, :]                           # (1, W)
-        b = vb_ref[0, 1, p:p + 1, :]
+        m = s_ref[0, :, p * W:(p + 1) * W] * decay(0, p)       # (dk, W)
+        kx, qx = key(0, p), query(0, p)
+        v = v_ref[0, p:p + 1, :].astype(F32)                   # (1, W)
         sk = jnp.sum(m * kx, axis=0, keepdims=True)
-        m = m + kx * (b * (v - sk))
+        m = m + kx * (beta(0, p) * (v - sk))
         s_out_ref[0, :, p * W:(p + 1) * W] = m
         o_ref[0, p:p + 1, :] = jnp.sum(m * qx, axis=0, keepdims=True)
 
 
-def _call(kernel, state, cols, rows, H: int, interpret: bool):
-    """One grid step a slot over the aliased state: `cols` (S, d_k, n H)
-    the transposed column operand, `rows` (S, r, H / group, group * d_v)
-    the expanded rows. Returns (o (S, H, d_v) float32, state)."""
+def _call(kernel, state, operands, specs, H: int, interpret: bool):
+    """One grid step a slot over the aliased state: `operands` the
+    kernel's small operands in its order, `specs` the block of each.
+    Returns (o (S, H, d_v) float32, state)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -149,27 +179,26 @@ def _call(kernel, state, cols, rows, H: int, interpret: bool):
     o, state = pl.pallas_call(
         functools.partial(kernel, H=H, dv=dv, group=G),
         grid=(S,),
-        in_specs=[pl.BlockSpec((1, dk, cols.shape[2]), lambda s: (s, 0, 0)),
-                  pl.BlockSpec((1, rows.shape[1], P, W),
-                               lambda s: (s, 0, 0, 0)),
-                  pl.BlockSpec((1, dk, HV), lambda s: (s, 0, 0))],
+        in_specs=[*specs, pl.BlockSpec((1, dk, HV), lambda s: (s, 0, 0))],
         out_specs=[pl.BlockSpec((1, P, W), lambda s: (s, 0, 0)),
                    pl.BlockSpec((1, dk, HV), lambda s: (s, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((S, P, W), F32),
                    jax.ShapeDtypeStruct(state.shape, F32)],
-        input_output_aliases={2: 1},
+        input_output_aliases={len(specs): 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_vmem_limit()),
         interpret=interpret,
-    )(cols, rows, state)
+    )(*operands, state)
     return o.reshape(S, H, dv), state
 
 
-def _transposed(*xs):
-    """(S, H, d_k) operands side by side as columns, (S, d_k, n H)."""
-    return jnp.concatenate([jnp.swapaxes(x.astype(F32), 1, 2) for x in xs],
-                           axis=2)
+def _slot_block(x):
+    """The block of one slot of a per-slot operand `x` (S, ...)."""
+    from jax.experimental import pallas as pl
+
+    zeros = (0,) * (x.ndim - 1)
+    return pl.BlockSpec((1, *x.shape[1:]), lambda s: (s, *zeros))
 
 
 # jitted so that a step over many layers traces and lowers the kernel
@@ -181,27 +210,35 @@ def gdn_step(state, q, k, v, g, beta, *, interpret: bool = False):
     S, H = q.shape[:2]
     dv = state.shape[2] // H
     shape = (S, H // _group(dv), _group(dv) * dv)
-    cols = _transposed(k, q)
+    cols = jnp.concatenate([jnp.swapaxes(x.astype(F32), 1, 2)
+                            for x in (k, q)], axis=2)
     rows = jnp.stack(
         [v.astype(F32).reshape(shape)]
         + [jnp.repeat(x.astype(F32), dv, axis=1).reshape(shape)
            for x in (jnp.exp(g.astype(F32)), beta)], axis=1)
-    o, state = _call(_step_kernel, state, cols, rows, H, interpret)
+    o, state = _call(_step_kernel, state, (cols, rows),
+                     (_slot_block(cols), _slot_block(rows)), H, interpret)
     return o.astype(v.dtype), state
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def kda_step(state, q, k, v, g, beta, *, interpret: bool = False):
     """`delta_step` with one decay a key channel (`g` (S, H, d_k)) as
-    one in-place kernel call (same arguments, same return)."""
+    one in-place kernel call (same arguments, same return). The operands
+    go to the kernel as the mixer has them; only `exp(g)` is made here,
+    elementwise on `g`'s own layout."""
+    from jax.experimental import pallas as pl
+
     S, H = q.shape[:2]
     dv = state.shape[2] // H
-    shape = (S, H // _group(dv), _group(dv) * dv)
-    cols = _transposed(k, q, jnp.exp(g.astype(F32)))
-    rows = jnp.stack(
-        [v.astype(F32).reshape(shape),
-         jnp.repeat(beta.astype(F32), dv, axis=1).reshape(shape)], axis=1)
-    o, state = _call(_channel_kernel, state, cols, rows, H, interpret)
+    G = _group(dv)
+    rows = (k.astype(F32), q.astype(F32), jnp.exp(g.astype(F32)),
+            v.reshape(S, H // G, G * dv))
+    beta = beta.astype(F32)
+    o, state = _call(
+        _channel_kernel, state, (*rows, beta),
+        (*map(_slot_block, rows), pl.BlockSpec((S, H), lambda s: (0, 0))),
+        H, interpret)
     return o.astype(v.dtype), state
 
 
@@ -250,6 +287,15 @@ def _eager_probe(dtype, H: int, dk: int, dv: int,
     return True
 
 
+def step_key(dtype, H: int, dk: int, dv: int, channels: bool) -> tuple:
+    """The shape class a verdict of this module is kept under.
+    `kda_step`'s names its operand form, head rows turned to columns in
+    the kernel, so a verdict probed on the transposed form of before is
+    told from this one's."""
+    key = (jnp.dtype(dtype).name, H, dk, dv)
+    return key + ("rows",) if channels else key
+
+
 def delta_step_or_none(state, q, k, v, g, beta):
     """Dispatch probe: the step through the kernel of `g`'s shape
     (`gdn_step` for (S, H), `kda_step` for (S, H, d_k)), or None when it
@@ -263,7 +309,7 @@ def delta_step_or_none(state, q, k, v, g, beta):
     S, dk, HV = state.shape
     H = q.shape[1]
     dv = HV // H
-    key = (jnp.dtype(v.dtype).name, H, dk, dv)
+    key = step_key(v.dtype, H, dk, dv, channels)
     G = _group(dv)
     if not G or H % G or dk % 8:
         _record_decline(family, key, f"{H} heads of {dk} x {dv}: off the "

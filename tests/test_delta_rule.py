@@ -247,18 +247,26 @@ def test_heads_of_and_flat_of_are_inverse():
 
 
 # ------------------------------------------------------ the Pallas kernel
-@pytest.mark.parametrize("H,dk,dv,dtype", [
-    (2, 96, 192, jnp.float32),     # the published head sizes, one pair
-    (6, 96, 192, jnp.bfloat16),    # three pairs, bfloat16 values
-    (3, 16, 128, jnp.float32),     # a value width of whole lane tiles
-], ids=["pair-f32", "pairs-bf16", "single-f32"])
+@pytest.mark.parametrize("S,H,dk,dv,dtype", [
+    (3, 2, 96, 192, jnp.float32),    # the published head sizes, one pair
+    (3, 6, 96, 192, jnp.bfloat16),   # three pairs, bfloat16 values
+    (3, 3, 16, 128, jnp.float32),    # a value width of whole lane tiles
+    (11, 4, 128, 128, jnp.bfloat16),  # slots no multiple of 8; H < d_k
+    (2, 130, 8, 128, jnp.float32),   # more heads than one 128-row tile
+], ids=["pair-f32", "pairs-bf16", "single-f32", "eleven-slots-bf16",
+        "two-row-tiles-f32"])
 @DECAYS
-def test_pallas_step_in_interpret_mode_equals_delta_step(H, dk, dv, dtype,
+def test_pallas_step_in_interpret_mode_equals_delta_step(S, H, dk, dv, dtype,
                                                          channels):
-    S = 3
+    """Both kernels against `delta_step` at the probe's tolerances, the
+    last slot inactive and bit for bit what it was. The channel kernel
+    takes its head rows as they are and pads them to whole 128-row tiles
+    before it turns them into columns: fewer heads than key channels,
+    more heads than a tile, and a slot count off the sublane grid (its
+    `beta` is read by slot from the resident (S, H))."""
     q, k, v, g, beta = (x[:, 0] for x in _inputs(
         S, 1, H, dk, dv, seed=H, dtype=dtype, channels=channels))
-    live = jnp.asarray([True, True, False])[:, None]
+    live = (jnp.arange(S) < S - 1)[:, None]
     g = jnp.where(live[..., None] if channels else live, g, 0.0)
     beta = jnp.where(live, beta, 0.0)
     h0 = jnp.asarray(np.random.default_rng(13)
@@ -271,13 +279,22 @@ def test_pallas_step_in_interpret_mode_equals_delta_step(H, dk, dv, dtype,
     np.testing.assert_allclose(got_o.astype(jnp.float32),
                                want_o.astype(jnp.float32),
                                atol=2e-2 if dtype == jnp.bfloat16 else 2e-6)
-    np.testing.assert_array_equal(got_s[2], h0[2])
+    np.testing.assert_array_equal(got_s[S - 1], h0[S - 1])
+
+
+def _entry_equations(step, *args):
+    """The equations of a jitted entry's own body (the kernel's body is
+    one of them, not looked into)."""
+    (call,) = jax.make_jaxpr(lambda *a: step(*a))(*args).jaxpr.eqns
+    return call.params["jaxpr"].jaxpr.eqns
 
 
 def test_the_channel_step_at_the_published_state_in_interpret_mode():
     """Ling-3.0-flash's linear layers' state: 32 heads of 128 x 128,
-    `(S, 128, 4096)` float32, one head a group of whole lane tiles; the
-    decay rides the transposed column operand, `(S, 128, 96)`."""
+    `(S, 128, 4096)` float32, one head a group of whole lane tiles. The
+    entry hands the kernel q, k and `exp(g)` by head row, v in its own
+    dtype and beta a head: nothing is transposed, joined or spread to
+    lanes before the call."""
     S, H, dk, dv = 3, 32, 128, 128
     q, k, v, g, beta = (x[:, 0] for x in _inputs(
         S, 1, H, dk, dv, seed=16, dtype=jnp.bfloat16, channels=True))
@@ -292,9 +309,18 @@ def test_the_channel_step_at_the_published_state_in_interpret_mode():
     np.testing.assert_allclose(got_o.astype(jnp.float32),
                                want_o.astype(jnp.float32), atol=2e-2)
     np.testing.assert_array_equal(got_s[1], h0[1])
-    text = str(jax.make_jaxpr(lambda *a: pk.kda_step(*a))(
-        h0, q, k, v, g, beta))
-    assert "f32[3,128,96]" in text and "f32[3,2,32,128]" in text
+    eqns = _entry_equations(pk.kda_step, h0, q, k, v, g, beta)
+    assert {e.primitive.name for e in eqns} <= {
+        "exp", "reshape", "convert_element_type", "pallas_call"}
+    (call,) = (e for e in eqns if e.primitive.name == "pallas_call")
+    assert [(x.aval.shape, x.aval.dtype.name) for x in call.invars] == [
+        ((S, H, dk), "float32")] * 3 + [
+        ((S, H, dv), "bfloat16"), ((S, H), "float32"),
+        (h0.shape, "float32")]
+    # the scalar kernel's entry is the one that lays its operands out
+    scalar = _entry_equations(pk.gdn_step, h0, q, k, v, g[..., 0], beta)
+    assert {"transpose", "concatenate"} <= {e.primitive.name
+                                            for e in scalar}
 
 
 @DECAYS
@@ -313,6 +339,9 @@ def test_the_kernel_never_dispatches_on_the_cpu(monkeypatch, channels):
     assert or_none(jnp.zeros((2, 8, 32), jnp.float32), *small) is None
     family = pk.KDA_FAMILY if channels else pk.FAMILY
     assert (pk.FAMILY, pk.KDA_FAMILY) == ("gdn_step", "kda_step")
-    verdict = kernel_dispatch.kernel_verdicts()[family][
-        ("float32", 2, 8, 16)]
+    # the channel kernel's key names its operand form: a verdict probed
+    # on the transposed operand of before is not this one's
+    key = pk.step_key(jnp.float32, 2, 8, 16, channels)
+    assert key == ("float32", 2, 8, 16) + (("rows",) if channels else ())
+    verdict = kernel_dispatch.kernel_verdicts()[family][key]
     assert not verdict.ok and "tile grid" in verdict.message

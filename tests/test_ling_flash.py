@@ -904,7 +904,9 @@ def test_the_family_refuses_what_it_does_not_run(over, what):
 def test_the_step_bench_rehearses_in_interpret_mode(tmp_path, capsys):
     """`tools/kda_step_bench.py` end to end at toy shapes, so that a chip
     call is not lost to a typo: a row a form and slot count, each within
-    rounding of `delta_step`, and no time printed as a device's."""
+    rounding of `delta_step`, and no time printed as a device's (the
+    reduction of the traced stretch is held to a recorded device's
+    events below)."""
     from tools import kda_step_bench as bench
 
     out = tmp_path / "bench.json"
@@ -918,9 +920,44 @@ def test_the_step_bench_rehearses_in_interpret_mode(tmp_path, capsys):
         ("delta_step", 2)]
     assert all(r["gap_o"] < 1e-5 and r["gap_state"] < 1e-5
                for r in table["rows"])
-    assert not any("call_ms" in r or "roofline_pct" in r
-                   for r in table["rows"])
+    # the traced stretch ran too (the kernel's own time beside the
+    # entry's on a chip) and found no device to read
+    assert not any(key in r for r in table["rows"] for key in (
+        "call_ms", "roofline_pct", "device_ms", "kernel_ms",
+        "entry_not_kernel_pct"))
+    assert not (tmp_path / ".kda_step_trace").exists()
     assert len(capsys.readouterr().out.splitlines()) == 4
+
+
+def test_the_step_bench_reads_the_kernel_beside_its_entry():
+    """`device_times` on a hand-made device trace of two calls: each a
+    transposing fusion of 250 us and the kernel's 750 us, so a quarter
+    of the entry is not the kernel; a form without the kernel has its
+    device time alone, a trace without a device nothing."""
+    from perfbench.harness import trace_reduce as tr
+    from tools import kda_step_bench as bench
+
+    def ev(name, start, dur, plane="/device:TPU:0", line=tr.OPS_LINE):
+        return {"plane": plane, "line": line, "name": name,
+                "start_ns": float(start), "dur_ns": float(dur)}
+
+    kernel = ('%kda_step.{n} = (f32[128,32,128]{{2,1,0}}, '
+              'f32[128,128,4096]{{2,1,0}}) custom-call(f32[128,32,128] %k), '
+              'custom_call_target="tpu_custom_call"')
+    events = []
+    for n in range(2):
+        events.append(ev(f"%fusion.{n} = f32[128,128,32] fusion(f32[8] %x)",
+                         2_000_000 * n, 250_000))
+        events.append(ev(kernel.format(n=n), 2_000_000 * n + 250_000,
+                         750_000))
+    got = bench.device_times(tr.TraceView(events), 2)
+    assert got == pytest.approx({"device_ms": 1.0, "kernel_ms": 0.75,
+                                 "entry_not_kernel_pct": 25.0})
+    xla = [e for e in events if "kda_step" not in e["name"]]
+    assert bench.device_times(tr.TraceView(xla), 2) \
+        == pytest.approx({"device_ms": 0.25})
+    host = [ev("$a.py:1 f", 0, 10, plane="/host:CPU", line="python3")]
+    assert bench.device_times(tr.TraceView(host), 2) == {}
 
 
 def test_nothing_in_the_program_branches_on_the_models_name():

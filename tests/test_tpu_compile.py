@@ -130,7 +130,9 @@ def test_channel_gated_delta_step_kernel_compiles_at_the_published_widths(
         one_chip):
     """Ling-3.0-flash's linear layers: 128 slots, 32 heads of 128 x 128
     with a decay a key channel, the state `(128, 128, 4096)` float32
-    (whole lane tiles, one head a group) updated in place."""
+    (whole lane tiles, one head a group) updated in place; q, k and the
+    decay go in as `(1, 32, 128)` row blocks and are turned into columns
+    in the kernel (a 32-row tile padded to 128 rows and transposed)."""
     from deeplearning4j_tpu.ops.pallas_delta_step import kda_step
 
     S = _shapes(one_chip)
@@ -140,10 +142,87 @@ def test_channel_gated_delta_step_kernel_compiles_at_the_published_widths(
             S((128, 128, 4096), f32), S((128, 32, 128), f32),
             S((128, 32, 128), f32), S((128, 32, 128)),
             S((128, 32, 128), f32), S((128, 32), f32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _call_operands(text)[1] == [
+        "f32[128,32,128]{2,1,0}"] * 3 + [
+        "bf16[128,32,128]{2,1,0}", "f32[128,32]{1,0}",
+        "f32[128,128,4096]{2,1,0}"]
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes == 128 * 128 * 4096 * 4
     assert stats.temp_size_in_bytes < 8 << 20
+
+
+def _call_operands(text: str) -> tuple:
+    """(the HLO lines that define the operands of the program's ONE
+    Pallas call, the layouts the call constrains them to)."""
+    import re
+
+    (call,) = (line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line)
+    names = re.findall(r"%[\w.\-]+", re.search(
+        r"custom-call\((.*?)\), custom_call_target", call).group(1))
+    defs = {line.split(" = ")[0].strip().removeprefix("ROOT "): line
+            for line in text.splitlines() if " = " in line}
+    layouts = re.search(r"operand_layout_constraints=\{(.*?\})\}",
+                        call).group(1)
+    return [defs[n] for n in names], re.findall(r"\w+\[[\d,]*\]\{[\d,]*\}",
+                                                layouts)
+
+
+def test_a_channel_gated_mixer_step_prepares_nothing_for_the_kernel(
+        one_chip, monkeypatch):
+    """ONE `ChannelGatedDeltaMixer.step` at Ling-3.0-flash's widths (d
+    2560, 32 heads of 128 x 128, 128 slots) under a scan of 4, as a
+    decode chunk runs it, with the kernel steered on: what XLA leaves
+    around the call. Before the kernel took head rows a layer paid three
+    `(32, 128) -> (128, 32)` transposes a slot into a lane-padded
+    `f32[128,32,128]{1,2,0}`, three transposing fusions and two
+    concatenates (`pad_maximum_fusion`) every step (ISSUE 47's count);
+    none of them may come back."""
+    import re
+
+    import chip_smoke
+    from deeplearning4j_tpu.nn.conf.decoder_block import (
+        ChannelGatedDeltaMixer,
+    )
+    from deeplearning4j_tpu.ops import pallas_delta_step
+
+    monkeypatch.setattr(pallas_delta_step, "_platform_supported",
+                        lambda: True)
+    monkeypatch.setattr(pallas_delta_step, "_probe_verdict",
+                        lambda *a, **k: True)
+    S = _shapes(one_chip)
+    mixer = ChannelGatedDeltaMixer(n_heads=32, key_dim=128, value_dim=128)
+    d, slots = 2560, 128
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda: mixer.init_params(
+            jax.random.PRNGKey(0), d, jnp.bfloat16,
+            lambda key, shape, *fans: jnp.zeros(shape, jnp.bfloat16))))
+    state, tail = (S(*sd) for sd in mixer.state_shapes(slots, jnp.bfloat16))
+
+    def chunk(params, x, h, tail, active):
+        def body(carry, _):
+            x, h, tail = carry
+            y, h, tail = mixer.step(params, x, h, tail, active)
+            return (x + y, h, tail), None
+        return jax.lax.scan(body, (x, h, tail), None, length=4)[0]
+
+    with jax.enable_x64(False):
+        text = jax.jit(chunk, donate_argnums=(2, 3)).lower(
+            params, S((slots, d)), state, tail,
+            S((slots,), jnp.bool_)).compile().as_text()
+    operands, layouts = _call_operands(text)
+    assert layouts[:3] == ["f32[128,32,128]{2,1,0}"] * 3
+    assert not re.search(r"f32\[128,32,128\]\{1,2,0", text)
+    assert "pad_maximum_fusion" not in text
+    made_by = [re.search(r'op_name="([^"]*)"', line) for line in operands]
+    made_by = [m.group(1).rsplit("/", 1)[-1] for m in made_by if m]
+    assert made_by and not {"concatenate", "transpose"} & set(made_by)
+    # the state is carried through the chunk in one buffer, in place
+    assert "f32[128,128,4096]{2,1,0" in operands[-1]
+    assert chip_smoke.pool_layout_copies(text, {"f32[128,128,4096]"}) == 0
 
 
 @pytest.mark.parametrize("B,H,T,kernels", [

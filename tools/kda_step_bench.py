@@ -15,7 +15,13 @@ each, milliseconds a call, microseconds a slot, the share of
 `kda_roofline.channel_gated_delta_step`'s least time (every slot's state
 read once and written once: the kernel sweeps all slots, live or not, so
 the share is of ALL slots' bytes here) and the largest gap of output and
-state to `delta_step`; `--xla` times that XLA form too. The rows also go
+state to `delta_step`; `--xla` times that XLA form too. `call_ms` is the
+jitted ENTRY by the host's clock, whatever XLA does to the operands
+around the kernel included; beside it `kernel_ms`, the custom call alone
+(`kda_roofline.is_step_kernel`'s events in a device trace of `--iters`
+more steps), `device_ms`, every operation of the traced steps, and
+`entry_not_kernel_pct`, the share of the device's time a call spends
+outside the kernel. The rows also go
 to `chiprun_out/kda_step_bench.json`. Needs a TPU; `--interpret` runs
 the kernel in interpret mode on any backend and prints no time as a
 device's (a rehearsal of the tool, not a measurement).
@@ -25,6 +31,7 @@ from __future__ import annotations
 import argparse
 import functools
 import json
+import shutil
 import sys
 import time
 from pathlib import Path
@@ -108,10 +115,53 @@ def bench(args) -> tuple:
                     gb_per_s=nbytes / call_s / 1e9,
                     roofline_pct=roofline.share_pct(
                         ops, nbytes, call_s, device.peaks(dev["kind"])))
+            mine = traced(row, step, mine, operands, args)
             rows.append(row)
             print(json.dumps(row), flush=True)
             del mine, out
     return dev, rows
+
+
+def traced(row: dict, step, mine, operands, args):
+    """`--iters` more steps under the profiler: `device_times` of their
+    trace into `row`. Returns the states the last step left."""
+    import jax
+
+    from perfbench.harness import trace_reduce
+
+    trace_dir = Path(args.out).parent / ".kda_step_trace"
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    jax.profiler.start_trace(str(trace_dir))
+    for _ in range(args.iters):
+        mine, out = step(mine, operands)
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    view = trace_reduce.TraceView(trace_reduce.load_xplane(str(trace_dir)))
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    row.update(device_times(view, args.iters * args.calls))
+    return mine
+
+
+def device_times(view, calls: int) -> dict:
+    """Of a device trace of `calls` calls of the entry: `device_ms`, the
+    time a call in which any operation ran; `kernel_ms`, the custom call
+    alone (`kda_roofline.is_step_kernel`), and `entry_not_kernel_pct`,
+    what of the device's time the entry spends around it. Nothing where
+    the trace holds no device (`--interpret`), no kernel rows for a form
+    without the kernel."""
+    from perfbench.harness import kda_roofline, trace_reduce
+
+    if not view.chips:
+        return {}
+    out = {"device_ms": 1e3 * view.busy_s() / calls}
+    kernel = sum(e["dur_ns"] for e in view.events
+                 if e["plane"] == view.chips[0]
+                 and e["line"] == trace_reduce.OPS_LINE
+                 and kda_roofline.is_step_kernel(e["name"])) / 1e6 / calls
+    if kernel:
+        out.update(kernel_ms=kernel, entry_not_kernel_pct=100.0 * (
+            1.0 - kernel / out["device_ms"]))
+    return out
 
 
 def main(argv=None) -> int:
