@@ -54,7 +54,8 @@ repo supports — `gpt_configuration(vocab 256, d_model 1024, 8 heads of
   reference and against an engine on the XLA forms (gather-and-attend,
   the scatter); about a third of the router's choices on zero experts;
   the `mla_attend`, `latent_write` and f-tiled grouped-expert kernels
-  engaged; no pool-shaped copy in the decode programs. Then the same
+  engaged; no pool-shaped copy in the decode programs and no weight
+  re-laid in them (`weight_layout_copies`, all three runs). Then the same
   stage (`latent_h128` in the summary) at DeepSeek-V2's published widths:
   a dense and a routed layer of ONE 128-head latent-attention sub-layer
   each under YaRN, group 0 of 8 groups of 20 experts 1536 wide held,
@@ -94,6 +95,7 @@ from __future__ import annotations
 import collections
 import gc
 import json
+import math
 import os
 import re
 import shutil
@@ -542,6 +544,111 @@ def weight_converts(hlo_text: str, weight_shapes) -> int:
     return _count_instructions(hlo_text, ("convert",), weight_shapes)
 
 
+# what hands an array on as it is, whole or in parts: the operand's
+# elements in the result's, nothing computed
+_MOVE_OPCODES = _LAYOUT_OPCODES + (
+    "reshape", "bitcast", "copy-done", "slice", "slice-start", "slice-done",
+    "dynamic-slice", "opt-barrier")
+_HLO_ITEMSIZE = {"bf16": 2, "f16": 2, "s8": 1, "u8": 1, "pred": 1}  # else 4
+
+
+def _hlo_computations(hlo_text: str) -> tuple:
+    """An HLO module's computations, {name: [(result, type, opcode,
+    operand text, attributes)]} in the order printed (operands before
+    their users), and the entry computation's name."""
+    head = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{$")
+    line = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.+?) ([\w\-]+)\((.*)$")
+    comps, entry, body = {}, None, None
+    for text in hlo_text.splitlines():
+        m = head.match(text)
+        if m:
+            body = comps.setdefault(m.group(2), [])
+            entry = m.group(2) if m.group(1) else entry
+        elif body is not None and (m := line.match(text)):
+            operands, _, attrs = m.group(4).partition(")")
+            body.append((*m.group(1, 2, 3), operands, attrs))
+    return comps, entry
+
+
+def _hlo_bytes(typ: str) -> int:
+    """The bytes of an HLO type's (first) array."""
+    m = re.search(r"(\w+)\[([\d,]*)\]", typ)
+    return math.prod(map(int, filter(None, m.group(2).split(",")))) \
+        * _HLO_ITEMSIZE.get(m.group(1), 4)
+
+
+def _is_prefetch(opcode: str, typ: str) -> bool:
+    """A `copy-start` into the layout it reads, another memory space
+    (`S(1)`) apart: the compiler fetching an array ahead of its use, the
+    one read it would cost anyway."""
+    layouts = [re.sub(r"S\(\d+\)", "", m) for m in
+               re.findall(r"\w+\[[\d,]*\]\{([^}]*)\}", typ)]
+    return opcode == "copy-start" and len(layouts) >= 2 \
+        and layouts[0] == layouts[1]
+
+
+def weight_layout_ops(hlo_text: str, weight_shapes) -> list:
+    """The instructions of an optimised HLO module that re-lay a weight,
+    as "opcode type" strings: a `copy`, `copy-start`, `transpose` or a
+    `reshape` left in the module that writes 1 MB or more and whose
+    operand IS a weight (an entry parameter of one of `weight_shapes`)
+    or comes from one by such moves, slices and bitcasts alone, whatever
+    the order or grouping of its dimensions, through fusions and loops.
+    A serving program has none: a weight lies as the products read it,
+    and what a step turns is its activations. Followed by dataflow, not
+    told by size: at 128 slots and heads of 128 the absorbed query (S,
+    H, 512) has a (H, 128, 512) matrix's elements exactly."""
+    comps, entry = _hlo_computations(hlo_text)
+    found = []
+
+    def walk(comp: str, params) -> object:
+        """Look through `comp`, whose parameters hold a weight where
+        `params` says so (True, or a dict by index for a tuple; None:
+        the entry's, told by shape); returns what its root holds."""
+        held, root = {}, None
+        for name, typ, opcode, operands, attrs in comps[comp]:
+            args = [held.get(o) for o in re.findall(r"%([\w.\-]+)", operands)]
+            subs = [c for c in re.findall(r"%([\w.\-]+)", attrs) if c in comps]
+            root = None
+            if opcode == "parameter" and params is None:
+                root = any(typ.startswith(w) for w in weight_shapes)
+            elif opcode == "parameter":
+                root = params[int(operands)] if int(operands) < len(params) \
+                    else None
+            elif opcode == "tuple":
+                root = dict(enumerate(args))
+            elif opcode == "get-tuple-element" and isinstance(args[0], dict):
+                root = args[0].get(int(re.search(r"index=(\d+)", attrs)[1]))
+            elif opcode in _MOVE_OPCODES or "ConcatBitcast" in attrs:
+                root = args[0] if args and isinstance(args[0], dict) \
+                    else True if True in args else None
+                if root is True and _hlo_bytes(typ) >= 1 << 20 \
+                        and opcode in (*_LAYOUT_OPCODES, "reshape") \
+                        and not _is_prefetch(opcode, typ):
+                    found.append(f"{opcode} {typ}")
+            elif opcode == "while":  # a weight goes round a loop as it is
+                for sub in subs:
+                    walk(sub, args[:1])
+                root = args[0]
+            elif opcode == "conditional":
+                for sub, arg in zip(subs, args[1:]):
+                    walk(sub, [arg])
+            elif opcode in ("fusion", "call") and subs:
+                root = walk(subs[0], args)
+            held[name] = root
+        return root
+
+    if entry:
+        walk(entry, None)
+    return found
+
+
+def weight_layout_copies(hlo_text: str, weight_shapes) -> int:
+    """How many instructions of an optimised HLO module re-lay a weight
+    (`weight_layout_ops`)."""
+    return len(weight_layout_ops(hlo_text, weight_shapes))
+
+
 def _hlo_shapes(arrays) -> set:
     return {f"{_HLO_DTYPES[a.dtype.name]}[{','.join(map(str, a.shape))}]"
             for a in arrays}
@@ -554,7 +661,8 @@ def _decode_program_counts(engine) -> dict:
     recurrent block's state. Its convolution tail is left out: 3 taps
     a channel, 0.1% of the state's bytes, and XLA moves it to fast
     memory and back each step by choice (same layout, `S(1)`). And the
-    casts of a weight matrix (`weight_converts`)."""
+    casts of a weight matrix (`weight_converts`) and the instructions
+    that re-lay one (`weight_layout_copies`)."""
     import jax
     import jax.numpy as jnp
 
@@ -575,7 +683,9 @@ def _decode_program_counts(engine) -> dict:
     return {"pool_layout_copies": {n: pool_layout_copies(t, pools)
                                    for n, t in texts.items()},
             "weight_converts": {n: weight_converts(t, weights)
-                                for n, t in texts.items()}}
+                                for n, t in texts.items()},
+            "weight_layout_copies": {n: weight_layout_copies(t, weights)
+                                     for n, t in texts.items()}}
 
 
 def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
@@ -1142,7 +1252,8 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
     finally:
         engine.shutdown(drain_timeout=30.0)
     print(f"latent: pool copies in the decode programs "
-          f"{out['pool_layout_copies']}", flush=True)
+          f"{out['pool_layout_copies']}, weights re-laid "
+          f"{out['weight_layout_copies']}", flush=True)
     if kernels:
         R, page = sz["kr"] + sz["rope"], shape["page_size"]
         key = pallas_mla_attend.attend_key(jnp.bfloat16, sz["H"], R,
@@ -1173,6 +1284,9 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
         _check(not any(out["pool_layout_copies"].values()),
                f"the decode programs copy their state or pools: "
                f"{out['pool_layout_copies']}")
+        _check(not any(out["weight_layout_copies"].values()),
+               f"the decode programs re-lay their weights: "
+               f"{out['weight_layout_copies']}")
     return out
 
 

@@ -155,6 +155,16 @@ class YarnScaling(_Kind):
 _SCORE_BYTES = 1 << 29
 
 
+def _as_written(a):
+    """`a`, made where and as the code makes it: the identity, to values
+    and to gradients. The latent mixer puts it around each small product
+    it splits or turns afterwards. Without it XLA carries the split or the
+    turn through the product onto the weight beside it, and a compiled
+    decode step re-lays 17 to 50 MB matrices that never change to spare
+    a 4 MB activation (`tests/test_tpu_compile.py` counts)."""
+    return jax.lax.optimization_barrier(a)
+
+
 def _decay_params(k_dt, k_a, n_heads: int, dtype) -> dict:
     """A recurrent mixer's per-head decay as Mamba-2 draws it: `dt_bias`
     the inverse softplus of a step log-uniform in [1e-3, 1e-1], `A_log`
@@ -313,13 +323,19 @@ class LatentAttentionMixer(_Kind):
 
     def init_params(self, key, d: int, dtype, winit) -> dict:
         """The published `q_b_proj`, `kv_a_proj_with_mqa` and `kv_b_proj`
-        are each held as their two parts, in the layouts the absorbed
-        step reads them in (fused, XLA re-lays a 38 MB matrix out every
-        decode step to split heads of 128 + 64 off the lane grid: my
-        sandbox compile, PR 40): `Wqn` / `Wqr` the queries' nope and
-        rope columns, `Wkvc` / `Wkr` the latent's and the rope key's,
-        `Wkb` (H, nope, kv_rank) / `Wvb` (H, kv_rank, v) a head's key
-        and value expansions."""
+        are each held as their two parts (fused, XLA re-lays a 38 MB
+        matrix out every decode step to split heads of 128 + 64 off the
+        lane grid: my sandbox compile, PR 40): `Wqn` / `Wqr` the
+        queries' nope and rope columns, `Wkvc` / `Wkr` the latent's and
+        the rope key's, `Wkb` (H, nope, kv_rank) / `Wvb` (H, kv_rank, v)
+        a head's key and value expansions. The absorbed step reads each
+        of them AS IT LIES here: `project`, `absorb` and `out` are
+        written so that what a decode step turns is its own small
+        products (`_as_written`), and `tests/test_tpu_compile.py` holds
+        the compiled `decode_step` and `decode_chunked` of all three
+        latent configurations to no copy, transpose or re-tiling of a
+        weight (until PR 48 `Wqr` was re-laid three times and `Wqn`,
+        `Wkb` and `Wvb` once a dispatch)."""
         H, kr = self.n_heads, self.kv_rank
         k = jax.random.split(key, 9)
         kvw = H * (self.nope_dim + self.v_dim)
@@ -386,9 +402,16 @@ class LatentAttentionMixer(_Kind):
                 cq = cq * jnp.asarray(
                     self._lora_scale(p, self.scale_q_lora, self.q_rank),
                     cq.dtype)
-            q_n = (cq @ p["Wqn"]).reshape(*x.shape[:-1], H, self.nope_dim)
+            # fewer rows than the matrices have (a decode step's slots, a
+            # short prompt): both products as written, so that the heads'
+            # split and the rope's evens-first swap stay on them, off
+            # `Wqn` / `Wqr`; a long prompt's products are the larger
+            # side, and XLA may turn the matrices for them as it did
+            few = math.prod(x.shape[:-1]) < cq.shape[-1]
+            pin = _as_written if few else lambda a: a
+            q_n = pin(cq @ p["Wqn"]).reshape(*x.shape[:-1], H, self.nope_dim)
             q_r = self._rope(
-                (cq @ p["Wqr"]).reshape(*x.shape[:-1], H, self.rope_dim),
+                pin(cq @ p["Wqr"]).reshape(*x.shape[:-1], H, self.rope_dim),
                 positions, True)
         with jax.named_scope("mla.kv_down"):
             c = rms_norm(x @ p["Wkvc"], p["kvn_w"], self.eps)
@@ -401,7 +424,10 @@ class LatentAttentionMixer(_Kind):
         q_n,h | q_r,h]`, so that one product with a cached latent is the
         whole score."""
         with jax.named_scope("mla.absorb"):
-            qt = jnp.einsum("...hn,hnr->...hr", q_n, p["Wkb"]) \
+            # the product by head comes out heads first and is turned
+            # then: `Wkb` is read as it lies
+            qt = _as_written(jnp.einsum("...hn,hnr->h...r", q_n, p["Wkb"]))
+            qt = jnp.moveaxis(qt, 0, -2) \
                 * jnp.asarray(self._s_kv(p), q_n.dtype)
             return jnp.concatenate([qt, q_r], axis=-1)
 
@@ -420,8 +446,9 @@ class LatentAttentionMixer(_Kind):
         head, -> (..., d): up through `s_kv W^V_h`, under the head gate
         of the mixer's input `x` where the mixer has one, then `Wo`."""
         with jax.named_scope("mla.out"):
-            o = jnp.einsum("...hr,hrv->...hv", u, p["Wvb"]) \
-                * jnp.asarray(self._s_kv(p), u.dtype)
+            # heads first and turned then, as in `absorb`: `Wvb` as it lies
+            o = _as_written(jnp.einsum("...hr,hrv->h...v", u, p["Wvb"]))
+            o = jnp.moveaxis(o, 0, -2) * jnp.asarray(self._s_kv(p), u.dtype)
             o = self._gated(p, o, x)
             return o.reshape(*u.shape[:-2], -1) @ p["Wo"]
 

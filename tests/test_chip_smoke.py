@@ -340,6 +340,65 @@ def test_pool_layout_copies_counts_pool_shaped_copies_only():
     assert count("", {"bf16[137,16,128,128]"}) == 0
 
 
+# lines as XLA:TPU prints them (PR 48's parent, DeepSeek-V2's
+# `decode_chunked`; layouts kept, operand lists and configs cut): a
+# weight re-laid in the entry, handed to the loop and re-tiled there
+# alone and inside a fusion, and what is NOT a weight's re-lay: the
+# absorbed query of the same elements, a prefetch in the same layout
+_CANNED_WEIGHTS_HLO = """\
+HloModule jit_decode_chunked, is_scheduled=true
+%fused_computation.6.clone (param_0.1: bf16[1536,8192], param_1.2: bf16[128,1,1536]) -> bf16[128,1,8192] {
+  %param_0.1 = bf16[1536,8192]{0,1:T(8,128)(2,1)} parameter(0)
+  %param_1.2 = bf16[128,1,1536]{2,0,1:T(8,128)(2,1)S(1)} parameter(1)
+  %bitcast.9 = bf16[128,32,2,1536]{3,2,1,0:T(8,128)(2,1)} bitcast(%param_0.1)
+  %copy.77 = bf16[128,32,2,1536]{3,0,2,1:T(8,128)(2,1)} copy(%bitcast.9), metadata={op_name="jit(decode_chunked)/while/body/closed_call/mla.q/dot_general"}
+  ROOT %convolution.5 = bf16[128,1,8192]{2,0,1:T(8,128)(2,1)} convolution(%param_1.2, %copy.77), dim_labels=0bf_io0->0bf
+}
+%wide.region_0.46 (wide.param: (s32[], bf16[1536,8192], bf16[128,128,512], bf16[128,128,512])) -> (s32[], bf16[1536,8192], bf16[128,128,512], bf16[128,128,512]) {
+  %wide.param = (s32[]{:T(128)}, bf16[1536,8192]{0,1:T(8,128)(2,1)}, bf16[128,128,512]{1,2,0:T(8,128)(2,1)}, bf16[128,128,512]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  %get-tuple-element.7 = bf16[1536,8192]{0,1:T(8,128)(2,1)} get-tuple-element(%wide.param), index=1
+  %reshape.31 = bf16[128,32,2,1536]{3,2,1,0:T(2,128)(2,1)S(1)} reshape(%get-tuple-element.7)
+  %fusion.30 = bf16[128,1,8192]{2,0,1:T(8,128)(2,1)} fusion(%get-tuple-element.7, %rsqrt.4), kind=kOutput, calls=%fused_computation.6.clone
+  %get-tuple-element.9 = bf16[128,128,512]{2,1,0:T(8,128)(2,1)} get-tuple-element(%wide.param), index=3
+  %fusion.309 = bf16[128,512,128]{2,1,0:T(8,128)(2,1)S(1)} fusion(%get-tuple-element.9, %bitcast.347), kind=kOutput, calls=%fused_computation.25
+  %copy.234 = bf16[128,512,128]{1,0,2:T(8,128)(2,1)S(1)} copy(%fusion.309), metadata={op_name="jit(decode_chunked)/while/body/closed_call/mla.absorb/...hn,hnr->...hr/dot_general"}
+  ROOT %tuple.3 = (s32[]{:T(128)}, bf16[1536,8192]{0,1:T(8,128)(2,1)}, bf16[128,128,512]{1,2,0:T(8,128)(2,1)}, bf16[128,128,512]{2,1,0:T(8,128)(2,1)}) tuple(%add.1, %get-tuple-element.7, %get-tuple-element.8, %get-tuple-element.9)
+}
+ENTRY %main.33 (bp_1___mx_Wqr__.1: bf16[1536,8192], bp_1___mx_Wkb__.1: bf16[128,128,512], bp_1___ff_Wd__.1: bf16[12288,5120]) -> bf16[128,1,8192] {
+  %bp_1___mx_Wqr__.1 = bf16[1536,8192]{1,0:T(8,128)(2,1)} parameter(4), metadata={op_name="bp[1][\'mx_Wqr\']"}
+  %bp_1___mx_Wkb__.1 = bf16[128,128,512]{2,1,0:T(8,128)(2,1)} parameter(7), metadata={op_name="bp[1][\'mx_Wkb\']"}
+  %bp_1___ff_Wd__.1 = bf16[12288,5120]{1,0:T(8,128)(2,1)} parameter(9)
+  %copy.146 = bf16[1536,8192]{0,1:T(8,128)(2,1)S(1)} copy(%bp_1___mx_Wqr__.1)
+  %copy.147 = bf16[128,128,512]{1,2,0:T(8,128)(2,1)S(1)} copy(%bp_1___mx_Wkb__.1)
+  %copy-start.1 = (bf16[128,128,512]{1,2,0:T(8,128)(2,1)}, bf16[128,128,512]{1,2,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) copy-start(%copy.147)
+  %copy-done.1 = bf16[128,128,512]{1,2,0:T(8,128)(2,1)} copy-done(%copy-start.1)
+  %copy-start.2 = (bf16[12288,5120]{1,0:T(8,128)(2,1)S(1)}, bf16[12288,5120]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%bp_1___ff_Wd__.1)
+  %tuple.9 = (s32[]{:T(128)}, bf16[1536,8192]{0,1:T(8,128)(2,1)}, bf16[128,128,512]{1,2,0:T(8,128)(2,1)}, bf16[128,128,512]{2,1,0:T(8,128)(2,1)}) tuple(%constant.1, %copy.146, %copy-done.1, %bp_1___mx_Wkb__.1)
+  %while.1 = (s32[]{:T(128)}, bf16[1536,8192]{0,1:T(8,128)(2,1)}, bf16[128,128,512]{1,2,0:T(8,128)(2,1)}, bf16[128,128,512]{2,1,0:T(8,128)(2,1)}) while(%tuple.9), condition=%wide.cond, body=%wide.region_0.46
+  ROOT %fusion.9 = bf16[128,1,8192]{2,0,1:T(8,128)(2,1)} fusion(%while.1), kind=kLoop, calls=%fused_computation.77
+}
+"""
+
+
+def test_weight_layout_copies_follows_a_weight_and_nothing_else():
+    """Counted: the entry's two copies of a parameter of a weight's
+    shape, and in the loop's body, which is handed the copy, its
+    `reshape` and the `copy` inside the fusion that multiplies by it.
+    Not counted: the copy of the absorbed query (a product's result
+    with a weight's elements), `copy-start`s into the same layout, and
+    anything of a parameter that is no weight."""
+    ops = chip_smoke.weight_layout_ops
+    weights = {"bf16[1536,8192]", "bf16[128,128,512]", "bf16[12288,5120]"}
+    assert [op.split("{")[0] for op in ops(_CANNED_WEIGHTS_HLO, weights)] == [
+        "copy bf16[1536,8192]", "copy bf16[128,128,512]",
+        "reshape bf16[128,32,2,1536]", "copy bf16[128,32,2,1536]"]
+    assert chip_smoke.weight_layout_copies(_CANNED_WEIGHTS_HLO, weights) == 4
+    assert ops(_CANNED_WEIGHTS_HLO, {"bf16[128,128,512]"}) == [
+        "copy bf16[128,128,512]{1,2,0:T(8,128)(2,1)S(1)}"]
+    assert ops(_CANNED_WEIGHTS_HLO, {"bf16[128,512,128]"}) == []
+    assert ops("", weights) == []
+
+
 def test_multichip_phase_toy_on_the_virtual_mesh():
     gpt = dict(GPT, n_heads=4)  # tp=4 needs four heads
     train = dict(T=32, batch=4, block=16, steps=3)

@@ -28,12 +28,49 @@ CONSTS = ref.Consts(q_rank=24, kv_rank=16, nope=8, rope=4, v_dim=8,
                     held_first=0)
 
 
+# the benchmark's three latent configurations' head counts, each on the
+# toy widths and in its own flavour: LongCat's (both lora scales),
+# DeepSeek-V2's (YaRN), Ling's (full-rank queries, a gate a head)
+BY_HEADS = {
+    4: MIXER,
+    64: dataclasses.replace(MIXER, n_heads=64),
+    128: dataclasses.replace(
+        MIXER, n_heads=128, scale_q_lora=False, scale_kv_lora=False,
+        rope_scaling=dict(kind="yarn", factor=4.0, original_max=8,
+                          beta_fast=4.0, beta_slow=1.0, mscale=0.707,
+                          mscale_all_dim=0.707)),
+    32: dataclasses.replace(MIXER, n_heads=32, q_rank=None,
+                            scale_q_lora=False, head_gate=True)}
+heads = pytest.mark.parametrize("n_heads", list(BY_HEADS),
+                                ids=lambda h: f"{h}-heads")
+
+
+# the loss of `test_training_through_the_forward_is_the_parents` and its
+# gradient's norm by leaf as the PARENT of PR 48 computes them (its
+# checkout at c511a3c under this file's `_params` and `_x`, on the CPU
+# under x64, as `conftest.py` sets it)
+PARENT_GRADIENTS = {
+    4: (1.9019822190507227, {
+        "Wkb": 0.6376142695503351, "Wkr": 0.3802957251456847,
+        "Wkvc": 1.6468304808787475, "Wo": 0.685206151384827,
+        "Wqa": 1.1112564869412824, "Wqn": 0.6582311431813809,
+        "Wqr": 0.22858757317118558, "Wvb": 0.8512571896333411,
+        "kvn_w": 0.7027468144284919, "qn_w": 0.20423964369677153}),
+    32: (1.2408171880238354, {
+        "Wa": 0.1385942009937708, "Wkb": 0.14171130026453743,
+        "Wkr": 0.10405339072378934, "Wkvc": 0.5282793152893134,
+        "Wo": 0.71624989624226, "Wqn": 0.2249589082793907,
+        "Wqr": 0.07191471657507807, "Wvb": 0.19943235601383444,
+        "kvn_w": 0.20271327238101983})}
+
+
 def _params(mixer=MIXER, seed=0):
     p = mixer.init_params(
         jax.random.PRNGKey(seed), D, jnp.float32,
         lambda k, shape, fi, fo: jax.random.normal(k, shape) / fi ** 0.5)
     k1, k2 = jax.random.split(jax.random.PRNGKey(seed + 1))
-    p["qn_w"] = 1.0 + 0.1 * jax.random.normal(k1, p["qn_w"].shape)
+    if "qn_w" in p:
+        p["qn_w"] = 1.0 + 0.1 * jax.random.normal(k1, p["qn_w"].shape)
     p["kvn_w"] = 1.0 + 0.1 * jax.random.normal(k2, p["kvn_w"].shape)
     return p
 
@@ -42,13 +79,13 @@ def _x(seed=3, t=T):
     return jax.random.normal(jax.random.PRNGKey(seed), (1, t, D))
 
 
-def _reference(p, x, c=CONSTS):
+def _reference(p, x, c=CONSTS, n_heads=MIXER.n_heads):
     names = {"Wqa": "Wqa0", "qn_w": "qn0", "Wqn": "Wqn0", "Wqr": "Wqr0",
              "Wkvc": "Wkvc0", "Wkr": "Wkr0", "kvn_w": "kvn0", "Wkb": "Wkb0",
              "Wvb": "Wvb0", "Wo": "Wo0"}
     with jax.default_matmul_precision("highest"):
         return ref.mla({names[k]: v for k, v in p.items()}, 0, x[0],
-                       jnp.arange(x.shape[1]), c, n_heads=MIXER.n_heads,
+                       jnp.arange(x.shape[1]), c, n_heads=n_heads,
                        eps=MIXER.eps, precision="float32")
 
 
@@ -65,10 +102,16 @@ def test_the_kind_round_trips_through_json():
     assert abs(kind.sm_scale - 192 ** -0.5) < 1e-12
 
 
-def test_the_expanded_forward_equals_the_reference():
-    p, x = _params(), _x()
-    np.testing.assert_allclose(MIXER.forward(p, x)[0], _reference(p, x),
-                               atol=2e-5)
+@heads
+def test_the_expanded_forward_equals_the_reference(n_heads):
+    """At the toy's four heads and at the three cells' head counts (the
+    reference is LongCat's: its flavour at every count; the other two
+    flavours are held to their own in `test_deepseek_v2.py` and
+    `test_ling_flash.py`)."""
+    mixer = dataclasses.replace(MIXER, n_heads=n_heads)
+    p, x = _params(mixer), _x()
+    np.testing.assert_allclose(mixer.forward(p, x)[0],
+                               _reference(p, x, n_heads=n_heads), atol=2e-5)
 
 
 @pytest.mark.parametrize("drop", ["rope_theta", "scale_q_lora",
@@ -83,25 +126,73 @@ def test_rotary_and_both_lora_scales_matter(drop):
     assert off > 100 * 2e-5
 
 
-def test_the_three_forms_agree():
+@heads
+def test_the_three_forms_agree(n_heads):
     """The whole sequence expanded; its second half as a chunk of
     absorbed queries against the cached latents; its last position as
-    the absorbed one-token step."""
-    p, x = _params(), _x()
-    want = MIXER.forward(p, x)[0]
+    the absorbed one-token step, and that step as the decode program
+    runs it: one query a SLOT, no sequence axis."""
+    mixer = BY_HEADS[n_heads]
+    p, x = _params(mixer), _x()
+    want = mixer.forward(p, x)[0]
     pos = jnp.arange(T)
-    q_n, q_r, latent = MIXER.project(p, x, pos)
+    q_n, q_r, latent = mixer.project(p, x, pos)
     # the chunk form: positions 10.. against every cached latent (those
     # past a query's position are masked)
-    q_abs = MIXER.absorb(p, q_n[:, 10:], q_r[:, 10:])
-    got = MIXER.out(p, MIXER.attend_latents(q_abs, latent, pos[None, 10:]))
+    q_abs = mixer.absorb(p, q_n[:, 10:], q_r[:, 10:])
+    got = mixer.out(p, mixer.attend_latents(q_abs, latent, pos[None, 10:]),
+                    x[:, 10:])
     np.testing.assert_allclose(got[0], want[10:], atol=2e-5)
     # the absorbed step: ONE query, projected alone at its own position
-    q_n1, q_r1, lat1 = MIXER.project(p, x[:, -1:], pos[None, -1:])
+    q_n1, q_r1, lat1 = mixer.project(p, x[:, -1:], pos[None, -1:])
     np.testing.assert_allclose(lat1[0, 0], latent[0, -1], atol=1e-6)
-    step = MIXER.out(p, MIXER.attend_latents(
-        MIXER.absorb(p, q_n1, q_r1), latent, pos[None, -1:]))
+    q_abs1 = mixer.absorb(p, q_n1, q_r1)
+    u = mixer.attend_latents(q_abs1, latent, pos[None, -1:])
+    step = mixer.out(p, u, x[:, -1:])
     np.testing.assert_allclose(step[0, 0], want[-1], atol=2e-5)
+    # `LatentPages.mix_decode`'s shapes: (S, H, .) in, (S, d) out
+    np.testing.assert_array_equal(
+        mixer.absorb(p, q_n1[:, 0], q_r1[:, 0]), q_abs1[:, 0])
+    np.testing.assert_allclose(mixer.out(p, u[:, 0], x[:, -1]), step[:, 0],
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("n_heads", (4, 32), ids=lambda h: f"{h}-heads")
+def test_training_through_the_forward_is_the_parents(n_heads, monkeypatch):
+    """`project` pins its two query products as written
+    (`decoder_block._as_written`, an optimization barrier): the identity
+    to the loss and to its gradient by every leaf and by the input.
+    Against the same forward with the barrier taken out, which is the
+    parent's program, and against the parent's own numbers (its checkout
+    at c511a3c, this file's `_params` and `_x`, on the CPU under x64)."""
+    from deeplearning4j_tpu.nn.conf import decoder_block
+
+    mixer = BY_HEADS[n_heads]
+    p, x = _params(mixer), _x()
+
+    def traced_now():  # a new function each time: nothing traced before
+        def loss(p, x):
+            y = mixer.forward(p, x)
+            return jnp.mean((y - jnp.roll(x, 1, axis=1)) ** 2)
+        return loss
+
+    value, (gp, gx) = jax.value_and_grad(traced_now(), argnums=(0, 1))(p, x)
+    assert "optimization_barrier" in str(jax.make_jaxpr(traced_now())(p, x))
+    monkeypatch.setattr(decoder_block, "_as_written", lambda a: a)
+    assert "optimization_barrier" not in str(
+        jax.make_jaxpr(traced_now())(p, x))
+    want, (wp, wx) = jax.value_and_grad(traced_now(), argnums=(0, 1))(p, x)
+    np.testing.assert_allclose(value, want, rtol=1e-6)
+    np.testing.assert_allclose(gx, wx, rtol=1e-5, atol=1e-8)
+    for name in p:
+        np.testing.assert_allclose(gp[name], wp[name], rtol=1e-5, atol=1e-8,
+                                   err_msg=name)
+    norms = {name: float(jnp.linalg.norm(g)) for name, g in gp.items()}
+    parent_value, parent_norms = PARENT_GRADIENTS[n_heads]
+    np.testing.assert_allclose(value, parent_value, rtol=1e-4)
+    assert norms.keys() == parent_norms.keys()
+    for name, n in parent_norms.items():
+        np.testing.assert_allclose(norms[name], n, rtol=1e-4, err_msg=name)
 
 
 def test_a_cached_latent_is_the_normed_latent_and_the_turned_rope_key():

@@ -542,70 +542,106 @@ def test_latent_kernels_compile_at_the_published_widths(one_chip,
     assert pme.f_tile(128, 6144, 2048, jnp.bfloat16) == 1024
 
 
-def test_shortcut_layer_decode_step_compiles_at_the_published_widths(
-        one_chip, monkeypatch):
-    """One layer of LongCat-Flash-Chat at its published widths (128
-    slots, 2560 pages of 128) through `build_programs`, its three kernel
-    families steered on as they are on the chip: two latent writes, two
-    paged latent attentions and the tiled grouped experts in one step,
-    and no pool-shaped copy left in the program."""
+def _latent_programs(one_chip, monkeypatch, fam, cfg, layers, pool_pages,
+                     max_len, float32=()):
+    """A latent family's net at `cfg`'s widths, described and not drawn
+    (`layers(sz)`: each layer's leaf names; `float32`: those not held
+    in bfloat16), through `build_programs` for 128 slots and pages of
+    128 with its kernel families steered on as they are on the chip.
+    Returns the plan, the programs, their (parameters, caches), the
+    decode programs' other operands and, for a prompt bucket, a
+    prefill's; lower inside `jax.enable_x64(False)`."""
     from types import SimpleNamespace
 
-    import chip_smoke
     from deeplearning4j_tpu.models.transformer import GPTPlan
-    from deeplearning4j_tpu.ops import pallas_mla_attend, pallas_moe_experts
+    from deeplearning4j_tpu.ops import (
+        pallas_delta_step, pallas_mla_attend, pallas_moe_experts,
+    )
     from deeplearning4j_tpu.serving import block_state, decode_programs
-    from perfbench.families import longcat_flash as fam
 
-    for mod in (pallas_mla_attend, pallas_moe_experts):
+    for mod in (pallas_mla_attend, pallas_moe_experts, pallas_delta_step):
         monkeypatch.setattr(mod, "_platform_supported", lambda: True)
         monkeypatch.setattr(mod, "_probe_verdict", lambda *a, **k: True)
         monkeypatch.setattr(mod, "_vmem_limit", lambda: 112 << 20)
-    sz = fam.sizes(chip_smoke.LATENT)
+    sz = fam.sizes(cfg)
     S = _shapes(one_chip)
     shapes = fam._leaf_shapes(sz)
     tree = {n: S(shapes[n]) for n in fam.TOP_LEAVES}
     tree["layers"] = [
-        {n: S(shapes[n], jnp.float32 if n in fam.FLOAT32_LEAVES
-              else jnp.bfloat16) for n in fam.LAYER_LEAVES}]
+        {n: S(shapes[n], jnp.float32 if n in float32 else jnp.bfloat16)
+         for n in names} for names in layers(sz)]
     net = fam.build_net(sz, training=False)
-    net._params = fam.to_program(tree)
+    net._params = [{k: S(v.shape, v.dtype) for k, v in p.items()}
+                   for p in fam.to_program(tree)]
     plan = GPTPlan(net)
-    assert plan.state_kinds() == [("latent", "latent")]
-    assert plan.latent_geometry() == [(512, 64)] * 2
-    assert plan.kv_geometry() == []
     n_slots, page = 128, 128
     states = block_state.block_states(plan, SimpleNamespace(
-        n_slots=n_slots, page=page, pool_pages=2560, cdt=plan.cdt,
+        n_slots=n_slots, page=page, pool_pages=pool_pages, cdt=plan.cdt,
         kv_quant=None, tp_shard=None, tp_axis=None))
+    i32, f32 = jnp.int32, jnp.float32
     with jax.enable_x64(False):
         programs = decode_programs.build_programs(
-            plan, states, n_slots=n_slots, page=page, L_logical=4096,
+            plan, states, n_slots=n_slots, page=page, L_logical=max_len,
             decode_chunk=4, top_k=0, logprobs=0, tp=None, donate=True)
         caches = [jax.tree.map(lambda a: S(a.shape, a.dtype),
                                jax.eval_shape(st.alloc)) for st in states]
-        i32, f32 = jnp.int32, jnp.float32
-        text = programs.decode_step.lower(
-            net._params, caches, S((n_slots, 4096 // page), i32),
-            S((n_slots,), i32), S((n_slots,), i32),
-            S((n_slots, 2), jnp.uint32), S((n_slots,), f32),
-            S((n_slots,), jnp.bool_)).compile().as_text()
-        # a 512-token prompt: 1 choice in 48 falls on the 16 held of
-        # the router's 768, so its rows go sorted at a third of the worst
-        # case's size, the walk the other branch of a conditional
-        prefill = programs.prefill.lower(
-            net._params, caches, S((1, 512), i32), S((), i32), S((), i32),
-            S((512 // page,), i32), S((n_slots,), i32), S((n_slots,), i32),
-            S((n_slots, 2), jnp.uint32), S((n_slots,), f32),
-            S((2,), jnp.uint32), S((2,), jnp.uint32),
-            S((), f32)).compile().as_text()
-    assert "moe_experts_sorted" in prefill and "conditional" in prefill
-    assert f"f32[{24 * 128},6144]" in prefill
+    slot_args = (S((n_slots,), i32), S((n_slots,), i32),
+                 S((n_slots, 2), jnp.uint32), S((n_slots,), f32))
+    decode_args = (S((n_slots, max_len // page), i32), *slot_args,
+                   S((n_slots,), jnp.bool_))
+    prefill_args = lambda T: (
+        S((1, T), i32), S((), i32), S((), i32), S((T // page,), i32),
+        *slot_args, S((2,), jnp.uint32), S((2,), jnp.uint32), S((), f32))
+    return plan, programs, (net._params, caches), decode_args, prefill_args
+
+
+def _assert_no_weight_is_relaid(text, params):
+    """No instruction of the compiled program `text` copies, transposes
+    or re-tiles a leaf of `params` of 1 MB or more."""
+    import chip_smoke
+
+    ops = chip_smoke.weight_layout_ops(text, chip_smoke._hlo_shapes(
+        a for a in jax.tree.leaves(params) if a.ndim >= 2))
+    assert ops == []
+
+
+@pytest.mark.parametrize("program", ("decode_step", "decode_chunked"))
+def test_shortcut_layer_decode_step_compiles_at_the_published_widths(
+        one_chip, monkeypatch, program):
+    """One layer of LongCat-Flash-Chat at its published widths (128
+    slots, 2560 pages of 128) through `build_programs`, its three kernel
+    families steered on as they are on the chip: two latent writes, two
+    paged latent attentions and the tiled grouped experts in one step,
+    no pool-shaped copy left in the program and no weight re-laid, in
+    the step alone and in the chunk of four that serves."""
+    import chip_smoke
+    from perfbench.families import longcat_flash as fam
+
+    plan, programs, held, decode_args, prefill_args = _latent_programs(
+        one_chip, monkeypatch, fam, chip_smoke.LATENT,
+        lambda sz: [fam.LAYER_LEAVES], 2560, 4096, fam.FLOAT32_LEAVES)
+    assert plan.state_kinds() == [("latent", "latent")]
+    assert plan.latent_geometry() == [(512, 64)] * 2
+    assert plan.kv_geometry() == []
+    with jax.enable_x64(False):
+        text = getattr(programs, program).lower(
+            *held, *decode_args).compile().as_text()
     assert text.count("tpu_custom_call") == 5
     for name in ("mla_attend", "latent_write", "moe_experts"):
         assert name in text
     assert "moe_experts_sorted" not in text
     assert chip_smoke.pool_layout_copies(text, {"bf16[2561,576,128]"}) == 0
+    _assert_no_weight_is_relaid(text, held[0])
+    if program == "decode_chunked":
+        return
+    # a 512-token prompt: 1 choice in 48 falls on the 16 held of
+    # the router's 768, so its rows go sorted at a third of the worst
+    # case's size, the walk the other branch of a conditional
+    with jax.enable_x64(False):
+        prefill = programs.prefill.lower(
+            *held, *prefill_args(512)).compile().as_text()
+    assert "moe_experts_sorted" in prefill and "conditional" in prefill
+    assert f"f32[{24 * 128},6144]" in prefill
 
 
 def test_latent_kernels_compile_at_128_heads(one_chip, monkeypatch):
@@ -657,67 +693,59 @@ def test_latent_kernels_compile_at_128_heads(one_chip, monkeypatch):
     assert pme.f_tile(512, 5120, 1536, jnp.bfloat16) == 768
 
 
+@pytest.mark.parametrize("family,program", [
+    ("deepseek_v2", "decode_step"), ("deepseek_v2", "decode_chunked"),
+    ("ling_flash", "decode_chunked")])
 def test_one_sub_layer_latent_net_compiles_at_the_published_widths(
-        one_chip, monkeypatch):
+        one_chip, monkeypatch, family, program):
     """DeepSeek-V2's leading dense layer and one routed layer at their
     published widths (128 slots, 8448 pages of 128, rows of 96 pages)
     through `build_programs`, the kernel families steered on as they are
     on the chip: a decode step of two latent writes, two paged latent
-    attentions and the grouped experts with no pool-shaped copy, and a
+    attentions and the grouped experts with no pool-shaped copy and no
+    weight re-laid, alone and in the chunk of four that serves, and a
     4,096-token prefill whose attention goes through the `mla_prefill`
     kernel and whose experts take their rows sorted: no
-    (128, 4096, 4096) array, under 1.5 GB of temporaries."""
-    from types import SimpleNamespace
+    (128, 4096, 4096) array, under 1.5 GB of temporaries. And
+    Ling-3.0-flash's pair of layers (a delta-rule layer, then latent
+    attention at 32 heads with full-rank queries and the head gate):
+    the chunk re-lays no weight either."""
+    import importlib
 
     import chip_smoke
-    from deeplearning4j_tpu.models.transformer import GPTPlan
-    from deeplearning4j_tpu.ops import pallas_mla_attend, pallas_moe_experts
-    from deeplearning4j_tpu.serving import block_state, decode_programs
-    from perfbench.families import deepseek_v2 as fam
 
-    for mod in (pallas_mla_attend, pallas_moe_experts):
-        monkeypatch.setattr(mod, "_platform_supported", lambda: True)
-        monkeypatch.setattr(mod, "_probe_verdict", lambda *a, **k: True)
-        monkeypatch.setattr(mod, "_vmem_limit", lambda: 112 << 20)
-    sz = fam.sizes(chip_smoke.LATENT_H128)
-    S = _shapes(one_chip)
-    shapes = fam._leaf_shapes(sz)
-    tree = {n: S(shapes[n]) for n in fam.TOP_LEAVES}
-    tree["layers"] = [{n: S(shapes[n]) for n in names}
-                      for names in (fam.DENSE_LEAVES, fam.MOE_LEAVES)]
-    net = fam.build_net(sz, training=False)
-    net._params = [{k: S(v.shape, v.dtype) for k, v in p.items()}
-                   for p in fam.to_program(tree)]
-    plan = GPTPlan(net)
-    assert plan.state_kinds() == ["latent", "latent"]
-    assert plan.latent_geometry() == [(512, 64)] * 2
-    n_slots, page, max_len = 128, 128, 12288
-    states = block_state.block_states(plan, SimpleNamespace(
-        n_slots=n_slots, page=page, pool_pages=8448, cdt=plan.cdt,
-        kv_quant=None, tp_shard=None, tp_axis=None))
-    i32, f32 = jnp.int32, jnp.float32
-    slot_args = (S((n_slots,), i32), S((n_slots,), i32),
-                 S((n_slots, 2), jnp.uint32), S((n_slots,), f32))
+    fam = importlib.import_module(f"perfbench.families.{family}")
+    described, kinds, pool, calls = {
+        "deepseek_v2": ((chip_smoke.LATENT_H128,
+                         lambda sz: (fam.DENSE_LEAVES, fam.MOE_LEAVES),
+                         8448, 12288),
+                        ["latent", "latent"], "bf16[8449,576,128]", 5),
+        "ling_flash": ((chip_smoke.LATENT_KDA,
+                        lambda sz: [fam.layer_leaves(sz, i)
+                                    for i in range(sz["L"])],
+                        2560, 4096, ("rb",)),
+                       ["recurrent", "latent"], "bf16[2561,576,128]", 4),
+    }[family]
+    plan, programs, held, decode_args, prefill_args = _latent_programs(
+        one_chip, monkeypatch, fam, *described)
+    assert plan.state_kinds() == kinds
+    assert plan.latent_geometry() == [(512, 64)] * kinds.count("latent")
     with jax.enable_x64(False):
-        programs = decode_programs.build_programs(
-            plan, states, n_slots=n_slots, page=page, L_logical=max_len,
-            decode_chunk=4, top_k=0, logprobs=0, tp=None, donate=True)
-        caches = [jax.tree.map(lambda a: S(a.shape, a.dtype),
-                               jax.eval_shape(st.alloc)) for st in states]
-        step = programs.decode_step.lower(
-            net._params, caches, S((n_slots, max_len // page), i32),
-            *slot_args, S((n_slots,), jnp.bool_)).compile().as_text()
-        prefill = programs.prefill.lower(
-            net._params, caches, S((1, 4096), i32), S((), i32), S((), i32),
-            S((4096 // page,), i32), *slot_args, S((2,), jnp.uint32),
-            S((2,), jnp.uint32), S((), f32)).compile()
-    assert step.count("tpu_custom_call") == 5
+        step = getattr(programs, program).lower(
+            *held, *decode_args).compile().as_text()
+    assert step.count("tpu_custom_call") == calls
     for name in ("mla_attend", "latent_write", "moe_experts"):
         assert name in step
-    assert chip_smoke.pool_layout_copies(step, {"bf16[8449,576,128]"}) == 0
+    assert chip_smoke.pool_layout_copies(step, {pool}) == 0
+    assert "moe_experts_sorted" not in step and "mla_prefill" not in step
+    _assert_no_weight_is_relaid(step, held[0])
+    if (family, program) != ("deepseek_v2", "decode_step"):
+        return
+    with jax.enable_x64(False):
+        prefill = programs.prefill.lower(
+            *held, *prefill_args(4096)).compile()
     text = prefill.as_text()
     assert "[128,4096,4096]" not in text
     for name in ("mla_prefill", "moe_experts_sorted"):
         assert name in text
-    assert "moe_experts_sorted" not in step and "mla_prefill" not in step
     assert prefill.memory_analysis().temp_size_in_bytes < 1.5e9
