@@ -233,6 +233,33 @@ LATENT_KDA = dict(vocab_size=256, hidden_size=2560, num_hidden_layers=2,
                   deployment=dict(num_experts_published=512,
                                   experts_held_first=0))
 LATENT_KDA_SERVE = ROUTED_SERVE
+# Command A+'s published widths under its config's own keys
+# (`perfbench/families/cohere2_moe.py` reads them): one period of three
+# sliding-window layers with rotary and one full layer without positions,
+# 128 query heads over 8 K/V heads, 8 of the 128 experts held
+WINDOW = dict(vocab_size=256, hidden_size=4096, num_hidden_layers=4,
+              layer_switch=4,
+              layer_types=["sliding_attention"] * 3 + ["full_attention"],
+              order_of_interleaved_layers="local_attn_first",
+              num_attention_heads=128, num_key_value_heads=8, head_dim=128,
+              sliding_window=4096, position_embedding_type="rope_gptj",
+              rotary_pct=1, rope_theta=50000, attention_bias=False,
+              use_qk_norm=False, use_parallel_block=True,
+              hidden_act="silu", use_gated_activation=True,
+              intermediate_size=4096, num_experts=8, num_experts_per_tok=8,
+              num_shared_experts=4, expert_selection_fn="sigmoid",
+              norm_topk_prob=True,
+              shared_expert_combination_strategy="average",
+              first_k_dense_replace=0, layer_norm_eps=1e-5, logit_scale=1,
+              tie_word_embeddings=True,
+              deployment=dict(num_experts_published=128,
+                              experts_held_first=0))
+# a prompt that fills the 4,096 bucket's last page and decodes past 4,096
+# + 128 positions, so that its ring of 33 pages wraps; two short ones; and
+# one longer than every bucket AND than the ring, in chunks of 128
+WINDOW_SERVE = dict(n_slots=16, max_len=4864, page_size=128,
+                    prefill_chunk=128, n_short=2, short_len=512,
+                    ring_len=4000, long_len=4400, n_tokens=320)
 LSTM = dict(vocab=256, hidden=1024, T=64, batch=2048, steps=2)
 
 # A greedy token may differ between two correct attention paths only
@@ -674,7 +701,7 @@ def _decode_program_counts(engine) -> dict:
         w for i in (plan.emb_i, *plan.block_is)
         for w in jax.tree_util.tree_leaves(engine._weights[i])
         if w.ndim >= 2 and w.dtype == plan.cdt)
-    args = (engine._weights, engine._caches, engine._page_table,
+    args = (engine._weights, engine._caches, engine._pool.tables,
             engine._tok, engine._pos, engine._keys, engine._temps,
             jnp.asarray(engine._active))
     texts = {name: fn.lower(*args).compile().as_text()
@@ -1290,6 +1317,132 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
     return out
 
 
+def phase_window(win: dict, shape: dict, *, kernels: bool,
+                 dtype=None) -> dict:
+    """A net of window layers with rotary and full layers without
+    positions (`cohere2_moe`), through the engine: a slot decodes past
+    its window and a page, so that its ring wraps; a prompt longer than
+    the ring rides chunks; against the family's reference and against
+    the dense paths (gather-and-attend over the ring, attention by
+    blocks of keys)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.serving.decode_engine import DecodeEngine
+    from perfbench.families import cohere2_moe as fam
+    from perfbench.families import cohere2_moe_reference as ref
+
+    dtype = dtype or jnp.bfloat16
+    vocab, n_tokens = win["vocab_size"], shape["n_tokens"]
+    sz = fam.sizes(win)
+    weights = fam.make_weights(0, sz)
+    net = fam.build_net(sz, training=False, dtype=dtype)
+    fam.install(net, jax.tree.map(
+        lambda a: a if a.dtype == jnp.float32 else a.astype(dtype), weights))
+    rng = np.random.default_rng(1)
+    lens = [shape["short_len"]] * shape["n_short"] \
+        + [shape["ring_len"], shape["long_len"]]
+    prompts = [rng.integers(0, vocab, n).astype(np.int32) for n in lens]
+    bucket = -(-shape["ring_len"] // shape["page_size"]) * shape["page_size"]
+    gen = dict(_engine_kwargs(shape),
+               prompt_buckets=(shape["short_len"], bucket))
+    toks, stats = _through_engine(net, prompts, n_tokens, **gen)
+    _check_tokens(toks, n_tokens, vocab, stats, len(prompts), "window")
+    page, W = shape["page_size"], sz["W"]
+    ring = W // page + 1
+    blocks = _blocks_by_state(net)
+    _check(stats["window_blocks"] == blocks["window"] == sz["window_layers"]
+           and stats["kv_blocks"] == blocks["kv"] == sz["full_layers"],
+           f"blocks by cache kind: {stats['window_blocks']} window, "
+           f"{stats['kv_blocks']} kv of the net's {dict(blocks)}")
+    _check(stats["window_ring_pages"] == ring
+           and stats["window_pages_in_use"] == 0
+           and stats["window_pages_in_use_peak"]
+           <= shape["n_slots"] * ring,
+           f"ring of {stats['window_ring_pages']} pages (want {ring}), "
+           f"{stats['window_pages_in_use']} still held, peak "
+           f"{stats['window_pages_in_use_peak']}")
+    _check(shape["ring_len"] + n_tokens > W + page,
+           "the ring prompt does not decode past a window and a page")
+    n_chunks = -(-shape["long_len"] // shape["prefill_chunk"])
+    _check(stats["prefill_chunks"] >= n_chunks,
+           f"long prompt did not ride chunked prefill: "
+           f"{stats['prefill_chunks']} chunks < {n_chunks}")
+    loop = stats["loop"]
+    _check(0 < loop["kv_positions_attended"] < loop["kv_positions_context"],
+           f"positions attended {loop['kv_positions_attended']} of "
+           f"{loop['kv_positions_context']}: the windows never bit")
+    out = {"requests": len(prompts), "tokens": int(sum(map(len, toks))),
+           "prefill_chunks": stats["prefill_chunks"],
+           "decode_steps": stats["decode_steps"],
+           "window_blocks": stats["window_blocks"],
+           "kv_blocks": stats["kv_blocks"],
+           "window_ring_pages": stats["window_ring_pages"],
+           "window_pages_in_use_peak": stats["window_pages_in_use_peak"],
+           "window_bytes_per_slot": stats["window_bytes_per_slot"],
+           "window_attended_pct": round(
+               100.0 * loop["kv_positions_attended"]
+               / loop["kv_positions_context"], 2),
+           **_experts_read(stats, "window")}
+    gc.collect()
+
+    # the prompt whose ring wrapped and the chunked one against the plain
+    # reference's full forward
+    picked = (len(prompts) - 2, len(prompts) - 1)
+    out["reference_gaps"] = [round(g, 5) for g in _reference_gaps(
+        fam, ref, win, sz, weights, [prompts[i] for i in picked],
+        [toks[i] for i in picked])]
+    _check(max(out["reference_gaps"]) < ROUTED_REFERENCE_GAP,
+           f"served tokens lie {out['reference_gaps']} under the "
+           f"reference's best logit")
+    gc.collect()
+
+    # the same prompts on the dense paths
+    off = ("DL4J_TPU_NO_PALLAS_PAGED_ATTENTION",
+           "DL4J_TPU_NO_PALLAS_ATTENTION")
+    os.environ.update(dict.fromkeys(off, "1"))
+    try:
+        xla, xla_stats = _through_engine(net, prompts, n_tokens, **gen)
+    finally:
+        for name in off:
+            del os.environ[name]
+    _check_tokens(xla, n_tokens, vocab, xla_stats, len(prompts),
+                  "xla-window")
+    out["agreement"] = _agreement(net, prompts, toks, xla,
+                                  "kernel and XLA windowed attention")
+    gc.collect()
+
+    engine = DecodeEngine(net, **gen)
+    try:
+        out.update(_decode_program_counts(engine))
+    finally:
+        engine.shutdown(drain_timeout=30.0)
+    print(f"window: pool copies in the decode programs "
+          f"{out['pool_layout_copies']}, weights re-laid "
+          f"{out['weight_layout_copies']}", flush=True)
+    if kernels:
+        H, Hkv, hd = sz["H"], sz["Hkv"], sz["hd"]
+        for tail in (("dense",), ("dense", "window", W)):
+            key = ("bfloat16", 1, H, Hkv, hd, page) + tail
+            _check(engaged("paged_attention", lambda k: k == key),
+                   f"paged attention did not engage for {key}")
+        for w in ("full", ("window", W)):
+            _check(engaged("flash_attention", lambda k: k[3:] == (
+                "forward", H // Hkv, w)),
+                   f"the flash forward did not serve the {bucket}-token "
+                   f"prompt's {w} layers")
+        key = ("bfloat16", shape["n_slots"], sz["d"], sz["f"])
+        _check(engaged("moe_experts", lambda k: k == key),
+               f"grouped expert kernel did not engage for {key}")
+        _check(not any(out["pool_layout_copies"].values()),
+               f"the decode programs copy their pools: "
+               f"{out['pool_layout_copies']}")
+        _check(not any(out["weight_layout_copies"].values()),
+               f"the decode programs re-lay their weights: "
+               f"{out['weight_layout_copies']}")
+    return out
+
+
 # -------------------------------------------------------------- multichip
 def phase_multichip(gpt: dict, train: dict, serve: dict,
                     one_chip_loss: float) -> dict:
@@ -1391,12 +1544,13 @@ def main(argv=None) -> int:
 
     names = list(sys.argv[1:] if argv is None else argv) \
         or ["train", "serve", "hybrid", "linear", "sublayer", "latent",
-            "lstm", "multichip"]
+            "window", "lstm", "multichip"]
     unknown = set(names) - {"train", "serve", "hybrid", "linear",
-                            "sublayer", "latent", "lstm", "multichip"}
+                            "sublayer", "latent", "window", "lstm",
+                            "multichip"}
     if unknown or ("multichip" in names and "train" not in names):
         print(f"chip_smoke: phases are train serve hybrid linear sublayer "
-              f"latent lstm multichip "
+              f"latent window lstm multichip "
               f"(multichip compares against train's loss, so name both); "
               f"got {names}", file=sys.stderr)
         return 2
@@ -1446,6 +1600,8 @@ def main(argv=None) -> int:
                 LATENT_H128_SERVE, kernels=True, family="deepseek_v2")
             run("latent_kda", phase_latent, LATENT_KDA, LATENT_KDA_SERVE,
                 kernels=True, family="ling_flash")
+        if "window" in names:
+            run("window", phase_window, WINDOW, WINDOW_SERVE, kernels=True)
         if "lstm" in names:
             run("lstm", phase_lstm, LSTM, kernels=True)
         if "multichip" in names:

@@ -33,9 +33,11 @@ from deeplearning4j_tpu.nn.conf.decoder_block import (
     GatedDeltaNetMixer,
     GatedMLP,
     LatentAttentionMixer,
+    LayerNorm,
     Mamba2Mixer,
     MoEFeedForward,
     RMSNorm,
+    Rotary,
     ShortcutDecoderBlock,
 )
 from deeplearning4j_tpu.nn.conf.layers import (
@@ -110,13 +112,18 @@ def composed_configuration(vocab_size: int, d_model: int, blocks, *,
                            logits_scaling: float = 1.0, seed: int = 12345,
                            learning_rate: float = 3e-4,
                            updater: Updater = Updater.ADAM,
+                           final_norm: str = "rms_norm",
                            ) -> MultiLayerConfiguration:
     """Causal LM around the composed `blocks` (`DecoderBlock`s or
     `ShortcutDecoderBlock`s of width `d_model`): a token embedding
     without positions, scaled by `embedding_multiplier`; the blocks; one
-    trailing RMSNorm; and the output head, untied and bias-free, or with
+    trailing RMSNorm (`final_norm` "layer_norm": a LayerNorm with a gain
+    and no bias); and the output head, untied and bias-free, or with
     `tied_head` the embedding, transposed, over `logits_scaling`. What
     the named families below share."""
+    if final_norm not in ("rms_norm", "layer_norm"):
+        raise ValueError(f"final_norm {final_norm!r}: 'rms_norm' or "
+                         "'layer_norm'")
     b = (NeuralNetConfiguration.Builder()
          .seed(seed)
          .learning_rate(learning_rate)
@@ -133,7 +140,10 @@ def composed_configuration(vocab_size: int, d_model: int, blocks, *,
                 dropout=0.0)
     return (b
             .layer(RMSNormalization(n_in=d_model, n_out=d_model, eps=eps,
-                                    dropout=0.0))
+                                    dropout=0.0)
+                   if final_norm == "rms_norm" else
+                   LayerNormalization(n_in=d_model, n_out=d_model, eps=eps,
+                                      has_bias=False, dropout=0.0))
             .layer(TiedRnnOutputLayer(tied_to=0,
                                       logits_scaling=logits_scaling, **head)
                    if tied_head else RnnOutputLayer(has_bias=False, **head))
@@ -413,6 +423,56 @@ def ling_flash_configuration(vocab_size: int, d_model: int, n_layers: int,
         learning_rate=learning_rate, updater=updater)
 
 
+def command_a_configuration(vocab_size: int, d_model: int, n_layers: int,
+                            *, layer_switch: int = 4, window: int,
+                            n_heads: int, n_kv_heads: int, head_dim: int = 0,
+                            rope_theta: float = 50000.0, n_experts: int,
+                            top_k: int, expert_width: int,
+                            n_shared_experts: int = 0,
+                            shared_width: int = 0, experts_held=None,
+                            logit_scale: float = 1.0, eps: float = 1e-5,
+                            seed: int = 12345, learning_rate: float = 3e-4,
+                            updater: Updater = Updater.ADAM,
+                            ) -> MultiLayerConfiguration:
+    """Causal LM of `n_layers` PARALLEL `DecoderBlock`s: one LayerNorm
+    (a gain, no bias) of the stream, read by grouped-query attention and
+    by the feed-forward alike, both added in one residual. Layer `l`
+    attends its whole context WITHOUT positions where `(l + 1) %
+    layer_switch == 0`; every other layer attends the last `window`
+    positions with rotary over the whole head (interleaved pairs,
+    `rope_theta`): with 4, three window layers to one full. Every
+    feed-forward is `n_experts` routed gated-silu experts, `top_k` a
+    token on sigmoid scores, gates the chosen scores normalised to sum
+    1, beside `n_shared_experts` shared experts of `shared_width` each
+    whose outputs are AVERAGED (held as one MLP `n_shared_experts *
+    shared_width` wide times `1 / n_shared_experts`: the same sums); a
+    trailing LayerNorm and the tied embedding as head, times
+    `logit_scale` (the Hugging Face `cohere2_moe` family's layout:
+    Command A+). `experts_held = (first, count)`: the share of each
+    layer's routed experts this network holds."""
+    def mixer(full: bool):
+        return AttentionMixer(
+            n_heads=n_heads, n_kv_heads=n_kv_heads, head_dim=head_dim,
+            rope=None if full else Rotary(theta=rope_theta,
+                                          interleaved=True),
+            window=None if full else window)
+
+    routed = MoEFeedForward(
+        n_experts=n_experts, top_k=top_k, expert_width=expert_width,
+        shared_width=n_shared_experts * shared_width,
+        shared_scale=1.0 / n_shared_experts if n_shared_experts else 1.0,
+        experts_held=experts_held, scoring="sigmoid")
+    blocks = [DecoderBlock(
+        n_in=d_model, n_out=d_model, norm=LayerNorm(eps=eps),
+        norm_placement="parallel",
+        mixer=mixer((i + 1) % layer_switch == 0), ffn=routed)
+        for i in range(n_layers)]
+    return composed_configuration(
+        vocab_size, d_model, blocks, eps=eps, tied_head=True,
+        logits_scaling=1.0 / logit_scale, final_norm="layer_norm",
+        seed=seed, learning_rate=learning_rate, updater=updater)
+
+
 # ---------------------------------------------------------------------------
 # shared decode plan + per-block compute (generate() AND the serving
 # decode engine trace through these — one implementation of the numerics)
@@ -449,7 +509,8 @@ class GPTPlan:
 
     def state_kinds(self):
         """Per block, the cache state a decode engine keeps for it:
-        "kv" (paged key/value pools), "recurrent" (per-slot arrays),
+        "kv" (paged key/value pools), "window" (the same as a ring of
+        pages a slot), "recurrent" (per-slot arrays),
         "latent" (one paged pool of latents) or "none". A
         `TransformerBlock` keeps K/V; a composed `DecoderBlock` keeps
         what its mixer kind declares, and nothing where it has no
@@ -478,7 +539,7 @@ class GPTPlan:
             layer = self.layers[i]
             if isinstance(layer, TransformerBlock):
                 out.append((layer._kv_heads, layer.n_out // layer.n_heads))
-            elif layer.state == "kv":
+            elif layer.state in ("kv", "window"):
                 out.append(layer.mixer.kv_geometry(layer._d))
         return out
 

@@ -3,6 +3,7 @@ and a norm, each a small config object of its own.
 
     h <- h + r * mixer(norm(h));  h <- h + r * ffn(norm(h))      "pre"
     h <- h + r * norm(mixer(h));  h <- h + r * norm(ffn(h))      "post"
+    u = norm(h);  h <- h + r * (mixer(u) + ffn(u))               "parallel"
 
 A block may also be ONE sub-layer, a mixer and no feed-forward or a
 feed-forward and no mixer (the `nemotron_h` family's layers): it then
@@ -36,7 +37,7 @@ allocation and its prefill / decode steps. A kind serialises as
 
 Parameters of a block are one flat dict, as every layer's: the mixer's
 under `mx_`, the feed-forward's under `ff_`, the norms' `n1_w`, `n2_w`
-(a block of one sub-layer has no `n2_w`); a `ShortcutDecoderBlock`'s
+(a block of one sub-layer, or a "parallel" block, has no `n2_w`); a `ShortcutDecoderBlock`'s
 first block's under `a_`, its second's under `b_`, the shortcut
 feed-forward's under `sc_`.
 """
@@ -114,6 +115,50 @@ class RMSNorm(_Kind):
         return rms_norm(x, w, self.eps)
 
 
+@_kind
+@dataclass(frozen=True)
+class LayerNorm(_Kind):
+    """The mean taken out, then one gain and NO bias: `(x - mean(x)) /
+    sqrt(var(x) + eps) * w`, statistics in float32 (Cohere's)."""
+    KIND = "layer_norm"
+    eps: float = 1e-5
+
+    def init_params(self, d: int, dtype) -> dict:
+        return {"w": jnp.ones((d,), dtype)}
+
+    def apply(self, w, x):
+        xf = x.astype(jnp.float32)
+        xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+        y = xc * jax.lax.rsqrt(
+            jnp.mean(xc * xc, axis=-1, keepdims=True) + self.eps)
+        return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+# ------------------------------------------------------------ rotary kinds
+@_kind
+@dataclass(frozen=True)
+class Rotary(_Kind):
+    """Rotary positions over a head's whole width: pair `i` turns by
+    `pos * theta^(-2i / head_dim)`, angles in float32. `interleaved`:
+    the pair is features (2i, 2i + 1) (`rope_gptj`); else (i, i +
+    head_dim / 2) (rotate-half)."""
+    KIND = "rotary"
+    theta: float = 10000.0
+    interleaved: bool = True
+
+    def turn(self, u, positions):
+        """`u` (..., T, H, hd) at `positions` (..., T) or (T,). An
+        interleaved head comes back evens-first, queries and keys alike,
+        so their product is the published one and the pages hold keys
+        that never turn again."""
+        hd = u.shape[-1]
+        if self.interleaved:
+            u = jnp.swapaxes(u.reshape(*u.shape[:-1], hd // 2, 2), -1, -2) \
+                .reshape(u.shape)
+        cos, sin = rope.rope_angles(positions, hd, self.theta)
+        return rope.rope_rotate(u, cos, sin)
+
+
 # ----------------------------------------------------- rotary scaling kinds
 @_kind
 @dataclass(frozen=True)
@@ -179,8 +224,13 @@ def _decay_params(k_dt, k_a, n_heads: int, dtype) -> dict:
 @_kind
 @dataclass(frozen=True)
 class AttentionMixer(_Kind):
-    """Causal grouped-query attention without biases and without
-    positional encoding; `head_dim` 0 is `d // n_heads`, any other is
+    """Causal grouped-query attention without biases; without positional
+    encoding unless `rope` names a `Rotary` kind, which then turns the
+    queries and the keys at the positions the caller hands (`heads`; the
+    turned keys are what the pages hold); `window` W (None: the whole
+    context): query `i` attends keys `j` with `j <= i` and `i - j < W`,
+    and a decode engine keeps such a layer's pages as a ring
+    (`state` "window"); `head_dim` 0 is `d // n_heads`, any other is
     the heads' own width (the projections are then `n_heads * head_dim`
     wide, which need not be `d`); `scale` multiplies the scores (None:
     the usual 1 / sqrt(head_dim)). With `qk_norm` the query and key projections
@@ -188,13 +238,26 @@ class AttentionMixer(_Kind):
     before the split into heads: the Olmo 2/3 convention), and the
     normed keys are what the pages hold. Keeps paged K/V."""
     KIND = "attention"
-    state = "kv"
     n_heads: int = 4
     n_kv_heads: int = 0          # 0: as many as n_heads
     scale: Optional[float] = None
     qk_norm: bool = False
     eps: float = 1e-6            # of the query / key norms
     head_dim: int = 0            # 0: d // n_heads
+    rope: Optional[Rotary] = None
+    window: Optional[int] = None
+
+    def __post_init__(self):
+        if self.window is not None and self.window < 1:
+            raise ValueError(f"window {self.window}: at least the "
+                             "query's own position")
+        object.__setattr__(self, "rope", kind_from_json(self.rope))
+
+    @property
+    def state(self) -> str:
+        """Paged K/V; as a ring of pages a slot where a window bounds
+        what a query reads."""
+        return "kv" if self.window is None else "window"
 
     @property
     def _kv_heads(self) -> int:
@@ -220,10 +283,12 @@ class AttentionMixer(_Kind):
         with jax.named_scope("attn.qk_norm"):
             return rms_norm(u, p[gain], self.eps)
 
-    def heads(self, p, x):
-        """(..., d) -> q (..., H, hd), k and v (..., Hkv, hd). The
-        attention paths all divide the scores by sqrt(hd); `q` is
-        scaled here so that their product comes out at `scale`."""
+    def heads(self, p, x, positions=None):
+        """(..., T, d) -> q (..., T, H, hd), k and v (..., T, Hkv, hd),
+        `q` and `k` turned at `positions` (..., T) or (T,) where the
+        mixer has a `rope` (None: `arange(T)`). The attention paths all
+        divide the scores by sqrt(hd); `q` is scaled here so that their
+        product comes out at `scale`."""
         hd = p["Wo"].shape[0] // self.n_heads
         qw, kvw = self.n_heads * hd, self._kv_heads * hd
         with jax.named_scope("attn.qkv"):
@@ -236,6 +301,12 @@ class AttentionMixer(_Kind):
                                             self._kv_heads, hd)
             if self.scale is not None:
                 q = q * jnp.asarray(self.scale * math.sqrt(hd), q.dtype)
+        if self.rope is not None:
+            if positions is None:
+                positions = jnp.arange(x.shape[-2])
+            with jax.named_scope("attn.rope"):
+                q = self.rope.turn(q, positions)
+                k = self.rope.turn(k, positions)
         return q, k, v
 
     def out(self, p, att):
@@ -248,7 +319,8 @@ class AttentionMixer(_Kind):
         q, k, v = self.heads(p, x)
         with jax.named_scope("attn.core"):
             att = multi_head_attention(q, k, v, causal=True,
-                                       block_size=_FLASH_FROM)
+                                       block_size=_FLASH_FROM,
+                                       window=self.window)
         return self.out(p, att.reshape(*x.shape[:-1], -1))
 
 
@@ -930,7 +1002,9 @@ class GatedMLP(_Kind):
 class MoEFeedForward(_Kind):
     """`n_experts` routed MLPs of width `expert_width`, `top_k` a token,
     no capacity and no token dropped; plus one shared MLP of width
-    `shared_width` (0: none) added unweighted. `experts_held = (first,
+    `shared_width` (0: none) added times `shared_scale` (1.0:
+    unweighted; 1 / n where the one MLP stands for n shared experts
+    side by side whose outputs are averaged). `experts_held = (first,
     count)` is the share of the experts whose weights this layer holds
     and computes (None: all of them): it routes over all `n_experts` and
     leaves the absent experts' part of the sum out
@@ -965,6 +1039,7 @@ class MoEFeedForward(_Kind):
     n_zero_experts: int = 0
     n_groups: int = 1
     topk_groups: int = 1
+    shared_scale: float = 1.0
 
     def __post_init__(self):
         from deeplearning4j_tpu.parallel.experts import check_groups
@@ -1046,9 +1121,12 @@ class MoEFeedForward(_Kind):
             topk_groups=self.topk_groups)
         if self.shared_width:
             with jax.named_scope("moe.shared"):
-                y = y + (gated_mlp(flat, p["sWg"], p["sWu"], p["sWd"])
-                         if self._gated
-                         else relu2_mlp(flat, p["sWu"], p["sWd"]))
+                shared = gated_mlp(flat, p["sWg"], p["sWu"], p["sWd"]) \
+                    if self._gated else relu2_mlp(flat, p["sWu"], p["sWd"])
+                if self.shared_scale != 1.0:
+                    shared = shared * jnp.asarray(self.shared_scale,
+                                                  shared.dtype)
+                y = y + shared
         return y.reshape(x.shape), counts
 
 
@@ -1075,9 +1153,9 @@ class DecoderBlock(FeedForwardLayer):
     norm_placement: str = "pre"
 
     def __post_init__(self):
-        if self.norm_placement not in ("pre", "post"):
+        if self.norm_placement not in ("pre", "post", "parallel"):
             raise ValueError(f"norm_placement {self.norm_placement!r}: "
-                             "'pre' or 'post'")
+                             "'pre', 'post' or 'parallel'")
         self.mixer = kind_from_json(self.mixer)
         self.ffn = kind_from_json(self.ffn)
         self.norm = kind_from_json(self.norm) or RMSNorm()
@@ -1086,10 +1164,17 @@ class DecoderBlock(FeedForwardLayer):
                              "feed-forward kind (or both)")
         if self.n_in and self.n_out and self.n_in != self.n_out:
             raise ValueError("DecoderBlock keeps width: n_in == n_out")
+        if self._parallel and (self.mixer is None or self.ffn is None):
+            raise ValueError("a 'parallel' DecoderBlock has a mixer AND a "
+                             "feed-forward: they share its one norm")
 
     @property
     def _d(self) -> int:
         return self.n_out or self.n_in
+
+    @property
+    def _parallel(self) -> bool:
+        return self.norm_placement == "parallel"
 
     @property
     def state(self) -> str:
@@ -1111,7 +1196,8 @@ class DecoderBlock(FeedForwardLayer):
         if self.ffn is not None:
             p.update({"ff_" + n: v for n, v in
                       self.ffn.init_params(k2, d, dtype, mk).items()})
-        if self.mixer is not None and self.ffn is not None:
+        if self.mixer is not None and self.ffn is not None \
+                and not self._parallel:
             p["n2_w"] = self.norm.init_params(d, dtype)["w"]
         return p
 
@@ -1126,11 +1212,12 @@ class DecoderBlock(FeedForwardLayer):
     def mixer_in(self, p, x):
         """What the mixer reads: the block's input, normed first where
         the norm stands before the sub-layer."""
-        return self.norm1(p, x) if self.norm_placement == "pre" else x
+        return x if self.norm_placement == "post" else self.norm1(p, x)
 
     def after_mixer(self, p, x, mixed):
         """The stream after the mixer's residual (`mixed` None: the
-        block has no mixer, the stream as it came)."""
+        block has no mixer, the stream as it came). A "parallel" block
+        has ONE residual, added in `finish`."""
         if self.mixer is None:
             return x
         r = jnp.asarray(self.residual_multiplier, x.dtype)
@@ -1157,6 +1244,13 @@ class DecoderBlock(FeedForwardLayer):
         has no mixer): the mixer's residual, then norm, feed-forward and
         its residual where the block has a feed-forward. Returns (h, the
         feed-forward's counts under `count_mask`, or None)."""
+        if self._parallel:
+            # the feed-forward reads what the mixer read; XLA makes the
+            # one norm once where both are traced in one program
+            f, counts = self.ffn.forward(sub(p, "ff_"), self.norm1(p, x),
+                                         count_mask)
+            r = jnp.asarray(self.residual_multiplier, x.dtype)
+            return x + r * (mixed + f), counts
         h = self.after_mixer(p, x, mixed)
         if self.ffn is None:
             return h, None

@@ -1201,13 +1201,15 @@ class LayerNormalization(FeedForwardLayer):
     No counterpart in the reference (its only normalization is batch norm,
     `nn/conf/layers/BatchNormalization.java`); required by the transformer
     tier. Statistics are computed in promoted >= f32 precision (same
-    rationale as BatchNormalization under bf16 mixed precision)."""
+    rationale as BatchNormalization under bf16 mixed precision).
+    `has_bias=False`: a gain alone, no `beta` leaf (Cohere's)."""
 
     TYPE = "layer_norm"
     input_kind = "rnn"
     n_in: int = 0
     n_out: int = 0
     eps: float = 1e-5
+    has_bias: bool = True
 
     def __post_init__(self):
         if self.n_out and self.n_in and self.n_out != self.n_in:
@@ -1218,11 +1220,14 @@ class LayerNormalization(FeedForwardLayer):
 
     def init_params(self, key, it, dtype=jnp.float32) -> Params:
         nf = self.n_out or self.n_in or it.size
-        return {"gamma": jnp.ones((nf,), dtype),
-                "beta": jnp.zeros((nf,), dtype)}
+        p = {"gamma": jnp.ones((nf,), dtype)}
+        if self.has_bias:
+            p["beta"] = jnp.zeros((nf,), dtype)
+        return p
 
     def forward(self, params, state, x, *, train=False, rng=None, mask=None):
-        return layer_norm(x, params["gamma"], params["beta"], self.eps), state
+        return layer_norm(x, params["gamma"], params.get("beta"),
+                          self.eps), state
 
 
 def layer_norm(x, gamma, beta, eps=1e-5):
@@ -1235,7 +1240,9 @@ def layer_norm(x, gamma, beta, eps=1e-5):
     mean = jnp.mean(xs, axis=-1, keepdims=True)
     var = jnp.var(xs, axis=-1, keepdims=True)
     xhat = (xs - mean) / jnp.sqrt(var + eps)
-    out = xhat * gamma.astype(stat_dtype) + beta.astype(stat_dtype)
+    out = xhat * gamma.astype(stat_dtype)
+    if beta is not None:
+        out = out + beta.astype(stat_dtype)
     return out.astype(x.dtype)
 
 

@@ -30,13 +30,27 @@ def mask_bias(key_mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(key_mask[:, None, None, :] > 0, 0.0, NEG_INF)
 
 
+def _behind_window(s, window: int):
+    """Scores `s` (..., Tq, Tk) of causal attention with the keys more
+    than `window` - 1 positions behind their query masked off: query `i`
+    keeps keys `j` with `i - j < window` (the queries aligned to the end
+    of the keys, as the causal mask aligns them)."""
+    Tq, Tk = s.shape[-2], s.shape[-1]
+    iq = jnp.arange(Tq)[:, None]
+    ik = jnp.arange(Tk)[None, :]
+    return jnp.where(ik > iq + (Tk - Tq) - window, s, NEG_INF)
+
+
 def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    bias: Optional[jnp.ndarray] = None,
-                   causal: bool = False) -> jnp.ndarray:
+                   causal: bool = False,
+                   window: Optional[int] = None) -> jnp.ndarray:
     """Plain softmax(QKᵀ/√d + bias)·V. q/k/v: (B, T, H, D); bias broadcastable
     to (B, H, Tq, Tk). Reference semantics for the blockwise/ring variants'
     parity tests (the cuDNN-vs-builtin parity pattern,
-    `deeplearning4j-cuda/src/test/.../TestConvolution.java`)."""
+    `deeplearning4j-cuda/src/test/.../TestConvolution.java`). `window`
+    (with `causal`): a query sees the `window` keys that end at its
+    own."""
     d = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(jnp.asarray(d, q.dtype))
     if bias is not None:
@@ -46,6 +60,8 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         iq = jnp.arange(Tq)[:, None]
         ik = jnp.arange(Tk)[None, :]
         s = jnp.where(ik <= iq + (Tk - Tq), s, NEG_INF)
+        if window is not None:
+            s = _behind_window(s, window)
     p = jax.nn.softmax(s, axis=-1)
     # fully-masked rows: softmax over all-NEG_INF is uniform garbage — zero
     # masked positions so such rows produce output 0, matching
@@ -57,7 +73,8 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 def full_attention_grouped(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            bias: Optional[jnp.ndarray] = None,
-                           causal: bool = False) -> jnp.ndarray:
+                           causal: bool = False,
+                           window: Optional[int] = None) -> jnp.ndarray:
     """`full_attention` for grouped-query attention WITHOUT materializing
     the repeated K/V: q (B, T, H, D) against k/v carrying only Hkv
     grouped heads (H a multiple of Hkv; query head j reads KV head
@@ -86,6 +103,8 @@ def full_attention_grouped(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         iq = jnp.arange(Tq)[:, None]
         ik = jnp.arange(Tk)[None, :]
         s = jnp.where(ik <= iq + (Tk - Tq), s, NEG_INF)
+        if window is not None:
+            s = _behind_window(s, window)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(s <= NEG_INF / 2, 0.0, p)
     att = jnp.einsum("bhgqk,bkhd->bqhgd", p, v)
@@ -139,16 +158,24 @@ def attention_finalize(o: jnp.ndarray, l: jnp.ndarray) -> jnp.ndarray:
 def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         *, causal: bool = False,
                         key_mask: Optional[jnp.ndarray] = None,
-                        block_size: int = 512) -> jnp.ndarray:
+                        block_size: int = 512,
+                        window: Optional[int] = None) -> jnp.ndarray:
     """Memory-efficient exact attention: scan over KV blocks with the
     online-softmax recurrence. Peak memory is O(Tq·block) for scores instead
     of O(Tq·Tk). q/k/v: (B, T, H, D); key_mask: (B, Tk) with 1=valid.
+    `k`/`v` may carry fewer heads than `q` (Hkv dividing H; query head j
+    reads KV head j // G): they are read as they are, a K/V head's G
+    query heads riding the query axis. `window` (with `causal`): a query
+    sees the `window` keys that end at its own.
 
     Under jit the scan compiles to a single XLA while-loop — static shapes,
     no data-dependent Python control flow.
     """
     B, Tk, H, D = k.shape
-    Tq = q.shape[1]
+    Tq, G = q.shape[1], q.shape[2] // H
+    if G > 1:
+        q = jnp.moveaxis(q.reshape(B, Tq, H, G, D), 3, 1).reshape(
+            B, G * Tq, H, D)
     Tk_orig = Tk
     blk = min(block_size, Tk)
     if Tk % blk != 0:  # pad keys to a block multiple; padded keys masked off
@@ -166,7 +193,7 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         ms = jnp.moveaxis(key_mask.reshape(B, n_blocks, blk), 1, 0)
     else:
         ms = jnp.ones((n_blocks, B, blk), q.dtype)
-    iq = jnp.arange(Tq)
+    iq = jnp.arange(Tq) if G == 1 else jnp.tile(jnp.arange(Tq), G)
     # Tq != Tk: align queries to the END of the keys (decode-style), matching
     # full_attention's `ik <= iq + (Tk - Tq)` — offset uses the UNPADDED Tk
     causal_off = Tk_orig - Tq
@@ -177,6 +204,10 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         if causal:
             ik = blk_idx * blk + jnp.arange(blk)
             cb = jnp.where(ik[None, :] <= iq[:, None] + causal_off, 0.0, NEG_INF)
+            if window is not None:
+                cb = jnp.where(
+                    ik[None, :] > iq[:, None] + causal_off - window, cb,
+                    NEG_INF)
             bias = bias + cb[None, None, :, :]
         carry = attention_block_accum(carry, q, k_blk, v_blk, bias)
         return carry, None
@@ -184,7 +215,11 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     init = _accum_init(q)
     (o, l, _), _ = lax.scan(body, init,
                             (ks, vs, ms, jnp.arange(n_blocks)))
-    return attention_finalize(o, l)
+    out = attention_finalize(o, l)
+    if G > 1:
+        out = jnp.moveaxis(out.reshape(B, G, Tq, H, D), 1, 3).reshape(
+            B, Tq, H * G, D)
+    return out
 
 
 def cached_attention_step(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -301,11 +336,47 @@ def paged_attention_step(q: jnp.ndarray, k_pool: jnp.ndarray,
     return cached_attention_step(q, k, v, pos)
 
 
+def ring_key_positions(last, n_entries: int, page: int):
+    """The position each column of a slot's gathered ring holds: `last`
+    (S,) the newest position written; entry `e` of the ring holds the
+    newest logical page `j <= last // page` with `j % n_entries == e`
+    (negative: never written). (S, n_entries * page)."""
+    jlast = (jnp.asarray(last) // page)[:, None]
+    j = jlast - (jlast - jnp.arange(n_entries)[None, :]) % n_entries
+    return (j[:, :, None] * page + jnp.arange(page)).reshape(
+        j.shape[0], n_entries * page)
+
+
+def ring_attention_chunk(q, k_pool, v_pool, ring_table, pos0,
+                         window: int) -> jnp.ndarray:
+    """The portable form of windowed paged attention, and the kernel's
+    oracle: `q` (S, C, H, D), C contiguous queries a slot from `pos0[s]`
+    on, each attending to the `window` positions that end at its own,
+    against pools whose pages a slot holds as a RING, `ring_table` (S,
+    R): logical page `j` at entry `j % R`, the chunk's own K/V already
+    written. Gathers each slot's ring and masks by the position every
+    column holds. Returns (S, C, H*D)."""
+    S, C, H, D = q.shape
+    Hkv, page = k_pool.shape[1], k_pool.shape[3]
+    G = H // Hkv
+    kd, vd = paged_gather(k_pool, v_pool, ring_table)
+    qpos = jnp.asarray(pos0)[:, None] + jnp.arange(C)[None, :]
+    kpos = ring_key_positions(qpos[:, -1], ring_table.shape[1], page)
+    qg = jnp.transpose(q.reshape(S, C, Hkv, G, D), (0, 2, 3, 1, 4))
+    s = jnp.einsum("skgcd,skdl->skgcl", qg,
+                   kd) / jnp.sqrt(jnp.asarray(D, q.dtype))
+    kp, qp = kpos[:, None, None, None, :], qpos[:, None, None, :, None]
+    s = jnp.where((kp <= qp) & (kp > qp - window) & (kp >= 0), s, -jnp.inf)
+    w = jax.nn.softmax(s, axis=-1)
+    att = jnp.einsum("skgcl,skld->skgcd", w, vd)
+    return jnp.transpose(att, (0, 3, 1, 2, 4)).reshape(S, C, H * D)
+
+
 def paged_attention_step_auto(q: jnp.ndarray, k_pool: jnp.ndarray,
                               v_pool: jnp.ndarray,
                               page_table: jnp.ndarray, pos,
                               active=None, k_scale=None,
-                              v_scale=None) -> jnp.ndarray:
+                              v_scale=None, window=None) -> jnp.ndarray:
     """`paged_attention_step` behind the kernel-dispatch contract: on
     TPU the Pallas paged-attention kernel walks the page table in place
     (`ops/pallas_paged_attention.py` — no dense transient, each cache
@@ -318,7 +389,9 @@ def paged_attention_step_auto(q: jnp.ndarray, k_pool: jnp.ndarray,
     int8 pools pass their f32 scale pools as `k_scale`/`v_scale`
     ((P+1, Hkv, page)): the kernel dequantizes inside the page loop,
     the fallback dequantizes via `paged_gather_quant` — same dispatch
-    contract, halved DMA bytes. Returns (S, H*D)."""
+    contract, halved DMA bytes. `window` W: each query sees the W
+    positions that end at its own, and `page_table` is the slots' RING
+    table (`ring_attention_chunk`). Returns (S, H*D)."""
     from deeplearning4j_tpu.ops.pallas_paged_attention import (
         paged_attention_or_none,
     )
@@ -327,6 +400,10 @@ def paged_attention_step_auto(q: jnp.ndarray, k_pool: jnp.ndarray,
     pos = jnp.asarray(pos)
     if pos.ndim == 0:
         pos = jnp.broadcast_to(pos, (S,))
+    if window is not None:
+        return paged_attention_chunk_auto(
+            q[:, None], k_pool, v_pool, page_table, pos, active,
+            window=window)[:, 0]
     out = paged_attention_or_none(q[:, None], k_pool, v_pool, page_table,
                                   pos, active, k_scale=k_scale,
                                   v_scale=v_scale)
@@ -343,7 +420,7 @@ def paged_attention_chunk_auto(q: jnp.ndarray, k_pool: jnp.ndarray,
                                v_pool: jnp.ndarray,
                                page_table: jnp.ndarray, pos0,
                                active=None, k_scale=None,
-                               v_scale=None) -> jnp.ndarray:
+                               v_scale=None, window=None) -> jnp.ndarray:
     """Chunk-width paged attention behind the same dispatch contract —
     the speculative (k+1)-verify and chunked-prefill-suffix shapes.
     `q`: (S, C, H, D) — C CONTIGUOUS query tokens per slot starting at
@@ -352,7 +429,8 @@ def paged_attention_chunk_auto(q: jnp.ndarray, k_pool: jnp.ndarray,
     one fused page-walk dispatch; fallback: `paged_gather` + slot-vmapped
     `cached_attention_chunk` (exactly `_verify_block_attention`, and for
     S=1 exactly `_prefill_chunk_block_attention`). int8 pools pass
-    `k_scale`/`v_scale` exactly as in `paged_attention_step_auto`.
+    `k_scale`/`v_scale` exactly as in `paged_attention_step_auto`, and
+    `window` with the ring table as there (no int8 form).
     Returns (S, C, H*D)."""
     from deeplearning4j_tpu.ops.pallas_paged_attention import (
         paged_attention_or_none,
@@ -362,6 +440,16 @@ def paged_attention_chunk_auto(q: jnp.ndarray, k_pool: jnp.ndarray,
     pos0 = jnp.asarray(pos0)
     if pos0.ndim == 0:
         pos0 = jnp.broadcast_to(pos0, (S,))
+    if window is not None:
+        if k_scale is not None:
+            raise NotImplementedError(
+                "windowed paged attention has no int8 form")
+        out = paged_attention_or_none(q, k_pool, v_pool, page_table, pos0,
+                                      active, window=window)
+        if out is not None:
+            return out.reshape(S, C, H * D)
+        return ring_attention_chunk(q, k_pool, v_pool, page_table, pos0,
+                                    window)
     out = paged_attention_or_none(q, k_pool, v_pool, page_table, pos0,
                                   active, k_scale=k_scale,
                                   v_scale=v_scale)
@@ -421,8 +509,33 @@ def sequence_parallel_scope(mesh, axis_name: str = "seq",
         _SEQ_PARALLEL.pop()
 
 
+def grouped_causal_attention(q, k, v, *, window: Optional[int] = None,
+                             one_array_to: Optional[int] = None
+                             ) -> jnp.ndarray:
+    """Causal self-attention, forward only, K/V read by group and never
+    repeated: q (B, T, H, D), k and v (B, T, Hkv, D), every query seeing
+    the `window` keys that end at its own (None: all before it). Up to
+    `one_array_to` keys (None: any number) the scores are one array
+    (`full_attention_grouped`). Past it they would not fit (128 heads at
+    4,096 keys: 8.6 GB): the flash kernel where it serves (key blocks
+    behind the window skipped: `pallas_attention.flash_attention_or_none`),
+    `blockwise_attention` elsewhere, the CPU's tests among them."""
+    if one_array_to is None or k.shape[1] <= one_array_to:
+        return full_attention_grouped(q, k, v, causal=True, window=window)
+    from deeplearning4j_tpu.ops.pallas_attention import (
+        flash_attention_or_none,
+    )
+
+    out = flash_attention_or_none(q, k, v, causal=True, window=window)
+    if out is None:
+        out = blockwise_attention(q, k, v, causal=True, window=window,
+                                  block_size=256)
+    return out
+
+
 def multi_head_attention(q, k, v, *, causal=False, key_mask=None,
-                         block_size: Optional[int] = None):
+                         block_size: Optional[int] = None,
+                         window: Optional[int] = None):
     """Dispatch (the cuDNN-helper pattern: same contract, fastest available
     path picked): ring attention when a sequence-parallel scope is active,
     pallas flash kernel for long unmasked sequences, XLA blockwise beyond
@@ -432,8 +545,20 @@ def multi_head_attention(q, k, v, *, causal=False, key_mask=None,
     full-attention path computes the grouping as a broadcast einsum
     (`full_attention_grouped` — no materialized repeat); the kernel
     paths (ring/flash/blockwise) require equal head counts and widen
-    via `jnp.repeat`, exactly the layers' historical behavior."""
+    via `jnp.repeat`, exactly the layers' historical behavior.
+
+    `window` (causal self-attention without a key mask only): a query
+    sees the `window` keys that end at its own
+    (`grouped_causal_attention`, forward only past `block_size` keys)."""
     H, Hkv = q.shape[2], k.shape[2]
+    if window is not None:
+        if not causal or key_mask is not None or _SEQ_PARALLEL \
+                or q.shape[1] != k.shape[1]:
+            raise NotImplementedError(
+                "a window is written for causal self-attention without a "
+                "key mask, outside a sequence-parallel scope")
+        return grouped_causal_attention(q, k, v, window=window,
+                                        one_array_to=block_size)
 
     def widened():
         if Hkv == H:

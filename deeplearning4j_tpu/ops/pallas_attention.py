@@ -75,7 +75,7 @@ FAMILY = "flash_attention"  # this module's row in kernel_verdicts()
 
 
 def _masked_scores(q_ref, k_ref, qi, ki, *, sm_scale, causal, block_q,
-                   block_k):
+                   block_k, window=None):
     """One (block_q, block_k) tile of scaled scores with the causal mask
     applied — the SINGLE implementation shared by the forward and every
     backward kernel, so mask/scale semantics cannot drift between them."""
@@ -89,6 +89,8 @@ def _masked_scores(q_ref, k_ref, qi, ki, *, sm_scale, causal, block_q,
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+        if window is not None:   # the `window` keys that end at the query
+            s = jnp.where(k_pos > q_pos - window, s, NEG_INF)
     return s, dt
 
 
@@ -99,14 +101,18 @@ def _tile_p(s, lse):
     return jnp.where(s <= NEG_INF / 2, 0.0, p)
 
 
-def _causal_needed_kv(qi, ki, block_q, block_k, causal):
+def _causal_needed_kv(qi, ki, block_q, block_k, causal, window=None):
     # KV blocks strictly above the diagonal contribute nothing
-    return (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    need = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    if window is not None:
+        # nor do those wholly behind the window of the block's first query
+        need = need & (ki * block_k + block_k - 1 > qi * block_q - window)
+    return need
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
                       causal: bool, block_q: int, block_k: int,
-                      with_lse: bool):
+                      with_lse: bool, window=None):
     from jax.experimental import pallas as pl
 
     if with_lse:
@@ -124,11 +130,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(_causal_needed_kv(qi, ki, block_q, block_k, causal))
+    @pl.when(_causal_needed_kv(qi, ki, block_q, block_k, causal, window))
     def _step():
         s, dt = _masked_scores(q_ref, k_ref, qi, ki, sm_scale=sm_scale,
                                causal=causal, block_q=block_q,
-                               block_k=block_k)
+                               block_k=block_k, window=window)
         m_prev = m_scr[:, :1]                                 # (bq, 1)
         l_prev = l_scr[:, :1]
         m_blk = jnp.max(s, axis=-1, keepdims=True)
@@ -262,16 +268,21 @@ def _from_slabs(x, B, H):
 
 
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                   with_lse):
+                   with_lse, window=None):
+    """`k` and `v` may carry fewer heads than `q` (Hkv dividing H): a
+    query head's slab then reads its GROUP's K/V slab, nothing
+    repeated. `window` (with `causal`): `_flash_fwd_kernel`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
+    groups = H // k.shape[2]
     qf, kf, vf = _to_slabs(q), _to_slabs(k), _to_slabs(v)
     kernel = functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
                                causal=causal, block_q=block_q,
-                               block_k=block_k, with_lse=with_lse)
+                               block_k=block_k, with_lse=with_lse,
+                               window=window)
     sdt = _stat_dtype(q.dtype)
     out_specs = [pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))]
     out_shape = [jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype)]
@@ -287,7 +298,9 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     # compute to hide the next real tile's DMA behind. Plain indexing + the
     # kernel-side compute skip wins.
     def kv_index(b, i, j):
-        return (b, j, 0)
+        # query slab b = batch * H + head reads K/V slab batch * Hkv +
+        # head // groups: b // groups (b itself at equal head counts)
+        return (b if groups == 1 else b // groups, j, 0)
 
     res = pl.pallas_call(
         kernel,
@@ -334,18 +347,31 @@ def _backward_form(Tq: int, D: int, dtype) -> str:
             else "split")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+class WindowedBackwardUnsupported(NotImplementedError):
+    """A gradient was asked of the flash kernel's forward-only form (a
+    window, or K/V read by group): its backward is not written."""
+
+
+FORWARD_ONLY = "forward"  # in `bwd_form`'s place where there is no backward
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_mha(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-               bwd_form):
+               bwd_form, window=None):
     # inference primal: no lse output (skips an f32 HBM write larger than
     # the attention output itself)
     out, _ = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                            interpret, with_lse=False)
+                            interpret, with_lse=False, window=window)
     return out
 
 
 def _flash_mha_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                   bwd_form):
+                   bwd_form, window=None):
+    if bwd_form == FORWARD_ONLY:
+        raise WindowedBackwardUnsupported(
+            "flash attention with a window, or with grouped K/V read by "
+            "group, has no backward: train on full causal attention with "
+            "equal head counts, or through the XLA paths")
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
                               interpret, with_lse=True)
     return out, (q, k, v, out, lse)
@@ -465,7 +491,7 @@ _BACKWARD = {"fused": _backward_fused, "split": _backward_split}
 
 
 def _flash_mha_bwd(causal, sm_scale, block_q, block_k, interpret, bwd_form,
-                   res, do):
+                   window, res, do):
     q, k, v, out, lse = res
     B, Tq, H, D = q.shape
     sdt = _stat_dtype(q.dtype)
@@ -486,11 +512,18 @@ _flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = False, sm_scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = False) -> jnp.ndarray:
+                    interpret: bool = False,
+                    window: Optional[int] = None) -> jnp.ndarray:
     """Exact attention, (B, T, H, D) layout, no key mask; differentiable
     (custom VJP: one Pallas backward kernel, or the dQ / dKV pair where the
     sequence is too long for it, `_backward_form`). Requires Tq/Tk
-    divisible by the block sizes (callers pad or fall back)."""
+    divisible by the block sizes (callers pad or fall back).
+
+    FORWARD ONLY (a gradient raises `WindowedBackwardUnsupported`): `k`
+    and `v` with fewer heads than `q` (Hkv dividing H) are read by group,
+    nothing repeated, and `window` (with `causal`) lets every query see
+    the `window` keys that end at its own, key blocks wholly behind it
+    skipped as those ahead of the diagonal are."""
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     if Tq % block_q or Tk % block_k:
@@ -498,7 +531,12 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          f"({block_q}, {block_k})")
     if causal and Tq != Tk:
         raise ValueError("causal flash path requires Tq == Tk")
+    if window is not None and not causal:
+        raise ValueError("a window is written for causal attention")
     scale = sm_scale if sm_scale is not None else 1.0 / (D ** 0.5)
+    if window is not None or k.shape[2] != H:
+        return _flash_mha(q, k, v, causal, scale, block_q, block_k,
+                          interpret, FORWARD_ONLY, window)
     return _flash_mha(q, k, v, causal, scale, block_q, block_k, interpret,
                       _backward_form(Tq, D, q.dtype))
 
@@ -508,7 +546,8 @@ def _platform_supported() -> bool:
     return _kernels_dispatch("DL4J_TPU_NO_PALLAS_ATTENTION")
 
 
-def _eager_probe(dtype, block: int, head_dim: int, bwd_form: str) -> bool:
+def _eager_probe(dtype, block: int, head_dim: int, bwd_form: str,
+                 groups: int = 1, window: Optional[int] = None) -> bool:
     """Compile + run the forward AND backward kernels once on tiny
     concrete inputs, OUTSIDE any trace. The dispatch itself usually runs
     inside a jit trace, where a Mosaic compile failure would surface at
@@ -517,7 +556,33 @@ def _eager_probe(dtype, block: int, head_dim: int, bwd_form: str) -> bool:
     a recorded XLA fallback instead of a training crash. Probed per
     (dtype, block, backward form) at T=block so the exact tile
     configuration and kernels that will run are the ones proven to
-    compile."""
+    compile. A forward-only class (`FORWARD_ONLY`: grouped K/V, a
+    window) runs the forward on three blocks of queries, one K/V head
+    and a window clipped to a block and a half, and is held to the
+    grouped XLA attention."""
+    if bwd_form == FORWARD_ONLY:
+        import numpy as np
+
+        from deeplearning4j_tpu.ops.attention import full_attention_grouped
+
+        T = 3 * block
+        rng = np.random.default_rng(0)
+        q = jnp.asarray(rng.standard_normal((1, T, groups, head_dim)), dtype)
+        k = jnp.asarray(rng.standard_normal((1, T, 1, head_dim)), dtype)
+        v = jnp.asarray(rng.standard_normal((1, T, 1, head_dim)), dtype)
+        w = None if window is None else min(int(window), block + block // 2)
+        out = np.asarray(flash_attention(q, k, v, causal=True, block_q=block,
+                                         block_k=block, window=w),
+                         np.float32)
+        ref = np.asarray(
+            full_attention_grouped(q, k, v, causal=True, window=w),
+            np.float32)
+        tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
+        if not np.allclose(out, ref, atol=tol, rtol=tol):
+            raise ValueError(
+                "flash forward compiled but disagrees with the XLA "
+                f"attention: max abs err {np.max(np.abs(out - ref)):.3g}")
+        return True
     B, T, H = 1, block, 1
     x = jnp.zeros((B, T, H, head_dim), dtype)
 
@@ -537,22 +602,31 @@ def _eager_probe(dtype, block: int, head_dim: int, bwd_form: str) -> bool:
 _BLOCK_CANDIDATES = (1024, 512, 256, 128)
 
 
-def _verdict_key(dtype, block: int, D: int, bwd_form: str) -> tuple:
-    # the backward's form last: `kernel_verdicts()` says which one engaged
-    return (jnp.dtype(dtype).name, block, D, bwd_form)
+def _verdict_key(dtype, block: int, D: int, bwd_form: str,
+                 groups: int = 1, window: Optional[int] = None) -> tuple:
+    # the backward's form last: `kernel_verdicts()` says which one engaged;
+    # a forward-only class adds its group width and its window
+    key = (jnp.dtype(dtype).name, block, D, bwd_form)
+    if bwd_form == FORWARD_ONLY:
+        key += (groups, "full" if window is None else ("window", int(window)))
+    return key
 
 
-def _probed_block(dtype, Tq: int, Tk: int, D: int,
-                  bwd_form: str) -> Optional[int]:
+def _probed_block(dtype, Tq: int, Tk: int, D: int, bwd_form: str,
+                  groups: int = 1,
+                  window: Optional[int] = None) -> Optional[int]:
     """Largest candidate tile that divides the sequence AND passes the
-    fwd+bwd compile probe. A block whose probe fails (e.g. VMEM overflow
+    fwd+bwd compile probe (a forward-only class: its forward's compile
+    and parity probe). A block whose probe fails (e.g. VMEM overflow
     at a bigger head dim) falls through to the next smaller candidate
     instead of abandoning the kernel outright."""
+    extra = () if bwd_form != FORWARD_ONLY else (groups, window)
     for block in _BLOCK_CANDIDATES:
         if Tq % block or Tk % block:
             continue
-        if _probe_verdict(FAMILY, _verdict_key(dtype, block, D, bwd_form),
-                          _eager_probe, (dtype, block, D, bwd_form)):
+        if _probe_verdict(FAMILY, _verdict_key(dtype, block, D, bwd_form,
+                                               *extra),
+                          _eager_probe, (dtype, block, D, bwd_form) + extra):
             return block
     return None
 
@@ -583,28 +657,38 @@ def flash_attention_over_mesh(q, k, v, mesh, batch_axis, *, causal: bool,
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
-def flash_attention_or_none(q, k, v, *,
-                            causal: bool = False) -> Optional[jnp.ndarray]:
+def flash_attention_or_none(q, k, v, *, causal: bool = False,
+                            window: Optional[int] = None
+                            ) -> Optional[jnp.ndarray]:
     """Dispatch probe (the reflective cuDNN-helper load): returns None when
     the kernel can't serve this call — wrong platform, non-divisible shapes,
     tiny sequences — or when every candidate tile failed its fwd+bwd
     compile probe. Biggest tile first: larger tiles amortise the
-    per-grid-step overhead that dominates on v5e."""
+    per-grid-step overhead that dominates on v5e. K/V with fewer heads
+    than `q`, or a `window`, take the kernel's forward-only form (a class
+    of its own a group width and window; causal only, and not under a
+    mesh)."""
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Hkv = k.shape[1], k.shape[2]
+    forward_only = window is not None or Hkv != H
     if (not _platform_supported() or (causal and Tq != Tk)
             or D % 128 or q.dtype not in (jnp.float32, jnp.bfloat16)):
         return None
-    bwd_form = _backward_form(Tq, D, q.dtype)
-    block = _probed_block(q.dtype, Tq, Tk, D, bwd_form)
+    if forward_only and (not causal or H % Hkv
+                         or _traced_mesh() is not None):
+        return None
+    bwd_form = FORWARD_ONLY if forward_only \
+        else _backward_form(Tq, D, q.dtype)
+    extra = (H // Hkv, window) if forward_only else ()
+    block = _probed_block(q.dtype, Tq, Tk, D, bwd_form, *extra)
     if block is None:
         return None
-    key = _verdict_key(q.dtype, block, D, bwd_form)
+    key = _verdict_key(q.dtype, block, D, bwd_form, *extra)
     try:
         scope = _traced_mesh()
         if scope is None:
             return flash_attention(q, k, v, causal=causal, block_q=block,
-                                   block_k=block)
+                                   block_k=block, window=window)
         out = flash_attention_over_mesh(q, k, v, *scope, causal=causal,
                                         block=block)
         if out is None:

@@ -79,7 +79,8 @@ NEG_INF = -1e30  # matches ops/attention.py: exp()/where() stay NaN-free
 
 def _paged_kernel(pt_ref, p0_ref, gate_ref, q_ref, k_hbm, v_hbm, *rest,
                   page: int, C: int, G: int, Hkv: int, hd: int,
-                  n_pages: int, sm_scale: float, quantized: bool = False):
+                  n_pages: int, sm_scale: float, quantized: bool = False,
+                  window: Optional[int] = None):
     """Grid (S,), slots in order: slot `s` walks pages
     `0 .. (p0 + C - 1) // page` of its page-table row and no others — a
     loop whose trip count comes from the slot's position, not from the
@@ -101,7 +102,13 @@ def _paged_kernel(pt_ref, p0_ref, gate_ref, q_ref, k_hbm, v_hbm, *rest,
     right before each matmul: one f32 multiply per element by the
     per-(head, position) scale row, then the cast to the MXU feed
     dtype. Numerics are pinned against the `paged_gather_quant` + dense
-    reference by the dispatch probe and the interpret-mode tests."""
+    reference by the dispatch probe and the interpret-mode tests.
+
+    `window` W (static; None: the walk above, op for op): row `c` of
+    slot `s` sees the W positions that end at `p0 + c`. The walk starts
+    at logical page `max(0, p0 - W + 1) // page`, that page's older
+    positions are masked, and the slot's row is a RING of `n_pages`
+    entries: logical page `j` lies at entry `j % n_pages`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -118,15 +125,24 @@ def _paged_kernel(pt_ref, p0_ref, gate_ref, q_ref, k_hbm, v_hbm, *rest,
     S = pl.num_programs(0)
     CG = C * G
 
+    def first_page(slot):
+        # the logical page of the oldest position slot's first row sees
+        return jnp.maximum(p0_ref[slot] - window + 1, 0) // page
+
     def live_pages(slot):
         # pages holding a position some query row can see; an inactive
         # lane walks none: its l stays 0 and the finalize emits exact
         # zeros (the flash kernel's fully-masked-row discipline)
-        n = jnp.minimum((p0_ref[slot] + C - 1) // page + 1, n_pages)
+        n = (p0_ref[slot] + C - 1) // page + 1
+        if window is not None:
+            n = n - first_page(slot)
+        n = jnp.minimum(n, n_pages)
         return jnp.where(gate_ref[slot] != 0, n, 0)
 
     def copies(slot, j, buf):
-        pid = pt_ref[slot, j]
+        # the slot's `j`-th live page
+        pid = pt_ref[slot, j] if window is None \
+            else pt_ref[slot, (first_page(slot) + j) % n_pages]
         return [pltpu.make_async_copy(pool.at[pid], dst.at[buf],
                                       sem.at[i, buf])
                 for i, (pool, dst) in enumerate(pools)]
@@ -168,10 +184,12 @@ def _paged_kernel(pt_ref, p0_ref, gate_ref, q_ref, k_hbm, v_hbm, *rest,
             c.wait()
         dt = _mxu_dtype(q_ref.dtype)
         q = q_ref[0]                                       # (C, H, hd)
-        kpos = j * page + jax.lax.broadcasted_iota(
-            jnp.int32, (CG, page), 1)
+        kpos = (j if window is None else first_page(s) + j) * page \
+            + jax.lax.broadcasted_iota(jnp.int32, (CG, page), 1)
         rowc = jax.lax.broadcasted_iota(jnp.int32, (CG, page), 0) // G
         mask = kpos <= p0 + rowc
+        if window is not None:
+            mask = mask & (kpos > p0 + rowc - window)
         for h in range(Hkv):
             # query heads h*G..(h+1)*G-1 share KV head h; fold (C, G)
             # into the sublane axis so one matmul serves the group
@@ -223,14 +241,15 @@ def _paged_kernel(pt_ref, p0_ref, gate_ref, q_ref, k_hbm, v_hbm, *rest,
 # which attends in 24 layers traces and lowers the kernel once and calls
 # it 24 times: lowering is paid on every start-up, compile cache or not
 # (PERF.md, PR 26 and PR 33)
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                     v_pool: jnp.ndarray, page_table: jnp.ndarray,
                     positions: jnp.ndarray, *,
                     k_scale: Optional[jnp.ndarray] = None,
                     v_scale: Optional[jnp.ndarray] = None,
                     active: Optional[jnp.ndarray] = None,
-                    interpret: bool = False) -> jnp.ndarray:
+                    interpret: bool = False,
+                    window: Optional[int] = None) -> jnp.ndarray:
     """Paged decode/verify/chunk attention, streamed from the pool.
 
     `q`: (S, C, H, hd) — C contiguous query tokens per slot (C=1 for
@@ -253,6 +272,11 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     dequantizes in VMEM inside the page loop — the
     `serving/quantize.py` tier's fast path.
 
+    `window` W: row c of slot s attends to the entries in `(positions[s]
+    + c - W, positions[s] + c]`, and `page_table` (S, R) is then a ring:
+    logical page j at entry `j % R` (`_paged_kernel`). The caller sees
+    to it that the pages a dispatch's rows can see number at most R.
+
     Returns (S, C, H, hd) in q.dtype.
     """
     from jax.experimental import pallas as pl
@@ -269,7 +293,7 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     kernel = functools.partial(
         _paged_kernel, page=page, C=C, G=G, Hkv=Hkv, hd=hd,
         n_pages=n_pages, sm_scale=1.0 / float(hd) ** 0.5,
-        quantized=quantized)
+        quantized=quantized, window=window)
 
     def slot(s, pt, p0, g):
         return (s, 0, 0, 0)
@@ -345,7 +369,8 @@ def _int8_kv_allowed() -> bool:
 
 
 def _eager_probe(dtype, C: int, H: int, Hkv: int, hd: int, page: int,
-                 quantized: bool = False) -> bool:
+                 quantized: bool = False,
+                 window: Optional[int] = None) -> bool:
     """Compile + run the kernel once at this exact shape class on tiny
     concrete pools, out of trace, and CHECK the output against the
     gather+dense reference — the dispatch contract's parity-probed
@@ -353,8 +378,12 @@ def _eager_probe(dtype, C: int, H: int, Hkv: int, hd: int, page: int,
     XLA instead of serving wrong tokens. The int8 variant probes with
     int8 pools + f32 scale pages against the `paged_gather_quant`
     oracle, so the page-loop dequant is parity-checked before the
-    first live dispatch."""
+    first live dispatch. A windowed class probes a ring that has
+    wrapped (`_eager_probe_window`)."""
     import numpy as np
+
+    if window is not None:
+        return _eager_probe_window(dtype, C, H, Hkv, hd, page, window)
 
     from deeplearning4j_tpu.ops.attention import (
         cached_attention_chunk,
@@ -419,9 +448,49 @@ def _eager_probe(dtype, C: int, H: int, Hkv: int, hd: int, page: int,
     return True
 
 
+def _eager_probe_window(dtype, C: int, H: int, Hkv: int, hd: int,
+                        page: int, window: int) -> bool:
+    """The windowed class: two slots whose rings of R entries have
+    wrapped (one whose window starts inside a page, one on a page's
+    first position), against `ops.attention.ring_attention_chunk` over
+    the gathered rings."""
+    import numpy as np
+
+    from deeplearning4j_tpu.ops.attention import ring_attention_chunk
+
+    S = 2
+    # one entry more than the engine's ring: its chunks start on a
+    # multiple of their width, these rows need not
+    R = -(-window // page) + -(-C // page) + 1
+    p0 = np.asarray([2 * R * page + 3 * page // 2,
+                     R * page + window - 1], np.int32)
+    rng = np.random.default_rng(0)
+    pt = jnp.asarray(1 + rng.permutation(S * R).reshape(S, R), jnp.int32)
+    q = jnp.asarray(rng.standard_normal((S, C, H, hd)), dtype)
+    k_pool = jnp.asarray(
+        rng.standard_normal((S * R + 1, Hkv, hd, page)), dtype)
+    v_pool = jnp.asarray(
+        rng.standard_normal((S * R + 1, Hkv, page, hd)), dtype)
+    p0 = jnp.asarray(p0)
+    out = np.asarray(paged_attention(q, k_pool, v_pool, pt, p0,
+                                     window=window)).astype(np.float32)
+    ref = np.asarray(ring_attention_chunk(
+        q, k_pool, v_pool, pt, p0, window)).reshape(S, C, H, hd) \
+        .astype(np.float32)
+    if not np.all(np.isfinite(out)):
+        return False
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
+    if not np.allclose(out, ref, atol=tol, rtol=tol):
+        raise ValueError(
+            "windowed kernel compiled but disagrees with the gathered "
+            f"ring: max abs err {np.max(np.abs(out - ref)):.3g} at "
+            f"atol=rtol={tol:g}")
+    return True
+
+
 def paged_attention_or_none(q, k_pool, v_pool, page_table, positions,
-                            active=None, k_scale=None,
-                            v_scale=None) -> Optional[jnp.ndarray]:
+                            active=None, k_scale=None, v_scale=None,
+                            window=None) -> Optional[jnp.ndarray]:
     """Dispatch probe (the reflective cuDNN-helper load): returns None
     when the kernel can't serve this call — CPU backend, kill switch,
     unsupported dtype, VMEM overflow at this shape — or when the shape
@@ -429,18 +498,21 @@ def paged_attention_or_none(q, k_pool, v_pool, page_table, positions,
     `paged_gather` + the dense step/chunk (`paged_gather_quant` for
     int8 pools). The int8 variant (scales present) is additionally
     gated by ``DL4J_TPU_NO_INT8_KV`` and probes its own shape-class
-    key."""
+    key. A `window` (the table a ring) is a shape class of its own, its
+    key ending `("window", W)`; int8 pools have no windowed form."""
     S, C, H, hd = q.shape
     _, Hkv, _, page = k_pool.shape
     quantized = k_scale is not None
     if not _platform_supported() \
             or q.dtype not in (jnp.float32, jnp.bfloat16) \
-            or H % Hkv:
+            or H % Hkv or (quantized and window is not None):
         return None
     if quantized and not _int8_kv_allowed():
         return None
     key = (jnp.dtype(q.dtype).name, C, H, Hkv, hd, page,
            "int8" if quantized else "dense")
+    if window is not None:
+        key += ("window", int(window))
     kv_itemsize = 1 if quantized else q.dtype.itemsize
     est = vmem_bytes_estimate(C, H, Hkv, hd, page, q.dtype.itemsize,
                               kv_itemsize=kv_itemsize)
@@ -450,14 +522,15 @@ def paged_attention_or_none(q, k_pool, v_pool, page_table, positions,
                         f"{_vmem_limit() >> 20} MiB ceiling")
         return None
     if not _probe_verdict(FAMILY, key, _eager_probe,
-                          (q.dtype, C, H, Hkv, hd, page, quantized)):
+                          (q.dtype, C, H, Hkv, hd, page, quantized)
+                          + (() if window is None else (int(window),))):
         return None
     if active is None:  # one traced function a shape class
         active = jnp.ones((S,), jnp.bool_)
     try:
         return paged_attention(q, k_pool, v_pool, page_table, positions,
                                k_scale=k_scale, v_scale=v_scale,
-                               active=active)
+                               active=active, window=window)
     except Exception as e:  # per-shape staging failure: fall back
         _record_decline(FAMILY, key,
                         f"staging at {q.shape}: {type(e).__name__}: {e}")

@@ -11,6 +11,15 @@ kind each block keeps (`GPTPlan.state_kinds`):
                     one position a token, read through the page table
                     (`TransformerBlock`, or a composed block whose mixer
                     is attention);
+    WindowPages     the K/V pools of an attention mixer that reads a
+                    WINDOW of its context: pools of their own of
+                    `n_slots * ring_pages` pages (+ the trash page), a
+                    slot's pages a ring of `ring_pages` entries in the
+                    page pool's second table (`serving/page_pool.py`);
+                    `KVPages`' writers with the ring's page ids, the
+                    windowed paged attention to read; a prompt's whole
+                    bucket attends in flight, so one longer than the
+                    ring keeps its last pages;
     RecurrentSlots  per-slot arrays of fixed size, as the mixer's
                     `state_shapes` declares them (a Mamba-2 mixer's
                     float32 state `(S, H, P, N)`, a gated delta-rule
@@ -122,15 +131,28 @@ class _ComposedAttention:
         self.kv_heads, self.head_dim = layer.mixer.kv_geometry(layer._d)
 
     def heads(self, p, x, positions):
+        # a mixer without rotary reads no position
         return self.layer.mixer.heads(sub(p, "mx_"),
-                                      self.layer.mixer_in(p, x))
+                                      self.layer.mixer_in(p, x), positions)
 
     def finish(self, p, x, att, d):
         return _finish_composed(self.layer, p, x,
                                 self.layer.mixer.out(sub(p, "mx_"), att), d)
 
     def prefill_attention(self, q, k, v):
-        return self._T._prefill_block_attention(self.layer.mixer, q, k, v)
+        """A bucket at or under `_FLASH_FROM` keys without a window: the
+        parent's program, the (H, P, P) scores in one array. A window,
+        or a longer bucket (128 heads at 4,096 keys: 8.6 GB of scores):
+        `ops.attention.grouped_causal_attention`."""
+        from deeplearning4j_tpu.nn.conf.decoder_block import _FLASH_FROM
+        from deeplearning4j_tpu.ops import attention
+
+        mixer = self.layer.mixer
+        if q.shape[1] > _FLASH_FROM or mixer.window is not None:
+            with jax.named_scope("attn.core"):
+                return attention.grouped_causal_attention(
+                    q, k, v, window=mixer.window, one_array_to=_FLASH_FROM)
+        return self._T._prefill_block_attention(mixer, q, k, v)
 
 
 def _finish_composed(layer, p, x, mixed, d):
@@ -202,9 +224,25 @@ class _Kind:
         return 0  # pages are held by length, not by slot
 
 
+class _ByPhase:
+    """`decode`, `prefill` and `prefill_chunk` as one `_block(which, p, x,
+    cache, d)`: the state objects whose three programs differ only in
+    which of a mixer's `mix_<which>` they run."""
+
+    def decode(self, p, x, cache, d):
+        return self._block("decode", p, x, cache, d)
+
+    def prefill(self, p, x, cache, d):
+        return self._block("prefill", p, x, cache, d)
+
+    def prefill_chunk(self, p, x, cache, d):
+        return self._block("prefill_chunk", p, x, cache, d)
+
+
 class KVPages(_Kind):
     kind = "kv"
     blocks_key, token_bytes_key = "kv_blocks", "kv_bytes_per_token"
+    window = None  # positions a query reads back (None: its whole context)
 
     def __init__(self, layer, env):
         self.env = env
@@ -214,9 +252,18 @@ class KVPages(_Kind):
         # a hand-off names the pools so; int8 pools bring their scales
         self.names = ("k", "v", "ks", "vs") if env.kv_quant else ("k", "v")
 
+    def _pool_pages(self) -> int:
+        return self.env.pool_pages
+
+    def _ids(self, d, name: str):
+        """The step's page ids and tables for this kind's class of page:
+        `pids`, `page_table`, `wpids`, `page_row` of `d`."""
+        return getattr(d, name)
+
     def alloc(self) -> tuple:
         env, b = self.env, self.block
-        P, page, Hkv, hd = env.pool_pages, env.page, b.kv_heads, b.head_dim
+        P, page, Hkv, hd = self._pool_pages(), env.page, b.kv_heads, \
+            b.head_dim
         # +1: page 0 is the reserved trash page for masked writes
         if env.kv_quant:
             # int8 payload pools + f32 per-(head, position) scale
@@ -271,10 +318,12 @@ class KVPages(_Kind):
                 kq, ksc = quantize_heads(k)
                 vq, vsc = quantize_heads(v)
                 kp_, vp_, ks_, vs_ = _write_token(
-                    cache, kq, vq, d.pids, d.loff, (ksc, vsc))
+                    cache, kq, vq, self._ids(d, "pids"), d.loff,
+                    (ksc, vsc))
             else:
                 ks_ = vs_ = None
-                kp_, vp_ = _write_token(cache, k, v, d.pids, d.loff)
+                kp_, vp_ = _write_token(cache, k, v, self._ids(d, "pids"),
+                                        d.loff)
         # kernel-dispatched paged attention: on TPU the Pallas
         # kernel streams pages straight from the pool (no dense
         # gather transient — the decode path's dominant cache-
@@ -282,8 +331,8 @@ class KVPages(_Kind):
         # step reference numerics run unchanged
         with jax.named_scope("kv.attend"):
             att = paged_attention_step_auto(
-                q, kp_, vp_, d.page_table, d.pos, d.active,
-                k_scale=ks_, v_scale=vs_)
+                q, kp_, vp_, self._ids(d, "page_table"), d.pos, d.active,
+                k_scale=ks_, v_scale=vs_, window=self.window)
         x = self.block.finish(p, x, att, d)
         return x, ((kp_, vp_, ks_, vs_) if env.kv_quant else (kp_, vp_))
 
@@ -304,14 +353,15 @@ class KVPages(_Kind):
                 kp_, vp_, ks_, vs_ = cache
                 kcol, kscol = quantize_heads(kcol, axis=2)
                 vrow, vscol = quantize_heads(vrow, axis=3)
-                ks_ = _write_scale_pages(ks_, kscol, d.wpids, z0, env.page)
-                vs_ = _write_scale_pages(vs_, vscol, d.wpids, z0, env.page)
-                kp_, vp_ = _write_pages(kp_, vp_, kcol, vrow, d.wpids, z0,
+                wpids = self._ids(d, "wpids")
+                ks_ = _write_scale_pages(ks_, kscol, wpids, z0, env.page)
+                vs_ = _write_scale_pages(vs_, vscol, wpids, z0, env.page)
+                kp_, vp_ = _write_pages(kp_, vp_, kcol, vrow, wpids, z0,
                                         env.page)
                 return x, (kp_, vp_, ks_, vs_)
             kp_, vp_ = cache
-            kp_, vp_ = _write_pages(kp_, vp_, kcol, vrow, d.wpids, z0,
-                                    env.page)
+            kp_, vp_ = _write_pages(kp_, vp_, kcol, vrow,
+                                    self._ids(d, "wpids"), z0, env.page)
             return x, (kp_, vp_)
 
     def prefill_chunk(self, p, x, cache, d):
@@ -324,19 +374,20 @@ class KVPages(_Kind):
         q, k, v = self.block.heads(p, x, d.qpos)
         kcol = jnp.transpose(k, (0, 2, 3, 1))   # (1, Hkv, hd, C)
         vrow = jnp.transpose(v, (0, 2, 1, 3))   # (1, Hkv, C, hd)
+        wpids = self._ids(d, "wpids")
         with jax.named_scope("kv.write"):
             if env.kv_quant:
                 kp_, vp_, ks_, vs_ = cache
                 kcol, kscol = quantize_heads(kcol, axis=2)
                 vrow, vscol = quantize_heads(vrow, axis=3)
-                ks_ = _write_scale_pages(ks_, kscol, d.wpids, d.woff,
+                ks_ = _write_scale_pages(ks_, kscol, wpids, d.woff,
                                          env.page)
-                vs_ = _write_scale_pages(vs_, vscol, d.wpids, d.woff,
+                vs_ = _write_scale_pages(vs_, vscol, wpids, d.woff,
                                          env.page)
             else:
                 kp_, vp_ = cache
                 ks_ = vs_ = None
-            kp_, vp_ = _write_pages(kp_, vp_, kcol, vrow, d.wpids, d.woff,
+            kp_, vp_ = _write_pages(kp_, vp_, kcol, vrow, wpids, d.woff,
                                     env.page)
         # attend AFTER the write: the chunk attends to itself
         # through the cache, which is exactly causal with the
@@ -345,10 +396,58 @@ class KVPages(_Kind):
         # (`_prefill_chunk_block_attention` numerics) elsewhere
         with jax.named_scope("kv.attend"):
             att = paged_attention_chunk_auto(
-                q, kp_, vp_, d.page_row[None], d.off[None],
-                k_scale=ks_, v_scale=vs_)
+                q, kp_, vp_, self._ids(d, "page_row")[None], d.off[None],
+                k_scale=ks_, v_scale=vs_, window=self.window)
         x = self.block.finish(p, x, att.reshape(1, Cw, -1), d)
         return x, ((kp_, vp_, ks_, vs_) if env.kv_quant else (kp_, vp_))
+
+
+class WindowPages(_ByPhase, KVPages):
+    """A block whose attention reads the last `window` positions: K/V
+    pools of its own class of page, `n_slots * ring_pages` of them, a
+    slot's a ring (module docstring). It IS `KVPages` with the ring's
+    ids and tables (`d.ring_pids`, `d.ring_page_table`, `d.ring_wpids`,
+    `d.ring_page_row`: the page pool's second table, handed by the programs)
+    and the window handed to the paged attention."""
+    kind = "window"
+    blocks_key, token_bytes_key = "window_blocks", None
+    refuses = dict.fromkeys(
+        _CACHE_FEATURES, "a window layer's ring of pages")
+
+    def __init__(self, layer, env):
+        super().__init__(layer, env)
+        self.window = layer.mixer.window
+
+    def _pool_pages(self) -> int:
+        return self.env.n_slots * self.env.ring_pages
+
+    def _ids(self, d, name: str):
+        return getattr(d, "ring_" + name)
+
+    def ring_bytes_per_slot(self) -> int:
+        """A slot's ring in this block, whatever its request's length."""
+        return self.env.ring_pages * self.env.page * self.bytes_per_token()
+
+    # its pages are not `read_pages` / `write_pages`' to move: a ring's
+    # ids mean nothing in another engine's table
+    read_pages = write_pages = None
+
+    def _block(self, which, p, x, cache, d):
+        with jax.named_scope("kv.window"):
+            return getattr(KVPages, which)(self, p, x, cache, d)
+
+
+def ring_pages(plan, page: int, prefill_chunk: int) -> int:
+    """Entries of a slot's ring of window pages: the pages of the
+    plan's widest window, and those a prefill chunk (a decode step: one)
+    writes ahead of the window it still reads; 0 where no block reads a
+    window."""
+    windows = [m.window for i in plan.block_is
+               for m in getattr(plan.layers[i], "mixers", list)()
+               if getattr(m, "window", None) is not None]
+    if not windows:
+        return 0
+    return -(-max(windows) // page) + max(1, -(-prefill_chunk // page))
 
 
 class RecurrentSlots(_Kind):
@@ -410,21 +509,6 @@ class RecurrentSlots(_Kind):
             n_valid=jnp.clip(d.t0 - d.off, 0, Cw))
         x = _finish_composed(self.layer, p, x, y, d)
         return x, self._store(cache, h1, tail1, d.slot)
-
-
-class _ByPhase:
-    """`decode`, `prefill` and `prefill_chunk` as one `_block(which, p, x,
-    cache, d)`: the state objects whose three programs differ only in
-    which of a mixer's `mix_<which>` they run."""
-
-    def decode(self, p, x, cache, d):
-        return self._block("decode", p, x, cache, d)
-
-    def prefill(self, p, x, cache, d):
-        return self._block("prefill", p, x, cache, d)
-
-    def prefill_chunk(self, p, x, cache, d):
-        return self._block("prefill_chunk", p, x, cache, d)
 
 
 class LatentPages(_ByPhase, _Kind):
@@ -567,7 +651,7 @@ class Stateless(_Kind):
 
 
 _KINDS = {"kv": KVPages, "recurrent": RecurrentSlots, "none": Stateless,
-          "latent": LatentPages}
+          "latent": LatentPages, "window": WindowPages}
 
 
 def block_states(plan, env) -> list:
@@ -594,9 +678,10 @@ def describe(states, env) -> SimpleNamespace:
     """What the built `states` say of themselves: `counters`, the keys
     of `stats()` about the caches (every kind's, 0 where no block is of
     it; numbers, so that they survive into the Prometheus exposition);
-    whether an admission overwrites per-slot state; and whether every
+    whether an admission overwrites per-slot state; whether every
     block's pages can be moved (`kv_only`: what the hand-off plane
-    asks)."""
+    asks); and `positions(ctx)`, what the K/V blocks read of a
+    context."""
     c = {key: 0 for cls in _KINDS.values()
          for key in (cls.blocks_key, cls.token_bytes_key) if key}
     # a two-mixer block counts once for each cache it keeps
@@ -607,9 +692,39 @@ def describe(states, env) -> SimpleNamespace:
     c["state_bytes_per_slot"] = sum(st.bytes_per_slot() for st in states)
     c["kv_quant_bits"] = 8 if env.kv_quant \
         else 8 * jnp.dtype(env.cdt).itemsize  # of the BUILT pools
+    rings = [st for st in states if isinstance(st, WindowPages)]
+    c["window_ring_pages"] = env.ring_pages if rings else 0
+    c["window_bytes_per_slot"] = sum(st.ring_bytes_per_slot()
+                                     for st in rings)
+    # what each block that keeps K/V reads back of a context: a window's
+    # width, or None for all of it
+    spans = [st.window for st in states if isinstance(st, KVPages)]
+
+    def positions(first, n_steps: int = 1) -> tuple:
+        """Over `n_steps` consecutive steps of slots whose contexts at
+        the first of them are `first` (one a slot), how many positions
+        the K/V blocks' attention reads, and how many it would with no
+        window: a slot's contexts are an arithmetic series, a window
+        block's clipped at its width."""
+        first = np.asarray(first, np.int64).reshape(-1)
+        whole = int(n_steps * first.sum()
+                    + first.size * (n_steps * (n_steps - 1) // 2))
+        read = 0
+        for w in spans:
+            if w is None:
+                read += whole
+                continue
+            # the steps whose context still lies inside the window
+            m = np.clip(w - first + 1, 0, n_steps)
+            read += int((m * first + m * (m - 1) // 2
+                         + (n_steps - m) * w).sum())
+        return read, whole * len(spans)
+
     return SimpleNamespace(
         counters=c, resets_on_admission=c["state_bytes_per_slot"] > 0,
-        kv_only=all(hasattr(st, "read_pages") for st in states))
+        kv_only=all(getattr(st, "read_pages", None) is not None
+                    for st in states),
+        positions=positions)
 
 
 def routed_ffns(plan) -> list:

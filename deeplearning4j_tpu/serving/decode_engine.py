@@ -109,7 +109,7 @@ class _GenRequest:
 
     __slots__ = ("prompt", "n_tokens", "temperature", "seed", "deadline",
                  "event", "tokens", "error", "enqueued_at", "probe",
-                 "slot", "completed_at", "n_pages", "pages",
+                 "slot", "completed_at", "n_pages", "pages", "ring",
                  "prefill_pos", "hit_len", "n_shared", "nodes", "digests",
                  "trace", "tenant", "priority", "resumed_at",
                  "preempted", "handoff", "import_state", "prefix_import",
@@ -145,6 +145,9 @@ class _GenRequest:
         self.slot: Optional[int] = None
         self.n_pages = 0
         self.pages: Optional[List[int]] = None
+        # its ring of window pages (the pool's second class), where the
+        # net has blocks that read a window; taken and returned with `pages`
+        self.ring: Optional[List[int]] = None
         self.prefill_pos: Optional[int] = None
         self.in_flight = 0
         # prefix-cache binding: hit_len prompt positions ride shared
@@ -800,9 +803,14 @@ class DecodeEngine:
 
         # what each block keeps between tokens, by the kind the plan
         # declares for it (serving/block_state.py)
+        # a slot's ring of window pages, the page pool's second class (0:
+        # no block reads a window, and nothing below is other than it was)
+        ring = block_state.ring_pages(plan, page, C if chunk_enabled
+                                      else page)
         env = SimpleNamespace(
             n_slots=S, page=page, pool_pages=pool_pages, cdt=cdt,
-            kv_quant=kv_quant, tp_shard=tp_shard, tp_axis=tp_axis)
+            kv_quant=kv_quant, tp_shard=tp_shard, tp_axis=tp_axis,
+            ring_pages=ring)
         states = block_state.block_states(plan, env)
         # the routed experts' counts: the new plan's facts, the totals
         # the engine's across swaps
@@ -811,7 +819,8 @@ class DecodeEngine:
         programs = decode_programs.build_programs(
             plan, states, n_slots=S, page=page, L_logical=L_logical,
             decode_chunk=self.decode_chunk, top_k=self.top_k,
-            logprobs=self._logprobs_k, tp=tp, donate=donate)
+            logprobs=self._logprobs_k, tp=tp, donate=donate,
+            ring_pages=ring)
         # weights placed once per (re)build: permuted + head/width-
         # sharded over the mesh under TP (a weight swap reshards from
         # the swapped net's clean host copy), then cast to the compute
@@ -906,10 +915,11 @@ class DecodeEngine:
             self._cond, n_slots=S, page_size=page, pool_pages=pool_pages,
             n_pages_max=n_pages_max, prefill_width=self._prefill_width,
             prefix_cache=self._prefix_cache, leases=self._plane.leases,
-            recorder=self.recorder)
+            recorder=self.recorder, ring_pages=ring)
         if old is not None:
-            with self._cond:  # the peak is the engine's, across swaps
+            with self._cond:  # the peaks are the engine's, across swaps
                 self._pool.in_use_peak = old.in_use_peak
+                self._pool.ring_in_use_peak = old.ring_in_use_peak
         self._plane.on_rebuild(
             pool=self._pool, weight_version=self._weight_version,
             kv_quant=kv_quant, max_len=L, n_blocks=len(states),
@@ -1743,6 +1753,10 @@ class DecodeEngine:
                "pool_pages": self.pool_pages,
                "pages_in_use": held,
                "pages_in_use_peak": self._pool.in_use_peak,
+               # the pool's second class, the window blocks' rings (0
+               # where the net has none)
+               "window_pages_in_use": self._pool.ring_in_use(),
+               "window_pages_in_use_peak": self._pool.ring_in_use_peak,
                "queued_page_demand": demand,
                "max_queued_pages": self.max_queued_pages,
                "page_fragmentation_pct": round(frag, 1),
@@ -2310,6 +2324,7 @@ class DecodeEngine:
                     self.prefix_misses += 1
                 self.prompt_tokens += int(req.prompt.shape[0])
                 req.pages = self._pool.take_locked(need, nodes)
+                req.ring = self._pool.take_ring_locked(len(req.pages))
                 held = self._pool.in_use()
             if nodes:
                 req.trace.event("prefix-bind", shared_pages=req.n_shared,
@@ -2321,7 +2336,7 @@ class DecodeEngine:
                                 hit_tokens=req.hit_len,
                                 pages_in_use=held, tenant=req.tenant,
                                 priority=req.priority)
-            self._pool.bind_row(slot, req.pages)
+            self._pool.bind_row(slot, req.pages, req.ring)
             if req.prefix_import is not None:
                 # fetched cluster-prefix pages scatter into the freshly
                 # allocated tail pages and promote into the local cache
@@ -2392,7 +2407,7 @@ class DecodeEngine:
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :t0] = req.prompt
         n_w = -(-bucket // page)
-        wpids = jnp.asarray(np.asarray(req.pages[:n_w], np.int32))
+        wpids = self._pool.write_ids(req, 0, n_w, (t0 - 1) // page)
         key = jax.random.PRNGKey(req.seed)
         kp, kdec = jax.random.split(key)  # generate()'s prefill/decode split
         info = {"slot": slot, "bucket": bucket, "t0": t0}
@@ -2533,12 +2548,9 @@ class DecodeEngine:
         ids = np.zeros((1, W), np.int32)
         take = min(W, rem)
         ids[0, :take] = req.prompt[off:off + take]
-        if W >= page:
-            pids = req.pages[off // page: off // page + W // page]
-            woff = 0
-        else:
-            pids = [req.pages[off // page]]
-            woff = off % page
+        n_w = max(1, W // page)
+        pids = req.pages[off // page: off // page + n_w]
+        woff = 0 if W >= page else off % page
         key = jax.random.PRNGKey(req.seed)
         kp, kdec = jax.random.split(key)
         info = {"slot": slot, "t0": t0, "chunk": W, "chunk_off": off,
@@ -2546,12 +2558,13 @@ class DecodeEngine:
         self._hook("pre_prefill", info)
 
         def run():
-            args = (self._weights, self._caches, self._page_table[slot],
+            args = (self._weights, self._caches, self._pool.rows(slot),
                     jnp.asarray(ids), jnp.asarray(off, jnp.int32),
                     jnp.asarray(woff, jnp.int32),
                     jnp.asarray(t0, jnp.int32),
                     jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(np.asarray(pids, np.int32)),
+                    self._pool.write_ids(req, off // page, n_w,
+                                         (t0 - 1) // page),
                     self._tok, self._pos, self._keys, self._temps, kp,
                     kdec, jnp.asarray(req.temperature, jnp.float32))
             if self._logprobs_k:
@@ -2667,7 +2680,8 @@ class DecodeEngine:
                 if r is not None:
                     self._slots[s] = None
                     self._active[s] = False
-                    r.pages = None  # pools rebuild wholesale after this
+                    # the pools, both classes, rebuild wholesale after this
+                    r.pages = r.ring = None
                     r.nodes = None  # ... and the prefix cache clears
                     if r.completed_at is not None:
                         continue  # ended at a collect; only pages stayed
@@ -3149,7 +3163,7 @@ class DecodeEngine:
             self._hook("pre_decode", info)
             fn = self._decode_chunked if chunked else self._decode_step
             out = _dispatched(lambda: fn(
-                self._weights, self._caches, self._page_table, self._tok,
+                self._weights, self._caches, self._pool.tables, self._tok,
                 self._pos, self._keys, self._temps, jnp.asarray(mask)))
         # graftlint: disable=typed-error  converts to a typed failure:
         # _decode_failure wraps the cause in InferenceFailedError for the
@@ -3167,6 +3181,7 @@ class DecodeEngine:
         lps_d = rest.pop(0) if self._logprobs_k else None
         counts_d = rest.pop(0) if rest else None
         page, width = self.page_size, self._n_pages_max
+        first = []
         for _, r in live:
             # the slot's position at the dispatch's first step, from
             # what the host holds: the pages `kv.attend` walks there
@@ -3174,8 +3189,17 @@ class DecodeEngine:
                 + r.in_flight - 1
             ph.kv_pages_walked += sum(min((pos + j) // page + 1, width)
                                       for j in range(n_steps))
+            first.append(pos + 1)
             r.in_flight += n_steps
         ph.kv_pages_table += len(live) * n_steps * width
+        # and the positions the K/V blocks' attention reads over the
+        # dispatch, beside what it would read with no window: once a
+        # dispatch, on the span too (a reader of a traced stretch sums
+        # the spans inside it)
+        read, ctx = self._kinds.positions(first, n_steps)
+        ph.kv_positions_attended += read
+        ph.kv_positions_context += ctx
+        ph.annotate(kv_positions_attended=read, kv_positions_context=ctx)
         ph.ahead_n += bool(self._inflight)
         self._inflight.append(_InFlight(
             program, live, (toks_d, oks_d, lps_d, counts_d), t0, info,

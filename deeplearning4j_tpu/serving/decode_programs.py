@@ -86,13 +86,24 @@ def token_logprobs(logits, chosen_tok, K: int):
 
 def build_programs(plan, states, *, n_slots: int, page: int,
                    L_logical: int, decode_chunk: int, top_k: int,
-                   logprobs: int, tp, donate: bool) -> SimpleNamespace:
+                   logprobs: int, tp, donate: bool,
+                   ring_pages: int = 0) -> SimpleNamespace:
     """The four jitted programs for `plan` over `states` (one state
     object per block, `block_state.block_states`). `L_logical` is the
     per-slot cache length in positions (a whole number of pages),
     `logprobs` the width K of the per-token logprob report (0: none;
     never combined with `tp`, so the extra tuple never crosses a
-    `shard_map` boundary), `tp` the `TPPlan` or None."""
+    `shard_map` boundary), `tp` the `TPPlan` or None.
+
+    `ring_pages` R > 0: the page pool has a second class of page, each
+    slot's a ring of R entries (`serving/page_pool.py`), and the
+    programs' `page_table`, `page_row` and `wpids` arguments are then
+    PAIRS, the pool's first table's and the ring's (`PagePool.tables`,
+    `.rows`, `.write_ids`); the step's `d` carries the second as
+    `ring_page_table`, `ring_pids`, `ring_page_row`, `ring_wpids` for the
+    blocks
+    that keep such pages. 0: one array each, and the programs are what
+    they were."""
     S, K = n_slots, logprobs
     emb_i, block_is = plan.emb_i, plan.block_is
     emb, cdt = plan.emb, plan.cdt
@@ -123,13 +134,19 @@ def build_programs(plan, states, *, n_slots: int, page: int,
         wpos = jnp.minimum(pos, L_logical - 1)
         lpage = wpos // page
         rows = jnp.arange(S)
+        ring = {}
+        if ring_pages:
+            # logical page j of a slot's ring lies at entry j % R
+            page_table, ring_table = page_table
+            ring = dict(ring_page_table=ring_table, ring_pids=jnp.where(
+                active, ring_table[rows, lpage % ring_pages], 0))
         d = SimpleNamespace(
             page_table=page_table, pos=pos, active=active,
             loff=wpos % page,
             # inactive lanes write to the reserved trash page 0
             pids=jnp.where(active, page_table[rows, lpage], 0),
             # the active slots' choices are counted, where the net routes
-            **account.step_fields(active))
+            **account.step_fields(active), **ring)
         new_caches = []
         for bi, i in enumerate(block_is):
             x, cache = states[bi].decode(bp[i], x, caches[bi], d)
@@ -187,7 +204,11 @@ def build_programs(plan, states, *, n_slots: int, page: int,
         if emb.positional:
             x = x + bp[emb_i]["P"][:P]
         x = emb.scaled(x).astype(cdt)
-        d = SimpleNamespace(wpids=wpids, t0=t0, slot=slot)
+        ring = {}
+        if ring_pages:
+            wpids, ring_wpids = wpids
+            ring = dict(ring_wpids=ring_wpids)
+        d = SimpleNamespace(wpids=wpids, t0=t0, slot=slot, **ring)
         new_caches = []
         for bi, i in enumerate(block_is):
             x, cache = states[bi].prefill(bp[i], x, caches[bi], d)
@@ -237,9 +258,13 @@ def build_programs(plan, states, *, n_slots: int, page: int,
             x = x + bp[emb_i]["P"][jnp.minimum(qpos,
                                                emb.max_length - 1)]
         x = emb.scaled(x).astype(cdt)
+        ring = {}
+        if ring_pages:
+            (wpids, ring_wpids), (page_row, ring_row) = wpids, page_row
+            ring = dict(ring_wpids=ring_wpids, ring_page_row=ring_row)
         d = SimpleNamespace(wpids=wpids, woff=woff, off=off,
                             qpos=qpos, page_row=page_row,
-                            t0=t0, slot=slot)
+                            t0=t0, slot=slot, **ring)
         new_caches = []
         for bi, i in enumerate(block_is):
             x, cache = states[bi].prefill_chunk(bp[i], x, caches[bi],

@@ -875,6 +875,11 @@ class ThreadPhases:
             self._annotation = _TraceAnnotation(self._labels[name])
             self._annotation.__enter__()
 
+    def annotate(self, **attrs) -> None:
+        """Add `attrs` to the span of the phase the thread is in."""
+        name, t0, cause, have = self._open
+        self._open = (name, t0, cause, {**(have or {}), **attrs})
+
     @property
     def current(self) -> Optional[str]:
         """The phase the thread is in."""
@@ -934,11 +939,17 @@ class SchedulerPhases(ThreadPhases):
         # steps, and those slots x steps x the page table's width
         self.kv_pages_walked = 0
         self.kv_pages_table = 0
+        # and of the K/V blocks' attention: the positions read over those
+        # steps, slots and blocks (a window block: at most its window),
+        # and what they would be with no window
+        self.kv_positions_attended = 0
+        self.kv_positions_context = 0
 
     def counters(self) -> dict:
         """The phases' `<phase>_s`, `<phase>_n` and ``{"iterations",
         "sink_s", "sink_n", "ahead_n", "drained_n", "overshoot_tokens",
-        "kv_pages_walked", "kv_pages_table", "spans_dropped"}`` (the
+        "kv_pages_walked", "kv_pages_table", "kv_positions_attended",
+        "kv_positions_context", "spans_dropped"}`` (the
         engine's `stats()["loop"]` adds `prefill_sorted_n`, which its
         routing account keeps)."""
         out = {"iterations": self.iterations}
@@ -950,6 +961,8 @@ class SchedulerPhases(ThreadPhases):
         out["overshoot_tokens"] = self.overshoot_tokens
         out["kv_pages_walked"] = self.kv_pages_walked
         out["kv_pages_table"] = self.kv_pages_table
+        out["kv_positions_attended"] = self.kv_positions_attended
+        out["kv_positions_context"] = self.kv_positions_context
         out["spans_dropped"] = self._timeline.dropped
         return out
 
@@ -1147,6 +1160,11 @@ DECODE_ENGINE_STATS_KEYS = frozenset({
     "kv_blocks", "stateless_blocks", "moe_routed",
     "moe_held_choices", "moe_experts_hit", "moe_experts_read", "moe_steps",
     "moe_experts_held",
+    # window blocks (attention that reads the last W positions): how
+    # many, a slot's ring of pages and its bytes over those blocks, and
+    # the page pool's second class in use (all 0 on a net without them)
+    "window_blocks", "window_ring_pages", "window_bytes_per_slot",
+    "window_pages_in_use", "window_pages_in_use_peak",
     # tensor-parallel tier: mesh degree (1 = single-device engine, so
     # capacity dashboards never branch on key presence) and the
     # per-shard slice of kv_bytes_per_token — each device's actual

@@ -3,7 +3,26 @@
 Every block's K/V pools (`serving/block_state.KVPages`) are cut into
 `pool_pages` pages of `page_size` positions, plus page 0, a reserved
 trash page that absorbs masked writes from inactive slots. `PagePool`
-is the one owner of the ids 1..pool_pages:
+is the one owner of page ids, in TWO classes where the net has blocks
+that read a window of their context only:
+
+- the pages of blocks that read their whole context (K/V and latent
+  pools), ids 1..pool_pages, held by a request's LENGTH: everything
+  below, as it always was;
+- the pages of window blocks (`block_state.WindowPages`, pools of their
+  own of `n_slots * ring_pages` pages + the trash page), ids
+  1..n_slots * ring_pages, of which a request holds `min(pages_for,
+  ring_pages)` whatever its length: a RING, logical page `j` at entry
+  `j % ring_pages` of the slot's row in a second device table
+  `ring_table` `(n_slots, ring_pages)`. They are taken and returned with
+  the request's other pages, under the same lock (`take_ring_locked`,
+  `release_locked`), and never mid-request; the class cannot run short
+  while a slot is free, since every slot's whole ring is provisioned.
+  `ring_pages` 0 (a net without window blocks): none of this exists, and
+  the pool, its one table and what the programs are handed (`tables`,
+  `rows`, `write_ids`) are the one-class pool's.
+
+Of the first class it owns:
 
 - the **free list** of page ids nobody holds;
 - the device **page table** `(n_slots, n_pages_max)`: row `s` lists, in
@@ -52,7 +71,8 @@ class PagePool:
     def __init__(self, cond, *, n_slots: int, page_size: int,
                  pool_pages: int, n_pages_max: int,
                  prefill_width: Callable[[int], int],
-                 prefix_cache=None, leases=None, recorder=None):
+                 prefix_cache=None, leases=None, recorder=None,
+                 ring_pages: int = 0):
         self._cond = cond
         self.n_slots = n_slots
         self.page_size = page_size
@@ -66,6 +86,13 @@ class PagePool:
         self.in_use_peak = 0  # guarded by: _cond
         # scheduler-thread-owned, like the pools
         self.page_table = jnp.zeros((n_slots, n_pages_max), jnp.int32)
+        # the second class: every slot's ring of window pages
+        self.ring_pages = ring_pages
+        self.ring_pool_pages = n_slots * ring_pages
+        self._free_ring = list(range(self.ring_pool_pages, 0, -1))  # guarded by: _cond
+        self.ring_in_use_peak = 0  # guarded by: _cond
+        self.ring_table = jnp.zeros((n_slots, ring_pages), jnp.int32) \
+            if ring_pages else None
 
     # -- arithmetic --------------------------------------------------------
     def pages_for(self, t0: int, n_tokens: int) -> int:
@@ -85,12 +112,22 @@ class PagePool:
         request actually writes — always <= the cold `pages_for`."""
         return -(-(t0 + n_tokens - 1) // self.page_size)
 
+    def ring_for(self, n_pages: int) -> int:
+        """Window pages a request of `n_pages` logical pages holds: all
+        of them, or a whole ring (0 where no block reads a window)."""
+        return min(n_pages, self.ring_pages)
+
     def can_hold(self, n_pages: int) -> bool:
-        """False: no retirement can ever make room for this many."""
-        return n_pages <= self.pool_pages
+        """False: no retirement can ever make room for this many, in
+        either class."""
+        return n_pages <= self.pool_pages \
+            and self.ring_for(n_pages) <= self.ring_pool_pages
 
     def in_use(self) -> int:
         return self.pool_pages - len(self._free_pages)
+
+    def ring_in_use(self) -> int:
+        return self.ring_pool_pages - len(self._free_ring)
 
     def n_free(self) -> int:
         return len(self._free_pages)
@@ -100,8 +137,12 @@ class PagePool:
         """Whether `need` pages are free, after releasing idle cached
         pages (LRU, leaf-first) if they are not: caching never shrinks
         effective capacity. `pinned` — the caller's own hit chain — is
-        held across the reclaim so it cannot eat it."""
+        held across the reclaim so it cannot eat it. The request's
+        `need + len(pinned)` logical pages must also find their window
+        pages (`ring_for`) free."""
         assert_owned(self._cond, "PagePool.make_room_locked")
+        if self.ring_for(need + len(pinned)) > len(self._free_ring):
+            return False
         short = need - len(self._free_pages)
         if short > 0 and self.prefix_cache is not None:
             self.prefix_cache.acquire(pinned)
@@ -127,14 +168,29 @@ class PagePool:
         self.in_use_peak = max(self.in_use_peak, self.in_use())
         return pages
 
+    def take_ring_locked(self, n_pages: int) -> List[int]:
+        """The window pages of a request just admitted with `n_pages`
+        logical pages (`take_locked`'s list), under the same hold of the
+        lock: its ring, in entry order; [] where no block reads a
+        window."""
+        assert_owned(self._cond, "PagePool.take_ring_locked")
+        ring = [self._free_ring.pop() for _ in range(self.ring_for(n_pages))]
+        self.ring_in_use_peak = max(self.ring_in_use_peak,
+                                    self.ring_in_use())
+        return ring
+
     def release_locked(self, holder) -> None:
         """Drop a holder's page references: owned pages return to the
         free list; shared (cached) pages only lose this holder's
         refcount — the cache keeps them resident until LRU reclaim, and
-        a prefix another slot still shares is never freed here. Once
-        per request (retirement, expiry, failure) and once per lease
-        (commit, abort, expiry)."""
+        a prefix another slot still shares is never freed here; its
+        window pages (`holder.ring`, where it has any) return to theirs.
+        Once per request (retirement, expiry, failure) and once per
+        lease (commit, abort, expiry)."""
         assert_owned(self._cond, "PagePool.release_locked")
+        if getattr(holder, "ring", None):
+            self._free_ring.extend(holder.ring)
+            holder.ring = None
         if holder.nodes:
             self.prefix_cache.release(holder.nodes)
             holder.nodes = None
@@ -187,12 +243,48 @@ class PagePool:
 
     # -- the device page table ---------------------------------------------
     # graftlint: hot-loop
-    def bind_row(self, slot: int, pages: List[int]) -> None:
+    def bind_row(self, slot: int, pages: List[int], ring=()) -> None:
         """Slot `slot` reads and writes through `pages` from now on
-        (scheduler thread)."""
+        (scheduler thread), and its window blocks through the ring
+        `ring`."""
         row = np.zeros((self.n_pages_max,), np.int32)
         row[:len(pages)] = pages
         self.page_table = self.page_table.at[slot].set(jnp.asarray(row))
+        if self.ring_pages:
+            row = np.zeros((self.ring_pages,), np.int32)
+            row[:len(ring)] = ring
+            self.ring_table = self.ring_table.at[slot].set(jnp.asarray(row))
+
+    # what the compiled programs are handed: one array each for a pool of
+    # one class, as ever; a pair (whole-context class, ring) for two
+    @property
+    def tables(self):
+        """The `page_table` argument of a decode dispatch."""
+        return self.page_table if not self.ring_pages \
+            else (self.page_table, self.ring_table)
+
+    def rows(self, slot: int):
+        """The `page_row` argument of a prefill chunk."""
+        return self.page_table[slot] if not self.ring_pages \
+            else (self.page_table[slot], self.ring_table[slot])
+
+    # graftlint: hot-loop
+    def write_ids(self, holder, first: int, count: int, upto: int):
+        """The `wpids` argument of a prefill or a prefill chunk that
+        writes `count` pages from logical page `first` on, of a prompt
+        whose last position lies in logical page `upto`: the holder's
+        pages; and of its ring, logical page `j` at entry `j %
+        ring_pages`, the pad pages past `upto` redirected to the trash
+        page (in a ring they would land on pages the window still
+        reads). A span longer than the ring writes in order, so its last
+        pages stay."""
+        ids = jnp.asarray(np.asarray(holder.pages[first:first + count],
+                                     np.int32))
+        if not self.ring_pages:
+            return ids
+        ring = [holder.ring[j % self.ring_pages] if j <= upto else 0
+                for j in range(first, first + count)]
+        return ids, jnp.asarray(np.asarray(ring, np.int32))
 
     def reset(self) -> None:
         """The pools were rebuilt (construction, weight swap, recovery
@@ -205,10 +297,14 @@ class PagePool:
         pages."""
         self.page_table = jnp.zeros((self.n_slots, self.n_pages_max),
                                     jnp.int32)
+        if self.ring_pages:
+            self.ring_table = jnp.zeros((self.n_slots, self.ring_pages),
+                                        jnp.int32)
         # the free list is read by submit()/stats() on caller threads:
         # publish the rebuilt state under the lock
         with self._cond:
             self._free_pages = list(range(self.pool_pages, 0, -1))
+            self._free_ring = list(range(self.ring_pool_pages, 0, -1))
             if self.prefix_cache is not None:
                 self.prefix_cache.clear()
             if self._leases is not None:

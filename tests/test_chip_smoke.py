@@ -261,6 +261,52 @@ def test_latent_phase_toy_of_the_family_with_both_cache_kinds():
     json.dumps(out)
 
 
+def test_window_phase_toy():
+    """The window stage at toy widths: window 8 and pages of 4, so the
+    ring prompt's three entries wrap within a dozen tokens and the long
+    prompt rides chunks past its ring."""
+    import jax.numpy as jnp
+
+    win = dict(chip_smoke.WINDOW, vocab_size=64, hidden_size=64,
+               num_attention_heads=8, num_key_value_heads=2, head_dim=8,
+               sliding_window=8, intermediate_size=24, num_experts=8,
+               num_experts_per_tok=3, num_shared_experts=2,
+               deployment=dict(num_experts_published=16,
+                               experts_held_first=0))
+    out = chip_smoke.phase_window(
+        win, dict(n_slots=3, max_len=96, page_size=4, prefill_chunk=4,
+                  n_short=2, short_len=8, ring_len=14, long_len=37,
+                  n_tokens=24),
+        kernels=False, dtype=jnp.float32)
+    assert out["requests"] == 4 and out["tokens"] == 4 * 24
+    assert out["prefill_chunks"] >= 10
+    assert (out["window_blocks"], out["kv_blocks"]) == (3, 1)
+    assert out["window_ring_pages"] == 3
+    assert out["window_pages_in_use_peak"] == 3 * 3
+    assert out["window_bytes_per_slot"] == 3 * 12 * 2 * 2 * 8 * 4
+    assert 30.0 < out["window_attended_pct"] < 70.0
+    assert max(out["reference_gaps"]) < 1e-4
+    assert out["agreement"]["common_prefix_tokens"] == [24] * 4
+    json.dumps(out)
+
+
+def test_window_phase_publishes_command_a_pluss_widths():
+    from perfbench.families import cohere2_moe as fam
+
+    sz = fam.sizes(chip_smoke.WINDOW)
+    assert (sz["d"], sz["H"], sz["Hkv"], sz["hd"], sz["W"], sz["f"],
+            sz["n_shared"], sz["topk"], sz["E"], sz["held"], sz["L"]) \
+        == (4096, 128, 8, 128, 4096, 4096, 4, 8, 128, (0, 8), 4)
+    assert sz["layer_types"] == ("sliding_attention",) * 3 \
+        + ("full_attention",)
+    shape = chip_smoke.WINDOW_SERVE
+    # the ring prompt fills the 4,096 bucket's last page and decodes past
+    # a window and a page; the long one outruns every bucket and the ring
+    assert shape["ring_len"] + shape["n_tokens"] > 4096 + 128
+    assert shape["long_len"] > 33 * 128
+    assert shape["long_len"] + shape["n_tokens"] <= shape["max_len"]
+
+
 def test_latent_phase_publishes_ling_flashs_widths():
     from perfbench.families import ling_flash as fam
 

@@ -168,7 +168,8 @@ def test_block_states_env_carries_no_function():
     finally:
         eng.shutdown(1.0)
     assert set(env) == {"n_slots", "page", "pool_pages", "cdt", "kv_quant",
-                        "tp_shard", "tp_axis"}
+                        "tp_shard", "tp_axis", "ring_pages"}  # PR 49
+    assert env["ring_pages"] == 0
     assert not [k for k, v in env.items()
                 if callable(v) and not isinstance(v, (type, np.dtype))]
 
@@ -249,7 +250,12 @@ def test_a_kind_made_here_is_refused_for_what_it_declares(monkeypatch):
 
 # `stats()` of the parent commit (PR 44) after one 6-token prompt and 6
 # tokens, as `{type name: keys}`: the same for a dense, a hybrid routed, a
-# latent and a stateless-block net, whatever moved behind `block_state`
+# latent and a stateless-block net, whatever moved behind `block_state`;
+# PR 49 added the window kind's keys (`WINDOW_STATS`, `WINDOW_LOOP`), all
+# zero on these nets but `kv_positions_*`
+WINDOW_STATS = """window_blocks window_bytes_per_slot window_pages_in_use
+    window_pages_in_use_peak window_ring_pages""".split()
+WINDOW_LOOP = ["kv_positions_attended", "kv_positions_context"]
 PARENT_STATS = {
     "int": """active_slots admitted cluster_prefix_hit_tokens decode_steps
         failures handoff_leases handoffs_aborted handoffs_committed
@@ -266,7 +272,7 @@ PARENT_STATS = {
         shed_unavailable slo_sheds state_bytes_per_slot state_resets
         stateless_blocks submitted swaps tokens_generated tp_degree
         tp_kv_bytes_per_token_per_shard weight_casts
-        weights_resident_bytes""".split(),
+        weights_resident_bytes""".split() + WINDOW_STATS,
     "float": """cluster_prefix_hit_tokens_pct page_fragmentation_pct
         prefix_fetch_ms queue_wait_s slot_occupancy_pct""".split(),
     "list": ["prompt_buckets"],
@@ -289,7 +295,7 @@ PARENT_NESTED = {
             decode.wait_n drained_n housekeeping_n iterations kv_pages_table
             kv_pages_walked overshoot_tokens prefill.deliver_n
             prefill.dispatch_n prefill.wait_n prefill_sorted_n sink_n
-            spans_dropped wait-work_n""".split(),
+            spans_dropped wait-work_n""".split() + WINDOW_LOOP,
         "float": """admit_s decode.deliver_s decode.dispatch_s decode.wait_s
             housekeeping_s prefill.deliver_s prefill.dispatch_s
             prefill.wait_s sink_s wait-work_s""".split()},
@@ -368,6 +374,9 @@ def test_stats_are_the_parents_keys_types_and_numbers(name, make_net):
         assert _by_type(st[key]) == _sets(want), key
     assert observability.DECODE_ENGINE_STATS_KEYS <= set(st)
     assert {k: st[k] for k in PARENT_NUMBERS[name]} == PARENT_NUMBERS[name]
+    assert [st[k] for k in WINDOW_STATS] == [0] * len(WINDOW_STATS)
+    loop = st["loop"]
+    assert loop["kv_positions_attended"] == loop["kv_positions_context"]
 
 
 def test_sampling_helpers_greedy_finite_screen_and_logprobs():

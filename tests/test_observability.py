@@ -732,6 +732,7 @@ def test_thread_phases_partition_a_threads_time():
     ph.enter("admit")
     ph.enter("admit")  # the phase goes on: no second span
     ph.enter("decode.dispatch", program="decode_step", chunk=1, active=2)
+    ph.annotate(kv_positions_attended=7)  # on the open phase's span
     ph.enter("decode.wait")
     still_open = ph.counters()
     ph.close()
@@ -743,7 +744,8 @@ def test_thread_phases_partition_a_threads_time():
     assert [s[3] for s in spans] == [0, 1, 1, 1]
     assert {s[4] for s in spans} == {threading.get_ident()}
     assert spans[2][5] == {"program": "decode_step", "chunk": 1,
-                           "active": 2}
+                           "active": 2, "kv_positions_attended": 7}
+    assert spans[3][5] is None
     c = ph.counters()
     assert c["iterations"] == 1 and c["admit_n"] == 1
     total = sum(v for k, v in c.items()
@@ -946,6 +948,8 @@ def test_loop_and_front_keys_in_contract_and_exposition(net):
         assert set(loop) == {"iterations", "sink_s", "sink_n", "ahead_n",
                              "drained_n", "overshoot_tokens",
                              "kv_pages_walked", "kv_pages_table",
+                             "kv_positions_attended",
+                             "kv_positions_context",
                              "prefill_sorted_n", "spans_dropped"} \
             | {p + sfx for p in obs.LEAF_PHASES for sfx in ("_s", "_n")}
         text = eng.metrics_text()

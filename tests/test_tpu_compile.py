@@ -749,3 +749,156 @@ def test_one_sub_layer_latent_net_compiles_at_the_published_widths(
     for name in ("mla_prefill", "moe_experts_sorted"):
         assert name in text
     assert prefill.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("window,table,pool", [(4096, 33, 48 * 33),
+                                               (None, 96, 3168)],
+                         ids=("ring-of-33", "row-of-96"))
+def test_paged_attention_compiles_at_128_heads_over_8(one_chip, window,
+                                                      table, pool):
+    """Command A+'s decode attention: 48 slots, 128 query heads over 8
+    K/V heads of 128 (16 to a group), pages of 128; a window layer's
+    walk over its ring of 33 entries and the full layer's over its row
+    of 96: the pools left where they lie."""
+    from deeplearning4j_tpu.ops.pallas_paged_attention import (
+        paged_attention,
+    )
+
+    S = _shapes(one_chip)
+    i32 = jnp.int32
+    with jax.enable_x64(False):
+        compiled = paged_attention.lower(
+            S((48, 1, 128, 128)), S((pool + 1, 8, 128, 128)),
+            S((pool + 1, 8, 128, 128)), S((48, table), i32), S((48,), i32),
+            active=S((48,), jnp.bool_), window=window).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("window", (4096, None), ids=("window", "full"))
+def test_flash_forward_reads_grouped_heads_at_4096(one_chip, window):
+    """A 4,096-token prompt's attention at 128 query heads over 8 K/V
+    heads: one kernel, the K/V slabs read by group (no array of the
+    repeated keys' size, 134 MB, and none of the scores', 8.6 GB)."""
+    from deeplearning4j_tpu.ops.pallas_attention import flash_attention
+
+    S = _shapes(one_chip)
+    with jax.enable_x64(False):
+        compiled = jax.jit(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, block_q=1024, block_k=1024,
+                window=window)).lower(
+            S((1, 4096, 128, 128)), S((1, 4096, 8, 128)),
+            S((1, 4096, 8, 128))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "[128,4096,4096]" not in text
+    # the transposes into and out of slabs: q and o, 134 MB each, K and V
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 135e6
+
+
+def _window_programs(one_chip, monkeypatch, layers: int):
+    """Command A+'s net at the cell's widths and engine settings (48
+    slots, pages of 128, 3,168 pages, rows of 96, a ring of 33),
+    described and not drawn, its kernel families steered on as they are
+    on the chip."""
+    import json
+    from pathlib import Path
+    from types import SimpleNamespace
+
+    from deeplearning4j_tpu.models.transformer import GPTPlan
+    from deeplearning4j_tpu.ops import (
+        pallas_attention, pallas_moe_experts, pallas_paged_attention,
+        pallas_paged_kv_write,
+    )
+    from deeplearning4j_tpu.serving import block_state, decode_programs
+    from perfbench.families import cohere2_moe as fam
+
+    for mod in (pallas_attention, pallas_moe_experts, pallas_paged_attention,
+                pallas_paged_kv_write):
+        monkeypatch.setattr(mod, "_platform_supported", lambda: True)
+        monkeypatch.setattr(mod, "_probe_verdict", lambda *a, **k: True)
+        monkeypatch.setattr(mod, "_vmem_limit", lambda: 112 << 20)
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "perfbench"
+                      / "configs" / "command-a-plus-05-2026.json")
+                     .read_text())
+    # a period of two, so that two layers hold one of each kind
+    cfg.update(num_hidden_layers=layers, layer_switch=2,
+               layer_types=["sliding_attention", "full_attention"][:layers])
+    sz = fam.sizes(cfg)
+    S = _shapes(one_chip)
+    shapes = fam._leaf_shapes(sz)
+    tree = {n: S(shapes[n]) for n in fam.TOP_LEAVES}
+    tree["layers"] = [
+        {n: S(shapes[n], jnp.float32 if n == "rb" else jnp.bfloat16)
+         for n in fam.LAYER_LEAVES} for _ in range(layers)]
+    net = fam.build_net(sz, training=False)
+    net._params = [{k: S(v.shape, v.dtype) for k, v in p.items()}
+                   for p in fam.to_program(tree)]
+    plan = GPTPlan(net)
+    n_slots, page, pool_pages, max_len = 48, 128, 3168, 12288
+    ring = block_state.ring_pages(plan, page, 128)
+    states = block_state.block_states(plan, SimpleNamespace(
+        n_slots=n_slots, page=page, pool_pages=pool_pages, cdt=plan.cdt,
+        kv_quant=None, tp_shard=None, tp_axis=None, ring_pages=ring))
+    i32, f32 = jnp.int32, jnp.float32
+    with jax.enable_x64(False):
+        programs = decode_programs.build_programs(
+            plan, states, n_slots=n_slots, page=page, L_logical=max_len,
+            decode_chunk=4, top_k=0, logprobs=0, tp=None, donate=True,
+            ring_pages=ring)
+        caches = [jax.tree.map(lambda a: S(a.shape, a.dtype),
+                               jax.eval_shape(st.alloc)) for st in states]
+    slot_args = (S((n_slots,), i32), S((n_slots,), i32),
+                 S((n_slots, 2), jnp.uint32), S((n_slots,), f32))
+    decode_args = ((S((n_slots, max_len // page), i32),
+                    S((n_slots, ring), i32)), *slot_args,
+                   S((n_slots,), jnp.bool_))
+    prefill_args = lambda T: (
+        S((1, T), i32), S((), i32), S((), i32),
+        (S((T // page,), i32), S((T // page,), i32)), *slot_args,
+        S((2,), jnp.uint32), S((2,), jnp.uint32), S((), f32))
+    return plan, ring, programs, (net._params, caches), decode_args, \
+        prefill_args
+
+
+@pytest.mark.parametrize("program", ("decode_step", "decode_chunked"))
+def test_window_net_decode_step_compiles_at_the_published_widths(
+        one_chip, monkeypatch, program):
+    """A window layer and a full layer of Command A+ at their published
+    widths through `build_programs`, two classes of page: two in-place
+    K/V writes, the windowed and the whole-context paged attention and
+    two grouped expert products tiled over `f` in one step, no pool of
+    either class copied and no weight re-laid, in the step alone and in
+    the chunk of four that serves."""
+    import chip_smoke
+
+    plan, ring, programs, held, decode_args, _ = _window_programs(
+        one_chip, monkeypatch, 2)
+    assert plan.state_kinds() == ["window", "kv"] and ring == 33
+    with jax.enable_x64(False):
+        text = getattr(programs, program).lower(
+            *held, *decode_args).compile().as_text()
+    assert text.count("tpu_custom_call") == 6
+    for name in ("paged_attention", "paged_kv_write", "moe_experts"):
+        assert name in text
+    pools = {f"bf16[{48 * 33 + 1},8,128,128]", "bf16[3169,8,128,128]"}
+    assert chip_smoke.pool_layout_copies(text, pools) == 0
+    _assert_no_weight_is_relaid(text, held[0])
+
+
+def test_window_net_prefill_at_4096_makes_no_array_of_scores(one_chip,
+                                                             monkeypatch):
+    """A 4,096-token prompt through both layers: the flash forward with
+    a window and without, grouped, the experts' rows sorted; no (128,
+    4096, 4096) array, temporaries under 1.5 GB."""
+    plan, ring, programs, held, _, prefill_args = _window_programs(
+        one_chip, monkeypatch, 2)
+    with jax.enable_x64(False):
+        prefill = programs.prefill.lower(
+            *held, *prefill_args(4096)).compile()
+    text = prefill.as_text()
+    assert "[128,4096,4096]" not in text
+    assert text.count("tpu_custom_call") == 4
+    assert "moe_experts_sorted" in text
+    assert prefill.memory_analysis().temp_size_in_bytes < 1.5e9
