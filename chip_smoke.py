@@ -676,6 +676,18 @@ def weight_layout_copies(hlo_text: str, weight_shapes) -> int:
     return len(weight_layout_ops(hlo_text, weight_shapes))
 
 
+def route_sorts(hlo_text: str) -> int:
+    """`sort` instructions of an optimised HLO module that a routed
+    block's choice left there: those traced under `moe.route` (the
+    groups' `moe.groups` lies inside it). The routers choose by a mask
+    made without one (`parallel.experts.chosen_mask`), so a program
+    holds none; a prefill's `sort_by_expert` sorts under `moe.experts`
+    and is not counted."""
+    line = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = .+? sort\(.*"
+                      r"op_name=\"[^\"]*moe\.(?:route|groups)[/\"]")
+    return sum(1 for text in hlo_text.splitlines() if line.match(text))
+
+
 def _hlo_shapes(arrays) -> set:
     return {f"{_HLO_DTYPES[a.dtype.name]}[{','.join(map(str, a.shape))}]"
             for a in arrays}
@@ -688,8 +700,9 @@ def _decode_program_counts(engine) -> dict:
     recurrent block's state. Its convolution tail is left out: 3 taps
     a channel, 0.1% of the state's bytes, and XLA moves it to fast
     memory and back each step by choice (same layout, `S(1)`). And the
-    casts of a weight matrix (`weight_converts`) and the instructions
-    that re-lay one (`weight_layout_copies`)."""
+    casts of a weight matrix (`weight_converts`), the instructions that
+    re-lay one (`weight_layout_copies`) and the sorts a router's choice
+    left (`route_sorts`)."""
     import jax
     import jax.numpy as jnp
 
@@ -712,7 +725,8 @@ def _decode_program_counts(engine) -> dict:
             "weight_converts": {n: weight_converts(t, weights)
                                 for n, t in texts.items()},
             "weight_layout_copies": {n: weight_layout_copies(t, weights)
-                                     for n, t in texts.items()}}
+                                     for n, t in texts.items()},
+            "route_sorts": {n: route_sorts(t) for n, t in texts.items()}}
 
 
 def phase_serve(gpt: dict, shape: dict, *, kernels: bool) -> dict:
@@ -1314,6 +1328,9 @@ def phase_latent(lat: dict, shape: dict, *, kernels: bool,
         _check(not any(out["weight_layout_copies"].values()),
                f"the decode programs re-lay their weights: "
                f"{out['weight_layout_copies']}")
+        _check(not any(out["route_sorts"].values()),
+               f"the decode programs sort for their routers: "
+               f"{out['route_sorts']}")
     return out
 
 
@@ -1440,6 +1457,9 @@ def phase_window(win: dict, shape: dict, *, kernels: bool,
         _check(not any(out["weight_layout_copies"].values()),
                f"the decode programs re-lay their weights: "
                f"{out['weight_layout_copies']}")
+        _check(not any(out["route_sorts"].values()),
+               f"the decode programs sort for their routers: "
+               f"{out['route_sorts']}")
     return out
 
 

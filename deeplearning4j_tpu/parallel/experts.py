@@ -17,6 +17,8 @@ routing is by sort/scatter, no data-dependent control flow.
 from __future__ import annotations
 
 import contextlib
+import functools
+import math
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -275,13 +277,96 @@ def switch_ffn(params, tokens: jnp.ndarray, *, act: Callable,
 # is no capacity, so no routing can lose a token.
 
 
+# `chosen_mask` ranks every lane against every other lane of its row
+# while that takes fewer than this many compares over all its rows
+# (rows x lanes^2), and marks the largest in rounds from there on, whose
+# work is k x rows x lanes. On a v5e the rank is as quick or quicker up
+# to (512, 72) and (128, 160), 2.7 M and 3.3 M compares (3.7 against
+# 13.7 us, 4.4 against 4.9), and the rounds from (128, 8, 64) and
+# (512, 128) on, 4.2 M and 8.4 M (1.4 against 5.6 us, 8.1 against 39.7;
+# 6.3 against 49.4 at (128, 512)); the form taken runs 4 to 120 times
+# quicker than the sort it replaces and compiles to a twentieth to four
+# fifths of it (`tools/router_choice_bench.py`; PERF.md section 6,
+# PR 51).
+RANK_COMPARES = 1 << 22
+
+
+def _total_order(scores: jnp.ndarray) -> jnp.ndarray:
+    """float32 -> int32 whose signed order is the floats' TOTAL order
+    (-NaN < -inf < ... < -0 < +0 < ... < +inf < +NaN), what `lax.top_k`
+    ranks by."""
+    bits = lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.int32)
+    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def _by_rank(key: jnp.ndarray, k: int) -> jnp.ndarray:
+    """A lane is chosen where fewer than `k` lanes of its row lie ahead
+    of it (a larger key, or the same key at a lower index): one
+    compare-and-count over (..., E, E), whose program does not grow
+    with `k`."""
+    E = key.shape[-1]
+    mine, other = key[..., :, None], key[..., None, :]
+    shape = key.shape + (E,)
+    i = lax.broadcasted_iota(jnp.int32, shape, key.ndim - 1)
+    j = lax.broadcasted_iota(jnp.int32, shape, key.ndim)
+    ahead = (other > mine) | ((other == mine) & (j < i))
+    return jnp.sum(ahead, axis=-1, dtype=jnp.int32) < k
+
+
+def _by_rounds(key: jnp.ndarray, k: int) -> jnp.ndarray:
+    """`k` rounds, each marking the FIRST largest key among the lanes
+    not yet marked, rolled into one loop: the program holds one round,
+    whatever `k`. Two rounds or fewer are written out (a loop holds as
+    much, and one whose carry has three axes compiles to seven times
+    that on the TPU)."""
+    E = key.shape[-1]
+    lane = lax.broadcasted_iota(jnp.int32, key.shape, key.ndim - 1)
+
+    def mark(_, chosen):
+        top = jnp.max(jnp.where(chosen, jnp.iinfo(jnp.int32).min, key),
+                      axis=-1, keepdims=True)
+        first = jnp.min(jnp.where(~chosen & (key >= top), lane, E),
+                        axis=-1, keepdims=True)
+        return chosen | (lane == first)
+
+    return lax.fori_loop(0, k, mark, jnp.zeros(key.shape, bool),
+                         unroll=k <= 2)
+
+
+def ranks(shape) -> bool:
+    """Whether `chosen_mask` ranks scores of `shape` (rounds else)."""
+    return math.prod(shape) * shape[-1] < RANK_COMPARES
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def chosen_mask(scores: jnp.ndarray, k: int) -> jnp.ndarray:
+    """(..., E) float32 scores -> (..., E) bool, true on the `min(k, E)`
+    lanes of each row that `lax.top_k(scores, k)[1]` names, for any
+    input: the larger score first in the floats' total order, equal
+    scores to the lower lane, a -inf lane only once fewer than `k`
+    others are left. No sort (the TPU's `top_k` is a full stable one),
+    no index array to gather by or to scatter to. The form follows the
+    static shape alone: a rank by compare-and-count while rows x E^2
+    stays under `RANK_COMPARES`, rounds of first-maximum from there. A
+    `jit` entry, so that a program's routed layers trace it once a shape
+    and not once a layer (tracing is paid on every start, cache or
+    not)."""
+    key = _total_order(scores)
+    # graftlint: disable=recompile  the form is MEANT to be baked in by
+    # the shape; a program's routers have a handful of shapes, each
+    # traced once
+    if ranks(key.shape):
+        return _by_rank(key, k)
+    return _by_rounds(key, min(k, key.shape[-1]))
+
+
 def topk_gates(logits: jnp.ndarray, top_k: int) -> jnp.ndarray:
     """(N, E) router logits -> (N, E) float32 gates: softmax over each
-    row's `top_k` largest logits, zero elsewhere."""
-    top_v, top_i = lax.top_k(logits.astype(jnp.float32), top_k)
-    w = jax.nn.softmax(top_v, axis=-1)
-    rows = jnp.arange(logits.shape[0])[:, None]
-    return jnp.zeros(logits.shape, jnp.float32).at[rows, top_i].set(w)
+    row's `top_k` largest logits (`chosen_mask`; the sum runs over the
+    chosen in lane order), zero elsewhere."""
+    logits = logits.astype(jnp.float32)
+    return jax.nn.softmax(
+        jnp.where(chosen_mask(logits, top_k), logits, -jnp.inf), axis=-1)
 
 
 def sigmoid_topk_gates(logits: jnp.ndarray, bias: jnp.ndarray, top_k: int,
@@ -292,22 +377,19 @@ def sigmoid_topk_gates(logits: jnp.ndarray, bias: jnp.ndarray, top_k: int,
     experts are chosen on `sigmoid(logits) + bias` (`bias` (E,), the
     score-correction bias: it moves the choice and never the weight),
     and a chosen expert's gate is its UNBIASED score over the chosen
-    scores' sum, times `scale`; zero elsewhere. With `n_groups` > 1 the
-    choice is made among each row's `topk_groups` best groups only, a
-    group scored by the SUM of its two largest biased scores
-    (`group_limited`); a biased score may be negative, so what lies
-    outside the kept groups is set to -inf, not 0, and no choice leaves
-    them."""
+    scores' sum (in lane order), times `scale`; zero elsewhere. With
+    `n_groups` > 1 the choice is made among each row's `topk_groups`
+    best groups only, a group scored by the SUM of its two largest
+    biased scores (`group_limited`); a biased score may be negative, so
+    what lies outside the kept groups is set to -inf, not 0, and no
+    choice leaves them."""
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     choose_on = s + bias.astype(jnp.float32)
     if n_groups > 1:
         choose_on = group_limited(choose_on, n_groups, topk_groups, best=2,
                                   fill=-jnp.inf)
-    _, top_i = lax.top_k(choose_on, top_k)
-    rows = jnp.arange(logits.shape[0])[:, None]
-    top_s = s[rows, top_i]
-    w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20) * scale
-    return jnp.zeros(logits.shape, jnp.float32).at[rows, top_i].set(w)
+    top_s = jnp.where(chosen_mask(choose_on, top_k), s, 0.0)
+    return top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20) * scale
 
 
 def group_limited(scores: jnp.ndarray, n_groups: int, topk_groups: int,
@@ -317,17 +399,16 @@ def group_limited(scores: jnp.ndarray, n_groups: int, topk_groups: int,
     groups of `E / n_groups` (expert `e` in group `e // (E / n_groups)`),
     a group's score is the sum of the `best` largest scores in it (1:
     the LARGEST, the published `group_limited_greedy`; 2: DeepSeek-V3's
-    `noaux_tc`), each row keeps its `topk_groups` best groups and every
-    other group's scores are set to `fill` (0 where the scores are >= 0;
-    -inf where they may be negative). `scores` (N, E) float32."""
+    `noaux_tc`), each row keeps its `topk_groups` best groups
+    (`chosen_mask`) and every other group's scores are set to `fill` (0
+    where the scores are >= 0; -inf where they may be negative).
+    `scores` (N, E) float32."""
     N, E = scores.shape
     with jax.named_scope("moe.groups"):
         by_group = scores.reshape(N, n_groups, E // n_groups)
-        of_group = jnp.max(by_group, axis=-1) if best == 1 else \
-            jnp.sum(lax.top_k(by_group, best)[0], axis=-1)
-        _, top_g = lax.top_k(of_group, topk_groups)
-        keep = jnp.zeros((N, n_groups), bool).at[
-            jnp.arange(N)[:, None], top_g].set(True)
+        of_group = jnp.max(by_group, axis=-1) if best == 1 else jnp.sum(
+            jnp.where(chosen_mask(by_group, best), by_group, 0.0), axis=-1)
+        keep = chosen_mask(of_group, topk_groups)
         return jnp.where(keep[:, :, None], by_group, fill).reshape(N, E)
 
 
@@ -346,10 +427,7 @@ def softmax_all_topk_gates(logits: jnp.ndarray, bias: jnp.ndarray,
     choose_on = s + bias.astype(jnp.float32)
     if n_groups > 1:
         choose_on = group_limited(choose_on, n_groups, topk_groups)
-    _, top_i = lax.top_k(choose_on, top_k)
-    rows = jnp.arange(logits.shape[0])[:, None]
-    return jnp.zeros(logits.shape, jnp.float32).at[rows, top_i].set(
-        s[rows, top_i] * scale)
+    return jnp.where(chosen_mask(choose_on, top_k), s * scale, 0.0)
 
 
 def check_groups(scoring: str, n_experts: int, n_groups: int,

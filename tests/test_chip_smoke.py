@@ -426,6 +426,26 @@ ENTRY %main.33 (bp_1___mx_Wqr__.1: bf16[1536,8192], bp_1___mx_Wkb__.1: bf16[128,
 """
 
 
+def test_route_sorts_counts_the_sorts_of_a_choice_only():
+    """Counted: a `sort` traced under `moe.route`, the groups' under
+    `moe.groups` inside it. Not counted: `sort_by_expert`'s under
+    `moe.experts`, a sort outside any routed block, and another opcode
+    under the router's scope."""
+    sort = ('  %sort.{n} = (f32[128,512]{{0,1:T(8,128)}}, s32[128,512]{{0,1}}) '
+            'sort(%copy.{n}, %iota.{n}), dimensions={{1}}, is_stable=true, '
+            'to_apply=%lt.{n}, metadata={{op_name="jit(decode_step)/{at}" '
+            'stack_frame_id=20}}, backend_config={{"flag_configs":[]}}')
+    text = "\n".join([
+        sort.format(n=1, at="blocks/moe.route/top_k"),
+        sort.format(n=2, at="blocks/moe.route/moe.groups/top_k"),
+        sort.format(n=3, at="blocks/moe.experts/sort"),
+        sort.format(n=4, at="sample/sort"),
+        '  ROOT %scatter.5 = f32[65536]{0} scatter(%a, %b, %c), metadata='
+        '{op_name="jit(decode_step)/blocks/moe.route/scatter"}'])
+    assert chip_smoke.route_sorts(text) == 2
+    assert chip_smoke.route_sorts("") == 0
+
+
 def test_weight_layout_copies_follows_a_weight_and_nothing_else():
     """Counted: the entry's two copies of a parameter of a weight's
     shape, and in the loop's body, which is handed the copy, its

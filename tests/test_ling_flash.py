@@ -318,7 +318,11 @@ def test_one_group_is_todays_sigmoid_router():
     np.testing.assert_array_equal(every, one)
     fn = lambda n: str(jax.make_jaxpr(lambda x: experts.routed_gates(
         x, 6, n_groups=n, topk_groups=n, **kw))(lg))
-    assert fn(1).count("top_k") == 1 and fn(8).count("top_k") == 3
+    # every choice is a mask made without `lax.top_k` (`chosen_mask`, a
+    # count at these shapes): the experts' alone, or after a group's
+    # best two, their sum and the groups kept
+    assert "top_k" not in fn(8) and fn(1).count("reduce_sum") + 3 \
+        == fn(8).count("reduce_sum")
     assert fn(1) == str(jax.make_jaxpr(
         lambda x: experts.routed_gates(x, 6, **kw))(lg))
 

@@ -543,11 +543,12 @@ def test_latent_kernels_compile_at_the_published_widths(one_chip,
 
 
 def _latent_programs(one_chip, monkeypatch, fam, cfg, layers, pool_pages,
-                     max_len, float32=()):
-    """A latent family's net at `cfg`'s widths, described and not drawn
-    (`layers(sz)`: each layer's leaf names; `float32`: those not held
-    in bfloat16), through `build_programs` for 128 slots and pages of
-    128 with its kernel families steered on as they are on the chip.
+                     max_len, float32=(), n_slots=128):
+    """A latent family's net (or, since PR 51, granite's) at `cfg`'s
+    widths, described and not drawn (`layers(sz)`: each layer's leaf
+    names; `float32`: those not held in bfloat16), through
+    `build_programs` for `n_slots` slots and pages of 128 with its
+    kernel families steered on as they are on the chip.
     Returns the plan, the programs, their (parameters, caches), the
     decode programs' other operands and, for a prompt bucket, a
     prefill's; lower inside `jax.enable_x64(False)`."""
@@ -555,7 +556,8 @@ def _latent_programs(one_chip, monkeypatch, fam, cfg, layers, pool_pages,
 
     from deeplearning4j_tpu.models.transformer import GPTPlan
     from deeplearning4j_tpu.ops import (
-        pallas_delta_step, pallas_mla_attend, pallas_moe_experts,
+        pallas_attention, pallas_delta_step, pallas_mla_attend,
+        pallas_moe_experts, pallas_paged_attention, pallas_paged_kv_write,
     )
     from deeplearning4j_tpu.serving import block_state, decode_programs
 
@@ -563,6 +565,11 @@ def _latent_programs(one_chip, monkeypatch, fam, cfg, layers, pool_pages,
         monkeypatch.setattr(mod, "_platform_supported", lambda: True)
         monkeypatch.setattr(mod, "_probe_verdict", lambda *a, **k: True)
         monkeypatch.setattr(mod, "_vmem_limit", lambda: 112 << 20)
+    # a net with K/V blocks (no latent family has one)
+    for mod in (pallas_attention, pallas_paged_attention,
+                pallas_paged_kv_write):
+        monkeypatch.setattr(mod, "_platform_supported", lambda: True)
+        monkeypatch.setattr(mod, "_probe_verdict", lambda *a, **k: True)
     sz = fam.sizes(cfg)
     S = _shapes(one_chip)
     shapes = fam._leaf_shapes(sz)
@@ -574,7 +581,7 @@ def _latent_programs(one_chip, monkeypatch, fam, cfg, layers, pool_pages,
     net._params = [{k: S(v.shape, v.dtype) for k, v in p.items()}
                    for p in fam.to_program(tree)]
     plan = GPTPlan(net)
-    n_slots, page = 128, 128
+    page = 128
     states = block_state.block_states(plan, SimpleNamespace(
         n_slots=n_slots, page=page, pool_pages=pool_pages, cdt=plan.cdt,
         kv_quant=None, tp_shard=None, tp_axis=None))
@@ -902,3 +909,67 @@ def test_window_net_prefill_at_4096_makes_no_array_of_scores(one_chip,
     assert text.count("tpu_custom_call") == 4
     assert "moe_experts_sorted" in text
     assert prefill.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+# The three programs' serialized executables, summed, as the tree BEFORE
+# PR 51 compiled them here (the routers' choices by `lax.top_k` and a
+# scatter), and the largest prompt bucket each cell's traffic warms.
+ROUTED_NETS = {
+    "granite": (512, 38_594_496),
+    "ling": (1024, 70_334_293),
+    "longcat": (1024, 63_088_586),
+}
+
+
+@pytest.mark.parametrize("net", sorted(ROUTED_NETS))
+def test_routed_net_chooses_without_a_sort_and_loads_no_more(
+        one_chip, monkeypatch, net):
+    """A granite-, a Ling- and a LongCat-shaped routed net at the
+    published widths: the compiled `decode_step`, `decode_chunked` and
+    largest prefill bucket hold no `sort` under `moe.route` /
+    `moe.groups` (`parallel.experts.chosen_mask` chooses by rank or by
+    rounds, by the shape), and the three serialized executables
+    together, what a warm start reads and loads, are no larger than the
+    parent's were. A form of the choice that bloats the programs fails
+    here and not at the driver (PERF.md section 7, what PR 50 taught)."""
+    import chip_smoke
+
+    import json
+    from pathlib import Path
+
+    from perfbench.families import granite_hybrid, ling_flash, longcat_flash
+
+    bucket, parent_bytes = ROUTED_NETS[net]
+    if net == "granite":
+        # a Mamba-2 layer and an attention layer, 36 of the 72 experts
+        # held, 64 slots and 576 pages as the cell has them
+        cfg = json.loads((Path(__file__).resolve().parents[1] / "perfbench"
+                          / "configs/granite-4.0-h-small.json").read_text())
+        cfg.update(num_hidden_layers=2, layer_types=["mamba", "attention"],
+                   vocab_size=256)
+        fam, described = granite_hybrid, (
+            cfg, lambda sz: [granite_hybrid.MIXER_LEAVES[kind]
+                             + granite_hybrid.FFN_LEAVES
+                             for kind in sz["layer_types"]], 576, 2048, (), 64)
+    elif net == "ling":
+        fam, described = ling_flash, (
+            chip_smoke.LATENT_KDA,
+            lambda sz: [ling_flash.layer_leaves(sz, i)
+                        for i in range(sz["L"])], 2560, 4096, ("rb",))
+    else:
+        fam, described = longcat_flash, (
+            chip_smoke.LATENT, lambda sz: [longcat_flash.LAYER_LEAVES],
+            2560, 4096, longcat_flash.FLOAT32_LEAVES)
+    _, programs, held, decode_args, prefill_args = _latent_programs(
+        one_chip, monkeypatch, fam, *described)
+    total = 0
+    with jax.enable_x64(False):
+        for program, args in ((programs.decode_step, decode_args),
+                              (programs.decode_chunked, decode_args),
+                              (programs.prefill, prefill_args(bucket))):
+            compiled = program.lower(*held, *args).compile()
+            text = compiled.as_text()
+            assert "moe.route" in text
+            assert chip_smoke.route_sorts(text) == 0
+            total += len(compiled.runtime_executable().serialize())
+    assert total <= parent_bytes, (net, total)

@@ -5,8 +5,8 @@
 
 Runs one serve cell of `BENCHMARK.json` as `perfbench/run.py` does (same
 harness call, same clock from the process's start) and prints, beside
-the cell's metrics, `setup_s` cut into what lies outside the program,
-`_build`'s four phases and the scheduler thread's warm-up and filling
+the cell's metrics and a traced run's `breakdown`, `setup_s` cut into
+what lies outside the program, `_build`'s four phases and the scheduler thread's warm-up and filling
 (`weight_hash`: the fold of the served leaves on the device, its bytes,
 the bytes of them that crossed to the host and the rate);
 JAX's compile pipeline by function and by the phase each event fell in;
@@ -141,6 +141,7 @@ def main(argv=None) -> int:
     table = {
         "workload": args.workload, "seed": args.seed, "trace": args.trace,
         "correct": out["correct"], "failed": out["failed"],
+        "breakdown": out.get("breakdown"),
         "device": out["device"],
         "metrics": {k: v["value"] for k, v in out["metrics"].items()},
         "end_to_end": {k: v["value"] for k, v in result.metric_values(
